@@ -51,13 +51,16 @@ def main():
         make_optimizer,
         make_train_step,
     )
+    from ray_tpu._private.compile_cache import enable_persistent_cache
     from ray_tpu.parallel import MeshConfig, create_mesh
+    from ray_tpu.util.accelerators import require_tpu
 
     ray_tpu.shutdown()
     ray_tpu.init(num_cpus=8)
 
-    on_tpu = jax.devices()[0].platform == "tpu"
-    cfg = LlamaConfig.llama3_1b() if on_tpu else LlamaConfig.debug()
+    require_tpu()
+    enable_persistent_cache()
+    cfg = LlamaConfig.llama3_1b()
     seq = min(args.seq, cfg.max_seq_len)
     mesh = create_mesh(MeshConfig(data=-1))
     params = init_params_sharded(cfg, mesh, jax.random.PRNGKey(0))
@@ -94,7 +97,7 @@ def main():
         })
 
     it = epoch_batches()
-    # Warmup: first batch + first step (compile + platform stall).
+    # Warmup: first batch + first step (compile).
     batch = next(it)
     tokens = jnp.asarray(np.asarray(batch["tokens"]))
     state, metrics = step(state, {
@@ -129,7 +132,7 @@ def main():
         "value": round(tokens_total / wall, 1),
         "unit": "tokens/s",
         "detail": {
-            "config": "llama-1.24B" if on_tpu else "llama-debug-cpu",
+            "config": "llama-1.24B",
             "steps": args.steps, "batch": args.batch, "seq": seq,
             "data_wait_fraction": round(data_wait / wall, 4),
             "data_wait_ms_per_step": round(

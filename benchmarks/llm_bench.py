@@ -154,13 +154,6 @@ def _proxy_sse_leg(cfg, params, prompts, max_tokens, concurrency):
     from ray_tpu.serve.llm import LLMDeployment
 
     ray_tpu.shutdown()
-    # The bench model's warmup compile (tens of seconds on CPU) would
-    # blow the default ~4s replica-health window and get the replica
-    # struck mid-warmup; widen supervision for the bench only.
-    from ray_tpu._private.config import ray_config
-
-    ray_config.serve_replica_health_timeout_s = 10.0  # bench-only
-    ray_config.serve_replica_health_failures = 30
     ray_tpu.init(num_cpus=4)
     serve.run(
         serve.deployment(LLMDeployment).bind(

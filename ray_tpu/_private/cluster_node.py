@@ -991,8 +991,8 @@ def _res_kwargs(resources: Dict[str, float]) -> dict:
     res = dict(resources)
     if "CPU" in res:
         kw["num_cpus"] = res.pop("CPU")
-    if "TPU" in res:
-        kw["num_tpus"] = res.pop("TPU")
+    # Always told, so a CPU node's init() never imports JAX.
+    kw["num_tpus"] = res.pop("TPU", 0)
     if res:
         kw["resources"] = res
     return kw
@@ -1012,6 +1012,17 @@ def main():
     resources = {"CPU": args.num_cpus}
     if args.num_tpus:
         resources["TPU"] = args.num_tpus
+        # This process runs the node's TPU tasks, so it is the one that
+        # opens the chips: a node that advertises more than it can open
+        # must not register.
+        import jax
+
+        found = sum(1 for d in jax.devices() if d.platform == "tpu")
+        if found < args.num_tpus:
+            raise SystemExit(
+                f"node advertises --num-tpus {args.num_tpus:g} but JAX "
+                f"found {found} TPU device(s) (platform "
+                f"{jax.devices()[0].platform!r})")
     labels = dict(kv.split("=", 1) for kv in args.label)
     runtime = NodeRuntime((host, int(port)), resources,
                           node_id=args.node_id, shm_name=args.shm_name,

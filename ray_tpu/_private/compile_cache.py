@@ -1,54 +1,50 @@
 """Persistent XLA compilation cache.
 
-Role-equivalent to the reference's lack of one — serving cold starts
-there are hidden by long-lived GPU replicas; on TPU the first request
-hitting an uncompiled program costs the full XLA compile (measured 14 s
-TTFT for the LLM engine in round 3). Enabling JAX's on-disk compilation
-cache makes every process after the first load compiled executables
-instead of recompiling, and `LLMEngine.warmup()` moves the remaining
-first-process compile to deploy time.
+A cold start compiles every program of a path (the LLM engine's bucket
+ladder, the train step); with JAX's on-disk cache every later process
+loads the executables instead. Where the cache lives follows one rule:
+
+- ``JAX_COMPILATION_CACHE_DIR`` set: JAX reads the variable itself and
+  this module sets no directory.
+- unset: one fixed directory in the checkout, beside the package. The
+  path is part of the cache's key, so it is derived from the package's
+  location and never from the working directory, the home directory, a
+  pid or a time.
+
+Both entry paths (`JaxTrainer` workers, `LLMEngine`) call
+:func:`enable_persistent_cache` before their first compile: JAX
+initialises its cache once, at the first compilation of the process.
 """
 
 from __future__ import annotations
 
 import os
+from typing import Optional
 
-_enabled = False
-
-DEFAULT_DIR = os.environ.get(
-    "RAY_TPU_COMPILE_CACHE",
-    os.path.join(os.path.expanduser("~"), ".cache", "ray_tpu_xla"))
+_IN_CHECKOUT_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
 
 
-def enable_persistent_cache(path: str | None = None) -> bool:
-    """Idempotently point JAX at an on-disk compilation cache. Returns
-    True if the cache is active. Set RAY_TPU_COMPILE_CACHE="" to opt
-    out."""
-    global _enabled
-    if _enabled:
-        return True
-    target = DEFAULT_DIR if path is None else path
-    if not target:
-        return False  # explicitly disabled
-    try:
-        import jax
+def cache_dir() -> str:
+    """The directory compiled programs are cached in."""
+    return os.environ.get("JAX_COMPILATION_CACHE_DIR") or _IN_CHECKOUT_DIR
 
-        if jax.default_backend() == "cpu" and \
-                not os.environ.get("RAY_TPU_COMPILE_CACHE"):
-            # CPU AOT results are machine-feature-sensitive (XLA warns
-            # mismatched loads "could lead to SIGILL"); the cache's win
-            # is on accelerators, so CPU only opts in explicitly.
-            return False
-        os.makedirs(target, exist_ok=True)
-        jax.config.update("jax_compilation_cache_dir", target)
-        # Cache even quick compiles: the serving path compiles many
-        # small-bucket programs whose combined cost is what hurts.
-        try:
-            jax.config.update(
-                "jax_persistent_cache_min_compile_time_secs", 0.2)
-        except Exception:
-            pass  # older knob name; the dir alone still works
-        _enabled = True
-        return True
-    except Exception:
-        return False
+
+def enable_persistent_cache() -> Optional[str]:
+    """Idempotently turn JAX's on-disk compilation cache on for this
+    process. Returns the directory in use, or None on the CPU backend:
+    cached CPU executables are machine-feature-sensitive (XLA warns a
+    mismatched load "could lead to SIGILL") and the cache's win is on
+    accelerators."""
+    import jax
+
+    if jax.default_backend() == "cpu":
+        return None
+    if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+        os.makedirs(_IN_CHECKOUT_DIR, exist_ok=True)
+        jax.config.update("jax_compilation_cache_dir", _IN_CHECKOUT_DIR)
+    # Cache even quick compiles: the serving path compiles many
+    # small-bucket programs whose combined cost is what hurts.
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.2)
+    return cache_dir()

@@ -173,7 +173,7 @@ class _Actor:
                 # fresh interpreter (pristine process globals — needed
                 # for jax.distributed ranks); True forks.
                 self._proc = self.backend.worker_pool.dedicated(
-                    spawn=spec.isolate_process == "spawn", meta=spec)
+                    spawn=self.backend._isolated_spawn(spec), meta=spec)
                 self._proc.request(("init", spec.func, args,
                                     kwargs, spec.runtime_env))
             else:
@@ -332,6 +332,27 @@ class LocalBackend:
             target=self._dispatch_loop, name="raylet-dispatch", daemon=True
         )
         self._dispatcher.start()
+
+    def _isolated_spawn(self, spec: TaskSpec) -> bool:
+        """Whether `spec`'s worker process is a fresh interpreter
+        ("spawn") and not a fork. A chip belongs to one process, so work
+        that was granted TPUs is refused where it could not open them: a
+        forked child inherits this process's JAX state, and no child
+        can take a chip this process holds."""
+        spawn = spec.isolate_process == "spawn"
+        if spec.resources.get("TPU"):
+            if not spawn:
+                raise ValueError(
+                    f"{spec.describe()} requests TPUs in a forked worker "
+                    "(isolate_process=True), which cannot open the chip; "
+                    "use isolate_process='spawn'")
+            if self.worker.holds_chip:
+                raise RuntimeError(
+                    f"{spec.describe()} requests TPUs in a spawned worker, "
+                    "but this process opened the chips when ray_tpu.init() "
+                    "counted them; tell init() its num_tpus so that the "
+                    "worker process can open them")
+        return spawn
 
     @property
     def worker_pool(self):
@@ -801,7 +822,7 @@ class LocalBackend:
                 # "spawn" = one-shot fresh interpreter.
                 result = self.worker_pool.run(
                     spec.func, args, kwargs, spec.runtime_env,
-                    spawn=spec.isolate_process == "spawn", meta=spec)
+                    spawn=self._isolated_spawn(spec), meta=spec)
             else:
                 with applied_runtime_env(spec.runtime_env):
                     result = spec.func(*args, **kwargs)

@@ -63,11 +63,14 @@ def ensure_built() -> str:
                 for s in srcs):
             return _LIB
         os.makedirs(_BUILD, exist_ok=True)
-        subprocess.run(  # raylint: disable=R2 -- _build_lock exists solely to make the one-time g++ compile once-only; every waiter needs the built artifact before it can proceed, so serializing them on the build IS the point
+        proc = subprocess.run(  # raylint: disable=R2 -- _build_lock exists solely to make the one-time g++ compile once-only; every waiter needs the built artifact before it can proceed, so serializing them on the build IS the point
             ["g++", "-O2", "-fPIC", "-std=c++17", "-shared", "-o", _LIB,
              os.path.join(_SRC, "store.cc"),
              os.path.join(_SRC, "transfer.cc"), "-lpthread", "-lrt"],
-            check=True, cwd=_SRC, capture_output=True)
+            cwd=_SRC, capture_output=True, text=True)
+        if proc.returncode != 0:
+            raise RuntimeError(
+                f"building {_LIB} from {_SRC} failed:\n{proc.stderr}")
         return _LIB
 
 

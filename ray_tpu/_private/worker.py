@@ -80,6 +80,9 @@ class Worker:
         # published to the node's shm segment for zero-copy cross-process
         # reads (plasma-provider role).
         self.shm_plane = None
+        # True when init() counted the TPUs through JAX: this process
+        # has opened the chips, so no child process can.
+        self.holds_chip = False
         self.backend = LocalBackend(self, resources)
         # Named actors / placement groups / KV — the "GCS" of this runtime.
         self.gcs = state_mod.GlobalState(self)
@@ -258,6 +261,10 @@ def init(
     connects as a thin client to a driver running a client server
     (`ray_tpu.enable_client_server` — the reference's ray:// client
     mode): the core API proxies there instead of running locally.
+
+    ``num_tpus`` unset counts the TPU devices JAX sees, which makes this
+    process the owner of the chip; given (0 included), JAX is not
+    imported.
     """
     global _global_worker
     with _init_lock:
@@ -288,18 +295,24 @@ def init(
         apply_system_config(_system_config)
         total: Dict[str, float] = {"CPU": float(num_cpus if num_cpus is not None
                                                 else os.cpu_count() or 1)}
-        try:
+        holds_chip = False
+        if num_tpus is None:
+            # Counting the chips opens them, and a chip belongs to one
+            # process: only a process that will run the device programs
+            # itself leaves num_tpus unset. One that coordinates (a
+            # cluster head, a CPU node, a driver whose workers are
+            # spawned ranks) is told its count and never touches JAX.
             import jax
 
-            tpus = sum(1 for d in jax.devices() if d.platform == "tpu")
-        except Exception:  # pragma: no cover - jax missing/broken
-            tpus = 0
-        total["TPU"] = float(num_tpus) if num_tpus is not None else float(tpus)
+            num_tpus = sum(1 for d in jax.devices() if d.platform == "tpu")
+            holds_chip = num_tpus > 0
+        total["TPU"] = float(num_tpus)
         if object_store_memory:
             total["object_store_memory"] = float(object_store_memory)
         total.update(resources or {})
         total = {k: v for k, v in total.items() if v > 0 or k == "CPU"}
         _global_worker = Worker(total, namespace=namespace)
+        _global_worker.holds_chip = holds_chip
         atexit.register(shutdown)
         return _global_worker
 

@@ -3483,7 +3483,9 @@ class Cluster:
         worker_mod.shutdown()
         self.driver_worker = worker_mod.init(
             num_cpus=head_node_args.get("num_cpus", 2),
-            num_tpus=head_node_args.get("num_tpus"),
+            # The head coordinates; chips belong to the nodes given
+            # num_tpus, so the head only counts its own when asked to.
+            num_tpus=head_node_args.get("num_tpus", 0),
             resources=head_node_args.get("resources"))
         self.head = ClusterHead(self.driver_worker)
         backend = ClusterBackendMixin(self.driver_worker, self.head)
@@ -3494,9 +3496,13 @@ class Cluster:
         # it; node subprocesses attach by name. Large objects then cross
         # process boundaries zero-copy instead of via pickle RPC.
         self.shm_plane = None
-        try:
-            from ray_tpu._private import shm_plane as shm_mod
+        from ray_tpu._private import shm_plane as shm_mod
+        from ray_tpu._private import shm_store
 
+        # A clean checkout has no built library yet; a build that fails
+        # is an error here, not a cluster that quietly pickles instead.
+        shm_store.ensure_built()
+        try:
             kwargs = {"capacity": shm_capacity} if shm_capacity else {}
             self.shm_plane = shm_mod.SharedPlane(
                 f"/ray_tpu_{os.getpid()}", create=True, **kwargs)
@@ -3506,7 +3512,7 @@ class Cluster:
             # RPC server — loopback in single-host simulation, the real
             # head host otherwise.
             self.head.transfer_addr = (self.head.server.address[0], port)
-        except Exception:  # shm unavailable: pickle RPC still works
+        except OSError:  # no usable /dev/shm: pickle RPC still works
             self.shm_plane = None
         self._procs: Dict[str, subprocess.Popen] = {}
         self._logs: Dict[str, str] = {}
@@ -3551,7 +3557,10 @@ class Cluster:
         if self.shm_plane is not None and not simulate_remote_host:
             cmd += ["--shm-name", self.shm_plane.name]
         env = dict(os.environ)
-        env.setdefault("JAX_PLATFORMS", "cpu")
+        if not num_tpus:
+            # A CPU node must not open the host's chip if one of its
+            # tasks imports jax; a node given TPUs owns them.
+            env.setdefault("JAX_PLATFORMS", "cpu")
         # Node subprocesses must resolve ray_tpu the same way the driver
         # does (a driver using sys.path.insert — e.g. a checkout not on
         # PYTHONPATH — would otherwise spawn nodes that can't import us).

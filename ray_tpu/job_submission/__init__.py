@@ -113,7 +113,9 @@ class JobSubmissionClient:
 
     def __init__(self, address: Optional[str] = None):
         self._jobs: Dict[str, Any] = {}
-        ray_tpu.init(ignore_reinit_error=True)
+        # The jobs' own driver subprocesses open the chip, so a runtime
+        # started here for supervising them must not.
+        ray_tpu.init(num_tpus=0, ignore_reinit_error=True)
 
     def submit_job(self, *, entrypoint: str,
                    runtime_env: Optional[dict] = None,

@@ -26,7 +26,8 @@ import jax.numpy as jnp
 from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 
-from ray_tpu.ops.attention import flash_attention, attention_reference
+from ray_tpu.ops.attention import (attention_reference, flash_attention,
+                                   on_tpu)
 from ray_tpu.ops.cross_entropy import (fused_linear_cross_entropy,
                                        softmax_cross_entropy)
 from ray_tpu.ops.norms import rms_norm_reference
@@ -35,6 +36,7 @@ from ray_tpu.ops.rope import (apply_rope, rope_frequencies,
 from ray_tpu.parallel.ring_attention import ring_attention
 from ray_tpu.parallel.sharding import (
     DEFAULT_RULES,
+    logical_to_mesh_axes,
     tree_shardings,
     with_logical_constraint,
 )
@@ -197,13 +199,21 @@ def _attention(cfg: LlamaConfig, q, k, v, mesh, rules):
         if seq_parallel:
             impl = "ring"
         else:
-            try:
-                on_tpu = jax.devices()[0].platform == "tpu"
-            except Exception:  # pragma: no cover
-                on_tpu = False
-            impl = "flash" if on_tpu else "reference"
+            impl = "flash" if on_tpu() else "reference"
     if impl == "flash":
-        return flash_attention(q, k, v, causal=True)
+        attn = functools.partial(flash_attention, causal=True)
+        if mesh is None:
+            return attn(q, k, v)
+        # GSPMD cannot partition the kernel's custom call: left bare in
+        # a sharded step it would gather the batch and run the whole
+        # attention on every chip. Each device runs it on its own batch
+        # rows and heads instead (kv_heads % tensor == 0 keeps every
+        # query head beside its GQA kv head).
+        q_spec = logical_to_mesh_axes(("batch", None, "heads"), rules)
+        kv_spec = logical_to_mesh_axes(("batch", None, "kv_heads"), rules)
+        return jax.shard_map(
+            attn, mesh=mesh, in_specs=(q_spec, kv_spec, kv_spec),
+            out_specs=q_spec, check_vma=False)(q, k, v)
     if impl in ("ring", "ulysses"):
         # Ring/Ulysses currently take equal head counts; expand GQA KV
         # heads (cheap relative to long-context attention itself).

@@ -132,8 +132,9 @@ def shape_check_llama(cfg, mesh_shape: Dict[str, int],
 
     params_abs = jax.eval_shape(init_fn, jax.random.PRNGKey(0))
     tx = training.make_optimizer(3e-4, moment_dtype=moment_dtype)
-    state_abs = jax.eval_shape(
-        lambda p: training.init_train_state(p, tx), params_abs)
+    state_abs = training.TrainState(
+        step=jax.ShapeDtypeStruct((), jnp.int32), params=params_abs,
+        opt_state=jax.eval_shape(tx.init, params_abs))
     shardings = training.state_shardings(
         llama.param_logical_axes(cfg), mesh, tx, params_abs)
 

@@ -65,12 +65,22 @@ def make_optimizer(
 
 
 def init_train_state(params, tx: optax.GradientTransformation) -> TrainState:
-    """Build a TrainState from already-sharded params; optimizer moments
-    are created inside jit and inherit the parameter shardings
-    (computation-follows-data)."""
-    opt_state = jax.jit(tx.init)(params)
-    return TrainState(step=jnp.zeros((), jnp.int32), params=params,
-                      opt_state=opt_state)
+    """Build a TrainState from already-sharded params; every optimizer
+    moment is created in its parameter's sharding, and the counters on
+    the same devices. That has to be said: `tx.init` reads only shapes,
+    so jit prunes the params from the program, nothing is left to
+    follow, and the whole optimizer state would be built on the first
+    device — and the train step compiled a second time once its own
+    output had replaced it."""
+    shardings = optax.tree_map_params(
+        tx, lambda _, p: p.sharding, jax.eval_shape(tx.init, params),
+        params, transform_non_params=lambda _: None)
+
+    def init(params):
+        return jnp.zeros((), jnp.int32), tx.init(params)
+
+    step, opt_state = jax.jit(init, out_shardings=(None, shardings))(params)
+    return TrainState(step=step, params=params, opt_state=opt_state)
 
 
 def make_train_step(
@@ -87,7 +97,8 @@ def make_train_step(
     `loss_fn(params, batch) -> (scalar_loss, metrics_dict)`.
     `batch_logical`: pytree of logical-axis tuples matching `batch` (e.g.
     `{"tokens": ("batch", "seq"), ...}`); defaults to sharding every leaf's
-    leading dim over ("data","fsdp").
+    leading dim over ("data","fsdp"). The step also has
+    `.lower(state, batch)`, as a `jax.jit` function does.
     """
 
     def step_fn(state: TrainState, batch):
@@ -110,15 +121,20 @@ def make_train_step(
 
     jitted = jax.jit(step_fn, donate_argnums=(0,) if donate else ())
 
-    @functools.wraps(step_fn)
-    def wrapper(state, batch):
+    def place(batch):
         shardings = batch_shardings(batch)
-        batch = jax.tree.map(
+        return jax.tree.map(
             lambda x, s: x if getattr(x, "sharding", None) == s
             else jax.device_put(x, s),
             batch, shardings)
-        return jitted(state, batch)
 
+    @functools.wraps(step_fn)
+    def wrapper(state, batch):
+        return jitted(state, place(batch))
+
+    # Like the mesh-less return value, the step can be lowered without
+    # running it (to read the compiled program or its memory analysis).
+    wrapper.lower = lambda state, batch: jitted.lower(state, place(batch))
     return wrapper
 
 

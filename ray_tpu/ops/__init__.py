@@ -6,13 +6,13 @@ get hand-written Pallas TPU kernels with pure-JAX reference fallbacks (used
 on CPU and in interpret-mode tests):
 
 - ``attention``     — flash attention (tiled online-softmax, MXU-shaped)
-- ``norms``         — fused RMSNorm / LayerNorm
+- ``norms``         — RMSNorm / LayerNorm (plain JAX)
 - ``rope``          — rotary position embeddings
 - ``cross_entropy`` — blockwise softmax cross-entropy (no full-vocab
                       probability materialization)
 """
 
 from ray_tpu.ops.attention import flash_attention  # noqa: F401
-from ray_tpu.ops.norms import rms_norm, layer_norm  # noqa: F401
+from ray_tpu.ops.norms import layer_norm, rms_norm_reference  # noqa: F401
 from ray_tpu.ops.rope import apply_rope, rope_frequencies  # noqa: F401
 from ray_tpu.ops.cross_entropy import softmax_cross_entropy  # noqa: F401

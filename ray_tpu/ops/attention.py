@@ -24,28 +24,21 @@ import jax
 import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
-
-try:  # TPU backend only; absent on pure-CPU installs
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
+from jax.experimental.pallas import tpu as pltpu
 
 _NEG_INF = -1e30
 
-
-def _vmem(shape, dtype):
-    """VMEM scratch on TPU; generic MemoryRef under pure-CPU interpret
-    installs where the TPU pallas plugin is absent (pltpu is None)."""
-    if pltpu is not None:
-        return pltpu.VMEM(shape, dtype)
-    return pl.MemoryRef(shape, dtype)  # pragma: no cover - no-TPU installs
+# Grid order of all three kernels: batch, head and the outer block axis
+# are independent; the inner sweep accumulates.
+_COMPILER_PARAMS = pltpu.CompilerParams(
+    dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"))
 
 
-def _on_tpu() -> bool:
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:  # pragma: no cover
-        return False
+def on_tpu() -> bool:
+    """Whether this process's default JAX backend is a TPU — the one
+    place the model and the kernels take that decision from. A backend
+    that cannot be opened raises here; it never reads as "not a TPU"."""
+    return jax.default_backend() == "tpu"
 
 
 # ---------------------------------------------------------------------------
@@ -178,16 +171,10 @@ def _flash_fwd(q, k, v, causal: bool, sm_scale: float,
         _flash_fwd_kernel, sm_scale=sm_scale, causal=causal,
         block_q=block_q, block_k=block_k, sk=sk,
     )
-    kwargs = {}
-    if pltpu is not None and not interpret:
-        kwargs["compiler_params"] = pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary"),
-        )
     scratch_shapes = [
-        _vmem((block_q, 128), jnp.float32),  # m
-        _vmem((block_q, 128), jnp.float32),  # l
-        _vmem((block_q, d), jnp.float32),    # acc
+        pltpu.VMEM((block_q, 128), jnp.float32),  # m
+        pltpu.VMEM((block_q, 128), jnp.float32),  # l
+        pltpu.VMEM((block_q, d), jnp.float32),    # acc
     ]
 
     o, lse = pl.pallas_call(
@@ -210,8 +197,9 @@ def _flash_fwd(q, k, v, causal: bool, sm_scale: float,
             jax.ShapeDtypeStruct((b, h, sq, 128), jnp.float32),
         ],
         scratch_shapes=scratch_shapes,
+        compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
-        **kwargs,
+        name="flash_fwd",
     )(q, k, v)
     return o, lse[..., 0]
 
@@ -425,13 +413,6 @@ def _flash_bwd(q, k, v, o, lse, do, causal: bool, sm_scale: float,
     lse4 = jnp.broadcast_to(lse[..., None], (b, h, sq, 128))
     delta4 = jnp.broadcast_to(delta[..., None], (b, h, sq, 128))
 
-    kwargs = {}
-    if pltpu is not None and not interpret:
-        kwargs["compiler_params"] = pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "parallel",
-                                 "arbitrary"),
-        )
-
     def kv_index(ib, ih, iq, ik):
         if causal:
             ik = jnp.minimum(ik, ((iq + 1) * block_q - 1) // block_k)
@@ -459,9 +440,10 @@ def _flash_bwd(q, k, v, o, lse, do, causal: bool, sm_scale: float,
         ],
         out_specs=pl.BlockSpec((1, 1, block_q, d), q_index),
         out_shape=jax.ShapeDtypeStruct((b, h, sq, d), q.dtype),
-        scratch_shapes=[_vmem((block_q, d), jnp.float32)],
+        scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
+        compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
-        **kwargs,
+        name="flash_bwd_dq",
     )(q, k, v, do, lse4, delta4)
 
     # --- dk/dv: grid over k-blocks, inner sweep over q-blocks --------------
@@ -507,11 +489,12 @@ def _flash_bwd(q, k, v, o, lse, do, causal: bool, sm_scale: float,
             jax.ShapeDtypeStruct((b, h, sk, d), jnp.float32),
         ],
         scratch_shapes=[
-            _vmem((block_k, d), jnp.float32),
-            _vmem((block_k, d), jnp.float32),
+            pltpu.VMEM((block_k, d), jnp.float32),
+            pltpu.VMEM((block_k, d), jnp.float32),
         ],
+        compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
-        **kwargs,
+        name="flash_bwd_dkv",
     )(q, k, v, do, lse4, delta4)
 
     if h_kv != h:
@@ -581,25 +564,25 @@ _flash.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
 def flash_attention(q, k, v, *, causal: bool = True,
                     sm_scale: Optional[float] = None,
                     block_q: int = 1024, block_k: int = 1024,
-                    interpret: Optional[bool] = None,
-                    use_pallas: Optional[bool] = None):
+                    interpret: bool = False):
     """Flash attention over [batch, seq, heads, head_dim] tensors.
 
-    KV tensors may have fewer heads (GQA). On non-TPU backends falls back
-    to the fused-by-XLA reference unless `interpret=True` forces the kernel
-    through the Pallas interpreter (used by tests).
+    KV tensors may have fewer heads (GQA). On a TPU backend this is
+    always the compiled kernel: `interpret` never reaches a TPU call,
+    and a kernel Mosaic refuses is an error, not a switch to the
+    reference. On other backends it is the fused-by-XLA reference unless
+    `interpret=True` runs the kernel through the Pallas interpreter
+    (used by tests).
     """
     if sm_scale is None:
         sm_scale = q.shape[-1] ** -0.5
     qt = q.transpose(0, 2, 1, 3)
     kt = k.transpose(0, 2, 1, 3)
     vt = v.transpose(0, 2, 1, 3)
-    on_tpu = _on_tpu()
-    if use_pallas is None:
-        use_pallas = on_tpu or bool(interpret)
-    if use_pallas:
-        out = _flash(qt, kt, vt, causal, sm_scale, block_q, block_k,
-                     bool(interpret) and not on_tpu)
+    if on_tpu():
+        out = _flash(qt, kt, vt, causal, sm_scale, block_q, block_k, False)
+    elif interpret:
+        out = _flash(qt, kt, vt, causal, sm_scale, block_q, block_k, True)
     else:
         out = attention_reference(qt, kt, vt, causal, sm_scale)
     return out.transpose(0, 2, 1, 3)

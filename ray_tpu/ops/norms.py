@@ -1,24 +1,11 @@
-"""Fused normalization kernels (RMSNorm, LayerNorm).
-
-XLA already fuses norm arithmetic well; the Pallas RMSNorm exists to fuse
-the weight multiply and optional residual-add in one VMEM pass for the
-decode hot path. The pure-JAX versions are the default on CPU and are what
-autodiff differentiates through (the kernels are forward-only wrappers).
+"""Normalization layers (RMSNorm, LayerNorm) in plain JAX, float32
+statistics: XLA fuses the norm arithmetic into its neighbours.
 """
 
 from __future__ import annotations
 
-import functools
-from typing import Optional
-
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
-
-try:
-    from jax.experimental.pallas import tpu as pltpu
-except ImportError:  # pragma: no cover
-    pltpu = None
 
 
 def rms_norm_reference(x, weight, eps: float = 1e-6):
@@ -39,45 +26,3 @@ def layer_norm(x, weight, bias=None, eps: float = 1e-6):
     if bias is not None:
         out = out + bias.astype(jnp.float32)
     return out.astype(dtype)
-
-
-def _rms_kernel(x_ref, w_ref, o_ref, *, eps: float):
-    x = x_ref[:].astype(jnp.float32)
-    var = jnp.mean(x * x, axis=-1, keepdims=True)
-    o_ref[:] = (x * jax.lax.rsqrt(var + eps)
-                * w_ref[:].astype(jnp.float32)).astype(o_ref.dtype)
-
-
-def rms_norm_pallas(x, weight, eps: float = 1e-6, block_rows: int = 512,
-                    interpret: bool = False):
-    """x: [..., D]; normalizes over the last axis."""
-    orig_shape = x.shape
-    d = orig_shape[-1]
-    x2 = x.reshape(-1, d)
-    rows = x2.shape[0]
-    block_rows = min(block_rows, rows)
-    grid = (pl.cdiv(rows, block_rows),)
-    out = pl.pallas_call(
-        functools.partial(_rms_kernel, eps=eps),
-        grid=grid,
-        in_specs=[
-            pl.BlockSpec((block_rows, d), lambda i: (i, 0)),
-            pl.BlockSpec((d,), lambda i: (0,)),
-        ],
-        out_specs=pl.BlockSpec((block_rows, d), lambda i: (i, 0)),
-        out_shape=jax.ShapeDtypeStruct((rows, d), x.dtype),
-        interpret=interpret,
-    )(x2, weight)
-    return out.reshape(orig_shape)
-
-
-def rms_norm(x, weight, eps: float = 1e-6, *,
-             use_pallas: Optional[bool] = None, interpret: bool = False):
-    if use_pallas is None:
-        try:
-            use_pallas = jax.devices()[0].platform == "tpu"
-        except Exception:  # pragma: no cover
-            use_pallas = False
-    if use_pallas or interpret:
-        return rms_norm_pallas(x, weight, eps, interpret=interpret)
-    return rms_norm_reference(x, weight, eps)

@@ -24,20 +24,6 @@ from jax import lax
 AxisName = Union[str, Sequence[str]]
 
 
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma: bool = True):
-    """Version-portable ``shard_map``: newer jax exposes ``jax.shard_map``
-    (with ``check_vma``); 0.4.x only has the experimental module (where
-    the same knob is ``check_rep``). Every per-shard kernel in this
-    package routes through here so a jax upgrade/downgrade is one-file."""
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=check_vma)
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-    return _shard_map(f, mesh=mesh, in_specs=in_specs,
-                      out_specs=out_specs, check_rep=check_vma)
-
-
 def allreduce(x, axis_name: AxisName, op: str = "sum"):
     """Reference parity: `collective.allreduce` (collective.py:258)."""
     if op == "sum":
@@ -90,12 +76,8 @@ def axis_index(axis_name: AxisName):
 
 
 def axis_size(axis_name: AxisName):
-    """Static size of a named mesh axis. ``lax.axis_size`` only exists
-    on newer jax; on 0.4.x the canonical idiom is ``psum(1, axis)``,
-    which constant-folds to a Python int for non-traced operands."""
-    if hasattr(lax, "axis_size"):
-        return lax.axis_size(axis_name)
-    return lax.psum(1, axis_name)
+    """Static size of a named mesh axis."""
+    return lax.axis_size(axis_name)
 
 
 def barrier(axis_name: AxisName):

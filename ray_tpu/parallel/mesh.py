@@ -30,8 +30,6 @@ import math
 from dataclasses import dataclass, fields
 from typing import Optional, Sequence
 
-import numpy as np
-
 AXIS_NAMES = ("data", "fsdp", "expert", "pipe", "seq", "tensor")
 
 
@@ -105,23 +103,19 @@ def create_mesh(config: Optional[MeshConfig] = None,
                 axis_names: Sequence[str] = AXIS_NAMES):
     """Build a `jax.sharding.Mesh` with the canonical axis names.
 
-    On real TPU hardware the device order comes from
+    The device order comes from
     `jax.experimental.mesh_utils.create_device_mesh`, which matches mesh
-    dims to the physical ICI torus; on CPU/virtual meshes we fall back to a
-    plain reshape.
+    dims to the physical ICI torus on TPU hardware and reshapes in order
+    on CPU/virtual devices. A shape it cannot lay out is an error.
     """
     import jax
+    from jax.experimental import mesh_utils
     from jax.sharding import Mesh
 
     devices = list(devices if devices is not None else jax.devices())
     config = config or MeshConfig()
     shape = config.shape(len(devices))
-    try:
-        from jax.experimental import mesh_utils
-
-        dev_array = mesh_utils.create_device_mesh(shape, devices=devices)
-    except Exception:
-        dev_array = np.asarray(devices).reshape(shape)
+    dev_array = mesh_utils.create_device_mesh(shape, devices=devices)
     return Mesh(dev_array, axis_names=tuple(axis_names))
 
 

@@ -33,7 +33,7 @@ from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
 
-from ray_tpu.parallel.collectives import axis_size as _axis_size, shard_map
+from ray_tpu.parallel.collectives import axis_size as _axis_size
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
@@ -99,7 +99,7 @@ def pipeline_apply(stage_fn: Callable, stage_params, x_microbatches, *,
     if mesh is None:
         return body(stage_params, x_microbatches)
     param_spec = jax.tree.map(lambda _: P(axis_name), stage_params)
-    fn = shard_map(
+    fn = jax.shard_map(
         lambda p, x: body(jax.tree.map(lambda a: a[0], p), x),
         mesh=mesh,
         in_specs=(param_spec, P()),
@@ -275,7 +275,7 @@ def pipeline_train_1f1b(stage_fn: Callable, head_loss_fn: Callable,
         # `pipe` rebuilds the stage-stacked layout of stage_params.
         return loss, jax.tree.map(lambda a: a[None], dstage), dhead, dx
 
-    fn = shard_map(
+    fn = jax.shard_map(
         _shard_body,
         mesh=mesh,
         in_specs=(param_spec, rep, P(), P()),

@@ -27,7 +27,7 @@ from typing import Optional
 
 import jax
 
-from ray_tpu.parallel.collectives import axis_size as _axis_size, shard_map
+from ray_tpu.parallel.collectives import axis_size as _axis_size
 import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
@@ -119,7 +119,7 @@ def ring_attention(q, k, v, *, mesh: Optional[Mesh] = None,
     if mesh is None:
         return _ring_attention_sharded(q, k, v, axis_name, causal)
     spec = P(("data", "fsdp"), axis_name, "tensor", None)
-    fn = shard_map(
+    fn = jax.shard_map(
         functools.partial(_ring_attention_sharded, axis_name=axis_name,
                           causal=causal),
         mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,

@@ -97,5 +97,8 @@ def with_logical_constraint(x, *logical_axes: Optional[str],
         return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, spec))
     try:
         return jax.lax.with_sharding_constraint(x, spec)
-    except Exception:
-        return x  # no mesh in scope → single-device path, constraint is moot
+    except (RuntimeError, ValueError):
+        # No mesh in scope (single-device path), or one without the
+        # rules' axes (the body of a pipeline stage's shard_map): the
+        # constraint is moot.
+        return x

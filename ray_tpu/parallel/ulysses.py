@@ -15,8 +15,7 @@ from __future__ import annotations
 import functools
 from typing import Callable, Optional
 
-
-from ray_tpu.parallel.collectives import shard_map
+import jax
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
@@ -52,7 +51,7 @@ def ulysses_attention(q, k, v, *, mesh: Optional[Mesh] = None,
     if mesh is None:
         return _ulysses_sharded(q, k, v, axis_name, causal, attn_fn)
     spec = P(("data", "fsdp"), axis_name, "tensor", None)
-    fn = shard_map(
+    fn = jax.shard_map(
         functools.partial(_ulysses_sharded, axis_name=axis_name,
                           causal=causal, attn_fn=attn_fn),
         mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,

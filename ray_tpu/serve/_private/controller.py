@@ -60,9 +60,10 @@ class _DeploymentState:
         self.message = ""
         # Replica supervision state: per-replica consecutive health-
         # check strikes, the in-flight (ping ref, sent_at) checked on
-        # later passes, and the set of replicas whose LAST ping
-        # answered ok (a degraded reason only clears once the
-        # replacement fleet confirms).
+        # later passes, and the set of replicas that have answered a
+        # ping ok (a degraded reason only clears once the replacement
+        # fleet confirms; until its first answer a replica is still
+        # constructing and is not struck for silence).
         self.health_strikes: Dict[Any, int] = {}
         self.health_pings: Dict[Any, Any] = {}
         self.health_ok: set = set()
@@ -201,6 +202,7 @@ class ServeController:
                 st.replicas.append(h)
                 st.replica_versions[h] = version
                 st.replica_names[h] = rname
+                st.health_ok.add(h)  # it just answered
                 recovered_replicas += 1
             st.status = "UPDATING"
             self._deployments[name] = st
@@ -541,6 +543,15 @@ class ServeController:
                                     ray_config.serve_replica_health_failures:
                                 cause = (f"{strikes} consecutive failed "
                                          f"health checks ({e})")
+                    elif r not in st.health_ok:
+                        # Never answered a ping yet: its constructor
+                        # is still running (a model server compiles
+                        # its programs there for minutes) and the
+                        # ping is queued behind it. That is starting,
+                        # not hung; a constructor that fails kills
+                        # the actor, which the branches above see.
+                        st.health_pings[r] = prev
+                        resend = False
                     elif now - sent_at > \
                             ray_config.serve_replica_health_timeout_s:
                         # Unanswered past the timeout: hung-replica
@@ -725,6 +736,11 @@ class ServeController:
                 info.get("init_kwargs"), info.get("user_config"),
                 st.version, actor_name=rname)
             st.replica_names[r] = rname
+            # First in the mailbox behind the constructor: answered the
+            # moment construction ends, before any request can occupy
+            # the replica. Until then the replica is starting.
+            st.health_pings[r] = (r.check_health.remote(),
+                                  time.monotonic())
             return r
         except Exception:
             st.message = traceback.format_exc()

@@ -20,6 +20,7 @@ from __future__ import annotations
 from dataclasses import dataclass
 from typing import Callable, Optional
 
+from ray_tpu._private.compile_cache import enable_persistent_cache
 from ray_tpu.air import session
 from ray_tpu.air.config import ScalingConfig
 from ray_tpu.parallel.mesh import MeshConfig, create_mesh
@@ -52,6 +53,9 @@ class JaxConfig(BackendConfig):
 class JaxBackend(Backend):
     def on_training_start(self, worker_group, backend_config: JaxConfig):
         if not getattr(backend_config, "distributed", False):
+            # Before the loop's first compile, in the process that runs
+            # it.
+            worker_group.execute(enable_persistent_cache)
             return
         import ray_tpu
 
@@ -74,19 +78,14 @@ class JaxBackend(Backend):
 
 
 def _jax_dist_init(coord, n, rank, platform=None, num_local_devices=None):
-    """Per-rank jax.distributed bring-up. Runs inside an isolated worker
-    process; if that process was forked from a parent that already
-    initialized JAX, the inherited backends are discarded first so the
-    distributed client is wired into fresh ones."""
+    """Per-rank jax.distributed bring-up. Runs inside a spawned worker
+    process, which opens this host's chips itself: the driver that
+    launches the ranks must not have (`ray_tpu.init(num_tpus=...)`)."""
     import os
     import re
 
     import jax
 
-    import jax._src.xla_bridge as xla_bridge
-
-    if xla_bridge._backends:  # pragma: no cover - forked-worker fallback
-        xla_bridge._clear_backends()
     if platform is not None:
         jax.config.update("jax_platforms", platform)
     if num_local_devices is not None and (platform or "") == "cpu":
@@ -102,6 +101,7 @@ def _jax_dist_init(coord, n, rank, platform=None, num_local_devices=None):
         jax.config.update("jax_cpu_collectives_implementation", "gloo")
     jax.distributed.initialize(coordinator_address=coord, num_processes=n,
                                process_id=rank)
+    enable_persistent_cache()
     return True
 
 

@@ -161,7 +161,9 @@ def test_accelerators():
 
     spec = accelerators.chip_spec(accelerators.TPU_V5E)
     assert spec.hbm_bytes == 16 * 2**30
-    assert accelerators.detect_tpu_type() in accelerators.TPU_SPECS
+    # The CPU test devices are no TPU generation: an error, not a default.
+    with pytest.raises(ValueError, match="device_kind 'cpu'"):
+        accelerators.detect_tpu_type()
 
 
 def test_check_serialize():

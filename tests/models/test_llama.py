@@ -1,20 +1,11 @@
 """Llama model tests: correctness, sharded equivalence, train step."""
 
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-
-# Known pre-existing divergence (see CHANGES.md, PR 3): under this
-# image's jax 0.4.37 the version-portable shard_map compat path makes
-# the sharded forward numerically diverge from single-device beyond
-# test tolerance on CPU. Real sharding bugs show up as shape/axis
-# errors or wild divergence, which xfail(strict=False) still surfaces
-# as XPASS→investigate when the underlying jax is fixed.
-_SHARDED_NUMERICS_XFAIL = pytest.mark.xfail(
-    reason="pre-existing sharded-vs-single-device numeric divergence "
-           "under jax 0.4.37 shard_map compat (tracked in CHANGES.md)",
-    strict=False)
 
 from ray_tpu.models import (
     LlamaConfig,
@@ -67,11 +58,14 @@ def test_param_logical_axes_structure_matches():
     )
 
 
-@_SHARDED_NUMERICS_XFAIL
-def test_sharded_forward_matches_single_device():
-    cfg = LlamaConfig.debug()
+@pytest.mark.parametrize("attention", ["auto", "flash"])
+def test_sharded_forward_matches_single_device(attention):
+    # "flash" takes the shard_map around the kernel call (batch over
+    # data x fsdp, GQA heads over tensor); off the TPU the function under
+    # it is the reference, so this checks the partitioning alone.
+    cfg = dataclasses.replace(LlamaConfig.debug(), attention=attention)
     params = init_params(cfg, jax.random.PRNGKey(0))
-    batch = _batch(cfg)
+    batch = _batch(cfg, b=4)  # shard_map wants batch % (data*fsdp) == 0
     expected = forward(params, batch["tokens"], cfg)
 
     mesh = create_mesh(MeshConfig(data=2, fsdp=2, tensor=2))
@@ -105,10 +99,17 @@ def test_context_parallel_forward_matches():
 
 def test_train_step_descends():
     cfg = LlamaConfig.debug()
-    mesh = create_mesh(MeshConfig(data=4, tensor=2))
+    mesh = create_mesh(MeshConfig(data=2, fsdp=2, tensor=2))
     params = init_params_sharded(cfg, mesh, jax.random.PRNGKey(0))
     tx = make_optimizer(1e-2, warmup_steps=0)
     state = init_train_state(params, tx)
+    # Moments start where their parameters are — not on the first
+    # device, to be cut up at the first step — and the whole state
+    # starts in the layout the step returns it in, or the step would
+    # compile a second time for its own output.
+    assert jax.tree.map(lambda m: m.sharding, state.opt_state[1].mu) == \
+        jax.tree.map(lambda p: p.sharding, params)
+    layout = jax.tree.map(lambda x: x.sharding, state)
 
     step = make_train_step(
         lambda p, b: loss_fn(p, b, cfg, mesh=mesh), tx, mesh=mesh,
@@ -122,9 +123,9 @@ def test_train_step_descends():
         losses.append(float(metrics["loss"]))
     assert losses[-1] < losses[0], losses
     assert int(state.step) == 5
+    assert jax.tree.map(lambda x: x.sharding, state) == layout
 
 
-@_SHARDED_NUMERICS_XFAIL
 def test_positions_shift_changes_logits():
     cfg = LlamaConfig.debug()
     params = init_params(cfg, jax.random.PRNGKey(0))

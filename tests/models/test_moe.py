@@ -5,15 +5,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-# Known pre-existing divergence (see CHANGES.md, PR 3): under this
-# image's jax 0.4.37 the version-portable shard_map compat path makes
-# the expert-parallel forward numerically diverge from single-device
-# beyond test tolerance on CPU.
-_SHARDED_NUMERICS_XFAIL = pytest.mark.xfail(
-    reason="pre-existing sharded-vs-single-device numeric divergence "
-           "under jax 0.4.37 shard_map compat (tracked in CHANGES.md)",
-    strict=False)
-
 from ray_tpu.models import (
     init_train_state,
     make_optimizer,
@@ -46,7 +37,6 @@ def test_moe_forward_finite_and_aux():
     assert 0.9 < float(aux) < 3.0
 
 
-@_SHARDED_NUMERICS_XFAIL
 def test_moe_expert_parallel_matches_single_device():
     cfg = MoEConfig.debug_moe()
     params = init_moe_params(cfg, jax.random.PRNGKey(0))

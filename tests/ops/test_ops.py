@@ -9,13 +9,11 @@ from ray_tpu.ops import (
     apply_rope,
     flash_attention,
     layer_norm,
-    rms_norm,
     rope_frequencies,
     softmax_cross_entropy,
 )
 from ray_tpu.ops.attention import attention_reference
 from ray_tpu.ops.cross_entropy import softmax_cross_entropy_reference
-from ray_tpu.ops.norms import rms_norm_pallas, rms_norm_reference
 
 
 @pytest.mark.parametrize("causal", [True, False])
@@ -104,15 +102,6 @@ def test_flash_attention_grad_pallas_bwd(causal, shape):
     for a, b_ in zip(vjp1(do), vjp2(do)):
         np.testing.assert_allclose(np.asarray(a), np.asarray(b_),
                                    rtol=1e-4, atol=1e-4)
-
-
-def test_rms_norm_pallas_matches_reference():
-    x = jax.random.normal(jax.random.PRNGKey(5), (4, 96, 256), jnp.float32)
-    w = jax.random.normal(jax.random.PRNGKey(6), (256,)) * 0.1 + 1.0
-    got = rms_norm_pallas(x, w, interpret=True)
-    expected = rms_norm_reference(x, w)
-    np.testing.assert_allclose(np.asarray(got), np.asarray(expected),
-                               rtol=1e-5, atol=1e-6)
 
 
 def test_layer_norm():

@@ -124,9 +124,7 @@ def test_collectives_roundtrip():
         return s, g, b
 
     x = jnp.arange(8.0).reshape(8, 1)
-    from ray_tpu.parallel.collectives import shard_map
-
-    fn = shard_map(body, mesh=mesh, in_specs=P("data"),
+    fn = jax.shard_map(body, mesh=mesh, in_specs=P("data"),
                    out_specs=(P("data"), P("data"), P("data")),
                    check_vma=False)
     s, g, b = fn(x)

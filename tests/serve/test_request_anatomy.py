@@ -56,12 +56,6 @@ def llm_up(monkeypatch):
     monkeypatch.setattr(ray_config, "llm_prefix_cache", True)
     monkeypatch.setattr(ray_config, "llm_kv_block_tokens", 4)
     monkeypatch.setattr(ray_config, "llm_prefix_shm_tier", False)
-    # The prefill sleeps stretch warmup past the default supervision
-    # window on a loaded box; this test asserts attribution, not
-    # failure detection.
-    monkeypatch.setattr(ray_config, "serve_replica_health_timeout_s",
-                        30.0)
-    monkeypatch.setattr(ray_config, "serve_replica_health_failures", 20)
     ray_tpu.shutdown()
     ray_tpu.init(num_cpus=4)
     yield

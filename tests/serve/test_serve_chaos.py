@@ -204,6 +204,36 @@ def test_hung_replica_struck_out_and_replaced(serve_up, monkeypatch):
         wedger.join(timeout=30)
 
 
+def test_constructing_replica_is_not_struck_out(serve_up, monkeypatch):
+    """A replica whose constructor outlasts the whole strike window (a
+    model server compiling its programs) is starting, not hung: its
+    first ping waits behind the constructor and no strike is counted."""
+    monkeypatch.setattr(ray_config, "serve_replica_health_timeout_s",
+                        0.3)
+
+    @serve.deployment(num_replicas=1, name="SlowStart")
+    class SlowStart:
+        def __init__(self):
+            # period 0.2 x 2 failures + timeout 0.3 strikes out in < 1s.
+            time.sleep(2.0)
+
+        def __call__(self, payload):
+            return {"ok": payload}
+
+    import ray_tpu as rt
+    from ray_tpu import serve as serve_mod
+    from ray_tpu._private.worker import global_worker
+
+    handle = serve_mod.run(SlowStart.bind(), route_prefix="/slowstart")
+    orig = {n for n in global_worker().gcs.list_named_actors()
+            if str(n).startswith("SERVE_REPLICA::SlowStart::")}
+    assert rt.get(handle.remote("a"), timeout=30)["ok"] == "a"
+    now = {n for n in global_worker().gcs.list_named_actors()
+           if str(n).startswith("SERVE_REPLICA::SlowStart::")}
+    assert now == orig, f"constructing replica was replaced: {orig} -> {now}"
+    assert not any("SlowStart" in r for r in health.provider_reasons())
+
+
 def test_saturated_replica_is_not_struck_out(serve_up, monkeypatch):
     """The kill-loop guard: a SATURATED replica — health ping FIFO'd
     behind a backlog deeper than its execution slots, but completing
