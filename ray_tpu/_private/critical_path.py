@@ -7,26 +7,62 @@ question — *which stage* made THIS request slow — and that attribution
 is the measured input every adaptive control-loop decision (ROADMAP
 item 4) needs.
 
-This module is the pure core. Hot paths call :func:`record_stage` —
-ONE scalar-tuple append to a bounded deque, nothing else — at every
-seam a request crosses (proxy dispatch, router assign, replica-direct
-acquire, replica execute, LLM admit/kv-lookup/prefill/first-token/
-decode, scheduler queue, object-plane pull/spill/restore). Everything
-downstream of that append (trace accumulation, histogram folds,
-exemplar upkeep, the flight ring, the ship queue) happens in
-:func:`flush`, driven by a process-lifetime folder thread at ~100 ms
-cadence and synchronously by every reader. The deferral is the whole
-performance story: on a serial request path every instruction between
-"replica produced the result" and "client read the response" is paid
-at GIL-scheduling granularity, so 20 µs of inline folding measured as
-~70 µs of added latency — while an append costs ~0.15 µs and the fold
-runs when the loop would otherwise be idle. The proxy's request
-envelope calls :func:`finish_request` once per request, which (at
-fold time):
+This module is the pure core, and the program's one recorder. What
+it records is a *span*: a name, a start and an end, the trace id of
+the request it worked for (if any), the enclosing span of the same
+thread as parent, and a few integer attributes. Two forms write the
+same record:
+
+- :func:`span` (a context manager; :func:`begin` / :func:`end` where a
+  ``with`` block does not fit) times the work where it happens. Start
+  is read from ``time.time`` — CLOCK_REALTIME, the clock the JAX
+  profiler's host plane stamps its events with (an xplane holds them
+  relative to the ``profile_start_time`` of its ``Task Environment``
+  plane) — and the duration from :func:`clock` (``time.perf_counter``).
+  While a ``jax.profiler`` trace is being taken the span also enters a
+  ``jax.profiler.TraceAnnotation`` of the same name and attributes, so
+  the program's spans lie in the trace's host plane beside the device's
+  ops, on one clock, with nothing to align. The engine loop
+  (``serve/llm.py`` ``engine.*``), the ingest iterator (``data.*``) and
+  the train step's wrapper (``train.*``) are timed this way.
+- :func:`record_stage` is the thin form for a duration a call site
+  measured itself (with :func:`clock`): start = now - duration. The
+  per-request stages use it (proxy dispatch and first byte, router
+  assign, replica-direct acquire, replica execute, LLM
+  admit/kv-lookup/prefill/first-token/decode, scheduler queue,
+  object-plane pull/spill/restore).
+
+Either way the hot path pays clock reads and ONE tuple append to a
+bounded deque, nothing else. Everything downstream of that append
+(trace accumulation, histogram folds, exemplar upkeep, the flight
+ring, the ship queue) happens in :func:`flush`, driven by a
+process-lifetime folder thread at ~100 ms cadence and synchronously by
+every reader. The deferral is the whole performance story: on a serial
+request path every instruction between "replica produced the result"
+and "client read the response" is paid at GIL-scheduling granularity,
+so 20 µs of inline folding measured as ~70 µs of added latency — while
+an append costs ~0.15 µs and the fold runs when the loop would
+otherwise be idle.
+
+Every record reaches the flight recorder's ring. Only a *request*
+opens an accumulator: the proxy's envelope calls :func:`open_request`
+where it mints (or honours) the trace id and :func:`finish_request`
+once when the response is out; records in between that carry the
+request's trace id collect there, and a record whose trace id no
+request opened goes to the ring (and, on a worker node, the ship
+queue) and nowhere else — so the tasks of the runtime, each a trace
+root of its own, cannot crowd a long request's early stages out.
+:func:`finish_request` (at fold time):
 
 - attributes the request's wall time to its recorded stages (the
   remainder is folded as the ``unattributed`` stage, so the vector
-  always sums to the measured total),
+  always sums to the measured total; the envelope span
+  ``proxy.first_byte`` lies over its children and is left out of the
+  sum),
+- derives ``front.ttft_self``: ``proxy.first_byte`` less the stretch
+  the engine's stages cover (``llm.admit`` to the first token's
+  hand-over) — the proxy's, router's, scheduler's, replica's and
+  stream hop's own share of the time to first token,
 - folds each stage duration into the
   ``request_stage_seconds{route,stage}`` fast-path distribution —
   exported as ``ray_tpu_request_stage_seconds_p50/_p99`` per
@@ -39,22 +75,26 @@ fold time):
 
 Stage records born on worker nodes ride the existing obs shipper
 (``drain_records`` → ``obs_report(stages=...)`` → :func:`ingest`), so
-the head folds cluster-wide attribution — replica/engine stages land
-seconds after the proxy already finished the request, which is why
-late arrivals for a finished trace fold immediately against the
-route the finish recorded.
+the head, which opened the request, folds cluster-wide attribution —
+replica/engine stages land seconds after the proxy already finished
+the request, which is why late arrivals for a finished trace fold
+immediately against the route the finish recorded.
 
 Layering: imports only peer ``_private`` modules (perf_stats,
-flight_recorder); never serve.
+flight_recorder); never serve, and never ``jax``: the annotation is
+bound the first time a span runs in a process that has imported JAX
+itself (a process that never does cannot be taking a trace).
 """
 
 from __future__ import annotations
 
 import bisect
+import itertools
+import sys
 import threading
 import time
 from collections import OrderedDict, deque
-from typing import Dict, List, Optional, Tuple
+from typing import Dict, Iterable, List, Optional, Tuple
 
 from ray_tpu._private import flight_recorder, perf_stats
 from ray_tpu._private.config import ray_config
@@ -80,11 +120,30 @@ STAGE_METRIC = "request_stage_seconds"
 # is the single biggest term in the recorder's fast-route overhead.
 MIN_SPAN_S = 5e-5
 
-# A record is the tuple (t, trace_id, stage, dur_s, route); the dict
-# shape only exists at the edges (the obs-ship wire format, snapshots).
-# A finish marker is the 6-tuple (t, trace_id, status, total_s, route,
-# None) — length is the dispatch tag.
-_T, _TRACE, _STAGE, _DUR, _ROUTE = range(5)
+# A span record is the tuple (t, trace_id, stage, dur_s, route, t0, id,
+# parent, attrs): ``t`` is its end (= t1) and ``t0`` its start, both
+# seconds on time.time's clock; ``parent`` is the id of the span that
+# enclosed it on its thread (0: none); ``attrs`` a dict of integers or
+# None. The dict shape only exists at the edges (the obs-ship wire
+# format, snapshots). A finish marker is the 6-tuple (t, trace_id,
+# status, total_s, route, None), an open marker the 3-tuple (t,
+# trace_id, route) — length is the dispatch tag.
+_T, _TRACE, _STAGE, _DUR, _ROUTE, _T0, _ID, _PARENT, _ATTRS = range(9)
+
+# The one clock of every duration this module is handed or takes.
+clock = time.perf_counter
+
+# The envelope lies over the stages it encloses: it is folded and shown
+# but not summed into the tiling of the request's wall time.
+ENVELOPE_STAGE = "proxy.first_byte"
+FRONT_SELF_STAGE = "front.ttft_self"
+# What the engine covers of the time to first token: from the request's
+# arrival in its queue to the first token's hand-over.
+_ENGINE_TTFT_STAGES = frozenset(
+    ("llm.admit", "llm.kv_lookup", "llm.prefill", "llm.first_token"))
+
+_ids = itertools.count(1)  # next() is GIL-atomic
+_tls = threading.local()   # .stack: ids of the thread's open spans
 
 # Raw hot-path appends awaiting a fold. Sized for several fold periods
 # at full serve throughput; sustained overflow drops oldest (bounded
@@ -202,9 +261,17 @@ def _fold(route: str, stage: str, dur_s: float, trace_id: str) -> None:
         bucket[idx] = (dur_s, trace_id)
 
 
+def _open_parent() -> int:
+    stack = getattr(_tls, "stack", None)
+    return stack[-1] if stack else 0
+
+
 def record_stage(trace_id: Optional[str], stage: str, dur_s: float,
                  route: str = "") -> None:
-    """Attribute ``dur_s`` seconds of ``stage`` work to ``trace_id``.
+    """Attribute ``dur_s`` seconds of ``stage`` work, measured by the
+    caller with :func:`clock` and ending now, to ``trace_id``: the thin
+    form of a span record (start = now - duration; parent = the span
+    open on this thread, if one is).
 
     Hot-path cost: one scalar-tuple append (GIL-atomic, no lock) —
     folding is deferred to :func:`flush`. Records without a trace id
@@ -220,8 +287,131 @@ def record_stage(trace_id: Optional[str], stage: str, dur_s: float,
     most of the per-request records."""
     if not _on() or dur_s < MIN_SPAN_S:
         return
-    _raw.append((time.time(), trace_id or "", stage, float(dur_s),
-                 route))
+    t1 = time.time()
+    dur_s = float(dur_s)
+    _raw.append((t1, trace_id or "", stage, dur_s, route, t1 - dur_s,
+                 next(_ids), _open_parent(), None))
+    if not _folder_started:
+        _ensure_folder()
+
+
+# jax.profiler.TraceAnnotation, bound the first time a span runs in a
+# process that has imported JAX itself (this module never imports it).
+_annotation = None
+
+
+def _bind_annotation():
+    global _annotation
+    jax = sys.modules.get("jax")
+    profiler = getattr(jax, "profiler", None)  # None while jax imports
+    _annotation = getattr(profiler, "TraceAnnotation", None)
+    return _annotation
+
+
+class Span:
+    """One timed stretch of work on one thread; see :func:`span`."""
+
+    __slots__ = ("name", "trace_id", "route", "attrs", "id", "parent",
+                 "t0", "_p0", "_ann")
+
+    def __init__(self, name: str, trace_id: str, route: str,
+                 attrs: Optional[dict]):
+        self.name = name
+        self.trace_id = trace_id
+        self.route = route
+        self.attrs = attrs
+        self._ann = None
+
+    def set(self, **attrs) -> None:
+        """Attributes known only once the work is done."""
+        if self.attrs is None:
+            self.attrs = attrs
+        else:
+            self.attrs.update(attrs)
+        if self._ann is not None:
+            self._ann.set_metadata(**attrs)
+
+    def __enter__(self) -> "Span":
+        stack = getattr(_tls, "stack", None)
+        if stack is None:
+            stack = _tls.stack = []
+        self.parent = stack[-1] if stack else 0
+        self.id = next(_ids)
+        stack.append(self.id)
+        ann = _annotation or _bind_annotation()
+        if ann is not None and ann.is_enabled():  # a trace is being taken
+            self._ann = ann(self.name, **(self.attrs or {}))
+            self._ann.__enter__()
+        self.t0 = time.time()
+        self._p0 = clock()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        dur_s = clock() - self._p0
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        _tls.stack.pop()
+        _raw.append((self.t0 + dur_s, self.trace_id, self.name, dur_s,
+                     self.route, self.t0, self.id, self.parent,
+                     self.attrs))
+        if not _folder_started:
+            _ensure_folder()
+
+
+class _NullSpan:
+    """What :func:`span` hands out while the recorder is off."""
+
+    __slots__ = ()
+
+    def set(self, **attrs) -> None:
+        pass
+
+    def __enter__(self) -> "_NullSpan":
+        return self
+
+    def __exit__(self, *exc) -> None:
+        pass
+
+
+_NULL_SPAN = _NullSpan()
+
+
+def span(name: str, trace_id: Optional[str] = None, route: str = "",
+         **attrs: int):
+    """Context manager that records the stretch it encloses as a span:
+    start, end, the span open on this thread as parent, ``attrs``
+    (integers; more through ``.set(**attrs)`` inside the block), and
+    the same stretch as a ``jax.profiler.TraceAnnotation`` while a
+    profiler trace is being taken. Spans of one thread nest. With no
+    trace being taken it costs three clock reads and one deque
+    append; no floor applies (the engine loop is tiled by spans, and a
+    tile that is left out reads as a gap)."""
+    if not _on():
+        return _NULL_SPAN
+    return Span(name, trace_id or "", route, attrs or None)
+
+
+def begin(name: str, trace_id: Optional[str] = None, route: str = "",
+          **attrs: int):
+    """:func:`span` for a stretch that no ``with`` block fits: returns
+    the entered span for :func:`end`, on the same thread."""
+    return span(name, trace_id, route, **attrs).__enter__()
+
+
+def end(sp, **attrs: int) -> None:
+    if attrs:
+        sp.set(**attrs)
+    sp.__exit__(None, None, None)
+
+
+def open_request(trace_id: Optional[str], route: str = "") -> None:
+    """Open a request's accumulator (the proxy's envelope, where the
+    trace id is minted or honoured): from here to
+    :func:`finish_request` records that carry ``trace_id`` collect
+    there. Same one-append hot path as :func:`record_stage`."""
+    if not _on() or not trace_id:
+        return
+    _raw.append((time.time(), trace_id, route))
     if not _folder_started:
         _ensure_folder()
 
@@ -291,41 +481,71 @@ def flush(max_n: Optional[int] = None) -> int:
             rec = _raw.popleft()
         except IndexError:
             break
-        if len(rec) == 5:
+        if len(rec) == 9:
             _fold_span(rec)
-        else:
+        elif len(rec) == 6:
             _fold_finish(rec)
+        else:
+            _fold_open(rec)
         n += 1
     return n
 
 
-def _fold_span(rec: tuple) -> None:
-    trace_id = rec[_TRACE]
-    flight_recorder.note_span(rec)
-    if not trace_id:
+def _fold_open(rec: tuple) -> None:
+    t, trace_id, route = rec
+    if trace_id in _traces:
         return
-    if SHIPPING:
-        _pending.append(rec)
-    stage = rec[_STAGE]
+    _traces[trace_id] = [[], route, t]
+    if len(_traces) > MAX_TRACES:
+        with _lock:
+            while len(_traces) > MAX_TRACES:
+                _traces.popitem(last=False)
+
+
+def _accumulate(rec: tuple) -> None:
+    """A span record into the request that opened its trace id, or,
+    after the request closed, straight into the finished route's
+    vector. No request opened it: nothing (the ring has it)."""
+    trace_id = rec[_TRACE]
     tr = _traces.get(trace_id)
     if tr is None:
         route_done = _finished_routes.get(trace_id)
         if route_done is not None:
             # Late arrival (node record shipped — or locally folded —
-            # after the request closed): fold against the finished
-            # route now.
+            # after the request closed).
             with _lock:
-                _fold(route_done, stage, rec[_DUR], trace_id)
-            return
-        tr = _traces.setdefault(trace_id, [[], rec[_ROUTE], rec[_T]])
-        if len(_traces) > MAX_TRACES:
-            with _lock:
-                while len(_traces) > MAX_TRACES:
-                    _traces.popitem(last=False)
+                _fold(route_done, rec[_STAGE], rec[_DUR], trace_id)
+        return
     if rec[_ROUTE] and not tr[1]:
         tr[1] = rec[_ROUTE]
     if len(tr[0]) < MAX_STAGES_PER_TRACE:
-        tr[0].append((stage, rec[_DUR]))
+        tr[0].append(rec)
+
+
+def _fold_span(rec: tuple) -> None:
+    flight_recorder.note_span(rec)
+    if not rec[_TRACE]:
+        return
+    if SHIPPING:
+        _pending.append(rec)
+    _accumulate(rec)
+
+
+def _front_ttft_self(stages: List[tuple]) -> Optional[tuple]:
+    """The derived record ``front.ttft_self`` of a request whose stages
+    hold the envelope and the engine's part of it: the envelope less
+    the engine's extent (two stamps of one process's clock, so a node's
+    clock offset cancels), a child of the envelope."""
+    envelope = next((r for r in stages if r[_STAGE] == ENVELOPE_STAGE),
+                    None)
+    engine = [r for r in stages if r[_STAGE] in _ENGINE_TTFT_STAGES]
+    if envelope is None or not engine:
+        return None
+    extent = max(r[_T] for r in engine) - min(r[_T0] for r in engine)
+    self_s = max(0.0, envelope[_DUR] - extent)
+    return (envelope[_T], envelope[_TRACE], FRONT_SELF_STAGE, self_s,
+            envelope[_ROUTE], envelope[_T] - self_s, next(_ids),
+            envelope[_ID], None)
 
 
 def _fold_finish(rec: tuple) -> None:
@@ -333,62 +553,73 @@ def _fold_finish(rec: tuple) -> None:
     with _lock:
         tr = _traces.pop(trace_id, None)
         stages = tr[0] if tr else []
-        agg: Dict[str, float] = {}
-        for stage, dur in stages:
-            agg[stage] = agg.get(stage, 0.0) + dur
+        front = _front_ttft_self(stages)
+        if front is not None:
+            flight_recorder.note_span(front)
+            _fold(route, FRONT_SELF_STAGE, front[_DUR], trace_id)
+        for r in stages:
+            if r[_STAGE] == ENVELOPE_STAGE:
+                _fold(route, ENVELOPE_STAGE, r[_DUR], trace_id)
+        agg = _agg(stages)
         for stage, dur in agg.items():
             _fold(route, stage, dur, trace_id)
         unattributed = max(0.0, total_s - sum(agg.values()))
         _fold(route, "unattributed", unattributed, trace_id)
         agg["unattributed"] = unattributed
         dominant = max(agg.items(), key=lambda kv: kv[1])[0]
-        _finished.append({
+        entry = {
             "trace_id": trace_id, "route": route, "status": status,
             "total_s": total_s, "dominant_stage": dominant,
             "unattributed_s": unattributed, "ts": t,
             "stages": stages,
-        })
+        }
+        if front is not None:
+            entry["front_ttft_self_s"] = front[_DUR]
+        _finished.append(entry)
         _finished_routes[trace_id] = route
         while len(_finished_routes) > MAX_TRACES:
             _finished_routes.popitem(last=False)
 
 
+def _unwire(rec: dict) -> tuple:
+    """The obs-ship wire shape back into a record tuple (raises on a
+    malformed entry). Records of an older sender carry no start."""
+    t = float(rec.get("t") or time.time())
+    dur_s = float(rec["dur_s"])
+    return (t, rec["trace_id"], rec["stage"], dur_s,
+            rec.get("route") or "", float(rec.get("t0") or t - dur_s),
+            int(rec.get("id") or 0), int(rec.get("parent") or 0),
+            rec.get("attrs") or None)
+
+
 def ingest(records: Optional[List[dict]]) -> None:
     """Head-side fold of node-shipped stage records (the
     ``obs_report(stages=...)`` path). Same accumulation as a local
-    :func:`record_stage`, minus re-shipping and re-ringing — the
-    origin node already ringed them."""
+    record, minus re-shipping and re-ringing — the origin node already
+    ringed them. The head's own backlog is folded first, so that the
+    request's open marker is in before its node-born stages."""
     if not _on() or not records:
         return
+    flush()
     for rec in records:
         try:
-            trace_id = rec["trace_id"]
-            stage = rec["stage"]
-            dur_s = float(rec["dur_s"])
-            route = rec.get("route") or ""
+            rec = _unwire(rec)
         except (KeyError, TypeError, ValueError):
             continue  # malformed entry must not poison the frame
-        if not trace_id:
-            continue
-        tr = _traces.get(trace_id)
-        if tr is None:
-            route_done = _finished_routes.get(trace_id)
-            if route_done is not None:
-                with _lock:
-                    _fold(route_done, stage, dur_s, trace_id)
-                continue
-            tr = _traces.setdefault(
-                trace_id, [[], route, rec.get("t") or time.time()])
-        if route and not tr[1]:
-            tr[1] = route
-        if len(tr[0]) < MAX_STAGES_PER_TRACE:
-            tr[0].append((stage, dur_s))
+        if rec[_TRACE]:
+            _accumulate(rec)
 
 
-def _wire(rec: tuple) -> dict:
-    """Record tuple -> the obs-ship wire shape :func:`ingest` reads."""
-    return {"trace_id": rec[_TRACE], "stage": rec[_STAGE],
-            "dur_s": rec[_DUR], "route": rec[_ROUTE], "t": rec[_T]}
+def span_dict(rec: tuple) -> dict:
+    """Record tuple -> the dict shape of the edges: the obs-ship wire
+    format :func:`ingest` reads, and the flight ring's snapshot."""
+    out = {"trace_id": rec[_TRACE], "stage": rec[_STAGE],
+           "dur_s": rec[_DUR], "route": rec[_ROUTE], "t": rec[_T],
+           "t0": rec[_T0], "t1": rec[_T], "id": rec[_ID],
+           "parent": rec[_PARENT]}
+    if rec[_ATTRS]:
+        out["attrs"] = rec[_ATTRS]
+    return out
 
 
 def drain_records(max_n: int = 1000) -> List[dict]:
@@ -399,7 +630,7 @@ def drain_records(max_n: int = 1000) -> List[dict]:
     out: List[dict] = []
     while len(out) < max_n:
         try:
-            out.append(_wire(_pending.popleft()))
+            out.append(span_dict(_pending.popleft()))
         except IndexError:
             break
     return out
@@ -408,24 +639,56 @@ def drain_records(max_n: int = 1000) -> List[dict]:
 def requeue_records(records: List[dict]) -> None:
     """Put drained records back after a failed ship (bounded: the deque
     drops oldest if the head stays unreachable)."""
-    _pending.extend(
-        (r["t"], r["trace_id"], r["stage"], r["dur_s"], r["route"])
-        for r in records)
+    _pending.extend(_unwire(r) for r in records)
+
+
+def self_seconds(spans: Iterable[dict]) -> Dict[int, float]:
+    """{span id: its duration less what its children cover} over span
+    dicts (``id``, ``parent``, ``t0``, ``t1``): children are the spans
+    naming it as parent, clipped to it, overlaps counted once."""
+    spans = [s for s in spans if s.get("id")]
+    children: Dict[int, List[Tuple[float, float]]] = {}
+    for s in spans:
+        if s.get("parent"):
+            children.setdefault(s["parent"], []).append(
+                (s["t0"], s["t1"]))
+    out = {}
+    for s in spans:
+        covered, edge = 0.0, s["t0"]
+        for c0, c1 in sorted(children.get(s["id"], ())):
+            c0, c1 = max(c0, edge), min(c1, s["t1"])
+            if c1 > c0:
+                covered += c1 - c0
+                edge = c1
+        out[s["id"]] = max(0.0, (s["t1"] - s["t0"]) - covered)
+    return out
+
+
+def _stage_dicts(stages: Iterable[tuple]) -> List[dict]:
+    return [{"stage": r[_STAGE], "dur_s": r[_DUR], "t0": r[_T0],
+             "t1": r[_T]} for r in stages]
+
+
+def _agg(stages: Iterable[tuple]) -> Dict[str, float]:
+    """{stage: summed seconds} of what tiles a request's wall time (the
+    envelope lies over the others and is left out)."""
+    agg: Dict[str, float] = {}
+    for r in stages:
+        if r[_STAGE] != ENVELOPE_STAGE:
+            agg[r[_STAGE]] = agg.get(r[_STAGE], 0.0) + r[_DUR]
+    return agg
 
 
 def _waterfall(entry: dict) -> dict:
     """Presentation shape shared by the API, the CLI, and the flight
-    recorder: stages plus each stage's share of the total. Retained
-    entries hold (stage, dur) pairs; the dict shape is built here, at
-    read time, not per request."""
+    recorder: stages (with start and end) plus each stage's share of
+    the total. Retained entries hold record tuples; the dict shape is
+    built here, at read time, not per request."""
     total = entry.get("total_s") or 0.0
-    stages = []
-    for stage, dur in entry.get("stages") or []:
-        frac = (dur / total) if total > 0 else 0.0
-        stages.append({"stage": stage, "dur_s": dur,
-                       "frac": round(frac, 4)})
     out = dict(entry)
-    out["stages"] = stages
+    out["stages"] = _stage_dicts(entry.get("stages") or [])
+    for s in out["stages"]:
+        s["frac"] = round(s["dur_s"] / total, 4) if total > 0 else 0.0
     return out
 
 
@@ -440,9 +703,7 @@ def slow_requests(n: int = 10,
         if include_inflight:
             now = time.time()
             for trace_id, tr in _traces.items():
-                agg: Dict[str, float] = {}
-                for stage, dur in tr[0]:
-                    agg[stage] = agg.get(stage, 0.0) + dur
+                agg = _agg(tr[0])
                 age = max(0.0, now - tr[2])
                 items.append({
                     "trace_id": trace_id, "route": tr[1],
@@ -505,11 +766,10 @@ def stage_spans_for_trace(trace_id: str) -> List[dict]:
     with _lock:
         tr = _traces.get(trace_id)
         if tr is not None:
-            return [{"stage": s, "dur_s": d} for s, d in tr[0]]
+            return _stage_dicts(tr[0])
         for entry in _finished:
             if entry["trace_id"] == trace_id:
-                return [{"stage": s, "dur_s": d}
-                        for s, d in entry["stages"]]
+                return _stage_dicts(entry["stages"])
     return []
 
 
@@ -519,8 +779,7 @@ def finished_waterfalls() -> List[dict]:
         out = []
         for e in _finished:
             e = dict(e)
-            e["stages"] = [{"stage": s, "dur_s": d}
-                           for s, d in e["stages"]]
+            e["stages"] = _stage_dicts(e["stages"])
             out.append(e)
         return out
 

@@ -5,8 +5,9 @@ A burn-rate alert tells the operator a route degraded; by the time a
 human looks, the queue drained and the evidence is gone. Every process
 therefore keeps cheap bounded ring buffers of what just happened:
 
-- **span ring** — recent stage spans (fed by
-  ``critical_path.record_stage``: one deque append on the hot path),
+- **span ring** — recent spans (fed by ``critical_path``'s fold: every
+  ``span`` and ``record_stage`` record, with start, end, parent and
+  attributes; one deque append each),
 - **sample ring** — periodic health samples (queue depths, SLO burn,
   memory pressure, loop lag; fed by ``collect_health_metrics`` at
   scrape/ship cadence).
@@ -66,19 +67,13 @@ def set_enabled(on: bool) -> None:
 
 def note_span(rec) -> None:
     """Hot path: one GIL-atomic bounded append. ``rec`` is the
-    critical-path record tuple ``(t, trace_id, stage, dur_s, route)``
-    (dicts from older callers pass through); the dict shape is built
-    at freeze time, not per span."""
+    critical-path span record tuple (dicts from older callers pass
+    through); the dict shape (``critical_path.span_dict``: ``t``,
+    ``trace_id``, ``stage``, ``dur_s``, ``route``, ``t0``, ``t1``,
+    ``id``, ``parent``, ``attrs``) is built at freeze time, not per
+    span."""
     if ENABLED:
         _spans.append(rec)
-
-
-def _span_dict(rec) -> dict:
-    if isinstance(rec, tuple):
-        t, trace_id, stage, dur_s, route = rec
-        return {"t": t, "trace_id": trace_id, "stage": stage,
-                "dur_s": dur_s, "route": route}
-    return rec
 
 
 def note_sample(kind: str, data: Dict[str, Any]) -> None:
@@ -95,7 +90,15 @@ def local_snapshot() -> dict:
 
     critical_path.flush()  # ring is fed at fold time, not append time
     n = max(1, int(ray_config.flight_ring_size))
-    spans = [_span_dict(r) for r in list(_spans)[-n:]]
+    spans = [critical_path.span_dict(r) if isinstance(r, tuple) else r
+             for r in list(_spans)[-n:]]
+    # Self time: a span's duration less what its children (of this
+    # snapshot) cover, e.g. what of an admission wave is none of its
+    # named parts.
+    self_s = critical_path.self_seconds(spans)
+    for s in spans:
+        if s.get("id") in self_s:
+            s["self_s"] = self_s[s["id"]]
     samples = list(_samples)[-n:]
     try:
         slow = critical_path.slow_requests(10, include_inflight=True)

@@ -376,7 +376,7 @@ class LocalBackend:
         # start): one monotonic read + attribute write — cheap enough
         # for the submit hot path, gated for the A/B overhead bench.
         if _perf_stats.ENABLED:
-            spec._submit_monotonic = _monotonic()
+            spec._submit_monotonic = _critical_path.clock()
         if spec.kind == TaskKind.ACTOR_TASK:
             self._submit_actor_task(spec)
             return
@@ -807,11 +807,11 @@ class LocalBackend:
                             threading.current_thread().name)
         submitted = getattr(spec, "_submit_monotonic", None)
         if submitted is not None:
-            _SCHED_LATENCY.record(_monotonic() - submitted)
+            queued_s = _critical_path.clock() - submitted
+            _SCHED_LATENCY.record(queued_s)
             if _critical_path.enabled():
                 _critical_path.record_stage(
-                    _trace_id_of(spec), "sched.queue",
-                    _monotonic() - submitted)
+                    _trace_id_of(spec), "sched.queue", queued_s)
         try:
             from ray_tpu._private.runtime_env import applied_runtime_env
 
@@ -848,11 +848,11 @@ class LocalBackend:
         if submitted is not None:
             # For actor tasks this is mailbox queue delay — the actor-
             # path backpressure signal.
-            _SCHED_LATENCY.record(_monotonic() - submitted)
+            queued_s = _critical_path.clock() - submitted
+            _SCHED_LATENCY.record(queued_s)
             if _critical_path.enabled():
                 _critical_path.record_stage(
-                    _trace_id_of(spec), "sched.queue",
-                    _monotonic() - submitted)
+                    _trace_id_of(spec), "sched.queue", queued_s)
         try:
             args, kwargs = self.worker.resolve_args(spec)
             if actor._proc is not None:

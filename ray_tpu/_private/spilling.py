@@ -22,7 +22,6 @@ from __future__ import annotations
 
 import os
 import threading
-import time
 from typing import Dict, List, Optional
 
 import cloudpickle
@@ -213,13 +212,13 @@ class SpillManager:
                     with self._lock:
                         self.in_memory_bytes -= size
                 continue
-            t0 = time.monotonic()
+            t0 = critical_path.clock()
             payload = cloudpickle.dumps(value)
             url = self.storage.spill(oid, payload)
             if critical_path.enabled():
                 critical_path.record_stage(
                     critical_path.ambient_trace_id(), "object.spill",
-                    time.monotonic() - t0)
+                    critical_path.clock() - t0)
             sanitize_hooks.crash_point("spill.write.after")
             sanitize_hooks.sched_point("spill.mark")
             if self.store.mark_spilled(oid, url):
@@ -237,12 +236,12 @@ class SpillManager:
         """Write an already-serialized payload (a shm arena object's
         RTS1 bytes — see ``shm_plane.payload_bytes``) to the storage
         backend. The caller flips its own entry; accounting here."""
-        t0 = time.monotonic()
+        t0 = critical_path.clock()
         url = self.storage.spill(object_id, payload)
         if critical_path.enabled():
             critical_path.record_stage(
                 critical_path.ambient_trace_id(), "object.spill",
-                time.monotonic() - t0)
+                critical_path.clock() - t0)
         sanitize_hooks.crash_point("spill.write.after")
         _SPILL_BYTES.inc(len(payload))
         with self._lock:
@@ -251,13 +250,13 @@ class SpillManager:
         return url
 
     def restore(self, url: str):
-        t0 = time.monotonic()
+        t0 = critical_path.clock()
         raw = self.storage.restore(url)
         _RESTORE_BYTES.inc(len(raw))
         if critical_path.enabled():
             critical_path.record_stage(
                 critical_path.ambient_trace_id(), "object.restore",
-                time.monotonic() - t0)
+                critical_path.clock() - t0)
         sanitize_hooks.sched_point("spill.restore")
         value = decode_spilled_payload(raw)
         with self._lock:

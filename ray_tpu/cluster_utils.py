@@ -139,7 +139,7 @@ def pull_via_transfer(worker, plane, oid, host: str, port: int) -> bool:
         t0 = time.monotonic()
         acquired = slots.acquire(timeout=30.0)
         _PULL_SLOT_WAIT.record(time.monotonic() - t0)
-        t1 = time.monotonic()
+        t1 = critical_path.clock()
         try:
             rc = plane.store.pull_from_striped(
                 oid.binary(), host, port,
@@ -151,7 +151,7 @@ def pull_via_transfer(worker, plane, oid, host: str, port: int) -> bool:
         if rc not in (0, -5):
             return False
         if rc == 0:
-            pull_s = time.monotonic() - t1
+            pull_s = critical_path.clock() - t1
             _PULL_SECONDS.record(pull_s)
             _PULL_BYTES.inc(plane.store.object_size(oid.binary()) or 0)
             # Critical-path stage: a pull inside a traced task charges

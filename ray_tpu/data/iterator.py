@@ -6,6 +6,11 @@ thread, get re-batched to a fixed batch size (static shapes for XLA), and
 `jax.device_put` runs one batch ahead of the consumer so the transfer
 overlaps the train step. Double-buffering is enough on TPU-VMs because
 device_put is async — the consumer only blocks if compute outruns ingest.
+
+The path is timed by `critical_path` spans (in the flight ring, and in
+a profiler's trace while one is taken): `data.batch_wait` is the
+consumer's wait for the next prefetched batch, `data.block_fetch` and
+`data.to_device` the two halves of the device path's producer.
 """
 
 from __future__ import annotations
@@ -17,6 +22,7 @@ from typing import Any, Dict, Iterator, Optional
 import numpy as np
 
 import ray_tpu
+from ray_tpu._private import critical_path
 from ray_tpu.data.block import BlockAccessor
 
 _SENTINEL = object()
@@ -47,7 +53,8 @@ def _rebatch(block_iter: Iterator[Any], batch_size: Optional[int],
 
 
 def _prefetch_iter(it: Iterator[Any], depth: int) -> Iterator[Any]:
-    """Run `it` on a background thread with a bounded queue."""
+    """Run `it` on a background thread with a bounded queue; the
+    consumer's waits for it are `data.batch_wait` spans."""
     q: "queue.Queue" = queue.Queue(maxsize=max(1, depth))
     err: list = []
 
@@ -63,7 +70,8 @@ def _prefetch_iter(it: Iterator[Any], depth: int) -> Iterator[Any]:
     t = threading.Thread(target=worker, daemon=True)
     t.start()
     while True:
-        item = q.get()
+        with critical_path.span("data.batch_wait"):
+            item = q.get()
         if item is _SENTINEL:
             if err:
                 raise err[0]
@@ -101,16 +109,19 @@ def iter_device_batches(ref_iter, *, batch_size: Optional[int],
 
     def to_device(batch: Dict[str, np.ndarray]):
         out = {}
-        for k, v in batch.items():
-            if dtypes and k in dtypes:
-                v = v.astype(dtypes[k])
-            out[k] = jax.device_put(v, target) if target is not None \
-                else jax.device_put(v)
+        with critical_path.span("data.to_device"):
+            for k, v in batch.items():
+                if dtypes and k in dtypes:
+                    v = v.astype(dtypes[k])
+                out[k] = jax.device_put(v, target) if target is not None \
+                    else jax.device_put(v)
         return out
 
     def blocks():
         for ref in ref_iter:
-            yield ray_tpu.get(ref)
+            with critical_path.span("data.block_fetch"):
+                block = ray_tpu.get(ref)
+            yield block
 
     host_iter = _rebatch(blocks(), batch_size,
                          lambda acc: acc.to_numpy(), drop_last)

@@ -70,27 +70,22 @@ def _stage_spans(trace_ids) -> List[Dict[str, Any]]:
     finished-request waterfall entry, sharing the request's traceId so
     an OTLP viewer shows the stage anatomy (proxy dispatch → replica
     execute → llm.prefill → ...) inside the same trace as the task
-    spans. Durations are attributed (not wall-clock-positioned): each
-    span is laid end-to-end from the request's finish timestamp minus
-    its total, which preserves ordering and proportion."""
+    spans, each where the recorder's span says it started and ended."""
     from ray_tpu._private import critical_path
 
     out: List[Dict[str, Any]] = []
     for entry in critical_path.finished_waterfalls():
         trace_id = entry["trace_id"]
-        t0 = entry["ts"] - (entry.get("total_s") or 0.0)
-        cursor = t0
         parent = trace_id if trace_id in trace_ids else None
         for i, st in enumerate(entry.get("stages") or []):
-            start, cursor = cursor, cursor + st["dur_s"]
             out.append({
                 "traceId": trace_id,
                 "spanId": f"stage:{st['stage']}:{i}:{trace_id[:8]}",
                 "parentSpanId": parent,
                 "name": f"stage.{st['stage']}",
                 "kind": "SPAN_KIND_INTERNAL",
-                "startTimeUnixNano": int(start * 1e9),
-                "endTimeUnixNano": int(cursor * 1e9),
+                "startTimeUnixNano": int(st["t0"] * 1e9),
+                "endTimeUnixNano": int(st["t1"] * 1e9),
                 "status": {"code": "STATUS_CODE_OK", "message": None},
                 "attributes": {
                     "ray_tpu.stage": st["stage"],

@@ -234,27 +234,32 @@ def _attention(cfg: LlamaConfig, q, k, v, mesh, rules):
 
 
 def layer_fn(cfg: LlamaConfig, mesh, rules, cos, sin, x, lp, positions):
-    """One transformer block. x: [B, S, D]."""
-    h = rms_norm_reference(x, lp["attn_norm"], cfg.norm_eps)
-    q = jnp.einsum("bsd,dhk->bshk", h, lp["wq"])
-    k = jnp.einsum("bsd,dhk->bshk", h, lp["wk"])
-    v = jnp.einsum("bsd,dhk->bshk", h, lp["wv"])
-    q = apply_rope(q, cos, sin, positions)
-    k = apply_rope(k, cos, sin, positions)
-    q = with_logical_constraint(q, "batch", "seq", "heads", "head_dim",
-                                mesh=mesh, rules=rules)
-    attn = _attention(cfg, q, k, v, mesh, rules)
-    x = x + jnp.einsum("bshk,hkd->bsd", attn.astype(cfg.dtype), lp["wo"])
-    h2 = rms_norm_reference(x, lp["mlp_norm"], cfg.norm_eps)
-    # Named for selective remat: cfg.remat="mlp" saves these two (the
-    # dominant recompute cost) while still rematerializing the rest.
-    gate = checkpoint_name(
-        jax.nn.silu(jnp.einsum("bsd,df->bsf", h2, lp["w1"])), "ffn_gate")
-    up = checkpoint_name(
-        jnp.einsum("bsd,df->bsf", h2, lp["w3"]), "ffn_up")
-    ff = with_logical_constraint(gate * up, "batch", "seq", "mlp",
-                                 mesh=mesh, rules=rules)
-    x = x + jnp.einsum("bsf,fd->bsd", ff, lp["w2"])
+    """One transformer block. x: [B, S, D]. The two halves are scoped
+    (`attn`, `mlp`) so that a device trace can tell their ops apart."""
+    with jax.named_scope("attn"):
+        h = rms_norm_reference(x, lp["attn_norm"], cfg.norm_eps)
+        q = jnp.einsum("bsd,dhk->bshk", h, lp["wq"])
+        k = jnp.einsum("bsd,dhk->bshk", h, lp["wk"])
+        v = jnp.einsum("bsd,dhk->bshk", h, lp["wv"])
+        q = apply_rope(q, cos, sin, positions)
+        k = apply_rope(k, cos, sin, positions)
+        q = with_logical_constraint(q, "batch", "seq", "heads", "head_dim",
+                                    mesh=mesh, rules=rules)
+        attn = _attention(cfg, q, k, v, mesh, rules)
+        x = x + jnp.einsum("bshk,hkd->bsd", attn.astype(cfg.dtype),
+                           lp["wo"])
+    with jax.named_scope("mlp"):
+        h2 = rms_norm_reference(x, lp["mlp_norm"], cfg.norm_eps)
+        # Named for selective remat: cfg.remat="mlp" saves these two (the
+        # dominant recompute cost) while still rematerializing the rest.
+        gate = checkpoint_name(
+            jax.nn.silu(jnp.einsum("bsd,df->bsf", h2, lp["w1"])),
+            "ffn_gate")
+        up = checkpoint_name(
+            jnp.einsum("bsd,df->bsf", h2, lp["w3"]), "ffn_up")
+        ff = with_logical_constraint(gate * up, "batch", "seq", "mlp",
+                                     mesh=mesh, rules=rules)
+        x = x + jnp.einsum("bsf,fd->bsd", ff, lp["w2"])
     x = with_logical_constraint(x, "batch", "seq", "act_embed",
                                 mesh=mesh, rules=rules)
     return x
@@ -371,21 +376,24 @@ def loss_fn(params, batch, cfg: LlamaConfig, *, mesh=None,
         x = forward_hidden(params, batch["tokens"], cfg, mesh=mesh,
                            rules=rules, positions=batch.get("positions"))
         out_w = params["embed"].T if cfg.tie_embeddings else params["out"]
-        losses = fused_linear_cross_entropy(
-            x.reshape(b * s, cfg.dim), out_w.astype(cfg.dtype),
-            batch["targets"].reshape(b * s))
+        with jax.named_scope("loss"):  # holds the output projection too
+            losses = fused_linear_cross_entropy(
+                x.reshape(b * s, cfg.dim), out_w.astype(cfg.dtype),
+                batch["targets"].reshape(b * s))
     else:
         logits = forward(params, batch["tokens"], cfg, mesh=mesh,
                          rules=rules, positions=batch.get("positions"))
-        losses = softmax_cross_entropy(
-            logits.reshape(b * s, cfg.vocab_size),
-            batch["targets"].reshape(b * s))
-    losses = losses.reshape(b, s)
-    mask = batch.get("mask")
-    if mask is None:
-        mask = jnp.ones((b, s), jnp.float32)
-    total = jnp.maximum(mask.sum(), 1.0)
-    loss = (losses * mask).sum() / total
+        with jax.named_scope("loss"):
+            losses = softmax_cross_entropy(
+                logits.reshape(b * s, cfg.vocab_size),
+                batch["targets"].reshape(b * s))
+    with jax.named_scope("loss"):
+        losses = losses.reshape(b, s)
+        mask = batch.get("mask")
+        if mask is None:
+            mask = jnp.ones((b, s), jnp.float32)
+        total = jnp.maximum(mask.sum(), 1.0)
+        loss = (losses * mask).sum() / total
     return loss, {"loss": loss, "tokens": total,
                   "perplexity": jnp.exp(loss)}
 
@@ -446,21 +454,24 @@ def forward_with_cache(params, tokens, cfg: LlamaConfig, cache,
 
     def layer(x, scanned):
         lp, k_cache_l, v_cache_l = scanned
-        h = rms_norm_reference(x, lp["attn_norm"], cfg.norm_eps)
-        q = jnp.einsum("bsd,dhk->bshk", h, lp["wq"])
-        k = jnp.einsum("bsd,dhk->bshk", h, lp["wk"])
-        v = jnp.einsum("bsd,dhk->bshk", h, lp["wv"])
-        q = apply_rope(q, cos, sin, positions)
-        k = apply_rope(k, cos, sin, positions)
-        k_cache_l = jax.vmap(write_cache)(k_cache_l, k, start_pos)
-        v_cache_l = jax.vmap(write_cache)(v_cache_l, v, start_pos)
-        attn = _cached_attention(cfg, q, k_cache_l, v_cache_l, positions)
-        x = x + jnp.einsum("bshk,hkd->bsd", attn.astype(cfg.dtype),
-                           lp["wo"])
-        h2 = rms_norm_reference(x, lp["mlp_norm"], cfg.norm_eps)
-        gate = jax.nn.silu(jnp.einsum("bsd,df->bsf", h2, lp["w1"]))
-        up = jnp.einsum("bsd,df->bsf", h2, lp["w3"])
-        x = x + jnp.einsum("bsf,fd->bsd", gate * up, lp["w2"])
+        with jax.named_scope("attn"):
+            h = rms_norm_reference(x, lp["attn_norm"], cfg.norm_eps)
+            q = jnp.einsum("bsd,dhk->bshk", h, lp["wq"])
+            k = jnp.einsum("bsd,dhk->bshk", h, lp["wk"])
+            v = jnp.einsum("bsd,dhk->bshk", h, lp["wv"])
+            q = apply_rope(q, cos, sin, positions)
+            k = apply_rope(k, cos, sin, positions)
+            k_cache_l = jax.vmap(write_cache)(k_cache_l, k, start_pos)
+            v_cache_l = jax.vmap(write_cache)(v_cache_l, v, start_pos)
+            attn = _cached_attention(cfg, q, k_cache_l, v_cache_l,
+                                     positions)
+            x = x + jnp.einsum("bshk,hkd->bsd", attn.astype(cfg.dtype),
+                               lp["wo"])
+        with jax.named_scope("mlp"):
+            h2 = rms_norm_reference(x, lp["mlp_norm"], cfg.norm_eps)
+            gate = jax.nn.silu(jnp.einsum("bsd,df->bsf", h2, lp["w1"]))
+            up = jnp.einsum("bsd,df->bsf", h2, lp["w3"])
+            x = x + jnp.einsum("bsf,fd->bsd", gate * up, lp["w2"])
         return x, (k_cache_l, v_cache_l)
 
     x, (k_new, v_new) = lax.scan(

@@ -178,18 +178,20 @@ def moe_forward(params, tokens, cfg: MoEConfig, *, mesh=None,
 
     def layer(carry, lp):
         x, aux_acc = carry
-        h = rms_norm_reference(x, lp["attn_norm"], cfg.norm_eps)
-        q = jnp.einsum("bsd,dhk->bshk", h, lp["wq"])
-        k_ = jnp.einsum("bsd,dhk->bshk", h, lp["wk"])
-        v = jnp.einsum("bsd,dhk->bshk", h, lp["wv"])
-        q = apply_rope(q, cos, sin, positions)
-        k_ = apply_rope(k_, cos, sin, positions)
-        attn = _attention(cfg, q, k_, v, mesh, rules)
-        x = x + jnp.einsum("bshk,hkd->bsd", attn.astype(cfg.dtype),
-                           lp["wo"])
-        h2 = rms_norm_reference(x, lp["mlp_norm"], cfg.norm_eps)
-        ffn_out, aux = _moe_ffn(cfg, lp, h2, mesh, rules)
-        x = x + ffn_out
+        with jax.named_scope("attn"):
+            h = rms_norm_reference(x, lp["attn_norm"], cfg.norm_eps)
+            q = jnp.einsum("bsd,dhk->bshk", h, lp["wq"])
+            k_ = jnp.einsum("bsd,dhk->bshk", h, lp["wk"])
+            v = jnp.einsum("bsd,dhk->bshk", h, lp["wv"])
+            q = apply_rope(q, cos, sin, positions)
+            k_ = apply_rope(k_, cos, sin, positions)
+            attn = _attention(cfg, q, k_, v, mesh, rules)
+            x = x + jnp.einsum("bshk,hkd->bsd", attn.astype(cfg.dtype),
+                               lp["wo"])
+        with jax.named_scope("mlp"):
+            h2 = rms_norm_reference(x, lp["mlp_norm"], cfg.norm_eps)
+            ffn_out, aux = _moe_ffn(cfg, lp, h2, mesh, rules)
+            x = x + ffn_out
         x = with_logical_constraint(x, "batch", "seq", "act_embed",
                                     mesh=mesh, rules=rules)
         return (x, aux_acc + aux), None
@@ -212,8 +214,9 @@ def moe_loss_fn(params, batch, cfg: MoEConfig, *, mesh=None,
                               rules=rules,
                               positions=batch.get("positions"))
     b, s, v = logits.shape
-    losses = softmax_cross_entropy(
-        logits.reshape(b * s, v), batch["targets"].reshape(b * s))
-    ce = losses.mean()
-    loss = ce + cfg.aux_loss_coeff * aux
+    with jax.named_scope("loss"):
+        losses = softmax_cross_entropy(
+            logits.reshape(b * s, v), batch["targets"].reshape(b * s))
+        ce = losses.mean()
+        loss = ce + cfg.aux_loss_coeff * aux
     return loss, {"loss": loss, "ce_loss": ce, "aux_loss": aux}

@@ -19,6 +19,7 @@ import optax
 
 from jax.sharding import NamedSharding, PartitionSpec as P
 
+from ray_tpu._private import critical_path
 from ray_tpu.parallel.sharding import (
     DEFAULT_RULES,
     logical_to_mesh_axes,
@@ -99,15 +100,23 @@ def make_train_step(
     `{"tokens": ("batch", "seq"), ...}`); defaults to sharding every leaf's
     leading dim over ("data","fsdp"). The step also has
     `.lower(state, batch)`, as a `jax.jit` function does.
+
+    A device trace splits the step by `op_name`: autodiff marks the
+    backward pass (`transpose(jvp(...))`), the models scope their loss
+    (`loss`), and the update is scoped `optimizer` here. On a mesh the
+    host's two halves are `critical_path` spans: `train.place_batch`
+    and `train.step_dispatch`.
     """
 
     def step_fn(state: TrainState, batch):
         grad_fn = jax.value_and_grad(loss_fn, has_aux=True)
         (loss, metrics), grads = grad_fn(state.params, batch)
-        updates, opt_state = tx.update(grads, state.opt_state, state.params)
-        params = optax.apply_updates(state.params, updates)
-        metrics = dict(metrics)
-        metrics["grad_norm"] = optax.global_norm(grads)
+        with jax.named_scope("optimizer"):
+            updates, opt_state = tx.update(grads, state.opt_state,
+                                           state.params)
+            params = optax.apply_updates(state.params, updates)
+            metrics = dict(metrics)
+            metrics["grad_norm"] = optax.global_norm(grads)
         return TrainState(state.step + 1, params, opt_state), metrics
 
     if mesh is None:
@@ -130,7 +139,10 @@ def make_train_step(
 
     @functools.wraps(step_fn)
     def wrapper(state, batch):
-        return jitted(state, place(batch))
+        with critical_path.span("train.place_batch"):
+            batch = place(batch)
+        with critical_path.span("train.step_dispatch"):
+            return jitted(state, batch)
 
     # Like the mesh-less return value, the step can be lowered without
     # running it (to read the compiled program or its memory analysis).

@@ -554,7 +554,7 @@ class HTTPProxy:
         in :meth:`_respond`."""
         from ray_tpu._private.config import ray_config
 
-        t0 = time.monotonic()
+        t0 = critical_path.clock()
         # Honor a caller-supplied trace id so an upstream LB or client
         # can stitch the request into ITS trace; mint one otherwise.
         # STRICTLY sanitized before use: the value is echoed into
@@ -568,6 +568,10 @@ class HTTPProxy:
         # caller's correlation.
         trace_id = supplied if supplied and len(supplied) <= 64 \
             and _TRACE_ID_OK(supplied) else uuid.uuid4().hex
+        # The request's critical-path accumulator lives from here to
+        # finish_request below: stages recorded for this trace id
+        # anywhere in between collect there.
+        critical_path.open_request(trace_id)
         # Job/tenant tag (X-Job-Id): same sanitizing as the trace id
         # (echoed into headers/logs), but never minted — an untagged
         # request falls through to the proxy process's ambient/default
@@ -601,7 +605,7 @@ class HTTPProxy:
             route = await self._respond(conn, req, trace_id, job_id,
                                         model=model)
         finally:
-            latency = time.monotonic() - t0
+            latency = critical_path.clock() - t0
             ttft_s = conn.ttft_s
             conn.trace_id = ""
             conn.job_id = ""
@@ -755,7 +759,7 @@ class HTTPProxy:
             for attempt in (0, 1, 2):
                 # Stage boundary: accept→dispatch covers slot claim /
                 # router queueing, dispatch→result the replica's work.
-                t_dispatch = time.monotonic()
+                t_dispatch = critical_path.clock()
                 # Replica-direct fast path: claim a slot in the
                 # long-poll-fed table and dispatch proxy→replica —
                 # no router lock, no per-request ref pruning, no
@@ -784,7 +788,7 @@ class HTTPProxy:
                             *args,
                             _queue_timeout_s=self.queue_timeout_s,
                             _trace=trace, _job=job)
-                t_wait = time.monotonic()
+                t_wait = critical_path.clock()
                 critical_path.record_stage(
                     trace_id, "proxy.dispatch", t_wait - t_dispatch,
                     route=route)
@@ -927,9 +931,15 @@ class HTTPProxy:
         try:
             async for chunk in aiter_stream(result):
                 if conn.ttft_s is None:
-                    # First token on the wire: the streaming TTFT stamp.
-                    conn.ttft_s = time.monotonic() - conn.t_start
+                    # First token on the wire: the streaming TTFT
+                    # stamp, and the envelope span of everything up to
+                    # it (front.ttft_self is derived from it when the
+                    # request finishes).
+                    conn.ttft_s = critical_path.clock() - conn.t_start
                     self._record_ttft(conn.ttft_s, route, model)
+                    critical_path.record_stage(
+                        conn.trace_id, critical_path.ENVELOPE_STAGE,
+                        conn.ttft_s, route=route)
                 conn.write_body(
                     b"data: " + json.dumps(chunk).encode() + b"\n\n",
                     chunked)

@@ -33,7 +33,6 @@ lives in :class:`DirectDispatcher` and the watch registry around it.
 from __future__ import annotations
 
 import threading
-import time
 import weakref
 from typing import Any, Callable, Dict, List, Optional
 
@@ -543,7 +542,7 @@ class DirectDispatcher:
         from ray_tpu._private.task_spec import (set_ambient_job_id,
                                                 set_ambient_trace_parent)
 
-        t_acquire = time.monotonic()
+        t_acquire = critical_path.clock()
         token = self.table.acquire(
             extra_load=self._router_load,
             affinity_tokens=self._affinity_hint(args, kwargs))
@@ -553,7 +552,7 @@ class DirectDispatcher:
         # dispatch RPC below is charged to the proxy's dispatch stage).
         critical_path.record_stage(
             trace[0] if trace else None, "direct.acquire",
-            time.monotonic() - t_acquire)
+            critical_path.clock() - t_acquire)
         try:
             prev = set_ambient_trace_parent(trace) \
                 if trace is not None else None

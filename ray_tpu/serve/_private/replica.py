@@ -114,7 +114,7 @@ class ServeReplica:
             self._total += 1
         trace_id = critical_path.ambient_trace_id() \
             if critical_path.enabled() else None
-        t0 = time.perf_counter()
+        t0 = critical_path.clock()
         try:
             target = self.callable
             if method and method != "__call__":
@@ -139,7 +139,7 @@ class ServeReplica:
             self._stat_errors.inc()
             raise
         finally:
-            elapsed = time.perf_counter() - t0
+            elapsed = critical_path.clock() - t0
             self._stat_latency.record(elapsed)
             critical_path.record_stage(trace_id, "replica.execute",
                                        elapsed)

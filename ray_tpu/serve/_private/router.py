@@ -242,7 +242,7 @@ class Router:
 
     def assign_request(self, method: str, args: tuple, kwargs: dict,
                        timeout: float = 30.0, trace=None, job=None):
-        t_enter = time.monotonic()
+        t_enter = critical_path.clock()
         deadline = t_enter + timeout
         dispatched = False
         with self._lock:
@@ -257,9 +257,9 @@ class Router:
                     dispatched = True
                     critical_path.record_stage(
                         trace[0] if trace else None, "router.assign",
-                        time.monotonic() - t_enter)
+                        critical_path.clock() - t_enter)
                     return ref
-                if time.monotonic() > deadline:
+                if critical_path.clock() > deadline:
                     raise QueueSaturatedError(
                         f"no replica available for {self._deployment} "
                         f"within {timeout}s")
@@ -283,7 +283,7 @@ class Router:
         right now, else None. The event-loop proxy's fast path — no
         coroutine, no parking; saturation falls back to
         :meth:`assign_request_async`."""
-        t_enter = time.monotonic()
+        t_enter = critical_path.clock()
         with self._lock:
             self._waiting += 1
         ref = self._try_assign(method, args, kwargs, trace, job)
@@ -293,7 +293,7 @@ class Router:
         else:
             critical_path.record_stage(
                 trace[0] if trace else None, "router.assign",
-                time.monotonic() - t_enter)
+                critical_path.clock() - t_enter)
         return ref
 
     async def assign_request_async(self, method: str, args: tuple,
@@ -305,7 +305,7 @@ class Router:
         ``await asyncio.sleep`` instead of blocking the loop thread."""
         import asyncio
 
-        t_enter = time.monotonic()
+        t_enter = critical_path.clock()
         deadline = t_enter + timeout
         dispatched = False
         with self._lock:  # raylint: disable=R1 -- microsecond critical section guarding state shared with sync dispatch threads; an asyncio.Lock cannot serialize against them
@@ -318,9 +318,9 @@ class Router:
                     dispatched = True
                     critical_path.record_stage(
                         trace[0] if trace else None, "router.assign",
-                        time.monotonic() - t_enter)
+                        critical_path.clock() - t_enter)
                     return ref
-                if time.monotonic() > deadline:
+                if critical_path.clock() > deadline:
                     raise QueueSaturatedError(
                         f"no replica available for {self._deployment} "
                         f"within {timeout}s")

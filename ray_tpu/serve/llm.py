@@ -180,6 +180,13 @@ class LLMEngine:
         self._pending_toks = None
         self._dev_last = None
         self._dev_lengths = None
+        # Running totals of what the loop's spans count at the same
+        # seams (`metrics()["totals"]`); only the loop's thread adds.
+        self._totals = dict.fromkeys((
+            "decode_steps", "active_slot_steps", "tokens_kept",
+            "tokens_discarded", "prefill_tokens_real",
+            "prefill_tokens_bucketed", "admit_waves", "admissions",
+            "kv_blocks_read_back", "kv_bytes_read_back"), 0)
 
         # Compiled programs. Prefill is per-slot (batch 1, bucketed T);
         # decode covers all slots at T=1. Params are explicit arguments —
@@ -518,7 +525,7 @@ class LLMEngine:
         req = _Request(
             request_id=next(self._req_counter), prompt=prompt,
             params=params or SamplingParams(), out_queue=queue.Queue(),
-            t_arrival=time.perf_counter(),
+            t_arrival=critical_path.clock(),
             model=model, priority=max(0, min(2, int(priority))), job=job,
             # Stamped on the CALLING thread (the replica's task context
             # is thread-local; the engine loop below has none).
@@ -550,6 +557,14 @@ class LLMEngine:
                 "compiled_programs": len(self._prefill_exec)
                 + (self._decode_exec is not None)
                 + (self._sample_exec is not None),
+                # Since the engine started: decode steps run and the
+                # slot-steps of them that held a request; tokens handed
+                # to clients (first tokens and kept decode tokens) and
+                # decode tokens computed for nobody; prompt tokens
+                # prefilled and the bucket sizes paid for them; waves
+                # of admission and requests admitted; KV blocks and
+                # bytes read back for the prefix cache.
+                "totals": dict(self._totals),
             }
         if self.prefix_cache is not None:
             out["kv_cache"] = self.prefix_cache.stats()
@@ -558,6 +573,11 @@ class LLMEngine:
     # -- engine loop -----------------------------------------------------
 
     def _loop(self):
+        """The loop is tiled by `critical_path` spans, so that a
+        profiler's trace shows under each of the device's idle gaps
+        what the loop was doing: `engine.admit_wave` (with its parts as
+        children), `engine.decode_dispatch`, `engine.token_fetch`,
+        `engine.consume_block`, `engine.idle_wait`."""
         self._temps_arr = np.zeros(self.n_slots, np.float32)
         self._topks_arr = np.zeros(self.n_slots, np.int32)
         while self._running.is_set():
@@ -566,11 +586,12 @@ class LLMEngine:
                 # Drop any in-flight block for fully-retired slots.
                 self._flush_pending()
                 if not admitted:
-                    try:
-                        req = self._queue.get(timeout=0.05)
-                        self._queue.put(req)
-                    except queue.Empty:
-                        continue
+                    with critical_path.span("engine.idle_wait"):
+                        try:
+                            req = self._queue.get(timeout=0.05)
+                            self._queue.put(req)
+                        except queue.Empty:
+                            pass
                 continue
             self._decode_once()
 
@@ -593,9 +614,16 @@ class LLMEngine:
     def _admit(self) -> bool:
         if self._queue.empty() or not self._free_slots:
             return False
+        with critical_path.span("engine.admit_wave") as wave:
+            return self._admit_wave(wave)
+
+    def _admit_wave(self, wave) -> bool:
+        """One wave of admission: everything here holds decode off."""
         # Admission invalidates the device carries and needs free slots:
         # drain the in-flight decode block first.
-        self._flush_pending()
+        with critical_path.span("engine.flush_pending"):
+            self._flush_pending()
+        totals = self._totals
         drained: List[_Request] = []
         while True:
             try:
@@ -616,42 +644,53 @@ class LLMEngine:
             t_real = len(prompt)
             slot = self._free_slots.pop()
             # Stage: admit = time spent queued for a slot.
-            t_admit = time.perf_counter()
+            t_admit = critical_path.clock()
             critical_path.record_stage(req.trace_id, "llm.admit",
                                        t_admit - req.t_arrival)
             # Prefix-cache fast path: copy matched KV blocks straight
             # into the slot, then prefill ONLY the tail at the tail's
             # bucket, starting at the matched offset.
-            m_tok, chain = self._prefix_copy_in(req, slot, prompt)
-            req.t_kv_done = time.perf_counter()
+            with critical_path.span("engine.prefix_copy_in") as sp:
+                m_tok, chain = self._prefix_copy_in(req, slot, prompt)
+                sp.set(matched_tokens=m_tok)
+            req.t_kv_done = critical_path.clock()
             critical_path.record_stage(req.trace_id, "llm.kv_lookup",
                                        req.t_kv_done - t_admit)
             tail = prompt[m_tok:]
             t_tail = len(tail)
             bucket = self._serve_bucket(t_tail)
-            tokens = np.zeros((1, bucket), np.int32)
-            tokens[0, :t_tail] = tail
-            self.cache, last_logits = self._run_prefill(
-                jnp.asarray(tokens), jnp.int32(slot), jnp.int32(t_tail),
-                jnp.int32(m_tok), bucket)
-            req.t_prefill_done = time.perf_counter()
+            with critical_path.span("engine.prefill_dispatch",
+                                    real=t_tail, bucket=bucket):
+                tokens = np.zeros((1, bucket), np.int32)
+                tokens[0, :t_tail] = tail
+                self.cache, last_logits = self._run_prefill(
+                    jnp.asarray(tokens), jnp.int32(slot),
+                    jnp.int32(t_tail), jnp.int32(m_tok), bucket)
+            totals["prefill_tokens_real"] += t_tail
+            totals["prefill_tokens_bucketed"] += bucket
+            req.t_prefill_done = critical_path.clock()
             staged.append((req, slot, t_real, last_logits, chain))
         for req in leftover:
             self._queue.put(req)
+        wave.set(admitted=len(staged), left_over=len(leftover))
         if not staged:
             return False
+        totals["admit_waves"] += 1
+        totals["admissions"] += len(staged)
+        totals["tokens_kept"] += len(staged)  # the first tokens, below
         # ONE device-side sampling + ONE host sync for the whole wave:
         # per-admit argmax fetches would serialize a host round-trip
         # per request. Padded to
         # n_slots so the program (and the eager stack feeding it) has
         # one fixed shape, compiled once at warmup.
         pad = self.n_slots - len(staged)
+        sync = critical_path.begin("engine.sample_sync")
         logits = jnp.stack([s[3] for s in staged]
                            + [staged[0][3]] * pad)  # [n_slots, vocab]
         temps_np = np.zeros(self.n_slots, np.float32)
         for i, s in enumerate(staged):
             temps_np[i] = s[0].params.temperature
-        t_sample = time.perf_counter()
+        t_sample = critical_path.clock()
         firsts_dev, self._rng = self._run_sample(
             logits, jnp.asarray(temps_np))
         # The host sync below is where the wave's ASYNC-dispatched
@@ -663,7 +702,8 @@ class LLMEngine:
         # splits tile the wave's wall time, so the per-request vector
         # still sums to what the request actually spent here.
         firsts = np.asarray(firsts_dev)[:len(staged)]
-        now = time.perf_counter()
+        critical_path.end(sync)
+        now = critical_path.clock()
         sync_share = (now - t_sample) / len(staged)
         for (req, slot, t_real, _, _chain), first in zip(staged, firsts):
             critical_path.record_stage(
@@ -691,8 +731,12 @@ class LLMEngine:
         # taxed by the host copies). Safe ordering: a slot retired above
         # cannot be re-admitted until a LATER _admit call, so the KV
         # bytes being read are still this request's prefill output.
-        for req, slot, t_real, _logits, chain in staged:
-            self._prefix_admit(req, slot, chain)
+        with critical_path.span("engine.prefix_readback") as sp:
+            blocks = sum(self._prefix_admit(req, slot, chain)
+                         for req, slot, _t_real, _logits, chain in staged)
+            sp.set(blocks=blocks, bytes=blocks * self._block_nbytes)
+        totals["kv_blocks_read_back"] += blocks
+        totals["kv_bytes_read_back"] += blocks * self._block_nbytes
         # Host state changed: rebuild device carries on the next decode.
         self._dev_last = self._dev_lengths = None
         return True
@@ -702,26 +746,38 @@ class LLMEngine:
         # 0..len-1, first generated token sits at len, etc.). Dispatch
         # block N+1 from the device-side carries, THEN fetch block N —
         # the host round-trip overlaps the next block's compute.
-        last = self._dev_last if self._dev_last is not None \
-            else jnp.asarray(self._last_token)
-        lengths = self._dev_lengths if self._dev_lengths is not None \
-            else jnp.asarray(self._lengths)
-        (self.cache, next_tokens, self._dev_last, self._dev_lengths,
-         self._rng) = self._run_decode(
-            last, lengths,
-            jnp.asarray(self._temps_arr),
-            jnp.asarray(self._topks_arr))
+        active = int(self._active.sum())
+        with critical_path.span("engine.decode_dispatch", active=active,
+                                n_slots=self.n_slots):
+            last = self._dev_last if self._dev_last is not None \
+                else jnp.asarray(self._last_token)
+            lengths = self._dev_lengths if self._dev_lengths is not None \
+                else jnp.asarray(self._lengths)
+            (self.cache, next_tokens, self._dev_last, self._dev_lengths,
+             self._rng) = self._run_decode(
+                last, lengths,
+                jnp.asarray(self._temps_arr),
+                jnp.asarray(self._topks_arr))
+        self._totals["decode_steps"] += self.decode_steps
+        self._totals["active_slot_steps"] += active * self.decode_steps
         prev, self._pending_toks = self._pending_toks, next_tokens
         if prev is not None:
-            self._consume_block(np.asarray(prev))
+            self._consume_block(self._fetch_tokens(prev))
 
     def _flush_pending(self):
         prev, self._pending_toks = self._pending_toks, None
         if prev is not None:
-            self._consume_block(np.asarray(prev))
+            self._consume_block(self._fetch_tokens(prev))
+
+    def _fetch_tokens(self, block):
+        """A decode block's sampled tokens to the host: the wait for
+        the block to finish on the device."""
+        with critical_path.span("engine.token_fetch"):
+            return np.asarray(block)
 
     def _consume_block(self, next_host):
-        with self._lock:
+        kept = 0
+        with critical_path.span("engine.consume_block") as sp, self._lock:
             for slot in np.nonzero(self._active)[0]:
                 req = self._slot_req[slot]
                 # Walk this slot's K-token block; once the request
@@ -731,12 +787,17 @@ class LLMEngine:
                     tok = int(next_host[slot, k])
                     req.tokens.append(tok)
                     req.out_queue.put(tok)  # raylint: disable=R2 -- per-request stream queues are unbounded, so put() cannot block; token delivery and slot-state mutation must share one hold or a racing admit could reuse the slot mid-block
+                    kept += 1
                     self._lengths[slot] += 1
                     self._last_token[slot] = tok
                     if self._finished(req, tok) or \
                             self._lengths[slot] >= self.max_seq - 1:
                         self._retire(slot)  # raylint: disable=R2 -- _retire only pushes the unbounded-queue end-of-stream sentinel and frees the slot; both must be atomic with the walk above
                         break
+            discarded = next_host.size - kept
+            self._totals["tokens_kept"] += kept
+            self._totals["tokens_discarded"] += discarded
+            sp.set(kept=kept, discarded=discarded)
 
     def _finished(self, req: _Request, token: int) -> bool:
         if token in req.params.stop_token_ids:
@@ -750,7 +811,7 @@ class LLMEngine:
                 # Per-slot decode stage: first token → end of stream.
                 critical_path.record_stage(
                     req.trace_id, "llm.decode",
-                    time.perf_counter() - req.t_first_token)
+                    critical_path.clock() - req.t_first_token)
             req.out_queue.put(None)
         self._active[slot] = False
         self._lengths[slot] = 0
@@ -812,10 +873,11 @@ class LLMEngine:
     def _prefix_admit(self, req: _Request, slot: int, chain):
         """After prefill, admit the prompt's full-block chain and read
         the KV bytes for newly-created blocks back to the host store.
-        Runs post-first-token so TTFT never pays for the readback."""
+        Runs post-first-token so TTFT never pays for the readback.
+        Returns the number of blocks read back."""
         pc = self.prefix_cache
         if pc is None or not chain:
-            return
+            return 0
         created, evicted = pc.admit(chain, req.job, self._block_nbytes)
         for h in created:
             kb, vb = self._read_block_j(
@@ -824,6 +886,7 @@ class LLMEngine:
             self._kv_store[h.block_id] = (np.asarray(kb), np.asarray(vb))
         pc.release(created)
         self._offload_evicted(evicted)
+        return len(created)
 
     @staticmethod
     def _shm_object_id(key: str):

@@ -26,6 +26,7 @@ def _clean_engines():
 
 
 def test_finish_folds_stages_and_unattributed():
+    critical_path.open_request("t1")
     critical_path.record_stage("t1", "proxy.dispatch", 0.01,
                                route="/r")
     critical_path.record_stage("t1", "replica.execute", 0.05,
@@ -52,6 +53,7 @@ def test_finish_folds_stages_and_unattributed():
 def test_late_arrival_folds_into_finished_route():
     """Node-born stage records ship seconds after the proxy closed the
     request; they must still land in the route's attribution vector."""
+    critical_path.open_request("t2")
     critical_path.record_stage("t2", "proxy.dispatch", 0.01, route="/r")
     critical_path.finish_request("t2", "/r", "200", 0.02)
     # Arrives via the obs shipper after the finish:
@@ -78,19 +80,195 @@ def test_drain_requeue_roundtrip():
 def test_head_process_does_not_queue_for_shipping():
     """The head folds its own records in place; with no shipper
     started, nothing accumulates in the pending queue."""
+    critical_path.open_request("t3b")
     critical_path.record_stage("t3b", "sched.queue", 0.001)
     assert critical_path.drain_records() == []
-    # ...but the trace still accumulated locally.
+    # ...but the request that opened the trace accumulated it locally.
     critical_path.finish_request("t3b", "/r", "200", 0.002)
     assert critical_path.attribution_vectors()["/r"]["sched.queue"][
         "count"] == 1
 
 
+def test_unopened_trace_reaches_ring_only():
+    """A record whose trace id no request opened (every task of the
+    runtime is a trace root of its own) goes to the flight ring and
+    nowhere else: no accumulator, no waterfall, no vector."""
+    critical_path.record_stage("task-root", "sched.queue", 0.002)
+    critical_path.finish_request("never-opened", "/r", "200", 0.01)
+    snap = critical_path.snapshot_state()
+    assert snap["traces"] == {}
+    assert [s["stage"] for s in
+            flight_recorder.local_snapshot()["spans"]] == ["sched.queue"]
+    (entry,) = critical_path.finished_waterfalls()
+    assert entry["stages"] == []
+    assert set(critical_path.attribution_vectors()["/r"]) == \
+        {"unattributed"}
+
+
+def test_long_request_keeps_every_stage_under_task_flood():
+    """The eviction (PERF.md section 6, PR 23, finding 2): a request
+    whose first stage is followed by 5,000 records of unrelated trace
+    ids, more than MAX_TRACES, keeps every stage when it finishes."""
+    critical_path.open_request("long")
+    critical_path.record_stage("long", "proxy.dispatch", 0.004,
+                               route="/llm")
+    critical_path.record_stage("long", "router.assign", 0.001)
+    for i in range(5000):
+        critical_path.record_stage(f"task-{i}", "sched.queue", 0.001)
+    critical_path.record_stage("long", "llm.admit", 9.6)
+    critical_path.record_stage("long", "llm.decode", 5.0)
+    critical_path.finish_request("long", "/llm", "200", 14.7)
+    assert 5000 > critical_path.MAX_TRACES
+    (row,) = critical_path.slow_requests(n=1)
+    assert [s["stage"] for s in row["stages"]] == [
+        "proxy.dispatch", "router.assign", "llm.admit", "llm.decode"]
+    assert row["dominant_stage"] == "llm.admit"
+    assert critical_path.snapshot_state()["traces"] == {}
+
+
+def test_span_records_start_end_parent_and_attributes():
+    """The span form: t0 <= t1 on time.time's clock, the enclosing span
+    of the same thread as parent, attributes from the call and from
+    `.set`; the begin/end form writes the same record; another thread's
+    span has no parent here."""
+    import threading
+    import time
+
+    t_before = time.time()
+    with critical_path.span("engine.admit_wave") as wave:
+        with critical_path.span("engine.prefill_dispatch", real=5,
+                                bucket=8):
+            time.sleep(0.002)
+        sp = critical_path.begin("engine.sample_sync")
+        other = threading.Thread(
+            target=lambda: critical_path.span("data.block_fetch")
+            .__enter__().__exit__(None, None, None))
+        other.start()
+        other.join()
+        critical_path.end(sp, admitted=1)
+        wave.set(admitted=1, left_over=0)
+    t_after = time.time()
+    spans = {s["stage"]: s
+             for s in flight_recorder.local_snapshot()["spans"]}
+    assert set(spans) == {"engine.admit_wave", "engine.prefill_dispatch",
+                          "engine.sample_sync", "data.block_fetch"}
+    for s in spans.values():
+        assert t_before <= s["t0"] <= s["t1"] <= t_after
+        assert s["t"] == s["t1"]
+        assert s["dur_s"] == pytest.approx(s["t1"] - s["t0"], abs=1e-6)
+    wave_id = spans["engine.admit_wave"]["id"]
+    assert spans["engine.admit_wave"]["parent"] == 0
+    assert spans["engine.prefill_dispatch"]["parent"] == wave_id
+    assert spans["engine.sample_sync"]["parent"] == wave_id
+    assert spans["data.block_fetch"]["parent"] == 0  # its own thread
+    assert spans["engine.prefill_dispatch"]["attrs"] == \
+        {"real": 5, "bucket": 8}
+    assert spans["engine.admit_wave"]["attrs"] == \
+        {"admitted": 1, "left_over": 0}
+    assert spans["engine.sample_sync"]["attrs"] == {"admitted": 1}
+    # A thin record made inside a span names it as parent too.
+    with critical_path.span("engine.admit_wave") as wave:
+        critical_path.record_stage("", "llm.admit", 0.01)
+    ring = flight_recorder.local_snapshot()["spans"]
+    assert ring[-2]["stage"] == "llm.admit"
+    assert ring[-2]["parent"] == ring[-1]["id"]
+    assert ring[-2]["t1"] - ring[-2]["t0"] == pytest.approx(0.01, abs=1e-6)
+
+
+def test_self_time_is_duration_less_childrens_cover():
+    """A hand-made nest: self time = duration less what the children
+    cover, overlaps counted once, children clipped to the parent,
+    grandchildren charged to their own parent only."""
+    nest = [
+        {"id": 1, "parent": 0, "t0": 0.0, "t1": 10.0},
+        {"id": 2, "parent": 1, "t0": 1.0, "t1": 4.0},
+        {"id": 3, "parent": 1, "t0": 3.0, "t1": 6.0},   # overlaps 2
+        {"id": 4, "parent": 1, "t0": 9.0, "t1": 12.0},  # sticks out
+        {"id": 5, "parent": 2, "t0": 1.5, "t1": 2.0},   # grandchild
+        {"id": 0, "parent": 0, "t0": 0.0, "t1": 1.0},   # no id: skipped
+    ]
+    assert critical_path.self_seconds(nest) == {
+        1: pytest.approx(10.0 - 5.0 - 1.0), 2: pytest.approx(2.5),
+        3: pytest.approx(3.0), 4: pytest.approx(3.0),
+        5: pytest.approx(0.5)}
+    # The flight ring's snapshot carries it for every span it holds.
+    import time
+
+    with critical_path.span("engine.admit_wave"):
+        time.sleep(0.002)
+        with critical_path.span("engine.flush_pending"):
+            time.sleep(0.002)
+    child, parent = flight_recorder.local_snapshot()["spans"]
+    assert parent["self_s"] == pytest.approx(
+        parent["dur_s"] - child["dur_s"], abs=1e-5)
+    assert child["self_s"] == pytest.approx(child["dur_s"], abs=1e-5)
+
+
+def test_front_ttft_self_is_envelope_less_engine_extent():
+    """`finish_request` derives front.ttft_self from the envelope
+    (`proxy.first_byte`) and the engine's stages, leaves the envelope
+    out of the tiling, and emits nothing when the engine's stages are
+    not there."""
+    import time
+
+    critical_path.open_request("f1")
+    critical_path.record_stage("f1", "proxy.dispatch", 0.002, route="/l")
+    time.sleep(0.03)
+    critical_path.record_stage("f1", "llm.admit", 0.01)
+    time.sleep(0.02)
+    # 0.01 s of admit + 0.02 s of sleep lie between the engine's first
+    # start and its last end.
+    critical_path.record_stage("f1", "llm.prefill", 0.015)
+    critical_path.record_stage("f1", "proxy.first_byte", 0.070, route="/l")
+    critical_path.record_stage("f1", "llm.decode", 0.1)
+    critical_path.finish_request("f1", "/l", "200", 0.2)
+    critical_path.open_request("f2")
+    critical_path.record_stage("f2", "proxy.first_byte", 0.05, route="/l")
+    critical_path.finish_request("f2", "/l", "200", 0.06)
+    rows = {r["trace_id"]: r for r in critical_path.slow_requests()}
+    front = rows["f1"]["front_ttft_self_s"]
+    assert 0.0 < front < 0.070
+    assert front == pytest.approx(0.070 - 0.030, abs=0.008)
+    assert "front_ttft_self_s" not in rows["f2"]
+    # The envelope is shown, but does not count twice in the tiling.
+    assert "proxy.first_byte" in [s["stage"] for s in rows["f1"]["stages"]]
+    assert rows["f1"]["unattributed_s"] == pytest.approx(
+        0.2 - 0.002 - 0.01 - 0.015 - 0.1)
+    vec = critical_path.attribution_vectors()["/l"]
+    assert vec["front.ttft_self"]["count"] == 1
+    assert vec["proxy.first_byte"]["count"] == 2
+    derived = [s for s in flight_recorder.local_snapshot()["spans"]
+               if s["stage"] == "front.ttft_self"]
+    assert len(derived) == 1 and derived[0]["trace_id"] == "f1"
+    assert derived[0]["dur_s"] == pytest.approx(front)
+
+
+def test_critical_path_loads_without_jax():
+    """Every process of the runtime imports the recorder; one that was
+    told its chips (and so never imports JAX) must not get JAX from
+    it, and its spans still record."""
+    import subprocess
+    import sys
+
+    code = (
+        "import sys\n"
+        "from ray_tpu._private import critical_path, flight_recorder\n"
+        "with critical_path.span('engine.idle_wait'):\n"
+        "    pass\n"
+        "assert 'jax' not in sys.modules, 'jax was imported'\n"
+        "assert flight_recorder.local_snapshot()['spans'][0]['stage'] "
+        "== 'engine.idle_wait'\n")
+    subprocess.run([sys.executable, "-c", code], check=True, timeout=120)
+
+
 def test_disabled_records_nothing():
     critical_path.set_enabled(False)
     try:
+        critical_path.open_request("t4")
         critical_path.record_stage("t4", "proxy.dispatch", 0.01,
                                    route="/r")
+        with critical_path.span("engine.idle_wait") as sp:
+            sp.set(n=1)
         critical_path.finish_request("t4", "/r", "200", 0.1)
         assert critical_path.finished_waterfalls() == []
         assert critical_path.drain_records() == []
@@ -102,6 +280,7 @@ def test_disabled_records_nothing():
 def test_slow_requests_ranked_with_fracs():
     for i, total in enumerate((0.1, 0.5, 0.3)):
         tid = f"t5-{i}"
+        critical_path.open_request(tid)
         critical_path.record_stage(tid, "replica.execute", total / 2,
                                    route="/r")
         critical_path.finish_request(tid, "/r", "200", total)
@@ -116,6 +295,7 @@ def test_stage_metric_p99_exported():
     from ray_tpu._private.runtime_metrics import _collect_fastpath_stats
     from ray_tpu.util.metrics import snapshot_registry
 
+    critical_path.open_request("t6")
     critical_path.record_stage("t6", "replica.execute", 0.05,
                                route="/r")
     critical_path.finish_request("t6", "/r", "200", 0.06)
@@ -175,6 +355,7 @@ def test_api_slow_requests_and_debug_dump(ray_start_2_cpus):
 
     from ray_tpu.dashboard import shutdown_dashboard, start_dashboard
 
+    critical_path.open_request("t7")
     critical_path.record_stage("t7", "replica.execute", 0.2,
                                route="/demo")
     critical_path.finish_request("t7", "/demo", "200", 0.25)
@@ -210,6 +391,7 @@ def test_api_slow_requests_and_debug_dump(ray_start_2_cpus):
 def test_cli_slow_prints_waterfalls(ray_start_2_cpus, capsys):
     from ray_tpu.scripts.cli import main as cli_main
 
+    critical_path.open_request("t8")
     critical_path.record_stage("t8", "llm.prefill", 0.3, route="/llm")
     critical_path.finish_request("t8", "/llm", "200", 0.4)
     cli_main(["slow", "-n", "5"])

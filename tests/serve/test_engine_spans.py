@@ -1,0 +1,281 @@
+"""The engine loop's spans and counters (PR 24): the spans tile the
+loop, the totals of `metrics()` add up to what the clients got, a
+profiler's trace carries the spans under their own names on the ring's
+clock, and the proxy's envelope gives `front.ttft_self`.
+
+Kept tier-1-sized: one tiny model, a few dozen requests.
+"""
+
+import glob
+import http.client
+import json
+import threading
+import time
+
+import pytest
+
+import jax
+import jax.numpy as jnp
+
+import ray_tpu
+from ray_tpu import serve
+from ray_tpu._private import critical_path, flight_recorder
+from ray_tpu._private.config import ray_config
+from ray_tpu.models.llama import LlamaConfig, init_params
+from ray_tpu.serve.llm import LLMDeployment, LLMEngine, SamplingParams
+
+_TINY = LlamaConfig(vocab_size=64, dim=16, n_layers=1, n_heads=2,
+                    n_kv_heads=2, hidden_dim=32, max_seq_len=64,
+                    dtype=jnp.float32, remat=False)
+
+# Every span name the loop emits.
+_LOOP_SPANS = {"engine.admit_wave", "engine.decode_dispatch",
+               "engine.token_fetch", "engine.consume_block",
+               "engine.idle_wait"}
+_WAVE_SPANS = {"engine.flush_pending", "engine.prefix_copy_in",
+               "engine.prefill_dispatch", "engine.sample_sync",
+               "engine.prefix_readback"}
+
+
+@pytest.fixture(scope="module")
+def params():
+    return init_params(_TINY, jax.random.PRNGKey(0))
+
+
+@pytest.fixture
+def ring(monkeypatch):
+    """An empty recorder whose snapshots hold the whole ring."""
+    critical_path.reset()
+    flight_recorder.reset()
+    monkeypatch.setattr(ray_config, "flight_ring_size", 2048)
+    monkeypatch.setattr(ray_config, "llm_kv_block_tokens", 4)
+    monkeypatch.setattr(ray_config, "llm_prefix_shm_tier", False)
+    yield
+    critical_path.reset()
+    flight_recorder.reset()
+
+
+class _SteppedEngine(LLMEngine):
+    """LLMEngine whose decode step takes what a small model's takes on
+    a chip (4 ms; the tiny model's own 0.5 ms on the CPU would make the
+    interpreter's few dozen microseconds between two spans a tenth of
+    the loop)."""
+
+    def _run_decode(self, last, lengths, temps, topks):
+        time.sleep(0.004)
+        return super()._run_decode(last, lengths, temps, topks)
+
+
+def _generate_all(engine, prompts, max_tokens):
+    results = [None] * len(prompts)
+
+    def worker(i):
+        results[i] = engine.generate(prompts[i],
+                                     SamplingParams(max_tokens=max_tokens))
+
+    threads = [threading.Thread(target=worker, args=(i,))
+               for i in range(len(prompts))]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(timeout=120)
+    return results
+
+
+def _engine_spans():
+    return [s for s in flight_recorder.local_snapshot()["spans"]
+            if s["stage"].startswith("engine.")]
+
+
+def _union(intervals):
+    covered, edge = 0.0, float("-inf")
+    for a, b in sorted(intervals):
+        a = max(a, edge)
+        if b > a:
+            covered += b - a
+            edge = b
+    return covered
+
+
+def test_spans_tile_the_loop_and_totals_add_up(params, ring):
+    engine = _SteppedEngine(_TINY, params, max_batch_size=2,
+                            max_seq_len=64)
+    engine.warmup(16)
+    # Ten requests of 50 tokens on two slots: five waves, some 250
+    # decode steps. Prompts of 5 and 9 tokens pay for buckets of 8 and
+    # 16, and hold one and two whole 4-token blocks for the read-back.
+    # The streams are drained afterwards, so that no client thread
+    # wakes for every token and takes the interpreter from the loop.
+    prompts = [[(7 * i + j) % 60 + 1 for j in range(5 + 4 * (i % 2))]
+               for i in range(10)]
+    streams = [engine.generate(p, SamplingParams(max_tokens=50),
+                               stream=True) for p in prompts]
+    deadline = time.monotonic() + 120
+    while time.monotonic() < deadline:
+        m = engine.metrics()
+        if m["totals"]["admissions"] == 10 and not m["active_slots"]:
+            break
+        time.sleep(0.05)
+    engine.stop()
+    answers = [list(s) for s in streams]
+    assert [len(a) for a in answers] == [50] * 10
+    totals = engine.metrics()["totals"]
+    spans = _engine_spans()
+    by_name = {}
+    for s in spans:
+        by_name.setdefault(s["stage"], []).append(s)
+    assert set(by_name) >= (_LOOP_SPANS | _WAVE_SPANS) - \
+        {"engine.idle_wait"}, sorted(by_name)
+
+    # The loop's own spans have no parent; a wave's parts name the wave.
+    waves = {s["id"] for s in by_name["engine.admit_wave"]}
+    for name in _WAVE_SPANS:
+        assert all(s["parent"] in waves for s in by_name[name]), name
+    top = [s for s in spans if s["parent"] == 0]
+    assert {s["stage"] for s in top} <= _LOOP_SPANS
+
+    # Tiling: from the first decode step to the end of the last block
+    # the loop was inside one of its spans at least 95 % of the time.
+    steps = by_name["engine.decode_dispatch"]
+    assert len(steps) >= 200
+    t_first = min(s["t0"] for s in steps)
+    t_last = max(s["t1"] for s in by_name["engine.consume_block"])
+    inside = _union((max(s["t0"], t_first), min(s["t1"], t_last))
+                    for s in top)
+    assert inside / (t_last - t_first) >= 0.95, inside / (t_last - t_first)
+
+    # The totals add up, among themselves and with the spans' counts.
+    assert totals["tokens_kept"] == sum(len(a) for a in answers)
+    assert totals["admissions"] == 10
+    assert totals["admit_waves"] == len(
+        [s for s in by_name["engine.admit_wave"]
+         if s["attrs"]["admitted"]])
+    assert totals["decode_steps"] == len(steps)
+    assert totals["active_slot_steps"] == sum(
+        s["attrs"]["active"] for s in steps)
+    assert 0 < totals["active_slot_steps"] <= totals["decode_steps"] * 2
+    kept = sum(s["attrs"]["kept"] for s in by_name["engine.consume_block"])
+    discarded = sum(s["attrs"]["discarded"]
+                    for s in by_name["engine.consume_block"])
+    assert kept + totals["admissions"] == totals["tokens_kept"]
+    assert discarded == totals["tokens_discarded"]
+    assert kept + discarded == 2 * len(by_name["engine.consume_block"])
+    prefills = by_name["engine.prefill_dispatch"]
+    assert totals["prefill_tokens_real"] == sum(
+        s["attrs"]["real"] for s in prefills) == sum(map(len, prompts))
+    assert totals["prefill_tokens_bucketed"] == sum(
+        s["attrs"]["bucket"] for s in prefills) == 5 * 8 + 5 * 16
+    assert totals["prefill_tokens_real"] <= \
+        totals["prefill_tokens_bucketed"]
+    blocks = sum(s["attrs"]["blocks"]
+                 for s in by_name["engine.prefix_readback"])
+    assert totals["kv_blocks_read_back"] == blocks == 5 * 1 + 5 * 2
+    assert totals["kv_bytes_read_back"] == sum(
+        s["attrs"]["bytes"] for s in by_name["engine.prefix_readback"]) \
+        == blocks * engine._block_nbytes
+
+
+def test_trace_carries_the_spans_on_the_rings_clock(params, ring,
+                                                    tmp_path):
+    """With a `jax.profiler` trace being taken every engine span is in
+    the xplane's host plane under its own name, with its attributes as
+    stats, and starts where the ring's record says (to 1 ms): the host
+    plane's clock is time.time's, counted from the `profile_start_time`
+    of the trace's `Task Environment` plane."""
+    from jax.profiler import ProfileData
+
+    engine = LLMEngine(_TINY, params, max_batch_size=2, max_seq_len=64)
+    engine.warmup(16)
+    engine.generate([1, 2, 3], SamplingParams(max_tokens=2))  # loop is up
+    critical_path.flush()
+    flight_recorder.reset()
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        _generate_all(engine, [[5, 6, 7, 8, 9], [9, 8, 7]], 6)
+        time.sleep(0.12)  # the loop idles: engine.idle_wait
+    finally:
+        jax.profiler.stop_trace()
+    engine.stop()
+    ring_spans = _engine_spans()
+    (path,) = glob.glob(str(tmp_path / "plugins/profile/*/*.xplane.pb"))
+    start_ns, seen = None, []
+    for plane in ProfileData.from_file(path).planes:
+        if plane.name == "Task Environment":
+            start_ns = dict(plane.stats)["profile_start_time"]
+        if plane.name.startswith("/host:CPU"):
+            for line in plane.lines:
+                seen += [(e.name, e.start_ns, dict(e.stats))
+                         for e in line.events
+                         if e.name.startswith("engine.")]
+    assert {name for name, _, _ in seen} == _LOOP_SPANS | _WAVE_SPANS
+    # Every annotation is some ring record's twin: same name, same
+    # attributes, starts within 1 ms of the record's t0.
+    for name, rel_ns, stats in seen:
+        t0 = (start_ns + rel_ns) / 1e9
+        twins = [s for s in ring_spans if s["stage"] == name
+                 and abs(s["t0"] - t0) < 1e-3]
+        assert twins, (name, t0)
+        assert any((s.get("attrs") or {}) == stats for s in twins), \
+            (name, stats, twins)
+    dispatch = [st for name, _, st in seen
+                if name == "engine.decode_dispatch"]
+    assert all(st["n_slots"] == 2 and 0 < st["active"] <= 2
+               for st in dispatch)
+
+
+def _sse_tokens(resp):
+    events = [e[len(b"data: "):] for e in resp.read().split(b"\n\n")
+              if e.startswith(b"data: ")]
+    return [json.loads(e)["token"] for e in events if e != b"[DONE]"]
+
+
+def test_front_ttft_self_from_the_proxys_envelope(params, ring):
+    """A streamed request through proxy, replica and engine: its
+    waterfall holds the envelope `proxy.first_byte` beside the stages
+    it encloses, and `front.ttft_self`, the front's own share, is there
+    and no longer than the envelope."""
+    ray_tpu.shutdown()
+    ray_tpu.init(num_cpus=4)
+    try:
+        serve.run(
+            serve.deployment(LLMDeployment).bind(
+                _TINY, lambda: params, max_batch_size=2, max_seq_len=64,
+                warmup_max_prompt_len=16),
+            route_prefix="/llm")
+        proxy = serve.start_http_proxy()
+        conn = http.client.HTTPConnection(proxy.host, proxy.port,
+                                          timeout=60)
+        for trace_id in ("warm", "front-1", "front-2"):
+            conn.request(
+                "POST", "/llm",
+                body=json.dumps({"prompt_ids": [3, 1, 4, 1, 5],
+                                 "max_tokens": 4, "stream": True}),
+                headers={"Content-Type": "application/json",
+                         "X-Trace-Id": trace_id})
+            resp = conn.getresponse()
+            assert resp.status == 200
+            assert len(_sse_tokens(resp)) == 4
+        conn.close()
+        deadline = time.monotonic() + 10
+        rows = {}
+        while time.monotonic() < deadline and "front-2" not in rows:
+            rows = {r["trace_id"]: r
+                    for r in critical_path.slow_requests(n=10)}
+            time.sleep(0.05)
+    finally:
+        serve.shutdown()
+        ray_tpu.shutdown()
+    for trace_id in ("front-1", "front-2"):
+        row = rows[trace_id]
+        stages = {s["stage"]: s for s in row["stages"]}
+        assert {"proxy.first_byte", "llm.prefill"} <= set(stages), stages
+        envelope = stages["proxy.first_byte"]
+        assert 0.0 <= row["front_ttft_self_s"] <= envelope["dur_s"]
+        # The engine's stages lie inside the envelope.
+        assert envelope["t0"] <= stages["llm.prefill"]["t0"]
+        assert stages["llm.prefill"]["t1"] <= envelope["t1"] + 1e-3
+    derived = [s for s in flight_recorder.local_snapshot()["spans"]
+               if s["stage"] == "front.ttft_self"]
+    assert {s["trace_id"] for s in derived} >= {"front-1", "front-2"}
+    vec = critical_path.attribution_vectors()["/llm"]
+    assert vec["front.ttft_self"]["count"] >= 2
