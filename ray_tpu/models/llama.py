@@ -416,22 +416,33 @@ def init_kv_cache(cfg: LlamaConfig, n_slots: int, max_seq: int,
 
 def _cached_attention(cfg, q, k_cache, v_cache, q_positions):
     """q: [B, T, H, D]; caches: [B, S, Hkv, D]; q_positions: [B, T]
-    absolute positions. Causal over absolute key positions."""
+    absolute positions. Causal over absolute key positions.
+
+    Guarantees: K and V enter both contractions in the dtype and layout
+    the cache stores them in (`forward_with_cache` hands q over in that
+    dtype), each byte read once; the `rep = H // Hkv` query heads of one
+    KV head are contracted together (a group of one for MHA), so no
+    array of `rep` x cache size and none of cache size in float32
+    exists. Scores, mask, softmax and both accumulations are float32;
+    `probs` is cast to the cache's dtype for the P.V product. Holds for
+    any (B, T): decode (T = 1), prefill (B = 1) and in between.
+
+    The benchmark's tests rely on this function's name and signature
+    (`tests/benchmark/test_references.py` patches it), on the cache
+    layout [layers, slots, max_seq, kv_heads, head_dim], and on the call
+    sitting inside the `attn` scope of `forward_with_cache`."""
     b, t, h, d = q.shape
-    s = k_cache.shape[1]
-    rep = cfg.n_heads // cfg.n_kv_heads
-    k = jnp.repeat(k_cache, rep, axis=2)
-    v = jnp.repeat(v_cache, rep, axis=2)
-    scores = jnp.einsum("bthd,bshd->bhts", q.astype(jnp.float32),
-                        k.astype(jnp.float32),
+    s, g = k_cache.shape[1], k_cache.shape[2]
+    qg = q.reshape(b, t, g, h // g, d)
+    scores = jnp.einsum("btgrd,bsgd->bgrts", qg, k_cache,
                         preferred_element_type=jnp.float32) * (d ** -0.5)
     key_pos = jnp.arange(s)
     mask = key_pos[None, None, :] <= q_positions[:, :, None]  # [B, T, S]
-    scores = jnp.where(mask[:, None, :, :], scores, -1e30)
+    scores = jnp.where(mask[:, None, None, :, :], scores, -1e30)
     probs = jax.nn.softmax(scores, axis=-1)
-    out = jnp.einsum("bhts,bshd->bthd", probs.astype(v.dtype), v,
-                     preferred_element_type=jnp.float32)
-    return out.astype(q.dtype)
+    out = jnp.einsum("bgrts,bsgd->btgrd", probs.astype(v_cache.dtype),
+                     v_cache, preferred_element_type=jnp.float32)
+    return out.reshape(b, t, h, d).astype(q.dtype)
 
 
 def forward_with_cache(params, tokens, cfg: LlamaConfig, cache,
