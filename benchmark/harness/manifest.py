@@ -4,7 +4,8 @@
 belongs to one of them sits in a file of its own under `benchmark/`:
 `configs/<config>.json`, `traffic/<mix>.json`, `metrics/<metric>.json`
 (a metric split by cells, `<quantity>.<cells>`, reads `<quantity>.json`
-where it has no file of its own), and the Python a file names (`runners/<kind>.py`, `readers/<reader>.py`,
+where it has no file of its own), and the Python a file names
+(`runners/<kind>.py`, `models/<family>.py`, `readers/<reader>.py`,
 `references/<name>.py`, `flops/<name>.py`). A later PR adds files and
 entries; nothing here lists them.
 """
@@ -43,6 +44,24 @@ def metric_spec(name):
 def plugin(group, name):
     """The module `benchmark/<group>/<name>.py`."""
     return importlib.import_module(f"benchmark.{group}.{name}")
+
+
+def model_adapter(config, needs=()):
+    """The model adapter of a configuration's `family`: the module
+    `benchmark/models/<family>.py`, through which alone the runners,
+    `sweep.py` and the tests reach the program's model
+    (`models/dense.py` says what one holds). `needs` are the names the
+    caller takes from it: a family that lacks one cannot run that kind
+    of cell yet, and the run ends here, at set-up, saying which."""
+    family = config["family"]
+    adapter = plugin("models", family)
+    missing = [n for n in needs if not hasattr(adapter, n)]
+    if missing:
+        raise SystemExit(
+            f"model family {family!r} cannot run a cell of kind "
+            f"{config.get('kind')!r} yet: benchmark/models/{family}.py has no "
+            f"{', '.join(missing)}")
+    return adapter
 
 
 class Cell:

@@ -33,13 +33,16 @@ import numpy as np
 
 from benchmark.harness import device as hw
 from benchmark.harness import traffic
-from benchmark.harness.manifest import BENCH_DIR, plugin
-from benchmark.runners.train import model_config, prng_key
+from benchmark.harness.manifest import BENCH_DIR, model_adapter, plugin
+from benchmark.runners.train import prng_key
 
 ROUTE = "/llm"
+# What a serving cell takes from its family's model adapter.
+NEEDS = ("program_config", "with_layers", "init", "cached_forward",
+         "init_cache", "deployment_args")
 
 
-def check_against_reference(cfg, config, seed, served=None):
+def check_against_reference(config, seed, served=None):
     """Logits through the cache, at the shape the engine serves, against
     the reference's full forward pass, on `reference_layers` layers at
     the published widths. One row a slot: every row is prefilled with
@@ -50,32 +53,31 @@ def check_against_reference(cfg, config, seed, served=None):
     used before does): a wrong mask, rows mixed up or positions off by
     one all show. (At the published widths the initialiser's weights
     give query-key scores a spread of 1.6, so attention weighs in the
-    logits as it is.) `served` stands in for `forward_with_cache` in
-    tests.
+    logits as it is.) `served` stands in for the adapter's
+    `cached_forward` in tests.
     Returns (largest error over largest |reference| logit, positions
     compared)."""
     import jax
     import jax.numpy as jnp
 
-    from ray_tpu.models import init_params
-    from ray_tpu.models.llama import forward_with_cache, init_kv_cache
-
     plan = config["serve"]
+    model = model_adapter(config, NEEDS)
     reference = plugin("references", config["reference"])
-    small = dataclasses.replace(cfg, n_layers=plan["reference_layers"])
-    params = jax.jit(functools.partial(init_params, small))(prng_key(seed))
+    small = model.with_layers(model.program_config(config),
+                              plan["reference_layers"])
+    params = jax.jit(functools.partial(model.init, small))(prng_key(seed))
     lens = np.asarray(plan["reference_prompt_lens"])
     rows, n_pre, n_dec = len(lens), int(lens.max()), \
         plan["reference_decode_steps"]
     tokens = np.random.default_rng([seed, 7]).integers(
-        0, cfg.vocab_size, (rows, n_pre + n_dec), dtype=np.int32)
+        0, config["vocab_size"], (rows, n_pre + n_dec), dtype=np.int32)
     with jax.default_matmul_precision("highest"):
         want = np.asarray(jax.jit(functools.partial(
             reference.forward, hp=reference.hyper(config)))(
                 params, jnp.asarray(tokens)))
-    step = jax.jit(functools.partial(served or forward_with_cache,
+    step = jax.jit(functools.partial(served or model.cached_forward,
                                      cfg=small))
-    cache = init_kv_cache(small, rows, plan["max_seq_len"])
+    cache = model.init_cache(small, rows, plan["max_seq_len"])
     logits, cache = step(params, jnp.asarray(tokens[:, :n_pre]),
                          cache=cache, start_pos=jnp.zeros(rows, jnp.int32))
     worst = np.abs(np.asarray(logits.astype(jnp.float32))
@@ -91,12 +93,13 @@ def check_against_reference(cfg, config, seed, served=None):
     return float(worst / np.abs(want).max()), rows * (n_pre + n_dec)
 
 
-def probes(config, seed, vocab):
+def probes(config, seed):
     """The greedy requests whose answers are held against the
     reference: prompts of different lengths, each asking for as many
     tokens as fill `probe_total`."""
     plan = config["serve"]
     rng = np.random.default_rng([seed, 11])
+    vocab = config["vocab_size"]
     return [{"prompt_ids": rng.integers(0, vocab, n).tolist(),
              "max_tokens": plan["probe_total"] - n, "stream": True,
              "temperature": 0.0} for n in plan["probe_prompt_lens"]]
@@ -181,7 +184,6 @@ class Deployment:
         import ray_tpu
         from ray_tpu import serve
         from ray_tpu._private.config import RayTpuConfig, ray_config
-        from ray_tpu.models import init_params
         from ray_tpu.serve.llm import LLMDeployment
 
         if dataclasses.asdict(ray_config) != \
@@ -189,7 +191,9 @@ class Deployment:
             raise RuntimeError("ray_config is not the default")
         config, mix = cell.config, cell.traffic
         plan = config["serve"]
-        self.cfg = cfg = model_config(config)
+        model = model_adapter(config, NEEDS)
+        cfg = model.program_config(config)
+        self.vocab = config["vocab_size"]
         self.n_slots = plan["max_batch_size"]
         self.ray_tpu, self.serve = ray_tpu, serve
         if not ray_tpu.is_initialized():  # a CPU rehearsal names its devices
@@ -198,13 +202,14 @@ class Deployment:
         self.params = []  # the replica's own weights, for the reference
 
         def params_fn():
-            self.params.append(jax.jit(functools.partial(init_params, cfg))(
+            self.params.append(jax.jit(functools.partial(model.init, cfg))(
                 prng_key(seed)))
             return self.params[-1]
 
+        args, kwargs = model.deployment_args(cfg, params_fn)
         self.handle = serve.run(
             serve.deployment(LLMDeployment).bind(
-                cfg, params_fn, max_batch_size=self.n_slots,
+                *args, **kwargs, max_batch_size=self.n_slots,
                 max_seq_len=plan["max_seq_len"],
                 warmup_max_prompt_len=traffic.longest_prompt(mix)),
             route_prefix=ROUTE)
@@ -226,7 +231,7 @@ class Load:
     def __init__(self, dep, mix, seed, run_dir, n_requests):
         plan = traffic.request_stream(mix, seed, n_requests)
         plan.update(host=dep.host, port=dep.port, route=ROUTE,
-                    vocab=dep.cfg.vocab_size,
+                    vocab=dep.vocab,
                     clients=mix.get("clients_per_slot", 0) * dep.n_slots)
         self.plan_path = os.path.join(run_dir, "plan.json")
         self.out_path = os.path.join(run_dir, "records.json")
@@ -392,14 +397,13 @@ def run(cell, *, seed, seconds, trace_dir, devices, run_dir):
     plan = config["serve"]
     phases = hw.Phases()
     phases.mark("imports")
-    cfg = model_config(config)
-    err, positions = check_against_reference(cfg, config, seed)
+    err, positions = check_against_reference(config, seed)
     gc.collect()
     phases.mark("reference check")
     dep = Deployment(cell, seed)
     phases.mark("deploy")
     try:
-        asked = probes(config, seed, cfg.vocab_size)
+        asked = probes(config, seed)
         twice = [post(dep.host, dep.port, asked[0]) for _ in range(2)]
         together = ask_together(dep.host, dep.port, asked)
         short, same, compared = check_served_tokens(
@@ -412,7 +416,7 @@ def run(cell, *, seed, seconds, trace_dir, devices, run_dir):
         out["memory"] = [hw.memory(devices[0])]
     finally:
         dep.close()
-    verdict = judge(out, cfg.vocab_size)
+    verdict = judge(out, config["vocab_size"])
     checks = {
         "prefill and decode logits within tolerance of the reference":
             err <= plan["logit_tolerance"],

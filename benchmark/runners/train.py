@@ -11,49 +11,16 @@ window untraced, for what is a rate, and then a few traced steps.
 
 from __future__ import annotations
 
-import dataclasses
 import time
 
 import numpy as np
 
 from benchmark.harness import device as hw
-from benchmark.harness.manifest import plugin
+from benchmark.harness.manifest import model_adapter, plugin
 
 
-def model_config(config):
-    """The program's config object for the cell's `config.json` keys."""
-    import jax.numpy as jnp
-
-    from ray_tpu.models.llama import LlamaConfig
-    from ray_tpu.models.moe import MoEConfig
-
-    common = dict(
-        vocab_size=config["vocab_size"], dim=config["hidden_size"],
-        n_layers=config["num_hidden_layers"],
-        n_heads=config["num_attention_heads"],
-        n_kv_heads=config["num_key_value_heads"],
-        hidden_dim=config["intermediate_size"],
-        max_seq_len=config["max_position_embeddings"],
-        rope_theta=float(config["rope_theta"]),
-        norm_eps=float(config["rms_norm_eps"]),
-        tie_embeddings=bool(config["tie_word_embeddings"]),
-        dtype={"bfloat16": jnp.bfloat16,
-               "float32": jnp.float32}[config["torch_dtype"]])
-    if config["family"] == "moe":
-        return MoEConfig(
-            **common, n_experts=config["num_local_experts"],
-            n_experts_per_token=config["num_experts_per_tok"],
-            aux_loss_coeff=float(config["router_aux_loss_coef"]))
-    return LlamaConfig(**common)
-
-
-def family_functions(family):
-    """(sharded init, loss) of the program for a model family."""
-    from ray_tpu.models import init_params_sharded, loss_fn
-    from ray_tpu.models.moe import init_moe_params_sharded, moe_loss_fn
-
-    return {"dense": (init_params_sharded, loss_fn),
-            "moe": (init_moe_params_sharded, moe_loss_fn)}[family]
+# What a training cell takes from its family's model adapter.
+NEEDS = ("program_config", "with_remat", "init_sharded", "loss")
 
 
 def prng_key(seed):
@@ -76,8 +43,8 @@ def run(cell, *, seed, seconds, trace_dir, devices, run_dir):
     n = len(devices)
     seq = mix["seq"]
     batch = plan["sequences_per_chip"] * n
-    cfg = dataclasses.replace(model_config(config), remat=plan["remat"])
-    init_sharded, program_loss = family_functions(config["family"])
+    model = model_adapter(config, NEEDS)
+    cfg = model.with_remat(model.program_config(config), plan["remat"])
     reference = plugin("references", config["reference"])
     hp = reference.hyper(config)
 
@@ -86,7 +53,7 @@ def run(cell, *, seed, seconds, trace_dir, devices, run_dir):
 
     def seeded_block(ids):
         rng = np.random.default_rng([seed, int(ids["id"][0])])
-        tokens = rng.integers(0, cfg.vocab_size, (batch, seq + 1),
+        tokens = rng.integers(0, config["vocab_size"], (batch, seq + 1),
                               dtype=np.int32)
         return {"tokens": tokens[:, :-1], "targets": tokens[:, 1:]}
 
@@ -106,7 +73,7 @@ def run(cell, *, seed, seconds, trace_dir, devices, run_dir):
 
         phases.mark("trainer start")
         mesh = create_mesh(scaling.mesh_config(), devices=devices)
-        params = init_sharded(cfg, mesh, prng_key(seed))
+        params = model.init_sharded(cfg, mesh, prng_key(seed))
         shard = session.get_dataset_shard("train")
         batch_sharding = named_sharding(mesh, "batch", "seq")
 
@@ -130,7 +97,7 @@ def run(cell, *, seed, seconds, trace_dir, devices, run_dir):
         state = init_train_state(params, tx)
         del params
         step = make_train_step(
-            lambda p, b: program_loss(p, b, cfg, mesh=mesh), tx, mesh=mesh,
+            lambda p, b: model.loss(p, b, cfg, mesh=mesh), tx, mesh=mesh,
             batch_logical={"tokens": ("batch", "seq"),
                            "targets": ("batch", "seq")})
         state, metrics = step(state, first)

@@ -9,9 +9,9 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from benchmark.harness.manifest import model_adapter
 from benchmark.references import dense_decoder, moe_top2
 from benchmark.runners import serve as serve_runner
-from benchmark.runners.train import model_config
 from ray_tpu.models import forward, init_params, loss_fn
 from ray_tpu.models.moe import init_moe_params, moe_forward, moe_loss_fn
 
@@ -23,6 +23,10 @@ DENSE = {"family": "dense", "vocab_size": 512, "hidden_size": 64,
          "torch_dtype": "float32"}
 MOE = {**DENSE, "family": "moe", "num_local_experts": 4,
        "num_experts_per_tok": 2, "router_aux_loss_coef": 0.02}
+
+
+def model_config(config):
+    return model_adapter(config).program_config(config)
 
 
 def _tokens(cfg, shape=(2, 33)):
@@ -99,7 +103,7 @@ SERVED = {**DENSE, "hidden_size": 512, "intermediate_size": 1024,
 
 def test_prefill_and_decode_through_the_cache_match_the_reference():
     err, positions = serve_runner.check_against_reference(
-        model_config(SERVED), SERVED, seed=2 ** 31 + 9)
+        SERVED, seed=2 ** 31 + 9)
     assert positions == 4 * 99 and err < 1e-4
 
 
@@ -147,14 +151,14 @@ def _faulty(fault):
     "no mask", "rope theta"])
 def test_the_logit_check_fails_a_faulty_served_path(fault):
     err, _ = serve_runner.check_against_reference(
-        model_config(SERVED), SERVED, seed=5, served=_faulty(fault))
+        SERVED, seed=5, served=_faulty(fault))
     assert err > SERVED["serve"]["logit_tolerance"], (fault, err)
 
 
 def test_served_tokens_are_held_against_the_reference():
     cfg = model_config(SERVED)
     params = init_params(cfg, jax.random.PRNGKey(6))
-    asked = serve_runner.probes(SERVED, 11, cfg.vocab_size)
+    asked = serve_runner.probes(SERVED, 11)
     assert [len(b["prompt_ids"]) + b["max_tokens"] for b in asked] == [12] * 4
 
     def greedy(body):

@@ -13,33 +13,42 @@ import pytest
 import ray_tpu
 from benchmark import run as bench_run
 from benchmark.harness import device as hw
-from benchmark.harness.manifest import Cell, manifest
+from benchmark.harness.manifest import Cell, manifest, model_adapter
 
-CELLS = {w["name"]: w for w in manifest()["workloads"]}
+CELLS = [w["name"] for w in manifest()["workloads"]]
 
 
-def debug_cell(name):
-    """The cell with its model cut to debug widths and its traffic to
-    lengths those hold. Never a benchmark configuration: a test's."""
-    cell = Cell(name)
-    c = cell.config
-    c.update(vocab_size=512, hidden_size=64, intermediate_size=128,
-             num_attention_heads=4, num_key_value_heads=2,
-             max_position_embeddings=256, num_hidden_layers=2)
-    mix = cell.traffic
-    if c["kind"] == "train":
-        c["train"]["loss_tolerance"] = 0.02  # bf16 on 64 tokens a batch
-        mix.update(seq=32, trace_s=1)
-    else:
-        c["serve"].update(max_batch_size=4, max_seq_len=128,
-                          reference_prompt_lens=[16, 12, 7, 3],
-                          reference_decode_steps=3,
-                          logit_tolerance=0.05,  # bf16 at hidden 64
-                          probe_prompt_lens=[9, 7, 5, 3], probe_total=12,
-                          served_token_margin=0.05)
-        mix["pairs"] = [[8 + 7 * (i % 9), 4 + (5 * i) % 13]
-                        for i in range(16)]
-        mix.update(ramp_s=1, drain_s=5, trace_s=1)
+def cells_of_kind(kind):
+    return [name for name in CELLS if Cell(name).config["kind"] == kind]
+
+
+def cut_train(config, mix):
+    config["train"]["loss_tolerance"] = 0.02  # bf16 on 64 tokens a batch
+    mix.update(seq=32, trace_s=1)
+
+
+def cut_serve(config, mix):
+    config["serve"].update(max_batch_size=4, max_seq_len=128,
+                           reference_prompt_lens=[16, 12, 7, 3],
+                           reference_decode_steps=3,
+                           logit_tolerance=0.05,  # bf16 at hidden 64
+                           probe_prompt_lens=[9, 7, 5, 3], probe_total=12,
+                           served_token_margin=0.05)
+    mix["pairs"] = [[8 + 7 * (i % 9), 4 + (5 * i) % 13] for i in range(16)]
+    mix.update(ramp_s=1, drain_s=5, trace_s=1)
+
+
+# By the configuration's `kind`: the plan and the traffic cut to what the
+# model's debug widths hold.
+CUTS = {"train": cut_train, "serve": cut_serve}
+
+
+def debug_cell(cell):
+    """The cell with its model cut to debug widths by its family's
+    adapter and its plan and traffic to lengths those hold. Never a
+    benchmark configuration: a test's."""
+    cell.config = model_adapter(cell.config).debug(cell.config)
+    CUTS[cell.config["kind"]](cell.config, cell.traffic)
     return cell
 
 
@@ -63,9 +72,9 @@ def drive(cell, tmp_path, seconds):
                  for g in ("end_to_end", "per_layer")}
 
 
-@pytest.mark.parametrize("name", [n for n in CELLS if n.startswith("train")])
+@pytest.mark.parametrize("name", cells_of_kind("train"))
 def test_training_cell_at_debug_width(name, tmp_path):
-    cell = debug_cell(name)
+    cell = debug_cell(Cell(name))
     run, metrics = drive(cell, tmp_path, seconds=1.5)
     assert all(run["checks"].values()), run["log"]
     assert run["failed"] == 0 and run["attempted"] == run["steps"] >= 8
@@ -84,9 +93,9 @@ def test_training_cell_at_debug_width(name, tmp_path):
     assert 0 <= per["ingest.wait_share"]["value"] < 100
 
 
-@pytest.mark.parametrize("name", [n for n in CELLS if n.startswith("serve")])
+@pytest.mark.parametrize("name", cells_of_kind("serve"))
 def test_serving_cell_at_debug_width(name, tmp_path):
-    cell = debug_cell(name)
+    cell = debug_cell(Cell(name))
     run, metrics = drive(cell, tmp_path, seconds=3.0)
     assert all(run["checks"].values()), run["log"]
     assert run["failed"] == 0 and run["attempted"] >= 5
