@@ -1,18 +1,38 @@
-"""Mixture-of-Experts Llama variant: expert parallelism over the
-``expert`` mesh axis.
+"""Mixture-of-Experts decoder: the Llama block with its feed-forward
+replaced by `n_experts` SwiGLU experts, of which every token uses the
+`n_experts_per_token` its router scores highest. Two published models
+run through it: Mixtral-8x7B (8 experts, 2 a token, gates renormalised
+over the chosen) and OLMoE-1B-7B (64 experts, 8 a token, gates as the
+softmax gives them, RMSNorm on the projected q and k); `MoEConfig`
+holds what they differ in.
 
-No reference equivalent (the reference has no model code); this exists so
-EP is a first-class, exercised parallelism axis (SURVEY.md §2 parallelism
-inventory calls EP "absent entirely" upstream — our charter adds it).
+The expert layer is dropless sparse dispatch (`_moe_ffn`): float32
+softmax over the router's logits, `lax.top_k` (exactly k experts a
+token, ties to the lower index), the Switch-Transformer load-balancing
+loss, then the (token, expert) pairs sorted by expert, the tokens
+gathered into that order, the three SwiGLU products as grouped matmuls
+(`lax.ragged_dot`, which the TPU compiler turns into a grouped-matmul
+kernel of its own) whose group sizes are known only on the device, and
+a gate-weighted sum back in token order. (Megablox's Pallas `gmm` ran
+the products a third faster on the v5e, but tracing its group metadata
+for every call adds 2.4 to 4 s to a warm process's first step: PERF.md,
+PR 27.) Every pair is computed whatever the load of its expert: no
+capacity, nothing dropped, static shapes ([tokens * k, d] rows in all).
+Both permutations are row gathers in the forward and in the backward
+pass (`_spread` and `_collect` are each other's transpose), never a
+scatter.
 
-Routing: top-k softmax gating with a load-balancing auxiliary loss
-(Switch-Transformer style). Dispatch is the dense-masked formulation:
-every expert runs over all tokens with gates zeroing non-selected
-contributions — compute-redundant by factor E/k but perfectly shardable
-by GSPMD over the expert axis (each device computes only its local
-experts; token activations stay put; one psum combines). The
-capacity-based sparse dispatch (all-to-all) is the planned optimization
-once the EP axis spans real slices.
+On a mesh tokens stay on the chip that holds them: dispatch, the
+grouped matmuls and the combine run under `shard_map` over the batch
+and sequence axes, each shard on its own tokens with every expert's
+weights gathered at the edge (the bytes FSDP moves anyway). A mesh with
+an `expert` axis gives the right result the same way, every chip
+computing all experts for its tokens; making that fast needs an
+all-to-all of tokens and is not here.
+
+No reference equivalent (the reference has no model code); this exists
+so sparse experts are a first-class, exercised layer (SURVEY.md §2
+parallelism inventory calls EP "absent entirely" upstream).
 """
 
 from __future__ import annotations
@@ -30,13 +50,16 @@ from ray_tpu.models.llama import (
     _attention,
     _embed_lookup,
     _init_layer,
+    _vocab_sharded,
 )
-from ray_tpu.ops.cross_entropy import softmax_cross_entropy
+from ray_tpu.ops.cross_entropy import (fused_linear_cross_entropy,
+                                       softmax_cross_entropy)
 from ray_tpu.ops.norms import rms_norm_reference
 from ray_tpu.ops.rope import (apply_rope, rope_frequencies,
                               rope_from_positions)
 from ray_tpu.parallel.sharding import (
     DEFAULT_RULES,
+    logical_to_mesh_axes,
     tree_shardings,
     with_logical_constraint,
 )
@@ -47,6 +70,12 @@ class MoEConfig(LlamaConfig):
     n_experts: int = 8
     n_experts_per_token: int = 2
     aux_loss_coeff: float = 0.01
+    # Divide a token's k gates by their sum (Mixtral). OLMoE weighs its
+    # experts by the softmax over all of them as it is.
+    norm_topk_prob: bool = True
+    # RMSNorm with a learned weight over the whole projected q and k
+    # vectors (all heads together), before rope (OLMoE).
+    qk_norm: bool = False
 
     @staticmethod
     def debug_moe() -> "MoEConfig":
@@ -62,6 +91,14 @@ class MoEConfig(LlamaConfig):
                          rope_theta=1e6, n_experts=8,
                          n_experts_per_token=2)
 
+    @staticmethod
+    def olmoe_1b_7b() -> "MoEConfig":
+        return MoEConfig(vocab_size=50304, dim=2048, n_layers=16,
+                         n_heads=16, n_kv_heads=16, hidden_dim=1024,
+                         max_seq_len=4096, rope_theta=1e4, norm_eps=1e-5,
+                         n_experts=64, n_experts_per_token=8,
+                         norm_topk_prob=False, qk_norm=True)
+
 
 def _init_moe_layer(cfg: MoEConfig, key) -> Dict[str, Any]:
     base = _init_layer(cfg, key)
@@ -75,6 +112,9 @@ def _init_moe_layer(cfg: MoEConfig, key) -> Dict[str, Any]:
     base["we1"] = init(k1, (e, d, h), cfg.dtype)
     base["we3"] = init(k2, (e, d, h), cfg.dtype)
     base["we2"] = init(k3, (e, h, d), cfg.dtype) * (h ** -0.5)
+    if cfg.qk_norm:
+        base["q_norm"] = jnp.ones(cfg.n_heads * cfg.head_dim, cfg.dtype)
+        base["k_norm"] = jnp.ones(cfg.n_kv_heads * cfg.head_dim, cfg.dtype)
     return base
 
 
@@ -94,6 +134,15 @@ def init_moe_params(cfg: MoEConfig, rng) -> Dict[str, Any]:
     return params
 
 
+# Logical axes of the three expert matrices without the layer axis: how
+# they are sharded where they enter the expert layer's shard_map.
+_EXPERT_AXES = {
+    "we1": ("expert", "embed", "mlp"),
+    "we3": ("expert", "embed", "mlp"),
+    "we2": ("expert", "mlp", "embed"),
+}
+
+
 def moe_param_logical_axes(cfg: MoEConfig) -> Dict[str, Any]:
     layer = {
         "attn_norm": (None, "norm"),
@@ -103,10 +152,11 @@ def moe_param_logical_axes(cfg: MoEConfig) -> Dict[str, Any]:
         "wo": (None, "heads", "head_dim", "embed"),
         "mlp_norm": (None, "norm"),
         "router": (None, "embed", None),
-        "we1": (None, "expert", "embed", "mlp"),
-        "we3": (None, "expert", "embed", "mlp"),
-        "we2": (None, "expert", "mlp", "embed"),
+        **{name: (None, *axes) for name, axes in _EXPERT_AXES.items()},
     }
+    if cfg.qk_norm:
+        layer["q_norm"] = (None, "norm")
+        layer["k_norm"] = (None, "norm")
     axes = {
         "embed": ("vocab", "embed"),
         "layers": layer,
@@ -124,38 +174,138 @@ def init_moe_params_sharded(cfg: MoEConfig, mesh, rng,
                    out_shardings=shardings)(rng)
 
 
+# -- the expert layer ---------------------------------------------------------
+#
+# A token's k choices are the pairs t*k .. t*k + k-1. `order` lists the
+# pairs sorted by expert (stable, so an expert's tokens stay in token
+# order) and `inv` is its inverse: pair p sits in row inv[p].
+
+
+def _rows(x, idx):
+    return x.at[idx].get(mode="promise_in_bounds")
+
+
+@jax.custom_vjp
+def _spread(x, order, inv):
+    """x [T, D] -> [T*k, D]: each pair's token, in expert order."""
+    return _rows(x, order // (order.size // x.shape[0]))
+
+
+@jax.custom_vjp
+def _collect(y, gates, order, inv):
+    """y [T*k, D] in expert order, gates [T, k] float32 -> [T, D]: every
+    token's k rows, weighted by its gates, summed in float32."""
+    t, k = gates.shape
+    return jnp.einsum("tkd,tk->td", _rows(y, inv).reshape(t, k, -1), gates,
+                      preferred_element_type=jnp.float32).astype(y.dtype)
+
+
+# Each is the other's transpose, so the backward pass is row gathers too
+# (autodiff would transpose a gather into a scatter-add).
+def _spread_bwd(res, g):
+    order, inv, t = res
+    summed = _rows(g, inv).reshape(t, order.size // t, -1).sum(
+        1, dtype=jnp.float32)
+    return summed.astype(g.dtype), None, None
+
+
+def _collect_bwd(res, g):
+    y, gates, order, inv = res
+    g_rows = _rows(g, order // gates.shape[1])  # each pair's token's
+    d_gates = _rows(jnp.einsum("pd,pd->p", g_rows, y,
+                               preferred_element_type=jnp.float32), inv)
+    d_y = g_rows * _rows(gates.reshape(-1), order)[:, None]
+    return (d_y.astype(y.dtype), d_gates.reshape(gates.shape), None, None)
+
+
+_spread.defvjp(lambda x, order, inv: (_spread(x, order, inv),
+                                      (order, inv, x.shape[0])),
+               _spread_bwd)
+_collect.defvjp(lambda y, gates, order, inv: (_collect(y, gates, order, inv),
+                                              (y, gates, order, inv)),
+                _collect_bwd)
+
+
+def _expert_counts(top_i, n_experts):
+    """How many of the pairs chose each expert: [E] int32."""
+    hits = top_i[..., None] == jnp.arange(n_experts, dtype=top_i.dtype)
+    return hits.sum(tuple(range(top_i.ndim)), dtype=jnp.int32)
+
+
+def _sparse_experts(x, gates, top_i, we1, we3, we2):
+    """The chosen experts of the tokens at hand. x [T, D], gates and
+    top_i [T, k], weights [E, ...] -> [T, D]."""
+    with jax.named_scope("moe_dispatch"):
+        order = jnp.argsort(top_i.reshape(-1), stable=True)
+        inv = jnp.argsort(order)
+        group_sizes = _expert_counts(top_i, we1.shape[0])
+        xs = _spread(x, order, inv)                        # [T*k, D]
+    with jax.named_scope("expert_matmul"):
+        hidden = jax.nn.silu(lax.ragged_dot(xs, we1, group_sizes)) \
+            * lax.ragged_dot(xs, we3, group_sizes)         # [T*k, F]
+        ys = lax.ragged_dot(hidden, we2, group_sizes)      # [T*k, D]
+    with jax.named_scope("moe_combine"):
+        return _collect(ys, gates, order, inv)
+
+
 def _moe_ffn(cfg: MoEConfig, lp, x, mesh, rules):
-    """x: [B, S, D] → ([B, S, D], aux_loss scalar)."""
+    """x: [B, S, D] -> ([B, S, D], aux loss scalar, pairs routed to each
+    expert [E] int32)."""
     b, s, d = x.shape
-    logits = jnp.einsum("bsd,de->bse", x, lp["router"]).astype(jnp.float32)
-    probs = jax.nn.softmax(logits, axis=-1)            # [B, S, E]
     k = cfg.n_experts_per_token
-    topk_vals, _ = lax.top_k(probs, k)
-    threshold = topk_vals[..., -1:]
-    gates = jnp.where(probs >= threshold, probs, 0.0)
-    gates = gates / jnp.maximum(gates.sum(-1, keepdims=True), 1e-9)
-    gates = gates.astype(cfg.dtype)                    # [B, S, E]
+    with jax.named_scope("router"):
+        logits = jnp.einsum("bsd,de->bse", x,
+                            lp["router"]).astype(jnp.float32)
+        probs = jax.nn.softmax(logits, axis=-1)            # [B, S, E]
+        gates, top_i = lax.top_k(probs, k)                 # [B, S, k]
+        if cfg.norm_topk_prob:
+            gates = gates / gates.sum(-1, keepdims=True)
+        counts = _expert_counts(top_i, cfg.n_experts)
+        # Load-balance aux loss: E * sum_e (share of the tokens that
+        # chose e) * (mean router probability of e).
+        aux = cfg.n_experts * jnp.sum(
+            counts / (b * s) * probs.mean(axis=(0, 1)))
 
-    # Load-balance aux loss: E * Σ_e fraction_tokens_e · mean_prob_e.
-    token_frac = (gates > 0).astype(jnp.float32).mean(axis=(0, 1))
-    prob_frac = probs.mean(axis=(0, 1))
-    aux = cfg.n_experts * jnp.sum(token_frac * prob_frac)
+    def experts(x, gates, top_i, *ws):
+        """On the tokens at hand: the whole batch, or one shard's."""
+        t = x.shape[0] * x.shape[1]
+        out = _sparse_experts(x.reshape(t, d), gates.reshape(t, k),
+                              top_i.reshape(t, k), *ws)
+        return out.reshape(x.shape)
 
-    # Dense-masked expert computation, sharded over the expert axis.
-    gate_x = jnp.einsum("bsd,edf->ebsf", x, lp["we1"])
-    up_x = jnp.einsum("bsd,edf->ebsf", x, lp["we3"])
-    hidden = jax.nn.silu(gate_x) * up_x                # [E, B, S, F]
-    hidden = with_logical_constraint(hidden, "expert", "batch", "seq",
-                                     "mlp", mesh=mesh, rules=rules)
-    per_expert = jnp.einsum("ebsf,efd->ebsd", hidden, lp["we2"])
-    out = jnp.einsum("ebsd,bse->bsd", per_expert,
-                     gates.transpose(0, 1, 2))
-    return out, aux
+    weights = [lp[name] for name in _EXPERT_AXES]
+    if mesh is None:
+        return experts(x, gates, top_i, *weights), aux, counts
+
+    # Each shard of the batch (and of the sequence) dispatches its own
+    # tokens; the expert matrices come in as they are sharded and are
+    # gathered whole inside, so that their gradients leave through the
+    # matching reduce-scatter.
+    w_specs = [logical_to_mesh_axes(axes, rules)
+               for axes in _EXPERT_AXES.values()]
+    tok = logical_to_mesh_axes(("batch", "seq", None), rules)
+    out = jax.shard_map(
+        lambda x, gates, top_i, *ws: experts(
+            x, gates, top_i,
+            *[_gather_whole(w, spec) for w, spec in zip(ws, w_specs)]),
+        mesh=mesh, in_specs=(tok, tok, tok, *w_specs), out_specs=tok,
+        check_vma=False)(x, gates, top_i, *weights)
+    return out, aux, counts
 
 
-def moe_forward(params, tokens, cfg: MoEConfig, *, mesh=None,
-                rules=DEFAULT_RULES, positions=None):
-    """Returns (logits [B,S,V], total aux loss)."""
+def _gather_whole(w, spec):
+    """Inside shard_map: the whole of an array that came in split as
+    `spec` says."""
+    for dim, axes in enumerate(spec):
+        if axes is not None:
+            w = lax.all_gather(w, axes, axis=dim, tiled=True)
+    return w
+
+
+def moe_forward_hidden(params, tokens, cfg: MoEConfig, *, mesh=None,
+                       rules=DEFAULT_RULES, positions=None):
+    """tokens [B, S] -> (final-norm hidden states [B, S, D], the layers'
+    mean aux loss, pairs routed per layer and expert [L, E] int32)."""
     # Same SPMD hygiene as llama.forward: explicit positions → elementwise
     # cos/sin sharded with the activations (no table gather), and the
     # embed table size-gated replicated/sharded before the token gather
@@ -183,6 +333,9 @@ def moe_forward(params, tokens, cfg: MoEConfig, *, mesh=None,
             q = jnp.einsum("bsd,dhk->bshk", h, lp["wq"])
             k_ = jnp.einsum("bsd,dhk->bshk", h, lp["wk"])
             v = jnp.einsum("bsd,dhk->bshk", h, lp["wv"])
+            if cfg.qk_norm:
+                q = _norm_all_heads(q, lp["q_norm"], cfg.norm_eps)
+                k_ = _norm_all_heads(k_, lp["k_norm"], cfg.norm_eps)
             q = apply_rope(q, cos, sin, positions)
             k_ = apply_rope(k_, cos, sin, positions)
             attn = _attention(cfg, q, k_, v, mesh, rules)
@@ -190,33 +343,67 @@ def moe_forward(params, tokens, cfg: MoEConfig, *, mesh=None,
                                lp["wo"])
         with jax.named_scope("mlp"):
             h2 = rms_norm_reference(x, lp["mlp_norm"], cfg.norm_eps)
-            ffn_out, aux = _moe_ffn(cfg, lp, h2, mesh, rules)
+            ffn_out, aux, counts = _moe_ffn(cfg, lp, h2, mesh, rules)
             x = x + ffn_out
         x = with_logical_constraint(x, "batch", "seq", "act_embed",
                                     mesh=mesh, rules=rules)
-        return (x, aux_acc + aux), None
+        return (x, aux_acc + aux), counts
 
     body = layer
     if cfg.remat:
         body = jax.checkpoint(
             layer, policy=jax.checkpoint_policies.nothing_saveable)
-    (x, aux_total), _ = lax.scan(body, (x, jnp.zeros((), jnp.float32)),
-                                 params["layers"])
+    (x, aux_total), expert_tokens = lax.scan(
+        body, (x, jnp.zeros((), jnp.float32)), params["layers"])
     x = rms_norm_reference(x, params["final_norm"], cfg.norm_eps)
+    return x, aux_total / cfg.n_layers, expert_tokens
+
+
+def _norm_all_heads(x, weight, eps):
+    """RMSNorm of [B, S, H, K] over heads and head size together."""
+    b, s, h, k = x.shape
+    return rms_norm_reference(x.reshape(b, s, h * k), weight,
+                              eps).reshape(b, s, h, k)
+
+
+def moe_forward(params, tokens, cfg: MoEConfig, *, mesh=None,
+                rules=DEFAULT_RULES, positions=None):
+    """Returns (logits [B,S,V], total aux loss)."""
+    x, aux, _ = moe_forward_hidden(params, tokens, cfg, mesh=mesh,
+                                   rules=rules, positions=positions)
     out_w = params["embed"].T if cfg.tie_embeddings else params["out"]
     logits = jnp.einsum("bsd,dv->bsv", x, out_w.astype(cfg.dtype))
-    return logits, aux_total / cfg.n_layers
+    return logits, aux
 
 
 def moe_loss_fn(params, batch, cfg: MoEConfig, *, mesh=None,
                 rules=DEFAULT_RULES):
-    logits, aux = moe_forward(params, batch["tokens"], cfg, mesh=mesh,
-                              rules=rules,
-                              positions=batch.get("positions"))
-    b, s, v = logits.shape
+    """Mean cross-entropy plus `aux_loss_coeff` times the load-balancing
+    loss. The metrics carry the step's routing: `expert_tokens` [L, E],
+    the pairs sent to each expert of each layer, and under `span_attrs`
+    (what `make_train_step` puts on its dispatch span) the busiest
+    expert's count and the mean."""
+    x, aux, expert_tokens = moe_forward_hidden(
+        params, batch["tokens"], cfg, mesh=mesh, rules=rules,
+        positions=batch.get("positions"))
+    b, s, d = x.shape
+    out_w = (params["embed"].T if cfg.tie_embeddings
+             else params["out"]).astype(cfg.dtype)
+    targets = batch["targets"].reshape(b * s)
     with jax.named_scope("loss"):
-        losses = softmax_cross_entropy(
-            logits.reshape(b * s, v), batch["targets"].reshape(b * s))
+        if cfg.fused_ce and not _vocab_sharded(mesh, rules):
+            # As llama.loss_fn: the [tokens, vocab] logits never exist.
+            losses = fused_linear_cross_entropy(
+                x.reshape(b * s, d), out_w, targets)
+        else:
+            logits = jnp.einsum("bsd,dv->bsv", x, out_w)
+            losses = softmax_cross_entropy(
+                logits.reshape(b * s, cfg.vocab_size), targets)
         ce = losses.mean()
         loss = ce + cfg.aux_loss_coeff * aux
-    return loss, {"loss": loss, "ce_loss": ce, "aux_loss": aux}
+    return loss, {"loss": loss, "ce_loss": ce, "aux_loss": aux,
+                  "expert_tokens": expert_tokens,
+                  "span_attrs": {
+                      "expert_tokens_max": expert_tokens.max(),
+                      "expert_tokens_mean": expert_tokens.sum()
+                      // expert_tokens.size}}

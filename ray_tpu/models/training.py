@@ -105,7 +105,10 @@ def make_train_step(
     backward pass (`transpose(jvp(...))`), the models scope their loss
     (`loss`), and the update is scoped `optimizer` here. On a mesh the
     host's two halves are `critical_path` spans: `train.place_batch`
-    and `train.step_dispatch`.
+    and `train.step_dispatch`. Where the loss's metrics hold a dict
+    `span_attrs` of scalars (the expert layer's routing counts, in
+    `models/moe.py`), the dispatch span carries as attributes those of
+    the last step that has finished.
     """
 
     def step_fn(state: TrainState, batch):
@@ -137,12 +140,26 @@ def make_train_step(
             else jax.device_put(x, s),
             batch, shardings)
 
+    pending = {}  # `span_attrs` of the step dispatched last, on the device
+
+    def finished_attrs():
+        """The last step's `span_attrs`, if that step has finished:
+        nothing here waits for the device."""
+        if critical_path.enabled() and pending and all(
+                a.is_ready() for a in pending.values()):
+            return {k: v.item() for k, v in jax.device_get(pending).items()}
+        return {}
+
     @functools.wraps(step_fn)
     def wrapper(state, batch):
         with critical_path.span("train.place_batch"):
             batch = place(batch)
-        with critical_path.span("train.step_dispatch"):
-            return jitted(state, batch)
+        with critical_path.span("train.step_dispatch",
+                                **finished_attrs()):
+            state, metrics = jitted(state, batch)
+        pending.clear()
+        pending.update(metrics.get("span_attrs", {}))
+        return state, metrics
 
     # Like the mesh-less return value, the step can be lowered without
     # running it (to read the compiled program or its memory analysis).

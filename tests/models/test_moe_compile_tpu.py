@@ -1,0 +1,60 @@
+"""The expert layer compiled for a described v5e at the two benchmark
+configurations' real shapes, without the chip: the TPU's compiler
+refuses here what it would refuse there (a grouped matmul it cannot
+tile, a program it cannot fit). Nothing runs and no time
+is read. The topology is described inside a fixture, never at import
+(one process at a time may load the TPU's library)."""
+
+import json
+import pathlib
+import re
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from ray_tpu.models import moe
+
+# The grouped product's names, kept in one place: the benchmark's
+# reader of the kernel's roofline finds it in a trace by the same list.
+PRODUCTS = json.loads((pathlib.Path(__file__).parents[2] / "benchmark"
+                       / "metrics" / "kernel.grouped_matmul_roofline.json"
+                       ).read_text())["args"]["products"]
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.mark.parametrize("tokens,k,d,f,e", [
+    (16384, 8, 2048, 1024, 64),   # OLMoE-1B-7B, 4 x 4096 tokens a chip
+    (4096, 2, 4096, 14336, 8),    # Mixtral-8x7B, 4096 tokens a chip
+], ids=["olmoe", "mixtral"])
+def test_expert_layer_compiles_for_the_v5e(one_chip, tokens, k, d, f, e):
+    def shape(*dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    def loss(x, gates, top_i, we1, we3, we2):
+        out = moe._sparse_experts(x, gates, top_i, we1, we3, we2)
+        return out.astype(jnp.float32).sum()
+
+    compiled = jax.jit(jax.grad(loss, argnums=(0, 1, 3, 4, 5))).lower(
+        shape(tokens, d), shape(tokens, k, dtype=jnp.float32),
+        shape(tokens, k, dtype=jnp.int32), shape(e, d, f), shape(e, d, f),
+        shape(e, f, d)).compile()
+    text = compiled.as_text()
+    # Forward 3 grouped products, backward 6: each the compiler's
+    # grouped-matmul kernel, not a dense product over every expert.
+    kernels = re.findall(r"%([\w-]+?)(?:\.\d+)? = \S+ custom-call\(", text)
+    assert sum(k in PRODUCTS for k in kernels) == 9
+    # The permutations are row gathers in both directions: no scatter
+    # of rows (the kernel's own group metadata scatters into vectors).
+    assert not re.search(r"\[\d+,\d+\]\S* scatter\(", text)
