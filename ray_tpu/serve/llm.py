@@ -23,7 +23,9 @@ generation stall).
 Prefix/KV cache (PR 16): full ``llm_kv_block_tokens``-sized chunks of
 every admitted prompt are hash-chained into the
 :class:`~ray_tpu._private.kv_cache.PrefixCache` decision core, with the
-block KV payloads read back off-device into a host store. A later
+block KV payloads read back off-device into a host store (one
+asynchronous gather a request, finished on the host while a decode
+block runs: "prefix/KV cache" below). A later
 request sharing the prompt head copies the matched blocks straight into
 its slot's KV region and prefills ONLY the tail at the tail's bucket —
 the shared-head prefill compute (the dominant pre-first-token cost on a
@@ -42,6 +44,7 @@ the slot shed point.
 
 from __future__ import annotations
 
+import collections
 import dataclasses
 import hashlib
 import itertools
@@ -110,6 +113,11 @@ class ModelSwapDeadlineError(RuntimeError):
 # lax.top_k needs a static k: per-slot top_k values are clamped to this.
 _TOP_K_MAX = 64
 
+# A read-back's gather hands its rows over in arrays of at most this
+# many bytes: the TPU runtime copies an array of 32 MiB to the host in
+# 35 ms and one of 28 MiB in 5 (v5e, jax 0.9.0; PERF.md section 6, PR 28).
+_D2H_ARRAY_BYTES = 16 << 20
+
 
 @dataclasses.dataclass
 class SamplingParams:
@@ -139,6 +147,19 @@ class _Request:
     trace_id: str = ""
     t_kv_done: float = 0.0
     t_prefill_done: float = 0.0
+
+
+@dataclasses.dataclass
+class _Readback:
+    """One admitted request's newly created blocks on their way to the
+    host: the slot's rows [start, start + rows) as device arrays whose
+    copy to the host has been started (`_read_rows_impl`'s parts, K's
+    then V's), and the handles (by block id) whose payload is still
+    wanted."""
+    arrays: tuple
+    start: int
+    handles: Dict[int, Any]
+    nbytes: int
 
 
 class LLMEngine:
@@ -181,12 +202,14 @@ class LLMEngine:
         self._dev_last = None
         self._dev_lengths = None
         # Running totals of what the loop's spans count at the same
-        # seams (`metrics()["totals"]`); only the loop's thread adds.
+        # seams (`metrics()["totals"]`); only the loop's thread adds
+        # (and, to `kv_readbacks_forced`, whoever forces a read-back).
         self._totals = dict.fromkeys((
             "decode_steps", "active_slot_steps", "tokens_kept",
             "tokens_discarded", "prefill_tokens_real",
             "prefill_tokens_bucketed", "admit_waves", "admissions",
-            "kv_blocks_read_back", "kv_bytes_read_back"), 0)
+            "kv_blocks_read_back", "kv_bytes_read_back",
+            "kv_readbacks_deferred", "kv_readbacks_forced"), 0)
 
         # Compiled programs. Prefill is per-slot (batch 1, bucketed T);
         # decode covers all slots at T=1. Params are explicit arguments —
@@ -250,12 +273,28 @@ class LLMEngine:
         self._chain_seed = self._seed_for(model)
         self._c_shm_offloads = perf_stats.counter("llm_kv_shm_offloads")
         self._c_shm_restores = perf_stats.counter("llm_kv_shm_restores")
-        # Per-block KV copy-in/read-back programs (fixed [L, B, Hkv, D]
-        # block shape, traced slot/offset → exactly one compiled
-        # program each, touched at warmup).
-        self._read_block_j = jax.jit(
-            self._read_block_impl,
-            in_shardings=(s1, s1, s1), out_shardings=(s1, s1))
+        # Read-backs on their way to the host, oldest first, and which
+        # of them holds a block's payload. Their device arrays may hold
+        # `_readback_cap` bytes together, an eighth of the slot cache
+        # and never less than one slot's rows, the largest one request
+        # can need (268 MB at 32 slots x 1024 of Mistral-7B's 64 KB a
+        # token: eight median prompts at bucket 512); at the cap the
+        # oldest is waited for. The lock is for `stop()` and
+        # `swap_params`, which force them from another thread.
+        self._readbacks: "collections.deque[_Readback]" = \
+            collections.deque()
+        self._readback_of: Dict[int, _Readback] = {}
+        self._readback_bytes = 0
+        self._readback_cap = per_token * self.max_seq * max(
+            1, self.n_slots // 8)
+        self._readback_lock = threading.Lock()
+        # KV copy programs: read-back takes `rows` (static: one program
+        # a prefill bucket, compiled by warmup) of a slot's region from
+        # a traced offset; copy-in writes one [L, B, Hkv, D] block.
+        self._read_rows_j = jax.jit(
+            self._read_rows_impl, static_argnums=(3,),
+            in_shardings=(s1, s1, s1), out_shardings=s1)
+        self._read_rows_exec: Dict[int, Any] = {}
         self._write_block_j = jax.jit(
             self._write_block_impl, donate_argnums=(0,),
             in_shardings=(s1, s1, s1, s1, s1), out_shardings=s1)
@@ -301,14 +340,16 @@ class LLMEngine:
             tokens = jnp.zeros((1, bucket), jnp.int32)
             self.cache, last = self._run_prefill(
                 tokens, jnp.int32(0), jnp.int32(1), jnp.int32(0), bucket)
-        if self.prefix_cache is not None \
-                and self.block_tokens <= self.max_seq:
-            # Touch the per-block KV copy programs so the first cache
-            # hit/readback doesn't pay a mid-serving compile.
-            kb, vb = self._read_block_j(
-                self.cache, jnp.int32(0), jnp.int32(0))
+        if self.prefix_cache is not None:
+            # Touch the KV copy programs so the first cache hit or
+            # read-back doesn't pay a mid-serving compile.
+            for rows in sorted(self._read_rows_exec):
+                self._run_read_rows(0, 0, rows)
+            k = self.cache["k"]
+            kb = jnp.zeros((k.shape[0], self.block_tokens) + k.shape[3:],
+                           k.dtype)
             self.cache = self._write_block_j(
-                self.cache, kb, vb, jnp.int32(0), jnp.int32(0))
+                self.cache, kb, kb, jnp.int32(0), jnp.int32(0))
         # Admission-wave sampling program (and its eager stack feeder).
         stacked = jnp.stack([last] * self.n_slots)
         _firsts, self._rng = self._run_sample(
@@ -359,8 +400,17 @@ class LLMEngine:
                 aval((n,), _jnp.float32), rng_aval)
             return "sample", lowered.compile()
 
+        def compile_read_rows(rows):
+            lowered = self._read_rows_j.lower(
+                cache_avals, aval(()), aval(()), rows)
+            return ("read_rows", rows), lowered.compile()
+
         jobs = [lambda b=b: compile_prefill(b) for b in buckets]
         jobs += [compile_decode, compile_sample]
+        if self.prefix_cache is not None:
+            # A read-back takes the bucket that holds its blocks' rows.
+            jobs += [lambda b=b: compile_read_rows(b) for b in buckets
+                     if b >= self.block_tokens]
         workers = min(len(jobs), max(2, os.cpu_count() or 4))
         with ThreadPoolExecutor(max_workers=workers,
                                 thread_name_prefix="aot-compile") as pool:
@@ -369,6 +419,8 @@ class LLMEngine:
                     self._decode_exec = compiled
                 elif key == "sample":
                     self._sample_exec = compiled
+                elif isinstance(key, tuple):
+                    self._read_rows_exec[key[1]] = compiled
                 else:
                     self._prefill_exec[key] = compiled
 
@@ -394,6 +446,15 @@ class LLMEngine:
     def _run_sample(self, logits, temps):
         fn = self._sample_exec or self._sample_admitted
         return fn(logits, temps, self._rng)
+
+    def _run_read_rows(self, slot, start, rows):
+        # Host scalars ride the call: a third off the dispatch against
+        # two device scalars made first (0.79 against 1.16 ms, v5e).
+        slot, start = np.int32(slot), np.int32(start)
+        compiled = self._read_rows_exec.get(rows)
+        if compiled is not None:
+            return compiled(self.cache, slot, start)
+        return self._read_rows_j(self.cache, slot, start, rows)
 
     # -- compiled bodies -------------------------------------------------
 
@@ -425,22 +486,28 @@ class LLMEngine:
         last = logits[0, length - 1]
         return cache, last
 
-    def _read_block_impl(self, cache, slot, start):
-        """Read one `block_tokens`-sized KV block out of a slot's region
-        at token offset `start` → (k, v) each [L, B, Hkv, D]."""
-        bt = self.block_tokens
+    def _read_rows_impl(self, cache, slot, start, rows):
+        """Read `rows` tokens' KV out of a slot's region from token
+        offset `start` → K's parts, then V's, each [L/p, rows, Hkv, D]:
+        the layers in as few equal parts as keep an array within
+        `_D2H_ARRAY_BYTES`."""
+        x = cache["k"]  # [L, slots, S, Hkv, D]
+        layers = x.shape[0]
+        layer_nbytes = rows * x.shape[3] * x.shape[4] * x.dtype.itemsize
+        parts = next((p for p in range(1, layers) if layers % p == 0
+                      and layer_nbytes * layers // p <= _D2H_ARRAY_BYTES),
+                     layers)
         out = []
         for name in ("k", "v"):
-            x = cache[name]  # [L, slots, S, Hkv, D]
             blk = jax.lax.dynamic_slice(
-                x, (0, slot, start, 0, 0),
-                (x.shape[0], 1, bt, x.shape[3], x.shape[4]))
-            out.append(blk[:, 0])
+                cache[name], (0, slot, start, 0, 0),
+                (layers, 1, rows, x.shape[3], x.shape[4]))
+            out += jnp.split(blk[:, 0], parts)
         return tuple(out)
 
     def _write_block_impl(self, cache, kb, vb, slot, start):
-        """Write one KV block (shapes from `_read_block_impl`) into a
-        slot's region at token offset `start`."""
+        """Write one KV block ([L, B, Hkv, D] each) into a slot's
+        region at token offset `start`."""
         new = {}
         for name, blk in (("k", kb), ("v", vb)):
             x = cache[name]
@@ -510,6 +577,8 @@ class LLMEngine:
         if t is not None and t.is_alive() \
                 and t is not threading.current_thread():
             t.join(timeout=10)
+        # What the loop had not finished goes to the host store now.
+        self._force_readbacks()
 
     def generate(self, prompt_ids: List[int],
                  params: Optional[SamplingParams] = None,
@@ -553,17 +622,22 @@ class LLMEngine:
                 "queued": self._queue.qsize(),
                 "model": self.model,
                 # AOT executables warmup left: prefill ladder + decode
-                # + admission sampler.
+                # + admission sampler + the read-back's gathers.
                 "compiled_programs": len(self._prefill_exec)
                 + (self._decode_exec is not None)
-                + (self._sample_exec is not None),
+                + (self._sample_exec is not None)
+                + len(self._read_rows_exec),
                 # Since the engine started: decode steps run and the
                 # slot-steps of them that held a request; tokens handed
                 # to clients (first tokens and kept decode tokens) and
                 # decode tokens computed for nobody; prompt tokens
                 # prefilled and the bucket sizes paid for them; waves
                 # of admission and requests admitted; KV blocks and
-                # bytes read back for the prefix cache.
+                # bytes read back for the prefix cache (counted when
+                # the block is created), the requests whose read-back
+                # left the wave, and those of them that were waited
+                # for (a hit or an eviction of a block still on its
+                # way, the cap on pending bytes, stop, a model swap).
                 "totals": dict(self._totals),
             }
         if self.prefix_cache is not None:
@@ -587,6 +661,7 @@ class LLMEngine:
                 self._flush_pending()
                 if not admitted:
                     with critical_path.span("engine.idle_wait"):
+                        self._finish_readbacks()
                         try:
                             req = self._queue.get(timeout=0.05)
                             self._queue.put(req)
@@ -727,16 +802,15 @@ class LLMEngine:
                                                    _TOP_K_MAX))
             if self._finished(req, first):
                 self._retire(slot)
-        # Prefix-cache read-back AFTER the first-token wave (TTFT is not
-        # taxed by the host copies). Safe ordering: a slot retired above
-        # cannot be re-admitted until a LATER _admit call, so the KV
-        # bytes being read are still this request's prefill output.
-        with critical_path.span("engine.prefix_readback") as sp:
-            blocks = sum(self._prefix_admit(req, slot, chain)
-                         for req, slot, _t_real, _logits, chain in staged)
-            sp.set(blocks=blocks, bytes=blocks * self._block_nbytes)
-        totals["kv_blocks_read_back"] += blocks
-        totals["kv_bytes_read_back"] += blocks * self._block_nbytes
+        # The prefix cache admits the prompts' blocks AFTER the
+        # first-token wave (TTFT is not taxed), and here only starts
+        # their read-back: one gather and an asynchronous copy a
+        # request; `_decode_once` finishes it on the host while a decode
+        # block runs. Safe ordering: a slot retired above cannot be
+        # re-admitted until a LATER _admit call, so the KV bytes being
+        # gathered are still this request's prefill output.
+        for req, slot, _t_real, _logits, chain in staged:
+            self._prefix_admit(req, slot, chain)
         # Host state changed: rebuild device carries on the next decode.
         self._dev_last = self._dev_lengths = None
         return True
@@ -758,6 +832,10 @@ class LLMEngine:
                 last, lengths,
                 jnp.asarray(self._temps_arr),
                 jnp.asarray(self._topks_arr))
+            # The device has a block to run and the host nothing to do
+            # but wait for the one before it: the read-backs' host half.
+            self._finish_readbacks(
+                self.max_seq // self.block_tokens, self._pending_toks)
         self._totals["decode_steps"] += self.decode_steps
         self._totals["active_slot_steps"] += active * self.decode_steps
         prev, self._pending_toks = self._pending_toks, next_tokens
@@ -826,6 +904,17 @@ class LLMEngine:
     # chain key. A chain key commits to the model seed + every token of
     # the prefix, so a key hit on ANY tier is byte-identical KV by
     # construction (same weights + same tokens + causal attention).
+    #
+    # A payload reaches `_kv_store` in two halves. The wave that
+    # admitted the request creates its blocks in the core and only
+    # DISPATCHES: one gather of the rows that hold them and an
+    # asynchronous copy to the host (`_start_readback`). The loop
+    # finishes it where the host would otherwise wait for a decode
+    # block (`_finish_readbacks`): cut into per-block payloads, stored.
+    # A block exists for lookups from its admission on, as before; a
+    # payload needed while still on its way (a hit, an eviction to the
+    # shm tier, the cap on pending bytes, stop, a model swap) is waited
+    # for then and there (`_force_readbacks`) and counted.
 
     def _prefix_copy_in(self, req: _Request, slot: int, prompt):
         """Copy the longest cached prefix of `prompt` into `slot`'s KV
@@ -856,6 +945,11 @@ class LLMEngine:
         payloads = []
         for i, h in enumerate(hit):
             p = self._kv_store.get(h.block_id)
+            rb = self._readback_of.get(h.block_id)
+            if p is None and rb is not None:
+                # Admitted a moment ago, still on its way to the host.
+                self._force_readbacks(rb)
+                p = self._kv_store.get(h.block_id)
             if p is None:
                 p = self._shm_restore(h)
             if p is None:
@@ -871,22 +965,131 @@ class LLMEngine:
         return len(hit) * self.block_tokens, chain
 
     def _prefix_admit(self, req: _Request, slot: int, chain):
-        """After prefill, admit the prompt's full-block chain and read
-        the KV bytes for newly-created blocks back to the host store.
-        Runs post-first-token so TTFT never pays for the readback.
-        Returns the number of blocks read back."""
+        """After the first-token wave (TTFT never pays for it), admit
+        the prompt's full-block chain and start the read-back of the
+        blocks that created. The created blocks are pinned for just
+        that long, and what the admission evicted leaves the host store
+        at once, so the core sees the holds and the order it saw when
+        the read-back was finished here."""
         pc = self.prefix_cache
         if pc is None or not chain:
-            return 0
+            return
         created, evicted = pc.admit(chain, req.job, self._block_nbytes)
-        for h in created:
-            kb, vb = self._read_block_j(
-                self.cache, jnp.int32(slot),
-                jnp.int32(h.index * self.block_tokens))
-            self._kv_store[h.block_id] = (np.asarray(kb), np.asarray(vb))
+        if created:
+            self._start_readback(slot, created)
         pc.release(created)
         self._offload_evicted(evicted)
-        return len(created)
+
+    def _start_readback(self, slot: int, created):
+        """The wave's half of a read-back, dispatch only: ONE gather of
+        the slot's rows that hold `created` (ascending; at the prefill
+        bucket that fits them, moved down where it would overhang the
+        slot) and the start of its copy to the host. No wait, unless
+        the pending arrays stand at their cap: then for the oldest."""
+        bt = self.block_tokens
+        lo, hi = created[0].index * bt, (created[-1].index + 1) * bt
+        rows = self._serve_bucket(hi - lo)
+        start = min(lo, self.max_seq - rows)
+        nbytes = rows * self._block_nbytes // bt
+        while self._readbacks \
+                and self._readback_bytes + nbytes > self._readback_cap:
+            self._force_readbacks(self._readbacks[0])
+        blocks = len(created)
+        with critical_path.span("engine.prefix_readback", blocks=blocks,
+                                bytes=blocks * self._block_nbytes):
+            arrays = self._run_read_rows(slot, start, rows)
+            for a in arrays:
+                a.copy_to_host_async()
+            rb = _Readback(arrays, start,
+                           {h.block_id: h for h in created}, nbytes)
+            with self._readback_lock:
+                self._readbacks.append(rb)
+                self._readback_of.update(dict.fromkeys(rb.handles, rb))
+                self._readback_bytes += nbytes
+        self._totals["kv_blocks_read_back"] += blocks
+        self._totals["kv_bytes_read_back"] += blocks * self._block_nbytes
+        self._totals["kv_readbacks_deferred"] += 1
+
+    @staticmethod
+    def _readback_ready(rb: _Readback) -> bool:
+        """The gather has run (its copy to the host started behind it)."""
+        return all(a.is_ready() for a in rb.arrays)
+
+    def _complete_readback(self) -> int:
+        """The host half of the oldest read-back: its blocks' rows
+        copied out of the gathered arrays into one block-major slab for
+        K and one for V (one copy a part, not two a block: each call
+        that lets go of the interpreter may wait for it again), of
+        which `_kv_store` holds a block's [L, B, Hkv, D] views; they
+        live until the last of the request's blocks is evicted. Blocks
+        evicted meanwhile are left out. Caller holds `_readback_lock`.
+        Returns the blocks stored."""
+        rb = self._readbacks.popleft()
+        self._readback_bytes -= rb.nbytes
+        if not rb.handles:
+            return 0
+        arrays = [np.asarray(a) for a in rb.arrays]
+        bt = self.block_tokens
+        first = min(h.index for h in rb.handles.values())
+        n = max(h.index for h in rb.handles.values()) + 1 - first
+        lo = first * bt - rb.start
+        slabs = []
+        for parts in (arrays[:len(arrays) // 2], arrays[len(arrays) // 2:]):
+            tail = parts[0].shape[2:]
+            slab = np.empty((n, self.cfg.n_layers, bt) + tail,
+                            parts[0].dtype)
+            layer = 0
+            for part in parts:
+                nl = part.shape[0]
+                slab[:, layer:layer + nl] = part[:, lo:lo + n * bt].reshape(
+                    (nl, n, bt) + tail).swapaxes(0, 1)
+                layer += nl
+            slabs.append(slab)
+        for block_id, h in rb.handles.items():
+            self._kv_store[block_id] = (slabs[0][h.index - first],
+                                        slabs[1][h.index - first])
+            del self._readback_of[block_id]
+        return len(rb.handles)
+
+    def _finish_readbacks(self, max_blocks: Optional[int] = None,
+                          block=None):
+        """Finish, oldest first, the read-backs whose gather has run —
+        never a wait for the device's queue. Called where the host has
+        nothing else to do: behind a decode dispatch, where it stops
+        after `max_blocks` blocks or as soon as the decode block that
+        is fetched next (`block`) is ready, and in the idle loop."""
+        if not self._readbacks:
+            return
+        with self._readback_lock:
+            sp, blocks = None, 0
+            while self._readbacks \
+                    and (max_blocks is None or blocks < max_blocks) \
+                    and self._readback_ready(self._readbacks[0]) \
+                    and not (block is not None and block.is_ready()):
+                if sp is None:
+                    sp = critical_path.begin("engine.prefix_readback")
+                blocks += self._complete_readback()
+            if sp is not None:
+                critical_path.end(sp, blocks=blocks,
+                                  bytes=blocks * self._block_nbytes)
+
+    def _force_readbacks(self, upto: Optional[_Readback] = None):
+        """Wait for the pending read-backs, oldest first, through
+        `upto` (all of them when None), and count them as forced."""
+        with self._readback_lock:
+            if not self._readbacks:
+                return
+            with critical_path.span("engine.prefix_readback") as sp:
+                forced = blocks = 0
+                while self._readbacks:
+                    last = self._readbacks[0] is upto
+                    blocks += self._complete_readback()
+                    forced += 1
+                    if last:
+                        break
+                self._totals["kv_readbacks_forced"] += forced
+                sp.set(blocks=blocks, bytes=blocks * self._block_nbytes,
+                       forced=forced)
 
     @staticmethod
     def _shm_object_id(key: str):
@@ -927,6 +1130,13 @@ class LLMEngine:
         quota) — a later hit restores bytes instead of recomputing."""
         plane = self._shm_plane() if evicted else None
         for e in evicted:
+            rb = self._readback_of.get(e.block_id)
+            if rb is not None and plane is not None:
+                self._force_readbacks(rb)  # its bytes go to the warm tier
+            elif rb is not None:
+                with self._readback_lock:  # nobody wants them any more
+                    del rb.handles[e.block_id]
+                    del self._readback_of[e.block_id]
             payload = self._kv_store.pop(e.block_id, None)
             if plane is None or payload is None:
                 continue
@@ -944,7 +1154,9 @@ class LLMEngine:
         compiled programs take params as ARGUMENTS with unchanged avals,
         so no recompile happens — the swap is one device_put. Caller
         must have drained the engine (no active slots / queued work):
-        in-flight KV belongs to the OLD model."""
+        in-flight KV belongs to the OLD model, and what is still on its
+        way to the host store gets there first."""
+        self._force_readbacks()
         with self._lock:
             if self._active.any() or not self._queue.empty():
                 raise RuntimeError(

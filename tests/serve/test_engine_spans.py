@@ -33,8 +33,10 @@ _LOOP_SPANS = {"engine.admit_wave", "engine.decode_dispatch",
                "engine.token_fetch", "engine.consume_block",
                "engine.idle_wait"}
 _WAVE_SPANS = {"engine.flush_pending", "engine.prefix_copy_in",
-               "engine.prefill_dispatch", "engine.sample_sync",
-               "engine.prefix_readback"}
+               "engine.prefill_dispatch", "engine.sample_sync"}
+# The read-back's two halves (PR 28): the dispatch in a wave, the
+# completion under the loop's span that shadows it.
+_READBACK = "engine.prefix_readback"
 
 
 @pytest.fixture(scope="module")
@@ -124,7 +126,7 @@ def test_spans_tile_the_loop_and_totals_add_up(params, ring):
     by_name = {}
     for s in spans:
         by_name.setdefault(s["stage"], []).append(s)
-    assert set(by_name) >= (_LOOP_SPANS | _WAVE_SPANS) - \
+    assert set(by_name) >= (_LOOP_SPANS | _WAVE_SPANS | {_READBACK}) - \
         {"engine.idle_wait"}, sorted(by_name)
 
     # The loop's own spans have no parent; a wave's parts name the wave.
@@ -133,6 +135,16 @@ def test_spans_tile_the_loop_and_totals_add_up(params, ring):
         assert all(s["parent"] in waves for s in by_name[name]), name
     top = [s for s in spans if s["parent"] == 0]
     assert {s["stage"] for s in top} <= _LOOP_SPANS
+    # A read-back is dispatched in a wave and finished under a decode
+    # dispatch (or the idle wait), so the loop's tiling covers both.
+    shadows = {s["id"] for name in ("engine.decode_dispatch",
+                                    "engine.idle_wait")
+               for s in by_name.get(name, ())}
+    dispatched = [s for s in by_name[_READBACK] if s["parent"] in waves]
+    finished = [s for s in by_name[_READBACK] if s["parent"] in shadows]
+    assert len(dispatched) + len(finished) == len(by_name[_READBACK])
+    assert len(dispatched) == 10 and finished
+    assert not any("forced" in s["attrs"] for s in by_name[_READBACK])
 
     # Tiling: from the first decode step to the end of the last block
     # the loop was inside one of its spans at least 95 % of the time.
@@ -167,12 +179,24 @@ def test_spans_tile_the_loop_and_totals_add_up(params, ring):
         s["attrs"]["bucket"] for s in prefills) == 5 * 8 + 5 * 16
     assert totals["prefill_tokens_real"] <= \
         totals["prefill_tokens_bucketed"]
-    blocks = sum(s["attrs"]["blocks"]
-                 for s in by_name["engine.prefix_readback"])
+    # Every created block is counted when its read-back is dispatched,
+    # and again by the span that stores its payload.
+    blocks = sum(s["attrs"]["blocks"] for s in dispatched)
     assert totals["kv_blocks_read_back"] == blocks == 5 * 1 + 5 * 2
+    assert sum(s["attrs"]["blocks"] for s in finished) == blocks
     assert totals["kv_bytes_read_back"] == sum(
-        s["attrs"]["bytes"] for s in by_name["engine.prefix_readback"]) \
+        s["attrs"]["bytes"] for s in dispatched) \
         == blocks * engine._block_nbytes
+    assert totals["kv_readbacks_deferred"] == 10
+    assert totals["kv_readbacks_forced"] == 0
+    # After stop() nothing is on its way: every created block's payload
+    # is in the host store, in the shape the copy-in program takes.
+    assert not engine._readbacks and not engine._readback_of
+    assert engine._readback_bytes == 0
+    assert len(engine._kv_store) == blocks
+    k = engine.cache["k"]
+    assert all(kb.shape == vb.shape == (k.shape[0], 4) + k.shape[3:]
+               for kb, vb in engine._kv_store.values())
 
 
 def test_trace_carries_the_spans_on_the_rings_clock(params, ring,
@@ -207,7 +231,8 @@ def test_trace_carries_the_spans_on_the_rings_clock(params, ring,
                 seen += [(e.name, e.start_ns, dict(e.stats))
                          for e in line.events
                          if e.name.startswith("engine.")]
-    assert {name for name, _, _ in seen} == _LOOP_SPANS | _WAVE_SPANS
+    assert {name for name, _, _ in seen} == \
+        _LOOP_SPANS | _WAVE_SPANS | {_READBACK}
     # Every annotation is some ring record's twin: same name, same
     # attributes, starts within 1 ms of the record's t0.
     for name, rel_ns, stats in seen:

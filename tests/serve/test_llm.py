@@ -18,6 +18,7 @@ from ray_tpu.models.llama import (
     init_kv_cache,
     init_params,
 )
+from ray_tpu.serve import llm
 from ray_tpu.serve.llm import (
     LLMEngine,
     PromptTooLongError,
@@ -241,3 +242,188 @@ def test_multi_model_chain_seeds_never_cross_hit(model):
     assert ka and kb and not (set(ka) & set(kb))
     engine_a.stop()
     engine_b.stop()
+
+
+# -- the prefix cache's read-back, in two halves (PR 28) --------------------
+#
+# A wave only dispatches a request's read-back (one gather, a copy to
+# the host started); the loop finishes it in the shadow of a decode
+# block. These cases hold a read-back pending by patching the readiness
+# check, and look at what has to happen when its payload is needed early.
+
+
+def _never_ready(monkeypatch):
+    monkeypatch.setattr(LLMEngine, "_readback_ready",
+                        staticmethod(lambda rb: False))
+
+
+def test_hit_on_a_block_whose_readback_is_pending(model, monkeypatch):
+    """A prompt repeated while its first read-back is still on its way
+    is served from the cache all the same: the payload is waited for
+    (one forced read-back), the match is whole, and the greedy tokens
+    are those of a run without the cache."""
+    cfg, params = model
+    monkeypatch.setattr(ray_config, "llm_kv_block_tokens", 4)
+    monkeypatch.setattr(ray_config, "llm_prefix_shm_tier", False)
+    prompt = list(range(1, 18))  # 4 full blocks and a tail of one
+    monkeypatch.setattr(ray_config, "llm_prefix_cache", False)
+    plain = LLMEngine(cfg, params, max_batch_size=2, max_seq_len=64)
+    expected = plain.generate(prompt, SamplingParams(max_tokens=6))
+    plain.stop()
+
+    monkeypatch.setattr(ray_config, "llm_prefix_cache", True)
+    _never_ready(monkeypatch)
+    matched = []
+
+    class Engine(LLMEngine):
+        def _prefix_copy_in(self, req, slot, prompt):
+            m_tok, chain = super()._prefix_copy_in(req, slot, prompt)
+            matched.append(m_tok)
+            return m_tok, chain
+
+    engine = Engine(cfg, params, max_batch_size=2, max_seq_len=64)
+    first = engine.generate(prompt, SamplingParams(max_tokens=6))
+    assert len(engine._readbacks) == 1 and not engine._kv_store
+    second = engine.generate(prompt, SamplingParams(max_tokens=6))
+    totals = engine.metrics()["totals"]
+    engine.stop()
+    assert first == second == expected
+    assert matched == [0, 16]
+    assert totals["kv_readbacks_deferred"] == 1
+    assert totals["kv_readbacks_forced"] == 1
+    assert totals["kv_blocks_read_back"] == 4 == len(engine._kv_store)
+
+
+def test_evicting_a_pending_block_offloads_its_bytes(model, monkeypatch):
+    """With the shm tier on, a block evicted while its read-back is
+    pending is waited for and offloaded with the bytes a finished
+    read-back stores."""
+    from ray_tpu._private.kv_cache import chain_keys
+
+    cfg, params = model
+    monkeypatch.setattr(ray_config, "llm_kv_block_tokens", 4)
+    monkeypatch.setattr(ray_config, "llm_prefix_shm_tier", False)
+    first, second = list(range(1, 10)), list(range(20, 29))  # 2 blocks each
+
+    # The reference: the same prompt through an engine that finishes
+    # its read-backs by itself.
+    ref = LLMEngine(cfg, params, max_batch_size=1, max_seq_len=64)
+    ref.generate(first, SamplingParams(max_tokens=2))
+    ref.stop()
+    keys = chain_keys(first, 4, ref._chain_seed)
+    want = {LLMEngine._shm_object_id(key):
+            ref._kv_store[ref.prefix_cache._blocks[key].block_id]
+            for key in keys}
+
+    class Plane:
+        def __init__(self):
+            self.put = {}
+
+        def maybe_put(self, oid, payload, timeout):
+            self.put[oid] = payload
+            return True
+
+    plane = Plane()
+    monkeypatch.setattr(ray_config, "llm_prefix_cache_bytes",
+                        2 * ref._block_nbytes)
+    _never_ready(monkeypatch)
+    # This engine's gather hands its rows over a layer an array (the
+    # reference's in one), as a long prompt's does at real widths.
+    monkeypatch.setattr(llm, "_D2H_ARRAY_BYTES", 1)
+    engine = LLMEngine(cfg, params, max_batch_size=1, max_seq_len=64)
+    monkeypatch.setattr(engine, "_shm_plane", lambda: plane)
+    engine.generate(first, SamplingParams(max_tokens=2))
+    assert len(engine._readbacks) == 1 and not plane.put
+    assert len(engine._readbacks[0].arrays) == 2 * cfg.n_layers
+    engine.generate(second, SamplingParams(max_tokens=2))  # evicts `first`
+    totals = engine.metrics()["totals"]
+    assert totals["kv_readbacks_forced"] == 1
+    assert len(engine._readbacks) == 1  # `second`'s own, still pending
+    engine.stop()
+    assert set(plane.put) == set(want)
+    for oid, (k, v) in want.items():
+        np.testing.assert_array_equal(plane.put[oid][0], k)
+        np.testing.assert_array_equal(plane.put[oid][1], v)
+    # The host store holds `second`'s blocks and none of the evicted.
+    assert len(engine._kv_store) == 2 == engine.prefix_cache.stats()["blocks"]
+
+
+def test_pending_readback_bytes_stay_under_the_cap(model, monkeypatch):
+    """A burst of admissions whose read-backs never finish by
+    themselves: the engine waits for the oldest before the pending
+    arrays pass the cap, and no payload is lost."""
+    cfg, params = model
+    monkeypatch.setattr(ray_config, "llm_kv_block_tokens", 4)
+    monkeypatch.setattr(ray_config, "llm_prefix_shm_tier", False)
+    _never_ready(monkeypatch)
+    pending = []
+
+    class Engine(LLMEngine):
+        def _start_readback(self, slot, created):
+            super()._start_readback(slot, created)
+            pending.append(self._readback_bytes)
+
+    engine = Engine(cfg, params, max_batch_size=16, max_seq_len=64)
+    cap = engine._readback_cap
+    assert cap == 2 * 64 * engine._block_nbytes // 4  # two slots' rows
+    prompts = [[(11 * i + j) % 500 + 1 for j in range(18 + i)]
+               for i in range(16)]  # 4 to 8 blocks each, unshared
+    threads = [threading.Thread(
+        target=engine.generate, args=(p, SamplingParams(max_tokens=3)))
+        for p in prompts]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(timeout=120)
+    assert not any(t.is_alive() for t in threads)
+    totals = engine.metrics()["totals"]
+    assert totals["kv_readbacks_deferred"] == len(pending) == 16
+    assert max(pending) <= cap
+    assert max(pending) > cap // 2  # several were pending together
+    assert 1 <= totals["kv_readbacks_forced"] < 16
+    engine.stop()
+    assert engine._readback_bytes == 0 and not engine._readbacks
+    assert len(engine._kv_store) == totals["kv_blocks_read_back"] \
+        == sum(len(p) // 4 for p in prompts)
+
+
+def test_wave_readback_time_does_not_grow_with_blocks(model, monkeypatch):
+    """What the change is for: with a copy to the host that costs
+    10 ms a block, a prompt of 25 blocks holds its admission wave no
+    longer than a prompt of one; the copy's time lies under the decode
+    loop's spans."""
+    from ray_tpu._private import critical_path, flight_recorder
+
+    cfg, params = model
+    monkeypatch.setattr(ray_config, "llm_kv_block_tokens", 4)
+    monkeypatch.setattr(ray_config, "llm_prefix_shm_tier", False)
+    monkeypatch.setattr(ray_config, "flight_ring_size", 2048)
+    critical_path.reset()
+    flight_recorder.reset()
+
+    class SlowCopy(LLMEngine):
+        def _complete_readback(self):
+            time.sleep(0.010 * len(self._readbacks[0].handles))
+            return super()._complete_readback()
+
+    engine = SlowCopy(cfg, params, max_batch_size=1, max_seq_len=128)
+    engine.warmup(128)  # nothing compiles inside a wave
+    short, long = list(range(1, 6)), list(range(100, 201))  # 1, 25 blocks
+    engine.generate(short, SamplingParams(max_tokens=8))
+    engine.generate(long, SamplingParams(max_tokens=8))
+    engine.stop()
+    spans = [s for s in flight_recorder.local_snapshot()["spans"]
+             if s["stage"].startswith("engine.")]
+    critical_path.reset()
+    flight_recorder.reset()
+    waves = {s["id"] for s in spans if s["stage"] == "engine.admit_wave"}
+    readbacks = [s for s in spans if s["stage"] == "engine.prefix_readback"]
+    in_wave = {s["attrs"]["blocks"]: s["dur_s"] for s in readbacks
+               if s["parent"] in waves}
+    outside = {s["attrs"]["blocks"]: s["dur_s"] for s in readbacks
+               if s["parent"] not in waves}
+    assert set(in_wave) == set(outside) == {1, 25}
+    assert outside[25] >= 0.25 and outside[1] >= 0.01
+    # The wave's half is a dispatch: far under the copy's 250 ms, and
+    # within a few milliseconds of the short prompt's.
+    assert in_wave[25] < 0.05 and in_wave[25] < in_wave[1] + 0.02

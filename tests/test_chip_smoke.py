@@ -55,7 +55,9 @@ def test_phases_at_debug_size_on_cpu_mesh():
     # Off the TPU "auto" is the reference: no Mosaic call in the program.
     assert train["attention_calls"] == {}
     assert train["compiled"]["programs"] > 0
-    assert serve["compiled_programs"] == 8 + 2  # buckets 1..128, decode, sampler
+    # Buckets 1..128, decode, sampler, and the prefix cache's read-back
+    # gathers at the buckets that hold a 16-token block (16..128).
+    assert serve["compiled_programs"] == 8 + 2 + 4
     assert serve["requests"] == 5 and serve["tokens"] == 20
     json.dumps({"train": train, "serve": serve})  # the summary line holds
 
