@@ -7,9 +7,15 @@ rules. The reference has no model zoo of its own (it wraps torch modules);
 these exist because the TPU framework's Train/Serve/RL layers need
 first-class compiled models to schedule.
 
-- ``llama`` — Llama-3-family decoder LM (GQA, RoPE, SwiGLU), the flagship
+- ``decoder`` — the one decoder stack (block, layer scan, parameter
+  skeleton, output head, loss tail), handed a sequence mixer and an FFN
+- ``llama`` — Llama-3-family decoder LM (GQA, RoPE, SwiGLU), the flagship:
+  `decoder` with self-attention or attention through the slot cache
+- ``moe``   — sparse-expert decoder (Mixtral, OLMoE): `decoder` with
+  `llama`'s self-attention and the dropless expert layer
 - ``mlp``   — small MLP classifier (the fashion-MNIST baseline workload)
 - ``training`` — TrainState + sharded train-step factory
+- ``hf``, ``memory_plan`` — Hugging Face weight import; HBM planning
 """
 
 from ray_tpu.models.llama import (  # noqa: F401

@@ -1,0 +1,224 @@
+"""The decoder stack that every architecture under `models/` composes.
+
+A block is norm, *sequence mixer*, residual, norm, *FFN*, residual
+(`block`, the only place that opens the `attn` and `mlp` scopes). What
+differs between architectures is handed in as two functions:
+
+- ``mixer(h, lp, rope, state) -> (attn [B, S, H, K], state)``: normed
+  activations and the layer's parameters to the attention output before
+  the `wo` projection. `rope` is the stack's `(cos, sin)`; `state` is
+  what the mixer carries per layer (a slot cache's K and V), or None.
+- ``ffn(h, lp) -> (out [B, S, D], extras)``: `extras` is a pytree the
+  layer reports (an expert layer's aux loss and counts), or None.
+
+Around it: the parameter skeleton, the stack (`hidden`), the output head
+(`logits`) and the loss tail (`loss`). No architecture is known here:
+`llama.py` and `moe.py` compose this with their mixers and FFNs. `cfg`
+is any config with `LlamaConfig`'s fields.
+"""
+
+from __future__ import annotations
+
+from typing import Any, Dict, Optional, Sequence
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+from ray_tpu.ops.cross_entropy import (fused_linear_cross_entropy,
+                                       softmax_cross_entropy)
+from ray_tpu.ops.norms import rms_norm_reference
+from ray_tpu.ops.rope import rope_frequencies, rope_from_positions
+from ray_tpu.parallel.sharding import (
+    DEFAULT_RULES,
+    tree_shardings,
+    with_logical_constraint,
+)
+
+
+def init_params(cfg, rng, init_layer) -> Dict[str, Any]:
+    """embed, `cfg.n_layers` of `init_layer(key)` stacked along a leading
+    axis, final norm, and `out` unless the embeddings are tied."""
+    k_embed, k_out, k_layers = jax.random.split(rng, 3)
+    init = jax.nn.initializers.normal(0.02)
+    params = {
+        "embed": init(k_embed, (cfg.vocab_size, cfg.dim), cfg.dtype),
+        "layers": jax.vmap(init_layer)(
+            jax.random.split(k_layers, cfg.n_layers)),
+        "final_norm": jnp.ones(cfg.dim, cfg.dtype),
+    }
+    if not cfg.tie_embeddings:
+        params["out"] = init(k_out, (cfg.dim, cfg.vocab_size), cfg.dtype)
+    return params
+
+
+def param_logical_axes(cfg, layer_axes) -> Dict[str, Any]:
+    """Same structure as `init_params` output, with logical-axis tuples as
+    leaves. `layer_axes` names one layer's; the leading `None` added
+    here is the scanned layer axis."""
+    axes = {
+        "embed": ("vocab", "embed"),
+        "layers": {name: (None, *a) for name, a in layer_axes.items()},
+        "final_norm": ("norm",),
+    }
+    if not cfg.tie_embeddings:
+        axes["out"] = ("embed", "vocab")
+    return axes
+
+
+def init_params_sharded(init, axes, mesh, rng, rules=DEFAULT_RULES):
+    """`init(rng)` directly into sharded device buffers (no host staging
+    — required for models bigger than host/chip memory)."""
+    return jax.jit(init, out_shardings=tree_shardings(mesh, axes, rules))(
+        rng)
+
+
+def block(mixer, ffn, cfg, rope, x, lp, state=None, *, mesh=None,
+          rules=DEFAULT_RULES):
+    """One transformer block. x: [B, S, D] -> (x, state, extras). The
+    two halves are scoped (`attn`, `mlp`) so that a device trace can
+    tell their ops apart."""
+    with jax.named_scope("attn"):
+        h = rms_norm_reference(x, lp["attn_norm"], cfg.norm_eps)
+        attn, state = mixer(h, lp, rope, state)
+        x = x + jnp.einsum("bshk,hkd->bsd", attn.astype(cfg.dtype),
+                           lp["wo"])
+    with jax.named_scope("mlp"):
+        h = rms_norm_reference(x, lp["mlp_norm"], cfg.norm_eps)
+        out, extras = ffn(h, lp)
+        x = x + out
+    x = with_logical_constraint(x, "batch", "seq", "act_embed",
+                                mesh=mesh, rules=rules)
+    return x, state, extras
+
+
+def layers(mixer, ffn, cfg, rope, x, stacked, state=None, *,
+           save: Optional[Sequence[str]] = None, mesh=None,
+           rules=DEFAULT_RULES):
+    """x through a run of like layers (`stacked`: their parameters, and
+    `state`: the mixer's, along a leading axis) by `lax.scan`. Returns
+    (x, state, extras), the last two stacked by layer. `save` is the
+    remat policy: None keeps every activation; a list rematerialises
+    each layer in the backward pass but for the `checkpoint_name`s in it
+    (an empty list saves nothing)."""
+    def body(x, scanned):
+        lp, layer_state = scanned
+        x, layer_state, extras = block(mixer, ffn, cfg, rope, x, lp,
+                                       layer_state, mesh=mesh, rules=rules)
+        return x, (layer_state, extras)
+
+    if save is not None:
+        body = jax.checkpoint(
+            body,
+            policy=jax.checkpoint_policies.save_only_these_names(*save))
+    x, (state, extras) = lax.scan(body, x, (stacked, state))
+    return x, state, extras
+
+
+def rope_tables(cfg, positions=None, *, mesh=None, rules=DEFAULT_RULES):
+    """(cos, sin) for `apply_rope`: the [max_seq, D/2] tables, or, from
+    explicit `positions` [B, S], the pre-selected [B, S, D/2]."""
+    # With context parallelism each shard sees a sequence chunk; RoPE
+    # must use global positions, which the caller passes in. Default is
+    # the unsharded arange. For explicit positions, cos/sin come from an
+    # elementwise compute (no table gather) hoisted out of the layer
+    # loop and constrained to the activation sharding — the gather form
+    # makes the SPMD partitioner replicate-and-repartition the looked-up
+    # values every step ("involuntary full rematerialization").
+    if positions is None:
+        return rope_frequencies(cfg.head_dim, cfg.max_seq_len,
+                                cfg.rope_theta)
+    return tuple(
+        with_logical_constraint(t, "batch", "seq", None, mesh=mesh,
+                                rules=rules)
+        for t in rope_from_positions(positions, cfg.head_dim,
+                                     cfg.rope_theta))
+
+
+# Tables up to this size are replicated before the token gather: with the
+# table left vocab-sharded the SPMD partitioner partitions the gather on
+# the vocab dim and then "involuntarily rematerializes" (fully replicates)
+# the gathered activations to reach the activation sharding, so one table
+# transition is strictly cheaper. Past the threshold (large-vocab TP
+# configs) replication would cost vocab*embed bytes of HBM per device, so
+# the table keeps its embed-dim shard instead — the gather then moves only
+# the looked-up rows, at the price of an all-gather over the activations.
+_EMBED_REPLICATE_MAX_BYTES = 1 << 27  # 128 MiB
+
+
+def _embed_lookup(embed, tokens, mesh, rules):
+    small = embed.size * embed.dtype.itemsize <= _EMBED_REPLICATE_MAX_BYTES
+    axes = (None, None) if small else (None, "embed")
+    embed = with_logical_constraint(embed, *axes, mesh=mesh, rules=rules)
+    return embed[tokens]
+
+
+def hidden(params, tokens, cfg, mixer, ffn, *, mesh=None,
+           rules=DEFAULT_RULES, positions=None, state=None, save=None):
+    """tokens: [B, S] int32 → (final-norm hidden states [B, S, D] in
+    cfg.dtype, the mixer's state, the FFN's extras, both stacked by
+    layer) — the stack without the output projection, so the loss can
+    fuse projection+CE (`fused_linear_cross_entropy`)."""
+    rope = rope_tables(cfg, positions, mesh=mesh, rules=rules)
+    x = _embed_lookup(params["embed"], tokens, mesh, rules).astype(cfg.dtype)
+    x = with_logical_constraint(x, "batch", "seq", "act_embed",
+                                mesh=mesh, rules=rules)
+    x, state, extras = layers(mixer, ffn, cfg, rope, x, params["layers"],
+                              state, save=save, mesh=mesh, rules=rules)
+    return (rms_norm_reference(x, params["final_norm"], cfg.norm_eps),
+            state, extras)
+
+
+def _head_weight(params, cfg):
+    out_w = params["embed"].T if cfg.tie_embeddings else params["out"]
+    return out_w.astype(cfg.dtype)
+
+
+def logits(params, x, cfg, *, mesh=None, rules=DEFAULT_RULES):
+    """Final-norm hidden states [B, S, D] → logits [B, S, vocab]."""
+    out = jnp.einsum("bsd,dv->bsv", x, _head_weight(params, cfg))
+    return with_logical_constraint(out, "batch", "seq", "vocab",
+                                   mesh=mesh, rules=rules)
+
+
+def _vocab_sharded(mesh, rules) -> bool:
+    if mesh is None:
+        return False
+    axis = dict(rules).get("vocab")
+    if axis is None:
+        return False
+    axes = axis if isinstance(axis, tuple) else (axis,)
+    size = 1
+    for a in axes:
+        size *= mesh.shape.get(a, 1)
+    return size > 1
+
+
+def loss(params, batch, cfg, mixer, ffn, *, mesh=None, rules=DEFAULT_RULES,
+         save=None):
+    """batch: {"tokens": [B,S], "targets": [B,S], optional "mask": [B,S],
+    optional "positions": [B,S]}. Returns (mean cross-entropy over the
+    unmasked tokens f32, how many those are, the FFN's extras)."""
+    x, _, extras = hidden(params, batch["tokens"], cfg, mixer, ffn,
+                          mesh=mesh, rules=rules,
+                          positions=batch.get("positions"), save=save)
+    b, s, d = x.shape
+    targets = batch["targets"].reshape(b * s)
+    if cfg.fused_ce and not _vocab_sharded(mesh, rules):
+        # Fused projection+CE: the [tokens, vocab] logits tensor is never
+        # materialized (the largest single activation at 128k vocab).
+        with jax.named_scope("loss"):  # holds the output projection too
+            losses = fused_linear_cross_entropy(
+                x.reshape(b * s, d), _head_weight(params, cfg), targets)
+    else:
+        out = logits(params, x, cfg, mesh=mesh, rules=rules)
+        with jax.named_scope("loss"):
+            losses = softmax_cross_entropy(
+                out.reshape(b * s, cfg.vocab_size), targets)
+    with jax.named_scope("loss"):
+        mask = batch.get("mask")
+        if mask is None:
+            mask = jnp.ones((b, s), jnp.float32)
+        total = jnp.maximum(mask.sum(), 1.0)
+        ce = (losses.reshape(b, s) * mask).sum() / total
+    return ce, total, extras

@@ -26,18 +26,15 @@ import jax.numpy as jnp
 from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 
+from ray_tpu.models import decoder
 from ray_tpu.ops.attention import (attention_reference, flash_attention,
                                    on_tpu)
-from ray_tpu.ops.cross_entropy import (fused_linear_cross_entropy,
-                                       softmax_cross_entropy)
 from ray_tpu.ops.norms import rms_norm_reference
-from ray_tpu.ops.rope import (apply_rope, rope_frequencies,
-                              rope_from_positions)
+from ray_tpu.ops.rope import apply_rope
 from ray_tpu.parallel.ring_attention import ring_attention
 from ray_tpu.parallel.sharding import (
     DEFAULT_RULES,
     logical_to_mesh_axes,
-    tree_shardings,
     with_logical_constraint,
 )
 from ray_tpu.parallel.ulysses import ulysses_attention
@@ -59,11 +56,14 @@ class LlamaConfig:
     # "auto" | "flash" | "ring" | "ulysses" | "reference"
     attention: str = "auto"
     # False | True (save attn out/lse only) | "gate" (+silu(w1) act) |
-    # "mlp" (+both ffn acts). Validated in forward_hidden.
+    # "mlp" (+both ffn acts). Validated in _saved_names.
     remat: Any = True
     # Fuse the output projection into the CE loss (logits never
     # materialized). Auto-disabled when the vocab dim is sharded.
     fused_ce: bool = True
+    # RMSNorm with a learned weight over the whole projected q and k
+    # vectors (all heads together), before rope (OLMoE).
+    qk_norm: bool = False
 
     @property
     def head_dim(self) -> int:
@@ -77,6 +77,7 @@ class LlamaConfig:
             + self.n_heads * self.head_dim * d         # wo
             + 3 * d * h                                # w1, w2, w3
             + 2 * d                                    # norms
+            + self.qk_norm * (self.n_heads + self.n_kv_heads) * self.head_dim
         )
         embeds = v * d * (1 if self.tie_embeddings else 2)
         return l * per_layer + embeds + d
@@ -116,79 +117,108 @@ class LlamaConfig:
 # Parameters
 # ---------------------------------------------------------------------------
 
+# A layer's key is split seven ways: the first four are attention's, the
+# last three the FFN's (an FFN with leaves of its own draws them from a
+# key folded out of the layer's, as `moe.py` does).
 
-def _init_layer(cfg: LlamaConfig, key) -> Dict[str, Any]:
+
+def attention_init(cfg: LlamaConfig, key) -> Dict[str, Any]:
+    """One layer's leaves but for the FFN's: the two block norms, the
+    four attention projections, and the q/k norm weights if configured."""
     d, hd = cfg.dim, cfg.head_dim
-    k1, k2, k3, k4, k5, k6, k7 = jax.random.split(key, 7)
-    scale = d ** -0.5
-    hidden_scale = cfg.hidden_dim ** -0.5
+    k1, k2, k3, k4 = jax.random.split(key, 7)[:4]
     init = jax.nn.initializers.normal(stddev=0.02)
-    return {
+    leaves = {
         "attn_norm": jnp.ones(d, cfg.dtype),
         "wq": init(k1, (d, cfg.n_heads, hd), cfg.dtype),
         "wk": init(k2, (d, cfg.n_kv_heads, hd), cfg.dtype),
         "wv": init(k3, (d, cfg.n_kv_heads, hd), cfg.dtype),
-        "wo": (init(k4, (cfg.n_heads, hd, d), cfg.dtype) * scale),
+        "wo": (init(k4, (cfg.n_heads, hd, d), cfg.dtype) * d ** -0.5),
         "mlp_norm": jnp.ones(d, cfg.dtype),
-        "w1": init(k5, (d, cfg.hidden_dim), cfg.dtype),
-        "w3": init(k6, (d, cfg.hidden_dim), cfg.dtype),
-        "w2": (init(k7, (cfg.hidden_dim, d), cfg.dtype) * hidden_scale),
     }
+    if cfg.qk_norm:
+        leaves["q_norm"] = jnp.ones(cfg.n_heads * hd, cfg.dtype)
+        leaves["k_norm"] = jnp.ones(cfg.n_kv_heads * hd, cfg.dtype)
+    return leaves
+
+
+def attention_axes(cfg: LlamaConfig) -> Dict[str, Any]:
+    """Logical axes of `attention_init`'s leaves (no layer axis)."""
+    axes = {
+        "attn_norm": ("norm",),
+        "wq": ("embed", "heads", "head_dim"),
+        "wk": ("embed", "kv_heads", "head_dim"),
+        "wv": ("embed", "kv_heads", "head_dim"),
+        "wo": ("heads", "head_dim", "embed"),
+        "mlp_norm": ("norm",),
+    }
+    if cfg.qk_norm:
+        axes.update(q_norm=("norm",), k_norm=("norm",))
+    return axes
+
+
+def _swiglu_init(cfg: LlamaConfig, key) -> Dict[str, Any]:
+    k5, k6, k7 = jax.random.split(key, 7)[4:]
+    init = jax.nn.initializers.normal(stddev=0.02)
+    return {
+        "w1": init(k5, (cfg.dim, cfg.hidden_dim), cfg.dtype),
+        "w3": init(k6, (cfg.dim, cfg.hidden_dim), cfg.dtype),
+        "w2": (init(k7, (cfg.hidden_dim, cfg.dim), cfg.dtype)
+               * cfg.hidden_dim ** -0.5),
+    }
+
+
+_SWIGLU_AXES = {
+    "w1": ("embed", "mlp"),
+    "w3": ("embed", "mlp"),
+    "w2": ("mlp", "embed"),
+}
 
 
 def init_params(cfg: LlamaConfig, rng) -> Dict[str, Any]:
-    k_embed, k_out, k_layers = jax.random.split(rng, 3)
-    layer_keys = jax.random.split(k_layers, cfg.n_layers)
-    layers = jax.vmap(functools.partial(_init_layer, cfg))(layer_keys)
-    params = {
-        "embed": jax.nn.initializers.normal(0.02)(
-            k_embed, (cfg.vocab_size, cfg.dim), cfg.dtype),
-        "layers": layers,
-        "final_norm": jnp.ones(cfg.dim, cfg.dtype),
-    }
-    if not cfg.tie_embeddings:
-        params["out"] = jax.nn.initializers.normal(0.02)(
-            k_out, (cfg.dim, cfg.vocab_size), cfg.dtype)
-    return params
+    return decoder.init_params(cfg, rng, lambda key: {
+        **attention_init(cfg, key), **_swiglu_init(cfg, key)})
 
 
 def param_logical_axes(cfg: LlamaConfig) -> Dict[str, Any]:
     """Same structure as `init_params` output, with logical-axis tuples as
     leaves. Leading `None` on layer params is the scanned layer axis."""
-    layer = {
-        "attn_norm": (None, "norm"),
-        "wq": (None, "embed", "heads", "head_dim"),
-        "wk": (None, "embed", "kv_heads", "head_dim"),
-        "wv": (None, "embed", "kv_heads", "head_dim"),
-        "wo": (None, "heads", "head_dim", "embed"),
-        "mlp_norm": (None, "norm"),
-        "w1": (None, "embed", "mlp"),
-        "w3": (None, "embed", "mlp"),
-        "w2": (None, "mlp", "embed"),
-    }
-    axes = {
-        "embed": ("vocab", "embed"),
-        "layers": layer,
-        "final_norm": ("norm",),
-    }
-    if not cfg.tie_embeddings:
-        axes["out"] = ("embed", "vocab")
-    return axes
+    return decoder.param_logical_axes(
+        cfg, {**attention_axes(cfg), **_SWIGLU_AXES})
 
 
 def init_params_sharded(cfg: LlamaConfig, mesh, rng,
                         rules=DEFAULT_RULES) -> Dict[str, Any]:
     """Initialize directly into sharded device buffers (no host staging —
     required for models bigger than host/chip memory)."""
-    shardings = tree_shardings(mesh, param_logical_axes(cfg), rules)
-    fn = jax.jit(functools.partial(init_params, cfg),
-                 out_shardings=shardings)
-    return fn(rng)
+    return decoder.init_params_sharded(
+        functools.partial(init_params, cfg), param_logical_axes(cfg), mesh,
+        rng, rules)
 
 
 # ---------------------------------------------------------------------------
 # Forward
 # ---------------------------------------------------------------------------
+
+
+def norm_all_heads(x, weight, eps):
+    """RMSNorm of [B, S, H, K] over heads and head size together."""
+    b, s, h, k = x.shape
+    return rms_norm_reference(x.reshape(b, s, h * k), weight,
+                              eps).reshape(b, s, h, k)
+
+
+def _qkv(cfg: LlamaConfig, h, lp, rope, positions, qk_norm):
+    """The projections of normed activations h [B, S, D], with the q/k
+    norm if configured and rope: q [B,S,H,D], k and v [B,S,Hkv,D]."""
+    q = jnp.einsum("bsd,dhk->bshk", h, lp["wq"])
+    k = jnp.einsum("bsd,dhk->bshk", h, lp["wk"])
+    v = jnp.einsum("bsd,dhk->bshk", h, lp["wv"])
+    if cfg.qk_norm:
+        q = qk_norm(q, lp["q_norm"], cfg.norm_eps)
+        k = qk_norm(k, lp["k_norm"], cfg.norm_eps)
+    return (apply_rope(q, *rope, positions), apply_rope(k, *rope, positions),
+            v)
 
 
 def _attention(cfg: LlamaConfig, q, k, v, mesh, rules):
@@ -233,54 +263,67 @@ def _attention(cfg: LlamaConfig, q, k, v, mesh, rules):
     return out.transpose(0, 2, 1, 3)
 
 
-def layer_fn(cfg: LlamaConfig, mesh, rules, cos, sin, x, lp, positions):
-    """One transformer block. x: [B, S, D]. The two halves are scoped
-    (`attn`, `mlp`) so that a device trace can tell their ops apart."""
-    with jax.named_scope("attn"):
-        h = rms_norm_reference(x, lp["attn_norm"], cfg.norm_eps)
-        q = jnp.einsum("bsd,dhk->bshk", h, lp["wq"])
-        k = jnp.einsum("bsd,dhk->bshk", h, lp["wk"])
-        v = jnp.einsum("bsd,dhk->bshk", h, lp["wv"])
-        q = apply_rope(q, cos, sin, positions)
-        k = apply_rope(k, cos, sin, positions)
+def self_attention(cfg: LlamaConfig, mesh=None, rules=DEFAULT_RULES,
+                   qk_norm=norm_all_heads):
+    """The mixer of training and of the uncached forward: causal
+    attention of the sequence over itself."""
+    def mixer(h, lp, rope, state):
+        q, k, v = _qkv(cfg, h, lp, rope, None, qk_norm)
         q = with_logical_constraint(q, "batch", "seq", "heads", "head_dim",
                                     mesh=mesh, rules=rules)
-        attn = _attention(cfg, q, k, v, mesh, rules)
-        x = x + jnp.einsum("bshk,hkd->bsd", attn.astype(cfg.dtype),
-                           lp["wo"])
-    with jax.named_scope("mlp"):
-        h2 = rms_norm_reference(x, lp["mlp_norm"], cfg.norm_eps)
+        return _attention(cfg, q, k, v, mesh, rules), state
+
+    return mixer
+
+
+def swiglu(mesh=None, rules=DEFAULT_RULES):
+    """The dense FFN: w2(silu(w1 h) * w3 h), no extras."""
+    def ffn(h, lp):
         # Named for selective remat: cfg.remat="mlp" saves these two (the
         # dominant recompute cost) while still rematerializing the rest.
         gate = checkpoint_name(
-            jax.nn.silu(jnp.einsum("bsd,df->bsf", h2, lp["w1"])),
+            jax.nn.silu(jnp.einsum("bsd,df->bsf", h, lp["w1"])),
             "ffn_gate")
         up = checkpoint_name(
-            jnp.einsum("bsd,df->bsf", h2, lp["w3"]), "ffn_up")
+            jnp.einsum("bsd,df->bsf", h, lp["w3"]), "ffn_up")
         ff = with_logical_constraint(gate * up, "batch", "seq", "mlp",
                                      mesh=mesh, rules=rules)
-        x = x + jnp.einsum("bsf,fd->bsd", ff, lp["w2"])
-    x = with_logical_constraint(x, "batch", "seq", "act_embed",
-                                mesh=mesh, rules=rules)
-    return x
+        return jnp.einsum("bsf,fd->bsd", ff, lp["w2"]), None
+
+    return ffn
 
 
-# Tables up to this size are replicated before the token gather: with the
-# table left vocab-sharded the SPMD partitioner partitions the gather on
-# the vocab dim and then "involuntarily rematerializes" (fully replicates)
-# the gathered activations to reach the activation sharding, so one table
-# transition is strictly cheaper. Past the threshold (large-vocab TP
-# configs) replication would cost vocab*embed bytes of HBM per device, so
-# the table keeps its embed-dim shard instead — the gather then moves only
-# the looked-up rows, at the price of an all-gather over the activations.
-_EMBED_REPLICATE_MAX_BYTES = 1 << 27  # 128 MiB
+def _saved_names(cfg: LlamaConfig):
+    """What `cfg.remat` keeps across the remat boundary, as
+    `decoder.layers` takes it."""
+    if not cfg.remat:
+        return None
+    # Save the flash-attention output + logsumexp across the remat
+    # boundary: the backward then recomputes only the cheap projections
+    # (for the q/k/v residuals) and never re-runs the forward attention
+    # kernel. ~37MB/layer at 4x2048 — a large step-time win for a small
+    # slice of HBM. remat="mlp" additionally saves the two MLP hidden
+    # activations (the dominant recompute FLOPs; ~268MB/layer at
+    # 4x2048) — worth it when the fused-CE loss path leaves the HBM
+    # headroom.
+    if cfg.remat not in (True, "mlp", "gate"):
+        raise ValueError(
+            f"remat={cfg.remat!r}: expected False, True, 'gate', or "
+            "'mlp' (a typo here would silently train with attn-only "
+            "checkpointing)")
+    names = ["flash_out", "flash_lse"]
+    if cfg.remat == "mlp":
+        names += ["ffn_gate", "ffn_up"]
+    elif cfg.remat == "gate":  # half the HBM of "mlp"
+        names += ["ffn_gate"]
+    return names
 
 
-def _embed_lookup(embed, tokens, mesh, rules):
-    small = embed.size * embed.dtype.itemsize <= _EMBED_REPLICATE_MAX_BYTES
-    axes = (None, None) if small else (None, "embed")
-    embed = with_logical_constraint(embed, *axes, mesh=mesh, rules=rules)
-    return embed[tokens]
+def _parts(cfg: LlamaConfig, mesh, rules):
+    """What `decoder` is handed for the dense architecture."""
+    return dict(mixer=self_attention(cfg, mesh, rules),
+                ffn=swiglu(mesh, rules), save=_saved_names(cfg), mesh=mesh,
+                rules=rules)
 
 
 def forward_hidden(params, tokens, cfg: LlamaConfig, *, mesh=None,
@@ -288,57 +331,8 @@ def forward_hidden(params, tokens, cfg: LlamaConfig, *, mesh=None,
     """tokens: [B, S] int32 → final-norm hidden states [B, S, D]
     (cfg.dtype) — the stack without the output projection, so the loss
     can fuse projection+CE (`fused_linear_cross_entropy`)."""
-    # With context parallelism each shard sees a sequence chunk; RoPE
-    # must use global positions, which the caller passes in. Default is
-    # the unsharded arange. For explicit positions, cos/sin come from an
-    # elementwise compute (no table gather) hoisted out of the layer
-    # loop and constrained to the activation sharding — the gather form
-    # makes the SPMD partitioner replicate-and-repartition the looked-up
-    # values every step ("involuntary full rematerialization").
-    if positions is not None:
-        cos, sin = rope_from_positions(positions, cfg.head_dim,
-                                       cfg.rope_theta)
-        cos = with_logical_constraint(cos, "batch", "seq", None,
-                                      mesh=mesh, rules=rules)
-        sin = with_logical_constraint(sin, "batch", "seq", None,
-                                      mesh=mesh, rules=rules)
-        positions = None
-    else:
-        cos, sin = rope_frequencies(cfg.head_dim, cfg.max_seq_len,
-                                    cfg.rope_theta)
-    x = _embed_lookup(params["embed"], tokens, mesh, rules).astype(cfg.dtype)
-    x = with_logical_constraint(x, "batch", "seq", "act_embed",
-                                mesh=mesh, rules=rules)
-
-    body = functools.partial(layer_fn, cfg, mesh, rules, cos, sin)
-
-    def scan_body(x, lp):
-        return body(x, lp, positions), None
-
-    if cfg.remat:
-        # Save the flash-attention output + logsumexp across the remat
-        # boundary: the backward then recomputes only the cheap projections
-        # (for the q/k/v residuals) and never re-runs the forward attention
-        # kernel. ~37MB/layer at 4x2048 — a large step-time win for a small
-        # slice of HBM. remat="mlp" additionally saves the two MLP hidden
-        # activations (the dominant recompute FLOPs; ~268MB/layer at
-        # 4x2048) — worth it when the fused-CE loss path leaves the HBM
-        # headroom.
-        if cfg.remat not in (True, "mlp", "gate"):
-            raise ValueError(
-                f"remat={cfg.remat!r}: expected False, True, 'gate', or "
-                "'mlp' (a typo here would silently train with attn-only "
-                "checkpointing)")
-        names = ["flash_out", "flash_lse"]
-        if cfg.remat == "mlp":
-            names += ["ffn_gate", "ffn_up"]
-        elif cfg.remat == "gate":  # half the HBM of "mlp"
-            names += ["ffn_gate"]
-        scan_body = jax.checkpoint(
-            scan_body,
-            policy=jax.checkpoint_policies.save_only_these_names(*names))
-    x, _ = lax.scan(scan_body, x, params["layers"])
-    return rms_norm_reference(x, params["final_norm"], cfg.norm_eps)
+    return decoder.hidden(params, tokens, cfg, positions=positions,
+                          **_parts(cfg, mesh, rules))[0]
 
 
 def forward(params, tokens, cfg: LlamaConfig, *, mesh=None,
@@ -346,54 +340,15 @@ def forward(params, tokens, cfg: LlamaConfig, *, mesh=None,
     """tokens: [B, S] int32 → logits [B, S, vocab] (cfg.dtype)."""
     x = forward_hidden(params, tokens, cfg, mesh=mesh, rules=rules,
                        positions=positions)
-    out_w = params["embed"].T if cfg.tie_embeddings else params["out"]
-    logits = jnp.einsum("bsd,dv->bsv", x, out_w.astype(cfg.dtype))
-    return with_logical_constraint(logits, "batch", "seq", "vocab",
-                                   mesh=mesh, rules=rules)
-
-
-def _vocab_sharded(mesh, rules) -> bool:
-    if mesh is None:
-        return False
-    axis = dict(rules).get("vocab")
-    if axis is None:
-        return False
-    axes = axis if isinstance(axis, tuple) else (axis,)
-    size = 1
-    for a in axes:
-        size *= mesh.shape.get(a, 1)
-    return size > 1
+    return decoder.logits(params, x, cfg, mesh=mesh, rules=rules)
 
 
 def loss_fn(params, batch, cfg: LlamaConfig, *, mesh=None,
             rules=DEFAULT_RULES):
     """batch: {"tokens": [B,S], "targets": [B,S], optional "mask": [B,S],
     optional "positions": [B,S]}. Returns (mean loss f32, metrics dict)."""
-    b, s = batch["tokens"].shape
-    if cfg.fused_ce and not _vocab_sharded(mesh, rules):
-        # Fused projection+CE: the [tokens, vocab] logits tensor is never
-        # materialized (the largest single activation at 128k vocab).
-        x = forward_hidden(params, batch["tokens"], cfg, mesh=mesh,
-                           rules=rules, positions=batch.get("positions"))
-        out_w = params["embed"].T if cfg.tie_embeddings else params["out"]
-        with jax.named_scope("loss"):  # holds the output projection too
-            losses = fused_linear_cross_entropy(
-                x.reshape(b * s, cfg.dim), out_w.astype(cfg.dtype),
-                batch["targets"].reshape(b * s))
-    else:
-        logits = forward(params, batch["tokens"], cfg, mesh=mesh,
-                         rules=rules, positions=batch.get("positions"))
-        with jax.named_scope("loss"):
-            losses = softmax_cross_entropy(
-                logits.reshape(b * s, cfg.vocab_size),
-                batch["targets"].reshape(b * s))
-    with jax.named_scope("loss"):
-        losses = losses.reshape(b, s)
-        mask = batch.get("mask")
-        if mask is None:
-            mask = jnp.ones((b, s), jnp.float32)
-        total = jnp.maximum(mask.sum(), 1.0)
-        loss = (losses * mask).sum() / total
+    loss, total, _ = decoder.loss(params, batch, cfg,
+                                  **_parts(cfg, mesh, rules))
     return loss, {"loss": loss, "tokens": total,
                   "perplexity": jnp.exp(loss)}
 
@@ -430,7 +385,8 @@ def _cached_attention(cfg, q, k_cache, v_cache, q_positions):
     The benchmark's tests rely on this function's name and signature
     (`tests/benchmark/test_references.py` patches it), on the cache
     layout [layers, slots, max_seq, kv_heads, head_dim], and on the call
-    sitting inside the `attn` scope of `forward_with_cache`."""
+    sitting inside the `attn` scope (`decoder.block` opens it around the
+    mixer, which looks this name up in this module when it is traced)."""
     b, t, h, d = q.shape
     s, g = k_cache.shape[1], k_cache.shape[2]
     qg = q.reshape(b, t, g, h // g, d)
@@ -445,6 +401,26 @@ def _cached_attention(cfg, q, k_cache, v_cache, q_positions):
     return out.reshape(b, t, h, d).astype(q.dtype)
 
 
+def _cached_self_attention(cfg: LlamaConfig, start_pos, positions):
+    """The mixer of serving: the new K and V written into the layer's
+    slices of the slot cache at `start_pos`, then attention over them.
+    Carries (k_cache, v_cache), each [B, S, Hkv, D]."""
+    def write_cache(cache_b, new_b, start_b):
+        # cache_b: [S, Hkv, D]; new_b: [T, Hkv, D]
+        return lax.dynamic_update_slice(
+            cache_b, new_b.astype(cache_b.dtype), (start_b, 0, 0))
+
+    def mixer(h, lp, rope, state):
+        k_cache, v_cache = state
+        q, k, v = _qkv(cfg, h, lp, rope, positions, norm_all_heads)
+        k_cache = jax.vmap(write_cache)(k_cache, k, start_pos)
+        v_cache = jax.vmap(write_cache)(v_cache, v, start_pos)
+        return (_cached_attention(cfg, q, k_cache, v_cache, positions),
+                (k_cache, v_cache))
+
+    return mixer
+
+
 def forward_with_cache(params, tokens, cfg: LlamaConfig, cache,
                        start_pos):
     """Incremental forward: runs `tokens` [B, T] starting at per-sequence
@@ -452,42 +428,8 @@ def forward_with_cache(params, tokens, cfg: LlamaConfig, cache,
     Returns (logits [B, T, vocab], new_cache). Works for prefill (T =
     prompt length) and decode (T = 1) with one code path.
     """
-    b, t = tokens.shape
-    cos, sin = rope_frequencies(cfg.head_dim, cfg.max_seq_len,
-                                cfg.rope_theta)
-    positions = start_pos[:, None] + jnp.arange(t)[None, :]  # [B, T]
-    x = params["embed"][tokens].astype(cfg.dtype)
-
-    def write_cache(cache_b, new_b, start_b):
-        # cache_b: [S, Hkv, D]; new_b: [T, Hkv, D]
-        return lax.dynamic_update_slice(
-            cache_b, new_b.astype(cache_b.dtype), (start_b, 0, 0))
-
-    def layer(x, scanned):
-        lp, k_cache_l, v_cache_l = scanned
-        with jax.named_scope("attn"):
-            h = rms_norm_reference(x, lp["attn_norm"], cfg.norm_eps)
-            q = jnp.einsum("bsd,dhk->bshk", h, lp["wq"])
-            k = jnp.einsum("bsd,dhk->bshk", h, lp["wk"])
-            v = jnp.einsum("bsd,dhk->bshk", h, lp["wv"])
-            q = apply_rope(q, cos, sin, positions)
-            k = apply_rope(k, cos, sin, positions)
-            k_cache_l = jax.vmap(write_cache)(k_cache_l, k, start_pos)
-            v_cache_l = jax.vmap(write_cache)(v_cache_l, v, start_pos)
-            attn = _cached_attention(cfg, q, k_cache_l, v_cache_l,
-                                     positions)
-            x = x + jnp.einsum("bshk,hkd->bsd", attn.astype(cfg.dtype),
-                               lp["wo"])
-        with jax.named_scope("mlp"):
-            h2 = rms_norm_reference(x, lp["mlp_norm"], cfg.norm_eps)
-            gate = jax.nn.silu(jnp.einsum("bsd,df->bsf", h2, lp["w1"]))
-            up = jnp.einsum("bsd,df->bsf", h2, lp["w3"])
-            x = x + jnp.einsum("bsf,fd->bsd", gate * up, lp["w2"])
-        return x, (k_cache_l, v_cache_l)
-
-    x, (k_new, v_new) = lax.scan(
-        layer, x, (params["layers"], cache["k"], cache["v"]))
-    x = rms_norm_reference(x, params["final_norm"], cfg.norm_eps)
-    out_w = params["embed"].T if cfg.tie_embeddings else params["out"]
-    logits = jnp.einsum("bsd,dv->bsv", x, out_w.astype(cfg.dtype))
-    return logits, {"k": k_new, "v": v_new}
+    positions = start_pos[:, None] + jnp.arange(tokens.shape[1])[None, :]
+    x, (k_new, v_new), _ = decoder.hidden(
+        params, tokens, cfg, state=(cache["k"], cache["v"]), ffn=swiglu(),
+        mixer=_cached_self_attention(cfg, start_pos, positions))
+    return decoder.logits(params, x, cfg), {"k": k_new, "v": v_new}

@@ -45,24 +45,21 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ray_tpu.models import decoder
 from ray_tpu.models.llama import (
     LlamaConfig,
-    _attention,
-    _embed_lookup,
-    _init_layer,
-    _vocab_sharded,
+    attention_axes,
+    attention_init,
+    norm_all_heads,
+    self_attention,
 )
-from ray_tpu.ops.cross_entropy import (fused_linear_cross_entropy,
-                                       softmax_cross_entropy)
-from ray_tpu.ops.norms import rms_norm_reference
-from ray_tpu.ops.rope import (apply_rope, rope_frequencies,
-                              rope_from_positions)
-from ray_tpu.parallel.sharding import (
-    DEFAULT_RULES,
-    logical_to_mesh_axes,
-    tree_shardings,
-    with_logical_constraint,
-)
+from ray_tpu.ops.norms import rms_norm_reference  # noqa: F401
+from ray_tpu.parallel.sharding import DEFAULT_RULES, logical_to_mesh_axes
+
+# The benchmark's fault test of the q/k norm patches this name, and
+# builds its fault from `rms_norm_reference` above, both as attributes of
+# this module (`tests/benchmark/test_olmoe.py`).
+_norm_all_heads = norm_all_heads
 
 
 @dataclasses.dataclass(frozen=True)
@@ -73,9 +70,6 @@ class MoEConfig(LlamaConfig):
     # Divide a token's k gates by their sum (Mixtral). OLMoE weighs its
     # experts by the softmax over all of them as it is.
     norm_topk_prob: bool = True
-    # RMSNorm with a learned weight over the whole projected q and k
-    # vectors (all heads together), before rope (OLMoE).
-    qk_norm: bool = False
 
     @staticmethod
     def debug_moe() -> "MoEConfig":
@@ -101,37 +95,21 @@ class MoEConfig(LlamaConfig):
 
 
 def _init_moe_layer(cfg: MoEConfig, key) -> Dict[str, Any]:
-    base = _init_layer(cfg, key)
     k_router, k1, k2, k3 = jax.random.split(jax.random.fold_in(key, 99), 4)
     init = jax.nn.initializers.normal(stddev=0.02)
     e, d, h = cfg.n_experts, cfg.dim, cfg.hidden_dim
-    # Replace the dense FFN with per-expert weights + a router.
-    for dead in ("w1", "w2", "w3"):
-        del base[dead]
-    base["router"] = init(k_router, (d, e), cfg.dtype)
-    base["we1"] = init(k1, (e, d, h), cfg.dtype)
-    base["we3"] = init(k2, (e, d, h), cfg.dtype)
-    base["we2"] = init(k3, (e, h, d), cfg.dtype) * (h ** -0.5)
-    if cfg.qk_norm:
-        base["q_norm"] = jnp.ones(cfg.n_heads * cfg.head_dim, cfg.dtype)
-        base["k_norm"] = jnp.ones(cfg.n_kv_heads * cfg.head_dim, cfg.dtype)
-    return base
+    return {
+        **attention_init(cfg, key),
+        "router": init(k_router, (d, e), cfg.dtype),
+        "we1": init(k1, (e, d, h), cfg.dtype),
+        "we3": init(k2, (e, d, h), cfg.dtype),
+        "we2": init(k3, (e, h, d), cfg.dtype) * (h ** -0.5),
+    }
 
 
 def init_moe_params(cfg: MoEConfig, rng) -> Dict[str, Any]:
-    k_embed, k_out, k_layers = jax.random.split(rng, 3)
-    layer_keys = jax.random.split(k_layers, cfg.n_layers)
-    layers = jax.vmap(functools.partial(_init_moe_layer, cfg))(layer_keys)
-    params = {
-        "embed": jax.nn.initializers.normal(0.02)(
-            k_embed, (cfg.vocab_size, cfg.dim), cfg.dtype),
-        "layers": layers,
-        "final_norm": jnp.ones(cfg.dim, cfg.dtype),
-    }
-    if not cfg.tie_embeddings:
-        params["out"] = jax.nn.initializers.normal(0.02)(
-            k_out, (cfg.dim, cfg.vocab_size), cfg.dtype)
-    return params
+    return decoder.init_params(cfg, rng,
+                               functools.partial(_init_moe_layer, cfg))
 
 
 # Logical axes of the three expert matrices without the layer axis: how
@@ -144,34 +122,16 @@ _EXPERT_AXES = {
 
 
 def moe_param_logical_axes(cfg: MoEConfig) -> Dict[str, Any]:
-    layer = {
-        "attn_norm": (None, "norm"),
-        "wq": (None, "embed", "heads", "head_dim"),
-        "wk": (None, "embed", "kv_heads", "head_dim"),
-        "wv": (None, "embed", "kv_heads", "head_dim"),
-        "wo": (None, "heads", "head_dim", "embed"),
-        "mlp_norm": (None, "norm"),
-        "router": (None, "embed", None),
-        **{name: (None, *axes) for name, axes in _EXPERT_AXES.items()},
-    }
-    if cfg.qk_norm:
-        layer["q_norm"] = (None, "norm")
-        layer["k_norm"] = (None, "norm")
-    axes = {
-        "embed": ("vocab", "embed"),
-        "layers": layer,
-        "final_norm": ("norm",),
-    }
-    if not cfg.tie_embeddings:
-        axes["out"] = ("embed", "vocab")
-    return axes
+    return decoder.param_logical_axes(
+        cfg, {**attention_axes(cfg), "router": ("embed", None),
+              **_EXPERT_AXES})
 
 
 def init_moe_params_sharded(cfg: MoEConfig, mesh, rng,
                             rules=DEFAULT_RULES):
-    shardings = tree_shardings(mesh, moe_param_logical_axes(cfg), rules)
-    return jax.jit(functools.partial(init_moe_params, cfg),
-                   out_shardings=shardings)(rng)
+    return decoder.init_params_sharded(
+        functools.partial(init_moe_params, cfg),
+        moe_param_logical_axes(cfg), mesh, rng, rules)
 
 
 # -- the expert layer ---------------------------------------------------------
@@ -302,68 +262,27 @@ def _gather_whole(w, spec):
     return w
 
 
+def _parts(cfg: MoEConfig, mesh, rules):
+    """What `decoder` is handed for this architecture: the mixer, the
+    FFN, and what a rematerialised layer saves (nothing)."""
+    def ffn(h, lp):
+        out, aux, counts = _moe_ffn(cfg, lp, h, mesh, rules)
+        return out, {"aux": aux, "counts": counts}
+
+    # `_norm_all_heads` is read here, when a forward pass is traced, so
+    # that a test's patch of it reaches the program.
+    return dict(mixer=self_attention(cfg, mesh, rules, _norm_all_heads),
+                ffn=ffn, save=[] if cfg.remat else None, mesh=mesh,
+                rules=rules)
+
+
 def moe_forward_hidden(params, tokens, cfg: MoEConfig, *, mesh=None,
                        rules=DEFAULT_RULES, positions=None):
     """tokens [B, S] -> (final-norm hidden states [B, S, D], the layers'
     mean aux loss, pairs routed per layer and expert [L, E] int32)."""
-    # Same SPMD hygiene as llama.forward: explicit positions → elementwise
-    # cos/sin sharded with the activations (no table gather), and the
-    # embed table size-gated replicated/sharded before the token gather
-    # (_embed_lookup) so the partitioner doesn't fully rematerialize the
-    # gathered activations.
-    if positions is not None:
-        cos, sin = rope_from_positions(positions, cfg.head_dim,
-                                       cfg.rope_theta)
-        cos = with_logical_constraint(cos, "batch", "seq", None,
-                                      mesh=mesh, rules=rules)
-        sin = with_logical_constraint(sin, "batch", "seq", None,
-                                      mesh=mesh, rules=rules)
-        positions = None
-    else:
-        cos, sin = rope_frequencies(cfg.head_dim, cfg.max_seq_len,
-                                    cfg.rope_theta)
-    x = _embed_lookup(params["embed"], tokens, mesh, rules).astype(cfg.dtype)
-    x = with_logical_constraint(x, "batch", "seq", "act_embed",
-                                mesh=mesh, rules=rules)
-
-    def layer(carry, lp):
-        x, aux_acc = carry
-        with jax.named_scope("attn"):
-            h = rms_norm_reference(x, lp["attn_norm"], cfg.norm_eps)
-            q = jnp.einsum("bsd,dhk->bshk", h, lp["wq"])
-            k_ = jnp.einsum("bsd,dhk->bshk", h, lp["wk"])
-            v = jnp.einsum("bsd,dhk->bshk", h, lp["wv"])
-            if cfg.qk_norm:
-                q = _norm_all_heads(q, lp["q_norm"], cfg.norm_eps)
-                k_ = _norm_all_heads(k_, lp["k_norm"], cfg.norm_eps)
-            q = apply_rope(q, cos, sin, positions)
-            k_ = apply_rope(k_, cos, sin, positions)
-            attn = _attention(cfg, q, k_, v, mesh, rules)
-            x = x + jnp.einsum("bshk,hkd->bsd", attn.astype(cfg.dtype),
-                               lp["wo"])
-        with jax.named_scope("mlp"):
-            h2 = rms_norm_reference(x, lp["mlp_norm"], cfg.norm_eps)
-            ffn_out, aux, counts = _moe_ffn(cfg, lp, h2, mesh, rules)
-            x = x + ffn_out
-        x = with_logical_constraint(x, "batch", "seq", "act_embed",
-                                    mesh=mesh, rules=rules)
-        return (x, aux_acc + aux), counts
-
-    body = layer
-    if cfg.remat:
-        body = jax.checkpoint(
-            layer, policy=jax.checkpoint_policies.nothing_saveable)
-    (x, aux_total), expert_tokens = lax.scan(
-        body, (x, jnp.zeros((), jnp.float32)), params["layers"])
-    x = rms_norm_reference(x, params["final_norm"], cfg.norm_eps)
-    return x, aux_total / cfg.n_layers, expert_tokens
-
-
-def _norm_all_heads(x, weight, eps):
-    """RMSNorm of [B, S, H, K] over heads and head size together."""
-    b, s, h, k = x.shape
-    return rms_norm_reference(x.reshape(b, s, h * k), weight,
-                              eps).reshape(b, s, h, k)
+    x, _, extras = decoder.hidden(params, tokens, cfg, positions=positions,
+                                  **_parts(cfg, mesh, rules))
+    return x, extras["aux"].mean(), extras["counts"]
 
 
 def moe_forward(params, tokens, cfg: MoEConfig, *, mesh=None,
@@ -371,9 +290,7 @@ def moe_forward(params, tokens, cfg: MoEConfig, *, mesh=None,
     """Returns (logits [B,S,V], total aux loss)."""
     x, aux, _ = moe_forward_hidden(params, tokens, cfg, mesh=mesh,
                                    rules=rules, positions=positions)
-    out_w = params["embed"].T if cfg.tie_embeddings else params["out"]
-    logits = jnp.einsum("bsd,dv->bsv", x, out_w.astype(cfg.dtype))
-    return logits, aux
+    return decoder.logits(params, x, cfg, mesh=mesh, rules=rules), aux
 
 
 def moe_loss_fn(params, batch, cfg: MoEConfig, *, mesh=None,
@@ -383,24 +300,11 @@ def moe_loss_fn(params, batch, cfg: MoEConfig, *, mesh=None,
     the pairs sent to each expert of each layer, and under `span_attrs`
     (what `make_train_step` puts on its dispatch span) the busiest
     expert's count and the mean."""
-    x, aux, expert_tokens = moe_forward_hidden(
-        params, batch["tokens"], cfg, mesh=mesh, rules=rules,
-        positions=batch.get("positions"))
-    b, s, d = x.shape
-    out_w = (params["embed"].T if cfg.tie_embeddings
-             else params["out"]).astype(cfg.dtype)
-    targets = batch["targets"].reshape(b * s)
-    with jax.named_scope("loss"):
-        if cfg.fused_ce and not _vocab_sharded(mesh, rules):
-            # As llama.loss_fn: the [tokens, vocab] logits never exist.
-            losses = fused_linear_cross_entropy(
-                x.reshape(b * s, d), out_w, targets)
-        else:
-            logits = jnp.einsum("bsd,dv->bsv", x, out_w)
-            losses = softmax_cross_entropy(
-                logits.reshape(b * s, cfg.vocab_size), targets)
-        ce = losses.mean()
-        loss = ce + cfg.aux_loss_coeff * aux
+    ce, _, extras = decoder.loss(params, batch, cfg,
+                                 **_parts(cfg, mesh, rules))
+    aux = extras["aux"].mean()
+    expert_tokens = extras["counts"]
+    loss = ce + cfg.aux_loss_coeff * aux
     return loss, {"loss": loss, "ce_loss": ce, "aux_loss": aux,
                   "expert_tokens": expert_tokens,
                   "span_attrs": {

@@ -299,9 +299,8 @@ def llama_pp_parts(cfg, params, *, n_stages: int):
     backward wave; the embedding runs OUTSIDE the pipeline (replicated),
     with its gradient recoverable from the returned d_x_mb.
     """
-    from ray_tpu.models import llama as _llama
+    from ray_tpu.models import decoder, llama
     from ray_tpu.ops.norms import rms_norm_reference
-    from ray_tpu.ops.rope import rope_frequencies
 
     L = cfg.n_layers
     if L % n_stages:
@@ -316,16 +315,11 @@ def llama_pp_parts(cfg, params, *, n_stages: int):
         head_params["out"] = params["out"]
     else:  # tied embeddings project through embed.T
         head_params["out_t"] = params["embed"]
-    cos, sin = rope_frequencies(cfg.head_dim, cfg.max_seq_len,
-                                cfg.rope_theta)
+    mixer, ffn = llama.self_attention(cfg), llama.swiglu()
+    rope = decoder.rope_tables(cfg)
 
     def stage_fn(layers_slice, x):
-        def body(h, lp):
-            return _llama.layer_fn(cfg, None, _llama.DEFAULT_RULES,
-                                   cos, sin, h, lp, None), None
-
-        x, _ = lax.scan(body, x, layers_slice)
-        return x
+        return decoder.layers(mixer, ffn, cfg, rope, x, layers_slice)[0]
 
     def head_loss_fn(hp, y, tokens):
         h = rms_norm_reference(y, hp["final_norm"], cfg.norm_eps)
