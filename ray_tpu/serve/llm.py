@@ -146,7 +146,6 @@ class _Request:
     # while the request crosses admit → kv-lookup → prefill → sample.
     trace_id: str = ""
     t_kv_done: float = 0.0
-    t_prefill_done: float = 0.0
 
 
 @dataclasses.dataclass
@@ -186,7 +185,6 @@ class LLMEngine:
         self._free_slots = list(range(self.n_slots))
         self._slot_req: Dict[int, _Request] = {}
         self._lengths = np.zeros(self.n_slots, np.int32)  # tokens in cache
-        self._last_token = np.zeros(self.n_slots, np.int32)
         self._active = np.zeros(self.n_slots, bool)
 
         self._queue: "queue.Queue[_Request]" = queue.Queue()
@@ -194,20 +192,27 @@ class LLMEngine:
         self._lock = threading.Lock()
         self._running = threading.Event()
         self._thread: Optional[threading.Thread] = None
-        # Pipelined decode: the in-flight block's device token array (its
-        # host fetch happens while the next block computes), plus
-        # device-side last-token/length carries valid while no admission
-        # has touched the host copies.
-        self._pending_toks = None
-        self._dev_last = None
-        self._dev_lengths = None
+        # Pipelined decode: the block in flight as (its device token
+        # array, the slots' requests when it was dispatched); its host
+        # fetch happens while the next block computes. The decode
+        # carries (each slot's last token and length) live on the
+        # device: a block hands them to the next, and an admission
+        # wave's sample program writes the admitted slots' first tokens
+        # and prompt lengths into them, so that no wave waits for a
+        # result. `_first_tokens` is the last wave's (sample output,
+        # its copy to the host started; the admitted (request, slot)
+        # pairs, a row each) until the loop delivers it, behind the
+        # next decode dispatch.
+        self._pending_block = None
+        self._first_tokens = None
         # Running totals of what the loop's spans count at the same
         # seams (`metrics()["totals"]`); only the loop's thread adds
         # (and, to `kv_readbacks_forced`, whoever forces a read-back).
         self._totals = dict.fromkeys((
             "decode_steps", "active_slot_steps", "tokens_kept",
             "tokens_discarded", "prefill_tokens_real",
-            "prefill_tokens_bucketed", "admit_waves", "admissions",
+            "prefill_tokens_bucketed", "admit_waves",
+            "admit_waves_behind_block", "slot_steps_stale", "admissions",
             "kv_blocks_read_back", "kv_bytes_read_back",
             "kv_readbacks_deferred", "kv_readbacks_forced"), 0)
 
@@ -231,6 +236,10 @@ class LLMEngine:
         self.params = jax.device_put(self.params, s1)
         self.cache = jax.device_put(self.cache, s1)
         self._rng = jax.device_put(self._rng, s1)
+        self._dev_last = jax.device_put(
+            jnp.zeros(self.n_slots, jnp.int32), s1)
+        self._dev_lengths = jax.device_put(
+            jnp.zeros(self.n_slots, jnp.int32), s1)
         self._decode = jax.jit(
             self._decode_impl, donate_argnums=(1,),
             in_shardings=(None, s1, s1, s1, s1, s1, s1),
@@ -242,10 +251,11 @@ class LLMEngine:
             out_shardings=(s1, s1))
         # First-token sampling for an admission wave — FIXED shape
         # [n_slots, vocab] (padded) so it is ONE program compiled at
-        # warmup, not a variant per distinct admitted-count.
+        # warmup, not a variant per distinct admitted-count. It also
+        # writes the admitted slots into the decode carries.
         self._sample_admitted = jax.jit(
             self._sample_admitted_impl,
-            in_shardings=(s1, s1, s1), out_shardings=(s1, s1))
+            in_shardings=(s1,) * 7, out_shardings=(s1,) * 4)
         # AOT-compiled executables, filled by warmup(): the bucket
         # ladder compiles CONCURRENTLY (XLA releases the GIL; compiles
         # parallelize across cores) and the serving path then calls the
@@ -337,9 +347,8 @@ class LLMEngine:
         self._compile_ladder_concurrent(buckets)
         last = None
         for bucket in buckets:
-            tokens = jnp.zeros((1, bucket), jnp.int32)
             self.cache, last = self._run_prefill(
-                tokens, jnp.int32(0), jnp.int32(1), jnp.int32(0), bucket)
+                np.zeros((1, bucket), np.int32), 0, 1, 0, bucket)
         if self.prefix_cache is not None:
             # Touch the KV copy programs so the first cache hit or
             # read-back doesn't pay a mid-serving compile.
@@ -349,11 +358,13 @@ class LLMEngine:
             kb = jnp.zeros((k.shape[0], self.block_tokens) + k.shape[3:],
                            k.dtype)
             self.cache = self._write_block_j(
-                self.cache, kb, kb, jnp.int32(0), jnp.int32(0))
-        # Admission-wave sampling program (and its eager stack feeder).
-        stacked = jnp.stack([last] * self.n_slots)
-        _firsts, self._rng = self._run_sample(
-            stacked, jnp.asarray(np.zeros(self.n_slots, np.float32)))
+                self.cache, kb, kb, np.int32(0), np.int32(0))
+        # Admission-wave sampling program; every row is padding, so
+        # the carries would come back as they went.
+        _firsts, self._rng, _last, _lens = self._run_sample(
+            (last,) * self.n_slots, np.zeros(self.n_slots, np.float32),
+            np.full(self.n_slots, self.n_slots, np.int32),
+            np.zeros(self.n_slots, np.int32))
         (self.cache, toks, _last, _lens, self._rng) = self._run_decode(
             jnp.zeros(self.n_slots, jnp.int32),
             jnp.zeros(self.n_slots, jnp.int32),
@@ -396,8 +407,9 @@ class LLMEngine:
         def compile_sample():
             # Prefill hands over its last-position logits in cfg.dtype.
             lowered = self._sample_admitted.lower(
-                aval((n, self.cfg.vocab_size), self.cfg.dtype),
-                aval((n,), _jnp.float32), rng_aval)
+                (aval((self.cfg.vocab_size,), self.cfg.dtype),) * n,
+                aval((n,), _jnp.float32), rng_aval, aval((n,)),
+                aval((n,)), aval((n,)), aval((n,)))
             return "sample", lowered.compile()
 
         def compile_read_rows(rows):
@@ -431,6 +443,9 @@ class LLMEngine:
     # raises — it is never turned into a recompile in the serving window.
 
     def _run_prefill(self, tokens, slot, length, start, bucket):
+        # Host values ride the call, as `_run_read_rows`' do.
+        slot, length, start = np.int32(slot), np.int32(length), \
+            np.int32(start)
         compiled = self._prefill_exec.get(bucket)
         if compiled is not None:
             return compiled(self.params, self.cache, tokens, slot, length,
@@ -443,9 +458,10 @@ class LLMEngine:
         return fn(self.params, self.cache, last, lengths, temps, topks,
                   self._rng)
 
-    def _run_sample(self, logits, temps):
+    def _run_sample(self, rows, temps, slots, lengths):
         fn = self._sample_exec or self._sample_admitted
-        return fn(logits, temps, self._rng)
+        return fn(rows, temps, self._rng, self._dev_last,
+                  self._dev_lengths, slots, lengths)
 
     def _run_read_rows(self, slot, start, rows):
         # Host scalars ride the call: a third off the dispatch against
@@ -458,15 +474,26 @@ class LLMEngine:
 
     # -- compiled bodies -------------------------------------------------
 
-    def _sample_admitted_impl(self, logits, temps, rng):
-        """logits [n_slots, vocab], temps [n_slots] → first token per
-        row (greedy at temp 0). Rows beyond the admitted count are
-        padding and ignored host-side."""
+    def _sample_admitted_impl(self, rows, temps, rng, last, lengths,
+                              slots, prompt_lengths):
+        """A wave's rows, one an admitted request: `rows`, n_slots
+        prefill outputs of [vocab] logits (stacked here: an eager
+        `jnp.stack` of arrays still being computed waits for them),
+        and temps → first token per row (greedy at temp 0), also
+        written with the row's prompt length into the decode carries
+        `last` and `lengths` at the row's slot, so that the next decode
+        block is fed from the device. Rows beyond the admitted count
+        are padding: their slot is `n_slots`, which the carries' update
+        drops, and the host ignores their token."""
+        logits = jnp.stack(rows)  # [n_slots, vocab]
         rng, sub = jax.random.split(rng)
         sampled = jax.random.categorical(
             sub, logits / jnp.maximum(temps, 1e-6)[:, None])
-        firsts = jnp.where(temps > 0, sampled, logits.argmax(-1))
-        return firsts.astype(jnp.int32), rng
+        firsts = jnp.where(temps > 0, sampled,
+                           logits.argmax(-1)).astype(jnp.int32)
+        last = last.at[slots].set(firsts, mode="drop")
+        lengths = lengths.at[slots].set(prompt_lengths, mode="drop")
+        return firsts, rng, last, lengths
 
     def _prefill_impl(self, params, cache, tokens, slot, length, start, t):
         """tokens: [1, t] padded prompt tail; writes KV for one slot
@@ -632,7 +659,11 @@ class LLMEngine:
                 # to clients (first tokens and kept decode tokens) and
                 # decode tokens computed for nobody; prompt tokens
                 # prefilled and the bucket sizes paid for them; waves
-                # of admission and requests admitted; KV blocks and
+                # of admission, those of them that found a decode block
+                # in flight and left it there, the slot-steps of blocks
+                # dropped because the block was older than the slot's
+                # request (part of the discarded), and requests
+                # admitted; KV blocks and
                 # bytes read back for the prefix cache (counted when
                 # the block is created), the requests whose read-back
                 # left the wave, and those of them that were waited
@@ -650,8 +681,15 @@ class LLMEngine:
         """The loop is tiled by `critical_path` spans, so that a
         profiler's trace shows under each of the device's idle gaps
         what the loop was doing: `engine.admit_wave` (with its parts as
-        children), `engine.decode_dispatch`, `engine.token_fetch`,
-        `engine.consume_block`, `engine.idle_wait`."""
+        children), `engine.decode_dispatch`, `engine.token_fetch` (the
+        wait for a decode block, and for a wave's first tokens with
+        their delivery as its child `engine.first_tokens`),
+        `engine.consume_block`, `engine.idle_wait`.
+
+        A wave only dispatches, so the decode pipeline runs through it:
+        wave (prefills and the sample program, queued behind block N in
+        flight), dispatch of block N+1, fetch and delivery of block N,
+        fetch and delivery of the wave's first tokens."""
         self._temps_arr = np.zeros(self.n_slots, np.float32)
         self._topks_arr = np.zeros(self.n_slots, np.int32)
         while self._running.is_set():
@@ -693,11 +731,16 @@ class LLMEngine:
             return self._admit_wave(wave)
 
     def _admit_wave(self, wave) -> bool:
-        """One wave of admission: everything here holds decode off."""
-        # Admission invalidates the device carries and needs free slots:
-        # drain the in-flight decode block first.
+        """One wave of admission. It dispatches and returns: the
+        prefills, the first tokens' sampling and the decode carries'
+        update are programs queued on the device behind the decode
+        block in flight, and nothing here waits for a result. The first
+        tokens reach the host in `_deliver_first_tokens`."""
         with critical_path.span("engine.flush_pending"):
-            self._flush_pending()
+            # The block in flight stays in flight. Its tokens for a
+            # slot admitted here belong to the request before, and
+            # `_consume_block` drops them by the block's owners.
+            behind = self._pending_block is not None
         totals = self._totals
         drained: List[_Request] = []
         while True:
@@ -709,14 +752,13 @@ class LLMEngine:
         # point: interactive (0) outranks normal (1) outranks batch (2);
         # FIFO within a class via the monotonic request id.
         drained.sort(key=lambda r: (r.priority, r.request_id))
-        staged = []  # (req, slot, t_real, last_logits_ref, chain)
+        staged = []  # (req, slot, last_logits_ref, chain)
         leftover: List[_Request] = []
         for req in drained:
             if not self._free_slots:
                 leftover.append(req)
                 continue
             prompt = req.prompt
-            t_real = len(prompt)
             slot = self._free_slots.pop()
             # Stage: admit = time spent queued for a slot.
             t_admit = critical_path.clock()
@@ -739,80 +781,58 @@ class LLMEngine:
                 tokens = np.zeros((1, bucket), np.int32)
                 tokens[0, :t_tail] = tail
                 self.cache, last_logits = self._run_prefill(
-                    jnp.asarray(tokens), jnp.int32(slot),
-                    jnp.int32(t_tail), jnp.int32(m_tok), bucket)
+                    tokens, slot, t_tail, m_tok, bucket)
             totals["prefill_tokens_real"] += t_tail
             totals["prefill_tokens_bucketed"] += bucket
-            req.t_prefill_done = critical_path.clock()
-            staged.append((req, slot, t_real, last_logits, chain))
-        for req in leftover:
-            self._queue.put(req)
-        wave.set(admitted=len(staged), left_over=len(leftover))
-        if not staged:
-            return False
-        totals["admit_waves"] += 1
-        totals["admissions"] += len(staged)
-        totals["tokens_kept"] += len(staged)  # the first tokens, below
-        # ONE device-side sampling + ONE host sync for the whole wave:
-        # per-admit argmax fetches would serialize a host round-trip
-        # per request. Padded to
-        # n_slots so the program (and the eager stack feeding it) has
-        # one fixed shape, compiled once at warmup.
-        pad = self.n_slots - len(staged)
-        sync = critical_path.begin("engine.sample_sync")
-        logits = jnp.stack([s[3] for s in staged]
-                           + [staged[0][3]] * pad)  # [n_slots, vocab]
-        temps_np = np.zeros(self.n_slots, np.float32)
-        for i, s in enumerate(staged):
-            temps_np[i] = s[0].params.temperature
-        t_sample = critical_path.clock()
-        firsts_dev, self._rng = self._run_sample(
-            logits, jnp.asarray(temps_np))
-        # The host sync below is where the wave's ASYNC-dispatched
-        # prefill compute actually completes; the fused sample kernel
-        # is trivial next to a transformer prefill, so the sync wait is
-        # attributed to each staged request's prefill stage (split
-        # evenly across the wave). The residual — dispatch overhead of
-        # the batched sample path — is the first-token stage. The two
-        # splits tile the wave's wall time, so the per-request vector
-        # still sums to what the request actually spent here.
-        firsts = np.asarray(firsts_dev)[:len(staged)]
-        critical_path.end(sync)
-        now = critical_path.clock()
-        sync_share = (now - t_sample) / len(staged)
-        for (req, slot, t_real, _, _chain), first in zip(staged, firsts):
-            critical_path.record_stage(
-                req.trace_id, "llm.prefill",
-                (req.t_prefill_done - req.t_kv_done) + sync_share)
-            critical_path.record_stage(
-                req.trace_id, "llm.first_token",
-                max(0.0, t_sample - req.t_prefill_done))
-            first = int(first)
-            req.t_first_token = now
-            req.tokens.append(first)
-            req.out_queue.put(first)
             with self._lock:
                 req.slot = slot
                 self._slot_req[slot] = req
-                self._lengths[slot] = t_real
-                self._last_token[slot] = first
+                self._lengths[slot] = len(prompt)
                 self._active[slot] = True
                 self._temps_arr[slot] = req.params.temperature
                 self._topks_arr[slot] = max(0, min(req.params.top_k,
                                                    _TOP_K_MAX))
-            if self._finished(req, first):
-                self._retire(slot)
-        # The prefix cache admits the prompts' blocks AFTER the
-        # first-token wave (TTFT is not taxed), and here only starts
-        # their read-back: one gather and an asynchronous copy a
-        # request; `_decode_once` finishes it on the host while a decode
-        # block runs. Safe ordering: a slot retired above cannot be
-        # re-admitted until a LATER _admit call, so the KV bytes being
-        # gathered are still this request's prefill output.
-        for req, slot, _t_real, _logits, chain in staged:
+            staged.append((req, slot, last_logits, chain))
+        for req in leftover:
+            self._queue.put(req)
+        wave.set(admitted=len(staged), left_over=len(leftover),
+                 behind_block=int(behind))
+        if not staged:
+            return False
+        totals["admit_waves"] += 1
+        totals["admit_waves_behind_block"] += behind
+        totals["admissions"] += len(staged)
+        # ONE device-side sampling for the whole wave, padded to
+        # n_slots rows so the program has one fixed shape, compiled
+        # once at warmup. The same program puts each first token and
+        # prompt length into the decode carries, and the tokens' copy
+        # to the host is started here and waited for behind the next
+        # decode dispatch.
+        with critical_path.span("engine.sample_dispatch"):
+            pad = self.n_slots - len(staged)
+            rows = tuple(s[2] for s in staged) + (staged[0][2],) * pad
+            temps = np.zeros(self.n_slots, np.float32)
+            slots = np.full(self.n_slots, self.n_slots, np.int32)
+            lengths = np.zeros(self.n_slots, np.int32)
+            for i, (req, slot, _logits, _chain) in enumerate(staged):
+                temps[i] = req.params.temperature
+                slots[i] = slot
+                lengths[i] = len(req.prompt)
+            (firsts, self._rng, self._dev_last,
+             self._dev_lengths) = self._run_sample(
+                rows, temps, slots, lengths)
+            firsts.copy_to_host_async()
+            self._first_tokens = (
+                firsts, [(req, slot) for req, slot, _, _ in staged])
+        # The prefix cache admits the prompts' blocks after the prefill
+        # is dispatched, and here only starts their read-back: one
+        # gather and an asynchronous copy a request, queued behind that
+        # prefill; `_decode_once` finishes it on the host while a decode
+        # block runs. Safe ordering: the slot cannot be admitted again
+        # before a LATER wave, so the KV bytes being gathered are this
+        # request's prefill output.
+        for req, slot, _logits, chain in staged:
             self._prefix_admit(req, slot, chain)
-        # Host state changed: rebuild device carries on the next decode.
-        self._dev_last = self._dev_lengths = None
         return True
 
     def _decode_once(self):
@@ -823,29 +843,31 @@ class LLMEngine:
         active = int(self._active.sum())
         with critical_path.span("engine.decode_dispatch", active=active,
                                 n_slots=self.n_slots):
-            last = self._dev_last if self._dev_last is not None \
-                else jnp.asarray(self._last_token)
-            lengths = self._dev_lengths if self._dev_lengths is not None \
-                else jnp.asarray(self._lengths)
+            prev = self._pending_block
             (self.cache, next_tokens, self._dev_last, self._dev_lengths,
              self._rng) = self._run_decode(
-                last, lengths,
+                self._dev_last, self._dev_lengths,
                 jnp.asarray(self._temps_arr),
                 jnp.asarray(self._topks_arr))
+            self._pending_block = (next_tokens, [
+                self._slot_req.get(slot) for slot in range(self.n_slots)])
             # The device has a block to run and the host nothing to do
             # but wait for the one before it: the read-backs' host half.
             self._finish_readbacks(
-                self.max_seq // self.block_tokens, self._pending_toks)
+                self.max_seq // self.block_tokens,
+                prev[0] if prev is not None else None)
         self._totals["decode_steps"] += self.decode_steps
         self._totals["active_slot_steps"] += active * self.decode_steps
-        prev, self._pending_toks = self._pending_toks, next_tokens
         if prev is not None:
-            self._consume_block(self._fetch_tokens(prev))
+            self._consume_block(self._fetch_tokens(prev[0]), prev[1])
+        # Dispatched before the block just dispatched, so they reach
+        # their clients before that block is waited for.
+        self._deliver_first_tokens()
 
     def _flush_pending(self):
-        prev, self._pending_toks = self._pending_toks, None
+        prev, self._pending_block = self._pending_block, None
         if prev is not None:
-            self._consume_block(self._fetch_tokens(prev))
+            self._consume_block(self._fetch_tokens(prev[0]), prev[1])
 
     def _fetch_tokens(self, block):
         """A decode block's sampled tokens to the host: the wait for
@@ -853,11 +875,18 @@ class LLMEngine:
         with critical_path.span("engine.token_fetch"):
             return np.asarray(block)
 
-    def _consume_block(self, next_host):
-        kept = 0
+    def _consume_block(self, next_host, owners):
+        """Hand a block's tokens to the requests it was dispatched for.
+        `owners` are the slots' requests at its dispatch: a slot that
+        holds another request by now (admitted while the block was in
+        flight, into a slot retired before) gets none of them."""
+        kept = stale = 0
         with critical_path.span("engine.consume_block") as sp, self._lock:
             for slot in np.nonzero(self._active)[0]:
                 req = self._slot_req[slot]
+                if owners[slot] is not req:
+                    stale += next_host.shape[1]
+                    continue
                 # Walk this slot's K-token block; once the request
                 # finishes mid-block the remaining tokens are padding
                 # compute and are discarded.
@@ -867,7 +896,6 @@ class LLMEngine:
                     req.out_queue.put(tok)  # raylint: disable=R2 -- per-request stream queues are unbounded, so put() cannot block; token delivery and slot-state mutation must share one hold or a racing admit could reuse the slot mid-block
                     kept += 1
                     self._lengths[slot] += 1
-                    self._last_token[slot] = tok
                     if self._finished(req, tok) or \
                             self._lengths[slot] >= self.max_seq - 1:
                         self._retire(slot)  # raylint: disable=R2 -- _retire only pushes the unbounded-queue end-of-stream sentinel and frees the slot; both must be atomic with the walk above
@@ -875,7 +903,44 @@ class LLMEngine:
             discarded = next_host.size - kept
             self._totals["tokens_kept"] += kept
             self._totals["tokens_discarded"] += discarded
-            sp.set(kept=kept, discarded=discarded)
+            self._totals["slot_steps_stale"] += stale
+            sp.set(kept=kept, discarded=discarded, stale=stale)
+
+    def _deliver_first_tokens(self):
+        """The last wave's first tokens to their clients: the wait for
+        the wave's prefills (queued behind the block that was in flight
+        at the wave), then for each admitted request its token, its
+        `llm.prefill` stage (from its dispatch to the token on the
+        host) and `llm.first_token` stage (from there to the client's
+        queue). A request that ends on its first token is retired here;
+        the block already dispatched for its slot is dropped by
+        `_consume_block`'s owners."""
+        pending, self._first_tokens = self._first_tokens, None
+        if pending is None:
+            return
+        firsts_dev, staged = pending
+        with critical_path.span("engine.token_fetch"):
+            firsts = np.asarray(firsts_dev)
+            t_host = critical_path.clock()
+            with critical_path.span("engine.first_tokens",
+                                    admitted=len(staged)) as sp:
+                ended = 0
+                for (req, slot), first in zip(staged, firsts):
+                    first = int(first)
+                    critical_path.record_stage(
+                        req.trace_id, "llm.prefill",
+                        t_host - req.t_kv_done)
+                    req.t_first_token = critical_path.clock()
+                    critical_path.record_stage(
+                        req.trace_id, "llm.first_token",
+                        req.t_first_token - t_host)
+                    req.tokens.append(first)
+                    req.out_queue.put(first)
+                    if self._finished(req, first):
+                        self._retire(slot)
+                        ended += 1
+                self._totals["tokens_kept"] += len(staged)
+                sp.set(ended=ended)
 
     def _finished(self, req: _Request, token: int) -> bool:
         if token in req.params.stop_token_ids:
@@ -959,15 +1024,15 @@ class LLMEngine:
             payloads.append(p)
         for h, (k_np, v_np) in zip(hit, payloads):
             self.cache = self._write_block_j(
-                self.cache, jnp.asarray(k_np), jnp.asarray(v_np),
-                jnp.int32(slot), jnp.int32(h.index * self.block_tokens))
+                self.cache, k_np, v_np, np.int32(slot),
+                np.int32(h.index * self.block_tokens))
         pc.release(hit)
         return len(hit) * self.block_tokens, chain
 
     def _prefix_admit(self, req: _Request, slot: int, chain):
-        """After the first-token wave (TTFT never pays for it), admit
-        the prompt's full-block chain and start the read-back of the
-        blocks that created. The created blocks are pinned for just
+        """After the wave's sample program is dispatched (the first
+        token never waits for it), admit the prompt's full-block chain
+        and start the read-back of the blocks that created. The created blocks are pinned for just
         that long, and what the admission evicted leaves the host store
         at once, so the core sees the holds and the order it saw when
         the read-back was finished here."""

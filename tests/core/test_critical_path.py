@@ -139,7 +139,7 @@ def test_span_records_start_end_parent_and_attributes():
         with critical_path.span("engine.prefill_dispatch", real=5,
                                 bucket=8):
             time.sleep(0.002)
-        sp = critical_path.begin("engine.sample_sync")
+        sp = critical_path.begin("engine.sample_dispatch")
         other = threading.Thread(
             target=lambda: critical_path.span("data.block_fetch")
             .__enter__().__exit__(None, None, None))
@@ -151,7 +151,7 @@ def test_span_records_start_end_parent_and_attributes():
     spans = {s["stage"]: s
              for s in flight_recorder.local_snapshot()["spans"]}
     assert set(spans) == {"engine.admit_wave", "engine.prefill_dispatch",
-                          "engine.sample_sync", "data.block_fetch"}
+                          "engine.sample_dispatch", "data.block_fetch"}
     for s in spans.values():
         assert t_before <= s["t0"] <= s["t1"] <= t_after
         assert s["t"] == s["t1"]
@@ -159,13 +159,13 @@ def test_span_records_start_end_parent_and_attributes():
     wave_id = spans["engine.admit_wave"]["id"]
     assert spans["engine.admit_wave"]["parent"] == 0
     assert spans["engine.prefill_dispatch"]["parent"] == wave_id
-    assert spans["engine.sample_sync"]["parent"] == wave_id
+    assert spans["engine.sample_dispatch"]["parent"] == wave_id
     assert spans["data.block_fetch"]["parent"] == 0  # its own thread
     assert spans["engine.prefill_dispatch"]["attrs"] == \
         {"real": 5, "bucket": 8}
     assert spans["engine.admit_wave"]["attrs"] == \
         {"admitted": 1, "left_over": 0}
-    assert spans["engine.sample_sync"]["attrs"] == {"admitted": 1}
+    assert spans["engine.sample_dispatch"]["attrs"] == {"admitted": 1}
     # A thin record made inside a span names it as parent too.
     with critical_path.span("engine.admit_wave") as wave:
         critical_path.record_stage("", "llm.admit", 0.01)

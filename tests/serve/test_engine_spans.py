@@ -1,4 +1,5 @@
-"""The engine loop's spans and counters (PR 24): the spans tile the
+"""The engine loop's spans and counters (PR 24; PR 31: a wave only
+dispatches): the spans tile the
 loop, the totals of `metrics()` add up to what the clients got, a
 profiler's trace carries the spans under their own names on the ring's
 clock, and the proxy's envelope gives `front.ttft_self`.
@@ -33,7 +34,11 @@ _LOOP_SPANS = {"engine.admit_wave", "engine.decode_dispatch",
                "engine.token_fetch", "engine.consume_block",
                "engine.idle_wait"}
 _WAVE_SPANS = {"engine.flush_pending", "engine.prefix_copy_in",
-               "engine.prefill_dispatch", "engine.sample_sync"}
+               "engine.prefill_dispatch", "engine.sample_dispatch"}
+# A wave's first tokens reach their clients behind the next decode
+# dispatch (PR 31): the wait is an `engine.token_fetch` of its own and
+# the delivery its child.
+_FIRST_TOKENS = "engine.first_tokens"
 # The read-back's two halves (PR 28): the dispatch in a wave, the
 # completion under the loop's span that shadows it.
 _READBACK = "engine.prefix_readback"
@@ -126,15 +131,37 @@ def test_spans_tile_the_loop_and_totals_add_up(params, ring):
     by_name = {}
     for s in spans:
         by_name.setdefault(s["stage"], []).append(s)
-    assert set(by_name) >= (_LOOP_SPANS | _WAVE_SPANS | {_READBACK}) - \
+    assert set(by_name) >= (_LOOP_SPANS | _WAVE_SPANS
+                            | {_READBACK, _FIRST_TOKENS}) - \
         {"engine.idle_wait"}, sorted(by_name)
 
-    # The loop's own spans have no parent; a wave's parts name the wave.
+    # The loop's own spans have no parent; a wave's parts name the wave,
+    # and a delivery of first tokens the fetch that waited for them.
     waves = {s["id"] for s in by_name["engine.admit_wave"]}
     for name in _WAVE_SPANS:
         assert all(s["parent"] in waves for s in by_name[name]), name
     top = [s for s in spans if s["parent"] == 0]
     assert {s["stage"] for s in top} <= _LOOP_SPANS
+    fetches = {s["id"] for s in by_name["engine.token_fetch"]}
+    deliveries = by_name[_FIRST_TOKENS]
+    assert all(s["parent"] in fetches for s in deliveries)
+    # One delivery a wave, after the wave and before the next one.
+    admitting = sorted((s for s in by_name["engine.admit_wave"]
+                        if s["attrs"]["admitted"]), key=lambda s: s["t0"])
+    deliveries.sort(key=lambda s: s["t0"])
+    assert len(deliveries) == len(admitting)
+    for wave, delivery, nxt in zip(admitting, deliveries,
+                                   admitting[1:] + [None]):
+        assert wave["t1"] <= delivery["t0"]
+        assert nxt is None or delivery["t1"] <= nxt["t0"]
+        assert delivery["attrs"]["admitted"] == wave["attrs"]["admitted"]
+
+    # A wave waits for nothing: what it still does about the block in
+    # flight is look at it, and with a 4 ms step all waves but the
+    # first land behind one.
+    assert max(s["dur_s"] for s in by_name["engine.flush_pending"]) < 1e-3
+    behind = sum(s["attrs"]["behind_block"] for s in admitting)
+    assert totals["admit_waves_behind_block"] == behind >= 4
     # A read-back is dispatched in a wave and finished under a decode
     # dispatch (or the idle wait), so the loop's tiling covers both.
     shadows = {s["id"] for name in ("engine.decode_dispatch",
@@ -166,12 +193,26 @@ def test_spans_tile_the_loop_and_totals_add_up(params, ring):
     assert totals["active_slot_steps"] == sum(
         s["attrs"]["active"] for s in steps)
     assert 0 < totals["active_slot_steps"] <= totals["decode_steps"] * 2
-    kept = sum(s["attrs"]["kept"] for s in by_name["engine.consume_block"])
-    discarded = sum(s["attrs"]["discarded"]
-                    for s in by_name["engine.consume_block"])
-    assert kept + totals["admissions"] == totals["tokens_kept"]
+    blocks = by_name["engine.consume_block"]
+    kept = sum(s["attrs"]["kept"] for s in blocks)
+    discarded = sum(s["attrs"]["discarded"] for s in blocks)
+    stale = sum(s["attrs"]["stale"] for s in blocks)
+    # First tokens are counted where they are delivered.
+    delivered = sum(s["attrs"]["admitted"] for s in deliveries)
+    assert delivered == totals["admissions"]
+    assert kept + delivered == totals["tokens_kept"]
     assert discarded == totals["tokens_discarded"]
-    assert kept + discarded == 2 * len(by_name["engine.consume_block"])
+    assert kept + discarded == 2 * len(blocks)
+    # Every decode step's block was consumed, but the last one in
+    # flight when the engine stopped.
+    assert kept + discarded in (2 * len(steps), 2 * (len(steps) - 1))
+    # A wave behind a block admits into slots the block ran for the
+    # requests before: one stale slot-step an admission, part of the
+    # discarded; none ended on its first token.
+    assert stale == totals["slot_steps_stale"] <= discarded
+    assert stale == sum(s["attrs"]["admitted"] for s in admitting
+                        if s["attrs"]["behind_block"])
+    assert not any(s["attrs"]["ended"] for s in deliveries)
     prefills = by_name["engine.prefill_dispatch"]
     assert totals["prefill_tokens_real"] == sum(
         s["attrs"]["real"] for s in prefills) == sum(map(len, prompts))
@@ -232,7 +273,7 @@ def test_trace_carries_the_spans_on_the_rings_clock(params, ring,
                          for e in line.events
                          if e.name.startswith("engine.")]
     assert {name for name, _, _ in seen} == \
-        _LOOP_SPANS | _WAVE_SPANS | {_READBACK}
+        _LOOP_SPANS | _WAVE_SPANS | {_READBACK, _FIRST_TOKENS}
     # Every annotation is some ring record's twin: same name, same
     # attributes, starts within 1 ms of the record's t0.
     for name, rel_ns, stats in seen:

@@ -197,6 +197,147 @@ def test_retired_slot_reuse_never_leaks_prior_tokens(model):
     engine.stop()
 
 
+# -- PR 31: a wave only dispatches, behind the decode block in flight -------
+#
+# The block in flight at a wave was dispatched for the slots' requests
+# of that moment; a request admitted into a retired slot must get none
+# of its tokens, its own first token comes from the device when the
+# prefill is done, and the decode carries are fed on the device.
+
+
+class _SlowDecode(LLMEngine):
+    """A decode dispatch that takes what a small model's step takes on
+    a chip, so that clients enqueue while a block is in flight."""
+
+    def _run_decode(self, last, lengths, temps, topks):
+        time.sleep(0.004)
+        return super()._run_decode(last, lengths, temps, topks)
+
+
+@pytest.mark.parametrize("decode_steps", [1, 4])
+def test_admitted_behind_a_block_in_flight_matches_naive(model,
+                                                         decode_steps):
+    """Requests admitted while other slots decode, into slots retired a
+    block earlier, answer token for token what the full forward pass
+    does; answers that end in the middle of a block of 4 included."""
+    cfg, params = model
+    engine = _SlowDecode(cfg, params, max_batch_size=3, max_seq_len=64,
+                         decode_steps=decode_steps)
+    prompts = [[(5 * i + j) % 97 + 1 for j in range(2 + i % 5)]
+               for i in range(9)]
+    lengths = [9, 14, 6, 11, 5, 7, 10, 3, 6]
+    expected = [naive_greedy(cfg, params, p, n)
+                for p, n in zip(prompts, lengths)]
+    results = [None] * len(prompts)
+
+    def worker(i):
+        results[i] = engine.generate(prompts[i],
+                                     SamplingParams(max_tokens=lengths[i]))
+
+    threads = [threading.Thread(target=worker, args=(i,))
+               for i in range(len(prompts))]
+    for t in threads:
+        t.start()
+        time.sleep(0.006)  # arrivals spread over the others' decoding
+    for t in threads:
+        t.join(timeout=120)
+    totals = engine.metrics()["totals"]
+    engine.stop()
+    assert results == expected
+    # The mechanism did engage: waves found a block in flight and left
+    # it there, and that block's steps for the slots they admitted into
+    # were dropped, not delivered.
+    assert totals["admit_waves_behind_block"] >= 3
+    assert totals["slot_steps_stale"] >= 3 * decode_steps
+    assert totals["slot_steps_stale"] <= totals["tokens_discarded"]
+    assert totals["tokens_kept"] == sum(lengths)
+
+
+def test_block_dispatched_before_an_admission_gives_it_no_token(model):
+    """`test_retired_slot_reuse_never_leaks_prior_tokens` with the
+    second request waiting while the first decodes: it is admitted into
+    the one slot behind a block that was dispatched for the first, and
+    every token of that block is counted as stale, none delivered."""
+    cfg, params = model
+    engine = _SlowDecode(cfg, params, max_batch_size=1, max_seq_len=64)
+    long_prompt, short_prompt = list(range(1, 25)), [42, 7]
+    exp_long = naive_greedy(cfg, params, long_prompt, 6)
+    exp_short = naive_greedy(cfg, params, short_prompt, 6)
+    got = {}
+    first = threading.Thread(target=lambda: got.update(
+        long=engine.generate(long_prompt, SamplingParams(max_tokens=6))))
+    first.start()
+    while engine.metrics()["active_slots"] == 0:
+        time.sleep(0.001)
+    got["short"] = engine.generate(short_prompt,
+                                   SamplingParams(max_tokens=6))
+    first.join(timeout=60)
+    totals = engine.metrics()["totals"]
+    engine.stop()
+    assert got == {"long": exp_long, "short": exp_short}
+    assert totals["admit_waves"] == 2
+    assert totals["admit_waves_behind_block"] == 1
+    assert totals["slot_steps_stale"] == 1
+    assert totals["tokens_kept"] == 12
+
+
+@pytest.mark.parametrize("how", ["max_tokens", "stop_id"])
+def test_request_that_ends_on_its_first_token(model, how):
+    """One token, the end of the stream, the slot free again, and the
+    next request in that slot exact: the decode block already
+    dispatched for the ended request is dropped."""
+    cfg, params = model
+    prompt, follower = [3, 17, 42, 8], [9, 8, 7]
+    first = naive_greedy(cfg, params, prompt, 1)
+    exp_follower = naive_greedy(cfg, params, follower, 5)
+    one = SamplingParams(max_tokens=1) if how == "max_tokens" else \
+        SamplingParams(max_tokens=8, stop_token_ids=(first[0],))
+    engine = _SlowDecode(cfg, params, max_batch_size=1, max_seq_len=64)
+    assert engine.generate(prompt, one) == first
+    m = engine.metrics()
+    assert m["active_slots"] == 0 and m["free_slots"] == 1
+    assert engine.generate(follower,
+                           SamplingParams(max_tokens=5)) == exp_follower
+    # Again with the follower waiting for the slot while the one-token
+    # request holds it.
+    got = {}
+    waiter = threading.Thread(target=lambda: got.update(
+        follower=engine.generate(follower, SamplingParams(max_tokens=5))))
+    stream = engine.generate(prompt, one, stream=True)
+    waiter.start()
+    assert list(stream) == first
+    waiter.join(timeout=60)
+    totals = engine.metrics()["totals"]
+    engine.stop()
+    assert got["follower"] == exp_follower
+    assert totals["admissions"] == 4
+    assert totals["tokens_kept"] == 2 * 1 + 2 * 5
+
+
+def test_first_token_is_streamed_before_any_decode_block_is_fetched(model):
+    """The first token reaches the stream when its prefill is done: with
+    every decode block's fetch held back it is there all the same, and
+    the rest follow it in order once the blocks are let through."""
+    cfg, params = model
+    gate = threading.Event()
+
+    class Gated(LLMEngine):
+        def _fetch_tokens(self, block):
+            assert gate.wait(timeout=60)
+            return super()._fetch_tokens(block)
+
+    prompt = [4, 2, 11]
+    expected = naive_greedy(cfg, params, prompt, 5)
+    engine = Gated(cfg, params, max_batch_size=2, max_seq_len=64)
+    stream = engine.generate(prompt, SamplingParams(max_tokens=5),
+                             stream=True)
+    assert next(stream) == expected[0]
+    assert engine.metrics()["totals"]["tokens_kept"] == 1
+    gate.set()
+    assert list(stream) == expected[1:]
+    engine.stop()
+
+
 def test_prefix_cache_greedy_identical_and_hits(model, monkeypatch):
     """The tentpole's correctness bar: greedy output is TOKEN-IDENTICAL
     with the prefix cache on vs off (copied-in KV blocks are
