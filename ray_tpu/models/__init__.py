@@ -13,6 +13,10 @@ first-class compiled models to schedule.
   `decoder` with self-attention or attention through the slot cache
 - ``moe``   — sparse-expert decoder (Mixtral, OLMoE): `decoder` with
   `llama`'s self-attention and the dropless expert layer
+- ``glm_dsa`` — GLM-5.2's decoder, served: `decoder` over runs of unlike
+  layers with latent attention, the sparse-attention indexer, and
+  `moe`'s expert layer with a shared expert and a held share
+- ``serving`` — which configs have a cached forward pass, for the engine
 - ``mlp``   — small MLP classifier (the fashion-MNIST baseline workload)
 - ``training`` — TrainState + sharded train-step factory
 - ``hf``, ``memory_plan`` — Hugging Face weight import; HBM planning

@@ -4,15 +4,20 @@ A block is norm, *sequence mixer*, residual, norm, *FFN*, residual
 (`block`, the only place that opens the `attn` and `mlp` scopes). What
 differs between architectures is handed in as two functions:
 
-- ``mixer(h, lp, rope, state) -> (attn [B, S, H, K], state)``: normed
-  activations and the layer's parameters to the attention output before
-  the `wo` projection. `rope` is the stack's `(cos, sin)`; `state` is
-  what the mixer carries per layer (a slot cache's K and V), or None.
+- ``mixer(h, lp, rope, state, handed) -> (attn [B, S, H, K], state,
+  handed)``: normed activations and the layer's parameters to the
+  attention output before the `wo` projection. `rope` is the stack's
+  `(cos, sin)`; `state` is what the mixer carries per layer (a slot
+  cache's K and V), or None; `handed` is what a layer hands up to the
+  layer above beside `x` (a sparse-attention layer's selection), None
+  in a stack that hands nothing on.
 - ``ffn(h, lp) -> (out [B, S, D], extras)``: `extras` is a pytree the
   layer reports (an expert layer's aux loss and counts), or None.
 
-Around it: the parameter skeleton, the stack (`hidden`), the output head
-(`logits`) and the loss tail (`loss`). No architecture is known here:
+Around it: the parameter skeleton, the stack (`hidden`: one run of
+like layers; `hidden_runs`: several, each with its own mixer, FFN,
+parameters and state), the output head (`logits`) and the loss tail
+(`loss`). No architecture is known here:
 `llama.py` and `moe.py` compose this with their mixers and FFNs. `cfg`
 is any config with `LlamaConfig`'s fields.
 """
@@ -73,14 +78,15 @@ def init_params_sharded(init, axes, mesh, rng, rules=DEFAULT_RULES):
         rng)
 
 
-def block(mixer, ffn, cfg, rope, x, lp, state=None, *, mesh=None,
-          rules=DEFAULT_RULES):
-    """One transformer block. x: [B, S, D] -> (x, state, extras). The
-    two halves are scoped (`attn`, `mlp`) so that a device trace can
-    tell their ops apart."""
+def block(mixer, ffn, cfg, rope, x, lp, state=None, handed=None, *,
+          mesh=None, rules=DEFAULT_RULES):
+    """One transformer block. x: [B, S, D] -> (x, state, extras,
+    handed). The two halves are scoped (`attn`, `mlp`) so that a device
+    trace can tell their ops apart. `handed` is what the layer below
+    handed up beside x, None in most stacks."""
     with jax.named_scope("attn"):
         h = rms_norm_reference(x, lp["attn_norm"], cfg.norm_eps)
-        attn, state = mixer(h, lp, rope, state)
+        attn, state, handed = mixer(h, lp, rope, state, handed)
         x = x + jnp.einsum("bshk,hkd->bsd", attn.astype(cfg.dtype),
                            lp["wo"])
     with jax.named_scope("mlp"):
@@ -89,30 +95,34 @@ def block(mixer, ffn, cfg, rope, x, lp, state=None, *, mesh=None,
         x = x + out
     x = with_logical_constraint(x, "batch", "seq", "act_embed",
                                 mesh=mesh, rules=rules)
-    return x, state, extras
+    return x, state, extras, handed
 
 
-def layers(mixer, ffn, cfg, rope, x, stacked, state=None, *,
+def layers(mixer, ffn, cfg, rope, x, stacked, state=None, handed=None, *,
            save: Optional[Sequence[str]] = None, mesh=None,
            rules=DEFAULT_RULES):
     """x through a run of like layers (`stacked`: their parameters, and
     `state`: the mixer's, along a leading axis) by `lax.scan`. Returns
-    (x, state, extras), the last two stacked by layer. `save` is the
-    remat policy: None keeps every activation; a list rematerialises
-    each layer in the backward pass but for the `checkpoint_name`s in it
-    (an empty list saves nothing)."""
-    def body(x, scanned):
+    (x, state, extras, handed), the middle two stacked by layer and
+    `handed` as the last layer left it. `save` is the remat policy: None
+    keeps every activation; a list rematerialises each layer in the
+    backward pass but for the `checkpoint_name`s in it (an empty list
+    saves nothing)."""
+    def body(carry, scanned):
+        x, handed = carry
         lp, layer_state = scanned
-        x, layer_state, extras = block(mixer, ffn, cfg, rope, x, lp,
-                                       layer_state, mesh=mesh, rules=rules)
-        return x, (layer_state, extras)
+        x, layer_state, extras, handed = block(
+            mixer, ffn, cfg, rope, x, lp, layer_state, handed, mesh=mesh,
+            rules=rules)
+        return (x, handed), (layer_state, extras)
 
     if save is not None:
         body = jax.checkpoint(
             body,
             policy=jax.checkpoint_policies.save_only_these_names(*save))
-    x, (state, extras) = lax.scan(body, x, (stacked, state))
-    return x, state, extras
+    (x, handed), (state, extras) = lax.scan(body, (x, handed),
+                                            (stacked, state))
+    return x, state, extras, handed
 
 
 def rope_tables(cfg, positions=None, *, mesh=None, rules=DEFAULT_RULES):
@@ -153,20 +163,39 @@ def _embed_lookup(embed, tokens, mesh, rules):
     return embed[tokens]
 
 
-def hidden(params, tokens, cfg, mixer, ffn, *, mesh=None,
-           rules=DEFAULT_RULES, positions=None, state=None, save=None):
+def hidden_runs(params, tokens, cfg, runs, *, handed=None, mesh=None,
+                rules=DEFAULT_RULES, positions=None, save=None):
     """tokens: [B, S] int32 → (final-norm hidden states [B, S, D] in
-    cfg.dtype, the mixer's state, the FFN's extras, both stacked by
-    layer) — the stack without the output projection, so the loss can
-    fuse projection+CE (`fused_linear_cross_entropy`)."""
+    cfg.dtype, each run's mixer state, each run's FFN extras) — the
+    stack without the output projection. `runs` is a sequence of
+    (mixer, ffn, stacked parameters, state), one `layers` scan each, for
+    a stack whose layers are not all alike: `handed` enters the first
+    layer and every layer's goes to the one above, across runs too."""
     rope = rope_tables(cfg, positions, mesh=mesh, rules=rules)
     x = _embed_lookup(params["embed"], tokens, mesh, rules).astype(cfg.dtype)
     x = with_logical_constraint(x, "batch", "seq", "act_embed",
                                 mesh=mesh, rules=rules)
-    x, state, extras = layers(mixer, ffn, cfg, rope, x, params["layers"],
-                              state, save=save, mesh=mesh, rules=rules)
+    states, extras = [], []
+    for mixer, ffn, stacked, state in runs:
+        x, state, run_extras, handed = layers(
+            mixer, ffn, cfg, rope, x, stacked, state, handed, save=save,
+            mesh=mesh, rules=rules)
+        states.append(state)
+        extras.append(run_extras)
     return (rms_norm_reference(x, params["final_norm"], cfg.norm_eps),
-            state, extras)
+            states, extras)
+
+
+def hidden(params, tokens, cfg, mixer, ffn, *, mesh=None,
+           rules=DEFAULT_RULES, positions=None, state=None, save=None):
+    """`hidden_runs` of a stack that is one run of like layers,
+    `params["layers"]`: (hidden states, the mixer's state, the FFN's
+    extras, both stacked by layer), so the loss can fuse projection+CE
+    (`fused_linear_cross_entropy`)."""
+    x, (state,), (extras,) = hidden_runs(
+        params, tokens, cfg, [(mixer, ffn, params["layers"], state)],
+        mesh=mesh, rules=rules, positions=positions, save=save)
+    return x, state, extras
 
 
 def _head_weight(params, cfg):

@@ -1,0 +1,507 @@
+"""GLM-5.2's decoder (`model_type` `glm_moe_dsa`, the DeepSeek-V3.2
+family), served through the slot cache: `decoder` with latent attention
+whose keys a learned indexer selects, a dense SwiGLU in the leading
+layers and `moe`'s expert layer (sigmoid router, a shared expert, a
+held share of the experts) in the rest.
+
+A layer, on normed activations `a` (`benchmark/references/glm_dsa.py`
+has the equations in full):
+
+- *Latent attention.* Queries go through a `q_lora_rank` bottleneck
+  with an RMSNorm; keys and values are one `kv_lora_rank` latent a token
+  (RMS-normed) and one rotary key of `qk_rope_head_dim` shared by all
+  heads. The slot cache holds just those two, 576 numbers a token and
+  layer. `wkvb` would expand a latent into each head's key and value;
+  here its key half is absorbed into the query and its value half is
+  applied to the attention output, so scores and the weighted sum run
+  against the cached latent itself, for prefill and decode alike.
+- *The indexer*, on a `full` layer: `index_n_heads` small queries from
+  the query bottleneck, one key a token (LayerNorm, cached beside the
+  latent), I[t, s] = sum_j w[t, j] relu(q[t, j] . k[s]) in float32 over
+  the keys s <= t, and the `index_topk` largest I of a row are the keys
+  that row attends (all of them while there are no more). A `shared`
+  layer has no indexer and attends the keys the nearest `full` layer
+  below it chose (IndexShare): the selection is the value `decoder`
+  hands from layer to layer, a mask [B, T, S].
+- *Selection* is by the k-th largest score, found by bisection on the
+  scores' bit patterns (32 compare-and-count passes, exact): a mask of
+  the keys above it and the first of those tied with it, which are the
+  keys `lax.top_k` returns; never a sort and never a gather of keys.
+- *Attention* over the selected keys is masked dense: by blocks of
+  queries and, inside, blocks of keys up to the last one the block's
+  positions can see, with a running softmax in float32, so that no
+  array of [T, S] a head exists and a row pays for the keys before it,
+  not for the slot's whole region. Rows stand at their own positions
+  (`positions`), as `llama._cached_attention` guarantees.
+
+Layers differ (dense or sparse FFN, `full` or `shared` indexer), so the
+stack is `decoder.hidden_runs` over runs of like layers, parameters and
+cache stacked by run. Not here: the multi-token-prediction layer (a
+draft head, ROADMAP M9), fp8 and the Hadamard rotation of the indexer
+(orthogonal: the scores are the same), an uncached forward pass and a
+loss (the model is served, not trained).
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import functools
+import itertools
+import math
+from typing import Any, Dict, Tuple
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+from jax import lax
+
+from ray_tpu.models import decoder, moe
+from ray_tpu.models.llama import swiglu
+from ray_tpu.ops.norms import layer_norm, rms_norm_reference
+from ray_tpu.parallel.sharding import DEFAULT_RULES
+
+# Queries and keys go through attention in blocks of at most this many.
+_QUERY_BLOCK = 256
+_KEY_BLOCK = 1024
+_INDEX_KEY_EPS = 1e-6
+
+def published_kinds(n_layers: int, first_dense: int = 3, freq: int = 4,
+                    offset: int = 3):
+    """(FFN, indexer) of each layer as GLM-5.2's `config.json` lists
+    them: `first_dense` dense layers, sparse after; the first three
+    indexers `full`, then every `freq`-th."""
+    return tuple(
+        ("dense" if i < first_dense else "sparse",
+         "full" if i < offset or (i - offset) % freq == freq - 1
+         else "shared") for i in range(n_layers))
+
+
+@dataclasses.dataclass(frozen=True)
+class GlmDsaConfig(moe.MoEConfig):
+    """Defaults are GLM-5.2's. `hidden_dim` is an expert's width and
+    `dense_hidden_dim` the leading dense layers'."""
+    vocab_size: int = 154880
+    dim: int = 6144
+    n_layers: int = 78
+    n_heads: int = 64
+    n_kv_heads: int = 64
+    hidden_dim: int = 2048
+    max_seq_len: int = 1048576
+    rope_theta: float = 8e6
+    norm_eps: float = 1e-5
+    n_experts: int = 256
+    n_experts_per_token: int = 8
+    scoring: str = "sigmoid"
+    selection_bias: bool = True
+    gate_scale: float = 2.5
+    shared_hidden_dim: int = 2048
+    dense_hidden_dim: int = 12288
+    q_lora_rank: int = 2048
+    kv_lora_rank: int = 512
+    qk_nope_head_dim: int = 192
+    qk_rope_head_dim: int = 64
+    v_head_dim: int = 256
+    index_n_heads: int = 32
+    index_head_dim: int = 128
+    index_topk: int = 2048
+    # (FFN, indexer) of each layer; () is the published pattern.
+    layer_kinds: Tuple[Tuple[str, str], ...] = ()
+
+    @property
+    def head_dim(self) -> int:
+        """What rotates: `decoder.rope_tables` makes (cos, sin) of this
+        many dimensions, for the rotary part of a head and of the
+        indexer's queries and key alike."""
+        return self.qk_rope_head_dim
+
+    @property
+    def kinds(self):
+        kinds = self.layer_kinds or published_kinds(self.n_layers)
+        assert len(kinds) == self.n_layers and kinds[0][1] == "full", kinds
+        return kinds
+
+    def runs(self):
+        """[((FFN, indexer), layers)]: the stack as runs of like layers."""
+        return [(kind, len(list(group)))
+                for kind, group in itertools.groupby(self.kinds)]
+
+    @staticmethod
+    def debug_glm() -> "GlmDsaConfig":
+        return GlmDsaConfig(
+            vocab_size=512, dim=64, n_layers=3, n_heads=4, n_kv_heads=4,
+            hidden_dim=32, dense_hidden_dim=128, max_seq_len=128,
+            dtype=jnp.float32, n_experts=8, n_experts_per_token=2,
+            shared_hidden_dim=32, q_lora_rank=48, kv_lora_rank=32,
+            qk_nope_head_dim=24, qk_rope_head_dim=8, v_head_dim=16,
+            index_n_heads=4, index_head_dim=16, index_topk=8,
+            layer_kinds=(("dense", "full"), ("sparse", "shared"),
+                         ("sparse", "full")))
+
+
+# ---------------------------------------------------------------------------
+# Parameters
+# ---------------------------------------------------------------------------
+
+
+def _init_layer(cfg: GlmDsaConfig, kind, key) -> Dict[str, Any]:
+    ffn_kind, indexer = kind
+    d, h, r = cfg.dim, cfg.n_heads, cfg.q_lora_rank
+    c, rope = cfg.kv_lora_rank, cfg.qk_rope_head_dim
+    ks = jax.random.split(key, 12)
+    init = jax.nn.initializers.normal(stddev=0.02)
+    lp = {
+        "attn_norm": jnp.ones(d, cfg.dtype),
+        "wqa": init(ks[0], (d, r), cfg.dtype),
+        "q_norm": jnp.ones(r, cfg.dtype),
+        "wqb": init(ks[1], (r, h, cfg.qk_nope_head_dim + rope), cfg.dtype),
+        "wkva": init(ks[2], (d, c + rope), cfg.dtype),
+        "kv_norm": jnp.ones(c, cfg.dtype),
+        "wkvb": init(ks[3], (c, h, cfg.qk_nope_head_dim + cfg.v_head_dim),
+                     cfg.dtype),
+        "wo": init(ks[4], (h, cfg.v_head_dim, d), cfg.dtype) * d ** -0.5,
+        "mlp_norm": jnp.ones(d, cfg.dtype),
+    }
+    if indexer == "full":
+        lp.update(
+            wiq=init(ks[5], (r, cfg.index_n_heads, cfg.index_head_dim),
+                     cfg.dtype),
+            wik=init(ks[6], (d, cfg.index_head_dim), cfg.dtype),
+            ik_norm=jnp.ones(cfg.index_head_dim, cfg.dtype),
+            ik_bias=jnp.zeros(cfg.index_head_dim, cfg.dtype),
+            wiw=init(ks[7], (d, cfg.index_n_heads), cfg.dtype))
+    if ffn_kind == "dense":
+        f = cfg.dense_hidden_dim
+        lp.update(w1=init(ks[8], (d, f), cfg.dtype),
+                  w3=init(ks[9], (d, f), cfg.dtype),
+                  w2=init(ks[10], (f, d), cfg.dtype) * f ** -0.5)
+    else:
+        lp.update(moe.expert_init(cfg, jax.random.split(ks[11], 4)))
+    return lp
+
+
+def init_params(cfg: GlmDsaConfig, rng) -> Dict[str, Any]:
+    """embed, `runs` (a list, one dict of stacked leaves a run of like
+    layers: which leaves a run has says what its layers are), final
+    norm, `out`."""
+    k_embed, k_out, k_layers = jax.random.split(rng, 3)
+    init = jax.nn.initializers.normal(0.02)
+    keys = jax.random.split(k_layers, cfg.n_layers)
+    runs, at = [], 0
+    for kind, n in cfg.runs():
+        runs.append(jax.vmap(functools.partial(_init_layer, cfg, kind))(
+            keys[at:at + n]))
+        at += n
+    return {"embed": init(k_embed, (cfg.vocab_size, cfg.dim), cfg.dtype),
+            "runs": runs,
+            "final_norm": jnp.ones(cfg.dim, cfg.dtype),
+            "out": init(k_out, (cfg.dim, cfg.vocab_size), cfg.dtype)}
+
+
+def init_cache(cfg: GlmDsaConfig, n_slots: int, max_seq: int,
+               dtype=None) -> Dict[str, Any]:
+    """The slot cache, a run at a time: the latent and the rotary key
+    of every layer, the indexer's key of the `full` ones. Every leaf is
+    [layers of the run, slots, max_seq, width]."""
+    dtype = dtype or cfg.dtype
+
+    def zeros(n, width):
+        return jnp.zeros((n, n_slots, max_seq, width), dtype)
+
+    runs = []
+    for (_, indexer), n in cfg.runs():
+        run = {"latent": zeros(n, cfg.kv_lora_rank),
+               "rope": zeros(n, cfg.qk_rope_head_dim)}
+        if indexer == "full":
+            run["index"] = zeros(n, cfg.index_head_dim)
+        runs.append(run)
+    return {"runs": runs}
+
+
+# ---------------------------------------------------------------------------
+# The mixer
+# ---------------------------------------------------------------------------
+
+
+def _rotate_pairs(x, cos, sin):
+    """Rotary positions on interleaved pairs (2i, 2i + 1) of the last
+    axis. x: [B, T, ..., D]; cos, sin: [B, T, D / 2]."""
+    extra = x.ndim - 3
+    cos = cos.reshape(cos.shape[:2] + (1,) * extra + cos.shape[2:])
+    sin = sin.reshape(cos.shape)
+    pairs = x.astype(jnp.float32).reshape(x.shape[:-1] + (-1, 2))
+    a, b = pairs[..., 0], pairs[..., 1]
+    out = jnp.stack([a * cos - b * sin, a * sin + b * cos], -1)
+    return out.reshape(x.shape).astype(x.dtype)
+
+
+def _rotate_head(x, cos, sin, n):
+    """`_rotate_pairs` on the first `n` of the last axis."""
+    return jnp.concatenate(
+        [_rotate_pairs(x[..., :n], cos, sin), x[..., n:]], -1)
+
+
+def _write(cache, new, start_pos):
+    """cache [B, S, W] with new [B, T, W] at each row's `start_pos`."""
+    return jax.vmap(lambda c, n, s: lax.dynamic_update_slice(
+        c, n.astype(c.dtype), (s, 0)))(cache, new, start_pos)
+
+
+def _key_blocks(positions, max_seq, block):
+    """How many blocks of `block` keys hold every key the rows at
+    `positions` can see."""
+    return jnp.minimum(positions.max() // block + 1, max_seq // block)
+
+
+def _index_scores(qi, w, index_cache, positions):
+    """I[b, t, s] = sum_j w[b, t, j] relu(qi[b, t, j] . k[b, s]) in
+    float32 for s <= positions[b, t], -inf past it. qi [B, T, J, D], w
+    [B, T, J] float32, index_cache [B, S, D] -> [B, T, S]."""
+    b, t = positions.shape
+    s = index_cache.shape[1]
+    tk = math.gcd(s, _KEY_BLOCK)
+
+    def body(j, out):
+        keys = lax.dynamic_slice_in_dim(index_cache, j * tk, tk, 1)
+        dots = jnp.einsum("btjd,bsd->btjs", qi, keys,
+                          preferred_element_type=jnp.float32)
+        block = (jax.nn.relu(dots) * w[..., None]).sum(2)
+        return lax.dynamic_update_slice_in_dim(out, block, j * tk, 2)
+
+    out = lax.fori_loop(0, _key_blocks(positions, s, tk), body,
+                        jnp.full((b, t, s), -jnp.inf, jnp.float32))
+    seen = jnp.arange(s)[None, None, :] <= positions[:, :, None]
+    return jnp.where(seen, out, -jnp.inf)
+
+
+def _top_k_mask(scores, k):
+    """Mask of the k largest entries of each row (last axis), ties to
+    the lower index, as `lax.top_k` chooses: the k-th largest value by
+    bisection on the float32 bit patterns, made to order as the numbers
+    do, then the entries above it and the first of those equal to it.
+    All of a row shorter than k."""
+    if k >= scores.shape[-1]:
+        return jnp.ones(scores.shape, bool)
+    bits = lax.bitcast_convert_type(scores, jnp.uint32)
+    keys = jnp.where(bits >> 31 == 1, ~bits, bits | jnp.uint32(1 << 31))
+
+    def body(i, reached):
+        higher = reached | (jnp.uint32(1 << 31) >> jnp.uint32(i))
+        enough = (keys >= higher).sum(-1, keepdims=True) >= k
+        return jnp.where(enough, higher, reached)
+
+    kth = lax.fori_loop(0, 32, body,
+                        jnp.zeros(scores.shape[:-1] + (1,), jnp.uint32))
+    above = keys > kth
+    tied = keys == kth
+    room = k - above.sum(-1, keepdims=True)
+    return above | (tied & (jnp.cumsum(tied, -1, dtype=jnp.int32) <= room))
+
+
+def _select(cfg, scores, positions):
+    """The keys each row attends: the `index_topk` of largest score
+    among those it can see."""
+    seen = jnp.arange(scores.shape[-1])[None, None, :] \
+        <= positions[:, :, None]
+    return _top_k_mask(scores, cfg.index_topk) & seen
+
+
+def _attend(q_lat, q_rope, latent, rope_keys, mask, positions, scale):
+    """Attention of q over the cached keys `mask` allows, against the
+    latent: q_lat [B, T, H, C], q_rope [B, T, H, R], latent [B, S, C],
+    rope_keys [B, S, R], mask [B, T, S] -> [B, T, H, C] float32. Scores,
+    softmax and both accumulations are float32; the caches enter both
+    products in the dtype they are stored in."""
+    b, t, h, c = q_lat.shape
+    s = latent.shape[1]
+    tk = math.gcd(s, _KEY_BLOCK)
+
+    def body(j, carry):
+        top, total, acc = carry
+        lat = lax.dynamic_slice_in_dim(latent, j * tk, tk, 1)
+        rot = lax.dynamic_slice_in_dim(rope_keys, j * tk, tk, 1)
+        allowed = lax.dynamic_slice_in_dim(mask, j * tk, tk, 2)[:, None]
+        scores = (jnp.einsum("bthc,bsc->bhts", q_lat, lat,
+                             preferred_element_type=jnp.float32)
+                  + jnp.einsum("bthr,bsr->bhts", q_rope, rot,
+                               preferred_element_type=jnp.float32)) * scale
+        new_top = jnp.maximum(
+            top, jnp.where(allowed, scores, -1e30).max(-1))
+        probs = jnp.where(allowed, jnp.exp(scores - new_top[..., None]), 0.0)
+        shrink = jnp.exp(top - new_top)
+        total = total * shrink + probs.sum(-1)
+        acc = acc * shrink[..., None] + jnp.einsum(
+            "bhts,bsc->bhtc", probs.astype(lat.dtype), lat,
+            preferred_element_type=jnp.float32)
+        return new_top, total, acc
+
+    _, total, acc = lax.fori_loop(
+        0, _key_blocks(positions, s, tk), body,
+        (jnp.full((b, h, t), -1e30, jnp.float32),
+         jnp.zeros((b, h, t), jnp.float32),
+         jnp.zeros((b, h, t, c), jnp.float32)))
+    return (acc / total[..., None]).transpose(0, 2, 1, 3)
+
+
+def _by_query_blocks(fn, t, *arrays):
+    """`fn` over blocks of the query axis (axis 1 of every array), its
+    results (a tuple of arrays) joined along it again."""
+    tq = math.gcd(t, _QUERY_BLOCK)
+    if tq == t:
+        return fn(*arrays)
+    n = t // tq
+
+    def split(x):
+        x = x.reshape((x.shape[0], n, tq) + x.shape[2:])
+        return jnp.moveaxis(x, 1, 0)
+
+    outs = lax.map(lambda xs: fn(*xs), tuple(split(x) for x in arrays))
+    return tuple(jnp.moveaxis(o, 0, 1).reshape(
+        (o.shape[1], t) + o.shape[3:]) for o in outs)
+
+
+def _mixer(cfg: GlmDsaConfig, indexer, start_pos, positions):
+    """The mixer of a run of `full` or of `shared` layers. It carries
+    the layer's slices of the slot cache, (latent, rotary key) and for
+    a `full` layer the indexer's key, each [B, S, width], and is handed
+    and hands on the selection [B, T, S]."""
+    nope, rot = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
+    scale = (nope + rot) ** -0.5
+    index_scale = (cfg.index_n_heads * cfg.index_head_dim) ** -0.5
+
+    def mixer(h, lp, rope, state, selected):
+        cos, sin = rope
+        with jax.named_scope("mla_proj"):
+            c_q = rms_norm_reference(
+                jnp.einsum("btd,dr->btr", h, lp["wqa"]), lp["q_norm"],
+                cfg.norm_eps)
+            q = jnp.einsum("btr,rhk->bthk", c_q, lp["wqb"])
+            kva = jnp.einsum("btd,dc->btc", h, lp["wkva"])
+            c_kv = rms_norm_reference(kva[..., :cfg.kv_lora_rank],
+                                      lp["kv_norm"], cfg.norm_eps)
+            latent = _write(state[0], c_kv, start_pos)
+            rope_keys = _write(
+                state[1], _rotate_pairs(kva[..., cfg.kv_lora_rank:], cos,
+                                        sin), start_pos)
+            q_nope = q[..., :nope].astype(latent.dtype)
+            q_rope = _rotate_pairs(q[..., nope:], cos, sin).astype(
+                latent.dtype)
+        new_state = (latent, rope_keys)
+        if indexer == "full":
+            with jax.named_scope("indexer"):
+                qi = _rotate_head(
+                    jnp.einsum("btr,rjd->btjd", c_q, lp["wiq"]), cos, sin,
+                    rot)
+                ki = _rotate_head(layer_norm(
+                    jnp.einsum("btd,de->bte", h, lp["wik"]), lp["ik_norm"],
+                    lp["ik_bias"], _INDEX_KEY_EPS), cos, sin, rot)
+                index_keys = _write(state[2], ki, start_pos)
+                qi = qi.astype(index_keys.dtype)
+                w = jnp.einsum("btd,dj->btj", h, lp["wiw"]).astype(
+                    jnp.float32) * index_scale
+            new_state += (index_keys,)
+
+        def attend(q_nope, q_rope, pos, *chosen):
+            """One block of queries. `chosen`: the indexer's queries
+            and weights on a `full` layer, the selection handed up on a
+            `shared` one."""
+            if indexer == "full":
+                with jax.named_scope("indexer"):
+                    scores = _index_scores(*chosen, index_keys, pos)
+                with jax.named_scope("index_select"):
+                    mask = _select(cfg, scores, pos)
+            else:
+                mask, = chosen
+            with jax.named_scope("sparse_attn"):
+                # `wkvb`'s key half goes into the query and its value
+                # half onto the output: scores against the latent.
+                q_lat = jnp.einsum("bthk,chk->bthc", q_nope,
+                                   lp["wkvb"][..., :nope])
+                out = _attend(q_lat, q_rope, latent, rope_keys, mask, pos,
+                              scale)
+                out = jnp.einsum("bthc,chv->bthv", out.astype(latent.dtype),
+                                 lp["wkvb"][..., nope:])
+            return out, mask
+
+        chosen = (qi, w) if indexer == "full" else (selected,)
+        out, selected = _by_query_blocks(
+            attend, h.shape[1], q_nope, q_rope, positions, *chosen)
+        return out, new_state, selected
+
+    return mixer
+
+
+# ---------------------------------------------------------------------------
+# Forward through the slot cache
+# ---------------------------------------------------------------------------
+
+def _ffn(cfg: GlmDsaConfig, ffn_kind):
+    if ffn_kind == "dense":
+        return swiglu()
+
+    def ffn(h, lp):
+        out, _, _, share = moe._moe_ffn(cfg, lp, h, None, DEFAULT_RULES)
+        return out, share
+
+    return ffn
+
+
+def _logits(params, x, cfg):
+    """The head in float32: the served logits feed an argmax, and two
+    near-equal logits rounded to bfloat16 are a tie."""
+    return jnp.einsum("...d,dv->...v", x, params["out"].astype(cfg.dtype),
+                      preferred_element_type=jnp.float32)
+
+
+def _hidden(params, tokens, cfg: GlmDsaConfig, cache, start_pos):
+    """The stack through the slot cache: (final-norm hidden states
+    [B, T, D], new cache, the expert layers' counts)."""
+    b, t = tokens.shape
+    positions = start_pos[:, None] + jnp.arange(t)[None, :]
+    max_seq = cache["runs"][0]["latent"].shape[2]
+    runs = []
+    for (kind, _), stacked, run in zip(cfg.runs(), params["runs"],
+                                       cache["runs"]):
+        state = (run["latent"], run["rope"]) + (
+            (run["index"],) if kind[1] == "full" else ())
+        runs.append((_mixer(cfg, kind[1], start_pos, positions),
+                     _ffn(cfg, kind[0]), stacked, state))
+    x, states, extras = decoder.hidden_runs(
+        params, tokens, cfg, runs, positions=positions,
+        handed=jnp.zeros((b, t, max_seq), bool))
+    new_cache = {"runs": [dict(zip(("latent", "rope", "index"), state))
+                          for state in states]}
+    # What the expert layers counted, summed over them; the dense runs
+    # report nothing.
+    counted = [e for e in extras if e is not None]
+    counts = jax.tree.map(lambda *xs: sum(x.sum() for x in xs),
+                          *counted) if counted else {}
+    return x, new_cache, counts
+
+
+def forward(params, tokens, cfg: GlmDsaConfig, cache, start_pos, at):
+    """What the engine serves through (`models.serving`): `tokens`
+    [B, T] from per-row absolute offsets `start_pos` [B], reading and
+    writing the slot cache of `init_cache`, prefill (T = the prompt's
+    bucket) and decode (T = 1) alike. Returns (the logits of position
+    `at` of `tokens`, [B, vocab] float32, without the [T, vocab] product
+    of the rest; the new cache; what the expert layers counted over the
+    call, int32 scalars: the (token, expert) pairs computed here,
+    `pairs_held`, the pairs routed, `pairs_routed`, and the buffers
+    beyond the first that a crowded share took, `pair_overflows`)."""
+    x, cache, counts = _hidden(params, tokens, cfg, cache, start_pos)
+    x = lax.dynamic_index_in_dim(x, at, 1, keepdims=False)
+    return _logits(params, x, cfg), cache, counts
+
+
+def forward_with_cache(params, tokens, cfg: GlmDsaConfig, cache, start_pos):
+    """`forward` with the logits of every position, [B, T, vocab]
+    float32, and no counts: what a comparison with a reference steps
+    through."""
+    x, cache, _ = _hidden(params, tokens, cfg, cache, start_pos)
+    return _logits(params, x, cfg), cache
+
+
+def keys_attended(cfg: GlmDsaConfig, lengths):
+    """Of `lengths` cached keys a row (host integers), how many the
+    row's next token attends."""
+    return np.minimum(lengths, cfg.index_topk)

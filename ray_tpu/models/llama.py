@@ -267,11 +267,11 @@ def self_attention(cfg: LlamaConfig, mesh=None, rules=DEFAULT_RULES,
                    qk_norm=norm_all_heads):
     """The mixer of training and of the uncached forward: causal
     attention of the sequence over itself."""
-    def mixer(h, lp, rope, state):
+    def mixer(h, lp, rope, state, handed):
         q, k, v = _qkv(cfg, h, lp, rope, None, qk_norm)
         q = with_logical_constraint(q, "batch", "seq", "heads", "head_dim",
                                     mesh=mesh, rules=rules)
-        return _attention(cfg, q, k, v, mesh, rules), state
+        return _attention(cfg, q, k, v, mesh, rules), state, handed
 
     return mixer
 
@@ -410,13 +410,13 @@ def _cached_self_attention(cfg: LlamaConfig, start_pos, positions):
         return lax.dynamic_update_slice(
             cache_b, new_b.astype(cache_b.dtype), (start_b, 0, 0))
 
-    def mixer(h, lp, rope, state):
+    def mixer(h, lp, rope, state, handed):
         k_cache, v_cache = state
         q, k, v = _qkv(cfg, h, lp, rope, positions, norm_all_heads)
         k_cache = jax.vmap(write_cache)(k_cache, k, start_pos)
         v_cache = jax.vmap(write_cache)(v_cache, v, start_pos)
         return (_cached_attention(cfg, q, k_cache, v_cache, positions),
-                (k_cache, v_cache))
+                (k_cache, v_cache), handed)
 
     return mixer
 

@@ -39,7 +39,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Any, Dict
+from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -70,6 +70,28 @@ class MoEConfig(LlamaConfig):
     # Divide a token's k gates by their sum (Mixtral). OLMoE weighs its
     # experts by the softmax over all of them as it is.
     norm_topk_prob: bool = True
+    # What the DeepSeek-V3 family's router differs in. An expert's score
+    # is the "softmax" over all experts or its own "sigmoid".
+    scoring: str = "softmax"
+    # The k experts are chosen by score plus a per-expert bias (the leaf
+    # `router_bias`); the gates are the scores themselves.
+    selection_bias: bool = False
+    # The gates are multiplied by this, after `norm_topk_prob`.
+    gate_scale: float = 1.0
+    # Width of a SwiGLU expert that every token passes through beside
+    # its k (leaves `ws1`, `ws3`, `ws2`); 0: none.
+    shared_hidden_dim: int = 0
+    # (first, count): the contiguous range of the `n_experts` experts
+    # whose weights this program holds, the share of one chip of a
+    # deployment that spreads a layer's experts over several. The router
+    # stays `n_experts` wide and chooses among all of them; the pairs
+    # that fall on absent experts are left out of the layer's output
+    # (the chip that holds them adds them). None: all.
+    experts_held: Optional[Tuple[int, int]] = None
+
+    @property
+    def n_experts_held(self) -> int:
+        return self.experts_held[1] if self.experts_held else self.n_experts
 
     @staticmethod
     def debug_moe() -> "MoEConfig":
@@ -96,15 +118,35 @@ class MoEConfig(LlamaConfig):
 
 def _init_moe_layer(cfg: MoEConfig, key) -> Dict[str, Any]:
     k_router, k1, k2, k3 = jax.random.split(jax.random.fold_in(key, 99), 4)
+    return {**attention_init(cfg, key),
+            **expert_init(cfg, (k_router, k1, k2, k3))}
+
+
+def expert_init(cfg: MoEConfig, keys) -> Dict[str, Any]:
+    """The expert layer's leaves from four keys: the router over all
+    `n_experts`, the three matrices of the experts held, and what the
+    config's router and shared expert add."""
+    k_router, k1, k2, k3 = keys
     init = jax.nn.initializers.normal(stddev=0.02)
-    e, d, h = cfg.n_experts, cfg.dim, cfg.hidden_dim
-    return {
-        **attention_init(cfg, key),
-        "router": init(k_router, (d, e), cfg.dtype),
+    e, d, h = cfg.n_experts_held, cfg.dim, cfg.hidden_dim
+    leaves = {
+        "router": init(k_router, (d, cfg.n_experts), cfg.dtype),
         "we1": init(k1, (e, d, h), cfg.dtype),
         "we3": init(k2, (e, d, h), cfg.dtype),
         "we2": init(k3, (e, h, d), cfg.dtype) * (h ** -0.5),
     }
+    if cfg.selection_bias:
+        # A buffer the published training balances the load with: small
+        # beside a sigmoid score, large enough to change who is chosen.
+        leaves["router_bias"] = 0.1 * jax.random.normal(
+            jax.random.fold_in(k_router, 1), (cfg.n_experts,), jnp.float32)
+    if cfg.shared_hidden_dim:
+        ks = jax.random.split(jax.random.fold_in(k1, 1), 3)
+        f = cfg.shared_hidden_dim
+        leaves.update(ws1=init(ks[0], (d, f), cfg.dtype),
+                      ws3=init(ks[1], (d, f), cfg.dtype),
+                      ws2=init(ks[2], (f, d), cfg.dtype) * (f ** -0.5))
+    return leaves
 
 
 def init_moe_params(cfg: MoEConfig, rng) -> Dict[str, Any]:
@@ -192,6 +234,14 @@ def _expert_counts(top_i, n_experts):
     return hits.sum(tuple(range(top_i.ndim)), dtype=jnp.int32)
 
 
+def _grouped_swiglu(xs, group_sizes, we1, we3, we2):
+    """Rows in expert order through their experts: [R, D] -> [R, D]."""
+    with jax.named_scope("expert_matmul"):
+        hidden = jax.nn.silu(lax.ragged_dot(xs, we1, group_sizes)) \
+            * lax.ragged_dot(xs, we3, group_sizes)         # [R, F]
+        return lax.ragged_dot(hidden, we2, group_sizes)    # [R, D]
+
+
 def _sparse_experts(x, gates, top_i, we1, we3, we2):
     """The chosen experts of the tokens at hand. x [T, D], gates and
     top_i [T, k], weights [E, ...] -> [T, D]."""
@@ -200,26 +250,112 @@ def _sparse_experts(x, gates, top_i, we1, we3, we2):
         inv = jnp.argsort(order)
         group_sizes = _expert_counts(top_i, we1.shape[0])
         xs = _spread(x, order, inv)                        # [T*k, D]
-    with jax.named_scope("expert_matmul"):
-        hidden = jax.nn.silu(lax.ragged_dot(xs, we1, group_sizes)) \
-            * lax.ragged_dot(xs, we3, group_sizes)         # [T*k, F]
-        ys = lax.ragged_dot(hidden, we2, group_sizes)      # [T*k, D]
+    ys = _grouped_swiglu(xs, group_sizes, we1, we3, we2)
     with jax.named_scope("moe_combine"):
         return _collect(ys, gates, order, inv)
 
 
-def _moe_ffn(cfg: MoEConfig, lp, x, mesh, rules):
-    """x: [B, S, D] -> ([B, S, D], aux loss scalar, pairs routed to each
-    expert [E] int32)."""
-    b, s, d = x.shape
+# A share's grouped products run over buffers of this many times the
+# pairs it would be dealt were the router uniform, and never of fewer
+# rows than this (a decode step's few pairs then always fit in one).
+_HELD_ROWS_SLACK = 2
+_HELD_ROWS_MIN = 256
+
+
+def _held_experts(cfg: MoEConfig, x, gates, top_i, we1, we3, we2):
+    """`_sparse_experts` of a share of the experts (`cfg.experts_held`;
+    the weights are theirs), at the cost of the pairs that landed on it.
+    The pairs are sorted held experts first, and the held ones go
+    through the grouped products a buffer of `rows` rows at a time
+    (static: `_HELD_ROWS_SLACK` times the uniform share of the T * k
+    pairs), as many buffers as hold them: one, unless the router
+    crowded this share. No pair is dropped, none on an absent expert is
+    gathered or computed, and each token's rows are added into it (a
+    scatter-add over the buffer's rows: forward only, this is a serving
+    path). Returns (out [T, D], pairs held int32, buffers beyond the
+    first int32)."""
+    first, count = cfg.experts_held
+    k = top_i.shape[1]
+    pairs = top_i.size
+    rows = min(pairs, max(
+        _HELD_ROWS_MIN,
+        -(-_HELD_ROWS_SLACK * pairs * count // cfg.n_experts)))
+    with jax.named_scope("moe_dispatch"):
+        local = top_i.reshape(-1) - first
+        local = jnp.where((local >= 0) & (local < count), local, count)
+        # The held pairs in expert order, then the absent; padded so
+        # that a buffer's slice never runs off the end.
+        order = jnp.pad(jnp.argsort(local, stable=True), (0, rows))
+        ends = jnp.cumsum(_expert_counts(local, count))
+        n_held = ends[-1]
+        flat_gates = gates.reshape(-1)
+
+    def buffer(i, out):
+        lo = i * rows
+        with jax.named_scope("moe_dispatch"):
+            pair = lax.dynamic_slice_in_dim(order, lo, rows)
+            token = pair // k
+            # Each expert's rows inside [lo, lo + rows).
+            inside = jnp.clip(ends, lo, lo + rows)
+            group_sizes = jnp.diff(inside, prepend=lo)
+            held = jnp.arange(rows) < n_held - lo
+            xs = _rows(x, token)                           # [rows, D]
+        ys = _grouped_swiglu(xs, group_sizes, we1, we3, we2)
+        with jax.named_scope("moe_combine"):
+            # Rows past the held pairs belong to no group: whatever the
+            # grouped product left there counts nothing.
+            weight = jnp.where(held, _rows(flat_gates, pair), 0.0)
+            ys = jnp.where(held[:, None], ys.astype(jnp.float32), 0.0)
+            return out.at[token].add(ys * weight[:, None])
+
+    n_buffers = (n_held + rows - 1) // rows
+    out = lax.fori_loop(0, n_buffers, buffer,
+                        jnp.zeros(x.shape, jnp.float32))
+    return (out.astype(x.dtype), n_held.astype(jnp.int32),
+            jnp.maximum(n_buffers - 1, 0).astype(jnp.int32))
+
+
+def _route(cfg: MoEConfig, lp, x):
+    """The router: x [B, S, D] -> (every expert's score [B, S, E]
+    float32, the k chosen experts' gates [B, S, k] float32, which they
+    are [B, S, k])."""
     k = cfg.n_experts_per_token
-    with jax.named_scope("router"):
+    if cfg.scoring == "sigmoid":
+        # DeepSeek-V3's gate multiplies in float32: with hundreds of
+        # experts the k-th and the next score lie closer than a
+        # bfloat16 logit tells apart.
+        probs = jax.nn.sigmoid(jnp.einsum(
+            "bsd,de->bse", x.astype(jnp.float32),
+            lp["router"].astype(jnp.float32),
+            precision=lax.Precision.HIGHEST))
+    else:
         logits = jnp.einsum("bsd,de->bse", x,
                             lp["router"]).astype(jnp.float32)
         probs = jax.nn.softmax(logits, axis=-1)            # [B, S, E]
+    if cfg.selection_bias:
+        # The bias chooses, it does not weigh.
+        _, top_i = lax.top_k(probs + lp["router_bias"], k)
+        gates = jnp.take_along_axis(probs, top_i, -1)
+    else:
         gates, top_i = lax.top_k(probs, k)                 # [B, S, k]
-        if cfg.norm_topk_prob:
-            gates = gates / gates.sum(-1, keepdims=True)
+    if cfg.norm_topk_prob:
+        gates = gates / gates.sum(-1, keepdims=True)
+    if cfg.gate_scale != 1.0:
+        gates = gates * cfg.gate_scale
+    return probs, gates, top_i
+
+
+def _moe_ffn(cfg: MoEConfig, lp, x, mesh, rules):
+    """x: [B, S, D] -> ([B, S, D], aux loss scalar, pairs routed to each
+    expert [E] int32, and what the layer computed of them as int32
+    scalars: `pairs_held`, the pairs that fell on experts held here
+    (all of them unless `cfg.experts_held`), `pairs_routed`, and
+    `pair_overflows`, the buffers beyond the first that a held share's
+    pairs took)."""
+    b, s, d = x.shape
+    k = cfg.n_experts_per_token
+    with jax.named_scope("router"):
+        probs, gates, top_i = _route(cfg, lp, x)
         counts = _expert_counts(top_i, cfg.n_experts)
         # Load-balance aux loss: E * sum_e (share of the tokens that
         # chose e) * (mean router probability of e).
@@ -234,23 +370,44 @@ def _moe_ffn(cfg: MoEConfig, lp, x, mesh, rules):
         return out.reshape(x.shape)
 
     weights = [lp[name] for name in _EXPERT_AXES]
-    if mesh is None:
-        return experts(x, gates, top_i, *weights), aux, counts
+    n_held, over = jnp.int32(b * s * k), jnp.int32(0)
+    if cfg.experts_held is not None:
+        assert mesh is None, "a held share of the experts runs on one chip"
+        out, n_held, over = _held_experts(
+            cfg, x.reshape(b * s, d), gates.reshape(b * s, k),
+            top_i.reshape(b * s, k), *weights)
+        out = out.reshape(x.shape)
+    elif mesh is None:
+        out = experts(x, gates, top_i, *weights)
+    else:
+        # Each shard of the batch (and of the sequence) dispatches its
+        # own tokens; the expert matrices come in as they are sharded
+        # and are gathered whole inside, so that their gradients leave
+        # through the matching reduce-scatter.
+        w_specs = [logical_to_mesh_axes(axes, rules)
+                   for axes in _EXPERT_AXES.values()]
+        tok = logical_to_mesh_axes(("batch", "seq", None), rules)
+        out = jax.shard_map(
+            lambda x, gates, top_i, *ws: experts(
+                x, gates, top_i,
+                *[_gather_whole(w, spec) for w, spec in zip(ws, w_specs)]),
+            mesh=mesh, in_specs=(tok, tok, tok, *w_specs), out_specs=tok,
+            check_vma=False)(x, gates, top_i, *weights)
+    return _add_shared_expert(cfg, lp, x, out), aux, counts, {
+        "pairs_held": n_held, "pairs_routed": jnp.int32(b * s * k),
+        "pair_overflows": over}
 
-    # Each shard of the batch (and of the sequence) dispatches its own
-    # tokens; the expert matrices come in as they are sharded and are
-    # gathered whole inside, so that their gradients leave through the
-    # matching reduce-scatter.
-    w_specs = [logical_to_mesh_axes(axes, rules)
-               for axes in _EXPERT_AXES.values()]
-    tok = logical_to_mesh_axes(("batch", "seq", None), rules)
-    out = jax.shard_map(
-        lambda x, gates, top_i, *ws: experts(
-            x, gates, top_i,
-            *[_gather_whole(w, spec) for w, spec in zip(ws, w_specs)]),
-        mesh=mesh, in_specs=(tok, tok, tok, *w_specs), out_specs=tok,
-        check_vma=False)(x, gates, top_i, *weights)
-    return out, aux, counts
+
+def _add_shared_expert(cfg: MoEConfig, lp, x, out):
+    """`out` plus the SwiGLU expert every token passes through, where
+    the config has one."""
+    if not cfg.shared_hidden_dim:
+        return out
+    with jax.named_scope("shared_expert"):
+        gate = jax.nn.silu(jnp.einsum("bsd,df->bsf", x, lp["ws1"]))
+        return out + jnp.einsum(
+            "bsf,fd->bsd", gate * jnp.einsum("bsd,df->bsf", x, lp["ws3"]),
+            lp["ws2"])
 
 
 def _gather_whole(w, spec):
@@ -266,7 +423,7 @@ def _parts(cfg: MoEConfig, mesh, rules):
     """What `decoder` is handed for this architecture: the mixer, the
     FFN, and what a rematerialised layer saves (nothing)."""
     def ffn(h, lp):
-        out, aux, counts = _moe_ffn(cfg, lp, h, mesh, rules)
+        out, aux, counts, _ = _moe_ffn(cfg, lp, h, mesh, rules)
         return out, {"aux": aux, "counts": counts}
 
     # `_norm_all_heads` is read here, when a forward pass is traced, so

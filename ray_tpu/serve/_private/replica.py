@@ -283,4 +283,11 @@ class ServeReplica:
             )
 
             remove_loop_lag_component(comp)
+        # The deployment's own teardown, as Ray Serve has it: a user
+        # class that defines `__del__` has it called when its replica
+        # stops (threads it started must not outlive it: a thread still
+        # inside a device call at interpreter exit aborts the process).
+        fn = getattr(self.callable, "__del__", None)
+        if fn is not None:
+            fn()
         return drained

@@ -7,11 +7,21 @@ from XLA's compilation model — every device program must have static
 shapes — so:
 
 - The KV cache is slot-based: `max_batch_size` sequence slots, each with a
-  `max_seq_len` KV region (`models.llama.init_kv_cache`). Admission =
-  prefill into a free slot; retirement frees the slot. The decode step is
-  ONE fixed-shape jit program over all slots regardless of occupancy.
-- Prefill lengths are bucketed to powers of two, so at most log2(max_seq)
-  prefill programs ever compile.
+  `max_seq_len` KV region. Admission = prefill into a free slot;
+  retirement frees the slot. The decode step is ONE fixed-shape jit
+  program over all slots regardless of occupancy.
+- The engine knows no model. `models.serving.served_model` names, by the
+  config's type, the cached forward pass and the cache's initialiser;
+  the cache is whatever pytree that gives, every leaf
+  [layers_i, slots, max_seq, ...] (Llama's K and V; a latent-attention
+  model's latents, rotary keys and indexer keys, stacked by runs of like
+  layers), and everything here that touches it (a slot's slice, the
+  prefix cache's reads, writes and payloads) works leaf by leaf.
+- Prefill lengths are bucketed: to powers of two up to 4,096 tokens and
+  to multiples of 1,024 past that (`prefill_bucket`), so at most
+  log2(4096) + max_seq / 1024 prefill programs ever compile and a long
+  prompt pays for at most 1,023 tokens of padding, not for as many again
+  as it has.
 - Sampling (greedy / temperature / top-k) runs on device; one token per
   slot per step streams back to waiting callers.
 
@@ -63,11 +73,36 @@ from ray_tpu._private.compile_cache import enable_persistent_cache
 from ray_tpu._private import perf_stats
 from ray_tpu._private.config import ray_config
 from ray_tpu._private.kv_cache import PrefixCache, chain_keys
-from ray_tpu.models.llama import (
-    LlamaConfig,
-    forward_with_cache,
-    init_kv_cache,
-)
+from ray_tpu.models.serving import served_model
+
+
+# A prefill program's cost grows faster than its length (attention), so
+# from here on a power of two's padding would cost seconds: the buckets
+# come every `_BUCKET_STEP` tokens instead.
+_BUCKET_LINEAR_FROM = 4096
+_BUCKET_STEP = 1024
+
+
+def prefill_bucket(n_tokens: int) -> int:
+    """The smallest prefill bucket that holds `n_tokens`: a power of two
+    up to `_BUCKET_LINEAR_FROM`, a multiple of `_BUCKET_STEP` past it."""
+    if n_tokens > _BUCKET_LINEAR_FROM:
+        return -(-n_tokens // _BUCKET_STEP) * _BUCKET_STEP
+    b = 1
+    while b < n_tokens:
+        b *= 2
+    return b
+
+
+def bucket_ladder(limit: int, max_seq: int) -> List[int]:
+    """Every prefill bucket up to the one that holds `limit` tokens,
+    none over a slot's `max_seq`."""
+    top = min(prefill_bucket(limit), max_seq)
+    buckets, b = [], 1
+    while b < top:
+        buckets.append(b)
+        b = prefill_bucket(b + 1)
+    return buckets + [top]
 
 
 class PromptTooLongError(ValueError):
@@ -152,17 +187,18 @@ class _Request:
 class _Readback:
     """One admitted request's newly created blocks on their way to the
     host: the slot's rows [start, start + rows) as device arrays whose
-    copy to the host has been started (`_read_rows_impl`'s parts, K's
-    then V's), and the handles (by block id) whose payload is still
-    wanted."""
+    copy to the host has been started (`_read_rows_impl`'s parts, leaf
+    after leaf of the cache), and the handles (by block id) whose
+    payload is still wanted."""
     arrays: tuple
     start: int
+    rows: int
     handles: Dict[int, Any]
     nbytes: int
 
 
 class LLMEngine:
-    def __init__(self, cfg: LlamaConfig, params, *,
+    def __init__(self, cfg, params, *,
                  max_batch_size: int = 8, max_seq_len: Optional[int] = None,
                  decode_steps: int = 1, seed: int = 0,
                  model: str = "default"):
@@ -178,7 +214,14 @@ class LLMEngine:
         # current block) for K-fold fewer dispatches.
         self.decode_steps = max(1, int(decode_steps))
         self.max_seq = max_seq_len or cfg.max_seq_len
-        self.cache = init_kv_cache(cfg, self.n_slots, self.max_seq)
+        self._served = served_model(cfg)
+        self.cache = self._served.init_cache(cfg, self.n_slots,
+                                             self.max_seq)
+        # The cache's leaves as shapes, [layers_i, slots, max_seq, ...]:
+        # what the host needs of them (the arrays themselves are donated
+        # to every program that writes them).
+        self._leaves = [jax.ShapeDtypeStruct(x.shape, x.dtype)
+                        for x in jax.tree.leaves(self.cache)]
         self._rng = jax.random.PRNGKey(seed)
 
         # Per-slot host state.
@@ -214,7 +257,21 @@ class LLMEngine:
             "prefill_tokens_bucketed", "admit_waves",
             "admit_waves_behind_block", "slot_steps_stale", "admissions",
             "kv_blocks_read_back", "kv_bytes_read_back",
-            "kv_readbacks_deferred", "kv_readbacks_forced"), 0)
+            "kv_readbacks_deferred", "kv_readbacks_forced",
+            "keys_cached", "keys_attended"), 0)
+        # What the model counts while it runs (nothing, for most) joins
+        # the totals under the model's own names, which one abstract
+        # evaluation of a decode step gives. The same evaluation says
+        # in which dtype the model hands over its logits (the sample
+        # program is compiled for it).
+        step = jnp.zeros((self.n_slots, 1), jnp.int32)
+        logits, _, counts = jax.eval_shape(
+            lambda p, c: self._served.forward(p, step, cfg, c, step[:, 0],
+                                              0),
+            params, self.cache)
+        self._logits_dtype = logits.dtype
+        self._count_names = tuple(sorted(counts))
+        self._totals.update(dict.fromkeys(self._count_names, 0))
 
         # Compiled programs. Prefill is per-slot (batch 1, bucketed T);
         # decode covers all slots at T=1. Params are explicit arguments —
@@ -243,7 +300,7 @@ class LLMEngine:
         self._decode = jax.jit(
             self._decode_impl, donate_argnums=(1,),
             in_shardings=(None, s1, s1, s1, s1, s1, s1),
-            out_shardings=(s1, s1, s1, s1, s1))
+            out_shardings=(s1, s1, s1, s1, s1, s1))
         self._prefill = jax.jit(
             self._prefill_impl, donate_argnums=(1,),
             static_argnums=(6,),  # t — positional: pjit rejects kwargs
@@ -277,8 +334,9 @@ class LLMEngine:
             self.prefix_cache = PrefixCache(
                 ray_config.llm_prefix_cache_bytes, self.block_tokens)
         self._kv_store: Dict[int, tuple] = {}
-        k = self.cache["k"]
-        per_token = 2 * k.size * k.dtype.itemsize // (k.shape[1] * k.shape[2])
+        per_token = sum(
+            x.size * x.dtype.itemsize // (x.shape[1] * x.shape[2])
+            for x in self._leaves)
         self._block_nbytes = per_token * self.block_tokens
         self._chain_seed = self._seed_for(model)
         self._c_shm_offloads = perf_stats.counter("llm_kv_shm_offloads")
@@ -300,14 +358,15 @@ class LLMEngine:
         self._readback_lock = threading.Lock()
         # KV copy programs: read-back takes `rows` (static: one program
         # a prefill bucket, compiled by warmup) of a slot's region from
-        # a traced offset; copy-in writes one [L, B, Hkv, D] block.
+        # a traced offset; copy-in writes one block, [layers_i, B, ...]
+        # a leaf.
         self._read_rows_j = jax.jit(
             self._read_rows_impl, static_argnums=(3,),
             in_shardings=(s1, s1, s1), out_shardings=s1)
         self._read_rows_exec: Dict[int, Any] = {}
         self._write_block_j = jax.jit(
             self._write_block_impl, donate_argnums=(0,),
-            in_shardings=(s1, s1, s1, s1, s1), out_shardings=s1)
+            in_shardings=(s1, s1, s1, s1), out_shardings=s1)
 
     def _seed_for(self, model: str) -> str:
         """Chain-key seed: model identity + the KV-shape fingerprint.
@@ -315,13 +374,16 @@ class LLMEngine:
         interchangeable — same model, same layout — which is what makes
         the shm tier safe to share across replicas."""
         c = self.cfg
-        return (f"{model}|{c.n_layers}x{c.dim}x{c.n_kv_heads}x"
-                f"{c.max_seq_len}|{self.block_tokens}")
+        layout = ",".join(
+            "x".join(map(str, (x.shape[0],) + x.shape[3:])) + f":{x.dtype}"
+            for x in self._leaves)
+        return (f"{model}|{c.n_layers}x{c.dim}x{c.max_seq_len}|{layout}|"
+                f"{self.block_tokens}")
 
     def warmup(self, max_prompt_len: Optional[int] = None) -> float:
         """Compile every program the serving path needs BEFORE the first
-        request (deploy-time AOT): prefill at each power-of-two bucket up
-        to ``max_prompt_len`` (default max_seq) plus the decode body and
+        request (deploy-time AOT): prefill at each bucket (`prefill_bucket`)
+        up to ``max_prompt_len`` (default max_seq) plus the decode body and
         the admission sampler. Must run before :meth:`start`.
 
         The bucket ladder compiles CONCURRENTLY: each program is
@@ -338,12 +400,7 @@ class LLMEngine:
             "warmup() must run before the engine loop starts"
         t0 = time.perf_counter()
         limit = min(max_prompt_len or self.max_seq, self.max_seq)
-        buckets, b = [], 1
-        while b < limit:
-            buckets.append(b)
-            b *= 2
-        buckets.append(min(b, self.max_seq))  # _admit's cap bucket
-        buckets = sorted(set(buckets))
+        buckets = bucket_ladder(limit, self.max_seq)
         self._compile_ladder_concurrent(buckets)
         last = None
         for bucket in buckets:
@@ -354,18 +411,19 @@ class LLMEngine:
             # read-back doesn't pay a mid-serving compile.
             for rows in sorted(self._read_rows_exec):
                 self._run_read_rows(0, 0, rows)
-            k = self.cache["k"]
-            kb = jnp.zeros((k.shape[0], self.block_tokens) + k.shape[3:],
-                           k.dtype)
+            block = tuple(
+                jnp.zeros((x.shape[0], self.block_tokens) + x.shape[3:],
+                          x.dtype) for x in self._leaves)
             self.cache = self._write_block_j(
-                self.cache, kb, kb, np.int32(0), np.int32(0))
+                self.cache, block, np.int32(0), np.int32(0))
         # Admission-wave sampling program; every row is padding, so
         # the carries would come back as they went.
         _firsts, self._rng, _last, _lens = self._run_sample(
             (last,) * self.n_slots, np.zeros(self.n_slots, np.float32),
             np.full(self.n_slots, self.n_slots, np.int32),
             np.zeros(self.n_slots, np.int32))
-        (self.cache, toks, _last, _lens, self._rng) = self._run_decode(
+        (self.cache, toks, _last, _lens, self._rng,
+         _counts) = self._run_decode(
             jnp.zeros(self.n_slots, jnp.int32),
             jnp.zeros(self.n_slots, jnp.int32),
             jnp.zeros(self.n_slots, jnp.float32),
@@ -405,9 +463,10 @@ class LLMEngine:
             return "decode", lowered.compile()
 
         def compile_sample():
-            # Prefill hands over its last-position logits in cfg.dtype.
+            # Prefill hands over its last-position logits as the model
+            # gives them.
             lowered = self._sample_admitted.lower(
-                (aval((self.cfg.vocab_size,), self.cfg.dtype),) * n,
+                (aval((self.cfg.vocab_size,), self._logits_dtype),) * n,
                 aval((n,), _jnp.float32), rng_aval, aval((n,)),
                 aval((n,)), aval((n,)), aval((n,)))
             return "sample", lowered.compile()
@@ -501,63 +560,68 @@ class LLMEngine:
         the matched-prefix length when cached KV blocks were copied in
         ahead of this call), returns logits at the last real position
         [vocab]."""
-        slot_cache = {"k": lax_slice_slot(cache["k"], slot),
-                      "v": lax_slice_slot(cache["v"], slot)}
-        logits, new_slot_cache = forward_with_cache(
+        slot_cache = jax.tree.map(
+            lambda x: jax.lax.dynamic_slice_in_dim(x, slot, 1, axis=1),
+            cache)
+        last, new_slot_cache, _ = self._served.forward(
             params, tokens, self.cfg, slot_cache,
-            jnp.full((1,), start, jnp.int32))
-        cache = {
-            "k": lax_write_slot(cache["k"], new_slot_cache["k"], slot),
-            "v": lax_write_slot(cache["v"], new_slot_cache["v"], slot),
-        }
-        last = logits[0, length - 1]
-        return cache, last
+            jnp.full((1,), start, jnp.int32), length - 1)
+        cache = jax.tree.map(
+            lambda x, new: jax.lax.dynamic_update_slice_in_dim(
+                x, new, slot, axis=1), cache, new_slot_cache)
+        return cache, last[0]
+
+    @staticmethod
+    def _layer_parts(leaf, rows) -> int:
+        """In how many equal parts a read-back hands over a leaf's
+        layers: as few as keep an array of `rows` rows within
+        `_D2H_ARRAY_BYTES`."""
+        layers = leaf.shape[0]
+        nbytes = rows * leaf.dtype.itemsize * int(np.prod(leaf.shape[3:]))
+        return next((p for p in range(1, layers) if layers % p == 0
+                     and nbytes * layers // p <= _D2H_ARRAY_BYTES), layers)
 
     def _read_rows_impl(self, cache, slot, start, rows):
         """Read `rows` tokens' KV out of a slot's region from token
-        offset `start` → K's parts, then V's, each [L/p, rows, Hkv, D]:
-        the layers in as few equal parts as keep an array within
-        `_D2H_ARRAY_BYTES`."""
-        x = cache["k"]  # [L, slots, S, Hkv, D]
-        layers = x.shape[0]
-        layer_nbytes = rows * x.shape[3] * x.shape[4] * x.dtype.itemsize
-        parts = next((p for p in range(1, layers) if layers % p == 0
-                      and layer_nbytes * layers // p <= _D2H_ARRAY_BYTES),
-                     layers)
+        offset `start` → leaf after leaf of the cache its parts, each
+        [layers_i / p, rows, ...] (`_layer_parts`)."""
         out = []
-        for name in ("k", "v"):
+        for x in jax.tree.leaves(cache):  # [layers_i, slots, S, ...]
+            tail = (0,) * (x.ndim - 3)
             blk = jax.lax.dynamic_slice(
-                cache[name], (0, slot, start, 0, 0),
-                (layers, 1, rows, x.shape[3], x.shape[4]))
-            out += jnp.split(blk[:, 0], parts)
+                x, (0, slot, start) + tail,
+                (x.shape[0], 1, rows) + x.shape[3:])
+            out += jnp.split(blk[:, 0], self._layer_parts(x, rows))
         return tuple(out)
 
-    def _write_block_impl(self, cache, kb, vb, slot, start):
-        """Write one KV block ([L, B, Hkv, D] each) into a slot's
-        region at token offset `start`."""
-        new = {}
-        for name, blk in (("k", kb), ("v", vb)):
-            x = cache[name]
-            new[name] = jax.lax.dynamic_update_slice(
-                x, blk[:, None], (0, slot, start, 0, 0))
-        return new
+    def _write_block_impl(self, cache, block, slot, start):
+        """Write one KV block (`block`: [layers_i, B, ...] a leaf of
+        the cache, in the leaves' order) into a slot's region at token
+        offset `start`."""
+        leaves, tree = jax.tree.flatten(cache)
+        return tree.unflatten([
+            jax.lax.dynamic_update_slice(
+                x, blk[:, None], (0, slot, start) + (0,) * (x.ndim - 3))
+            for x, blk in zip(leaves, block)])
 
     def _decode_impl(self, params, cache, last_tokens, lengths, temps,
                      topks, rng):
         """`decode_steps` tokens for every slot per dispatch, via an
         in-program `lax.scan` (vLLM-style multi-step decoding): one
         device execution amortizes the per-dispatch overhead over K
-        tokens. Returns tokens [slots, K]."""
-
+        tokens. Returns tokens [slots, K] and, last, what the model
+        counted over the K steps: int32 [names], names sorted, or () of
+        a model that counts nothing."""
         def step(carry, _):
             cache, tokens, lengths, rng = carry
             # Clamp for retired slots that keep computing until their
             # slot is re-admitted (pipelined decode fetches lag a block):
             # their writes wrap at the last position instead of OOB.
             lengths = jnp.minimum(lengths, self.max_seq - 2)
-            logits, cache = forward_with_cache(
-                params, tokens[:, None], self.cfg, cache, lengths)
-            logits = logits[:, 0, :].astype(jnp.float32)  # [slots, vocab]
+            logits, cache, counts = self._served.forward(
+                params, tokens[:, None], self.cfg, cache, lengths, 0)
+            counts = tuple(counts[name] for name in self._count_names)
+            logits = logits.astype(jnp.float32)  # [slots, vocab]
             greedy = logits.argmax(-1)
             # Per-slot top-k truncation: threshold at each slot's k-th
             # largest logit (k clamped to _TOP_K_MAX — lax.top_k needs a
@@ -573,14 +637,17 @@ class LLMEngine:
                 sub, sample_logits / jnp.maximum(temps, 1e-6)[:, None])
             next_tokens = jnp.where(temps > 0, sampled,
                                     greedy).astype(jnp.int32)
-            return (cache, next_tokens, lengths + 1, rng), next_tokens
+            return (cache, next_tokens, lengths + 1, rng), (next_tokens,
+                                                            counts)
 
-        (cache, last, lengths, rng), toks = jax.lax.scan(
+        (cache, last, lengths, rng), (toks, counts) = jax.lax.scan(
             step, (cache, last_tokens, lengths, rng), None,
             length=self.decode_steps)
+        if counts:
+            counts = jnp.stack(counts, -1).sum(0, dtype=jnp.int32)
         # Device-side carries (last/lengths) let the NEXT decode dispatch
         # before this block's tokens reach the host (pipelined decode).
-        return cache, toks.T, last, lengths, rng  # toks: [slots, K]
+        return cache, toks.T, last, lengths, rng, counts  # toks [slots, K]
 
     # -- public API ------------------------------------------------------
 
@@ -668,7 +735,14 @@ class LLMEngine:
                 # the block is created), the requests whose read-back
                 # left the wave, and those of them that were waited
                 # for (a hit or an eviction of a block still on its
-                # way, the cap on pending bytes, stop, a model swap).
+                # way, the cap on pending bytes, stop, a model swap);
+                # over the active slots of every decode dispatch the
+                # keys their caches held and those of them the step
+                # attended (fewer where the model selects keys:
+                # `ServedModel.keys_attended`); and what the model
+                # itself counted in its decode blocks, under its names
+                # (an expert layer's `pairs_held`, `pairs_routed`,
+                # `pair_overflows`).
                 "totals": dict(self._totals),
             }
         if self.prefix_cache is not None:
@@ -710,15 +784,12 @@ class LLMEngine:
 
     def _serve_bucket(self, t_real: int) -> int:
         """Smallest compiled bucket that fits `t_real` tokens. The old
-        code keyed `_run_prefill` on the exact power-of-two, so a
+        code keyed `_run_prefill` on the exact bucket, so a
         request just over `warmup_max_prompt_len` missed the AOT ladder
         and paid a mid-serving compile even though a LARGER compiled
         bucket could serve it; now any bucket ≤ the compiled max
         serves from the ladder."""
-        b = 1
-        while b < t_real:
-            b *= 2
-        b = min(b, self.max_seq)
+        b = min(prefill_bucket(t_real), self.max_seq)
         if b in self._prefill_exec or not self._prefill_exec:
             return b
         bigger = [x for x in self._prefill_exec if x >= b]
@@ -841,16 +912,25 @@ class LLMEngine:
         # block N+1 from the device-side carries, THEN fetch block N —
         # the host round-trip overlaps the next block's compute.
         active = int(self._active.sum())
+        attrs = {}
+        if critical_path.enabled():
+            lengths = self._lengths[self._active]
+            attrs = {"keys_cached": int(lengths.sum()),
+                     "keys_attended": int(self._served.keys_attended(
+                         self.cfg, lengths).sum())}
+            for name, n in attrs.items():
+                self._totals[name] += n
         with critical_path.span("engine.decode_dispatch", active=active,
-                                n_slots=self.n_slots):
+                                n_slots=self.n_slots, **attrs):
             prev = self._pending_block
             (self.cache, next_tokens, self._dev_last, self._dev_lengths,
-             self._rng) = self._run_decode(
+             self._rng, counts) = self._run_decode(
                 self._dev_last, self._dev_lengths,
                 jnp.asarray(self._temps_arr),
                 jnp.asarray(self._topks_arr))
             self._pending_block = (next_tokens, [
-                self._slot_req.get(slot) for slot in range(self.n_slots)])
+                self._slot_req.get(slot) for slot in range(self.n_slots)],
+                counts)
             # The device has a block to run and the host nothing to do
             # but wait for the one before it: the read-backs' host half.
             self._finish_readbacks(
@@ -859,7 +939,7 @@ class LLMEngine:
         self._totals["decode_steps"] += self.decode_steps
         self._totals["active_slot_steps"] += active * self.decode_steps
         if prev is not None:
-            self._consume_block(self._fetch_tokens(prev[0]), prev[1])
+            self._consume_block(self._fetch_tokens(prev[0]), *prev[1:])
         # Dispatched before the block just dispatched, so they reach
         # their clients before that block is waited for.
         self._deliver_first_tokens()
@@ -867,7 +947,7 @@ class LLMEngine:
     def _flush_pending(self):
         prev, self._pending_block = self._pending_block, None
         if prev is not None:
-            self._consume_block(self._fetch_tokens(prev[0]), prev[1])
+            self._consume_block(self._fetch_tokens(prev[0]), *prev[1:])
 
     def _fetch_tokens(self, block):
         """A decode block's sampled tokens to the host: the wait for
@@ -875,12 +955,15 @@ class LLMEngine:
         with critical_path.span("engine.token_fetch"):
             return np.asarray(block)
 
-    def _consume_block(self, next_host, owners):
+    def _consume_block(self, next_host, owners, counts=()):
         """Hand a block's tokens to the requests it was dispatched for.
         `owners` are the slots' requests at its dispatch: a slot that
         holds another request by now (admitted while the block was in
-        flight, into a slot retired before) gets none of them."""
+        flight, into a slot retired before) gets none of them.
+        `counts` is what the model counted in the block, ready with its
+        tokens: onto the span and into the totals."""
         kept = stale = 0
+        counted = dict(zip(self._count_names, map(int, np.asarray(counts))))
         with critical_path.span("engine.consume_block") as sp, self._lock:
             for slot in np.nonzero(self._active)[0]:
                 req = self._slot_req[slot]
@@ -904,7 +987,9 @@ class LLMEngine:
             self._totals["tokens_kept"] += kept
             self._totals["tokens_discarded"] += discarded
             self._totals["slot_steps_stale"] += stale
-            sp.set(kept=kept, discarded=discarded, stale=stale)
+            for name, n in counted.items():
+                self._totals[name] += n
+            sp.set(kept=kept, discarded=discarded, stale=stale, **counted)
 
     def _deliver_first_tokens(self):
         """The last wave's first tokens to their clients: the wait for
@@ -964,7 +1049,8 @@ class LLMEngine:
     #
     # The PrefixCache core (pure, spec-checked) decides which blocks
     # exist; the engine owns the PAYLOADS: `_kv_store` maps block
-    # generation id → (k, v) host arrays, and evicted payloads fall to
+    # generation id → host arrays, one a leaf of the cache in the
+    # leaves' order (Llama's (k, v)), and evicted payloads fall to
     # the shm plane under a deterministic ObjectID derived from the
     # chain key. A chain key commits to the model seed + every token of
     # the prefix, so a key hit on ANY tier is byte-identical KV by
@@ -1022,9 +1108,9 @@ class LLMEngine:
                 hit = hit[:i]
                 break
             payloads.append(p)
-        for h, (k_np, v_np) in zip(hit, payloads):
+        for h, block in zip(hit, payloads):
             self.cache = self._write_block_j(
-                self.cache, k_np, v_np, np.int32(slot),
+                self.cache, tuple(block), np.int32(slot),
                 np.int32(h.index * self.block_tokens))
         pc.release(hit)
         return len(hit) * self.block_tokens, chain
@@ -1065,7 +1151,7 @@ class LLMEngine:
             arrays = self._run_read_rows(slot, start, rows)
             for a in arrays:
                 a.copy_to_host_async()
-            rb = _Readback(arrays, start,
+            rb = _Readback(arrays, start, rows,
                            {h.block_id: h for h in created}, nbytes)
             with self._readback_lock:
                 self._readbacks.append(rb)
@@ -1082,11 +1168,12 @@ class LLMEngine:
 
     def _complete_readback(self) -> int:
         """The host half of the oldest read-back: its blocks' rows
-        copied out of the gathered arrays into one block-major slab for
-        K and one for V (one copy a part, not two a block: each call
-        that lets go of the interpreter may wait for it again), of
-        which `_kv_store` holds a block's [L, B, Hkv, D] views; they
-        live until the last of the request's blocks is evicted. Blocks
+        copied out of the gathered arrays into one block-major slab a
+        leaf of the cache (one copy a part, not one a block and leaf:
+        each call that lets go of the interpreter may wait for it
+        again), of which `_kv_store` holds a block's [layers_i, B, ...]
+        views, a tuple in the leaves' order; they live until the last
+        of the request's blocks is evicted. Blocks
         evicted meanwhile are left out. Caller holds `_readback_lock`.
         Returns the blocks stored."""
         rb = self._readbacks.popleft()
@@ -1098,11 +1185,12 @@ class LLMEngine:
         first = min(h.index for h in rb.handles.values())
         n = max(h.index for h in rb.handles.values()) + 1 - first
         lo = first * bt - rb.start
-        slabs = []
-        for parts in (arrays[:len(arrays) // 2], arrays[len(arrays) // 2:]):
+        slabs, at = [], 0
+        for x in self._leaves:
+            parts = arrays[at:at + self._layer_parts(x, rb.rows)]
+            at += len(parts)
             tail = parts[0].shape[2:]
-            slab = np.empty((n, self.cfg.n_layers, bt) + tail,
-                            parts[0].dtype)
+            slab = np.empty((n, x.shape[0], bt) + tail, parts[0].dtype)
             layer = 0
             for part in parts:
                 nl = part.shape[0]
@@ -1111,8 +1199,8 @@ class LLMEngine:
                 layer += nl
             slabs.append(slab)
         for block_id, h in rb.handles.items():
-            self._kv_store[block_id] = (slabs[0][h.index - first],
-                                        slabs[1][h.index - first])
+            self._kv_store[block_id] = tuple(
+                slab[h.index - first] for slab in slabs)
             del self._readback_of[block_id]
         return len(rb.handles)
 
@@ -1246,16 +1334,6 @@ class LLMEngine:
         }
 
 
-def lax_slice_slot(cache, slot):
-    """cache: [L, slots, S, H, D] → [L, 1, S, H, D] at `slot`."""
-    return jax.lax.dynamic_slice_in_dim(cache, slot, 1, axis=1)
-
-
-def lax_write_slot(cache, slot_cache, slot):
-    return jax.lax.dynamic_update_slice_in_dim(cache, slot_cache, slot,
-                                               axis=1)
-
-
 # -- Serve integration ------------------------------------------------------
 
 
@@ -1277,6 +1355,9 @@ def _parse_priority(raw) -> int:
 class LLMDeployment:
     """Deployment-ready wrapper: `serve.deployment(LLMDeployment).bind(...)`.
 
+    `cfg` is the config of any architecture `models.serving` names a
+    cached forward pass for (`LlamaConfig`, `GlmDsaConfig`): nothing
+    else of the deployment depends on which.
     Each replica owns one engine (one KV cache in its chip's HBM) and
     may multiplex N weight variants (``models={name: params_fn}``): the
     compiled programs take params as arguments, so switching models is
@@ -1288,7 +1369,7 @@ class LLMDeployment:
     whose prefix cache already holds the request's prompt head.
     """
 
-    def __init__(self, cfg: LlamaConfig, params_fn: Callable[[], Any] = None,
+    def __init__(self, cfg, params_fn: Callable[[], Any] = None,
                  max_batch_size: int = 8,
                  max_seq_len: Optional[int] = None,
                  decode_steps: int = 1,
@@ -1320,6 +1401,13 @@ class LLMDeployment:
         self.warmup_s = self.engine.warmup(warmup_max_prompt_len) \
             if warmup else 0.0
         self.engine.start()
+
+    def __del__(self):
+        """The replica is stopped (`Replica.prepare_for_shutdown`): the
+        engine's loop ends with it, whatever it still held."""
+        engine = getattr(self, "engine", None)
+        if engine is not None:
+            engine.stop()
 
     # -- model loading / swapping ---------------------------------------
 

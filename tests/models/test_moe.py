@@ -87,9 +87,12 @@ def test_moe_topk_gating_selects_k_experts():
     x = x.at[..., 0].set(1.0)
     lp["router"] = jnp.zeros_like(lp["router"]).at[0].set(
         jnp.array([3.0, 1.0, 1.0, -3.0]))
-    out, aux, counts = _moe_ffn(cfg, lp, x, None, DEFAULT_RULES)
+    out, aux, counts, share = _moe_ffn(cfg, lp, x, None, DEFAULT_RULES)
     assert out.shape == x.shape
     np.testing.assert_array_equal(counts, [8, 8, 0, 0])
+    # Every expert is held: every pair routed is computed here.
+    assert {k: int(v) for k, v in share.items()} == {
+        "pairs_held": 16, "pairs_routed": 16, "pair_overflows": 0}
 
     def experts(weights):
         """The weighted sum of whole experts, one token at a time."""
@@ -107,7 +110,7 @@ def test_moe_topk_gating_selects_k_experts():
     # Gates as the softmax gives them (OLMoE's `norm_topk_prob` false).
     import dataclasses
     plain = dataclasses.replace(cfg, norm_topk_prob=False)
-    out, _, counts = _moe_ffn(plain, lp, x, None, DEFAULT_RULES)
+    out, _, counts, _ = _moe_ffn(plain, lp, x, None, DEFAULT_RULES)
     np.testing.assert_array_equal(counts, [8, 8, 0, 0])
     np.testing.assert_allclose(out, experts([p[0], p[1], 0.0, 0.0]),
                                rtol=1e-4, atol=1e-6)

@@ -58,3 +58,53 @@ def test_expert_layer_compiles_for_the_v5e(one_chip, tokens, k, d, f, e):
     # The permutations are row gathers in both directions: no scatter
     # of rows (the kernel's own group metadata scatters into vectors).
     assert not re.search(r"\[\d+,\d+\]\S* scatter\(", text)
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill"])
+def test_glm_served_step_compiles_for_the_v5e(one_chip, program):
+    """GLM-5.2's share as `benchmark/configs/glm-5.2-serve.json` cuts
+    it, through the engine's own decode and prefill programs at the
+    cell's 16 slots x 16,384: it compiles, the held experts run through
+    the grouped kernel, and the program fits beside nothing else."""
+    from benchmark.harness.manifest import ROOT, load_json, model_adapter
+    from ray_tpu.models.serving import served_model
+    from ray_tpu.serve.llm import LLMEngine
+
+    config = load_json(ROOT, "benchmark", "configs", "glm-5.2-serve.json")
+    model = model_adapter(config)
+    cfg = model.program_config(config)
+    plan = config["serve"]
+    n = plan["max_batch_size"]
+
+    def on_chip(tree):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=one_chip), tree)
+
+    def ints(*dims, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    params = on_chip(jax.eval_shape(
+        lambda: model.init(cfg, jax.random.PRNGKey(0))))
+    cache = on_chip(jax.eval_shape(
+        lambda: model.init_cache(cfg, n, plan["max_seq_len"])))
+    engine = LLMEngine.__new__(LLMEngine)  # its programs, no device
+    engine.cfg, engine._served = cfg, served_model(cfg)
+    engine.max_seq, engine.decode_steps, engine.n_slots = \
+        plan["max_seq_len"], 1, n
+    engine._count_names = ("pair_overflows", "pairs_held", "pairs_routed")
+    if program == "decode":
+        compiled = jax.jit(engine._decode_impl, donate_argnums=(1,)).lower(
+            params, cache, ints(n), ints(n), ints(n, dtype=jnp.float32),
+            ints(n), ints(2, dtype=jnp.uint32)).compile()
+    else:
+        compiled = jax.jit(engine._prefill_impl, donate_argnums=(1,),
+                           static_argnums=(6,)).lower(
+            params, cache, ints(1, 8192), ints(), ints(), ints(),
+            8192).compile()
+    kernels = re.findall(r"%([\w-]+?)(?:\.\d+)? = \S+ custom-call\(",
+                         compiled.as_text())
+    assert sum(k in PRODUCTS for k in kernels) >= 3
+    memory = compiled.memory_analysis()
+    assert memory.argument_size_in_bytes > 9.3e9  # weights and cache
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes \
+        < 16.0e9

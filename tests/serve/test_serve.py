@@ -48,6 +48,29 @@ def test_class_deployment_with_state():
     assert ray_tpu.get(handle.value.remote()) == 12
 
 
+def test_a_stopped_replica_calls_the_deployments_del():
+    """Ray Serve's contract: a user class's `__del__` runs when its
+    replica is stopped, so what it started (an engine's loop thread)
+    ends with it and not at interpreter exit."""
+    import threading
+
+    stopped = threading.Event()
+
+    @serve.deployment
+    class Worker:
+        def __call__(self):
+            return "up"
+
+        def __del__(self):
+            stopped.set()
+
+    handle = serve.run(Worker.bind())
+    assert ray_tpu.get(handle.remote()) == "up"
+    assert not stopped.is_set()
+    serve.shutdown()
+    assert stopped.is_set()
+
+
 def test_multiple_replicas_round_robin():
     @serve.deployment(num_replicas=3)
     class Who:

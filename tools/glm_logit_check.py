@@ -1,0 +1,393 @@
+"""GLM-5.2's served path against the plain reference, with the faults
+that must not pass:
+`python tools/glm_logit_check.py [--weights benchmark|plain] [--hit]
+[seed ...]`.
+
+Outside the benchmark and its timed window (PERF.md, PR 32, has the
+readings). For each seed, what `benchmark/runners/serve.py`'s
+`check_against_reference` does for `benchmark/configs/glm-5.2-serve.json`
+(the shallow copy at the published widths, one row a slot, prefill then
+decode with the rows at their own positions, contexts past
+`index_topk`), with the reference computed once and then, beside the
+program, each of `FAULTS`: the weights cut to float8 e4m3's three
+mantissa bits (the nearest precision below the one the file states; on
+the bits, because the TPU compiler drops a convert to a narrower type
+and back), every key attended (no selection), 1024 keys, a `shared`
+layer selecting anew (run as a `full` layer with the indexer of the
+`full` layer above it), softmax scores in place of sigmoid, the bias
+weighing in the gates, the gate scale left out, the shared expert left
+out, the routed pairs dropped. A position's error is its largest logit
+error over the largest |reference| logit; of each variant the line
+gives the largest over all positions (what the runner holds under
+`logit_tolerance`), the median, the 99th and the 99.9th percentile and
+`over`, the share of positions over `logit_tolerance`.
+
+`--weights benchmark` (the default) draws the weights as the benchmark
+does (`benchmark/models/glm_dsa.py` `init`: the routed experts'
+down-projection `ROUTED_OUT_SCALE` of the program's and the router's
+selection bias `ROUTER_BIAS_SCALE` of it), `--weights plain` as the
+program's initialiser does. At the plain weights a router that
+chooses another expert than the float32 reference (the 8th and 9th of
+256 scores closer than bfloat16 resolves) moves one position in a
+hundred by a tenth of the largest logit and more, in the program and
+in every fault alike, so the largest error tells nothing there. The
+verdict: the program keeps every limit of the file's
+`tool_checks[weights]` (statistics by name) and, at the benchmark's
+weights, the runner's own (`max` within `logit_tolerance`); every fault
+breaks one of them, but for those in `UNSEEN[weights]`, which the other
+set of weights shows.
+
+With `--hit`, after the first seed, the prefix cache's path. `hit
+against miss`: the last row's logits of a prompt prefilled from its
+last whole block on (what a hit runs, over the rows a miss wrote)
+against those of the whole prefill. `engine hit`: an `LLMEngine` over
+the same shallow copy asked the same prompt twice, so that the second
+answer goes through the read-back's payloads and the copy-in, leaf by
+leaf: how many tokens it matched, whether the two answers are the same
+tokens (they need not be: the two prefills are two compiled programs),
+and the hit's tokens against the reference as the runner holds served
+tokens (`served_token_margin`).
+
+Exit code 1 if a verdict fails. `tests/models/test_glm_dsa.py` runs the
+same faults at debug widths on the CPU in float32; this needs a TPU
+(`--rehearse` runs it at the adapter's debug widths wherever it is, for
+the control flow alone).
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import functools
+import json
+import os
+import sys
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
+
+
+def cut(x, bits):
+    """Round to nearest at `bits` fewer mantissa bits."""
+    import jax
+    import jax.numpy as jnp
+
+    whole = {2: jnp.uint16, 4: jnp.uint32}[x.dtype.itemsize]
+    u = jax.lax.bitcast_convert_type(x, whole)
+    u = (u + whole(1 << (bits - 1))) & ~whole((1 << bits) - 1)
+    return jax.lax.bitcast_convert_type(u, x.dtype)
+
+
+def faults(forward, init_cache):
+    """{name: served}: `forward` (the program's cached forward pass,
+    `(params, tokens, cfg, cache, start_pos)`) with one fault each, as
+    the serving runner's check calls it (`served(params, tokens, cfg=,
+    cache=, start_pos=)`)."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.models import moe
+
+    def with_cfg(**changes):
+        def served(params, tokens, cfg, cache, start_pos):
+            return forward(params, tokens,
+                           dataclasses.replace(cfg, **changes), cache,
+                           start_pos)
+        return served
+
+    def lower_precision(params, tokens, cfg, cache, start_pos):
+        # bfloat16 keeps 7 mantissa bits and float32 23, e4m3 keeps 3.
+        bits = {2: 4, 4: 20}
+        params = jax.tree.map(
+            lambda x: cut(x, bits[x.dtype.itemsize]) if x.ndim > 1 else x,
+            params)
+        return forward(params, tokens, cfg, cache, start_pos)
+
+    def shared_selects_anew(params, tokens, cfg, cache, start_pos):
+        """Every `shared` layer becomes a `full` one with the indexer
+        of the last layer (a `full` one). Its cache is made here, at the
+        prefill, because it has leaves the program's lacks."""
+        kinds = tuple((ffn, "full") for ffn, _ in cfg.kinds)
+        faulty = dataclasses.replace(cfg, layer_kinds=kinds)
+        last = params["runs"][-1]
+        indexer = {k: last[k][-1:] for k in
+                   ("wiq", "wik", "ik_norm", "ik_bias", "wiw")}
+        layers = []
+        for run in params["runs"]:
+            for i in range(run["wqa"].shape[0]):
+                layer = jax.tree.map(lambda x: x[i:i + 1], run)
+                layers.append({**indexer, **layer})
+        runs, at = [], 0
+        for _, n in faulty.runs():
+            runs.append(jax.tree.map(lambda *xs: jnp.concatenate(xs),
+                                     *layers[at:at + n]))
+            at += n
+        if tokens.shape[1] > 1:
+            cache = init_cache(faulty, tokens.shape[0],
+                               cache["runs"][0]["latent"].shape[2])
+        return forward({**params, "runs": runs}, tokens, faulty, cache,
+                       start_pos)
+
+    def bias_in_the_gates(params, tokens, cfg, cache, start_pos):
+        route = moe._route
+
+        def biased(cfg, lp, x):
+            fair = dataclasses.replace(cfg, norm_topk_prob=False,
+                                       gate_scale=1.0)
+            probs, gates, top_i = route(fair, lp, x)
+            gates = gates + jnp.take_along_axis(
+                jnp.broadcast_to(lp["router_bias"], probs.shape), top_i, -1)
+            if cfg.norm_topk_prob:
+                gates = gates / gates.sum(-1, keepdims=True)
+            return probs, gates * cfg.gate_scale, top_i
+
+        moe._route = biased
+        try:
+            return forward(params, tokens, cfg, cache, start_pos)
+        finally:
+            moe._route = route
+
+    def routed_pairs_dropped(params, tokens, cfg, cache, start_pos):
+        held = moe._held_experts
+
+        def none(*args):
+            out, n_held, over = held(*args)
+            return jnp.zeros_like(out), n_held, over
+
+        moe._held_experts = none
+        try:
+            return forward(params, tokens, cfg, cache, start_pos)
+        finally:
+            moe._held_experts = held
+
+    return {
+        "lower precision": lower_precision,
+        "every key": with_cfg(index_topk=1 << 30),
+        "half the keys": lambda p, t, cfg, cache, start_pos: forward(
+            p, t, dataclasses.replace(cfg, index_topk=cfg.index_topk // 2),
+            cache, start_pos),
+        "shared selects anew": shared_selects_anew,
+        "softmax scores": with_cfg(scoring="softmax"),
+        "bias in the gates": bias_in_the_gates,
+        "no gate scale": with_cfg(gate_scale=1.0),
+        "no shared expert": with_cfg(shared_hidden_dim=0),
+        "routed pairs dropped": routed_pairs_dropped,
+    }
+
+
+# The faults a set of weights cannot show on the chip (each is seen at
+# the other; PERF.md section 6, PR 32, has the readings). The
+# benchmark's weights make the routed experts 32 times quieter, so what
+# only changes their gates stays inside the program's own error there.
+# At the plain weights one position in a hundred is moved by a router's
+# near-tie, and a `shared` layer that selects anew moves fewer than that.
+UNSEEN = {"benchmark": ("bias in the gates", "no gate scale"),
+          "plain": ("shared selects anew",)}
+
+
+def within(row, limits):
+    """Whether a variant's statistics keep every limit: `limits` names
+    statistics of `distances` and the most each may be."""
+    return all(row[name] <= most for name, most in limits.items())
+
+
+def weights_and_tokens(config, seed, model, init):
+    """The shallow copy, its weights and the check's tokens, as the
+    serving runner makes them from the seed."""
+    import jax
+    import numpy as np
+
+    from benchmark.runners.train import prng_key
+
+    plan = config["serve"]
+    small = model.with_layers(model.program_config(config),
+                              plan["reference_layers"])
+    params = jax.jit(functools.partial(init, small))(prng_key(seed))
+    lens = np.asarray(plan["reference_prompt_lens"])
+    tokens = np.random.default_rng([seed, 7]).integers(
+        0, config["vocab_size"],
+        (len(lens), int(lens.max()) + plan["reference_decode_steps"]),
+        dtype=np.int32)
+    return small, params, lens, tokens
+
+
+def distances(config, small, params, lens, tokens, model, reference,
+              served_by_name):
+    """`check_against_reference` of the serving runner for each of
+    `served_by_name`, the reference computed once: {name: the errors of
+    its positions, each the largest logit error over the largest
+    |reference| logit, as their largest, median, 99th and 99.9th
+    percentile and the share over `logit_tolerance`}."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    plan = config["serve"]
+    rows, n_dec = len(lens), plan["reference_decode_steps"]
+    n_pre = tokens.shape[1] - n_dec
+    with jax.default_matmul_precision("highest"):
+        want = jax.jit(functools.partial(
+            reference.forward, hp=reference.hyper(config)))(
+                params, jnp.asarray(tokens))
+    top = float(jnp.abs(want).max())
+    error = jax.jit(lambda got, want: jnp.abs(
+        got.astype(jnp.float32) - want).max(-1))
+    out = {}
+    at = np.arange(rows)
+    for name, served in served_by_name.items():
+        step = jax.jit(functools.partial(served, cfg=small))
+        cache = model.init_cache(small, rows, plan["max_seq_len"])
+        logits, cache = step(params, jnp.asarray(tokens[:, :n_pre]),
+                             cache=cache,
+                             start_pos=jnp.zeros(rows, jnp.int32))
+        errors = [np.asarray(error(logits, want[:, :n_pre])).ravel()]
+        for i in range(n_dec):
+            logits, cache = step(
+                params, jnp.asarray(tokens[at, lens + i][:, None]),
+                cache=cache, start_pos=jnp.asarray(lens + i, jnp.int32))
+            errors.append(np.asarray(error(logits[:, 0],
+                                           want[at, lens + i])))
+        e = np.concatenate(errors) / top
+        out[name] = {"max": float(e.max()),
+                     **{f"q{q}": float(np.quantile(e, float(q) / 100))
+                        for q in ("50", "99", "99.9")},
+                     "over": float((e > plan["logit_tolerance"]).mean())}
+        del cache, logits
+    return out
+
+
+def hit_against_miss(config, small, params, tokens, model):
+    """What a prefix hit changes in the logits: a prompt's last row
+    from a prefill of the whole prompt (a miss), and from a prefill of
+    what follows its last whole block over the rows the miss wrote (a
+    hit copies those rows in bit for bit and prefills the rest), for a
+    prompt past `index_topk` and a short one. {length: largest logit
+    difference over the largest |logit|}."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu._private.config import ray_config
+
+    block = ray_config.llm_kv_block_tokens
+    step = jax.jit(functools.partial(model.cached_forward, cfg=small))
+    out = {}
+    for n in (tokens.shape[1], min(3 * block + 8, tokens.shape[1] - 3)):
+        prompt = jnp.asarray(tokens[:1, :n])
+        cache = model.init_cache(small, 1, config["serve"]["max_seq_len"])
+        miss, cache = step(params, prompt, cache=cache,
+                           start_pos=jnp.zeros(1, jnp.int32))
+        matched = (n - 1) // block * block
+        hit, cache = step(params, prompt[:, matched:], cache=cache,
+                          start_pos=jnp.full(1, matched, jnp.int32))
+        out[n] = float(jnp.abs(hit[0, -1] - miss[0, -1]).max()
+                       / jnp.abs(miss[0, -1]).max())
+        del cache
+    return out
+
+
+def engine_hit(config, small, params, tokens, reference, n_prompt, n_new):
+    """The same prompt asked of an `LLMEngine` twice: the second time
+    the prefix cache matches all its whole blocks, so the answer goes
+    through the payloads the first one's read-back stored and the
+    copy-in of every leaf of the cache. The hit's tokens are held
+    against the reference as the runner holds served tokens."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from ray_tpu.serve.llm import LLMEngine, SamplingParams
+
+    matched = []
+
+    class Engine(LLMEngine):
+        def _prefix_copy_in(self, req, slot, prompt):
+            m_tok, chain = super()._prefix_copy_in(req, slot, prompt)
+            matched.append(m_tok)
+            return m_tok, chain
+
+    prompt = [int(t) for t in tokens[0, :n_prompt]]
+    engine = Engine(small, params, max_batch_size=2,
+                    max_seq_len=1 << (n_prompt + n_new).bit_length())
+    try:
+        miss = engine.generate(prompt, SamplingParams(max_tokens=n_new))
+        hit = engine.generate(prompt, SamplingParams(max_tokens=n_new))
+        totals = engine.metrics()["totals"]
+    finally:
+        engine.stop()
+    with jax.default_matmul_precision("highest"):
+        logits, = reference.logits_layer_by_layer(
+            params, [jnp.asarray((prompt + hit)[:-1], jnp.int32)],
+            reference.hyper(config))
+    rows = np.asarray(logits)[n_prompt - 1:]
+    short = float((rows.max(-1) - rows[np.arange(n_new), hit]).max()
+                  / np.abs(np.asarray(logits)).max())
+    same = next((i for i, (a, b) in enumerate(zip(miss, hit)) if a != b),
+                n_new)
+    return {"matched": matched, "tokens": n_new, "same as the miss": same,
+            "worst under the reference": short,
+            "blocks read back": totals["kv_blocks_read_back"]}
+
+
+def main(argv):
+    import jax
+
+    from benchmark.harness.manifest import load_json, model_adapter, plugin
+    from ray_tpu.models.glm_dsa import init_params
+
+    weights = "benchmark"
+    if "--weights" in argv:
+        weights = argv.pop(argv.index("--weights") + 1)
+        argv.remove("--weights")
+    rehearse, hit = "--rehearse" in argv, "--hit" in argv
+    argv = [a for a in argv if a not in ("--rehearse", "--hit")]
+    seeds = [int(a) for a in argv] or [2147483747]
+    config = load_json(ROOT, "benchmark", "configs", "glm-5.2-serve.json")
+    model = model_adapter(config)
+    if rehearse:
+        config = model.debug(config)
+        config["serve"].update(reference_prompt_lens=[40, 33, 26, 19],
+                               max_seq_len=64)
+    elif jax.default_backend() != "tpu":
+        raise SystemExit(f"needs a TPU, found {jax.default_backend()}")
+    reference = plugin("references", config["reference"])
+    plan = config["serve"]
+    tolerance = plan["logit_tolerance"]
+    init = {"benchmark": model.init, "plain": init_params}[weights]
+    served = {"program": model.cached_forward,
+              **faults(model.cached_forward, model.init_cache)}
+    # The benchmark's weights are held as the runner holds them, by the
+    # largest error, and by the tool's own limits; the plain weights by
+    # the tool's limits alone.
+    limits = dict(plan["tool_checks"][weights])
+    if weights == "benchmark":
+        limits["max"] = tolerance
+    ok = True
+    for i, seed in enumerate(seeds):
+        small, params, lens, tokens = weights_and_tokens(
+            config, seed, model, init)
+        row = distances(config, small, params, lens, tokens, model,
+                        reference, served)
+        print(json.dumps({"seed": seed, "weights": weights, **row}),
+              flush=True)
+        ok = ok and within(row.pop("program"), limits) and not any(
+            within(v, limits) for k, v in row.items()
+            if k not in UNSEEN[weights])
+        if hit and i == 0:
+            n_prompt = int(lens.max()) - 12 if rehearse else 2100
+            by_length = hit_against_miss(config, small, params, tokens,
+                                         model)
+            asked = engine_hit(config, small, params, tokens, reference,
+                               n_prompt, 8 if rehearse else 24)
+            print(json.dumps({"seed": seed, "hit against miss": by_length,
+                              "engine hit": asked}), flush=True)
+            ok = ok and max(by_length.values()) <= tolerance \
+                and asked["matched"][0] == 0 < asked["matched"][1] \
+                and asked["worst under the reference"] \
+                <= plan["served_token_margin"]
+    print(json.dumps({"weights": weights, "tolerance": tolerance,
+                      "ok": ok}))
+    return 0 if ok else 1
+
+
+if __name__ == "__main__":
+    sys.exit(main(sys.argv[1:]))
