@@ -7,10 +7,14 @@ differs between architectures is handed in as two functions:
 - ``mixer(h, lp, rope, state, handed) -> (attn [B, S, H, K], state,
   handed)``: normed activations and the layer's parameters to the
   attention output before the `wo` projection. `rope` is the stack's
-  `(cos, sin)`; `state` is what the mixer carries per layer (a slot
-  cache's K and V), or None; `handed` is what a layer hands up to the
-  layer above beside `x` (a sparse-attention layer's selection), None
-  in a stack that hands nothing on.
+  `(cos, sin)`. `state` is None, or what the mixer keeps from call to
+  call as `(stacks, layer)`: the run's stacked leaves (a slot cache's,
+  each [layers, slots, max_seq, ...]) and the index of this layer in
+  them. Only the mixer writes them, its new rows at its own layer
+  (`write_rows`), and it reads its layer back out of them
+  (`layer_rows`); it returns the stacks. `handed` is what a layer hands
+  up to the layer above beside `x` (a sparse-attention layer's
+  selection), None in a stack that hands nothing on.
 - ``ffn(h, lp) -> (out [B, S, D], extras)``: `extras` is a pytree the
   layer reports (an expert layer's aux loss and counts), or None.
 
@@ -82,8 +86,9 @@ def block(mixer, ffn, cfg, rope, x, lp, state=None, handed=None, *,
           mesh=None, rules=DEFAULT_RULES):
     """One transformer block. x: [B, S, D] -> (x, state, extras,
     handed). The two halves are scoped (`attn`, `mlp`) so that a device
-    trace can tell their ops apart. `handed` is what the layer below
-    handed up beside x, None in most stacks."""
+    trace can tell their ops apart. `state` goes to the mixer as it is
+    and comes back as the mixer returns it; `handed` is what the layer
+    below handed up beside x, None in most stacks."""
     with jax.named_scope("attn"):
         h = rms_norm_reference(x, lp["attn_norm"], cfg.norm_eps)
         attn, state, handed = mixer(h, lp, rope, state, handed)
@@ -101,28 +106,69 @@ def block(mixer, ffn, cfg, rope, x, lp, state=None, handed=None, *,
 def layers(mixer, ffn, cfg, rope, x, stacked, state=None, handed=None, *,
            save: Optional[Sequence[str]] = None, mesh=None,
            rules=DEFAULT_RULES):
-    """x through a run of like layers (`stacked`: their parameters, and
-    `state`: the mixer's, along a leading axis) by `lax.scan`. Returns
-    (x, state, extras, handed), the middle two stacked by layer and
-    `handed` as the last layer left it. `save` is the remat policy: None
-    keeps every activation; a list rematerialises each layer in the
-    backward pass but for the `checkpoint_name`s in it (an empty list
-    saves nothing)."""
+    """x through a run of like layers (`stacked`: their parameters along
+    a leading axis) by `lax.scan`. Returns (x, state, extras, handed):
+    `extras` stacked by layer, `handed` as the last layer left it.
+
+    `state` is what the mixer keeps from call to call, stacked by layer
+    like the parameters (a slot cache's leaves, [layers, slots, max_seq,
+    ...]), or None. It is the scan's carry, never a scanned input or
+    output: a scanned leaf that the body changes is copied out of its
+    stack and into a new one, layer by layer, whole. The mixer is handed
+    `(state, layer)`, the stacks and its layer's index in them, writes
+    its new rows there (`write_rows`), reads its layer through
+    `layer_rows`, and returns the stacks. With no state it is handed
+    None and the scan carries x and `handed` alone.
+
+    `save` is the remat policy: None keeps every activation; a list
+    rematerialises each layer in the backward pass but for the
+    `checkpoint_name`s in it (an empty list saves nothing)."""
     def body(carry, scanned):
-        x, handed = carry
-        lp, layer_state = scanned
-        x, layer_state, extras, handed = block(
-            mixer, ffn, cfg, rope, x, lp, layer_state, handed, mesh=mesh,
+        x, handed, state = carry
+        lp, layer = scanned
+        x, state, extras, handed = block(
+            mixer, ffn, cfg, rope, x, lp,
+            None if state is None else (state, layer), handed, mesh=mesh,
             rules=rules)
-        return (x, handed), (layer_state, extras)
+        return (x, handed, state), extras
 
     if save is not None:
         body = jax.checkpoint(
             body,
             policy=jax.checkpoint_policies.save_only_these_names(*save))
-    (x, handed), (state, extras) = lax.scan(body, (x, handed),
-                                            (stacked, state))
+    index = None if state is None else jnp.arange(
+        jax.tree.leaves(state)[0].shape[0])
+    (x, handed, state), extras = lax.scan(body, (x, handed, state),
+                                          (stacked, index))
     return x, state, extras, handed
+
+
+def write_rows(stack, layer, new, start_pos):
+    """`new` [B, T, ...] into `stack` [layers, B, S, ...] at (layer,
+    row, start_pos[row]), cast to the stack's dtype: B x T rows are
+    written and no other byte of the stack is read or written. A row
+    that would pass S is moved back to end there, as
+    `lax.dynamic_update_slice` does."""
+    rows = jnp.arange(new.shape[0], dtype=start_pos.dtype)
+    at = jnp.stack([jnp.full_like(rows, layer), rows, start_pos], -1)
+    return lax.scatter(
+        stack, at, new.astype(stack.dtype),
+        lax.ScatterDimensionNumbers(
+            update_window_dims=tuple(range(1, new.ndim)),
+            inserted_window_dims=(0, 1),
+            scatter_dims_to_operand_dims=(0, 1, 2)),
+        indices_are_sorted=True, unique_indices=True, mode="clip")
+
+
+def layer_rows(stack, layer, start, rows):
+    """Rows [start, start + rows) of every slot of one layer of `stack`
+    [layers, B, S, ...], to be read: [B, rows, ...], one slice of the
+    stack itself, so that a loop over blocks of rows never holds the
+    layer whole."""
+    tail = stack.shape[3:]
+    return lax.dynamic_slice(
+        stack, (layer, 0, start) + (0,) * len(tail),
+        (1, stack.shape[1], rows) + tail)[0]
 
 
 def rope_tables(cfg, positions=None, *, mesh=None, rules=DEFAULT_RULES):

@@ -240,12 +240,6 @@ def _rotate_head(x, cos, sin, n):
         [_rotate_pairs(x[..., :n], cos, sin), x[..., n:]], -1)
 
 
-def _write(cache, new, start_pos):
-    """cache [B, S, W] with new [B, T, W] at each row's `start_pos`."""
-    return jax.vmap(lambda c, n, s: lax.dynamic_update_slice(
-        c, n.astype(c.dtype), (s, 0)))(cache, new, start_pos)
-
-
 def _key_blocks(positions, max_seq, block):
     """How many blocks of `block` keys hold every key the rows at
     `positions` can see."""
@@ -255,13 +249,14 @@ def _key_blocks(positions, max_seq, block):
 def _index_scores(qi, w, index_cache, positions):
     """I[b, t, s] = sum_j w[b, t, j] relu(qi[b, t, j] . k[b, s]) in
     float32 for s <= positions[b, t], -inf past it. qi [B, T, J, D], w
-    [B, T, J] float32, index_cache [B, S, D] -> [B, T, S]."""
+    [B, T, J] float32, index_cache (the stack [layers, B, S, D], the
+    layer), read a block of keys at a time -> [B, T, S]."""
     b, t = positions.shape
-    s = index_cache.shape[1]
+    s = index_cache[0].shape[2]
     tk = math.gcd(s, _KEY_BLOCK)
 
     def body(j, out):
-        keys = lax.dynamic_slice_in_dim(index_cache, j * tk, tk, 1)
+        keys = decoder.layer_rows(*index_cache, j * tk, tk)
         dots = jnp.einsum("btjd,bsd->btjs", qi, keys,
                           preferred_element_type=jnp.float32)
         block = (jax.nn.relu(dots) * w[..., None]).sum(2)
@@ -307,18 +302,19 @@ def _select(cfg, scores, positions):
 
 def _attend(q_lat, q_rope, latent, rope_keys, mask, positions, scale):
     """Attention of q over the cached keys `mask` allows, against the
-    latent: q_lat [B, T, H, C], q_rope [B, T, H, R], latent [B, S, C],
-    rope_keys [B, S, R], mask [B, T, S] -> [B, T, H, C] float32. Scores,
-    softmax and both accumulations are float32; the caches enter both
-    products in the dtype they are stored in."""
+    latent: q_lat [B, T, H, C], q_rope [B, T, H, R], mask [B, T, S] ->
+    [B, T, H, C] float32. `latent` and `rope_keys` are each (the stack
+    [layers, B, S, width], the layer), read a block of keys at a time.
+    Scores, softmax and both accumulations are float32; the caches
+    enter both products in the dtype they are stored in."""
     b, t, h, c = q_lat.shape
-    s = latent.shape[1]
+    s = latent[0].shape[2]
     tk = math.gcd(s, _KEY_BLOCK)
 
     def body(j, carry):
         top, total, acc = carry
-        lat = lax.dynamic_slice_in_dim(latent, j * tk, tk, 1)
-        rot = lax.dynamic_slice_in_dim(rope_keys, j * tk, tk, 1)
+        lat = decoder.layer_rows(*latent, j * tk, tk)
+        rot = decoder.layer_rows(*rope_keys, j * tk, tk)
         allowed = lax.dynamic_slice_in_dim(mask, j * tk, tk, 2)[:, None]
         scores = (jnp.einsum("bthc,bsc->bhts", q_lat, lat,
                              preferred_element_type=jnp.float32)
@@ -360,15 +356,19 @@ def _by_query_blocks(fn, t, *arrays):
 
 
 def _mixer(cfg: GlmDsaConfig, indexer, start_pos, positions):
-    """The mixer of a run of `full` or of `shared` layers. It carries
-    the layer's slices of the slot cache, (latent, rotary key) and for
-    a `full` layer the indexer's key, each [B, S, width], and is handed
-    and hands on the selection [B, T, S]."""
+    """The mixer of a run of `full` or of `shared` layers. Its state is
+    the run's stacks of the slot cache, (latent, rotary key) and for
+    `full` layers the indexer's key, each [layers, B, S, width], which
+    `decoder.layers` carries through the scan: the layer's B x T new
+    rows go into them at (layer, row, `start_pos[row]`), and the
+    indexer and attention read the layer's keys out of them by blocks.
+    It is handed and hands on the selection [B, T, S]."""
     nope, rot = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
     scale = (nope + rot) ** -0.5
     index_scale = (cfg.index_n_heads * cfg.index_head_dim) ** -0.5
 
     def mixer(h, lp, rope, state, selected):
+        stacks, layer = state
         cos, sin = rope
         with jax.named_scope("mla_proj"):
             c_q = rms_norm_reference(
@@ -378,14 +378,18 @@ def _mixer(cfg: GlmDsaConfig, indexer, start_pos, positions):
             kva = jnp.einsum("btd,dc->btc", h, lp["wkva"])
             c_kv = rms_norm_reference(kva[..., :cfg.kv_lora_rank],
                                       lp["kv_norm"], cfg.norm_eps)
-            latent = _write(state[0], c_kv, start_pos)
-            rope_keys = _write(
-                state[1], _rotate_pairs(kva[..., cfg.kv_lora_rank:], cos,
-                                        sin), start_pos)
-            q_nope = q[..., :nope].astype(latent.dtype)
-            q_rope = _rotate_pairs(q[..., nope:], cos, sin).astype(
-                latent.dtype)
-        new_state = (latent, rope_keys)
+            # Each a stack with this layer's new rows in it, and the
+            # layer: what the indexer and attention read blocks from.
+            latent = (decoder.write_rows(stacks[0], layer, c_kv, start_pos),
+                      layer)
+            rope_keys = (decoder.write_rows(
+                stacks[1], layer, _rotate_pairs(
+                    kva[..., cfg.kv_lora_rank:], cos, sin), start_pos),
+                layer)
+            cached = stacks[0].dtype
+            q_nope = q[..., :nope].astype(cached)
+            q_rope = _rotate_pairs(q[..., nope:], cos, sin).astype(cached)
+        new_state = (latent[0], rope_keys[0])
         if indexer == "full":
             with jax.named_scope("indexer"):
                 qi = _rotate_head(
@@ -394,11 +398,12 @@ def _mixer(cfg: GlmDsaConfig, indexer, start_pos, positions):
                 ki = _rotate_head(layer_norm(
                     jnp.einsum("btd,de->bte", h, lp["wik"]), lp["ik_norm"],
                     lp["ik_bias"], _INDEX_KEY_EPS), cos, sin, rot)
-                index_keys = _write(state[2], ki, start_pos)
-                qi = qi.astype(index_keys.dtype)
+                index_keys = (decoder.write_rows(stacks[2], layer, ki,
+                                                 start_pos), layer)
+                qi = qi.astype(stacks[2].dtype)
                 w = jnp.einsum("btd,dj->btj", h, lp["wiw"]).astype(
                     jnp.float32) * index_scale
-            new_state += (index_keys,)
+            new_state += (index_keys[0],)
 
         def attend(q_nope, q_rope, pos, *chosen):
             """One block of queries. `chosen`: the indexer's queries
@@ -418,7 +423,7 @@ def _mixer(cfg: GlmDsaConfig, indexer, start_pos, positions):
                                    lp["wkvb"][..., :nope])
                 out = _attend(q_lat, q_rope, latent, rope_keys, mask, pos,
                               scale)
-                out = jnp.einsum("bthc,chv->bthv", out.astype(latent.dtype),
+                out = jnp.einsum("bthc,chv->bthv", out.astype(cached),
                                  lp["wkvb"][..., nope:])
             return out, mask
 

@@ -23,7 +23,6 @@ from typing import Any, Dict
 
 import jax
 import jax.numpy as jnp
-from jax import lax
 from jax.ad_checkpoint import checkpoint_name
 
 from ray_tpu.models import decoder
@@ -402,21 +401,22 @@ def _cached_attention(cfg, q, k_cache, v_cache, q_positions):
 
 
 def _cached_self_attention(cfg: LlamaConfig, start_pos, positions):
-    """The mixer of serving: the new K and V written into the layer's
-    slices of the slot cache at `start_pos`, then attention over them.
-    Carries (k_cache, v_cache), each [B, S, Hkv, D]."""
-    def write_cache(cache_b, new_b, start_b):
-        # cache_b: [S, Hkv, D]; new_b: [T, Hkv, D]
-        return lax.dynamic_update_slice(
-            cache_b, new_b.astype(cache_b.dtype), (start_b, 0, 0))
-
+    """The mixer of serving. Its state is the slot cache's two stacks,
+    (K, V), each [layers, B, S, Hkv, D], which `decoder.layers` carries
+    through the scan: the layer's new K and V go into them at (layer,
+    row, `start_pos[row]`), B x T rows a stack, and `_cached_attention`
+    (looked up in this module when the mixer is traced) reads the
+    layer's [B, S, Hkv, D] out of them."""
     def mixer(h, lp, rope, state, handed):
-        k_cache, v_cache = state
+        (k_stack, v_stack), layer = state
         q, k, v = _qkv(cfg, h, lp, rope, positions, norm_all_heads)
-        k_cache = jax.vmap(write_cache)(k_cache, k, start_pos)
-        v_cache = jax.vmap(write_cache)(v_cache, v, start_pos)
-        return (_cached_attention(cfg, q, k_cache, v_cache, positions),
-                (k_cache, v_cache), handed)
+        k_stack = decoder.write_rows(k_stack, layer, k, start_pos)
+        v_stack = decoder.write_rows(v_stack, layer, v, start_pos)
+        max_seq = k_stack.shape[2]
+        out = _cached_attention(
+            cfg, q, decoder.layer_rows(k_stack, layer, 0, max_seq),
+            decoder.layer_rows(v_stack, layer, 0, max_seq), positions)
+        return out, (k_stack, v_stack), handed
 
     return mixer
 

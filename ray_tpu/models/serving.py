@@ -13,7 +13,10 @@ through the slot cache. The engine knows no model; it takes from here
 - ``init_cache(cfg, n_slots, max_seq) -> cache``: any pytree whose
   every leaf is [layers_i, slots, max_seq, ...]; the engine slices
   slots, reads and writes blocks of rows and sizes its prefix cache leaf
-  by leaf;
+  by leaf. `forward` returns it with the call's [B, T] new rows a layer
+  written in and nothing else moved: the leaves ride the model's layer
+  scan as its carry (`decoder.layers`), so a cache that is donated to
+  the program is updated where it lies;
 - ``keys_attended(cfg, lengths) -> per row``: of `lengths` cached keys
   (host integers) how many the next token attends: all of them, unless
   the model selects keys.

@@ -1,15 +1,24 @@
 """`_cached_attention` against the plain repeat-and-float32 form it
-replaced (kept here as the reference), and a walk over a decode step's
-jaxpr that keeps the repeat and the up-cast of the cache from coming
-back unnoticed."""
+replaced (kept here as the reference); a walk over the jaxpr of both
+served families' cached forward pass that holds its structure: the slot
+cache rides the layer scan as its carry, a layer writes B x T rows into
+it, and nothing but the stacks is larger than a layer's leaf (so the
+repeat and the up-cast of the cache cannot come back unnoticed); and
+the forward pass against the form it had up to PR 32 (kept here too:
+the cache scanned beside the parameters, a layer's slices written
+whole), to the bit."""
+
+import dataclasses
 
 import numpy as np
 import pytest
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 
-from ray_tpu.models import llama
+from ray_tpu.models import decoder, glm_dsa, llama
+from ray_tpu.ops.norms import layer_norm, rms_norm_reference
 from ray_tpu.models.llama import (
     LlamaConfig,
     forward_with_cache,
@@ -77,45 +86,265 @@ def test_matches_repeat_and_float32_reference(rep, b, t):
                            np.float32))
 
 
-def _intermediates(jaxpr):
+# ---------------------------------------------------------------------------
+# The structure of the cached forward pass
+# ---------------------------------------------------------------------------
+
+# Longer than a block of keys (`glm_dsa._KEY_BLOCK`), so that a read of
+# a block is not a read of a one-layer run's whole leaf.
+SLOTS, MAX_SEQ = 4, 2048
+
+# Two layers and more in a run, so that a cache scanned by layer would
+# show; bfloat16 and four query heads a KV head for the dense family, so
+# that the repeat and the up-cast would.
+WALKED = {
+    "llama": (
+        LlamaConfig(vocab_size=64, dim=512, n_layers=2, n_heads=8,
+                    n_kv_heads=2, hidden_dim=64, max_seq_len=MAX_SEQ,
+                    dtype=jnp.bfloat16),
+        init_params, init_kv_cache, forward_with_cache),
+    "glm_dsa": (
+        dataclasses.replace(
+            glm_dsa.GlmDsaConfig.debug_glm(), n_layers=4, layer_kinds=(
+                ("dense", "full"), ("sparse", "shared"),
+                ("sparse", "shared"), ("sparse", "full"))),
+        glm_dsa.init_params, glm_dsa.init_cache,
+        glm_dsa.forward_with_cache),
+}
+
+
+def _eqns(jaxpr):
+    """Every equation, those inside scans, loops and calls too."""
     for eqn in jaxpr.eqns:
-        for var in eqn.outvars:
-            if hasattr(var.aval, "shape"):
-                yield eqn.primitive.name, var.aval
+        yield eqn
         for sub in jax.core.jaxprs_in_params(eqn.params):
-            yield from _intermediates(sub)
+            yield from _eqns(sub)
 
 
-def _decode_step_offenders():
-    """Intermediates of one decode step (`T = 1`, `rep = 4`) that are
-    larger than one layer's K cache, or as large and float32. One layer,
-    so that the stacked cache the step returns is of that size too."""
-    slots, max_seq = 4, 256
-    cfg = LlamaConfig(vocab_size=64, dim=128, n_layers=1, n_heads=8,
-                      n_kv_heads=2, hidden_dim=64, max_seq_len=max_seq,
-                      dtype=jnp.bfloat16)
-    layer_cache = slots * max_seq * cfg.n_kv_heads * cfg.head_dim
-    params = jax.eval_shape(lambda: init_params(cfg, jax.random.PRNGKey(0)))
-    cache = jax.eval_shape(lambda: init_kv_cache(cfg, slots, max_seq))
+def _walk(family, b, t):
+    """The cached forward pass of `tokens` [b, t] as a jaxpr, walked:
+    (offences, how many scans carry a cache leaf, how many writes into
+    a cache leaf). An offence is a cache leaf scanned in or out by
+    layer, a write into one that is not b x t rows, and an intermediate
+    larger than a layer's largest leaf that is not a stack, or as large
+    and float32 where the cache is not."""
+    cfg, init, init_cache, forward = WALKED[family]
+    params = jax.eval_shape(lambda: init(cfg, jax.random.PRNGKey(0)))
+    cache = jax.eval_shape(lambda: init_cache(cfg, b, MAX_SEQ))
     closed = jax.make_jaxpr(
-        lambda p, c, tok, pos: forward_with_cache(p, tok, cfg, c, pos))(
-            params, cache, jax.ShapeDtypeStruct((slots, 1), jnp.int32),
-            jax.ShapeDtypeStruct((slots,), jnp.int32))
-    seen = list(_intermediates(closed.jaxpr))
-    assert len(seen) > 50  # the walk went into the layer scan
-    return [(name, aval.shape, aval.dtype.name) for name, aval in seen
-            if aval.size > layer_cache
-            or (aval.size == layer_cache and aval.dtype == jnp.float32)]
+        lambda p, c, tok, pos: forward(p, tok, cfg, c, pos))(
+            params, cache, jax.ShapeDtypeStruct((b, t), jnp.int32),
+            jax.ShapeDtypeStruct((b,), jnp.int32))
+    leaves = jax.tree.leaves(cache)
+    stacks = {(x.shape, x.dtype) for x in leaves}
+    layer_leaf = max(x.size // x.shape[0] for x in leaves)
+
+    def is_stack(var):
+        aval = var.aval
+        return (getattr(aval, "shape", None), getattr(aval, "dtype", None)) \
+            in stacks
+
+    offences, carrying, writes, seen = [], 0, 0, 0
+    for eqn in _eqns(closed.jaxpr):
+        seen += 1
+        name = eqn.primitive.name
+        if name == "scan":
+            consts, carry = eqn.params["num_consts"], eqn.params["num_carry"]
+            carrying += any(map(is_stack,
+                                eqn.invars[consts:consts + carry]))
+            offences += [("scanned in", v.aval.shape)
+                         for v in eqn.invars[consts + carry:] if is_stack(v)]
+            offences += [("scanned out", v.aval.shape)
+                         for v in eqn.outvars[carry:] if is_stack(v)]
+        if list(jax.core.jaxprs_in_params(eqn.params)):
+            continue  # a scan, a loop, a call: its body is walked
+        for var in eqn.outvars:
+            aval = var.aval
+            if not hasattr(aval, "shape"):
+                continue
+            if is_stack(var):
+                rows = eqn.invars[2].aval.shape[:2] \
+                    if name == "scatter" else None
+                writes += rows == (b, t)
+                if rows != (b, t):
+                    offences.append((name, aval.shape, "rows", rows))
+            elif aval.size > layer_leaf or (
+                    aval.size == layer_leaf and aval.dtype == jnp.float32
+                    and leaves[0].dtype != jnp.float32):
+                offences.append((name, aval.shape, aval.dtype.name))
+    assert seen > 50  # the walk went into the layer scan
+    return offences, carrying, writes, len(leaves)
 
 
-def test_decode_step_holds_nothing_larger_than_a_layers_cache():
-    assert _decode_step_offenders() == []
+@pytest.mark.parametrize("b,t", [(SLOTS, 1), (1, 4)],
+                         ids=["decode", "prefill"])
+@pytest.mark.parametrize("family", WALKED)
+def test_the_cache_rides_the_scan_and_is_written_by_rows(family, b, t):
+    offences, carrying, writes, leaves = _walk(family, b, t)
+    assert offences == []
+    # One scan a run of layers carries that run's leaves, and each leaf
+    # is written once in its scan's body.
+    runs = len(WALKED[family][0].runs()) if family == "glm_dsa" else 1
+    assert carrying == runs
+    assert writes == leaves
 
 
 def test_the_walk_finds_the_repeat_and_the_upcast(monkeypatch):
     monkeypatch.setattr(llama, "_cached_attention", reference_attention)
-    found = _decode_step_offenders()
+    found = [o for o in _walk("llama", SLOTS, 1)[0] if len(o) == 3]
     # rep x the cache (bf16, then float32) for K and for V.
     assert len(found) >= 4
     assert any(dtype == "float32" for _, _, dtype in found)
     assert any(dtype == "bfloat16" for _, _, dtype in found)
+
+
+# ---------------------------------------------------------------------------
+# Against the form of up to PR 32, to the bit
+# ---------------------------------------------------------------------------
+
+
+def scanned_layers(mixer, ffn, cfg, rope, x, stacked, state=None,
+                   handed=None, **_):
+    """`decoder.layers` as it was: the mixer's state scanned in and out
+    beside the parameters, a layer's slices to the mixer."""
+    def body(carry, scanned):
+        x, handed = carry
+        lp, layer_state = scanned
+        x, layer_state, extras, handed = decoder.block(
+            mixer, ffn, cfg, rope, x, lp, layer_state, handed)
+        return (x, handed), (layer_state, extras)
+
+    (x, handed), (state, extras) = lax.scan(body, (x, handed),
+                                            (stacked, state))
+    return x, state, extras, handed
+
+
+def _write_slices(cache, new, start_pos):
+    """cache [B, S, ...] with new [B, T, ...] at each row's `start_pos`."""
+    return jax.vmap(lambda c, n, s: lax.dynamic_update_slice(
+        c, n.astype(c.dtype), (s,) + (0,) * (c.ndim - 1)))(
+            cache, new, start_pos)
+
+
+def sliced_self_attention(cfg, start_pos, positions):
+    """`llama._cached_self_attention` as it was: carries the layer's
+    (k_cache, v_cache), each [B, S, Hkv, D]."""
+    def mixer(h, lp, rope, state, handed):
+        q, k, v = llama._qkv(cfg, h, lp, rope, positions,
+                             llama.norm_all_heads)
+        k_cache = _write_slices(state[0], k, start_pos)
+        v_cache = _write_slices(state[1], v, start_pos)
+        return (llama._cached_attention(cfg, q, k_cache, v_cache, positions),
+                (k_cache, v_cache), handed)
+
+    return mixer
+
+
+def sliced_glm_mixer(cfg, indexer, start_pos, positions):
+    """`glm_dsa._mixer` as it was: carries the layer's slices (latent,
+    rotary key[, indexer's key]), each [B, S, width]. The indexer and
+    attention, which now read a stack at a layer, are handed the slice
+    as a stack of one."""
+    g = glm_dsa
+    nope, rot = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
+    scale = (nope + rot) ** -0.5
+    index_scale = (cfg.index_n_heads * cfg.index_head_dim) ** -0.5
+
+    def mixer(h, lp, rope, state, selected):
+        cos, sin = rope
+        c_q = rms_norm_reference(
+            jnp.einsum("btd,dr->btr", h, lp["wqa"]), lp["q_norm"],
+            cfg.norm_eps)
+        q = jnp.einsum("btr,rhk->bthk", c_q, lp["wqb"])
+        kva = jnp.einsum("btd,dc->btc", h, lp["wkva"])
+        c_kv = rms_norm_reference(kva[..., :cfg.kv_lora_rank],
+                                  lp["kv_norm"], cfg.norm_eps)
+        latent = _write_slices(state[0], c_kv, start_pos)
+        rope_keys = _write_slices(
+            state[1], g._rotate_pairs(kva[..., cfg.kv_lora_rank:], cos,
+                                      sin), start_pos)
+        q_nope = q[..., :nope].astype(latent.dtype)
+        q_rope = g._rotate_pairs(q[..., nope:], cos, sin).astype(
+            latent.dtype)
+        new_state = (latent, rope_keys)
+        if indexer == "full":
+            qi = g._rotate_head(
+                jnp.einsum("btr,rjd->btjd", c_q, lp["wiq"]), cos, sin, rot)
+            ki = g._rotate_head(layer_norm(
+                jnp.einsum("btd,de->bte", h, lp["wik"]), lp["ik_norm"],
+                lp["ik_bias"], g._INDEX_KEY_EPS), cos, sin, rot)
+            index_keys = _write_slices(state[2], ki, start_pos)
+            qi = qi.astype(index_keys.dtype)
+            w = jnp.einsum("btd,dj->btj", h, lp["wiw"]).astype(
+                jnp.float32) * index_scale
+            new_state += (index_keys,)
+
+        def attend(q_nope, q_rope, pos, *chosen):
+            if indexer == "full":
+                mask = g._select(cfg, g._index_scores(
+                    *chosen, (index_keys[None], 0), pos), pos)
+            else:
+                mask, = chosen
+            q_lat = jnp.einsum("bthk,chk->bthc", q_nope,
+                               lp["wkvb"][..., :nope])
+            out = g._attend(q_lat, q_rope, (latent[None], 0),
+                            (rope_keys[None], 0), mask, pos, scale)
+            out = jnp.einsum("bthc,chv->bthv", out.astype(latent.dtype),
+                             lp["wkvb"][..., nope:])
+            return out, mask
+
+        chosen = (qi, w) if indexer == "full" else (selected,)
+        out, selected = g._by_query_blocks(
+            attend, h.shape[1], q_nope, q_rope, positions, *chosen)
+        return out, new_state, selected
+
+    return mixer
+
+
+SERVED = {
+    # float32, so that a bit is a bit of the arithmetic; the GLM stack
+    # has a run of two `shared` layers above their `full` one, and
+    # contexts past its `index_topk` of 8.
+    "llama": (LlamaConfig.debug(), init_params, init_kv_cache,
+              forward_with_cache, llama, "_cached_self_attention",
+              sliced_self_attention),
+    "glm_dsa": (WALKED["glm_dsa"][0], glm_dsa.init_params,
+                glm_dsa.init_cache, glm_dsa.forward_with_cache, glm_dsa,
+                "_mixer", sliced_glm_mixer),
+}
+
+
+@pytest.mark.parametrize("family", SERVED)
+def test_logits_and_cache_are_those_of_the_scanned_cache_to_the_bit(
+        monkeypatch, family):
+    cfg, init, init_cache, forward, module, name, sliced = SERVED[family]
+    params = init(cfg, jax.random.PRNGKey(1))
+    lens = np.array([13, 11, 9, 12])
+    rows, n_pre, n_dec = len(lens), int(lens.max()), 4
+    tokens = np.random.default_rng(5).integers(
+        0, cfg.vocab_size, (rows, n_pre + n_dec), dtype=np.int32)
+
+    def served():
+        """A prefill of every row from 0, then `n_dec` decode steps with
+        the rows at their own lengths: [(logits, cache)] a call."""
+        step = jax.jit(lambda p, t, c, s: forward(p, t, cfg, c, s))
+        out = [step(params, jnp.asarray(tokens[:, :n_pre]),
+                    init_cache(cfg, rows, 32), jnp.zeros(rows, jnp.int32))]
+        at = np.arange(rows)
+        for i in range(n_dec):
+            out.append(step(
+                params, jnp.asarray(tokens[at, lens + i][:, None]),
+                out[-1][1], jnp.asarray(lens + i, jnp.int32)))
+        return jax.tree.map(np.asarray, out)
+
+    now = served()
+    monkeypatch.setattr(decoder, "layers", scanned_layers)
+    monkeypatch.setattr(module, name, sliced)
+    before = served()
+    assert jax.tree.structure(now) == jax.tree.structure(before)
+    for call, (got, want) in enumerate(zip(now, before)):
+        for x, y in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+            assert x.dtype == y.dtype and np.array_equal(x, y), call
+    # The decode steps wrote: a row's last key is not the prefill's.
+    assert not np.array_equal(jax.tree.leaves(now[-1][1])[0],
+                              jax.tree.leaves(now[0][1])[0])
