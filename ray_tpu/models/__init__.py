@@ -16,6 +16,12 @@ first-class compiled models to schedule.
 - ``glm_dsa`` — GLM-5.2's decoder, served: `decoder` over runs of unlike
   layers with latent attention, the sparse-attention indexer, and
   `moe`'s expert layer with a shared expert and a held share
+- ``mamba2`` — the Mamba-2 state-space mixer through two cache leaves
+  with no sequence axis: the recurrence for a decode step, the chunked
+  scan for a prefill
+- ``nemotron_h`` — Nemotron 3 Super's hybrid decoder, served: blocks of
+  a mixer or an FFN alone, `mamba2`, attention with no positional
+  encoding, and `moe`'s expert layer with relu^2 experts in a latent
 - ``serving`` — which configs have a cached forward pass, for the engine
 - ``mlp``   — small MLP classifier (the fashion-MNIST baseline workload)
 - ``training`` — TrainState + sharded train-step factory

@@ -1,7 +1,8 @@
 """The decoder stack that every architecture under `models/` composes.
 
-A block is norm, *sequence mixer*, residual, norm, *FFN*, residual
-(`block`, the only place that opens the `attn` and `mlp` scopes). What
+A block is norm, *sequence mixer*, residual, norm, *FFN*, residual,
+either half optional (`block`, the only place that opens the mixer's
+scope, `attn` unless the mixer names its kind, and `mlp`). What
 differs between architectures is handed in as two functions:
 
 - ``mixer(h, lp, rope, state, handed) -> (attn [B, S, H, K], state,
@@ -14,7 +15,9 @@ differs between architectures is handed in as two functions:
   (`write_rows`), and it reads its layer back out of them
   (`layer_rows`); it returns the stacks. `handed` is what a layer hands
   up to the layer above beside `x` (a sparse-attention layer's
-  selection), None in a stack that hands nothing on.
+  selection), None in a stack that hands nothing on. A mixer that is
+  no attention carries its kind as the attribute `scope` (`"ssm"`),
+  the name of the scope the block opens around it.
 - ``ffn(h, lp) -> (out [B, S, D], extras)``: `extras` is a pytree the
   layer reports (an expert layer's aux loss and counts), or None.
 
@@ -84,20 +87,27 @@ def init_params_sharded(init, axes, mesh, rng, rules=DEFAULT_RULES):
 
 def block(mixer, ffn, cfg, rope, x, lp, state=None, handed=None, *,
           mesh=None, rules=DEFAULT_RULES):
-    """One transformer block. x: [B, S, D] -> (x, state, extras,
-    handed). The two halves are scoped (`attn`, `mlp`) so that a device
-    trace can tell their ops apart. `state` goes to the mixer as it is
-    and comes back as the mixer returns it; `handed` is what the layer
-    below handed up beside x, None in most stacks."""
-    with jax.named_scope("attn"):
-        h = rms_norm_reference(x, lp["attn_norm"], cfg.norm_eps)
-        attn, state, handed = mixer(h, lp, rope, state, handed)
-        x = x + jnp.einsum("bshk,hkd->bsd", attn.astype(cfg.dtype),
-                           lp["wo"])
-    with jax.named_scope("mlp"):
-        h = rms_norm_reference(x, lp["mlp_norm"], cfg.norm_eps)
-        out, extras = ffn(h, lp)
-        x = x + out
+    """One block. x: [B, S, D] -> (x, state, extras, handed). Either
+    half may be absent (`mixer` or `ffn` None: a stack whose layers are
+    a mixer or an FFN alone); the block is then the other half, norm,
+    part, residual. The two halves are scoped so that a device trace can
+    tell their ops apart: the FFN `mlp`, the mixer by its kind, which is
+    `attn` unless the mixer says otherwise (its attribute `scope`: a
+    state-space mixer is no attention). `state` goes to the mixer as it
+    is and comes back as the mixer returns it; `handed` is what the
+    layer below handed up beside x, None in most stacks."""
+    extras = None
+    if mixer is not None:
+        with jax.named_scope(getattr(mixer, "scope", "attn")):
+            h = rms_norm_reference(x, lp["attn_norm"], cfg.norm_eps)
+            attn, state, handed = mixer(h, lp, rope, state, handed)
+            x = x + jnp.einsum("bshk,hkd->bsd", attn.astype(cfg.dtype),
+                               lp["wo"])
+    if ffn is not None:
+        with jax.named_scope("mlp"):
+            h = rms_norm_reference(x, lp["mlp_norm"], cfg.norm_eps)
+            out, extras = ffn(h, lp)
+            x = x + out
     x = with_logical_constraint(x, "batch", "seq", "act_embed",
                                 mesh=mesh, rules=rules)
     return x, state, extras, handed

@@ -4,7 +4,10 @@ replaced by `n_experts` SwiGLU experts, of which every token uses the
 run through it: Mixtral-8x7B (8 experts, 2 a token, gates renormalised
 over the chosen) and OLMoE-1B-7B (64 experts, 8 a token, gates as the
 softmax gives them, RMSNorm on the projected q and k); `MoEConfig`
-holds what they differ in.
+holds what they differ in, and what the served families add: a
+sigmoid router with a selection bias, a shared expert, a held share of
+the experts, experts that are relu^2 with two matrices, and a latent
+the routed experts work in (`expert_kind`, `latent_dim`).
 
 The expert layer is dropless sparse dispatch (`_moe_ffn`): float32
 softmax over the router's logits, `lax.top_k` (exactly k experts a
@@ -88,6 +91,15 @@ class MoEConfig(LlamaConfig):
     # that fall on absent experts are left out of the layer's output
     # (the chip that holds them adds them). None: all.
     experts_held: Optional[Tuple[int, int]] = None
+    # What an expert, routed or shared, computes: "swiglu",
+    # w2 (silu(w1 x) * w3 x), three matrices; "relu2", w2 relu(w1 x)^2,
+    # two (the leaves `we3` and `ws3` are then absent).
+    expert_kind: str = "swiglu"
+    # Width of the latent the routed experts work in (LatentMoE): the
+    # layer projects a token down once (`w_dn`, shared by the experts)
+    # before dispatch and up again (`w_up`) after the combine; the
+    # router and the shared expert read the full width. 0: none.
+    latent_dim: int = 0
 
     @property
     def n_experts_held(self) -> int:
@@ -122,19 +134,30 @@ def _init_moe_layer(cfg: MoEConfig, key) -> Dict[str, Any]:
             **expert_init(cfg, (k_router, k1, k2, k3))}
 
 
-def expert_init(cfg: MoEConfig, keys) -> Dict[str, Any]:
+def expert_init(cfg: MoEConfig, keys, init=None) -> Dict[str, Any]:
     """The expert layer's leaves from four keys: the router over all
-    `n_experts`, the three matrices of the experts held, and what the
-    config's router and shared expert add."""
+    `n_experts`, the matrices of the experts held (three, or two of
+    `expert_kind` "relu2"; `latent_dim` wide where the config has a
+    latent, with the pair `w_dn`, `w_up` around them), and what the
+    config's router and shared expert add. `init(key, shape, dtype)`
+    draws a matrix; `jax.nn.initializers.normal(0.02)` unless given."""
     k_router, k1, k2, k3 = keys
-    init = jax.nn.initializers.normal(stddev=0.02)
+    init = init or jax.nn.initializers.normal(stddev=0.02)
     e, d, h = cfg.n_experts_held, cfg.dim, cfg.hidden_dim
+    gated = cfg.expert_kind == "swiglu"
+    assert gated or cfg.expert_kind == "relu2", cfg.expert_kind
+    w = cfg.latent_dim or d  # what a routed expert reads and writes
     leaves = {
         "router": init(k_router, (d, cfg.n_experts), cfg.dtype),
-        "we1": init(k1, (e, d, h), cfg.dtype),
-        "we3": init(k2, (e, d, h), cfg.dtype),
-        "we2": init(k3, (e, h, d), cfg.dtype) * (h ** -0.5),
+        "we1": init(k1, (e, w, h), cfg.dtype),
+        "we2": init(k3, (e, h, w), cfg.dtype) * (h ** -0.5),
     }
+    if gated:
+        leaves["we3"] = init(k2, (e, w, h), cfg.dtype)
+    if cfg.latent_dim:
+        kd, ku = jax.random.split(jax.random.fold_in(k_router, 2))
+        leaves.update(w_dn=init(kd, (d, w), cfg.dtype),
+                      w_up=init(ku, (w, d), cfg.dtype))
     if cfg.selection_bias:
         # A buffer the published training balances the load with: small
         # beside a sigmoid score, large enough to change who is chosen.
@@ -144,8 +167,9 @@ def expert_init(cfg: MoEConfig, keys) -> Dict[str, Any]:
         ks = jax.random.split(jax.random.fold_in(k1, 1), 3)
         f = cfg.shared_hidden_dim
         leaves.update(ws1=init(ks[0], (d, f), cfg.dtype),
-                      ws3=init(ks[1], (d, f), cfg.dtype),
                       ws2=init(ks[2], (f, d), cfg.dtype) * (f ** -0.5))
+        if gated:
+            leaves["ws3"] = init(ks[1], (d, f), cfg.dtype)
     return leaves
 
 
@@ -154,8 +178,10 @@ def init_moe_params(cfg: MoEConfig, rng) -> Dict[str, Any]:
                                functools.partial(_init_moe_layer, cfg))
 
 
-# Logical axes of the three expert matrices without the layer axis: how
-# they are sharded where they enter the expert layer's shard_map.
+# Logical axes of the expert matrices without the layer axis: how they
+# are sharded where they enter the expert layer's shard_map. A layer
+# has `we3` only if its experts are gated, and hands the grouped
+# products the matrices it has, in this order.
 _EXPERT_AXES = {
     "we1": ("expert", "embed", "mlp"),
     "we3": ("expert", "embed", "mlp"),
@@ -234,15 +260,30 @@ def _expert_counts(top_i, n_experts):
     return hits.sum(tuple(range(top_i.ndim)), dtype=jnp.int32)
 
 
-def _grouped_swiglu(xs, group_sizes, we1, we3, we2):
-    """Rows in expert order through their experts: [R, D] -> [R, D]."""
+def _relu2(x):
+    """What a "relu2" expert puts between its two matrices."""
+    return jnp.square(jax.nn.relu(x))
+
+
+def _expert_matrices(lp):
+    return [lp[name] for name in _EXPERT_AXES if name in lp]
+
+
+def _grouped_experts(xs, group_sizes, we1, *rest):
+    """Rows in expert order through their experts: [R, D] -> [R, D].
+    `rest` is (we3, we2) of gated SiLU experts, (we2,) of relu^2 ones."""
+    *gate, we2 = rest
     with jax.named_scope("expert_matmul"):
-        hidden = jax.nn.silu(lax.ragged_dot(xs, we1, group_sizes)) \
-            * lax.ragged_dot(xs, we3, group_sizes)         # [R, F]
+        hidden = lax.ragged_dot(xs, we1, group_sizes)      # [R, F]
+        if gate:
+            hidden = jax.nn.silu(hidden) \
+                * lax.ragged_dot(xs, gate[0], group_sizes)
+        else:
+            hidden = _relu2(hidden)
         return lax.ragged_dot(hidden, we2, group_sizes)    # [R, D]
 
 
-def _sparse_experts(x, gates, top_i, we1, we3, we2):
+def _sparse_experts(x, gates, top_i, we1, *rest):
     """The chosen experts of the tokens at hand. x [T, D], gates and
     top_i [T, k], weights [E, ...] -> [T, D]."""
     with jax.named_scope("moe_dispatch"):
@@ -250,7 +291,7 @@ def _sparse_experts(x, gates, top_i, we1, we3, we2):
         inv = jnp.argsort(order)
         group_sizes = _expert_counts(top_i, we1.shape[0])
         xs = _spread(x, order, inv)                        # [T*k, D]
-    ys = _grouped_swiglu(xs, group_sizes, we1, we3, we2)
+    ys = _grouped_experts(xs, group_sizes, we1, *rest)
     with jax.named_scope("moe_combine"):
         return _collect(ys, gates, order, inv)
 
@@ -262,7 +303,7 @@ _HELD_ROWS_SLACK = 2
 _HELD_ROWS_MIN = 256
 
 
-def _held_experts(cfg: MoEConfig, x, gates, top_i, we1, we3, we2):
+def _held_experts(cfg: MoEConfig, x, gates, top_i, *ws):
     """`_sparse_experts` of a share of the experts (`cfg.experts_held`;
     the weights are theirs), at the cost of the pairs that landed on it.
     The pairs are sorted held experts first, and the held ones go
@@ -273,7 +314,7 @@ def _held_experts(cfg: MoEConfig, x, gates, top_i, we1, we3, we2):
     gathered or computed, and each token's rows are added into it (a
     scatter-add over the buffer's rows: forward only, this is a serving
     path). Returns (out [T, D], pairs held int32, buffers beyond the
-    first int32)."""
+    first int32, held experts with at least one pair int32)."""
     first, count = cfg.experts_held
     k = top_i.shape[1]
     pairs = top_i.size
@@ -286,7 +327,8 @@ def _held_experts(cfg: MoEConfig, x, gates, top_i, we1, we3, we2):
         # The held pairs in expert order, then the absent; padded so
         # that a buffer's slice never runs off the end.
         order = jnp.pad(jnp.argsort(local, stable=True), (0, rows))
-        ends = jnp.cumsum(_expert_counts(local, count))
+        held_counts = _expert_counts(local, count)
+        ends = jnp.cumsum(held_counts)
         n_held = ends[-1]
         flat_gates = gates.reshape(-1)
 
@@ -300,7 +342,7 @@ def _held_experts(cfg: MoEConfig, x, gates, top_i, we1, we3, we2):
             group_sizes = jnp.diff(inside, prepend=lo)
             held = jnp.arange(rows) < n_held - lo
             xs = _rows(x, token)                           # [rows, D]
-        ys = _grouped_swiglu(xs, group_sizes, we1, we3, we2)
+        ys = _grouped_experts(xs, group_sizes, *ws)
         with jax.named_scope("moe_combine"):
             # Rows past the held pairs belong to no group: whatever the
             # grouped product left there counts nothing.
@@ -312,7 +354,8 @@ def _held_experts(cfg: MoEConfig, x, gates, top_i, we1, we3, we2):
     out = lax.fori_loop(0, n_buffers, buffer,
                         jnp.zeros(x.shape, jnp.float32))
     return (out.astype(x.dtype), n_held.astype(jnp.int32),
-            jnp.maximum(n_buffers - 1, 0).astype(jnp.int32))
+            jnp.maximum(n_buffers - 1, 0).astype(jnp.int32),
+            (held_counts > 0).sum(dtype=jnp.int32))
 
 
 def _route(cfg: MoEConfig, lp, x):
@@ -349,10 +392,15 @@ def _moe_ffn(cfg: MoEConfig, lp, x, mesh, rules):
     """x: [B, S, D] -> ([B, S, D], aux loss scalar, pairs routed to each
     expert [E] int32, and what the layer computed of them as int32
     scalars: `pairs_held`, the pairs that fell on experts held here
-    (all of them unless `cfg.experts_held`), `pairs_routed`, and
+    (all of them unless `cfg.experts_held`), `pairs_routed`,
     `pair_overflows`, the buffers beyond the first that a held share's
-    pairs took)."""
-    b, s, d = x.shape
+    pairs took, `experts_touched`, the experts held here that at least
+    one pair fell on, whose matrices the grouped products read, and
+    `experts_held_steps`, the experts held here). With `cfg.latent_dim`
+    the routed experts work on x projected down to the latent (scope
+    `latent_down`) and their combined output is projected up again
+    (`latent_up`); the router and the shared expert read x itself."""
+    b, s, _ = x.shape
     k = cfg.n_experts_per_token
     with jax.named_scope("router"):
         probs, gates, top_i = _route(cfg, lp, x)
@@ -362,6 +410,12 @@ def _moe_ffn(cfg: MoEConfig, lp, x, mesh, rules):
         aux = cfg.n_experts * jnp.sum(
             counts / (b * s) * probs.mean(axis=(0, 1)))
 
+    full = x
+    if cfg.latent_dim:
+        with jax.named_scope("latent_down"):
+            x = jnp.einsum("bsd,dl->bsl", x, lp["w_dn"])
+    d = x.shape[-1]
+
     def experts(x, gates, top_i, *ws):
         """On the tokens at hand: the whole batch, or one shard's."""
         t = x.shape[0] * x.shape[1]
@@ -369,11 +423,12 @@ def _moe_ffn(cfg: MoEConfig, lp, x, mesh, rules):
                               top_i.reshape(t, k), *ws)
         return out.reshape(x.shape)
 
-    weights = [lp[name] for name in _EXPERT_AXES]
+    weights = _expert_matrices(lp)
     n_held, over = jnp.int32(b * s * k), jnp.int32(0)
+    touched = (counts > 0).sum(dtype=jnp.int32)
     if cfg.experts_held is not None:
         assert mesh is None, "a held share of the experts runs on one chip"
-        out, n_held, over = _held_experts(
+        out, n_held, over, touched = _held_experts(
             cfg, x.reshape(b * s, d), gates.reshape(b * s, k),
             top_i.reshape(b * s, k), *weights)
         out = out.reshape(x.shape)
@@ -385,7 +440,7 @@ def _moe_ffn(cfg: MoEConfig, lp, x, mesh, rules):
         # and are gathered whole inside, so that their gradients leave
         # through the matching reduce-scatter.
         w_specs = [logical_to_mesh_axes(axes, rules)
-                   for axes in _EXPERT_AXES.values()]
+                   for name, axes in _EXPERT_AXES.items() if name in lp]
         tok = logical_to_mesh_axes(("batch", "seq", None), rules)
         out = jax.shard_map(
             lambda x, gates, top_i, *ws: experts(
@@ -393,21 +448,28 @@ def _moe_ffn(cfg: MoEConfig, lp, x, mesh, rules):
                 *[_gather_whole(w, spec) for w, spec in zip(ws, w_specs)]),
             mesh=mesh, in_specs=(tok, tok, tok, *w_specs), out_specs=tok,
             check_vma=False)(x, gates, top_i, *weights)
-    return _add_shared_expert(cfg, lp, x, out), aux, counts, {
+    if cfg.latent_dim:
+        with jax.named_scope("latent_up"):
+            out = jnp.einsum("bsl,ld->bsd", out, lp["w_up"])
+    return _add_shared_expert(cfg, lp, full, out), aux, counts, {
         "pairs_held": n_held, "pairs_routed": jnp.int32(b * s * k),
-        "pair_overflows": over}
+        "pair_overflows": over, "experts_touched": touched,
+        "experts_held_steps": jnp.int32(cfg.n_experts_held)}
 
 
 def _add_shared_expert(cfg: MoEConfig, lp, x, out):
-    """`out` plus the SwiGLU expert every token passes through, where
-    the config has one."""
+    """`out` plus the expert every token passes through at the full
+    width, of the config's `expert_kind`, where the config has one."""
     if not cfg.shared_hidden_dim:
         return out
     with jax.named_scope("shared_expert"):
-        gate = jax.nn.silu(jnp.einsum("bsd,df->bsf", x, lp["ws1"]))
-        return out + jnp.einsum(
-            "bsf,fd->bsd", gate * jnp.einsum("bsd,df->bsf", x, lp["ws3"]),
-            lp["ws2"])
+        hidden = jnp.einsum("bsd,df->bsf", x, lp["ws1"])
+        if cfg.expert_kind == "swiglu":
+            hidden = jax.nn.silu(hidden) \
+                * jnp.einsum("bsd,df->bsf", x, lp["ws3"])
+        else:
+            hidden = _relu2(hidden)
+        return out + jnp.einsum("bsf,fd->bsd", hidden, lp["ws2"])
 
 
 def _gather_whole(w, spec):

@@ -10,13 +10,26 @@ through the slot cache. The engine knows no model; it takes from here
   dict of int32 scalars the model counted over the call, which a decode
   block sums and hands to the host with its tokens, {} of a model that
   counts nothing;
-- ``init_cache(cfg, n_slots, max_seq) -> cache``: any pytree whose
-  every leaf is [layers_i, slots, max_seq, ...]; the engine slices
-  slots, reads and writes blocks of rows and sizes its prefix cache leaf
-  by leaf. `forward` returns it with the call's [B, T] new rows a layer
-  written in and nothing else moved: the leaves ride the model's layer
-  scan as its carry (`decoder.layers`), so a cache that is donated to
-  the program is updated where it lies;
+- ``init_cache(cfg, n_slots, max_seq) -> cache``: any pytree of
+  leaves of two kinds, each [layers_i, slots, ...]. A *row* leaf is
+  [layers_i, slots, max_seq, ...], one row a position (keys and values,
+  latents): `forward` returns it with the call's [B, T] new rows a
+  layer written in and nothing else moved; the engine slices slots,
+  reads and writes blocks of rows and sizes its prefix cache by these
+  leaves. A *state* leaf has no sequence axis (a recurrent layer's
+  state, a convolution's last rows): `forward` rewrites it whole, a row
+  that starts at position 0 starts from zeros whatever the leaf held,
+  and what is left is the state after position `at` and no later (a
+  prefill's bucket padding must not enter it); the engine only slices
+  its slots. All leaves ride the model's layer scan as its carry
+  (`decoder.layers`), so a cache that is donated to the program is
+  updated where it lies;
+- ``state_leaves(cache) -> the same structure of bools``: True at a
+  state leaf; all rows unless the model says otherwise. A model with a
+  state leaf is served with no prefix cache: the prefix cache holds
+  blocks of rows, and a recurrent layer cannot resume from a block of
+  rows, only from a snapshot of its state at the block's boundary,
+  which nothing takes yet;
 - ``keys_attended(cfg, lengths) -> per row``: of `lengths` cached keys
   (host integers) how many the next token attends: all of them, unless
   the model selects keys.
@@ -32,11 +45,17 @@ def _every_key(cfg, lengths):
     return lengths
 
 
+def _all_rows(cache):
+    import jax
+    return jax.tree.map(lambda _: False, cache)
+
+
 @dataclasses.dataclass(frozen=True)
 class ServedModel:
     forward: Callable
     init_cache: Callable
     keys_attended: Callable = _every_key
+    state_leaves: Callable = _all_rows
 
 
 def _llama():
@@ -56,9 +75,16 @@ def _glm_dsa():
                        glm_dsa.keys_attended)
 
 
+def _nemotron_h():
+    from ray_tpu.models import nemotron_h
+    return ServedModel(nemotron_h.forward, nemotron_h.init_cache,
+                       state_leaves=nemotron_h.state_leaves)
+
+
 # By the config's own type, not its bases: `MoEConfig` is a
 # `LlamaConfig` and has no cached forward pass.
-_SERVED = {"LlamaConfig": _llama, "GlmDsaConfig": _glm_dsa}
+_SERVED = {"LlamaConfig": _llama, "GlmDsaConfig": _glm_dsa,
+           "NemotronHConfig": _nemotron_h}
 
 
 def served_model(cfg) -> ServedModel:

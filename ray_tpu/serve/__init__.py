@@ -79,7 +79,7 @@ class Application:
 def deployment(_func_or_class=None, *, name: Optional[str] = None,
                num_replicas: int = 1, init_args: tuple = (),
                init_kwargs: Optional[dict] = None, user_config: Any = None,
-               max_concurrent_queries: int = 100,
+               max_concurrent_queries: Optional[int] = None,
                ray_actor_options: Optional[dict] = None,
                autoscaling_config: Optional[dict] = None,
                route_prefix: Optional[str] = None,
@@ -87,11 +87,16 @@ def deployment(_func_or_class=None, *, name: Optional[str] = None,
     """`@serve.deployment` (reference `serve/api.py`)."""
 
     def wrap(obj):
+        # A class may say how many queries one replica of it takes at
+        # once (`LLMDeployment` queues in its engine): the default
+        # where the caller names none.
+        cap = max_concurrent_queries if max_concurrent_queries is not None \
+            else getattr(obj, "max_concurrent_queries", 100)
         return Deployment(
             func_or_class=obj, name=name or obj.__name__,
             num_replicas=num_replicas, init_args=init_args,
             init_kwargs=init_kwargs or {}, user_config=user_config,
-            max_concurrent_queries=max_concurrent_queries,
+            max_concurrent_queries=cap,
             ray_actor_options=ray_actor_options,
             autoscaling_config=autoscaling_config,
             route_prefix=route_prefix, version=version)

@@ -13,10 +13,15 @@ shapes — so:
 - The engine knows no model. `models.serving.served_model` names, by the
   config's type, the cached forward pass and the cache's initialiser;
   the cache is whatever pytree that gives, every leaf
-  [layers_i, slots, max_seq, ...] (Llama's K and V; a latent-attention
-  model's latents, rotary keys and indexer keys, stacked by runs of like
-  layers), and everything here that touches it (a slot's slice, the
-  prefix cache's reads, writes and payloads) works leaf by leaf.
+  [layers_i, slots, ...]: row leaves, [layers_i, slots, max_seq, ...]
+  (Llama's K and V; a latent-attention model's latents, rotary keys and
+  indexer keys, stacked by runs of like layers), and state leaves with
+  no sequence axis (a state-space layer's recurrent state), which the
+  model says apart (`ServedModel.state_leaves`). Everything here that
+  touches it works leaf by leaf: a slot's slice on every leaf, the
+  prefix cache's reads, writes and payloads on the row leaves alone. A
+  model with a state leaf is served with no prefix cache
+  (`models/serving.py` says why).
 - Prefill lengths are bucketed: to powers of two up to 4,096 tokens and
   to multiples of 1,024 past that (`prefill_bucket`), so at most
   log2(4096) + max_seq / 1024 prefill programs ever compile and a long
@@ -74,6 +79,7 @@ from ray_tpu._private import perf_stats
 from ray_tpu._private.config import ray_config
 from ray_tpu._private.kv_cache import PrefixCache, chain_keys
 from ray_tpu.models.serving import served_model
+from ray_tpu.serve.streaming import STREAM_WAITING_KEY, WAITING_BEAT_S
 
 
 # A prefill program's cost grows faster than its length (attention), so
@@ -217,11 +223,16 @@ class LLMEngine:
         self._served = served_model(cfg)
         self.cache = self._served.init_cache(cfg, self.n_slots,
                                              self.max_seq)
-        # The cache's leaves as shapes, [layers_i, slots, max_seq, ...]:
-        # what the host needs of them (the arrays themselves are donated
-        # to every program that writes them).
+        # The cache's row leaves as shapes, [layers_i, slots, max_seq,
+        # ...]: what the host needs of them (the arrays themselves are
+        # donated to every program that writes them). `_is_state` says,
+        # in the leaves' order, which of the cache's leaves are not
+        # among them: state, with no sequence axis.
+        self._is_state = jax.tree.leaves(
+            self._served.state_leaves(self.cache))
         self._leaves = [jax.ShapeDtypeStruct(x.shape, x.dtype)
-                        for x in jax.tree.leaves(self.cache)]
+                        for x, state in zip(jax.tree.leaves(self.cache),
+                                            self._is_state) if not state]
         self._rng = jax.random.PRNGKey(seed)
 
         # Per-slot host state.
@@ -330,7 +341,8 @@ class LLMEngine:
         # (evicted payloads fall to the shm-plane warm tier).
         self.block_tokens = max(1, int(ray_config.llm_kv_block_tokens))
         self.prefix_cache: Optional[PrefixCache] = None
-        if ray_config.llm_prefix_cache and self.block_tokens < self.max_seq:
+        if ray_config.llm_prefix_cache and not any(self._is_state) \
+                and self.block_tokens < self.max_seq:
             self.prefix_cache = PrefixCache(
                 ray_config.llm_prefix_cache_bytes, self.block_tokens)
         self._kv_store: Dict[int, tuple] = {}
@@ -583,10 +595,13 @@ class LLMEngine:
 
     def _read_rows_impl(self, cache, slot, start, rows):
         """Read `rows` tokens' KV out of a slot's region from token
-        offset `start` → leaf after leaf of the cache its parts, each
-        [layers_i / p, rows, ...] (`_layer_parts`)."""
+        offset `start` → row leaf after row leaf of the cache its
+        parts, each [layers_i / p, rows, ...] (`_layer_parts`)."""
         out = []
-        for x in jax.tree.leaves(cache):  # [layers_i, slots, S, ...]
+        for x, state in zip(jax.tree.leaves(cache), self._is_state):
+            if state:
+                continue
+            # [layers_i, slots, S, ...]
             tail = (0,) * (x.ndim - 3)
             blk = jax.lax.dynamic_slice(
                 x, (0, slot, start) + tail,
@@ -595,14 +610,16 @@ class LLMEngine:
         return tuple(out)
 
     def _write_block_impl(self, cache, block, slot, start):
-        """Write one KV block (`block`: [layers_i, B, ...] a leaf of
-        the cache, in the leaves' order) into a slot's region at token
-        offset `start`."""
+        """Write one KV block (`block`: [layers_i, B, ...] a row leaf
+        of the cache, in the leaves' order) into a slot's region at
+        token offset `start`."""
         leaves, tree = jax.tree.flatten(cache)
+        block = iter(block)
         return tree.unflatten([
-            jax.lax.dynamic_update_slice(
-                x, blk[:, None], (0, slot, start) + (0,) * (x.ndim - 3))
-            for x, blk in zip(leaves, block)])
+            x if state else jax.lax.dynamic_update_slice(
+                x, next(block)[:, None],
+                (0, slot, start) + (0,) * (x.ndim - 3))
+            for x, state in zip(leaves, self._is_state)])
 
     def _decode_impl(self, params, cache, last_tokens, lengths, temps,
                      topks, rng):
@@ -679,8 +696,14 @@ class LLMEngine:
                  stream: bool = False, *,
                  model: Optional[str] = None,
                  priority: int = 1,
-                 job: str = "default"):
-        """Blocking generate (or an iterator of tokens with stream=True)."""
+                 job: str = "default",
+                 beat_s: Optional[float] = None):
+        """Blocking generate (or an iterator of tokens with stream=True).
+
+        With `beat_s` the iterator yields None where the request has
+        waited that long for its first token and the loop has run
+        decode steps meanwhile: it is queued behind full slots, not
+        hung. A loop that steps for nobody gives no such sign."""
         prompt = list(prompt_ids)
         cap = self.max_seq - 1
         if len(prompt) > cap:
@@ -698,10 +721,18 @@ class LLMEngine:
         self.start()
 
         def token_iter():
+            wait, steps = beat_s, self._totals["decode_steps"]
             while True:
-                item = req.out_queue.get()
+                try:
+                    item = req.out_queue.get(timeout=wait)
+                except queue.Empty:
+                    steps, before = self._totals["decode_steps"], steps
+                    if steps != before:
+                        yield None
+                    continue
                 if item is None:
                     return
+                wait = None  # admitted: every step brings a token
                 yield item
 
         if stream:
@@ -742,7 +773,8 @@ class LLMEngine:
                 # `ServedModel.keys_attended`); and what the model
                 # itself counted in its decode blocks, under its names
                 # (an expert layer's `pairs_held`, `pairs_routed`,
-                # `pair_overflows`).
+                # `pair_overflows`, `experts_touched`,
+                # `experts_held_steps`).
                 "totals": dict(self._totals),
             }
         if self.prefix_cache is not None:
@@ -1356,8 +1388,10 @@ class LLMDeployment:
     """Deployment-ready wrapper: `serve.deployment(LLMDeployment).bind(...)`.
 
     `cfg` is the config of any architecture `models.serving` names a
-    cached forward pass for (`LlamaConfig`, `GlmDsaConfig`): nothing
-    else of the deployment depends on which.
+    cached forward pass for (`LlamaConfig`, `GlmDsaConfig`,
+    `NemotronHConfig`): nothing else of the deployment depends on
+    which, but that a model with a state leaf in its cache has no
+    prefix cache, so the router gets no digests to route by.
     Each replica owns one engine (one KV cache in its chip's HBM) and
     may multiplex N weight variants (``models={name: params_fn}``): the
     compiled programs take params as arguments, so switching models is
@@ -1368,6 +1402,16 @@ class LLMDeployment:
     Serve's router spreads requests over replicas, preferring replicas
     whose prefix cache already holds the request's prompt head.
     """
+
+    # What `serve.deployment` gives a replica's in-flight cap unless the
+    # caller names one. A request beyond the slots waits in the engine's
+    # queue, where priority decides who is admitted and from where its
+    # stream tells the reader that it waits (`__call__`); held back at the
+    # router by the generic 100 it waits for a replica instead and is
+    # shed with a 503 after the proxy's 15 s, though the engine would
+    # have served it (128 closed-loop clients on 64 slots lost 28
+    # requests that way; PERF.md section 6, PR 34).
+    max_concurrent_queries = 1024
 
     def __init__(self, cfg, params_fn: Callable[[], Any] = None,
                  max_batch_size: int = 8,
@@ -1488,13 +1532,22 @@ class LLMDeployment:
             self._ensure_model(model, job)  # raylint: disable=R2 -- the blocking drain IS the design: the swap lock must span drain+swap+enqueue or a concurrent request could swap weights between our model check and our admission; the engine drains independently of this lock, so the wait always terminates
             it = self.engine.generate(
                 request["prompt_ids"], params, stream=True,
-                model=model, priority=priority, job=job)
+                model=model, priority=priority, job=job,
+                beat_s=WAITING_BEAT_S if request.get("stream") else None)
         if request.get("stream"):
             # Generator return → the replica streams it chunk-by-chunk
-            # (tokens reach the client during decode, not after).
+            # (tokens reach the client during decode, not after). A
+            # request the engine holds in its queue says so to the
+            # stream's reader, which would else end it as hung after
+            # its 60 s without a chunk (`serve/streaming.py`).
             def token_stream():
-                for i, token in enumerate(it):
+                i = 0
+                for token in it:
+                    if token is None:
+                        yield {STREAM_WAITING_KEY: True}
+                        continue
                     yield {"token": int(token), "index": i}
+                    i += 1
             return token_stream()
         tokens = []
         ttft_s = None

@@ -16,10 +16,23 @@ from typing import Any, Iterator
 
 STREAM_KEY = "__ray_tpu_stream__"
 STREAM_END_KEY = "__ray_tpu_stream_end__"
+# A chunk a deployment's generator may yield while it has nothing to
+# send yet and is not hung (`LLMDeployment`: the request waits for a
+# slot and the engine is decoding for others). The readers below start
+# their wait for a chunk anew and pass nothing on: their timeout stays
+# the contract for a deployment that has gone silent.
+STREAM_WAITING_KEY = "__ray_tpu_stream_waiting__"
+# How long such a generator lets pass between two of them: a third of
+# the readers' default timeout.
+WAITING_BEAT_S = 20.0
 
 
 def is_stream(result: Any) -> bool:
     return isinstance(result, dict) and STREAM_KEY in result
+
+
+def _is_waiting(item: Any) -> bool:
+    return isinstance(item, dict) and STREAM_WAITING_KEY in item
 
 
 def iter_stream(result: Any, timeout: float = 60.0) -> Iterator[Any]:
@@ -35,6 +48,8 @@ def iter_stream(result: Any, timeout: float = 60.0) -> Iterator[Any]:
     try:
         while True:
             item = queue.get(timeout=timeout)
+            if _is_waiting(item):
+                continue
             if isinstance(item, dict) and item.get(STREAM_END_KEY):
                 error = item.get("error")
                 if error:
@@ -65,6 +80,8 @@ async def aiter_stream(result: Any, timeout: float = 60.0):
             if not ok:
                 raise TimeoutError(
                     f"no stream chunk within {timeout}s")
+            if _is_waiting(item):
+                continue
             if isinstance(item, dict) and item.get(STREAM_END_KEY):
                 error = item.get("error")
                 if error:

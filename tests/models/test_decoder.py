@@ -73,7 +73,7 @@ def test_every_entry_point_runs_the_one_block(monkeypatch, entry):
 @pytest.mark.parametrize("scope", ["attn", "mlp"])
 def test_only_the_block_opens_the_two_layer_scopes(scope):
     opened = {
-        path.name: len(re.findall(rf'named_scope\("{scope}"\)',
+        path.name: len(re.findall(rf'named_scope\([^\n]*"{scope}"\)',
                                   path.read_text()))
         for path in pathlib.Path(decoder.__file__).parent.glob("*.py")}
     assert {name: n for name, n in opened.items() if n} == {"decoder.py": 1}

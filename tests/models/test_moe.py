@@ -92,7 +92,8 @@ def test_moe_topk_gating_selects_k_experts():
     np.testing.assert_array_equal(counts, [8, 8, 0, 0])
     # Every expert is held: every pair routed is computed here.
     assert {k: int(v) for k, v in share.items()} == {
-        "pairs_held": 16, "pairs_routed": 16, "pair_overflows": 0}
+        "pairs_held": 16, "pairs_routed": 16, "pair_overflows": 0,
+        "experts_touched": 2, "experts_held_steps": 4}
 
     def experts(weights):
         """The weighted sum of whole experts, one token at a time."""

@@ -1,5 +1,6 @@
-"""The expert layer compiled for a described v5e at the two benchmark
-configurations' real shapes, without the chip: the TPU's compiler
+"""The expert layer compiled for a described v5e at the training
+configurations' real shapes, and the served shares' decode and prefill
+programs at theirs, without the chip: the TPU's compiler
 refuses here what it would refuse there (a grouped matmul it cannot
 tile, a program it cannot fit). Nothing runs and no time
 is read. The topology is described inside a fixture, never at import
@@ -60,17 +61,27 @@ def test_expert_layer_compiles_for_the_v5e(one_chip, tokens, k, d, f, e):
     assert not re.search(r"\[\d+,\d+\]\S* scatter\(", text)
 
 
-@pytest.mark.parametrize("program", ["decode", "prefill"])
-def test_glm_served_step_compiles_for_the_v5e(one_chip, program):
-    """GLM-5.2's share as `benchmark/configs/glm-5.2-serve.json` cuts
-    it, through the engine's own decode and prefill programs at the
-    cell's 16 slots x 16,384: it compiles, the held experts run through
-    the grouped kernel, and the program fits beside nothing else."""
+@pytest.mark.parametrize("name,program,bucket,resident,products", [
+    ("glm-5.2-serve", "decode", 0, 9.3e9, 3),
+    ("glm-5.2-serve", "prefill", 8192, 9.3e9, 3),
+    ("nemotron-3-super-serve", "decode", 0, 10.9e9, 2),
+    ("nemotron-3-super-serve", "prefill", 2048, 10.9e9, 2),
+])
+def test_served_step_compiles_for_the_v5e(one_chip, name, program, bucket,
+                                          resident, products):
+    """A served share as its file under `benchmark/configs/` cuts it,
+    through the engine's own decode and prefill programs at its cell's
+    slots and the cell's largest bucket: it compiles, the held experts
+    run through the grouped kernel, and the program fits beside nothing
+    else. GLM-5.2 at 16 slots x 16,384; Nemotron 3 Super at 64 x 4,096,
+    whose Mamba-2 state rides the same carry as leaves with no sequence
+    axis. `products`: the grouped products an expert layer has, three of
+    a gated SwiGLU, two of relu^2."""
     from benchmark.harness.manifest import ROOT, load_json, model_adapter
     from ray_tpu.models.serving import served_model
     from ray_tpu.serve.llm import LLMEngine
 
-    config = load_json(ROOT, "benchmark", "configs", "glm-5.2-serve.json")
+    config = load_json(ROOT, "benchmark", "configs", name + ".json")
     model = model_adapter(config)
     cfg = model.program_config(config)
     plan = config["serve"]
@@ -85,10 +96,10 @@ def test_glm_served_step_compiles_for_the_v5e(one_chip, program):
 
     params = on_chip(jax.eval_shape(
         lambda: model.init(cfg, jax.random.PRNGKey(0))))
-    cache = on_chip(jax.eval_shape(
-        lambda: model.init_cache(cfg, n, plan["max_seq_len"])))
     engine = LLMEngine.__new__(LLMEngine)  # its programs, no device
     engine.cfg, engine._served = cfg, served_model(cfg)
+    cache = on_chip(jax.eval_shape(
+        lambda: engine._served.init_cache(cfg, n, plan["max_seq_len"])))
     engine.max_seq, engine.decode_steps, engine.n_slots = \
         plan["max_seq_len"], 1, n
     engine._count_names = ("pair_overflows", "pairs_held", "pairs_routed")
@@ -99,12 +110,12 @@ def test_glm_served_step_compiles_for_the_v5e(one_chip, program):
     else:
         compiled = jax.jit(engine._prefill_impl, donate_argnums=(1,),
                            static_argnums=(6,)).lower(
-            params, cache, ints(1, 8192), ints(), ints(), ints(),
-            8192).compile()
+            params, cache, ints(1, bucket), ints(), ints(), ints(),
+            bucket).compile()
     kernels = re.findall(r"%([\w-]+?)(?:\.\d+)? = \S+ custom-call\(",
                          compiled.as_text())
-    assert sum(k in PRODUCTS for k in kernels) >= 3
+    assert sum(k in PRODUCTS for k in kernels) >= products
     memory = compiled.memory_analysis()
-    assert memory.argument_size_in_bytes > 9.3e9  # weights and cache
+    assert memory.argument_size_in_bytes > resident  # weights and cache
     assert memory.argument_size_in_bytes + memory.temp_size_in_bytes \
         < 16.0e9

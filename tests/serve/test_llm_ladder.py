@@ -53,3 +53,112 @@ def test_a_stopped_deployment_stops_its_engine():
     assert thread.is_alive()
     dep.__del__()
     assert not thread.is_alive()
+
+
+def test_a_deployment_of_a_model_with_a_state_leaf_has_no_prefix_cache():
+    """Nemotron-H's toy through `LLMDeployment`: the same wrapper and
+    engine as any other model, but no prefix cache is built for a cache
+    with state leaves, so the router gets no digests, and no read-back
+    program is compiled by the warm-up."""
+    from ray_tpu.models import nemotron_h
+
+    cfg = nemotron_h.NemotronHConfig.debug_nemotron()
+    params = nemotron_h.init_params(cfg, jax.random.PRNGKey(0))
+    dep = LLMDeployment(cfg, lambda: params, max_batch_size=2,
+                        max_seq_len=32, warmup_max_prompt_len=8)
+    try:
+        assert dep.engine.prefix_cache is None
+        assert dep.prefix_digests() is None
+        ladder = llm.bucket_ladder(8, 32)
+        assert sorted(dep.engine._prefill_exec) == ladder
+        # The ladder, decode and the sampler: no read-back's gather.
+        assert dep.stats()["compiled_programs"] == len(ladder) + 2
+        first = dep({"prompt_ids": [5, 6, 7, 8, 9], "max_tokens": 4})
+        again = dep({"prompt_ids": [5, 6, 7, 8, 9], "max_tokens": 4})
+        assert first["tokens"] == again["tokens"] and len(first["tokens"]) == 4
+        stats = dep.stats()
+        assert "kv_cache" not in stats
+        assert stats["totals"]["experts_held_steps"] > 0
+    finally:
+        dep.__del__()
+
+
+def test_a_llama_engine_still_builds_its_prefix_cache():
+    cfg = LlamaConfig.debug()
+    engine = llm.LLMEngine(cfg, init_params(cfg, jax.random.PRNGKey(0)),
+                           max_batch_size=2, max_seq_len=64)
+    assert engine.prefix_cache is not None
+    assert engine._is_state == [False, False]
+    assert len(engine._leaves) == 2
+
+
+def test_an_llm_deployment_names_its_own_in_flight_cap():
+    """Requests beyond the slots queue in the engine, by priority: the
+    generic cap of 100 queries a replica would hold them at the router
+    instead, where the proxy sheds them after its queue timeout."""
+    from ray_tpu import serve
+
+    assert serve.deployment(LLMDeployment).max_concurrent_queries == 1024
+    assert serve.deployment(
+        LLMDeployment, max_concurrent_queries=7).max_concurrent_queries == 7
+
+    @serve.deployment
+    class Plain:
+        pass
+
+    assert Plain.max_concurrent_queries == 100
+
+
+def test_a_queued_stream_beats_while_the_engine_decodes_for_others(
+        monkeypatch):
+    """One slot, two streamed requests through the deployment: the
+    second waits in the engine's queue for the first to end, and its
+    stream says so (`STREAM_WAITING_KEY` chunks, which a stream's
+    reader swallows and starts its timeout anew for) until its own
+    tokens come, the same tokens as without the wait."""
+    from ray_tpu.serve.streaming import STREAM_WAITING_KEY
+
+    monkeypatch.setattr(llm, "WAITING_BEAT_S", 0.005)
+    cfg = LlamaConfig.debug()
+    params = init_params(cfg, jax.random.PRNGKey(0))
+    dep = LLMDeployment(cfg, lambda: params, max_batch_size=1,
+                        max_seq_len=256, warmup_max_prompt_len=8)
+    try:
+        alone = [c["token"] for c in dep(
+            {"prompt_ids": [7, 8, 9], "max_tokens": 4, "stream": True})]
+        first = dep({"prompt_ids": [5, 6], "max_tokens": 200,
+                     "stream": True})
+        assert next(first)["index"] == 0  # it holds the slot
+        second = list(dep({"prompt_ids": [7, 8, 9], "max_tokens": 4,
+                           "stream": True}))
+        assert len(list(first)) == 199
+        beats = [c for c in second if STREAM_WAITING_KEY in c]
+        tokens = [c for c in second if STREAM_WAITING_KEY not in c]
+        assert beats and second[:len(beats)] == beats
+        assert [c["token"] for c in tokens] == alone
+        assert [c["index"] for c in tokens] == [0, 1, 2, 3]
+    finally:
+        dep.__del__()
+
+
+def test_a_queued_stream_of_a_loop_that_does_not_step_gives_no_beat():
+    """The beat means the engine works for others. A request in the
+    queue of a loop that runs no decode step is silent, and the
+    reader's timeout ends it as before."""
+    import threading
+
+    cfg = LlamaConfig.debug()
+    engine = llm.LLMEngine(cfg, init_params(cfg, jax.random.PRNGKey(0)),
+                           max_batch_size=1, max_seq_len=32)
+    engine.start = lambda: None  # the loop never runs
+    stream = engine.generate([5, 6], llm.SamplingParams(max_tokens=2),
+                             stream=True, beat_s=0.005)
+    seen = []
+    reader = threading.Thread(target=lambda: seen.extend(stream),
+                              daemon=True)
+    reader.start()
+    reader.join(timeout=0.2)
+    assert reader.is_alive() and seen == []
+    engine._queue.get_nowait().out_queue.put(None)  # let the reader go
+    reader.join(timeout=5)
+    assert not reader.is_alive() and seen == []

@@ -122,6 +122,44 @@ def test_aiter_stream_async_consumer(serve_up):
     assert [c["i"] for c in chunks] == [0, 1, 2, 3, 4]
 
 
+@pytest.mark.parametrize("reader", ["iter_stream", "aiter_stream"])
+def test_waiting_chunks_restart_the_readers_timeout(serve_up, reader):
+    """A generator that has nothing to send yet but says it is alive
+    (`STREAM_WAITING_KEY`) outlasts the reader's timeout, and the reader
+    passes none of those chunks on; one that goes silent for as long is
+    ended by it."""
+    import asyncio
+
+    from ray_tpu.serve.streaming import STREAM_WAITING_KEY
+    from ray_tpu.util.queue import Empty
+
+    @serve.deployment
+    class Slow:
+        def __call__(self, request):
+            def gen():
+                for _ in range(4):
+                    time.sleep(0.5)
+                    if request["alive"]:
+                        yield {STREAM_WAITING_KEY: True}
+                yield {"i": 0}
+            return gen()
+
+    handle = serve.run(Slow.bind(), route_prefix="/wait-" + reader)
+
+    def read(alive):
+        result = ray_tpu.get(handle.remote({"alive": alive}), timeout=60)
+        if reader == "iter_stream":
+            return list(serve.iter_stream(result, timeout=1.0))
+
+        async def consume():
+            return [c async for c in serve.aiter_stream(result, 1.0)]
+        return asyncio.run(consume())
+
+    assert read(True) == [{"i": 0}]
+    with pytest.raises((Empty, TimeoutError)):
+        read(False)
+
+
 def test_http_sse_streams_incrementally(serve_up):
     """Chunks arrive over HTTP while the generator is still producing —
     the first data line lands well before the slow tail finishes."""

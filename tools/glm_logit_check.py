@@ -1,7 +1,13 @@
-"""GLM-5.2's served path against the plain reference, with the faults
-that must not pass:
-`python tools/glm_logit_check.py [--weights benchmark|plain] [--hit]
-[seed ...]`.
+"""A served model's path through the cache against its plain reference,
+with the faults that must not pass:
+`python tools/glm_logit_check.py [--config NAME] [--weights
+benchmark|plain] [--hit] [seed ...]`. NAME is a configuration under
+`benchmark/configs/`, `glm-5.2-serve` unless given; the file keeps the
+name it had when GLM-5.2 was the one model it knew, which
+`glm-5.2-serve.json` cites. `FAMILIES` has, by the configuration's
+family, its faults, the faults a set of weights cannot show and the
+program's own initialiser; below, GLM-5.2's at length, then
+`nemotron_faults` for `nemotron-3-super-serve`.
 
 Outside the benchmark and its timed window (PERF.md, PR 32, has the
 readings). For each seed, what `benchmark/runners/serve.py`'s
@@ -151,8 +157,8 @@ def faults(forward, init_cache):
         held = moe._held_experts
 
         def none(*args):
-            out, n_held, over = held(*args)
-            return jnp.zeros_like(out), n_held, over
+            out, *counted = held(*args)
+            return jnp.zeros_like(out), *counted
 
         moe._held_experts = none
         try:
@@ -175,6 +181,87 @@ def faults(forward, init_cache):
     }
 
 
+def nemotron_faults(forward, init_cache):
+    """{name: served} for the family `nemotron_h`, as `faults` for
+    GLM-5.2: the weights cut to float8 e4m3's mantissa and the
+    recurrent state rounded to bfloat16's after every call (the two
+    lower precisions), a term of the Mamba-2 layer left out (D, dt's
+    bias, the convolution's bias), the group norm before the gate, relu
+    in place of relu^2, the gate scale left out, the bias weighing in
+    the gates, the latent's down-projection replaced by a cut of the
+    first `latent_dim` channels, and a prefill whose bucket padding
+    enters the state."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.models import mamba2, moe, nemotron_h
+
+    glm = faults(forward, init_cache)
+
+    def with_leaf(name, change):
+        def served(params, tokens, cfg, cache, start_pos):
+            runs = [{**run, name: change(run[name])} if name in run else run
+                    for run in params["runs"]]
+            return forward({**params, "runs": runs}, tokens, cfg, cache,
+                           start_pos)
+        return served
+
+    def patched(module, name, other):
+        def served(params, tokens, cfg, cache, start_pos):
+            real = getattr(module, name)
+            setattr(module, name, other(real))
+            try:
+                return forward(params, tokens, cfg, cache, start_pos)
+            finally:
+                setattr(module, name, real)
+        return served
+
+    def norm_first(real):
+        def gated_norm(cfg, y, z, weight):
+            b, t, h, p = y.shape
+            groups = y.astype(jnp.float32).reshape(b, t, cfg.ssm_groups, -1)
+            groups = groups * jax.lax.rsqrt(
+                jnp.mean(groups * groups, -1, keepdims=True) + cfg.norm_eps)
+            return groups.reshape(b, t, h * p) * weight.astype(jnp.float32) \
+                * jax.nn.silu(z.astype(jnp.float32)).reshape(b, t, h * p)
+        return gated_norm
+
+    def state_in_bfloat16(params, tokens, cfg, cache, start_pos):
+        logits, cache = forward(params, tokens, cfg, cache, start_pos)
+        state = nemotron_h.state_leaves(cache)
+        return logits, jax.tree.map(
+            lambda x, is_state: cut(x, 16)
+            if is_state and x.dtype == jnp.float32 else x, cache, state)
+
+    def to_the_end(real):
+        """The state left is that after the call's last token, padding
+        and all."""
+        def forward_with_cache(params, tokens, cfg, cache, start_pos,
+                               at=None):
+            return real(params, tokens, cfg, cache, start_pos)
+        return forward_with_cache
+
+    def cut_to_the_latent(w_dn):
+        eye = jnp.eye(w_dn.shape[-2], w_dn.shape[-1], dtype=w_dn.dtype)
+        return jnp.broadcast_to(eye, w_dn.shape)
+
+    return {
+        "lower precision": glm["lower precision"],
+        "state in bfloat16": state_in_bfloat16,
+        "no D term": with_leaf("D", jnp.zeros_like),
+        "no dt bias": with_leaf("dt_bias", jnp.zeros_like),
+        "no conv bias": with_leaf("conv_b", jnp.zeros_like),
+        "norm before the gate": patched(mamba2, "_gated_norm", norm_first),
+        "relu for relu2": patched(moe, "_relu2",
+                                  lambda real: jax.nn.relu),
+        "no gate scale": glm["no gate scale"],
+        "bias in the gates": glm["bias in the gates"],
+        "no latent projection": with_leaf("w_dn", cut_to_the_latent),
+        "pad absorbed": patched(nemotron_h, "forward_with_cache",
+                                to_the_end),
+    }
+
+
 # The faults a set of weights cannot show on the chip (each is seen at
 # the other; PERF.md section 6, PR 32, has the readings). The
 # benchmark's weights make the routed experts 32 times quieter, so what
@@ -183,6 +270,36 @@ def faults(forward, init_cache):
 # near-tie, and a `shared` layer that selects anew moves fewer than that.
 UNSEEN = {"benchmark": ("bias in the gates", "no gate scale"),
           "plain": ("shared selects anew",)}
+# The same for `nemotron_h` (PERF.md section 6, PR 34). The benchmark's
+# weights make the routed experts 32 times quieter, as GLM-5.2's do.
+# The state rounded to bfloat16 after every call reads the program's
+# own digits on the chip at either set, at the check's eight decode
+# steps and at 512 (0.79-0.83 % beside the program's 0.79-0.83 %); the
+# float32 test on the CPU holds it (`tests/models/test_nemotron_h.py`).
+NEMOTRON_UNSEEN = {
+    "benchmark": ("state in bfloat16", "no gate scale",
+                  "bias in the gates", "no latent projection"),
+    "plain": ("state in bfloat16",)}
+
+
+def _glm_init():
+    from ray_tpu.models.glm_dsa import init_params
+    return init_params
+
+
+def _nemotron_init():
+    from ray_tpu.models.nemotron_h import init_params
+    return init_params
+
+
+# By a configuration's family: its faults, the faults a set of weights
+# cannot show, the program's own initialiser (the plain weights), and
+# the prompt lengths of a rehearsal at debug widths.
+FAMILIES = {
+    "glm_dsa": (faults, UNSEEN, _glm_init, [40, 33, 26, 19]),
+    "nemotron_h": (nemotron_faults, NEMOTRON_UNSEEN, _nemotron_init,
+                   [45, 39, 26, 19]),
+}
 
 
 def within(row, limits):
@@ -332,29 +449,36 @@ def main(argv):
     import jax
 
     from benchmark.harness.manifest import load_json, model_adapter, plugin
-    from ray_tpu.models.glm_dsa import init_params
 
-    weights = "benchmark"
-    if "--weights" in argv:
-        weights = argv.pop(argv.index("--weights") + 1)
-        argv.remove("--weights")
+    options = {"--weights": "benchmark", "--config": "glm-5.2-serve"}
+    for name in options:
+        if name in argv:
+            options[name] = argv.pop(argv.index(name) + 1)
+            argv.remove(name)
+    weights = options["--weights"]
     rehearse, hit = "--rehearse" in argv, "--hit" in argv
     argv = [a for a in argv if a not in ("--rehearse", "--hit")]
     seeds = [int(a) for a in argv] or [2147483747]
-    config = load_json(ROOT, "benchmark", "configs", "glm-5.2-serve.json")
+    config = load_json(ROOT, "benchmark", "configs",
+                       options["--config"] + ".json")
     model = model_adapter(config)
+    family_faults, unseen, plain_init, rehearsal_lens = \
+        FAMILIES[config["family"]]
+    if hit and config["family"] != "glm_dsa":
+        raise SystemExit("--hit needs a prefix cache: a model with a "
+                         "state leaf in its cache is served with none")
     if rehearse:
         config = model.debug(config)
-        config["serve"].update(reference_prompt_lens=[40, 33, 26, 19],
+        config["serve"].update(reference_prompt_lens=rehearsal_lens,
                                max_seq_len=64)
     elif jax.default_backend() != "tpu":
         raise SystemExit(f"needs a TPU, found {jax.default_backend()}")
     reference = plugin("references", config["reference"])
     plan = config["serve"]
     tolerance = plan["logit_tolerance"]
-    init = {"benchmark": model.init, "plain": init_params}[weights]
+    init = {"benchmark": model.init, "plain": plain_init()}[weights]
     served = {"program": model.cached_forward,
-              **faults(model.cached_forward, model.init_cache)}
+              **family_faults(model.cached_forward, model.init_cache)}
     # The benchmark's weights are held as the runner holds them, by the
     # largest error, and by the tool's own limits; the plain weights by
     # the tool's limits alone.
@@ -371,7 +495,7 @@ def main(argv):
               flush=True)
         ok = ok and within(row.pop("program"), limits) and not any(
             within(v, limits) for k, v in row.items()
-            if k not in UNSEEN[weights])
+            if k not in unseen[weights])
         if hit and i == 0:
             n_prompt = int(lens.max()) - 12 if rehearse else 2100
             by_length = hit_against_miss(config, small, params, tokens,
