@@ -19,7 +19,10 @@ differs between architectures is handed in as two functions:
   no attention carries its kind as the attribute `scope` (`"ssm"`),
   the name of the scope the block opens around it.
 - ``ffn(h, lp) -> (out [B, S, D], extras)``: `extras` is a pytree the
-  layer reports (an expert layer's aux loss and counts), or None.
+  layer reports (an expert layer's aux loss and counts), or None. An
+  FFN that reads some of its parameters in place names them in its
+  attribute `whole`: `layers` keeps those leaves of the run's stack out
+  of the scan and calls ``ffn(h, lp, stacks=(leaves, layer))``.
 
 Around it: the parameter skeleton, the stack (`hidden`: one run of
 like layers; `hidden_runs`: several, each with its own mixer, FFN,
@@ -31,6 +34,7 @@ is any config with `LlamaConfig`'s fields.
 
 from __future__ import annotations
 
+import functools
 from typing import Any, Dict, Optional, Sequence
 
 import jax
@@ -130,14 +134,34 @@ def layers(mixer, ffn, cfg, rope, x, stacked, state=None, handed=None, *,
     `layer_rows`, and returns the stacks. With no state it is handed
     None and the scan carries x and `handed` alone.
 
+    Parameters ride whole beside the state where the FFN asks for them:
+    the leaves of `stacked` it names in its attribute `whole` are no
+    scanned input either, and the FFN is called as `ffn(h, lp,
+    stacks=(those leaves, layer))` with `lp` holding the rest. A scanned
+    leaf reaches the body as a slice of its stack, which fuses into a
+    matmul that reads it and is a copy of the slice, whole, ahead of a
+    kernel that takes no fused operand (the grouped products of a held
+    share of the experts, `moe.served_ffn`: the copy was a third of a
+    decode step). Forward only: the gradient of a stack handed whole
+    would be the size of the stack at every layer, so a trained FFN
+    names nothing and keeps its slices.
+
     `save` is the remat policy: None keeps every activation; a list
     rematerialises each layer in the backward pass but for the
     `checkpoint_name`s in it (an empty list saves nothing)."""
+    whole = {name: stacked[name] for name in getattr(ffn, "whole", ())
+             if name in stacked}
+    if whole:
+        stacked = {name: leaf for name, leaf in stacked.items()
+                   if name not in whole}
+
     def body(carry, scanned):
         x, handed, state = carry
         lp, layer = scanned
         x, state, extras, handed = block(
-            mixer, ffn, cfg, rope, x, lp,
+            mixer,
+            functools.partial(ffn, stacks=(whole, layer)) if whole else ffn,
+            cfg, rope, x, lp,
             None if state is None else (state, layer), handed, mesh=mesh,
             rules=rules)
         return (x, handed, state), extras
@@ -146,8 +170,8 @@ def layers(mixer, ffn, cfg, rope, x, stacked, state=None, handed=None, *,
         body = jax.checkpoint(
             body,
             policy=jax.checkpoint_policies.save_only_these_names(*save))
-    index = None if state is None else jnp.arange(
-        jax.tree.leaves(state)[0].shape[0])
+    index = None if state is None and not whole else jnp.arange(
+        jax.tree.leaves(stacked)[0].shape[0])
     (x, handed, state), extras = lax.scan(body, (x, handed, state),
                                           (stacked, index))
     return x, state, extras, handed

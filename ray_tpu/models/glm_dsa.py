@@ -58,7 +58,6 @@ from jax import lax
 from ray_tpu.models import decoder, moe
 from ray_tpu.models.llama import swiglu
 from ray_tpu.ops.norms import layer_norm, rms_norm_reference
-from ray_tpu.parallel.sharding import DEFAULT_RULES
 
 # Queries and keys go through attention in blocks of at most this many.
 _QUERY_BLOCK = 256
@@ -440,14 +439,7 @@ def _mixer(cfg: GlmDsaConfig, indexer, start_pos, positions):
 # ---------------------------------------------------------------------------
 
 def _ffn(cfg: GlmDsaConfig, ffn_kind):
-    if ffn_kind == "dense":
-        return swiglu()
-
-    def ffn(h, lp):
-        out, _, _, share = moe._moe_ffn(cfg, lp, h, None, DEFAULT_RULES)
-        return out, share
-
-    return ffn
+    return swiglu() if ffn_kind == "dense" else moe.served_ffn(cfg)
 
 
 def _logits(params, x, cfg):

@@ -25,6 +25,20 @@ Both permutations are row gathers in the forward and in the backward
 pass (`_spread` and `_collect` are each other's transpose), never a
 scatter.
 
+A served model that holds a share of a layer's experts
+(`cfg.experts_held`) goes another way from the router on
+(`_held_experts`, forward only): only the pairs that fell on the share
+are gathered and computed, a buffer of rows at a time, and the expert
+matrices are read where they lie in the run's stack. `served_ffn` names
+them as the leaves `decoder.layers` keeps whole beside the scan, and
+the grouped products take the stack as layers x experts groups of
+which all but the layer's are empty: a layer's matrices sliced out of
+the stack by the scan are a copy of every held expert's weights ahead
+of a kernel that reads a few of them (a third to two fifths of a decode
+step, PERF.md, PR 35). The trained layer keeps its scanned slice: a
+stack handed whole would make every layer's weight gradient the size of
+the stack.
+
 On a mesh tokens stay on the chip that holds them: dispatch, the
 grouped matmuls and the combine run under `shard_map` over the batch
 and sequence axes, each shard on its own tokens with every expert's
@@ -303,9 +317,19 @@ _HELD_ROWS_SLACK = 2
 _HELD_ROWS_MIN = 256
 
 
-def _held_experts(cfg: MoEConfig, x, gates, top_i, *ws):
-    """`_sparse_experts` of a share of the experts (`cfg.experts_held`;
-    the weights are theirs), at the cost of the pairs that landed on it.
+def _held_experts(cfg: MoEConfig, x, gates, top_i, layer, *stacks):
+    """`_sparse_experts` of a share of the experts (`cfg.experts_held`),
+    at the cost of the pairs that landed on it. The weights are read
+    where they lie: `stacks` are the expert matrices of a run of layers,
+    each [layers, experts held, ...], and `layer` (an int32 scalar, as
+    `decoder.layers` counts) says whose turn it is. The grouped products
+    take a stack with its two leading axes read as one, layers x
+    experts groups, all of them empty but this layer's: an empty group
+    takes no rows and its matrix is not read, so the products cost what
+    the layer's own would, and nothing slices the layer out of the
+    stack first (that slice was a copy of every held expert's weights,
+    a layer, ahead of a kernel that takes no fused operand).
+
     The pairs are sorted held experts first, and the held ones go
     through the grouped products a buffer of `rows` rows at a time
     (static: `_HELD_ROWS_SLACK` times the uniform share of the T * k
@@ -321,6 +345,8 @@ def _held_experts(cfg: MoEConfig, x, gates, top_i, *ws):
     rows = min(pairs, max(
         _HELD_ROWS_MIN,
         -(-_HELD_ROWS_SLACK * pairs * count // cfg.n_experts)))
+    n_groups = stacks[0].shape[0] * count
+    ws = [w.reshape((n_groups,) + w.shape[2:]) for w in stacks]
     with jax.named_scope("moe_dispatch"):
         local = top_i.reshape(-1) - first
         local = jnp.where((local >= 0) & (local < count), local, count)
@@ -337,9 +363,12 @@ def _held_experts(cfg: MoEConfig, x, gates, top_i, *ws):
         with jax.named_scope("moe_dispatch"):
             pair = lax.dynamic_slice_in_dim(order, lo, rows)
             token = pair // k
-            # Each expert's rows inside [lo, lo + rows).
+            # Each expert's rows inside [lo, lo + rows), at this
+            # layer's place among the groups of the whole run.
             inside = jnp.clip(ends, lo, lo + rows)
-            group_sizes = jnp.diff(inside, prepend=lo)
+            group_sizes = lax.dynamic_update_slice_in_dim(
+                jnp.zeros(n_groups, inside.dtype),
+                jnp.diff(inside, prepend=lo), layer * count, 0)
             held = jnp.arange(rows) < n_held - lo
             xs = _rows(x, token)                           # [rows, D]
         ys = _grouped_experts(xs, group_sizes, *ws)
@@ -388,7 +417,7 @@ def _route(cfg: MoEConfig, lp, x):
     return probs, gates, top_i
 
 
-def _moe_ffn(cfg: MoEConfig, lp, x, mesh, rules):
+def _moe_ffn(cfg: MoEConfig, lp, x, mesh, rules, stacks=None):
     """x: [B, S, D] -> ([B, S, D], aux loss scalar, pairs routed to each
     expert [E] int32, and what the layer computed of them as int32
     scalars: `pairs_held`, the pairs that fell on experts held here
@@ -399,7 +428,10 @@ def _moe_ffn(cfg: MoEConfig, lp, x, mesh, rules):
     `experts_held_steps`, the experts held here). With `cfg.latent_dim`
     the routed experts work on x projected down to the latent (scope
     `latent_down`) and their combined output is projected up again
-    (`latent_up`); the router and the shared expert read x itself."""
+    (`latent_up`); the router and the shared expert read x itself.
+    `stacks` is None, the expert matrices being `lp`'s, or, for a held
+    share, (the matrices of the whole run stacked by layer, this
+    layer's index in them), `lp` holding the layer's other leaves."""
     b, s, _ = x.shape
     k = cfg.n_experts_per_token
     with jax.named_scope("router"):
@@ -423,14 +455,17 @@ def _moe_ffn(cfg: MoEConfig, lp, x, mesh, rules):
                               top_i.reshape(t, k), *ws)
         return out.reshape(x.shape)
 
-    weights = _expert_matrices(lp)
+    run, layer = stacks or (lp, None)
+    weights = _expert_matrices(run)
     n_held, over = jnp.int32(b * s * k), jnp.int32(0)
     touched = (counts > 0).sum(dtype=jnp.int32)
     if cfg.experts_held is not None:
         assert mesh is None, "a held share of the experts runs on one chip"
+        if stacks is None:  # a layer's own matrices: a run of one layer
+            weights, layer = [w[None] for w in weights], 0
         out, n_held, over, touched = _held_experts(
             cfg, x.reshape(b * s, d), gates.reshape(b * s, k),
-            top_i.reshape(b * s, k), *weights)
+            top_i.reshape(b * s, k), layer, *weights)
         out = out.reshape(x.shape)
     elif mesh is None:
         out = experts(x, gates, top_i, *weights)
@@ -479,6 +514,22 @@ def _gather_whole(w, spec):
         if axes is not None:
             w = lax.all_gather(w, axes, axis=dim, tiled=True)
     return w
+
+
+def served_ffn(cfg: MoEConfig):
+    """The FFN `decoder` is handed for a served expert layer: the
+    layer's output and, as its extras, what it counted (`_moe_ffn`'s
+    int32 scalars). Where the config holds a share of the experts, it
+    names the expert matrices as the leaves `decoder.layers` leaves
+    whole (`whole`) and reads its layer's in their stacks
+    (`_held_experts`); a layer of all the experts takes its slice."""
+    def ffn(h, lp, stacks=None):
+        out, _, _, share = _moe_ffn(cfg, lp, h, None, DEFAULT_RULES, stacks)
+        return out, share
+
+    if cfg.experts_held is not None:
+        ffn.whole = tuple(_EXPERT_AXES)
+    return ffn
 
 
 def _parts(cfg: MoEConfig, mesh, rules):

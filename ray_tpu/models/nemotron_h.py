@@ -43,7 +43,6 @@ from jax import lax
 
 from ray_tpu.models import decoder, llama, mamba2, moe
 from ray_tpu.models.glm_dsa import _by_query_blocks
-from ray_tpu.parallel.sharding import DEFAULT_RULES
 
 PUBLISHED_PATTERN = (
     "MEMEMEM*EMEMEMEM*EMEMEMEM*EMEMEMEMEM*EMEMEMEMEM*EMEMEMEMEM*"
@@ -226,14 +225,6 @@ def _attention(cfg: NemotronHConfig, start_pos, positions):
     return mixer
 
 
-def _experts(cfg: NemotronHConfig):
-    def ffn(h, lp):
-        out, _, _, share = moe._moe_ffn(cfg, lp, h, None, DEFAULT_RULES)
-        return out, share
-
-    return ffn
-
-
 # ---------------------------------------------------------------------------
 # Forward through the slot cache
 # ---------------------------------------------------------------------------
@@ -246,7 +237,7 @@ def _hidden(params, tokens, cfg: NemotronHConfig, cache, start_pos, at):
     positions = start_pos[:, None] + jnp.arange(tokens.shape[1])[None, :]
     mixers = {"ssm": mamba2.mixer(cfg, start_pos, at),
               "attn": _attention(cfg, start_pos, positions), None: None}
-    runs = [(mixers[mixer], _experts(cfg) if ffn else None, stacked,
+    runs = [(mixers[mixer], moe.served_ffn(cfg) if ffn else None, stacked,
              tuple(run[k] for k in _LEAVES[mixer]) if mixer else None)
             for ((mixer, ffn), _), stacked, run in zip(
                 cfg.runs(), params["runs"], cache["runs"])]

@@ -198,17 +198,19 @@ class ServeReplica:
                 agen.aclose(), loop).result(timeout=5)
 
     def _start_stream(self, gen):
-        """Generator results stream through an actor-backed queue: the
-        replica pumps in a background thread (bounded queue =
-        backpressure); the consumer — HTTP proxy or Python caller via
-        `serve.iter_stream` — pulls until the end marker. This is the
+        """Generator results stream through a queue
+        (`streaming.channel`: actor-backed, or in this process where
+        the reader is too): the replica pumps in a background thread
+        (bounded queue = backpressure); the consumer — HTTP proxy or
+        Python caller via `serve.iter_stream` — pulls until the end
+        marker. This is the
         token-streaming channel (reference: ASGI StreamingResponse
         through `http_proxy.py:425`; the transport differs, the contract
         — incremental chunks over one request — is the same)."""
-        from ray_tpu.serve.streaming import STREAM_END_KEY, STREAM_KEY
-        from ray_tpu.util.queue import Queue
+        from ray_tpu.serve.streaming import (STREAM_END_KEY, STREAM_KEY,
+                                             channel)
 
-        queue = Queue(maxsize=64)
+        queue = channel(maxsize=64)
 
         def pump():
             # Finite put timeouts: an abandoned consumer (client gone,

@@ -215,3 +215,234 @@ def test_step_dispatch_span_carries_the_last_finished_steps_routing():
             "expert_tokens_max": int(
                 before["span_attrs"]["expert_tokens_max"]),
             "expert_tokens_mean": 2 * 4 * 16 // 4}
+
+
+# -- a held share reads its layer's experts in the run's stack ----------------
+
+
+def _held_run(kind, n_layers=3, seed=0):
+    """A run of FFN-only blocks whose experts are a held share: config,
+    parameters stacked by layer, activations. Swiglu experts as GLM-5.2
+    has them (a shared expert at the full width), relu2 ones as Nemotron
+    3 Super (in a latent). The middle layer's router never chooses a
+    held expert."""
+    import dataclasses
+
+    from ray_tpu.models import moe
+
+    cfg = dataclasses.replace(
+        MoEConfig.debug_moe(), n_experts=16, n_experts_per_token=3,
+        hidden_dim=24, scoring="sigmoid", selection_bias=True,
+        gate_scale=2.5, experts_held=(4, 4), expert_kind=kind,
+        shared_hidden_dim=40, latent_dim=32 if kind == "relu2" else 0)
+
+    def layer(key):
+        return {"mlp_norm": jnp.ones(cfg.dim, cfg.dtype),
+                **moe.expert_init(cfg, jax.random.split(key, 4))}
+
+    k_layers, k_x = jax.random.split(jax.random.PRNGKey(seed))
+    stacked = jax.vmap(layer)(jax.random.split(k_layers, n_layers))
+    stacked["router_bias"] = stacked["router_bias"].at[1, 4:8].set(-100.0)
+    return cfg, stacked, jax.random.normal(k_x, (2, 24, cfg.dim))
+
+
+def _share_by_hand(cfg, lp, h):
+    """A held share's layer, every held expert on every token and the
+    gates of the pairs the router did not send there zero."""
+    from ray_tpu.models import moe
+
+    _, gates, top_i = moe._route(cfg, lp, h)
+    first, count = cfg.experts_held
+    z = h @ lp["w_dn"] if cfg.latent_dim else h
+    out = jnp.zeros_like(z)
+    for e in range(count):
+        hidden = z @ lp["we1"][e]
+        hidden = (jax.nn.silu(hidden) * (z @ lp["we3"][e]) if "we3" in lp
+                  else jnp.square(jax.nn.relu(hidden)))
+        gate = jnp.where(top_i == first + e, gates, 0.0).sum(-1)
+        out += gate[..., None] * (hidden @ lp["we2"][e])
+    if cfg.latent_dim:
+        out = out @ lp["w_up"]
+    return moe._add_shared_expert(cfg, lp, h, out)
+
+
+@pytest.mark.parametrize("crowded", [False, True], ids=["roomy", "crowded"])
+@pytest.mark.parametrize("kind", ["swiglu", "relu2"])
+def test_a_run_of_held_layers_reads_each_layers_experts_in_the_stack(
+        kind, crowded, monkeypatch):
+    """`decoder.layers` over three held expert layers, whose expert
+    matrices ride whole: every layer's output is that of the layer's own
+    slice (by hand, and through `_moe_ffn` given the slice), also where
+    the share takes several buffers and where no pair falls on it."""
+    from ray_tpu.models import decoder, moe
+    from ray_tpu.ops.norms import rms_norm_reference
+
+    if crowded:
+        monkeypatch.setattr(moe, "_HELD_ROWS_MIN", 4)
+        monkeypatch.setattr(moe, "_HELD_ROWS_SLACK", 0)
+    cfg, stacked, x = _held_run(kind)
+    served = moe.served_ffn(cfg)
+    assert served.whole == ("we1", "we3", "we2")
+    seen = []
+
+    def ffn(h, lp, stacks):
+        # What `layers` hands over: the run's stacks and a layer, and
+        # an `lp` without the expert matrices.
+        seen.append((sorted(stacks[0]), [w.shape[0]
+                                         for w in stacks[0].values()]))
+        assert not set(lp) & set(served.whole)
+        out, counted = served(h, lp, stacks=stacks)
+        return out, {**counted, "out": out}
+
+    ffn.whole = served.whole
+    got, _, extras, _ = jax.jit(
+        lambda stacked, x: decoder.layers(None, ffn, cfg, None, x, stacked)
+    )(stacked, x)
+    names = sorted(n for n in served.whole if n in stacked)
+    assert seen == [(names, [3] * len(names))]
+    for layer in range(3):
+        lp = jax.tree.map(lambda leaf: leaf[layer], stacked)
+        h = rms_norm_reference(x, lp["mlp_norm"], cfg.norm_eps)
+        np.testing.assert_allclose(extras["out"][layer],
+                                   _share_by_hand(cfg, lp, h), atol=3e-6)
+        out, _, _, counted = moe._moe_ffn(cfg, lp, h, None, None)
+        np.testing.assert_allclose(extras["out"][layer], out, atol=1e-6)
+        for name, value in counted.items():
+            assert int(extras[name][layer]) == int(value), (name, layer)
+        x = x + out
+    np.testing.assert_allclose(got, x, atol=1e-6)
+    held = [int(n) for n in extras["pairs_held"]]
+    assert held[1] == 0 < min(held[0], held[2])
+    assert int(extras["experts_touched"][1]) == 0
+    over = [int(n) for n in extras["pair_overflows"]]
+    assert over == ([-(-n // 4) - 1 if n else 0 for n in held] if crowded
+                    else [0, 0, 0])
+
+
+def _subjaxprs(jaxpr):
+    """`jaxpr` and every jaxpr inside its equations' parameters."""
+    yield jaxpr
+    for eqn in jaxpr.eqns:
+        for value in eqn.params.values():
+            for sub in value if isinstance(value, (tuple, list)) else [value]:
+                sub = getattr(sub, "jaxpr", sub)
+                if hasattr(sub, "eqns"):
+                    yield from _subjaxprs(sub)
+
+
+def _expert_copies(jaxpr, matrix_shapes):
+    """Where a program takes one layer's expert matrices out of a stack:
+    the scanned inputs of a `scan` whose slice is a matrix's shape, and
+    the `dynamic_slice`s that give one."""
+    found = []
+    for sub in _subjaxprs(jaxpr):
+        for eqn in sub.eqns:
+            if eqn.primitive.name == "scan":
+                skip = eqn.params["num_consts"] + eqn.params["num_carry"]
+                found += [("scan", v.aval.shape) for v in eqn.invars[skip:]
+                          if v.aval.shape[1:] in matrix_shapes]
+            elif eqn.primitive.name in ("dynamic_slice", "gather"):
+                found += [(eqn.primitive.name, v.aval.shape)
+                          for v in eqn.outvars
+                          if v.aval.shape[-3:] in matrix_shapes]
+    return found
+
+
+def _served_engine(family):
+    """An engine at debug widths whose model has a run of several held
+    expert layers, and the shapes of a layer's expert matrices."""
+    import dataclasses
+
+    from ray_tpu.models import glm_dsa, nemotron_h
+    from ray_tpu.serve.llm import LLMEngine
+
+    if family == "glm_dsa":
+        cfg = dataclasses.replace(
+            glm_dsa.GlmDsaConfig.debug_glm(), n_layers=5, experts_held=(2, 4),
+            layer_kinds=(("dense", "full"),) + (("sparse", "shared"),) * 3
+            + (("sparse", "full"),))
+        params = glm_dsa.init_params(cfg, jax.random.PRNGKey(0))
+    else:
+        cfg = nemotron_h.NemotronHConfig.debug_nemotron()  # MEMEM*E
+        params = nemotron_h.init_params(cfg, jax.random.PRNGKey(0))
+    runs = [run for run in params["runs"] if "we1" in run]
+    assert max(run["we1"].shape[0] for run in runs) > 1
+    shapes = {run[name].shape[1:] for run in runs
+              for name in ("we1", "we3", "we2") if name in run}
+    assert all(s[0] == cfg.n_experts_held for s in shapes)
+    return LLMEngine(cfg, params, max_batch_size=2, max_seq_len=32), shapes
+
+
+@pytest.mark.parametrize("family", ["glm_dsa", "nemotron_h"])
+def test_no_served_program_slices_expert_matrices_out_of_their_stack(
+        family, monkeypatch):
+    """The decode and the prefill program of a model that holds a share
+    of the experts: no layer scan has an expert matrix among its scanned
+    operands and nothing slices one out of the run's stack, so the
+    grouped products read the stack itself (a scanned slice is a copy of
+    every held expert's weights, a layer: PERF.md, PR 35). The same
+    reading finds the copies in the program as it was, the FFN naming
+    nothing to stay whole."""
+    from ray_tpu.models import moe
+
+    eng, shapes = _served_engine(family)
+    n = eng.n_slots
+    ints = jnp.zeros(n, jnp.int32)
+
+    def programs():
+        return {
+            "decode": jax.make_jaxpr(eng._decode_impl)(
+                eng.params, eng.cache, ints, ints, jnp.zeros(n), ints,
+                jax.random.PRNGKey(0)),
+            "prefill": jax.make_jaxpr(
+                lambda p, c, t: eng._prefill_impl(p, c, t, 0, 5, 0, 8))(
+                eng.params, eng.cache, jnp.zeros((1, 8), jnp.int32))}
+
+    for name, program in programs().items():
+        assert _expert_copies(program.jaxpr, shapes) == [], name
+        # The grouped products take the run's stack, layers x experts
+        # groups of it.
+        groups = {eqn.invars[1].aval.shape[0]
+                  for sub in _subjaxprs(program.jaxpr) for eqn in sub.eqns
+                  if eqn.primitive.name == "ragged_dot_general"}
+        assert max(groups) > eng.cfg.n_experts_held, (name, groups)
+
+    served = moe.served_ffn
+
+    def scanned(cfg):
+        ffn = served(cfg)
+        return lambda h, lp: ffn(h, lp)
+
+    monkeypatch.setattr(moe, "served_ffn", scanned)
+    for name, program in programs().items():
+        kinds = {kind for kind, _ in _expert_copies(program.jaxpr, shapes)}
+        assert kinds == {"scan"}, name
+
+
+def test_a_trained_expert_layer_still_takes_its_layers_slice():
+    """The trained path is as it was: every leaf of the layers' stack,
+    the expert matrices among them, is a scanned input of the forward
+    scan and of no other kind (no layer index rides along), and the
+    grouped products take one layer's [E, K, N] matrices, in the loss
+    and in its gradient."""
+    cfg = MoEConfig.debug_moe()
+    params = init_moe_params(cfg, jax.random.PRNGKey(0))
+    batch = _batch(cfg)
+    shapes = {params["layers"][name].shape[1:]
+              for name in ("we1", "we3", "we2")}
+    program = jax.make_jaxpr(jax.grad(
+        lambda p: moe_loss_fn(p, batch, cfg)[0]))(params)
+    scans = [eqn for sub in _subjaxprs(program.jaxpr) for eqn in sub.eqns
+             if eqn.primitive.name == "scan"]
+    forward = scans[0]
+    skip = forward.params["num_consts"] + forward.params["num_carry"]
+    assert sorted(v.aval.shape for v in forward.invars[skip:]) == sorted(
+        leaf.shape for leaf in jax.tree.leaves(params["layers"]))
+    assert len(_expert_copies(program.jaxpr, shapes)) >= 3
+    # (A weight's gradient is a grouped product of two matrices of
+    # rows; the others read expert matrices, as they lie or transposed.)
+    read = {eqn.invars[1].aval.shape
+            for sub in _subjaxprs(program.jaxpr) for eqn in sub.eqns
+            if eqn.primitive.name == "ragged_dot_general"
+            and eqn.invars[1].aval.ndim == 3}
+    assert read and read <= shapes | {(e, n, k) for e, k, n in shapes}, read

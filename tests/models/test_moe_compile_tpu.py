@@ -72,8 +72,8 @@ def test_served_step_compiles_for_the_v5e(one_chip, name, program, bucket,
     """A served share as its file under `benchmark/configs/` cuts it,
     through the engine's own decode and prefill programs at its cell's
     slots and the cell's largest bucket: it compiles, the held experts
-    run through the grouped kernel, and the program fits beside nothing
-    else. GLM-5.2 at 16 slots x 16,384; Nemotron 3 Super at 64 x 4,096,
+    run through the grouped kernel where their matrices lie, and the
+    program fits beside nothing else. GLM-5.2 at 16 slots x 16,384; Nemotron 3 Super at 64 x 4,096,
     whose Mamba-2 state rides the same carry as leaves with no sequence
     axis. `products`: the grouped products an expert layer has, three of
     a gated SwiGLU, two of relu^2."""
@@ -112,9 +112,17 @@ def test_served_step_compiles_for_the_v5e(one_chip, name, program, bucket,
                            static_argnums=(6,)).lower(
             params, cache, ints(1, bucket), ints(), ints(), ints(),
             bucket).compile()
-    kernels = re.findall(r"%([\w-]+?)(?:\.\d+)? = \S+ custom-call\(",
-                         compiled.as_text())
+    text = compiled.as_text()
+    kernels = re.findall(r"%([\w-]+?)(?:\.\d+)? = \S+ custom-call\(", text)
     assert sum(k in PRODUCTS for k in kernels) >= products
+    # The grouped products read a layer's experts in the run's stack:
+    # no op makes an array of one layer's expert matrices (the layer
+    # scan's slice of them was a copy of 403 and 704 MB a matrix and
+    # layer, a third of a decode step: PERF.md, PR 35).
+    e, w, f = cfg.n_experts_held, cfg.latent_dim or cfg.dim, cfg.hidden_dim
+    assert not re.findall(
+        rf"= bf16\[{e},(?:{w},{f}|{f},{w})\]\S* "
+        r"(?:fusion|copy|copy-start|dynamic-slice)\(", text)
     memory = compiled.memory_analysis()
     assert memory.argument_size_in_bytes > resident  # weights and cache
     assert memory.argument_size_in_bytes + memory.temp_size_in_bytes \

@@ -204,3 +204,139 @@ def test_http_sse_streams_incrementally(serve_up):
     assert [c["i"] for c in items] == [0, 1, 2, 3]
     # 4 chunks at 0.4s spacing = ~1.6s total; the first arrived early.
     assert first_at is not None and first_at < 1.0, first_at
+
+
+# -- the stream's channel where reader and replica share a process -----------
+
+
+def test_a_stream_in_one_process_rides_no_actor(serve_up):
+    """Under the in-process backend a replica pumps into a
+    `LocalChannel`: the stream starts no queue actor."""
+    from ray_tpu.serve.streaming import STREAM_KEY, LocalChannel
+    from ray_tpu.util import queue as actor_queue
+
+    @serve.deployment
+    class Streamer:
+        def __call__(self, request):
+            return ({"i": i} for i in range(3))
+
+    handle = serve.run(Streamer.bind(), route_prefix="/local")
+    started = []
+    real = actor_queue._QueueActor.options
+    actor_queue._QueueActor.options = lambda **kw: started.append(kw) or real(**kw)
+    try:
+        result = ray_tpu.get(handle.remote({}), timeout=60)
+        assert isinstance(result[STREAM_KEY], LocalChannel)
+        assert list(serve.iter_stream(result)) == [{"i": 0}, {"i": 1},
+                                                   {"i": 2}]
+    finally:
+        actor_queue._QueueActor.options = real
+    assert started == []
+
+
+def test_local_channel_is_bounded_ordered_and_lets_go():
+    import asyncio
+    import threading
+
+    from ray_tpu.serve.streaming import LocalChannel
+    from ray_tpu.util.queue import Empty, Full
+
+    ch = LocalChannel(maxsize=2)
+    ch.put(0, timeout=1.0)
+    ch.put(1, timeout=1.0)
+    t0 = time.perf_counter()
+    with pytest.raises(Full):  # no room, and none comes
+        ch.put(2, timeout=0.2)
+    assert time.perf_counter() - t0 >= 0.2
+    assert ch.get(timeout=1.0) == 0
+    ch.put(2, timeout=1.0)  # room again
+    assert [ch.get(timeout=1.0), ch.get(timeout=1.0)] == [1, 2]
+    with pytest.raises(Empty):
+        ch.get(timeout=0.1)
+
+    async def read(n):
+        got = [await ch.get_async(5.0) for _ in range(n)]
+        return got, await ch.get_async(0.1)
+
+    # A reader that waits on its event loop is woken by a put from
+    # another thread, a chunk at a time and in order; past the last it
+    # times out with (False, None).
+    writer = threading.Thread(
+        target=lambda: [(time.sleep(0.05), ch.put(i, timeout=5.0))
+                        for i in range(5)])
+    writer.start()
+    got, after = asyncio.run(read(5))
+    writer.join(timeout=10)
+    assert not writer.is_alive()
+    assert got == [(True, i) for i in range(5)] and after == (False, None)
+
+    # A writer that waits for room is released when the reader leaves.
+    ch.put("a", timeout=1.0)
+    ch.put("b", timeout=1.0)
+    outcome = []
+
+    def blocked():
+        try:
+            ch.put("c", timeout=30.0)
+            outcome.append("put")
+        except Full:
+            outcome.append("full")
+
+    writer = threading.Thread(target=blocked)
+    writer.start()
+    time.sleep(0.1)
+    ch.shutdown()
+    writer.join(timeout=10)
+    assert not writer.is_alive() and outcome == ["full"]
+    with pytest.raises(Full):
+        ch.put("d", timeout=1.0)
+
+
+def test_an_abandoned_local_stream_closes_its_generator(serve_up):
+    """The reader of a stream in one process leaves after a chunk: the
+    replica's pump, waiting for room behind it, lets go and closes the
+    deployment's generator."""
+    closed = []
+
+    @serve.deployment
+    class Endless:
+        def __call__(self, request):
+            def gen():
+                try:
+                    i = 0
+                    while True:
+                        yield {"i": i}
+                        i += 1
+                finally:
+                    closed.append(True)
+            return gen()
+
+    handle = serve.run(Endless.bind(), route_prefix="/endless")
+    result = ray_tpu.get(handle.remote({}), timeout=60)
+    reader = serve.iter_stream(result)
+    assert next(reader) == {"i": 0}
+    reader.close()  # the finally of iter_stream shuts the channel down
+    deadline = time.time() + 10
+    while not closed and time.time() < deadline:
+        time.sleep(0.05)
+    assert closed == [True]
+
+
+def test_a_local_channel_that_leaves_the_process_becomes_a_queue(serve_up):
+    """Pickled (a client process fetched the stream's handle), the
+    channel hands its reader an actor-backed queue and forwards what
+    the pump puts, to the end marker."""
+    import cloudpickle  # what carries a result between processes
+
+    from ray_tpu.serve.streaming import (STREAM_END_KEY, STREAM_KEY,
+                                         LocalChannel)
+    from ray_tpu.util.queue import Queue
+
+    ch = LocalChannel(maxsize=4)
+    ch.put({"i": 0}, timeout=1.0)
+    remote = cloudpickle.loads(cloudpickle.dumps({STREAM_KEY: ch}))
+    assert isinstance(remote[STREAM_KEY], Queue)
+    ch.put({"i": 1}, timeout=1.0)
+    ch.put({STREAM_END_KEY: True}, timeout=1.0)
+    assert list(serve.iter_stream(remote, timeout=10.0)) == [{"i": 0},
+                                                             {"i": 1}]
