@@ -279,13 +279,16 @@ def record_stage(trace_id: Optional[str], stage: str, dur_s: float,
     flight ring at fold time — they are real cluster activity the
     post-mortem wants — but never the attribution vectors.
 
-    Spans under :data:`MIN_SPAN_S` are dropped at the door: a stage
-    that took tens of microseconds can never be the answer to "which
-    stage made this request slow", it folds into ``unattributed`` by
-    the tiling contract anyway, and recording it costs exactly as much
-    as recording a meaningful one — on a fast route the floor drops
-    most of the per-request records."""
-    if not _on() or dur_s < MIN_SPAN_S:
+    A request's stages under :data:`MIN_SPAN_S` are dropped at the
+    door: a stage that took tens of microseconds can never be the
+    answer to "which stage made this request slow", it folds into
+    ``unattributed`` by the tiling contract anyway, and recording it
+    costs exactly as much as recording a meaningful one — on a fast
+    route the floor drops most of the per-request records. The floor
+    is attribution's: a record without a trace id goes in whatever it
+    took, as a span does (a hand-over lag of 20 µs is the reading, and
+    a floor would make every quantile of it read high)."""
+    if not _on() or (trace_id and dur_s < MIN_SPAN_S):
         return
     t1 = time.time()
     dur_s = float(dur_s)

@@ -79,7 +79,8 @@ from ray_tpu._private import perf_stats
 from ray_tpu._private.config import ray_config
 from ray_tpu._private.kv_cache import PrefixCache, chain_keys
 from ray_tpu.models.serving import served_model
-from ray_tpu.serve.streaming import STREAM_WAITING_KEY, WAITING_BEAT_S
+from ray_tpu.serve.streaming import (LAG_SAMPLE_EVERY, STREAM_WAITING_KEY,
+                                     WAITING_BEAT_S)
 
 
 # A prefill program's cost grows faster than its length (attention), so
@@ -187,6 +188,10 @@ class _Request:
     # while the request crosses admit → kv-lookup → prefill → sample.
     trace_id: str = ""
     t_kv_done: float = 0.0
+    # When the loop put the newest token whose count is a multiple of
+    # `LAG_SAMPLE_EVERY` into `out_queue`: its reader records
+    # `stream.wake` from it.
+    t_put: float = 0.0
 
 
 @dataclasses.dataclass
@@ -247,7 +252,9 @@ class LLMEngine:
         self._running = threading.Event()
         self._thread: Optional[threading.Thread] = None
         # Pipelined decode: the block in flight as (its device token
-        # array, the slots' requests when it was dispatched); its host
+        # array, the slots' requests when it was dispatched, what the
+        # model counted, whether prefills were dispatched in front of
+        # it); its host
         # fetch happens while the next block computes. The decode
         # carries (each slot's last token and length) live on the
         # device: a block hands them to the next, and an admission
@@ -259,6 +266,12 @@ class LLMEngine:
         # next decode dispatch.
         self._pending_block = None
         self._first_tokens = None
+        # A wave has dispatched prefills since the last decode dispatch
+        # (the next block runs behind them), and when the last block's
+        # hand-over ended (None: no block was in flight before the one
+        # that is now): the two ends of `engine.block_gap.*`.
+        self._wave_dispatched = False
+        self._handed_over: Optional[float] = None
         # Running totals of what the loop's spans count at the same
         # seams (`metrics()["totals"]`); only the loop's thread adds
         # (and, to `kv_readbacks_forced`, whoever forces a read-back).
@@ -269,7 +282,8 @@ class LLMEngine:
             "admit_waves_behind_block", "slot_steps_stale", "admissions",
             "kv_blocks_read_back", "kv_bytes_read_back",
             "kv_readbacks_deferred", "kv_readbacks_forced",
-            "keys_cached", "keys_attended"), 0)
+            "keys_cached", "keys_attended", "blocks_behind_wave",
+            "blocks_plain"), 0)
         # What the model counts while it runs (nothing, for most) joins
         # the totals under the model's own names, which one abstract
         # evaluation of a decode step gives. The same evaluation says
@@ -722,6 +736,7 @@ class LLMEngine:
 
         def token_iter():
             wait, steps = beat_s, self._totals["decode_steps"]
+            n = 0
             while True:
                 try:
                     item = req.out_queue.get(timeout=wait)
@@ -732,6 +747,12 @@ class LLMEngine:
                     continue
                 if item is None:
                     return
+                n += 1
+                if not n % LAG_SAMPLE_EVERY:
+                    # The loop's put to this thread's wake-up.
+                    critical_path.record_stage(
+                        None, "stream.wake",
+                        critical_path.clock() - req.t_put)
                 wait = None  # admitted: every step brings a token
                 yield item
 
@@ -770,7 +791,10 @@ class LLMEngine:
                 # over the active slots of every decode dispatch the
                 # keys their caches held and those of them the step
                 # attended (fewer where the model selects keys:
-                # `ServedModel.keys_attended`); and what the model
+                # `ServedModel.keys_attended`); the blocks handed over
+                # behind another, those with a wave's prefills in front
+                # of them and those without (one `engine.block_gap.*`
+                # record each); and what the model
                 # itself counted in its decode blocks, under its names
                 # (an expert layer's `pairs_held`, `pairs_routed`,
                 # `pair_overflows`, `experts_touched`,
@@ -790,7 +814,10 @@ class LLMEngine:
         children), `engine.decode_dispatch`, `engine.token_fetch` (the
         wait for a decode block, and for a wave's first tokens with
         their delivery as its child `engine.first_tokens`),
-        `engine.consume_block`, `engine.idle_wait`.
+        `engine.consume_block`, `engine.idle_wait`. Between two blocks
+        handed over one behind the other lies one thin record,
+        `engine.block_gap.wave` or `engine.block_gap.plain`
+        (`_consume_block`).
 
         A wave only dispatches, so the decode pipeline runs through it:
         wave (prefills and the sample program, queued behind block N in
@@ -904,6 +931,7 @@ class LLMEngine:
             return False
         totals["admit_waves"] += 1
         totals["admit_waves_behind_block"] += behind
+        self._wave_dispatched = True  # the next block runs behind them
         totals["admissions"] += len(staged)
         # ONE device-side sampling for the whole wave, padded to
         # n_slots rows so the program has one fixed shape, compiled
@@ -953,7 +981,9 @@ class LLMEngine:
             for name, n in attrs.items():
                 self._totals[name] += n
         with critical_path.span("engine.decode_dispatch", active=active,
-                                n_slots=self.n_slots, **attrs):
+                                n_slots=self.n_slots,
+                                keys_reserved=self.n_slots * self.max_seq,
+                                **attrs):
             prev = self._pending_block
             (self.cache, next_tokens, self._dev_last, self._dev_lengths,
              self._rng, counts) = self._run_decode(
@@ -962,7 +992,8 @@ class LLMEngine:
                 jnp.asarray(self._topks_arr))
             self._pending_block = (next_tokens, [
                 self._slot_req.get(slot) for slot in range(self.n_slots)],
-                counts)
+                counts, self._wave_dispatched)
+            self._wave_dispatched = False
             # The device has a block to run and the host nothing to do
             # but wait for the one before it: the read-backs' host half.
             self._finish_readbacks(
@@ -970,7 +1001,9 @@ class LLMEngine:
                 prev[0] if prev is not None else None)
         self._totals["decode_steps"] += self.decode_steps
         self._totals["active_slot_steps"] += active * self.decode_steps
-        if prev is not None:
+        if prev is None:
+            self._handed_over = None  # a gap lies between two blocks
+        else:
             self._consume_block(self._fetch_tokens(prev[0]), *prev[1:])
         # Dispatched before the block just dispatched, so they reach
         # their clients before that block is waited for.
@@ -987,13 +1020,21 @@ class LLMEngine:
         with critical_path.span("engine.token_fetch"):
             return np.asarray(block)
 
-    def _consume_block(self, next_host, owners, counts=()):
+    def _consume_block(self, next_host, owners, counts=(),
+                       behind_wave=False):
         """Hand a block's tokens to the requests it was dispatched for.
         `owners` are the slots' requests at its dispatch: a slot that
         holds another request by now (admitted while the block was in
         flight, into a slot retired before) gets none of them.
         `counts` is what the model counted in the block, ready with its
-        tokens: onto the span and into the totals."""
+        tokens: onto the span and into the totals.
+
+        Where the block before this one was in flight at its dispatch,
+        the time from that block's hand-over to the end of this one is
+        recorded, the gap every active slot's client is dealt: as
+        `engine.block_gap.wave` where a wave's prefills were dispatched
+        in front of this block (`behind_wave`), as
+        `engine.block_gap.plain` where none were."""
         kept = stale = 0
         counted = dict(zip(self._count_names, map(int, np.asarray(counts))))
         with critical_path.span("engine.consume_block") as sp, self._lock:
@@ -1008,6 +1049,8 @@ class LLMEngine:
                 for k in range(next_host.shape[1]):
                     tok = int(next_host[slot, k])
                     req.tokens.append(tok)
+                    if not len(req.tokens) % LAG_SAMPLE_EVERY:
+                        req.t_put = critical_path.clock()
                     req.out_queue.put(tok)  # raylint: disable=R2 -- per-request stream queues are unbounded, so put() cannot block; token delivery and slot-state mutation must share one hold or a racing admit could reuse the slot mid-block
                     kept += 1
                     self._lengths[slot] += 1
@@ -1021,7 +1064,16 @@ class LLMEngine:
             self._totals["slot_steps_stale"] += stale
             for name, n in counted.items():
                 self._totals[name] += n
-            sp.set(kept=kept, discarded=discarded, stale=stale, **counted)
+            sp.set(kept=kept, discarded=discarded, stale=stale,
+                   slot_steps=next_host.size, behind_wave=int(behind_wave),
+                   **counted)
+        now = critical_path.clock()
+        if self._handed_over is not None:
+            total, name = ("blocks_behind_wave", "engine.block_gap.wave") \
+                if behind_wave else ("blocks_plain", "engine.block_gap.plain")
+            self._totals[total] += 1
+            critical_path.record_stage(None, name, now - self._handed_over)
+        self._handed_over = now
 
     def _deliver_first_tokens(self):
         """The last wave's first tokens to their clients: the wait for
@@ -1036,7 +1088,7 @@ class LLMEngine:
         if pending is None:
             return
         firsts_dev, staged = pending
-        with critical_path.span("engine.token_fetch"):
+        with critical_path.span("engine.token_fetch", first_tokens=1):
             firsts = np.asarray(firsts_dev)
             t_host = critical_path.clock()
             with critical_path.span("engine.first_tokens",

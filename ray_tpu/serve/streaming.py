@@ -20,6 +20,8 @@ import threading
 import time
 from typing import Any, Iterator
 
+from ray_tpu._private import critical_path
+
 STREAM_KEY = "__ray_tpu_stream__"
 STREAM_END_KEY = "__ray_tpu_stream_end__"
 # A chunk a deployment's generator may yield while it has nothing to
@@ -31,6 +33,10 @@ STREAM_WAITING_KEY = "__ray_tpu_stream_waiting__"
 # How long such a generator lets pass between two of them: a third of
 # the readers' default timeout.
 WAITING_BEAT_S = 20.0
+# A stream's hand-over lag is timed for one chunk in this many, by the
+# chunk's index: `stream.wake` (the engine's queue to the replica's
+# pump, `serve/llm.py`) and `stream.channel` (`LocalChannel`, below).
+LAG_SAMPLE_EVERY = 16
 
 
 class LocalChannel:
@@ -42,10 +48,15 @@ class LocalChannel:
     what a front of 64 streams passed tokens at (PERF.md, PR 35).
     Bounded like the actor's queue: `put` waits for room, `timeout`
     seconds at the most, and a channel that was shut down takes
-    nothing more, so a pump whose reader left lets go."""
+    nothing more, so a pump whose reader left lets go.
+
+    Every `LAG_SAMPLE_EVERY`-th chunk lies in the deque beside the time
+    it was put, and its taker records `stream.channel`, the time it lay
+    there: a thin record with no trace id (`critical_path`)."""
 
     def __init__(self, maxsize: int):
-        self._items = collections.deque()
+        self._items = collections.deque()  # (chunk, its stamp or 0.0)
+        self._puts = 0
         self._maxsize = maxsize
         self._cond = threading.Condition()
         self._waiter = None  # (loop, future) of a reader in get_async
@@ -62,7 +73,10 @@ class LocalChannel:
                     raise Full()
             if self._closed:
                 raise Full()
-            self._items.append(item)
+            self._puts += 1
+            self._items.append((
+                item, 0.0 if self._puts % LAG_SAMPLE_EVERY
+                else critical_path.clock()))
             waiter, self._waiter = self._waiter, None
             self._cond.notify_all()
         if waiter is not None:
@@ -78,8 +92,11 @@ class LocalChannel:
             future.set_result(None)
 
     def _take(self):
-        item = self._items.popleft()
+        item, stamp = self._items.popleft()
         self._cond.notify_all()
+        if stamp:
+            critical_path.record_stage(None, "stream.channel",
+                                       critical_path.clock() - stamp)
         return item
 
     def get(self, timeout: float):

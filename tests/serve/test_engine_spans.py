@@ -2,7 +2,10 @@
 dispatches): the spans tile the
 loop, the totals of `metrics()` add up to what the clients got, a
 profiler's trace carries the spans under their own names on the ring's
-clock, and the proxy's envelope gives `front.ttft_self`.
+clock, and the proxy's envelope gives `front.ttft_self`. PR 36: the gap
+between two blocks handed over one behind the other is a thin record
+by kind (`engine.block_gap.*`), a stream's hand-over lag is sampled
+(`stream.wake`), and nothing is recorded a token.
 
 Kept tier-1-sized: one tiny model, a few dozen requests.
 """
@@ -10,6 +13,8 @@ Kept tier-1-sized: one tiny model, a few dozen requests.
 import glob
 import http.client
 import json
+import os
+import re
 import threading
 import time
 
@@ -42,6 +47,9 @@ _FIRST_TOKENS = "engine.first_tokens"
 # The read-back's two halves (PR 28): the dispatch in a wave, the
 # completion under the loop's span that shadows it.
 _READBACK = "engine.prefix_readback"
+# The thin records between two blocks (PR 36): durations, not spans of
+# the loop's tiling (each lies over a whole turn of the loop).
+_GAP_WAVE, _GAP_PLAIN = "engine.block_gap.wave", "engine.block_gap.plain"
 
 
 @pytest.fixture(scope="module")
@@ -89,9 +97,15 @@ def _generate_all(engine, prompts, max_tokens):
     return results
 
 
+def _ring(*names):
+    return sorted((s for s in flight_recorder.local_snapshot()["spans"]
+                   if s["stage"] in names), key=lambda s: s["t1"])
+
+
 def _engine_spans():
     return [s for s in flight_recorder.local_snapshot()["spans"]
-            if s["stage"].startswith("engine.")]
+            if s["stage"].startswith("engine.")
+            and s["stage"] not in (_GAP_WAVE, _GAP_PLAIN)]
 
 
 def _union(intervals):
@@ -102,6 +116,19 @@ def _union(intervals):
             covered += b - a
             edge = b
     return covered
+
+
+def _wait_idle(engine, admissions):
+    deadline = time.monotonic() + 120
+    while time.monotonic() < deadline:
+        m = engine.metrics()
+        if m["totals"]["admissions"] == admissions \
+                and not m["active_slots"] and not m["queued"]:
+            # The loop flushes the block in flight, then idles.
+            time.sleep(0.1)
+            return
+        time.sleep(0.02)
+    raise AssertionError("the engine never went idle")
 
 
 def test_spans_tile_the_loop_and_totals_add_up(params, ring):
@@ -117,12 +144,7 @@ def test_spans_tile_the_loop_and_totals_add_up(params, ring):
                for i in range(10)]
     streams = [engine.generate(p, SamplingParams(max_tokens=50),
                                stream=True) for p in prompts]
-    deadline = time.monotonic() + 120
-    while time.monotonic() < deadline:
-        m = engine.metrics()
-        if m["totals"]["admissions"] == 10 and not m["active_slots"]:
-            break
-        time.sleep(0.05)
+    _wait_idle(engine, 10)
     engine.stop()
     answers = [list(s) for s in streams]
     assert [len(a) for a in answers] == [50] * 10
@@ -203,6 +225,11 @@ def test_spans_tile_the_loop_and_totals_add_up(params, ring):
     assert kept + delivered == totals["tokens_kept"]
     assert discarded == totals["tokens_discarded"]
     assert kept + discarded == 2 * len(blocks)
+    # The denominators ride the spans (PR 36): a block's slot-steps, and
+    # the keys the slots reserve beside those they hold.
+    assert sum(s["attrs"]["slot_steps"] for s in blocks) == 2 * len(blocks)
+    assert all(0 <= s["attrs"]["keys_cached"] <= s["attrs"]["keys_reserved"]
+               == 2 * 64 for s in steps)
     # Every decode step's block was consumed, but the last one in
     # flight when the engine stopped.
     assert kept + discarded in (2 * len(steps), 2 * (len(steps) - 1))
@@ -238,6 +265,163 @@ def test_spans_tile_the_loop_and_totals_add_up(params, ring):
     k = engine.cache["k"]
     assert all(kb.shape == vb.shape == (k.shape[0], 4) + k.shape[3:]
                for kb, vb in engine._kv_store.values())
+
+
+def test_block_gaps_tile_a_busy_stretch_by_kind(params, ring):
+    """Between two blocks handed over one behind the other lies one
+    thin record (PR 36): the gaps of a busy stretch run from its first
+    block's hand-over to its last one's with nothing between them, a
+    block that a wave's prefills were dispatched in front of is `wave`
+    and its neighbours `plain`, and no gap spans an idle loop."""
+    engine = _SteppedEngine(_TINY, params, max_batch_size=3,
+                            max_seq_len=64)
+    engine.warmup(16)
+    streams = [engine.generate([3 + i, 1, 4, 1, 5],
+                               SamplingParams(max_tokens=40), stream=True)
+               for i in range(2)]
+    while engine.metrics()["totals"]["decode_steps"] < 10:
+        time.sleep(0.002)
+    # A third request, into the free slot: a wave in mid-stretch.
+    streams.append(engine.generate([9, 2, 6], SamplingParams(max_tokens=12),
+                                   stream=True))
+    _wait_idle(engine, 3)
+    # A second busy stretch, behind an idle loop.
+    streams.append(engine.generate([2, 7, 1, 8], SamplingParams(max_tokens=9),
+                                   stream=True))
+    _wait_idle(engine, 4)
+    engine.stop()
+    assert [len(list(s)) for s in streams] == [40, 40, 12, 9]
+    totals = engine.metrics()["totals"]
+
+    blocks = _ring("engine.consume_block")
+    gaps = _ring(_GAP_WAVE, _GAP_PLAIN)
+    dispatches = _ring("engine.decode_dispatch")
+    idles = _ring("engine.idle_wait")
+    waves = [w for w in _ring("engine.admit_wave")
+             if w["attrs"]["admitted"]]
+    assert len(blocks) == len(dispatches) == totals["decode_steps"]
+    assert not any(g["trace_id"] or g.get("attrs") for g in gaps)
+
+    # `behind_wave` says that a wave's prefills went to the device
+    # between the dispatch of the block before and the block's own.
+    for k, (step, block) in enumerate(zip(dispatches, blocks)):
+        since = dispatches[k - 1]["t0"] if k else float("-inf")
+        behind = any(since <= w["t0"] and w["t1"] <= step["t0"]
+                     for w in waves)
+        assert block["attrs"]["behind_wave"] == int(behind), k
+
+    # Busy stretches: blocks with no idle loop between them.
+    stretches = [[blocks[0]]]
+    for before, block in zip(blocks, blocks[1:]):
+        if any(before["t1"] <= i["t0"] <= block["t0"] for i in idles):
+            stretches.append([])
+        stretches[-1].append(block)
+    assert len(stretches) == 2 and len(stretches[0]) > 40
+    assert len(gaps) == len(blocks) - 2  # one fewer than a stretch's blocks
+    at = 0
+    for stretch in stretches:
+        mine = gaps[at:at + len(stretch) - 1]
+        at += len(mine)
+        # Each gap ends where its block's hand-over does and starts
+        # where the one before ended: they tile the stretch.
+        for gap, before, block in zip(mine, stretch, stretch[1:]):
+            assert abs(gap["t1"] - block["t1"]) < 1e-3
+            assert abs(gap["dur_s"] - (block["t1"] - before["t1"])) < 1e-3
+            assert gap["stage"] == (
+                _GAP_WAVE if block["attrs"]["behind_wave"] else _GAP_PLAIN)
+        assert abs(sum(g["dur_s"] for g in mine)
+                   - (stretch[-1]["t1"] - stretch[0]["t1"])) < 1e-3
+    # The third request's wave: one `wave` gap between `plain` ones.
+    kinds = [g["stage"] for g in gaps[:len(stretches[0]) - 1]]
+    assert _GAP_WAVE in kinds[5:]
+    i = kinds.index(_GAP_WAVE, 5)
+    assert kinds[i - 1] == kinds[i + 1] == _GAP_PLAIN
+    # The totals count the same blocks.
+    n_wave = sum(g["stage"] == _GAP_WAVE for g in gaps)
+    assert totals["blocks_behind_wave"] == n_wave
+    assert totals["blocks_behind_wave"] + totals["blocks_plain"] == len(gaps)
+    # A block behind a wave waits for the wave's first tokens too; the
+    # fetch that does says so.
+    fetches = _ring("engine.token_fetch")
+    firsts = {s["parent"] for s in _ring(_FIRST_TOKENS)}
+    assert all((f.get("attrs") == {"first_tokens": 1}) == (f["id"] in firsts)
+               for f in fetches)
+    assert len(firsts) == len(waves)
+
+
+def test_stream_wake_is_sampled_and_no_record_is_a_tokens(params, ring):
+    """64 streams (PR 36): a request's reader records `stream.wake` for
+    each 16th token and nothing else, with no trace id, and the ring
+    takes far fewer records a block than the block has tokens."""
+    n, asked = 64, 50
+    engine = LLMEngine(_TINY, params, max_batch_size=n, max_seq_len=64)
+    engine.warmup(8)
+    prompts = [[(5 * i + j) % 60 + 1 for j in range(3 + i % 4)]
+               for i in range(n)]
+    answers = _generate_all(engine, prompts, asked)
+    engine.stop()
+    assert [len(a) for a in answers] == [asked] * n
+    spans = flight_recorder.local_snapshot()["spans"]
+    assert len(spans) < 2048  # the ring lost none
+    wakes = [s for s in spans if s["stage"] == "stream.wake"]
+    assert len(wakes) == n * (asked // 16)
+    assert not any(s["trace_id"] for s in wakes)
+    assert all(0 <= s["dur_s"] < 5.0 for s in wakes)
+    blocks = [s for s in spans if s["stage"] == "engine.consume_block"]
+    kept = sum(s["attrs"]["kept"] for s in blocks)
+    assert kept == n * (asked - 1)
+    # A record a token would be `kept` and more. Stated: what is
+    # recorded a block or a sampled token (the block's three spans, its
+    # gap, a wake for each 16th token of a slot) stays under 8 a block
+    # with 64 slots, and the rest, a request's stages and a wave's
+    # spans, under 12 a request.
+    steady = [s for s in spans if s["stage"] in (
+        "engine.decode_dispatch", "engine.token_fetch",
+        "engine.consume_block", _GAP_WAVE, _GAP_PLAIN, "stream.wake")]
+    assert len(steady) / len(blocks) < 8, (len(steady), len(blocks))
+    assert len(spans) - len(steady) < 12 * n
+    assert len(spans) < kept / 2
+
+
+def test_every_name_has_a_row_and_every_reader_a_name(params):
+    """PERF.md section 3 lists every `engine.*`, `stream.*` and `llm.*`
+    name and every total the program records, with its reader; and what
+    a metric's file reads under such a name is a name the program
+    records (PR 36)."""
+    root = os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))))
+    read = lambda *parts: open(os.path.join(root, *parts)).read()  # noqa: E731
+    source = read("ray_tpu", "serve", "llm.py") \
+        + read("ray_tpu", "serve", "streaming.py")
+    # Every name is written out as a string where it is recorded.
+    emitted = set(re.findall(r'"((?:engine|stream|llm)\.[a-z_.]+)"', source))
+    assert {"engine.block_gap.wave", "engine.block_gap.plain", "stream.wake",
+            "stream.channel", "llm.prefill", "engine.first_tokens"} \
+        <= emitted and len(emitted) >= 20, sorted(emitted)
+    perf = read("PERF.md")
+    layers = perf[perf.index("## 3. Layers"):perf.index("## 4. Cells")]
+    rows = set(re.findall(r"`([a-z_.]+)`", layers))
+    assert emitted <= rows, sorted(emitted - rows)
+    engine = LLMEngine(_TINY, params, max_batch_size=2, max_seq_len=64)
+    totals = set(engine.metrics()["totals"])
+    assert {"blocks_behind_wave", "blocks_plain"} <= totals <= rows, \
+        sorted(totals - rows)
+    # The readers: every stage or span a metric's file names.
+    metrics = os.path.join(root, "benchmark", "metrics")
+    programs = source + "".join(
+        read("ray_tpu", "models", f)
+        for f in os.listdir(os.path.join(root, "ray_tpu", "models"))
+        if f.endswith(".py"))
+    for f in sorted(os.listdir(metrics)):
+        args = json.loads(read(metrics, f))["args"]
+        named = [v for value in args.values()
+                 for v in (value if isinstance(value, list) else [value])
+                 if isinstance(v, str)
+                 and re.fullmatch(r"(engine|stream|llm)\.[a-z_.]*[a-z]", v)]
+        assert set(named) <= emitted, (f, named)
+        if named and "num" in args:  # a ratio of two attributes
+            assert all(re.search(rf"\b{args[k]}\b", programs)
+                       for k in ("num", "den")), (f, args)
 
 
 def test_trace_carries_the_spans_on_the_rings_clock(params, ring,

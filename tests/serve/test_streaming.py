@@ -340,3 +340,73 @@ def test_a_local_channel_that_leaves_the_process_becomes_a_queue(serve_up):
     ch.put({STREAM_END_KEY: True}, timeout=1.0)
     assert list(serve.iter_stream(remote, timeout=10.0)) == [{"i": 0},
                                                              {"i": 1}]
+
+
+# -- the channel's hand-over lag, sampled (PR 36) -----------------------------
+
+
+@pytest.fixture
+def ring(monkeypatch):
+    """An empty recorder whose snapshots hold the whole ring."""
+    from ray_tpu._private import critical_path, flight_recorder
+    from ray_tpu._private.config import ray_config
+
+    critical_path.reset()
+    flight_recorder.reset()
+    monkeypatch.setattr(ray_config, "flight_ring_size", 2048)
+    yield lambda: [s for s in flight_recorder.local_snapshot()["spans"]
+                   if s["stage"] == "stream.channel"]
+    critical_path.reset()
+    flight_recorder.reset()
+
+
+def test_local_channel_records_a_lag_of_20us_as_20us(ring, monkeypatch):
+    """Every 16th chunk lies in the channel beside the time it was put,
+    and its taker records how long it lay there as `stream.channel`: a
+    thin record with no trace id and no floor (the request stages' 50 us
+    would drop it). The reader gets the very object that was put."""
+    from ray_tpu._private import critical_path
+    from ray_tpu.serve.streaming import LAG_SAMPLE_EVERY, LocalChannel
+
+    assert LAG_SAMPLE_EVERY == 16
+    ticks = iter([100.0, 100.00002, 200.0, 200.5])
+    monkeypatch.setattr(critical_path, "clock", lambda: next(ticks))
+    ch = LocalChannel(maxsize=64)
+    chunks = [{"token": i, "index": i} for i in range(33)]
+    for c in chunks[:20]:
+        ch.put(c, timeout=1.0)
+    got = [ch.get(timeout=1.0) for _ in range(20)]
+    assert all(a is b for a, b in zip(got, chunks))
+    (rec,) = ring()  # the 16th chunk's, and no other's
+    assert rec["dur_s"] == pytest.approx(2e-5, abs=1e-9)
+    assert rec["dur_s"] < critical_path.MIN_SPAN_S
+    assert rec["trace_id"] == "" and not rec.get("attrs")
+    for c in chunks[20:]:
+        ch.put(c, timeout=1.0)
+    assert [ch.get(timeout=1.0) for _ in range(13)] == chunks[20:]
+    assert [round(r["dur_s"], 6) for r in ring()] == [2e-5, 0.5]
+    # A request's own stage keeps the floor.
+    critical_path.record_stage("req-1", "llm.kv_lookup", 2e-5)
+    critical_path.record_stage("", "stream.wake", 2e-5)
+    from ray_tpu._private import flight_recorder
+    names = [s["stage"] for s in flight_recorder.local_snapshot()["spans"]]
+    assert "llm.kv_lookup" not in names and "stream.wake" in names
+
+
+def test_a_served_stream_samples_its_channel_and_changes_no_chunk(
+        serve_up, ring):
+    """Through a replica's pump and `iter_stream`: the chunks arrive as
+    the deployment yielded them, and of the 40 and the end marker two
+    were timed (the 16th and the 32nd put)."""
+    @serve.deployment
+    class Streamer:
+        def __call__(self, request):
+            return ({"token": 7 * i, "index": i} for i in range(40))
+
+    handle = serve.run(Streamer.bind(), route_prefix="/lag")
+    result = ray_tpu.get(handle.remote({}), timeout=60)
+    assert list(serve.iter_stream(result)) == [
+        {"token": 7 * i, "index": i} for i in range(40)]
+    lags = ring()
+    assert len(lags) == 2
+    assert all(r["trace_id"] == "" and 0 <= r["dur_s"] < 5.0 for r in lags)
