@@ -14,13 +14,10 @@ softmax over the router's logits, `lax.top_k` (exactly k experts a
 token, ties to the lower index), the Switch-Transformer load-balancing
 loss, then the (token, expert) pairs sorted by expert, the tokens
 gathered into that order, the three SwiGLU products as grouped matmuls
-(`lax.ragged_dot`, which the TPU compiler turns into a grouped-matmul
-kernel of its own) whose group sizes are known only on the device, and
-a gate-weighted sum back in token order. (Megablox's Pallas `gmm` ran
-the products a third faster on the v5e, but tracing its group metadata
-for every call adds 2.4 to 4 s to a warm process's first step: PERF.md,
-PR 27.) Every pair is computed whatever the load of its expert: no
-capacity, nothing dropped, static shapes ([tokens * k, d] rows in all).
+whose group sizes are known only on the device, and a gate-weighted sum
+back in token order. Every pair is computed whatever the load of its
+expert: no capacity, nothing dropped, static shapes ([tokens * k, d]
+rows in all).
 Both permutations are row gathers in the forward and in the backward
 pass (`_spread` and `_collect` are each other's transpose), never a
 scatter.
@@ -31,13 +28,26 @@ A served model that holds a share of a layer's experts
 are gathered and computed, a buffer of rows at a time, and the expert
 matrices are read where they lie in the run's stack. `served_ffn` names
 them as the leaves `decoder.layers` keeps whole beside the scan, and
-the grouped products take the stack as layers x experts groups of
-which all but the layer's are empty: a layer's matrices sliced out of
-the stack by the scan are a copy of every held expert's weights ahead
-of a kernel that reads a few of them (a third to two fifths of a decode
-step, PERF.md, PR 35). The trained layer keeps its scanned slice: a
-stack handed whole would make every layer's weight gradient the size of
-the stack.
+the grouped products pick the layer's experts out of the stack: a
+layer's matrices sliced out of the stack by the scan are a copy of
+every held expert's weights ahead of a kernel that reads a few of them
+(a third to two fifths of a decode step, PERF.md, PR 35). The trained
+layer keeps its scanned slice: a stack handed whole would make every
+layer's weight gradient the size of the stack.
+
+Two kernels run the grouped products, one a path. The trained layer's
+are `lax.ragged_dot`, which the TPU compiler turns into a grouped-matmul
+kernel of its own: thousands of rows a group, bound by the MXU, and it
+has the VJP training needs. (Megablox's Pallas `gmm` ran them a third
+faster on the v5e, but tracing its group metadata for every call adds
+2.4 to 4 s to a warm process's first step: PERF.md, PR 27.) A held
+share's are `ops.grouped_matmul`, a Pallas kernel tiled for what a
+served step gives it, one to three rows an expert in a decode step and
+tens to hundreds in a prefill, where the product is bound by reading
+the touched experts' matrices once: the compiler's kernel reads them at
+two fifths of memory speed and less (PERF.md, PR 37). It is forward
+only, and its group metadata is built once a layer for the layer's two
+or three products.
 
 On a mesh tokens stay on the chip that holds them: dispatch, the
 grouped matmuls and the combine run under `shard_map` over the batch
@@ -70,6 +80,7 @@ from ray_tpu.models.llama import (
     norm_all_heads,
     self_attention,
 )
+from ray_tpu.ops import grouped_matmul
 from ray_tpu.ops.norms import rms_norm_reference  # noqa: F401
 from ray_tpu.parallel.sharding import DEFAULT_RULES, logical_to_mesh_axes
 
@@ -283,18 +294,19 @@ def _expert_matrices(lp):
     return [lp[name] for name in _EXPERT_AXES if name in lp]
 
 
-def _grouped_experts(xs, group_sizes, we1, *rest):
+def _grouped_experts(xs, product, we1, *rest):
     """Rows in expert order through their experts: [R, D] -> [R, D].
-    `rest` is (we3, we2) of gated SiLU experts, (we2,) of relu^2 ones."""
+    `rest` is (we3, we2) of gated SiLU experts, (we2,) of relu^2 ones;
+    `product(rows, w)` is the path's grouped product of rows with their
+    experts' matrices among `w`."""
     *gate, we2 = rest
     with jax.named_scope("expert_matmul"):
-        hidden = lax.ragged_dot(xs, we1, group_sizes)      # [R, F]
+        hidden = product(xs, we1)                          # [R, F]
         if gate:
-            hidden = jax.nn.silu(hidden) \
-                * lax.ragged_dot(xs, gate[0], group_sizes)
+            hidden = jax.nn.silu(hidden) * product(xs, gate[0])
         else:
             hidden = _relu2(hidden)
-        return lax.ragged_dot(hidden, we2, group_sizes)    # [R, D]
+        return product(hidden, we2)                        # [R, D]
 
 
 def _sparse_experts(x, gates, top_i, we1, *rest):
@@ -305,7 +317,9 @@ def _sparse_experts(x, gates, top_i, we1, *rest):
         inv = jnp.argsort(order)
         group_sizes = _expert_counts(top_i, we1.shape[0])
         xs = _spread(x, order, inv)                        # [T*k, D]
-    ys = _grouped_experts(xs, group_sizes, we1, *rest)
+    ys = _grouped_experts(
+        xs, functools.partial(lax.ragged_dot, group_sizes=group_sizes),
+        we1, *rest)
     with jax.named_scope("moe_combine"):
         return _collect(ys, gates, order, inv)
 
@@ -323,12 +337,15 @@ def _held_experts(cfg: MoEConfig, x, gates, top_i, layer, *stacks):
     where they lie: `stacks` are the expert matrices of a run of layers,
     each [layers, experts held, ...], and `layer` (an int32 scalar, as
     `decoder.layers` counts) says whose turn it is. The grouped products
-    take a stack with its two leading axes read as one, layers x
-    experts groups, all of them empty but this layer's: an empty group
-    takes no rows and its matrix is not read, so the products cost what
-    the layer's own would, and nothing slices the layer out of the
-    stack first (that slice was a copy of every held expert's weights,
-    a layer, ahead of a kernel that takes no fused operand).
+    are `ops.grouped_matmul`'s, not the trained path's `lax.ragged_dot`:
+    its kernel picks (layer, expert) out of the stack as it fetches a
+    matrix, so nothing slices the layer out of the stack first (that
+    slice was a copy of every held expert's weights, a layer), an
+    expert no pair fell on is not read, and a touched one is read once,
+    at memory speed, for its one to three rows of a decode step (the
+    compiler's kernel, tiled for training's groups, took 2.5 times as
+    long: PERF.md, PR 37). The groups' metadata is built here, once a
+    buffer, for the layer's two or three products.
 
     The pairs are sorted held experts first, and the held ones go
     through the grouped products a buffer of `rows` rows at a time
@@ -345,8 +362,6 @@ def _held_experts(cfg: MoEConfig, x, gates, top_i, layer, *stacks):
     rows = min(pairs, max(
         _HELD_ROWS_MIN,
         -(-_HELD_ROWS_SLACK * pairs * count // cfg.n_experts)))
-    n_groups = stacks[0].shape[0] * count
-    ws = [w.reshape((n_groups,) + w.shape[2:]) for w in stacks]
     with jax.named_scope("moe_dispatch"):
         local = top_i.reshape(-1) - first
         local = jnp.where((local >= 0) & (local < count), local, count)
@@ -363,15 +378,14 @@ def _held_experts(cfg: MoEConfig, x, gates, top_i, layer, *stacks):
         with jax.named_scope("moe_dispatch"):
             pair = lax.dynamic_slice_in_dim(order, lo, rows)
             token = pair // k
-            # Each expert's rows inside [lo, lo + rows), at this
-            # layer's place among the groups of the whole run.
+            # Each expert's rows inside [lo, lo + rows).
             inside = jnp.clip(ends, lo, lo + rows)
-            group_sizes = lax.dynamic_update_slice_in_dim(
-                jnp.zeros(n_groups, inside.dtype),
-                jnp.diff(inside, prepend=lo), layer * count, 0)
+            groups = grouped_matmul.plan(jnp.diff(inside, prepend=lo), rows)
             held = jnp.arange(rows) < n_held - lo
             xs = _rows(x, token)                           # [rows, D]
-        ys = _grouped_experts(xs, group_sizes, *ws)
+        ys = _grouped_experts(
+            xs, functools.partial(grouped_matmul.grouped_matmul,
+                                  layer=layer, groups=groups), *stacks)
         with jax.named_scope("moe_combine"):
             # Rows past the held pairs belong to no group: whatever the
             # grouped product left there counts nothing.

@@ -10,6 +10,9 @@ on CPU and in interpret-mode tests):
 - ``rope``          — rotary position embeddings
 - ``cross_entropy`` — blockwise softmax cross-entropy (no full-vocab
                       probability materialization)
+- ``grouped_matmul`` — a served share of experts' grouped products, the
+                      matrices read where they lie in a run's stack
+                      (``lax.ragged_dot`` off the TPU)
 """
 
 from ray_tpu.ops.attention import flash_attention  # noqa: F401

@@ -266,17 +266,26 @@ def _share_by_hand(cfg, lp, h):
     return moe._add_shared_expert(cfg, lp, h, out)
 
 
+@pytest.mark.parametrize("products", ["ragged_dot", "kernel"])
 @pytest.mark.parametrize("crowded", [False, True], ids=["roomy", "crowded"])
 @pytest.mark.parametrize("kind", ["swiglu", "relu2"])
 def test_a_run_of_held_layers_reads_each_layers_experts_in_the_stack(
-        kind, crowded, monkeypatch):
+        kind, crowded, products, monkeypatch):
     """`decoder.layers` over three held expert layers, whose expert
     matrices ride whole: every layer's output is that of the layer's own
     slice (by hand, and through `_moe_ffn` given the slice), also where
-    the share takes several buffers and where no pair falls on it."""
+    the share takes several buffers and where no pair falls on it; with
+    the grouped products as they run off the TPU and as the kernel the
+    TPU runs, here through the Pallas interpreter."""
+    import functools
+
     from ray_tpu.models import decoder, moe
+    from ray_tpu.ops import grouped_matmul
     from ray_tpu.ops.norms import rms_norm_reference
 
+    if products == "kernel":
+        monkeypatch.setattr(grouped_matmul, "plan", functools.partial(
+            grouped_matmul.plan, interpret=True))
     if crowded:
         monkeypatch.setattr(moe, "_HELD_ROWS_MIN", 4)
         monkeypatch.setattr(moe, "_HELD_ROWS_SLACK", 0)
@@ -373,18 +382,24 @@ def _served_engine(family):
     return LLMEngine(cfg, params, max_batch_size=2, max_seq_len=32), shapes
 
 
+@pytest.mark.parametrize("products", ["ragged_dot", "kernel"])
 @pytest.mark.parametrize("family", ["glm_dsa", "nemotron_h"])
 def test_no_served_program_slices_expert_matrices_out_of_their_stack(
-        family, monkeypatch):
+        family, products, monkeypatch):
     """The decode and the prefill program of a model that holds a share
     of the experts: no layer scan has an expert matrix among its scanned
     operands and nothing slices one out of the run's stack, so the
     grouped products read the stack itself (a scanned slice is a copy of
-    every held expert's weights, a layer: PERF.md, PR 35). The same
-    reading finds the copies in the program as it was, the FFN naming
-    nothing to stay whole."""
+    every held expert's weights, a layer: PERF.md, PR 35), as
+    `lax.ragged_dot` off the TPU and as the held path's Pallas kernel on
+    it (the test says which backend it is on; nothing is lowered). The
+    same reading finds the copies in the program as it was, the FFN
+    naming nothing to stay whole."""
     from ray_tpu.models import moe
+    from ray_tpu.ops import grouped_matmul
 
+    if products == "kernel":
+        monkeypatch.setattr(grouped_matmul, "on_tpu", lambda: True)
     eng, shapes = _served_engine(family)
     n = eng.n_slots
     ints = jnp.zeros(n, jnp.int32)
@@ -400,12 +415,21 @@ def test_no_served_program_slices_expert_matrices_out_of_their_stack(
 
     for name, program in programs().items():
         assert _expert_copies(program.jaxpr, shapes) == [], name
-        # The grouped products take the run's stack, layers x experts
-        # groups of it.
-        groups = {eqn.invars[1].aval.shape[0]
-                  for sub in _subjaxprs(program.jaxpr) for eqn in sub.eqns
+        eqns = [eqn for sub in _subjaxprs(program.jaxpr) for eqn in sub.eqns]
+        ragged = {eqn.invars[1].aval.shape[0] for eqn in eqns
                   if eqn.primitive.name == "ragged_dot_general"}
-        assert max(groups) > eng.cfg.n_experts_held, (name, groups)
+        if products == "kernel":
+            # The kernel takes the run's stack as it lies, [layers,
+            # experts held, K, N], and no other grouped product runs.
+            stacks = {v.aval.shape for eqn in eqns
+                      if eqn.primitive.name == "pallas_call"
+                      for v in eqn.invars if v.aval.ndim == 4}
+            assert not ragged and stacks, (name, ragged)
+            assert {s[1:] for s in stacks} == shapes, (name, stacks)
+            assert max(s[0] for s in stacks) > 1, (name, stacks)
+        else:
+            # `lax.ragged_dot` takes it as layers x experts groups.
+            assert max(ragged) > eng.cfg.n_experts_held, (name, ragged)
 
     served = moe.served_ffn
 

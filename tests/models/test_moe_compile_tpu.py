@@ -68,19 +68,23 @@ def test_expert_layer_compiles_for_the_v5e(one_chip, tokens, k, d, f, e):
     ("nemotron-3-super-serve", "prefill", 2048, 10.9e9, 2),
 ])
 def test_served_step_compiles_for_the_v5e(one_chip, name, program, bucket,
-                                          resident, products):
+                                          resident, products, monkeypatch):
     """A served share as its file under `benchmark/configs/` cuts it,
     through the engine's own decode and prefill programs at its cell's
     slots and the cell's largest bucket: it compiles, the held experts
-    run through the grouped kernel where their matrices lie, and the
-    program fits beside nothing else. GLM-5.2 at 16 slots x 16,384; Nemotron 3 Super at 64 x 4,096,
+    run through their own grouped kernel (`ops/grouped_matmul.py`'s
+    `gmm`, which this process's CPU backend would not choose: the test
+    says it is on a TPU) where their matrices lie, and the program fits
+    beside nothing else. GLM-5.2 at 16 slots x 16,384; Nemotron 3 Super at 64 x 4,096,
     whose Mamba-2 state rides the same carry as leaves with no sequence
     axis. `products`: the grouped products an expert layer has, three of
     a gated SwiGLU, two of relu^2."""
     from benchmark.harness.manifest import ROOT, load_json, model_adapter
     from ray_tpu.models.serving import served_model
+    from ray_tpu.ops import grouped_matmul
     from ray_tpu.serve.llm import LLMEngine
 
+    monkeypatch.setattr(grouped_matmul, "on_tpu", lambda: True)
     config = load_json(ROOT, "benchmark", "configs", name + ".json")
     model = model_adapter(config)
     cfg = model.program_config(config)
@@ -114,7 +118,11 @@ def test_served_step_compiles_for_the_v5e(one_chip, name, program, bucket,
             bucket).compile()
     text = compiled.as_text()
     kernels = re.findall(r"%([\w-]+?)(?:\.\d+)? = \S+ custom-call\(", text)
-    assert sum(k in PRODUCTS for k in kernels) >= products
+    # Each run of expert layers is one scan, whose body holds the
+    # layer's products once: the held path's kernel and no other.
+    runs = sum("we1" in run for run in params["runs"])
+    assert [k for k in kernels if k in PRODUCTS] == ["gmm"] * (products * runs)
+    assert "ragged-dot" not in text
     # The grouped products read a layer's experts in the run's stack:
     # no op makes an array of one layer's expert matrices (the layer
     # scan's slice of them was a copy of 403 and 704 MB a matrix and
