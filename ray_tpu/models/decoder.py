@@ -1,9 +1,14 @@
 """The decoder stack that every architecture under `models/` composes.
 
-A block is norm, *sequence mixer*, residual, norm, *FFN*, residual,
-either half optional (`block`, the only place that opens the mixer's
-scope, `attn` unless the mixer names its kind, and `mlp`). What
-differs between architectures is handed in as two functions:
+A block has one of two forms (`block`, the only place that opens the
+mixer's scope, `attn` unless the mixer names its kind, and `mlp`).
+Sequential, the default: norm, *sequence mixer*, residual, norm, *FFN*,
+residual, either half optional. Parallel (`cfg.parallel_block`): one
+norm, whose output both halves read, and one residual that takes the
+sum of the two. The norm is RMSNorm unless `cfg.norm_kind` is "layer"
+(the mean taken off, a weight, no bias), the final norm with it. What
+differs between architectures beyond that is handed in as two
+functions:
 
 - ``mixer(h, lp, rope, state, handed) -> (attn [B, S, H, K], state,
   handed)``: normed activations and the layer's parameters to the
@@ -43,7 +48,7 @@ from jax import lax
 
 from ray_tpu.ops.cross_entropy import (fused_linear_cross_entropy,
                                        softmax_cross_entropy)
-from ray_tpu.ops.norms import rms_norm_reference
+from ray_tpu.ops.norms import layer_norm, rms_norm_reference
 from ray_tpu.ops.rope import rope_frequencies, rope_from_positions
 from ray_tpu.parallel.sharding import (
     DEFAULT_RULES,
@@ -89,29 +94,45 @@ def init_params_sharded(init, axes, mesh, rng, rules=DEFAULT_RULES):
         rng)
 
 
+def norm(cfg, x, weight):
+    """The stack's norm, by `cfg.norm_kind`: "rms", or "layer", a
+    LayerNorm with a weight and no bias."""
+    if cfg.norm_kind == "layer":
+        return layer_norm(x, weight, None, cfg.norm_eps)
+    assert cfg.norm_kind == "rms", cfg.norm_kind
+    return rms_norm_reference(x, weight, cfg.norm_eps)
+
+
 def block(mixer, ffn, cfg, rope, x, lp, state=None, handed=None, *,
           mesh=None, rules=DEFAULT_RULES):
     """One block. x: [B, S, D] -> (x, state, extras, handed). Either
     half may be absent (`mixer` or `ffn` None: a stack whose layers are
     a mixer or an FFN alone); the block is then the other half, norm,
-    part, residual. The two halves are scoped so that a device trace can
-    tell their ops apart: the FFN `mlp`, the mixer by its kind, which is
-    `attn` unless the mixer says otherwise (its attribute `scope`: a
-    state-space mixer is no attention). `state` goes to the mixer as it
-    is and comes back as the mixer returns it; `handed` is what the
-    layer below handed up beside x, None in most stacks."""
+    part, residual. With `cfg.parallel_block` both halves are there and
+    read the one norm's output (`attn_norm`), and x takes their sum. The
+    two halves are scoped so that a device trace can tell their ops
+    apart: the FFN `mlp`, the mixer by its kind, which is `attn` unless
+    the mixer says otherwise (its attribute `scope`: a state-space mixer
+    is no attention). `state` goes to the mixer as it is and comes back
+    as the mixer returns it; `handed` is what the layer below handed up
+    beside x, None in most stacks."""
     extras = None
+    parallel = cfg.parallel_block
+    assert not parallel or (mixer is not None and ffn is not None)
     if mixer is not None:
         with jax.named_scope(getattr(mixer, "scope", "attn")):
-            h = rms_norm_reference(x, lp["attn_norm"], cfg.norm_eps)
+            h = norm(cfg, x, lp["attn_norm"])
             attn, state, handed = mixer(h, lp, rope, state, handed)
-            x = x + jnp.einsum("bshk,hkd->bsd", attn.astype(cfg.dtype),
+            mixed = jnp.einsum("bshk,hkd->bsd", attn.astype(cfg.dtype),
                                lp["wo"])
+            if not parallel:
+                x = x + mixed
     if ffn is not None:
         with jax.named_scope("mlp"):
-            h = rms_norm_reference(x, lp["mlp_norm"], cfg.norm_eps)
+            if not parallel:
+                h = norm(cfg, x, lp["mlp_norm"])
             out, extras = ffn(h, lp)
-            x = x + out
+            x = x + mixed + out if parallel else x + out
     x = with_logical_constraint(x, "batch", "seq", "act_embed",
                                 mesh=mesh, rules=rules)
     return x, state, extras, handed
@@ -262,8 +283,7 @@ def hidden_runs(params, tokens, cfg, runs, *, handed=None, mesh=None,
             mesh=mesh, rules=rules)
         states.append(state)
         extras.append(run_extras)
-    return (rms_norm_reference(x, params["final_norm"], cfg.norm_eps),
-            states, extras)
+    return norm(cfg, x, params["final_norm"]), states, extras
 
 
 def hidden(params, tokens, cfg, mixer, ffn, *, mesh=None,

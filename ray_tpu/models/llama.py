@@ -63,6 +63,12 @@ class LlamaConfig:
     # RMSNorm with a learned weight over the whole projected q and k
     # vectors (all heads together), before rope (OLMoE).
     qk_norm: bool = False
+    # What `decoder.block` is: "rms" or "layer" (the mean taken off, a
+    # weight, no bias) for its norms, and whether mixer and FFN both
+    # read one norm's output and are added to x together (Cohere's
+    # parallel block) or follow each other, each behind its own norm.
+    norm_kind: str = "rms"
+    parallel_block: bool = False
 
     @property
     def head_dim(self) -> int:
@@ -378,8 +384,16 @@ def _cached_attention(cfg, q, k_cache, v_cache, q_positions):
     KV head are contracted together (a group of one for MHA), so no
     array of `rep` x cache size and none of cache size in float32
     exists. Scores, mask, softmax and both accumulations are float32;
-    `probs` is cast to the cache's dtype for the P.V product. Holds for
-    any (B, T): decode (T = 1), prefill (B = 1) and in between.
+    `probs` is cast to the cache's dtype for the P.V product. Right for
+    any (B, T), decode (T = 1), prefill (B = 1) and in between, but the
+    scores are one dense [B, H, T, S] float32 array over the slot's
+    whole region S = max_seq: 4 B x H x T x S bytes, 2.1 GB at 32 heads
+    of a 1,024-token prefill against 16,384 rows and 69 GB at 128 heads
+    of 8,192 tokens, so a chip's 16 GB hold it up to about T x S = 2^24
+    at 32 heads. A long-context family goes through it a block of
+    queries at a time (`nemotron_h._attention`) or uses
+    `cohere2_moe._attend`, which also visits only the blocks of keys a
+    row can see.
 
     The benchmark's tests rely on this function's name and signature
     (`tests/benchmark/test_references.py` patches it), on the cache

@@ -5,9 +5,10 @@ run through it: Mixtral-8x7B (8 experts, 2 a token, gates renormalised
 over the chosen) and OLMoE-1B-7B (64 experts, 8 a token, gates as the
 softmax gives them, RMSNorm on the projected q and k); `MoEConfig`
 holds what they differ in, and what the served families add: a
-sigmoid router with a selection bias, a shared expert, a held share of
-the experts, experts that are relu^2 with two matrices, and a latent
-the routed experts work in (`expert_kind`, `latent_dim`).
+sigmoid router with a selection bias, a shared expert (or several,
+summed or averaged, fused into one), a held share of the experts,
+experts that are relu^2 with two matrices, and a latent the routed
+experts work in (`expert_kind`, `latent_dim`).
 
 The expert layer is dropless sparse dispatch (`_moe_ffn`): float32
 softmax over the router's logits, `lax.top_k` (exactly k experts a
@@ -107,8 +108,14 @@ class MoEConfig(LlamaConfig):
     # The gates are multiplied by this, after `norm_topk_prob`.
     gate_scale: float = 1.0
     # Width of a SwiGLU expert that every token passes through beside
-    # its k (leaves `ws1`, `ws3`, `ws2`); 0: none.
+    # its k (leaves `ws1`, `ws3`, `ws2`); 0: none. Several shared
+    # experts are one of their summed width, `n_shared_experts` side by
+    # side in the leaves' columns (one pair of matmuls, not one a
+    # shared expert): its output is their sum, or with
+    # `shared_combination` "average" their mean (Command A+).
     shared_hidden_dim: int = 0
+    n_shared_experts: int = 1
+    shared_combination: str = "sum"
     # (first, count): the contiguous range of the `n_experts` experts
     # whose weights this program holds, the share of one chip of a
     # deployment that spreads a layer's experts over several. The router
@@ -508,9 +515,13 @@ def _moe_ffn(cfg: MoEConfig, lp, x, mesh, rules, stacks=None):
 
 def _add_shared_expert(cfg: MoEConfig, lp, x, out):
     """`out` plus the expert every token passes through at the full
-    width, of the config's `expert_kind`, where the config has one."""
+    width, of the config's `expert_kind`, where the config has one:
+    `n_shared_experts` of them fused (their hidden units side by side,
+    so the down-projection sums them), the sum divided by their number
+    where the config averages them."""
     if not cfg.shared_hidden_dim:
         return out
+    assert cfg.shared_combination in ("sum", "average"), cfg
     with jax.named_scope("shared_expert"):
         hidden = jnp.einsum("bsd,df->bsf", x, lp["ws1"])
         if cfg.expert_kind == "swiglu":
@@ -518,7 +529,10 @@ def _add_shared_expert(cfg: MoEConfig, lp, x, out):
                 * jnp.einsum("bsd,df->bsf", x, lp["ws3"])
         else:
             hidden = _relu2(hidden)
-        return out + jnp.einsum("bsf,fd->bsd", hidden, lp["ws2"])
+        shared = jnp.einsum("bsf,fd->bsd", hidden, lp["ws2"])
+        if cfg.shared_combination == "average":
+            shared = shared / cfg.n_shared_experts
+        return out + shared
 
 
 def _gather_whole(w, spec):

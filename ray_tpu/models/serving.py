@@ -21,15 +21,25 @@ through the slot cache. The engine knows no model; it takes from here
   that starts at position 0 starts from zeros whatever the leaf held,
   and what is left is the state after position `at` and no later (a
   prefill's bucket padding must not enter it); the engine only slices
-  its slots. All leaves ride the model's layer scan as its carry
-  (`decoder.layers`), so a cache that is donated to the program is
-  updated where it lies;
+  its slots. A *ring* is a state leaf too, though it has an axis of
+  rows: [layers_i, slots, window, ...], the keys and values of a
+  windowed layer, position p in row p mod window whatever `max_seq`
+  is (`cohere2_moe`). Which position a row holds follows from the
+  slot's length, which the model is handed as `start_pos`, so the
+  engine need not clear a ring between two requests; but a row is no
+  position the engine could name, so it may not read, write or copy
+  blocks of a ring's rows, nor size anything by them, and what holds
+  for a recurrent state holds for a ring: `forward` leaves in it the
+  rows up to `at` and none of the padding after. All leaves ride the
+  model's layer scan as its carry (`decoder.layers`), so a cache that
+  is donated to the program is updated where it lies;
 - ``state_leaves(cache) -> the same structure of bools``: True at a
   state leaf; all rows unless the model says otherwise. A model with a
   state leaf is served with no prefix cache: the prefix cache holds
-  blocks of rows, and a recurrent layer cannot resume from a block of
-  rows, only from a snapshot of its state at the block's boundary,
-  which nothing takes yet;
+  blocks of rows, and neither a recurrent layer nor a ring can resume
+  from a block of rows, only from a snapshot of its state at the
+  block's boundary (for a ring: the window's worth of rows that end
+  there), which nothing takes yet;
 - ``keys_attended(cfg, lengths) -> per row``: of `lengths` cached keys
   (host integers) how many the next token attends: all of them, unless
   the model selects keys.
@@ -81,10 +91,17 @@ def _nemotron_h():
                        state_leaves=nemotron_h.state_leaves)
 
 
+def _cohere2_moe():
+    from ray_tpu.models import cohere2_moe
+    return ServedModel(cohere2_moe.forward, cohere2_moe.init_cache,
+                       cohere2_moe.keys_attended, cohere2_moe.state_leaves)
+
+
 # By the config's own type, not its bases: `MoEConfig` is a
 # `LlamaConfig` and has no cached forward pass.
 _SERVED = {"LlamaConfig": _llama, "GlmDsaConfig": _glm_dsa,
-           "NemotronHConfig": _nemotron_h}
+           "NemotronHConfig": _nemotron_h,
+           "Cohere2MoeConfig": _cohere2_moe}
 
 
 def served_model(cfg) -> ServedModel:

@@ -8,6 +8,8 @@ kernel (grid over q-blocks, inner k sweep) — the [Sq, Sk] score matrix
 never materialises in HBM in either direction. Long-context training
 memory is additionally handled one level up by ring attention
 (`ray_tpu.parallel.ring_attention`), which only ever sees per-chunk blocks.
+The forward kernel alone also takes a sliding window and can leave the
+logsumexp unwritten (`flash_attention_forward`, a served prefill's).
 
 Layout: public API takes [batch, seq, heads, head_dim] (matching the rest
 of the framework); the kernel runs in [batch, heads, seq, head_dim]. GQA is
@@ -49,7 +51,11 @@ def on_tpu() -> bool:
 def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
                       m_ref, l_ref, acc_ref, *,
                       sm_scale: float, causal: bool,
-                      block_q: int, block_k: int, sk: int):
+                      block_q: int, block_k: int, sk: int,
+                      window: Optional[int] = None):
+    """`window` (with `causal`): a row sees that many keys, itself the
+    last of them. `lse_ref` is None where no backward pass will ask for
+    the logsumexp (`flash_attention_forward`)."""
     iq = pl.program_id(2)
     ik = pl.program_id(3)
     nk = pl.num_programs(3)
@@ -64,6 +70,10 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
     should_compute = True
     if causal:
         should_compute = (iq + 1) * block_q > ik * block_k
+    if window is not None:
+        # Nor do blocks whose newest key lies a window or more behind
+        # the block's first row.
+        should_compute &= iq * block_q - ((ik + 1) * block_k - 1) < window
 
     # Ragged last k-block (sk % block_k != 0): the padded columns hold
     # undefined memory and must not feed the online softmax. Statically
@@ -101,6 +111,8 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
                 mask = rows >= cols
             else:
                 mask = cols < sk
+            if window is not None:
+                mask &= rows - cols < window
             s = jnp.where(mask, s, _NEG_INF)
 
         m_prev = m_ref[:]                         # [bq, 128], lanes equal
@@ -131,6 +143,9 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
         # for the iota/compare/select passes; blocks fully below the
         # diagonal — most of the sweep for long sequences — skip them.
         needs_mask = iq * block_q < (ik + 1) * block_k - 1
+        if window is not None:
+            # ... and those the window's far edge crosses.
+            needs_mask |= (iq + 1) * block_q - 1 - ik * block_k >= window
         if pad_cols:
             needs_mask = needs_mask | (ik == nk - 1)
         pl.when(should_compute & needs_mask)(lambda: compute(True))
@@ -143,15 +158,18 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
         o_ref[0, 0] = (acc_ref[:] / l[:, :1]).astype(o_ref.dtype)
         # Per-row logsumexp (lane-broadcast), consumed by the backward
         # kernels to recompute p = exp(s - lse) per tile.
-        lse_ref[0, 0] = m_ref[:] + jnp.log(l)
+        if lse_ref is not None:
+            lse_ref[0, 0] = m_ref[:] + jnp.log(l)
 
 
 def _flash_fwd(q, k, v, causal: bool, sm_scale: float,
-               block_q: int, block_k: int, interpret: bool):
+               block_q: int, block_k: int, interpret: bool,
+               window: Optional[int] = None, with_lse: bool = True):
     """q: [B, H, S, D]; k/v: [B, Hkv, Sk, D] (already transposed).
 
     Returns ``(o, lse)`` where ``lse`` is the per-row logsumexp with shape
-    ``[B, H, Sq]`` (float32), needed by the Pallas backward.
+    ``[B, H, Sq]`` (float32), needed by the Pallas backward; None without
+    `with_lse`, and then the kernel writes none.
     """
     b, h, sq, d = q.shape
     _, h_kv, sk, _ = k.shape
@@ -165,19 +183,28 @@ def _flash_fwd(q, k, v, causal: bool, sm_scale: float,
             # clamp their fetch index to the diagonal block so the pipeline
             # doesn't stream K/V tiles that are never read.
             ik = jnp.minimum(ik, ((iq + 1) * block_q - 1) // block_k)
+        if window is not None:
+            # Nor those wholly behind the window of the block's first row.
+            ik = jnp.maximum(ik, (iq * block_q - window + 1) // block_k)
         return (ib, ih * h_kv // h, ik, 0)
 
     kernel = functools.partial(
         _flash_fwd_kernel, sm_scale=sm_scale, causal=causal,
-        block_q=block_q, block_k=block_k, sk=sk,
+        block_q=block_q, block_k=block_k, sk=sk, window=window,
     )
+    if not with_lse:
+        with_out = kernel
+
+        def kernel(q_ref, k_ref, v_ref, o_ref, *scratch):
+            with_out(q_ref, k_ref, v_ref, o_ref, None, *scratch)
     scratch_shapes = [
         pltpu.VMEM((block_q, 128), jnp.float32),  # m
         pltpu.VMEM((block_q, 128), jnp.float32),  # l
         pltpu.VMEM((block_q, d), jnp.float32),    # acc
     ]
 
-    o, lse = pl.pallas_call(
+    n_out = 2 if with_lse else 1
+    o, *lse = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
@@ -191,17 +218,17 @@ def _flash_fwd(q, k, v, causal: bool, sm_scale: float,
                          lambda ib, ih, iq, ik: (ib, ih, iq, 0)),
             pl.BlockSpec((1, 1, block_q, 128),
                          lambda ib, ih, iq, ik: (ib, ih, iq, 0)),
-        ],
+        ][:n_out],
         out_shape=[
             jax.ShapeDtypeStruct((b, h, sq, d), q.dtype),
             jax.ShapeDtypeStruct((b, h, sq, 128), jnp.float32),
-        ],
+        ][:n_out],
         scratch_shapes=scratch_shapes,
         compiler_params=_COMPILER_PARAMS,
         interpret=interpret,
         name="flash_fwd",
     )(q, k, v)
-    return o, lse[..., 0]
+    return o, lse[0][..., 0] if with_lse else None
 
 
 # ---------------------------------------------------------------------------
@@ -509,8 +536,9 @@ def _flash_bwd(q, k, v, o, lse, do, causal: bool, sm_scale: float,
 # ---------------------------------------------------------------------------
 
 
-def attention_reference(q, k, v, causal: bool, sm_scale: float):
-    """[B, H, S, D] layout. GQA-aware."""
+def attention_reference(q, k, v, causal: bool, sm_scale: float,
+                        window: Optional[int] = None):
+    """[B, H, S, D] layout. GQA-aware. `window` as the kernel's."""
     b, h, sq, d = q.shape
     h_kv = k.shape[1]
     if h_kv != h:
@@ -521,7 +549,8 @@ def attention_reference(q, k, v, causal: bool, sm_scale: float):
                    preferred_element_type=jnp.float32) * sm_scale
     if causal:
         sk = k.shape[2]
-        mask = jnp.arange(sq)[:, None] >= jnp.arange(sk)[None, :]
+        back = jnp.arange(sq)[:, None] - jnp.arange(sk)[None, :]
+        mask = back >= 0 if window is None else (back >= 0) & (back < window)
         s = jnp.where(mask[None, None], s, _NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     return jnp.einsum("bhqk,bhkd->bhqd", p.astype(v.dtype), v,
@@ -585,4 +614,26 @@ def flash_attention(q, k, v, *, causal: bool = True,
         out = _flash(qt, kt, vt, causal, sm_scale, block_q, block_k, True)
     else:
         out = attention_reference(qt, kt, vt, causal, sm_scale)
+    return out.transpose(0, 2, 1, 3)
+
+
+def flash_attention_forward(q, k, v, *, window: Optional[int] = None,
+                            sm_scale: Optional[float] = None,
+                            block_q: int = 1024, block_k: int = 1024,
+                            interpret: bool = False):
+    """Causal attention with no backward pass, for a served prefill over
+    its own call's keys: `flash_attention`'s forward kernel, which here
+    writes no logsumexp, and with `window` a row sees that many keys and
+    no more, itself the last of them (the blocks of keys wholly behind a
+    block of queries' windows are neither read nor computed). Layout,
+    GQA and the choice between kernel, interpreter and reference as
+    `flash_attention`'s."""
+    if sm_scale is None:
+        sm_scale = q.shape[-1] ** -0.5
+    qt, kt, vt = (x.transpose(0, 2, 1, 3) for x in (q, k, v))
+    if on_tpu() or interpret:
+        out, _ = _flash_fwd(qt, kt, vt, True, sm_scale, block_q, block_k,
+                            not on_tpu(), window=window, with_lse=False)
+    else:
+        out = attention_reference(qt, kt, vt, True, sm_scale, window)
     return out.transpose(0, 2, 1, 3)

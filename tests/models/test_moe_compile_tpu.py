@@ -66,6 +66,8 @@ def test_expert_layer_compiles_for_the_v5e(one_chip, tokens, k, d, f, e):
     ("glm-5.2-serve", "prefill", 8192, 9.3e9, 3),
     ("nemotron-3-super-serve", "decode", 0, 10.9e9, 2),
     ("nemotron-3-super-serve", "prefill", 2048, 10.9e9, 2),
+    ("command-a-plus-serve", "decode", 0, 11.3e9, 3),
+    ("command-a-plus-serve", "prefill", 12288, 11.3e9, 3),
 ])
 def test_served_step_compiles_for_the_v5e(one_chip, name, program, bucket,
                                           resident, products, monkeypatch):
@@ -77,14 +79,16 @@ def test_served_step_compiles_for_the_v5e(one_chip, name, program, bucket,
     says it is on a TPU) where their matrices lie, and the program fits
     beside nothing else. GLM-5.2 at 16 slots x 16,384; Nemotron 3 Super at 64 x 4,096,
     whose Mamba-2 state rides the same carry as leaves with no sequence
-    axis. `products`: the grouped products an expert layer has, three of
+    axis; Command A+ at 16 x 16,384, whose sliding layers' rings of
+    4,096 rows ride it beside the full layer's rows. `products`: the grouped products an expert layer has, three of
     a gated SwiGLU, two of relu^2."""
     from benchmark.harness.manifest import ROOT, load_json, model_adapter
     from ray_tpu.models.serving import served_model
-    from ray_tpu.ops import grouped_matmul
+    from ray_tpu.ops import attention, grouped_matmul
     from ray_tpu.serve.llm import LLMEngine
 
     monkeypatch.setattr(grouped_matmul, "on_tpu", lambda: True)
+    monkeypatch.setattr(attention, "on_tpu", lambda: True)
     config = load_json(ROOT, "benchmark", "configs", name + ".json")
     model = model_adapter(config)
     cfg = model.program_config(config)
@@ -123,6 +127,11 @@ def test_served_step_compiles_for_the_v5e(one_chip, name, program, bucket,
     runs = sum("we1" in run for run in params["runs"])
     assert [k for k in kernels if k in PRODUCTS] == ["gmm"] * (products * runs)
     assert "ragged-dot" not in text
+    # A prefill of Command A+ holds the flash kernel for a call from
+    # position 0, once a run of like layers (windowed and full), beside
+    # the attention by blocks for any other call.
+    assert kernels.count("flash_fwd") == (
+        2 if (name, program) == ("command-a-plus-serve", "prefill") else 0)
     # The grouped products read a layer's experts in the run's stack:
     # no op makes an array of one layer's expert matrices (the layer
     # scan's slice of them was a copy of 403 and 704 MB a matrix and

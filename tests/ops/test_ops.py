@@ -182,3 +182,44 @@ def test_fused_linear_cross_entropy_matches_unfused(v, block):
                                rtol=1e-4, atol=1e-5)
     np.testing.assert_allclose(np.asarray(gw1), np.asarray(gw2),
                                rtol=1e-4, atol=1e-5)
+
+
+@pytest.mark.parametrize("s,h,h_kv,window,block", [
+    (256, 4, 4, 64, 64),     # the window a block long
+    (256, 4, 2, 100, 64),    # its far edge inside a block; grouped heads
+    (256, 2, 1, 8, 64),      # shorter than a block
+    (192, 2, 2, 1000, 64),   # longer than the sequence: plain causal
+    (128, 2, 2, 37, 128),    # one block of queries and of keys
+    (256, 2, 1, None, 64),   # no window
+])
+def test_flash_attention_forward_window(s, h, h_kv, window, block):
+    """The forward kernel with a window (a row sees `window` keys, itself
+    the last) and no logsumexp written, through the interpreter, against
+    the dense masked softmax; and the path a backend that is no TPU takes
+    without the interpreter, which is that reference."""
+    from ray_tpu.ops.attention import flash_attention_forward
+
+    d = 32
+    kq, kk, kv = jax.random.split(jax.random.PRNGKey(s + (window or 0)), 3)
+    q = jax.random.normal(kq, (2, s, h, d), jnp.float32)
+    k = jax.random.normal(kk, (2, s, h_kv, d), jnp.float32)
+    v = jax.random.normal(kv, (2, s, h_kv, d), jnp.float32)
+    back = np.arange(s)[:, None] - np.arange(s)[None, :]
+    seen = (back >= 0) & (back < (window or s))
+    scores = np.einsum("bqgrd,bkgd->bgrqk",
+                       np.asarray(q).reshape(2, s, h_kv, h // h_kv, d),
+                       np.asarray(k)) * d ** -0.5
+    scores = np.where(seen, scores, -np.inf)
+    probs = np.exp(scores - scores.max(-1, keepdims=True))
+    probs /= probs.sum(-1, keepdims=True)
+    want = np.einsum("bgrqk,bkgd->bqgrd", probs,
+                     np.asarray(v)).reshape(2, s, h, d)
+    got = flash_attention_forward(q, k, v, window=window, block_q=block,
+                                  block_k=block, interpret=True)
+    np.testing.assert_allclose(np.asarray(got), want, rtol=2e-5, atol=2e-6)
+    plain = flash_attention_forward(q, k, v, window=window)
+    np.testing.assert_allclose(np.asarray(plain), want, rtol=2e-5, atol=2e-6)
+    if window is not None and window < s:  # the window is not ignored
+        causal = flash_attention(q, k, v, block_q=block, block_k=block,
+                                 interpret=True)
+        assert np.abs(np.asarray(causal) - want).max() > 1e-2

@@ -7,7 +7,8 @@ name it had when GLM-5.2 was the one model it knew, which
 `glm-5.2-serve.json` cites. `FAMILIES` has, by the configuration's
 family, its faults, the faults a set of weights cannot show and the
 program's own initialiser; below, GLM-5.2's at length, then
-`nemotron_faults` for `nemotron-3-super-serve`.
+`nemotron_faults` for `nemotron-3-super-serve` and `cohere_faults` for
+`command-a-plus-serve`.
 
 Outside the benchmark and its timed window (PERF.md, PR 32, has the
 readings). For each seed, what `benchmark/runners/serve.py`'s
@@ -84,6 +85,26 @@ def cut(x, bits):
     return jax.lax.bitcast_convert_type(u, x.dtype)
 
 
+def _with_cfg(forward, **changes):
+    """`forward` under a config with `changes`."""
+    def served(params, tokens, cfg, cache, start_pos):
+        return forward(params, tokens, dataclasses.replace(cfg, **changes),
+                       cache, start_pos)
+    return served
+
+
+def _patched(forward, module, name, other):
+    """`forward` while `module.name` is `other(what it was)`."""
+    def served(params, tokens, cfg, cache, start_pos):
+        real = getattr(module, name)
+        setattr(module, name, other(real))
+        try:
+            return forward(params, tokens, cfg, cache, start_pos)
+        finally:
+            setattr(module, name, real)
+    return served
+
+
 def faults(forward, init_cache):
     """{name: served}: `forward` (the program's cached forward pass,
     `(params, tokens, cfg, cache, start_pos)`) with one fault each, as
@@ -94,12 +115,7 @@ def faults(forward, init_cache):
 
     from ray_tpu.models import moe
 
-    def with_cfg(**changes):
-        def served(params, tokens, cfg, cache, start_pos):
-            return forward(params, tokens,
-                           dataclasses.replace(cfg, **changes), cache,
-                           start_pos)
-        return served
+    with_cfg = functools.partial(_with_cfg, forward)
 
     def lower_precision(params, tokens, cfg, cache, start_pos):
         # bfloat16 keeps 7 mantissa bits and float32 23, e4m3 keeps 3.
@@ -206,15 +222,7 @@ def nemotron_faults(forward, init_cache):
                            start_pos)
         return served
 
-    def patched(module, name, other):
-        def served(params, tokens, cfg, cache, start_pos):
-            real = getattr(module, name)
-            setattr(module, name, other(real))
-            try:
-                return forward(params, tokens, cfg, cache, start_pos)
-            finally:
-                setattr(module, name, real)
-        return served
+    patched = functools.partial(_patched, forward)
 
     def norm_first(real):
         def gated_norm(cfg, y, z, weight):
@@ -262,6 +270,52 @@ def nemotron_faults(forward, init_cache):
     }
 
 
+def cohere_faults(forward, init_cache):
+    """{name: served} for the family `cohere2_moe`, as `faults` for
+    GLM-5.2: the weights cut to float8 e4m3's mantissa, the window
+    ignored on the sliding layers (a prefill's rows attend every key
+    before them), rotary positions on the full layer too, the shared
+    experts summed and not averaged, a sequential block in place of the
+    parallel one (the expert layer behind a norm of its own, after
+    attention's residual), RMSNorm in place of LayerNorm, the chosen
+    gates left as the sigmoid gave them, and a prefill whose bucket
+    padding enters the rings."""
+    from ray_tpu.models import cohere2_moe
+
+    glm = faults(forward, init_cache)
+
+    with_cfg = functools.partial(_with_cfg, forward)
+
+    patched = functools.partial(_patched, forward, cohere2_moe)
+
+    def sequential_block(params, tokens, cfg, cache, start_pos):
+        runs = [{**run, "mlp_norm": run["attn_norm"]}
+                for run in params["runs"]]
+        return forward({**params, "runs": runs}, tokens,
+                       dataclasses.replace(cfg, parallel_block=False),
+                       cache, start_pos)
+
+    def to_the_end(real):
+        """The rings are left as after the call's last token, padding
+        and all."""
+        def forward_with_cache(params, tokens, cfg, cache, start_pos,
+                               at=None, keep=None):
+            return real(params, tokens, cfg, cache, start_pos, keep=keep)
+        return forward_with_cache
+
+    return {
+        "lower precision": glm["lower precision"],
+        "window ignored": with_cfg(sliding_window=1 << 30),
+        "rope on the full layer": patched(
+            "_ROTATED", lambda real: ("sliding", "full")),
+        "shared experts summed": with_cfg(shared_combination="sum"),
+        "sequential block": sequential_block,
+        "rms norm": with_cfg(norm_kind="rms"),
+        "gates not renormalised": with_cfg(norm_topk_prob=False),
+        "pad enters the ring": patched("forward_with_cache", to_the_end),
+    }
+
+
 # The faults a set of weights cannot show on the chip (each is seen at
 # the other; PERF.md section 6, PR 32, has the readings). The
 # benchmark's weights make the routed experts 32 times quieter, so what
@@ -282,6 +336,13 @@ NEMOTRON_UNSEEN = {
     "plain": ("state in bfloat16",)}
 
 
+# The same for `cohere2_moe` (PERF.md section 6, PR 39): nothing. At the
+# benchmark's weights even the one fault of the routed experts' gates
+# reads 4.7 % against the runner's 2 %; at the plain weights every
+# fault breaks the median or the 99th percentile.
+COHERE_UNSEEN = {"benchmark": (), "plain": ()}
+
+
 def _glm_init():
     from ray_tpu.models.glm_dsa import init_params
     return init_params
@@ -292,6 +353,11 @@ def _nemotron_init():
     return init_params
 
 
+def _cohere_init():
+    from ray_tpu.models.cohere2_moe import init_params
+    return init_params
+
+
 # By a configuration's family: its faults, the faults a set of weights
 # cannot show, the program's own initialiser (the plain weights), and
 # the prompt lengths of a rehearsal at debug widths.
@@ -299,6 +365,8 @@ FAMILIES = {
     "glm_dsa": (faults, UNSEEN, _glm_init, [40, 33, 26, 19]),
     "nemotron_h": (nemotron_faults, NEMOTRON_UNSEEN, _nemotron_init,
                    [45, 39, 26, 19]),
+    "cohere2_moe": (cohere_faults, COHERE_UNSEEN, _cohere_init,
+                    [45, 39, 26, 19]),
 }
 
 
