@@ -46,6 +46,19 @@ real rows: a decode step's one row in place, a prefill's rows up to
 `at` and none of its bucket's padding (row p + `sliding_window` lands
 on row p's place, and p is a real key inside the window).
 
+A decode step (one row a slot, a shape the code sees) has a seam that
+computes nothing: a `lax.optimization_barrier` between the three
+projections and the rotary turn. Without it the TPU's compiler gives
+the *weight* the layout that yields q already in pairs: on a `sliding`
+layer the step had `copy` -> `bf16[1,4096,128,128]{1,2,3,0}`, the
+layer's `wq` read and written anew, 134 MB each way, and 8.4 MB each of
+`wk` and `wv` after it, to spare q's 0.5 MB a turn of its own (1.4 ms
+of a 16.1 ms step; PERF.md, PR 40). With it the products read the
+weights as they lie and the step schedules no such `copy`
+(`tests/models/test_moe_compile_tpu.py` holds that). A prefill has no
+barrier: there q is the large array and the compiler's trade costs
+nothing that shows.
+
 Not here: the vision tower (not in the language model's config), an
 uncached forward pass and a loss (the model is served, not trained; a
 window in the trained path's backward kernels is ROADMAP M7).
@@ -323,6 +336,10 @@ def _mixer(cfg: Cohere2MoeConfig, kind, start_pos, positions, at):
         k = jnp.einsum("bsd,dhk->bshk", h, lp["wk"])
         v = jnp.einsum("bsd,dhk->bshk", h, lp["wv"]).astype(cached)
         if kind in _ROTATED:
+            if t == 1:
+                # The seam the module's docstring describes: the turn
+                # is q's and k's to pay for, not the weights'.
+                q, k, v = lax.optimization_barrier((q, k, v))
             q, k = _rotate_pairs(q, *rope), _rotate_pairs(k, *rope)
         q, k = q.astype(cached), k.astype(cached)
         rows = k_stack.shape[2]

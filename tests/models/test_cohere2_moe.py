@@ -13,6 +13,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax import lax
 
 from benchmark.harness.manifest import ROOT, load_json, model_adapter
 from benchmark.references import cohere2_moe as reference
@@ -210,6 +211,39 @@ def test_a_later_call_of_flash_size_still_attends_the_cache(
         params, tokens[:, 16:], CFG, cache, zeros + 16)
     np.testing.assert_allclose(first, want[:, :16], atol=LIMIT)
     np.testing.assert_allclose(rest, want[:, 16:], atol=LIMIT)
+
+
+@pytest.mark.parametrize("t", [1, 16], ids=["decode", "prefill"])
+def test_the_decode_steps_seam_moves_no_number(params, monkeypatch, t):
+    """A call of one row a slot goes through the seam, a barrier ahead
+    of the rotary turn on each `sliding` layer; a call of more is the
+    program it was. Both give what the parent's program gives, which is
+    this one with the barrier the identity: logits, rings, rows and
+    counts, from slots of unlike lengths whose rings another request
+    filled."""
+    lens = np.array([24, 13, 5])
+    tokens = _tokens((3, 24 + t))
+    cache = jax.tree.map(lambda x: x + 3.0,
+                         cohere2_moe.init_cache(CFG, 3, 64))
+    _, cache = cohere2_moe.forward_with_cache(
+        params, tokens[:, :24], CFG, cache, jnp.zeros(3, jnp.int32),
+        at=jnp.asarray(lens - 1))
+
+    def call():
+        return cohere2_moe.forward(params, tokens[:, 24:], CFG, cache,
+                                   jnp.asarray(lens, jnp.int32), t - 1)
+
+    def barriers():  # (a function of its own: a trace is kept by function)
+        return str(jax.make_jaxpr(lambda: call())()).count(
+            "optimization_barrier")
+
+    got, seams = call(), barriers()
+    assert seams == (1 if t == 1 else 0)  # one scan body of sliding layers
+    monkeypatch.setattr(lax, "optimization_barrier", lambda x: x)
+    want = call()
+    assert barriers() == 0
+    for a, b in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        np.testing.assert_allclose(a, b, atol=1e-6, rtol=0)
 
 
 def _expert_layer(seed=3):
