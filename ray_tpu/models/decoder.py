@@ -1,14 +1,16 @@
 """The decoder stack that every architecture under `models/` composes.
 
-A block has one of two forms (`block`, the only place that opens the
+A block has one of three forms (`block`, the only place that opens the
 mixer's scope, `attn` unless the mixer names its kind, and `mlp`).
 Sequential, the default: norm, *sequence mixer*, residual, norm, *FFN*,
 residual, either half optional. Parallel (`cfg.parallel_block`): one
 norm, whose output both halves read, and one residual that takes the
-sum of the two. The norm is RMSNorm unless `cfg.norm_kind` is "layer"
-(the mean taken off, a weight, no bias), the final norm with it. What
-differs between architectures beyond that is handed in as two
-functions:
+sum of the two. Sequential with `cfg.norm_placement` "output" (OLMo 2's
+arrangement): each half reads the stream as it is and its *output* is
+normed before the residual takes it. The norm is RMSNorm unless
+`cfg.norm_kind` is "layer" (the mean taken off, a weight, no bias), the
+final norm with it. What differs between architectures beyond that is
+handed in as two functions:
 
 - ``mixer(h, lp, rope, state, handed) -> (attn [B, S, H, K], state,
   handed)``: normed activations and the layer's parameters to the
@@ -109,7 +111,10 @@ def block(mixer, ffn, cfg, rope, x, lp, state=None, handed=None, *,
     half may be absent (`mixer` or `ffn` None: a stack whose layers are
     a mixer or an FFN alone); the block is then the other half, norm,
     part, residual. With `cfg.parallel_block` both halves are there and
-    read the one norm's output (`attn_norm`), and x takes their sum. The
+    read the one norm's output (`attn_norm`), and x takes their sum.
+    With `cfg.norm_placement` "output" a half reads x itself and its
+    output is normed (by the same leaves, `attn_norm` and `mlp_norm`)
+    before it is added. The
     two halves are scoped so that a device trace can tell their ops
     apart: the FFN `mlp`, the mixer by its kind, which is `attn` unless
     the mixer says otherwise (its attribute `scope`: a state-space mixer
@@ -118,20 +123,29 @@ def block(mixer, ffn, cfg, rope, x, lp, state=None, handed=None, *,
     beside x, None in most stacks."""
     extras = None
     parallel = cfg.parallel_block
-    assert not parallel or (mixer is not None and ffn is not None)
+    assert cfg.norm_placement in ("input", "output"), cfg.norm_placement
+    after = cfg.norm_placement == "output"
+    assert not parallel or (mixer is not None and ffn is not None
+                            and not after)
     if mixer is not None:
         with jax.named_scope(getattr(mixer, "scope", "attn")):
-            h = norm(cfg, x, lp["attn_norm"])
+            h = x if after else norm(cfg, x, lp["attn_norm"])
             attn, state, handed = mixer(h, lp, rope, state, handed)
             mixed = jnp.einsum("bshk,hkd->bsd", attn.astype(cfg.dtype),
                                lp["wo"])
+            if after:
+                mixed = norm(cfg, mixed, lp["attn_norm"])
             if not parallel:
                 x = x + mixed
     if ffn is not None:
         with jax.named_scope("mlp"):
-            if not parallel:
+            if after:
+                h = x
+            elif not parallel:
                 h = norm(cfg, x, lp["mlp_norm"])
             out, extras = ffn(h, lp)
+            if after:
+                out = norm(cfg, out, lp["mlp_norm"])
             x = x + mixed + out if parallel else x + out
     x = with_logical_constraint(x, "batch", "seq", "act_embed",
                                 mesh=mesh, rules=rules)

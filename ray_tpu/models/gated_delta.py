@@ -1,0 +1,321 @@
+"""The gated delta-rule sequence mixer (Gated DeltaNet, Yang, Kautz &
+Hatamizadeh 2024: linear attention whose state is corrected, not only
+added to) for `decoder.block`, served through the slot cache: the six
+projections, three causal depthwise convolutions, the recurrence on a
+matrix state a head and the gated norm of its output; the block applies
+the output projection (`wo`, [heads, value size, D]).
+
+On a layer's input a, H heads of dk key and dv value channels, K the
+convolution's width, float32 wherever the state is touched:
+
+    q~ = a wq   k~ = a wk   v~ = a wv   z = a wg   al = a wa   b = a wb
+    x_t <- silu(sum_j conv_x[:, j] x~_{t-K+1+j})   x in q, k, v; zeros before the start, no bias
+    q_t[h] = q_t[h] / |q_t[h]|_2 / sqrt(dk)        k_t[h] = k_t[h] / |k_t[h]|_2
+    gamma_t[h] = exp(-exp(A_log[h]) softplus(al_t[h] + dt_bias[h]))           in (0, 1)
+    beta_t[h]  = 2 sigmoid(b_t[h])                 (`allow_neg_eigval`; else sigmoid)
+    S_t[h] = gamma_t S_{t-1} + k_t (outer) beta_t (v_t - (gamma_t S_{t-1})^T k_t)     S [dk, dv]
+    o_t[h] = S_t^T q_t
+    out_t  = RMSNorm_dv(o_t[h]; o_norm) * silu(z_t[h])
+
+The transition gamma (I - beta k k^T) is no diagonal (Mamba-2's is a
+scalar a head, `mamba2._scan`), and with beta up to 2 it may have a
+negative eigenvalue. The norm comes before the gate here and after it
+in `mamba2._gated_norm`: two functions, not one with a switch.
+`mamba2._conv` serves the three convolutions (they have no bias, which
+it is told).
+
+What a sequence leaves behind is S [H, dk, dv] (`cfg.state_dtype`) and
+the last K - 1 un-convolved rows of q~, k~ and v~: four leaves of the
+slot cache with no sequence axis, [layers, slots, ...], rewritten whole
+at every call, and `mamba2`'s three rules hold of them: a row that
+starts at position 0 starts from zeros whatever its slot held; the
+state left is that after position `at` of the call's tokens and no
+later (padding past `at` takes beta = 0 and gamma = 1, which neither
+writes to the state nor decays it, and the carries are cut at `at`); a
+row that starts past 0 continues from its leaves.
+
+A call of one token is the recurrence as written, elementwise in
+float32 (scope `delta_update`). A longer one is the chunked form (scope
+`delta_scan`). With g_i the log decays summed from the chunk's start
+through i, G_i = exp(g_i), and u_j = beta_j (v_j - (gamma_j S_{j-1})^T
+k_j) the value position j really writes, the recurrence unrolls to
+S_i = G_i S_0 + sum_{j<=i} (G_i / G_j) k_j u_j^T; put into u_i's own
+definition it gives, a chunk of C rows at a time,
+
+    (I + A) U = beta V - (beta K * G) S_0,   A_ij = beta_i (k_i . k_j) G_i / G_j  (j < i)
+
+so with T = (I + A)^-1 (`_unit_lower_inverse`: forward substitution,
+which stays exact where keys repeat and beta is 2, as a Neumann series
+does not), W = T (beta K * G) and U' = T (beta V), all a chunk's own,
+the scan across chunks carries S in float32:
+
+    U = U' - W S       O = (Q * G) S + lower(Q K^T * G_i / G_j) U
+    S <- G_C S + (K * G_C / G_j)^T U
+
+Every ratio of decays is the exponential of a difference that is not
+positive, so a gamma near 0 underflows to the right limit. Products
+take their inputs in `cfg.dtype` and accumulate in float32. Forward
+only: no backward pass is written for the chunked scan.
+"""
+
+from __future__ import annotations
+
+import math
+from typing import Any, Dict
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+from ray_tpu.models import mamba2
+
+# Rows below which `_unit_lower_inverse` substitutes row by row.
+_INVERSE_BASE = 16
+_L2_EPS = 1e-6
+
+CONVS = ("conv_q", "conv_k", "conv_v")
+
+
+def init(cfg, key) -> Dict[str, Any]:
+    """One layer's leaves but the block's norms: the six projections,
+    the three convolutions, dt's bias, A, the output norm and `wo`.
+    `dt_bias`, `A_log` and the convolutions are drawn as `mamba2.init`
+    draws them (dt log-uniform in [0.001, 0.1], A uniform in [1, 16],
+    the convolutions uniform in +-K^-1/2)."""
+    d, h = cfg.dim, cfg.delta_heads
+    dk, dv, k = cfg.delta_key_dim, cfg.delta_value_dim, cfg.conv_kernel
+    ks = jax.random.split(key, 12)
+    init = mamba2.normal(0.02)
+    dt = jnp.exp(jax.random.uniform(ks[6], (h,), jnp.float32,
+                                    math.log(1e-3), math.log(1e-1)))
+    bound = k ** -0.5
+
+    def conv(key, width):
+        return jax.random.uniform(key, (width, k), jnp.float32, -bound,
+                                  bound).astype(cfg.dtype)
+
+    return {
+        "wq": init(ks[0], (d, h, dk), cfg.dtype),
+        "wk": init(ks[1], (d, h, dk), cfg.dtype),
+        "wv": init(ks[2], (d, h, dv), cfg.dtype),
+        "wg": init(ks[3], (d, h, dv), cfg.dtype),
+        "wa": init(ks[4], (d, h), cfg.dtype),
+        "wb": init(ks[5], (d, h), cfg.dtype),
+        # softplus(dt_bias) = dt
+        "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),
+        "A_log": jnp.log(jax.random.uniform(ks[7], (h,), jnp.float32, 1.0,
+                                            16.0)),
+        "conv_q": conv(ks[8], h * dk),
+        "conv_k": conv(ks[9], h * dk),
+        "conv_v": conv(ks[10], h * dv),
+        "o_norm": jnp.ones(dv, cfg.dtype),
+        "wo": init(ks[11], (h, dv, d), cfg.dtype) * d ** -0.5,
+    }
+
+
+def init_state(cfg, n_layers: int, n_slots: int) -> Dict[str, Any]:
+    """The four state leaves of a run of `n_layers` delta layers."""
+    h, dk, dv = cfg.delta_heads, cfg.delta_key_dim, cfg.delta_value_dim
+    rows = (n_layers, n_slots, cfg.conv_kernel - 1)
+    return {
+        "state": jnp.zeros((n_layers, n_slots, h, dk, dv), cfg.state_dtype),
+        "conv_q": jnp.zeros(rows + (h * dk,), cfg.dtype),
+        "conv_k": jnp.zeros(rows + (h * dk,), cfg.dtype),
+        "conv_v": jnp.zeros(rows + (h * dv,), cfg.dtype),
+    }
+
+
+LEAVES = ("state",) + CONVS
+
+
+def _l2_normalise(x):
+    """x [..., H, dk] over its last axis, in float32."""
+    x = x.astype(jnp.float32)
+    return x * lax.rsqrt((x * x).sum(-1, keepdims=True) + _L2_EPS)
+
+
+def _queries(q):
+    """Unit length a head, then 1 / sqrt(dk): q [..., H, dk] float32."""
+    return _l2_normalise(q) * q.shape[-1] ** -0.5
+
+
+def _keys(k):
+    """Unit length a head: k [..., H, dk] float32."""
+    return _l2_normalise(k)
+
+
+def _update(s0, q, k, v, gamma, beta):
+    """The recurrence for one token, float32 throughout and elementwise
+    (no matmul unit rounds the state): s0 [B, H, dk, dv], q and k
+    [B, H, dk], v [B, H, dv], gamma and beta [B, H] -> (o [B, H, dv],
+    S [B, H, dk, dv])."""
+    s = s0 * gamma[..., None, None]
+    u = beta[..., None] * (v - (s * k[..., None]).sum(-2))
+    s = s + k[..., None] * u[..., None, :]
+    return (s * q[..., None]).sum(-2), s
+
+
+def _by_rows(a):
+    """(I + A)^-1 of a strictly lower triangular A [..., C, C] by
+    forward substitution: row i is e_i - sum_{j<i} A_ij row_j."""
+    c = a.shape[-1]
+    eye = jnp.eye(c, dtype=a.dtype)
+
+    def row(i, t):
+        a_i = lax.dynamic_index_in_dim(a, i, -2, keepdims=False)
+        new = eye[i] - (a_i[..., :, None] * t).sum(-2)
+        return lax.dynamic_update_index_in_dim(t, new, i, -2)
+
+    return lax.fori_loop(0, c, row, jnp.zeros_like(a))
+
+
+def _unit_lower_inverse(a):
+    """(I + A)^-1 of a strictly lower triangular A [..., C, C], float32:
+    by halves down to `_INVERSE_BASE` rows, [[T1, 0], [-T2 A21 T1,
+    T2]], the two products at the highest precision."""
+    c = a.shape[-1]
+    if c <= _INVERSE_BASE or c % 2:
+        return _by_rows(a)
+    half = c // 2
+    t1 = _unit_lower_inverse(a[..., :half, :half])
+    t2 = _unit_lower_inverse(a[..., half:, half:])
+    below = -jnp.matmul(
+        jnp.matmul(t2, a[..., half:, :half], precision="highest"), t1,
+        precision="highest")
+    return jnp.concatenate([
+        jnp.concatenate([t1, jnp.zeros_like(below).swapaxes(-1, -2)], -1),
+        jnp.concatenate([below, t2], -1)], -2)
+
+
+def _scan(cfg, s0, q, k, v, log_gamma, beta):
+    """The chunked form over T tokens from the carried state: s0
+    [B, H, dk, dv] float32, q and k [B, T, H, dk] (normalised, q
+    scaled), v [B, T, H, dv], log_gamma and beta [B, T, H] float32
+    (both 0 at a position that is not to count) -> (o [B, T, H, dv]
+    float32, S after the last position [B, H, dk, dv] float32)."""
+    bsz, t, h, _ = q.shape
+    c = min(cfg.chunk_size, t)
+    pad = -t % c
+    if pad:  # beta = 0, gamma = 1: the tail neither writes nor decays
+        q, k, v, log_gamma, beta = (jnp.pad(
+            x, ((0, 0), (0, pad)) + ((0, 0),) * (x.ndim - 2))
+            for x in (q, k, v, log_gamma, beta))
+    nc = (t + pad) // c
+    dtype = v.dtype
+    f32 = jnp.float32
+
+    def chunks(x):  # [B, T, H, ...] -> [B, nc, H, C, ...]
+        return jnp.moveaxis(x.reshape((bsz, nc, c) + x.shape[2:]), 2, 3)
+
+    q, k, v, beta = chunks(q), chunks(k), chunks(v), chunks(beta)
+    g = jnp.cumsum(chunks(log_gamma), -1)                    # [B, nc, H, C]
+    seen = jnp.tril(jnp.ones((c, c), bool))
+    ratio = jnp.exp(jnp.where(seen, g[..., :, None] - g[..., None, :],
+                              -jnp.inf))                      # G_i / G_j
+    kk = jnp.einsum("bnhik,bnhjk->bnhij", k, k, preferred_element_type=f32)
+    a = jnp.where(jnp.tril(seen, -1), beta[..., None] * kk * ratio, 0.0)
+    t_inv = _unit_lower_inverse(a)
+    w = jnp.einsum("bnhij,bnhjk->bnhik",
+                   (t_inv * (beta * jnp.exp(g))[..., None, :]).astype(dtype),
+                   k, preferred_element_type=f32).astype(dtype)
+    u = jnp.einsum("bnhij,bnhjv->bnhiv",
+                   (t_inv * beta[..., None, :]).astype(dtype), v,
+                   preferred_element_type=f32)
+    qk = (jnp.einsum("bnhik,bnhjk->bnhij", q, k,
+                     preferred_element_type=f32) * ratio).astype(dtype)
+    q_in = (q.astype(f32) * jnp.exp(g)[..., None]).astype(dtype)
+    k_out = (k.astype(f32) * jnp.exp(g[..., -1:] - g)[..., None]).astype(
+        dtype)
+    whole = jnp.exp(g[..., -1])                               # [B, nc, H]
+
+    def chunk(s, xs):
+        w, u, qk, q_in, k_out, whole = xs
+        entering = s.astype(dtype)
+        u = u - jnp.einsum("bhik,bhkv->bhiv", w, entering,
+                           preferred_element_type=f32)
+        written = u.astype(dtype)
+        o = jnp.einsum("bhik,bhkv->bhiv", q_in, entering,
+                       preferred_element_type=f32) \
+            + jnp.einsum("bhij,bhjv->bhiv", qk, written,
+                         preferred_element_type=f32)
+        s = s * whole[..., None, None] + jnp.einsum(
+            "bhik,bhiv->bhkv", k_out, written, preferred_element_type=f32)
+        return s, o
+
+    last, o = lax.scan(chunk, s0, tuple(
+        x.swapaxes(0, 1) for x in (w, u, qk, q_in, k_out, whole)))
+    o = jnp.moveaxis(o, 0, 1)                                 # [B, nc, H, C, dv]
+    return (jnp.moveaxis(o, 2, 3).reshape(bsz, nc * c, h, -1)[:, :t], last)
+
+
+def _gated_norm(cfg, o, z, weight):
+    """RMSNorm over each head's dv channels, then the gate: o, z
+    [B, T, H, dv] -> [B, T, H, dv] float32."""
+    o = o.astype(jnp.float32)
+    o = o * lax.rsqrt(jnp.mean(o * o, -1, keepdims=True) + cfg.norm_eps)
+    return o * weight.astype(jnp.float32) \
+        * jax.nn.silu(z.astype(jnp.float32))
+
+
+def mixer(cfg, start_pos, at):
+    """The mixer of a run of delta layers. Its state is the run's four
+    stacks (`LEAVES`: S [layers, B, H, dk, dv] and the three carries
+    [layers, B, K - 1, channels]), which `decoder.layers` carries
+    through the scan; it reads its layer of each and writes it back
+    whole. `start_pos` [B]: a row at 0 starts from zeros; `at`: the
+    position of the call's tokens after which the state is left, an int
+    or an int32 scalar for all rows or int32 [B], one a row."""
+    h, dk, dv = cfg.delta_heads, cfg.delta_key_dim, cfg.delta_value_dim
+    at = jnp.broadcast_to(jnp.asarray(at, jnp.int32), start_pos.shape)
+    fresh = start_pos == 0
+
+    def mix(a, lp, rope, state, handed):
+        (s_stack, *carries), layer = state
+        bsz, t = a.shape[:2]
+        s0 = jnp.where(fresh[:, None, None, None], 0.0,
+                       lax.dynamic_index_in_dim(s_stack, layer, 0, False)
+                       .astype(jnp.float32))
+        convolved = []
+        with jax.named_scope("delta_conv"):
+            for name, stack, w in zip(CONVS, carries, ("wq", "wk", "wv")):
+                x = jnp.einsum("btd,dhk->bthk", a, lp[w]).reshape(bsz, t, -1)
+                carry = jnp.where(
+                    fresh[:, None, None], 0,
+                    lax.dynamic_index_in_dim(stack, layer, 0, False))
+                convolved.append(mamba2._conv(
+                    cfg, {"conv_w": lp[name]}, carry, x, at, bias=False))
+        (q, k, v), carries_out = zip(*convolved)
+        q = _queries(q.reshape(bsz, t, h, dk))
+        k = _keys(k.reshape(bsz, t, h, dk))
+        v = v.reshape(bsz, t, h, dv)
+        z = jnp.einsum("btd,dhv->bthv", a, lp["wg"])
+        real = (jnp.arange(t) <= at[:, None])[..., None]
+        log_gamma = -jnp.exp(lp["A_log"].astype(jnp.float32)) \
+            * jax.nn.softplus(jnp.einsum("btd,dh->bth", a, lp["wa"])
+                              .astype(jnp.float32) + lp["dt_bias"])
+        log_gamma = jnp.where(real, log_gamma, 0.0)
+        beta = jax.nn.sigmoid(jnp.einsum("btd,dh->bth", a, lp["wb"])
+                              .astype(jnp.float32))
+        beta = jnp.where(real, 2.0 * beta if cfg.allow_neg_eigval else beta,
+                         0.0)
+        if t == 1:
+            with jax.named_scope("delta_update"):
+                o, s = _update(s0, q[:, 0], k[:, 0],
+                               v[:, 0].astype(jnp.float32),
+                               jnp.exp(log_gamma[:, 0]), beta[:, 0])
+                o = o[:, None]
+        else:
+            with jax.named_scope("delta_scan"):
+                o, s = _scan(cfg, s0, q.astype(a.dtype), k.astype(a.dtype),
+                             v, log_gamma, beta)
+        with jax.named_scope("delta_norm"):
+            out = _gated_norm(cfg, o, z, lp["o_norm"])
+        state = (lax.dynamic_update_index_in_dim(
+            s_stack, s.astype(s_stack.dtype), layer, 0),) + tuple(
+            lax.dynamic_update_index_in_dim(
+                stack, carry.astype(stack.dtype), layer, 0)
+            for stack, carry in zip(carries, carries_out))
+        return out.astype(a.dtype), state, handed
+
+    mix.scope = "delta"
+    return mix

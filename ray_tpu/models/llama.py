@@ -69,6 +69,10 @@ class LlamaConfig:
     # parallel block) or follow each other, each behind its own norm.
     norm_kind: str = "rms"
     parallel_block: bool = False
+    # "input": a half reads the norm of the stream (Llama). "output": it
+    # reads the stream and its output is normed before the residual
+    # takes it (OLMo 2 and 3, `olmo_hybrid`).
+    norm_placement: str = "input"
 
     @property
     def head_dim(self) -> int:

@@ -113,16 +113,18 @@ def init_state(cfg, n_layers: int, n_slots: int) -> Dict[str, Any]:
     }
 
 
-def _conv(cfg, lp, carry, xbc, at):
+def _conv(cfg, lp, carry, xbc, at, bias=True):
     """The causal depthwise convolution of xbc [B, T, C] behind the
     carried rows [B, K - 1, C] -> (silu of it [B, T, C], the rows to
-    carry on: the K - 1 un-convolved rows that end at position `at[row]`)."""
+    carry on: the K - 1 un-convolved rows that end at position `at[row]`).
+    `bias` False: the convolution has none (`gated_delta`'s three)."""
     k, t = cfg.conv_kernel, xbc.shape[1]
     window = jnp.concatenate([carry.astype(xbc.dtype), xbc], 1)
     out = sum(window[:, j:j + t].astype(jnp.float32)
               * lp["conv_w"][:, j].astype(jnp.float32) for j in range(k))
-    out = jax.nn.silu(out + lp["conv_b"].astype(jnp.float32))
-    return (out.astype(xbc.dtype), jax.vmap(
+    if bias:
+        out = out + lp["conv_b"].astype(jnp.float32)
+    return (jax.nn.silu(out).astype(xbc.dtype), jax.vmap(
         lambda rows, last: lax.dynamic_slice_in_dim(rows, last + 1, k - 1)
     )(window, at))
 

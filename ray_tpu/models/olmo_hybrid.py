@@ -1,0 +1,307 @@
+"""Olmo Hybrid's decoder (`model_type` `olmo_hybrid`), served through
+the slot cache: `decoder` over two kinds of layer, three `linear` to
+one `full` (`benchmark/references/olmo_hybrid.py` has the equations in
+full):
+
+- a `linear` layer mixes by the gated delta rule (`gated_delta`): its
+  state is four cache leaves with no sequence axis, a float32 matrix a
+  head and the carried rows of three convolutions, rewritten whole at
+  every call;
+- a `full` layer is attention through cached keys and values, as many
+  key-value heads as query heads, an RMSNorm over the whole projected q
+  and k, and no positional encoding (the linear layers carry the
+  order);
+- every layer has a SwiGLU, and the block is OLMo 2's
+  (`norm_placement` "output"): a half reads the stream as it is and its
+  output is normed before the residual takes it.
+
+Like layers in a row are one run of `decoder.hidden_runs`, parameters
+and cache stacked by run. The cache is {"runs": [a dict a run]}:
+`gated_delta.LEAVES` of a `linear` run (state leaves, [layers, slots,
+...]), `k` and `v` of a `full` run (row leaves, [layers, slots,
+max_seq, heads x head size]). `state_leaves` says which is which, for
+the engine.
+
+A row of keys is one axis of 3,840 channels, not [30, 128]. An array
+whose last two axes are [30, 128] the TPU keeps with its rows before
+its heads ([slots, heads, max_seq, head size] in memory, to spare the
+padding of 30 to 32), and a program that writes a row at a position
+and reads a layer's rows wants it the other way: compiled for the v5e
+with the leaf as [..., max_seq, 30, 128], a decode step copied every
+key and value leaf into the other order at entry and back at exit (12
+copies of 503 MB at 32 slots of 2,048, 5.9 GB of temporaries on top of
+10.4 GB of arguments: it did not fit), and with the heads merged but
+attention through `llama._cached_attention` on a [max_seq, 30, 128]
+view it copied each layer's keys and values once a step. A decode step
+therefore attends on the merged axis itself (`_attend_one_token`); a
+prefill, one slot's rows, pays the view (15.7 MB a leaf).
+
+Not here: an uncached forward pass and a loss (the chunked delta scan
+has no backward pass: the model is served, not trained).
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import functools
+import itertools
+from typing import Any, Dict, Tuple
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+from ray_tpu.models import decoder, gated_delta, llama, mamba2
+from ray_tpu.models.glm_dsa import _by_query_blocks
+
+PUBLISHED_LAYER_TYPES = ("linear", "linear", "linear", "full") * 8
+
+
+@dataclasses.dataclass(frozen=True)
+class OlmoHybridConfig(llama.LlamaConfig):
+    """Defaults are Olmo-Hybrid-7B's. `layer_types` names the layers
+    held, bottom to top, "linear" or "full", `n_layers` of them."""
+    vocab_size: int = 100352
+    dim: int = 3840
+    n_layers: int = 32
+    n_heads: int = 30
+    n_kv_heads: int = 30
+    hidden_dim: int = 11008
+    max_seq_len: int = 65536
+    norm_eps: float = 1e-6
+    qk_norm: bool = True
+    norm_placement: str = "output"
+    layer_types: Tuple[str, ...] = PUBLISHED_LAYER_TYPES
+    delta_heads: int = 30
+    delta_key_dim: int = 96
+    delta_value_dim: int = 192
+    conv_kernel: int = 4
+    # beta in (0, 2): an eigenvalue of the transition may be negative.
+    allow_neg_eigval: bool = True
+    chunk_size: int = 64
+    # The delta state's dtype in the cache; the recurrence itself runs
+    # in float32 whatever this is.
+    state_dtype: Any = jnp.float32
+
+    def runs(self):
+        """[(kind, layers)]: the stack as runs of like layers."""
+        assert len(self.layer_types) == self.n_layers \
+            and set(self.layer_types) <= {"linear", "full"}, self.layer_types
+        return [(kind, len(list(group)))
+                for kind, group in itertools.groupby(self.layer_types)]
+
+    @staticmethod
+    def debug_olmo_hybrid() -> "OlmoHybridConfig":
+        """Two periods; dk != dv, a head count that is no power of two,
+        a chunk shorter than the CPU tests' prompts."""
+        return OlmoHybridConfig(
+            vocab_size=512, dim=60, n_layers=8, n_heads=3, n_kv_heads=3,
+            hidden_dim=96, max_seq_len=256, dtype=jnp.float32,
+            layer_types=("linear", "linear", "linear", "full") * 2,
+            delta_heads=3, delta_key_dim=8, delta_value_dim=16,
+            chunk_size=8)
+
+
+# ---------------------------------------------------------------------------
+# Parameters and cache
+# ---------------------------------------------------------------------------
+
+# Every matrix is drawn in float32 and cast: `mamba2.normal` says why.
+_init = mamba2.normal(0.02)
+
+
+def _init_layer(cfg: OlmoHybridConfig, kind, key) -> Dict[str, Any]:
+    d, hd, f = cfg.dim, cfg.head_dim, cfg.hidden_dim
+    k_mixer, k1, k2, k3 = jax.random.split(key, 4)
+    lp = {"attn_norm": jnp.ones(d, cfg.dtype),
+          "mlp_norm": jnp.ones(d, cfg.dtype),
+          "w1": _init(k1, (d, f), cfg.dtype),
+          "w3": _init(k2, (d, f), cfg.dtype),
+          "w2": _init(k3, (f, d), cfg.dtype) * f ** -0.5}
+    if kind == "linear":
+        lp.update(gated_delta.init(cfg, k_mixer))
+    else:
+        kq, kk, kv, ko = jax.random.split(k_mixer, 4)
+        lp.update(wq=_init(kq, (d, cfg.n_heads, hd), cfg.dtype),
+                  wk=_init(kk, (d, cfg.n_kv_heads, hd), cfg.dtype),
+                  wv=_init(kv, (d, cfg.n_kv_heads, hd), cfg.dtype),
+                  wo=_init(ko, (cfg.n_heads, hd, d), cfg.dtype) * d ** -0.5,
+                  q_norm=jnp.ones(cfg.n_heads * hd, cfg.dtype),
+                  k_norm=jnp.ones(cfg.n_kv_heads * hd, cfg.dtype))
+    return lp
+
+
+def init_params(cfg: OlmoHybridConfig, rng) -> Dict[str, Any]:
+    """embed, `runs` (a list, one dict of stacked leaves a run of like
+    layers: a run with `A_log` is `linear`), final norm, `out`."""
+    k_embed, k_out, k_layers = jax.random.split(rng, 3)
+    keys = jax.random.split(k_layers, cfg.n_layers)
+    runs, at = [], 0
+    for kind, n in cfg.runs():
+        runs.append(jax.vmap(functools.partial(_init_layer, cfg, kind))(
+            keys[at:at + n]))
+        at += n
+    return {"embed": _init(k_embed, (cfg.vocab_size, cfg.dim), cfg.dtype),
+            "runs": runs,
+            "final_norm": jnp.ones(cfg.dim, cfg.dtype),
+            "out": _init(k_out, (cfg.dim, cfg.vocab_size), cfg.dtype)}
+
+
+# The cache leaves of a run, by its kind: a `linear` run's are state, a
+# `full` run's rows.
+_LEAVES = {"linear": gated_delta.LEAVES, "full": ("k", "v")}
+
+
+def init_cache(cfg: OlmoHybridConfig, n_slots: int,
+               max_seq: int) -> Dict[str, Any]:
+    """The slot cache, a run at a time: the delta state and the three
+    convolutions' rows of a `linear` run ([layers, slots, ...], no
+    sequence axis), keys and values of a `full` run ([layers, slots,
+    max_seq, heads x head size])."""
+    runs = []
+    for kind, n in cfg.runs():
+        if kind == "linear":
+            runs.append(gated_delta.init_state(cfg, n, n_slots))
+        else:
+            shape = (n, n_slots, max_seq, cfg.n_kv_heads * cfg.head_dim)
+            runs.append({"k": jnp.zeros(shape, cfg.dtype),
+                         "v": jnp.zeros(shape, cfg.dtype)})
+    return {"runs": runs}
+
+
+def state_leaves(cache):
+    """`cache`'s structure with True at a leaf that is state (no
+    sequence axis, rewritten whole) and False at one of rows."""
+    return jax.tree_util.tree_map_with_path(
+        lambda path, _: path[-1].key in _LEAVES["linear"], cache)
+
+
+# ---------------------------------------------------------------------------
+# The full layer
+# ---------------------------------------------------------------------------
+
+
+def _attend_one_token(q, keys, values, positions):
+    """A decode step's attention over keys and values as the cache
+    holds them, heads and head size in one axis of H x D channels: q
+    [B, 1, H, D], keys and values [B, S, H x D], positions [B, 1] ->
+    [B, 1, H, D]. Every query head has a key head of its own, so a step
+    is a matrix-vector product a (row, head), which the TPU's compiler
+    takes off the matmul unit and for which it lays the whole cache out
+    anew (module docstring). Here q is spread over a block diagonal,
+    [B, H x D, H] with head h's channels in column h and zeros
+    elsewhere, so that the scores of all heads are one product with the
+    keys where they lie, [S, H x D] x [H x D, H] a row; the weighted
+    sum is [H, S] x [S, H x D], of which head h keeps its own D
+    channels. The zeros cost H times the operations, a twentieth of
+    the step's at 32 slots of 2,048, and no byte."""
+    b, s, width = keys.shape
+    h, d = q.shape[2:]
+    own = jnp.eye(h, dtype=q.dtype)
+    spread = (q[:, 0, :, :, None] * own[:, None, :]).reshape(b, width, h)
+    scores = jnp.einsum("bsc,bch->bsh", keys, spread,
+                        preferred_element_type=jnp.float32) * d ** -0.5
+    seen = jnp.arange(s)[None, :] <= positions                  # [B, S]
+    probs = jax.nn.softmax(jnp.where(seen[..., None], scores, -1e30), 1)
+    mixed = jnp.einsum("bsh,bsc->bhc", probs.astype(values.dtype), values,
+                       preferred_element_type=jnp.float32)
+    out = (mixed.reshape(b, h, h, d) * own[None, :, :, None]).sum(2)
+    return out[:, None].astype(q.dtype)
+
+
+def _attention(cfg: OlmoHybridConfig, start_pos, positions):
+    """The mixer of a run of `full` layers: attention through the slot
+    cache with a norm over all of q's and all of k's heads and no
+    rotary turn. A prefill goes through `llama._cached_attention` a
+    block of queries at a time, so that its scores are never [T,
+    max_seq] a head; a decode step through `_attend_one_token`."""
+    def mixer(h, lp, rope, state, handed):
+        (k_stack, v_stack), layer = state
+        b, t = h.shape[:2]
+        q = jnp.einsum("bsd,dhk->bshk", h, lp["wq"])
+        k = jnp.einsum("bsd,dhk->bshk", h, lp["wk"])
+        if cfg.qk_norm:
+            q = llama.norm_all_heads(q, lp["q_norm"], cfg.norm_eps)
+            k = llama.norm_all_heads(k, lp["k_norm"], cfg.norm_eps)
+        v = jnp.einsum("bsd,dhk->bshk", h, lp["wv"])
+        k_stack = decoder.write_rows(k_stack, layer, k.reshape(b, t, -1),
+                                     start_pos)
+        v_stack = decoder.write_rows(v_stack, layer, v.reshape(b, t, -1),
+                                     start_pos)
+        max_seq = k_stack.shape[2]
+        keys = decoder.layer_rows(k_stack, layer, 0, max_seq)
+        values = decoder.layer_rows(v_stack, layer, 0, max_seq)
+        q = q.astype(k_stack.dtype)
+        if t == 1:
+            out = _attend_one_token(q, keys, values, positions)
+        else:
+            by_head = (b, max_seq, cfg.n_kv_heads, cfg.head_dim)
+            out, = _by_query_blocks(
+                lambda q, pos: (llama._cached_attention(
+                    cfg, q, keys.reshape(by_head), values.reshape(by_head),
+                    pos),), t, q, positions)
+        return out, (k_stack, v_stack), handed
+
+    return mixer
+
+# ---------------------------------------------------------------------------
+# Forward through the slot cache
+# ---------------------------------------------------------------------------
+
+
+def _hidden(params, tokens, cfg: OlmoHybridConfig, cache, start_pos, at):
+    """The stack through the slot cache: (final-norm hidden states
+    [B, T, D], new cache)."""
+    positions = start_pos[:, None] + jnp.arange(tokens.shape[1])[None, :]
+    mixers = {"linear": gated_delta.mixer(cfg, start_pos, at),
+              "full": _attention(cfg, start_pos, positions)}
+    ffn = llama.swiglu()
+    runs = [(mixers[kind], ffn, stacked,
+             tuple(run[name] for name in _LEAVES[kind]))
+            for (kind, _), stacked, run in zip(cfg.runs(), params["runs"],
+                                               cache["runs"])]
+    x, states, _ = decoder.hidden_runs(params, tokens, cfg, runs,
+                                       positions=positions)
+    return x, {"runs": [dict(zip(_LEAVES[kind], state))
+                        for (kind, _), state in zip(cfg.runs(), states)]}
+
+
+def _counts(tokens, start_pos, at):
+    """What a call counts, int32 scalars: the rows that started from
+    zeros, and the real tokens a prefill carried through the chunked
+    scan (its padding past `at` left out; none of a call of one
+    token)."""
+    at = jnp.broadcast_to(jnp.asarray(at, jnp.int32), start_pos.shape)
+    return {"delta_state_resets": (start_pos == 0).sum(dtype=jnp.int32),
+            "delta_scan_tokens": (at + 1).sum(dtype=jnp.int32)
+            if tokens.shape[1] > 1 else jnp.zeros((), jnp.int32)}
+
+
+def _logits(params, x, cfg):
+    """The head in float32, as `glm_dsa`'s: the logits feed an argmax."""
+    return jnp.einsum("...d,dv->...v", x, params["out"].astype(cfg.dtype),
+                      preferred_element_type=jnp.float32)
+
+
+def forward(params, tokens, cfg: OlmoHybridConfig, cache, start_pos, at):
+    """What the engine serves through (`models.serving`): `tokens`
+    [B, T] from per-row absolute offsets `start_pos` [B], prefill (T =
+    the prompt's bucket) and decode (T = 1) alike. Returns (the logits
+    of position `at` of `tokens`, [B, vocab] float32; the new cache,
+    whose state leaves are those after position `at` and no later; the
+    call's `delta_state_resets` and `delta_scan_tokens`)."""
+    x, cache = _hidden(params, tokens, cfg, cache, start_pos, at)
+    x = lax.dynamic_index_in_dim(x, at, 1, keepdims=False)
+    return _logits(params, x, cfg), cache, _counts(tokens, start_pos, at)
+
+
+def forward_with_cache(params, tokens, cfg: OlmoHybridConfig, cache,
+                       start_pos, at=None):
+    """`forward` with the logits of every position, [B, T, vocab]
+    float32, and no counts: what a comparison with a reference steps
+    through. The state left is that after position `at` (an int for
+    all rows, or int32 [B], one a row), the last of `tokens` unless
+    given."""
+    at = tokens.shape[1] - 1 if at is None else at
+    x, cache = _hidden(params, tokens, cfg, cache, start_pos, at)
+    return _logits(params, x, cfg), cache

@@ -17,7 +17,9 @@ through the slot cache. The engine knows no model; it takes from here
   layer written in and nothing else moved; the engine slices slots,
   reads and writes blocks of rows and sizes its prefix cache by these
   leaves. A *state* leaf has no sequence axis (a recurrent layer's
-  state, a convolution's last rows): `forward` rewrites it whole, a row
+  state, a vector a channel as Mamba-2's or a matrix a head as the
+  delta rule's, and a convolution's last rows, of which a layer may
+  carry several: `gated_delta` has three): `forward` rewrites it whole, a row
   that starts at position 0 starts from zeros whatever the leaf held,
   and what is left is the state after position `at` and no later (a
   prefill's bucket padding must not enter it); the engine only slices
@@ -91,6 +93,12 @@ def _nemotron_h():
                        state_leaves=nemotron_h.state_leaves)
 
 
+def _olmo_hybrid():
+    from ray_tpu.models import olmo_hybrid
+    return ServedModel(olmo_hybrid.forward, olmo_hybrid.init_cache,
+                       state_leaves=olmo_hybrid.state_leaves)
+
+
 def _cohere2_moe():
     from ray_tpu.models import cohere2_moe
     return ServedModel(cohere2_moe.forward, cohere2_moe.init_cache,
@@ -101,7 +109,8 @@ def _cohere2_moe():
 # `LlamaConfig` and has no cached forward pass.
 _SERVED = {"LlamaConfig": _llama, "GlmDsaConfig": _glm_dsa,
            "NemotronHConfig": _nemotron_h,
-           "Cohere2MoeConfig": _cohere2_moe}
+           "Cohere2MoeConfig": _cohere2_moe,
+           "OlmoHybridConfig": _olmo_hybrid}
 
 
 def served_model(cfg) -> ServedModel:

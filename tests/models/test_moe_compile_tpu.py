@@ -174,3 +174,61 @@ def test_served_step_compiles_for_the_v5e(one_chip, name, program, bucket,
     assert memory.argument_size_in_bytes > resident  # weights and cache
     assert memory.argument_size_in_bytes + memory.temp_size_in_bytes \
         < 16.0e9
+
+
+@pytest.mark.parametrize("program,bucket", [("decode", 0),
+                                            ("prefill", 2048)])
+def test_olmo_hybrids_step_compiles_for_the_v5e(one_chip, program, bucket):
+    """Olmo-Hybrid-7B's first stage as `olmo-hybrid-7b-serve.json` cuts
+    it, through the engine's own programs at the cell's 32 slots of
+    2,048: it compiles and fits, and no step lays a leaf of keys out
+    anew. With the leaf as [..., max_seq, 30, 128] the TPU keeps it
+    with heads before rows and a decode step copied every leaf there
+    and back (5.9 GB of temporaries, over the chip); with heads merged
+    and attention on a [max_seq, 30, 128] view it copied a layer's keys
+    and values once a step (`models/olmo_hybrid.py` says what a decode
+    step does instead)."""
+    from benchmark.harness.manifest import ROOT, load_json, model_adapter
+    from ray_tpu.models.serving import served_model
+    from ray_tpu.serve.llm import LLMEngine
+
+    config = load_json(ROOT, "benchmark", "configs",
+                       "olmo-hybrid-7b-serve.json")
+    model = model_adapter(config)
+    cfg = model.program_config(config)
+    plan = config["serve"]
+    n, rows = plan["max_batch_size"], plan["max_seq_len"]
+
+    def on_chip(tree):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=one_chip), tree)
+
+    def ints(*dims, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    params = on_chip(jax.eval_shape(
+        lambda: model.init(cfg, jax.random.PRNGKey(0))))
+    engine = LLMEngine.__new__(LLMEngine)  # its programs, no device
+    engine.cfg, engine._served = cfg, served_model(cfg)
+    cache = on_chip(jax.eval_shape(
+        lambda: engine._served.init_cache(cfg, n, rows)))
+    engine.max_seq, engine.decode_steps, engine.n_slots = rows, 1, n
+    engine._count_names = ("delta_scan_tokens", "delta_state_resets")
+    if program == "decode":
+        compiled = jax.jit(engine._decode_impl, donate_argnums=(1,)).lower(
+            params, cache, ints(n), ints(n), ints(n, dtype=jnp.float32),
+            ints(n), ints(2, dtype=jnp.uint32)).compile()
+    else:
+        compiled = jax.jit(engine._prefill_impl, donate_argnums=(1,),
+                           static_argnums=(6,)).lower(
+            params, cache, ints(1, bucket), ints(), ints(), ints(),
+            bucket).compile()
+    width = cfg.n_kv_heads * cfg.head_dim
+    assert not re.findall(
+        rf"= \w+\[1,{n},{rows},{width}\]\S* (?:copy|convert|transpose)\(",
+        _scheduled(compiled.as_text()))
+    memory = compiled.memory_analysis()
+    assert memory.argument_size_in_bytes > 10.2e9  # weights and cache
+    assert memory.temp_size_in_bytes < 0.5e9
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes \
+        < 16.0e9

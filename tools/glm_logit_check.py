@@ -7,8 +7,8 @@ name it had when GLM-5.2 was the one model it knew, which
 `glm-5.2-serve.json` cites. `FAMILIES` has, by the configuration's
 family, its faults, the faults a set of weights cannot show and the
 program's own initialiser; below, GLM-5.2's at length, then
-`nemotron_faults` for `nemotron-3-super-serve` and `cohere_faults` for
-`command-a-plus-serve`.
+`nemotron_faults` for `nemotron-3-super-serve`, `cohere_faults` for
+`command-a-plus-serve` and `olmo_faults` for `olmo-hybrid-7b-serve`.
 
 Outside the benchmark and its timed window (PERF.md, PR 32, has the
 readings). For each seed, what `benchmark/runners/serve.py`'s
@@ -103,6 +103,29 @@ def _patched(forward, module, name, other):
         finally:
             setattr(module, name, real)
     return served
+
+
+def _state_in_bfloat16(forward, state_leaves):
+    """`forward` with every float32 state leaf of the cache it returns
+    (`state_leaves(cache)`) rounded to bfloat16's mantissa."""
+    def served(params, tokens, cfg, cache, start_pos):
+        import jax
+        import jax.numpy as jnp
+
+        logits, cache = forward(params, tokens, cfg, cache, start_pos)
+        return logits, jax.tree.map(
+            lambda x, is_state: cut(x, 16)
+            if is_state and x.dtype == jnp.float32 else x, cache,
+            state_leaves(cache))
+    return served
+
+
+def _to_the_end(real):
+    """A `forward_with_cache` whose state is left as after the call's
+    last token, padding and all: `at` is dropped."""
+    def forward_with_cache(params, tokens, cfg, cache, start_pos, at=None):
+        return real(params, tokens, cfg, cache, start_pos)
+    return forward_with_cache
 
 
 def faults(forward, init_cache):
@@ -234,28 +257,14 @@ def nemotron_faults(forward, init_cache):
                 * jax.nn.silu(z.astype(jnp.float32)).reshape(b, t, h * p)
         return gated_norm
 
-    def state_in_bfloat16(params, tokens, cfg, cache, start_pos):
-        logits, cache = forward(params, tokens, cfg, cache, start_pos)
-        state = nemotron_h.state_leaves(cache)
-        return logits, jax.tree.map(
-            lambda x, is_state: cut(x, 16)
-            if is_state and x.dtype == jnp.float32 else x, cache, state)
-
-    def to_the_end(real):
-        """The state left is that after the call's last token, padding
-        and all."""
-        def forward_with_cache(params, tokens, cfg, cache, start_pos,
-                               at=None):
-            return real(params, tokens, cfg, cache, start_pos)
-        return forward_with_cache
-
     def cut_to_the_latent(w_dn):
         eye = jnp.eye(w_dn.shape[-2], w_dn.shape[-1], dtype=w_dn.dtype)
         return jnp.broadcast_to(eye, w_dn.shape)
 
     return {
         "lower precision": glm["lower precision"],
-        "state in bfloat16": state_in_bfloat16,
+        "state in bfloat16": _state_in_bfloat16(
+            forward, nemotron_h.state_leaves),
         "no D term": with_leaf("D", jnp.zeros_like),
         "no dt bias": with_leaf("dt_bias", jnp.zeros_like),
         "no conv bias": with_leaf("conv_b", jnp.zeros_like),
@@ -266,7 +275,7 @@ def nemotron_faults(forward, init_cache):
         "bias in the gates": glm["bias in the gates"],
         "no latent projection": with_leaf("w_dn", cut_to_the_latent),
         "pad absorbed": patched(nemotron_h, "forward_with_cache",
-                                to_the_end),
+                                _to_the_end),
     }
 
 
@@ -316,6 +325,58 @@ def cohere_faults(forward, init_cache):
     }
 
 
+def olmo_faults(forward, init_cache):
+    """{name: served} for the family `olmo_hybrid`, as `faults` for
+    GLM-5.2: the weights cut to float8 e4m3's mantissa and the delta
+    state rounded to bfloat16's after every call (the two lower
+    precisions), beta without its factor 2, the gate before the output
+    norm, k not normalised, q without 1 / sqrt(dk), no decay (gamma =
+    1), the full layer's q and k norm left out, the block's norms on a
+    half's input and not its output, and a prefill whose bucket padding
+    enters the state and the carries."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.models import gated_delta, olmo_hybrid
+
+    with_cfg = functools.partial(_with_cfg, forward)
+    patched = functools.partial(_patched, forward)
+
+    def gate_first(real):
+        def gated_norm(cfg, o, z, weight):
+            o = o.astype(jnp.float32) * jax.nn.silu(z.astype(jnp.float32))
+            return o * jax.lax.rsqrt(
+                jnp.mean(o * o, -1, keepdims=True) + cfg.norm_eps) \
+                * weight.astype(jnp.float32)
+        return gated_norm
+
+    def no_decay(params, tokens, cfg, cache, start_pos):
+        runs = [{**run, "A_log": jnp.full_like(run["A_log"], -jnp.inf)}
+                if "A_log" in run else run for run in params["runs"]]
+        return forward({**params, "runs": runs}, tokens, cfg, cache,
+                       start_pos)
+
+    return {
+        "lower precision": faults(forward, init_cache)["lower precision"],
+        "state in bfloat16": _state_in_bfloat16(
+            forward, olmo_hybrid.state_leaves),
+        "beta without its 2": with_cfg(allow_neg_eigval=False),
+        "gate before the norm": patched(gated_delta, "_gated_norm",
+                                        gate_first),
+        "k not normalised": patched(
+            gated_delta, "_keys",
+            lambda real: lambda k: k.astype(jnp.float32)),
+        "q without its scale": patched(
+            gated_delta, "_queries",
+            lambda real: gated_delta._l2_normalise),
+        "no decay": no_decay,
+        "no q and k norm": with_cfg(qk_norm=False),
+        "norm on the input": with_cfg(norm_placement="input"),
+        "pad absorbed": patched(olmo_hybrid, "forward_with_cache",
+                                _to_the_end),
+    }
+
+
 # The faults a set of weights cannot show on the chip (each is seen at
 # the other; PERF.md section 6, PR 32, has the readings). The
 # benchmark's weights make the routed experts 32 times quieter, so what
@@ -343,6 +404,12 @@ NEMOTRON_UNSEEN = {
 COHERE_UNSEEN = {"benchmark": (), "plain": ()}
 
 
+# The same for `olmo_hybrid` (PERF.md section 6, PR 41): both sets of
+# weights are the program's initialiser's.
+OLMO_UNSEEN = {"benchmark": ("state in bfloat16",),
+               "plain": ("state in bfloat16",)}
+
+
 def _glm_init():
     from ray_tpu.models.glm_dsa import init_params
     return init_params
@@ -358,6 +425,11 @@ def _cohere_init():
     return init_params
 
 
+def _olmo_init():
+    from ray_tpu.models.olmo_hybrid import init_params
+    return init_params
+
+
 # By a configuration's family: its faults, the faults a set of weights
 # cannot show, the program's own initialiser (the plain weights), and
 # the prompt lengths of a rehearsal at debug widths.
@@ -366,6 +438,8 @@ FAMILIES = {
     "nemotron_h": (nemotron_faults, NEMOTRON_UNSEEN, _nemotron_init,
                    [45, 39, 26, 19]),
     "cohere2_moe": (cohere_faults, COHERE_UNSEEN, _cohere_init,
+                    [45, 39, 26, 19]),
+    "olmo_hybrid": (olmo_faults, OLMO_UNSEEN, _olmo_init,
                     [45, 39, 26, 19]),
 }
 
