@@ -26,16 +26,19 @@ it is told).
 
 What a sequence leaves behind is S [H, dk, dv] (`cfg.state_dtype`) and
 the last K - 1 un-convolved rows of q~, k~ and v~: four leaves of the
-slot cache with no sequence axis, [layers, slots, ...], rewritten whole
-at every call, and `mamba2`'s three rules hold of them: a row that
-starts at position 0 starts from zeros whatever its slot held; the
+slot cache with no sequence axis, [layers, slots, ...]. A call writes
+its layer of each whole, but for a decode step's states, which are
+updated where they lie. `mamba2`'s three rules hold of them: a row
+that starts at position 0 starts from zeros whatever its slot held; the
 state left is that after position `at` of the call's tokens and no
 later (padding past `at` takes beta = 0 and gamma = 1, which neither
 writes to the state nor decays it, and the carries are cut at `at`); a
 row that starts past 0 continues from its leaves.
 
 A call of one token is the recurrence as written, elementwise in
-float32 (scope `delta_update`). A longer one is the chunked form (scope
+float32 (scope `delta_update`; `ops/delta_update.py`: on a TPU one
+kernel that reads a head's state out of the run's stack once and
+writes it back there). A longer one is the chunked form (scope
 `delta_scan`). With g_i the log decays summed from the chunk's start
 through i, G_i = exp(g_i), and u_j = beta_j (v_j - (gamma_j S_{j-1})^T
 k_j) the value position j really writes, the recurrence unrolls to
@@ -68,6 +71,11 @@ import jax.numpy as jnp
 from jax import lax
 
 from ray_tpu.models import mamba2
+# `_update`: the recurrence as written, where `delta_update` has it for
+# backends without the kernel; what the tests hold the chunked form to,
+# token by token.
+from ray_tpu.ops.delta_update import (  # noqa: F401
+    delta_update, reference as _update)
 
 # Rows below which `_unit_lower_inverse` substitutes row by row.
 _INVERSE_BASE = 16
@@ -142,17 +150,6 @@ def _queries(q):
 def _keys(k):
     """Unit length a head: k [..., H, dk] float32."""
     return _l2_normalise(k)
-
-
-def _update(s0, q, k, v, gamma, beta):
-    """The recurrence for one token, float32 throughout and elementwise
-    (no matmul unit rounds the state): s0 [B, H, dk, dv], q and k
-    [B, H, dk], v [B, H, dv], gamma and beta [B, H] -> (o [B, H, dv],
-    S [B, H, dk, dv])."""
-    s = s0 * gamma[..., None, None]
-    u = beta[..., None] * (v - (s * k[..., None]).sum(-2))
-    s = s + k[..., None] * u[..., None, :]
-    return (s * q[..., None]).sum(-2), s
 
 
 def _by_rows(a):
@@ -262,9 +259,11 @@ def mixer(cfg, start_pos, at):
     stacks (`LEAVES`: S [layers, B, H, dk, dv] and the three carries
     [layers, B, K - 1, channels]), which `decoder.layers` carries
     through the scan; it reads its layer of each and writes it back
-    whole. `start_pos` [B]: a row at 0 starts from zeros; `at`: the
-    position of the call's tokens after which the state is left, an int
-    or an int32 scalar for all rows or int32 [B], one a row."""
+    whole, but in a call of one token, which updates its layer of S in
+    place in the stack and never slices it out. `start_pos` [B]: a row
+    at 0 starts from zeros; `at`: the position of the call's tokens
+    after which the state is left, an int or an int32 scalar for all
+    rows or int32 [B], one a row."""
     h, dk, dv = cfg.delta_heads, cfg.delta_key_dim, cfg.delta_value_dim
     at = jnp.broadcast_to(jnp.asarray(at, jnp.int32), start_pos.shape)
     fresh = start_pos == 0
@@ -272,9 +271,6 @@ def mixer(cfg, start_pos, at):
     def mix(a, lp, rope, state, handed):
         (s_stack, *carries), layer = state
         bsz, t = a.shape[:2]
-        s0 = jnp.where(fresh[:, None, None, None], 0.0,
-                       lax.dynamic_index_in_dim(s_stack, layer, 0, False)
-                       .astype(jnp.float32))
         convolved = []
         with jax.named_scope("delta_conv"):
             for name, stack, w in zip(CONVS, carries, ("wq", "wk", "wv")):
@@ -299,19 +295,27 @@ def mixer(cfg, start_pos, at):
         beta = jnp.where(real, 2.0 * beta if cfg.allow_neg_eigval else beta,
                          0.0)
         if t == 1:
+            # The stack's one reader and writer in the scan's body: a
+            # second one would have the compiler copy the stack to keep
+            # the kernel's alias honest.
             with jax.named_scope("delta_update"):
-                o, s = _update(s0, q[:, 0], k[:, 0],
-                               v[:, 0].astype(jnp.float32),
-                               jnp.exp(log_gamma[:, 0]), beta[:, 0])
+                o, s_stack = delta_update(
+                    s_stack, layer, fresh, q[:, 0], k[:, 0],
+                    v[:, 0].astype(jnp.float32), jnp.exp(log_gamma[:, 0]),
+                    beta[:, 0])
                 o = o[:, None]
         else:
+            s0 = jnp.where(fresh[:, None, None, None], 0.0,
+                           lax.dynamic_index_in_dim(s_stack, layer, 0, False)
+                           .astype(jnp.float32))
             with jax.named_scope("delta_scan"):
                 o, s = _scan(cfg, s0, q.astype(a.dtype), k.astype(a.dtype),
                              v, log_gamma, beta)
+            s_stack = lax.dynamic_update_index_in_dim(
+                s_stack, s.astype(s_stack.dtype), layer, 0)
         with jax.named_scope("delta_norm"):
             out = _gated_norm(cfg, o, z, lp["o_norm"])
-        state = (lax.dynamic_update_index_in_dim(
-            s_stack, s.astype(s_stack.dtype), layer, 0),) + tuple(
+        state = (s_stack,) + tuple(
             lax.dynamic_update_index_in_dim(
                 stack, carry.astype(stack.dtype), layer, 0)
             for stack, carry in zip(carries, carries_out))

@@ -13,6 +13,10 @@ on CPU and in interpret-mode tests):
 - ``grouped_matmul`` — a served share of experts' grouped products, the
                       matrices read where they lie in a run's stack
                       (``lax.ragged_dot`` off the TPU)
+- ``delta_update``  — the gated delta rule's one-token recurrence, a
+                      head's state read once and written back where it
+                      lies in a run's stack (the plain recurrence on the
+                      sliced layer off the TPU)
 """
 
 from ray_tpu.ops.attention import flash_attention  # noqa: F401

@@ -178,7 +178,8 @@ def test_served_step_compiles_for_the_v5e(one_chip, name, program, bucket,
 
 @pytest.mark.parametrize("program,bucket", [("decode", 0),
                                             ("prefill", 2048)])
-def test_olmo_hybrids_step_compiles_for_the_v5e(one_chip, program, bucket):
+def test_olmo_hybrids_step_compiles_for_the_v5e(one_chip, program, bucket,
+                                                monkeypatch):
     """Olmo-Hybrid-7B's first stage as `olmo-hybrid-7b-serve.json` cuts
     it, through the engine's own programs at the cell's 32 slots of
     2,048: it compiles and fits, and no step lays a leaf of keys out
@@ -187,11 +188,17 @@ def test_olmo_hybrids_step_compiles_for_the_v5e(one_chip, program, bucket):
     and back (5.9 GB of temporaries, over the chip); with heads merged
     and attention on a [max_seq, 30, 128] view it copied a layer's keys
     and values once a step (`models/olmo_hybrid.py` says what a decode
-    step does instead)."""
+    step does instead). A decode step updates a delta layer's states
+    where they lie in the run's stack (`ops/delta_update.py`'s kernel,
+    which this process's CPU backend would not choose: the test says it
+    is on a TPU): nothing else makes an array of a layer's states or of
+    the stack."""
     from benchmark.harness.manifest import ROOT, load_json, model_adapter
     from ray_tpu.models.serving import served_model
+    from ray_tpu.ops import delta_update
     from ray_tpu.serve.llm import LLMEngine
 
+    monkeypatch.setattr(delta_update, "on_tpu", lambda: True)
     config = load_json(ROOT, "benchmark", "configs",
                        "olmo-hybrid-7b-serve.json")
     model = model_adapter(config)
@@ -223,10 +230,31 @@ def test_olmo_hybrids_step_compiles_for_the_v5e(one_chip, program, bucket):
                            static_argnums=(6,)).lower(
             params, cache, ints(1, bucket), ints(), ints(), ints(),
             bucket).compile()
+    scheduled = _scheduled(compiled.as_text())
     width = cfg.n_kv_heads * cfg.head_dim
     assert not re.findall(
         rf"= \w+\[1,{n},{rows},{width}\]\S* (?:copy|convert|transpose)\(",
-        _scheduled(compiled.as_text()))
+        scheduled)
+    if program == "decode":
+        # One kernel a linear run, in its scan's body, and the state
+        # leaf aliased through it: a second reader of the carried stack
+        # there would show as a `copy` of it (212 MB a layer), the
+        # plain recurrence as three fusions over a layer's states
+        # (PERF.md, PR 42).
+        state = cache["runs"][0]["state"].shape
+        assert state == (3, n, cfg.delta_heads, cfg.delta_key_dim,
+                         cfg.delta_value_dim)
+        calls = re.findall(r'%delta_update(?:\.\d+)? = .* custom-call\('
+                           r'.*op_name="([^"]*)"', scheduled)
+        assert len(calls) == sum("conv_q" in run for run in params["runs"])
+        assert all(re.search(r"while/body/(?:closed_call/)?delta/"
+                             r"delta_update/", path) for path in calls)
+        stack, layer = (f"f32[{','.join(map(str, dims))}]"
+                        for dims in (state, state[1:]))
+        made = re.findall(r"%[\w.-]+ = (.*?) (?:copy|fusion|"
+                          r"dynamic-update-slice)\(", scheduled)
+        assert made and not [shapes for shapes in made
+                             if stack in shapes or layer in shapes]
     memory = compiled.memory_analysis()
     assert memory.argument_size_in_bytes > 10.2e9  # weights and cache
     assert memory.temp_size_in_bytes < 0.5e9
