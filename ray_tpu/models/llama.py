@@ -26,6 +26,7 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
 from ray_tpu.models import decoder
+from ray_tpu.ops import attention
 from ray_tpu.ops.attention import (attention_reference, flash_attention,
                                    on_tpu)
 from ray_tpu.ops.norms import rms_norm_reference
@@ -389,9 +390,14 @@ def _cached_attention(cfg, q, k_cache, v_cache, q_positions):
     array of `rep` x cache size and none of cache size in float32
     exists. Scores, mask, softmax and both accumulations are float32;
     `probs` is cast to the cache's dtype for the P.V product. Right for
-    any (B, T), decode (T = 1), prefill (B = 1) and in between, but the
-    scores are one dense [B, H, T, S] float32 array over the slot's
-    whole region S = max_seq: 4 B x H x T x S bytes, 2.1 GB at 32 heads
+    any (B, T), decode (T = 1), prefill (B = 1) and in between, but
+    every row reads the slot's whole region S = max_seq, whatever the
+    slot holds: on a TPU a call of one token a slot goes through
+    `ops.attention.decode_attention` instead, which gives the same
+    guarantees and reads the blocks of rows a slot's length reaches;
+    off the TPU that function comes back here. The
+    scores are one dense [B, H, T, S] float32 array over the
+    region: 4 B x H x T x S bytes, 2.1 GB at 32 heads
     of a 1,024-token prefill against 16,384 rows and 69 GB at 128 heads
     of 8,192 tokens, so a chip's 16 GB hold it up to about T x S = 2^24
     at 32 heads. A long-context family goes through it a block of
@@ -422,18 +428,26 @@ def _cached_self_attention(cfg: LlamaConfig, start_pos, positions):
     """The mixer of serving. Its state is the slot cache's two stacks,
     (K, V), each [layers, B, S, Hkv, D], which `decoder.layers` carries
     through the scan: the layer's new K and V go into them at (layer,
-    row, `start_pos[row]`), B x T rows a stack, and `_cached_attention`
-    (looked up in this module when the mixer is traced) reads the
-    layer's [B, S, Hkv, D] out of them."""
+    row, `start_pos[row]`), B x T rows a stack. On a TPU a call of one
+    token a slot (a decode step) then hands the stacks whole to
+    `attention.decode_attention`, which reads out of them the blocks of
+    rows each slot holds; a call of more tokens (a prefill), and any
+    call off the TPU, has `_cached_attention` (looked up in this module
+    when the mixer is traced) read the layer's [B, S, Hkv, D] out of
+    them."""
     def mixer(h, lp, rope, state, handed):
         (k_stack, v_stack), layer = state
         q, k, v = _qkv(cfg, h, lp, rope, positions, norm_all_heads)
         k_stack = decoder.write_rows(k_stack, layer, k, start_pos)
         v_stack = decoder.write_rows(v_stack, layer, v, start_pos)
-        max_seq = k_stack.shape[2]
-        out = _cached_attention(
-            cfg, q, decoder.layer_rows(k_stack, layer, 0, max_seq),
-            decoder.layer_rows(v_stack, layer, 0, max_seq), positions)
+        if q.shape[1] == 1 and attention.on_tpu():
+            out = attention.decode_attention(
+                q[:, 0], k_stack, v_stack, layer, positions[:, 0] + 1)[:, None]
+        else:
+            max_seq = k_stack.shape[2]
+            out = _cached_attention(
+                cfg, q, decoder.layer_rows(k_stack, layer, 0, max_seq),
+                decoder.layer_rows(v_stack, layer, 0, max_seq), positions)
         return out, (k_stack, v_stack), handed
 
     return mixer

@@ -33,8 +33,12 @@ copies of 503 MB at 32 slots of 2,048, 5.9 GB of temporaries on top of
 10.4 GB of arguments: it did not fit), and with the heads merged but
 attention through `llama._cached_attention` on a [max_seq, 30, 128]
 view it copied each layer's keys and values once a step. A decode step
-therefore attends on the merged axis itself (`_attend_one_token`); a
-prefill, one slot's rows, pays the view (15.7 MB a leaf).
+therefore attends on the merged axis itself: on a TPU
+`ops.attention.decode_attention` is handed the run's stacks whole and
+reads out of them the blocks of rows each slot holds, q spread over a
+block diagonal so that the scores of all heads are one product with a
+block where it lies. A prefill, one slot's rows, pays the view
+(15.7 MB a leaf), and off the TPU so does a decode step.
 
 Not here: an uncached forward pass and a loss (the chunked delta scan
 has no backward pass: the model is served, not trained).
@@ -53,6 +57,7 @@ from jax import lax
 
 from ray_tpu.models import decoder, gated_delta, llama, mamba2
 from ray_tpu.models.glm_dsa import _by_query_blocks
+from ray_tpu.ops import attention
 
 PUBLISHED_LAYER_TYPES = ("linear", "linear", "linear", "full") * 8
 
@@ -181,40 +186,14 @@ def state_leaves(cache):
 # ---------------------------------------------------------------------------
 
 
-def _attend_one_token(q, keys, values, positions):
-    """A decode step's attention over keys and values as the cache
-    holds them, heads and head size in one axis of H x D channels: q
-    [B, 1, H, D], keys and values [B, S, H x D], positions [B, 1] ->
-    [B, 1, H, D]. Every query head has a key head of its own, so a step
-    is a matrix-vector product a (row, head), which the TPU's compiler
-    takes off the matmul unit and for which it lays the whole cache out
-    anew (module docstring). Here q is spread over a block diagonal,
-    [B, H x D, H] with head h's channels in column h and zeros
-    elsewhere, so that the scores of all heads are one product with the
-    keys where they lie, [S, H x D] x [H x D, H] a row; the weighted
-    sum is [H, S] x [S, H x D], of which head h keeps its own D
-    channels. The zeros cost H times the operations, a twentieth of
-    the step's at 32 slots of 2,048, and no byte."""
-    b, s, width = keys.shape
-    h, d = q.shape[2:]
-    own = jnp.eye(h, dtype=q.dtype)
-    spread = (q[:, 0, :, :, None] * own[:, None, :]).reshape(b, width, h)
-    scores = jnp.einsum("bsc,bch->bsh", keys, spread,
-                        preferred_element_type=jnp.float32) * d ** -0.5
-    seen = jnp.arange(s)[None, :] <= positions                  # [B, S]
-    probs = jax.nn.softmax(jnp.where(seen[..., None], scores, -1e30), 1)
-    mixed = jnp.einsum("bsh,bsc->bhc", probs.astype(values.dtype), values,
-                       preferred_element_type=jnp.float32)
-    out = (mixed.reshape(b, h, h, d) * own[None, :, :, None]).sum(2)
-    return out[:, None].astype(q.dtype)
-
-
 def _attention(cfg: OlmoHybridConfig, start_pos, positions):
     """The mixer of a run of `full` layers: attention through the slot
     cache with a norm over all of q's and all of k's heads and no
     rotary turn. A prefill goes through `llama._cached_attention` a
     block of queries at a time, so that its scores are never [T,
-    max_seq] a head; a decode step through `_attend_one_token`."""
+    max_seq] a head, and so does any call off the TPU; on a TPU a
+    decode step hands the stacks whole to
+    `attention.decode_attention`."""
     def mixer(h, lp, rope, state, handed):
         (k_stack, v_stack), layer = state
         b, t = h.shape[:2]
@@ -228,13 +207,14 @@ def _attention(cfg: OlmoHybridConfig, start_pos, positions):
                                      start_pos)
         v_stack = decoder.write_rows(v_stack, layer, v.reshape(b, t, -1),
                                      start_pos)
-        max_seq = k_stack.shape[2]
-        keys = decoder.layer_rows(k_stack, layer, 0, max_seq)
-        values = decoder.layer_rows(v_stack, layer, 0, max_seq)
         q = q.astype(k_stack.dtype)
-        if t == 1:
-            out = _attend_one_token(q, keys, values, positions)
+        if t == 1 and attention.on_tpu():
+            out = attention.decode_attention(
+                q[:, 0], k_stack, v_stack, layer, positions[:, 0] + 1)[:, None]
         else:
+            max_seq = k_stack.shape[2]
+            keys = decoder.layer_rows(k_stack, layer, 0, max_seq)
+            values = decoder.layer_rows(v_stack, layer, 0, max_seq)
             by_head = (b, max_seq, cfg.n_kv_heads, cfg.head_dim)
             out, = _by_query_blocks(
                 lambda q, pos: (llama._cached_attention(

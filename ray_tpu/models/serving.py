@@ -44,17 +44,31 @@ through the slot cache. The engine knows no model; it takes from here
   there), which nothing takes yet;
 - ``keys_attended(cfg, lengths) -> per row``: of `lengths` cached keys
   (host integers) how many the next token attends: all of them, unless
-  the model selects keys.
+  the model selects keys;
+- ``keys_read(cfg, lengths) -> per row``, or None: the rows a layer's
+  attention fetches for a row of `lengths` keys, where a decode step
+  reads by the slot's length (`ops.attention.decode_attention`: the
+  length rounded up to the kernel's block of rows; the engine holds it
+  to the slot's region). None where a step reads the region whole or
+  reads something that is no region (a ring, selected keys).
 """
 
 from __future__ import annotations
 
 import dataclasses
-from typing import Callable
+from typing import Callable, Optional
 
 
 def _every_key(cfg, lengths):
     return lengths
+
+
+def _keys_read(cfg, lengths):
+    """`lengths` rounded up to `decode_attention`'s block of rows for
+    `cfg`'s key heads."""
+    from ray_tpu.ops.attention import decode_block_rows
+    rows = decode_block_rows(cfg.n_kv_heads, cfg.head_dim, cfg.dtype)
+    return -(-lengths // rows) * rows
 
 
 def _all_rows(cache):
@@ -68,6 +82,7 @@ class ServedModel:
     init_cache: Callable
     keys_attended: Callable = _every_key
     state_leaves: Callable = _all_rows
+    keys_read: Optional[Callable] = None
 
 
 def _llama():
@@ -78,7 +93,7 @@ def _llama():
                                                  start_pos)
         return logits[:, at], cache, {}
 
-    return ServedModel(forward, llama.init_kv_cache)
+    return ServedModel(forward, llama.init_kv_cache, keys_read=_keys_read)
 
 
 def _glm_dsa():
@@ -96,7 +111,8 @@ def _nemotron_h():
 def _olmo_hybrid():
     from ray_tpu.models import olmo_hybrid
     return ServedModel(olmo_hybrid.forward, olmo_hybrid.init_cache,
-                       state_leaves=olmo_hybrid.state_leaves)
+                       state_leaves=olmo_hybrid.state_leaves,
+                       keys_read=_keys_read)
 
 
 def _cohere2_moe():

@@ -20,6 +20,7 @@ the BlockSpec index maps — no KV replication in HBM.
 from __future__ import annotations
 
 import functools
+import math
 from typing import Optional
 
 import jax
@@ -637,3 +638,218 @@ def flash_attention_forward(q, k, v, *, window: Optional[int] = None,
     else:
         out = attention_reference(qt, kt, vt, True, sm_scale, window)
     return out.transpose(0, 2, 1, 3)
+
+
+# ---------------------------------------------------------------------------
+# Decode attention: one token a slot over the slot cache where it lies
+# ---------------------------------------------------------------------------
+
+# A block of a leaf holds at most this many positions and this many
+# bytes. A slot's count of keys is rounded up to a block, so half a
+# block a slot and leaf is fetched for nothing, and a grid step costs
+# some 0.35 us whether it fetches or not: 256 positions keep both near a
+# tenth of what a half-filled region of 1,024 or 2,048 costs to read
+# (PERF.md, PR 43). Two blocks of K and two of V are in fast memory at
+# once.
+_DECODE_ROWS = 256
+_DECODE_BLOCK_BYTES = 2 << 20
+
+
+def decode_block_rows(kv_heads: int, head_dim: int, dtype) -> int:
+    """Positions of a block of `decode_attention`, from a leaf's shapes
+    alone: the largest power of two that is at most `_DECODE_ROWS` and
+    whose keys are at most `_DECODE_BLOCK_BYTES`. A region shorter than
+    that is one block."""
+    row = kv_heads * head_dim * jnp.dtype(dtype).itemsize
+    rows = min(_DECODE_ROWS, max(16, _DECODE_BLOCK_BYTES // row))
+    return 1 << (rows.bit_length() - 1)
+
+
+def _decode_kernel(layer_ref, len_ref, q_ref, own_ref, k_ref, v_ref, o_ref,
+                   qs_ref, m_ref, l_ref, acc_ref, *, rows: int, group: int,
+                   lane_heads: int, rep: int, sm_scale: float):
+    """One slot's block of `rows` positions. A block of K or V is
+    [rows x group, lane_heads x D] as the leaf stores it: `group` key
+    heads a position lie in consecutive rows (the dense leaf's
+    [S, Hkv, D], rows and heads merged) or `lane_heads` of them side by
+    side in a row (Olmo-Hybrid's [S, Hkv x D]). Either way the scores of
+    all query heads are one product with the block where it lies: q is
+    [heads, lane_heads x D] with a head's channels under its key head's
+    lanes and zeros elsewhere, `own_ref` [heads, rows x group] takes
+    the columns of other key heads' rows out (-1e30; zeros where a row
+    holds every head), and of the weighted sum [heads,
+    lane_heads x D] a head keeps the D lanes of its key head."""
+    del layer_ref  # the K and V blocks' index maps read it
+    i = pl.program_id(1)
+    n = len_ref[pl.program_id(0)]
+    d = q_ref.shape[-1]
+
+    @pl.when(i == 0)
+    def _init():
+        m_ref[:] = jnp.full_like(m_ref, _NEG_INF)
+        l_ref[:] = jnp.zeros_like(l_ref)
+        acc_ref[:] = jnp.zeros_like(acc_ref)
+        q = q_ref[...]
+        if lane_heads > 1:
+            wide = jnp.concatenate([q.astype(jnp.float32)] * lane_heads, 1)
+            lane = jax.lax.broadcasted_iota(jnp.int32, wide.shape, 1)
+            first = jax.lax.broadcasted_iota(
+                jnp.int32, wide.shape, 0) // rep * d
+            q = jnp.where((lane >= first) & (lane < first + d), wide,
+                          0.0).astype(q.dtype)
+        qs_ref[...] = q
+
+    def attend(edge: bool):
+        k, v = k_ref[...], v_ref[...]
+        s = jax.lax.dot_general(
+            qs_ref[...], k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * sm_scale + own_ref[...]
+        if edge:
+            # The slot's last block: rows past its length hold whatever
+            # was there before. Their scores are masked, and they are
+            # zeroed in V (0 x NaN is NaN).
+            left = (n - i * rows) * group
+            s = jnp.where(jax.lax.broadcasted_iota(
+                jnp.int32, s.shape, 1) < left, s, _NEG_INF)
+            v = jnp.where(jax.lax.broadcasted_iota(
+                jnp.int32, v.shape, 0) < left, v.astype(jnp.float32),
+                0.0).astype(v.dtype)
+        # Every head sees a key in every block computed (the block's
+        # first position lies under the length), so a masked column's
+        # exp is 0 and needs no second mask.
+        m_prev = m_ref[:]
+        m_next = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+        p = jnp.exp(s - m_next[:, :1])
+        correction = jnp.exp(m_prev - m_next)
+        l_ref[:] = l_ref[:] * correction + jnp.sum(p, axis=-1, keepdims=True)
+        m_ref[:] = m_next
+        acc_ref[:] = acc_ref[:] * correction[:, :1] + jax.lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)
+
+    # A block past the slot's length is neither fetched (its index map
+    # names the next slot's first block all along) nor computed.
+    start = i * rows
+    pl.when(start + rows <= n)(lambda: attend(False))
+    pl.when((start < n) & (start + rows > n))(lambda: attend(True))
+
+    @pl.when(i == pl.num_programs(1) - 1)
+    def _finalize():
+        inv = 1.0 / l_ref[:, :1]
+        if lane_heads == 1:
+            o_ref[...] = acc_ref[:] * inv
+        else:
+            o_ref[...] = jnp.zeros_like(o_ref)
+            for kv in range(lane_heads):
+                at = slice(kv * rep, (kv + 1) * rep)
+                o_ref[at, :] = acc_ref[at, kv * d:(kv + 1) * d] * inv[at]
+
+
+@functools.partial(jax.jit,
+                   static_argnames=("rows", "group", "rep", "interpret"))
+def _decode_call(q, own, k, v, layer, lengths, *, rows: int, group: int,
+                 rep: int, interpret: bool):
+    """The kernel's call: q [B, heads, D], k and v [layers, B,
+    S x group, width]. Jitted, so that a program's layers trace and
+    lower it once."""
+    _, slots, span, width = k.shape
+    heads, d = q.shape[1:]
+    lane_heads = width // d
+    block = rows * group
+
+    def rows_of(b, i, layer, lengths):
+        # Past the slot's last needed block: the next slot's first, so
+        # that it is on its way while this slot's last is worked on and
+        # is not fetched again when its turn comes.
+        needed = i * rows < lengths[b]
+        ahead = jnp.minimum(b + 1, slots - 1)
+        return (layer[0], jnp.where(needed, b, ahead),
+                jnp.where(needed, i, 0), 0)
+
+    leaf = pl.BlockSpec((None, None, block, width), rows_of)
+    a_slot = pl.BlockSpec((None, heads, d), lambda b, i, *_: (b, 0, 0))
+    block_bytes = block * width * k.dtype.itemsize
+    return pl.pallas_call(
+        functools.partial(
+            _decode_kernel, rows=rows, group=group, lane_heads=lane_heads,
+            rep=rep, sm_scale=d ** -0.5),
+        out_shape=jax.ShapeDtypeStruct(q.shape, jnp.float32),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(slots, pl.cdiv(span, block)),
+            in_specs=[a_slot,
+                      pl.BlockSpec(own.shape, lambda b, i, *_: (0, 0)),
+                      leaf, leaf],
+            out_specs=a_slot,
+            scratch_shapes=[
+                pltpu.VMEM((heads, width), q.dtype),       # q, spread
+                pltpu.VMEM((heads, 128), jnp.float32),     # m
+                pltpu.VMEM((heads, 128), jnp.float32),     # l
+                pltpu.VMEM((heads, width), jnp.float32),   # acc
+            ]),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=6 * block_bytes + (16 << 20)),
+        interpret=interpret,
+        name="decode_attention",
+    )(layer[None], lengths, q, own, k, v)
+
+
+def decode_attention(q, k_stack, v_stack, layer, lengths, *,
+                     interpret: bool = False):
+    """One token a slot attends the keys its slot holds, read out of the
+    run's stacks where they lie: q [B, H, D] in the stacks' dtype;
+    `k_stack` and `v_stack` a run's leaves whole, [layers, B, S, Hkv, D]
+    or [layers, B, S, Hkv x D]; `layer` an int32 scalar; `lengths`
+    int32 [B], the keys a row sees (its position + 1, this step's row
+    written) -> [B, H, D].
+
+    The grid is (slot, block of `decode_block_rows` positions). `layer`
+    and `lengths` are scalar-prefetch arguments: the K and V blocks'
+    index maps pick (layer, slot, block), and past a slot's length the
+    next slot's first block, so nothing slices a stack, no other layer
+    is touched, and a block past a slot's length is neither fetched nor
+    computed. Online softmax across blocks; scores, mask and both
+    accumulations float32, the weights cast to the stacks' dtype for
+    the product with V, and the `H // Hkv` query heads of a key head
+    contracted together: what `llama._cached_attention` guarantees.
+
+    On a TPU backend this is always the compiled kernel: `interpret`
+    never reaches a TPU call, and a kernel Mosaic refuses is an error.
+    On other backends it is `llama._cached_attention` on the sliced
+    layer (looked up in its module when traced), unless `interpret=True`
+    runs the kernel through the Pallas interpreter (used by tests)."""
+    b, h, d = q.shape
+    span = k_stack.shape[2]
+    kv_heads = math.prod(k_stack.shape[3:]) // d
+    lengths = jnp.clip(lengths, 1, span).astype(jnp.int32)
+    interpret = interpret and not on_tpu()
+    if not (on_tpu() or interpret):
+        from ray_tpu.models import decoder, llama
+        keys, values = (
+            decoder.layer_rows(x, layer, 0, span).reshape(
+                b, span, kv_heads, d) for x in (k_stack, v_stack))
+        plain = llama._cached_attention  # raylint: disable=R3 -- the plain path is the served model's own, found by the name the benchmark's tests patch; no second copy of its arithmetic lives here
+        return plain(None, q[:, None], keys, values,
+                     lengths[:, None] - 1)[:, 0]
+    rows = min(decode_block_rows(kv_heads, d, k_stack.dtype), span)
+    group = kv_heads if k_stack.ndim == 5 else 1
+    if group > 1:
+        # [S, Hkv, D] as [S x Hkv, D]: the same bytes, as the TPU tiles
+        # them too (a tile is 8 rows of 128 lanes).
+        k_stack, v_stack = (x.reshape(x.shape[:2] + (span * group, d))
+                            for x in (k_stack, v_stack))
+    # Query heads in whole tiles of the stacks' dtype; the rows added
+    # attend like any other and are cut off.
+    padded = -(-h // 16) * 16
+    q = jnp.pad(q, ((0, 0), (0, padded - h), (0, 0)))
+    rep = h // kv_heads
+    # A block's row r holds key head r % group; a query head's own is
+    # head // rep. (Every head's, where a row holds them all.)
+    own = jnp.where(
+        jnp.arange(rows * group)[None, :] % group
+        == jnp.arange(padded)[:, None] // rep % group, 0.0, _NEG_INF)
+    out = _decode_call(q, own, k_stack, v_stack,
+                       jnp.asarray(layer, jnp.int32), lengths, rows=rows,
+                       group=group, rep=rep, interpret=interpret)
+    return out[:, :h].astype(q.dtype)

@@ -282,8 +282,8 @@ class LLMEngine:
             "admit_waves_behind_block", "slot_steps_stale", "admissions",
             "kv_blocks_read_back", "kv_bytes_read_back",
             "kv_readbacks_deferred", "kv_readbacks_forced",
-            "keys_cached", "keys_attended", "blocks_behind_wave",
-            "blocks_plain"), 0)
+            "keys_cached", "keys_attended", "keys_read",
+            "blocks_behind_wave", "blocks_plain"), 0)
         # What the model counts while it runs (nothing, for most) joins
         # the totals under the model's own names, which one abstract
         # evaluation of a decode step gives. The same evaluation says
@@ -791,7 +791,9 @@ class LLMEngine:
                 # over the active slots of every decode dispatch the
                 # keys their caches held and those of them the step
                 # attended (fewer where the model selects keys:
-                # `ServedModel.keys_attended`); the blocks handed over
+                # `ServedModel.keys_attended`) and the rows a layer's
+                # attention fetched for them (`ServedModel.keys_read`;
+                # 0 for a model that names none); the blocks handed over
                 # behind another, those with a wave's prefills in front
                 # of them and those without (one `engine.block_gap.*`
                 # record each); and what the model
@@ -978,6 +980,11 @@ class LLMEngine:
             attrs = {"keys_cached": int(lengths.sum()),
                      "keys_attended": int(self._served.keys_attended(
                          self.cfg, lengths).sum())}
+            if self._served.keys_read is not None:
+                # The fed token's row is written before the step reads.
+                attrs["keys_read"] = int(np.minimum(
+                    self._served.keys_read(self.cfg, lengths + 1),
+                    self.max_seq).sum())
             for name, n in attrs.items():
                 self._totals[name] += n
         with critical_path.span("engine.decode_dispatch", active=active,

@@ -6,9 +6,13 @@ it, and nothing but the stacks is larger than a layer's leaf (so the
 repeat and the up-cast of the cache cannot come back unnoticed); and
 the forward pass against the form it had up to PR 32 (kept here too:
 the cache scanned beside the parameters, a layer's slices written
-whole), to the bit."""
+whole), to the bit; and a decode step through
+`ops.attention.decode_attention`, the kernel a TPU runs it through,
+interpreted here, against the plain path at the logits."""
 
 import dataclasses
+import functools
+import types
 
 import numpy as np
 import pytest
@@ -18,6 +22,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from ray_tpu.models import decoder, glm_dsa, llama
+from ray_tpu.ops import attention
 from ray_tpu.ops.norms import layer_norm, rms_norm_reference
 from ray_tpu.models.llama import (
     LlamaConfig,
@@ -348,3 +353,73 @@ def test_logits_and_cache_are_those_of_the_scanned_cache_to_the_bit(
     # The decode steps wrote: a row's last key is not the prefill's.
     assert not np.array_equal(jax.tree.leaves(now[-1][1])[0],
                               jax.tree.leaves(now[0][1])[0])
+
+
+# ---------------------------------------------------------------------------
+# A decode step through the kernel
+# ---------------------------------------------------------------------------
+
+
+def through_the_kernel(monkeypatch, module, rows):
+    """What `module`'s mixer sees on a TPU, on the CPU: a call of one
+    token a slot goes to `decode_attention`, interpreted, in blocks of
+    at most `rows` positions."""
+    monkeypatch.setattr(attention, "_DECODE_ROWS", rows)
+    monkeypatch.setattr(module, "attention", types.SimpleNamespace(
+        on_tpu=lambda: True, decode_attention=functools.partial(
+            attention.decode_attention, interpret=True)))
+
+
+@pytest.mark.parametrize("rows", [16, 256], ids=["blocks-of-16", "one-block"])
+@pytest.mark.parametrize("lens", [(17, 9, 1), (16, 32, 33), (64, 5, 48)],
+                         ids=lambda lens: "-".join(map(str, lens)))
+def test_a_decode_step_through_the_kernel_equals_the_plain_path(
+        monkeypatch, lens, rows):
+    """Rows prefilled to their own lengths decode four steps together,
+    once as the CPU runs them and once as a TPU does: the same logits
+    (float32, to a few of its last digits: the kernel sums a block at a
+    time) and the same rows written."""
+    cfg = LlamaConfig.debug()
+    params = init_params(cfg, jax.random.PRNGKey(3))
+    lens, steps = np.asarray(lens), 4
+    tokens = np.random.default_rng(int(lens.sum())).integers(
+        0, cfg.vocab_size, (len(lens), lens.max() + steps), dtype=np.int32)
+    _, filled = forward_with_cache(
+        params, jnp.asarray(tokens[:, :lens.max()]), cfg,
+        init_kv_cache(cfg, len(lens), 96), jnp.zeros(len(lens), jnp.int32))
+
+    def decoded():
+        out, cache, at = [], filled, np.arange(len(lens))
+        for i in range(steps):
+            logits, cache = forward_with_cache(
+                params, jnp.asarray(tokens[at, lens + i][:, None]), cfg,
+                cache, jnp.asarray(lens + i, jnp.int32))
+            out.append(np.asarray(logits))
+        return np.stack(out), cache
+
+    want, plain_cache = decoded()
+    through_the_kernel(monkeypatch, llama, rows)
+    got, cache = decoded()
+    np.testing.assert_allclose(got, want, atol=2e-6 * np.abs(want).max())
+    assert not np.array_equal(got, want)  # it did go another way
+    for x, y in zip(jax.tree.leaves(cache), jax.tree.leaves(plain_cache)):
+        np.testing.assert_allclose(x, y, atol=1e-6)
+
+
+@pytest.mark.parametrize("family,cfg,read", [
+    # Mistral-7B's key heads: blocks of 256 rows.
+    ("dense", dataclasses.replace(LlamaConfig(), n_kv_heads=8, dim=4096,
+                                  n_heads=32, dtype=jnp.bfloat16),
+     [256, 256, 512, 1024]),
+    # A model whose step reads no region by its length names none.
+    ("glm_dsa", WALKED["glm_dsa"][0], None),
+], ids=["dense", "glm_dsa"])
+def test_the_rows_a_step_reads_are_the_lengths_in_whole_blocks(family, cfg,
+                                                               read):
+    from ray_tpu.models.serving import served_model
+    keys_read = served_model(cfg).keys_read
+    if read is None:
+        assert keys_read is None
+    else:
+        got = keys_read(cfg, np.array([1, 256, 257, 1000]))
+        assert got.tolist() == read

@@ -77,6 +77,48 @@ def _scheduled(text):
     return "\n".join(kept)
 
 
+def _engines_program(one_chip, name, program, bucket, count_names):
+    """The engine's own decode or prefill program for the configuration
+    `benchmark/configs/<name>.json` at its cell's slots, compiled for
+    the described chip from shapes alone: (cfg, params, cache,
+    compiled), the first three as shapes."""
+    from benchmark.harness.manifest import ROOT, load_json, model_adapter
+    from ray_tpu.models.serving import served_model
+    from ray_tpu.serve.llm import LLMEngine
+
+    config = load_json(ROOT, "benchmark", "configs", name + ".json")
+    model = model_adapter(config)
+    cfg = model.program_config(config)
+    plan = config["serve"]
+    n, rows = plan["max_batch_size"], plan["max_seq_len"]
+
+    def on_chip(tree):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=one_chip), tree)
+
+    def ints(*dims, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    params = on_chip(jax.eval_shape(
+        lambda: model.init(cfg, jax.random.PRNGKey(0))))
+    engine = LLMEngine.__new__(LLMEngine)  # its programs, no device
+    engine.cfg, engine._served = cfg, served_model(cfg)
+    cache = on_chip(jax.eval_shape(
+        lambda: engine._served.init_cache(cfg, n, rows)))
+    engine.max_seq, engine.decode_steps, engine.n_slots = rows, 1, n
+    engine._count_names = count_names
+    if program == "decode":
+        compiled = jax.jit(engine._decode_impl, donate_argnums=(1,)).lower(
+            params, cache, ints(n), ints(n), ints(n, dtype=jnp.float32),
+            ints(n), ints(2, dtype=jnp.uint32)).compile()
+    else:
+        compiled = jax.jit(engine._prefill_impl, donate_argnums=(1,),
+                           static_argnums=(6,)).lower(
+            params, cache, ints(1, bucket), ints(), ints(), ints(),
+            bucket).compile()
+    return cfg, params, cache, compiled
+
+
 @pytest.mark.parametrize("name,program,bucket,resident,products", [
     ("glm-5.2-serve", "decode", 0, 9.3e9, 3),
     ("glm-5.2-serve", "prefill", 8192, 9.3e9, 3),
@@ -98,44 +140,13 @@ def test_served_step_compiles_for_the_v5e(one_chip, name, program, bucket,
     axis; Command A+ at 16 x 16,384, whose sliding layers' rings of
     4,096 rows ride it beside the full layer's rows. `products`: the grouped products an expert layer has, three of
     a gated SwiGLU, two of relu^2."""
-    from benchmark.harness.manifest import ROOT, load_json, model_adapter
-    from ray_tpu.models.serving import served_model
     from ray_tpu.ops import attention, grouped_matmul
-    from ray_tpu.serve.llm import LLMEngine
 
     monkeypatch.setattr(grouped_matmul, "on_tpu", lambda: True)
     monkeypatch.setattr(attention, "on_tpu", lambda: True)
-    config = load_json(ROOT, "benchmark", "configs", name + ".json")
-    model = model_adapter(config)
-    cfg = model.program_config(config)
-    plan = config["serve"]
-    n = plan["max_batch_size"]
-
-    def on_chip(tree):
-        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
-            x.shape, x.dtype, sharding=one_chip), tree)
-
-    def ints(*dims, dtype=jnp.int32):
-        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
-
-    params = on_chip(jax.eval_shape(
-        lambda: model.init(cfg, jax.random.PRNGKey(0))))
-    engine = LLMEngine.__new__(LLMEngine)  # its programs, no device
-    engine.cfg, engine._served = cfg, served_model(cfg)
-    cache = on_chip(jax.eval_shape(
-        lambda: engine._served.init_cache(cfg, n, plan["max_seq_len"])))
-    engine.max_seq, engine.decode_steps, engine.n_slots = \
-        plan["max_seq_len"], 1, n
-    engine._count_names = ("pair_overflows", "pairs_held", "pairs_routed")
-    if program == "decode":
-        compiled = jax.jit(engine._decode_impl, donate_argnums=(1,)).lower(
-            params, cache, ints(n), ints(n), ints(n, dtype=jnp.float32),
-            ints(n), ints(2, dtype=jnp.uint32)).compile()
-    else:
-        compiled = jax.jit(engine._prefill_impl, donate_argnums=(1,),
-                           static_argnums=(6,)).lower(
-            params, cache, ints(1, bucket), ints(), ints(), ints(),
-            bucket).compile()
+    cfg, params, _, compiled = _engines_program(
+        one_chip, name, program, bucket,
+        ("pair_overflows", "pairs_held", "pairs_routed"))
     text = compiled.as_text()
     kernels = re.findall(r"%([\w-]+?)(?:\.\d+)? = \S+ custom-call\(", text)
     # Each run of expert layers is one scan, whose body holds the
@@ -176,6 +187,54 @@ def test_served_step_compiles_for_the_v5e(one_chip, name, program, bucket,
         < 16.0e9
 
 
+def _reads_the_cache_through_the_kernel(scheduled, scans, *regions):
+    """A decode program's attention over the slot cache: one
+    `decode_attention` call a scan of attention layers, in the body
+    under `attn`, handed the carried stacks as they are, so that no op
+    leaves an array of a layer's keys or values (`regions`: the shapes
+    such an array could have; PERF.md, PR 43: the dense step's slice of
+    a layer out of the stack was a fifth of its time, and both steps
+    read a slot's whole region whatever it held)."""
+    calls = re.findall(r'%decode_attention(?:\.\d+)? = .* custom-call\('
+                       r'.*op_name="([^"]*)"', scheduled)
+    assert len(calls) == scans
+    assert all(re.search(r"while/body/(?:closed_call/)?attn/.*"
+                         r"decode_attention", path) for path in calls)
+    for region in regions:
+        dims = ",".join(map(str, region))
+        assert not re.findall(
+            rf"= \w+\[(?:1,)?{dims}\]\S* (?:fusion|copy|copy-start|"
+            r"dynamic-slice|convert|transpose)\(", scheduled)
+
+
+def test_the_dense_decode_step_compiles_for_the_v5e(one_chip, monkeypatch):
+    """Mistral-7B's served cut (`mistral-7b-v0.3-serve.json`, 16 layers,
+    32 slots of 1,024) through the engine's own decode program: the
+    layer scan's body holds one `decode_attention` call, which takes the
+    carried K and V stacks [16, 32, 1024, 8, 128] as [16, 32, 8192, 128]
+    (a bitcast: the TPU tiles 8 rows of 128 lanes either way), and the
+    layer's keys and values are never sliced out of them."""
+    from ray_tpu.ops import attention
+
+    monkeypatch.setattr(attention, "on_tpu", lambda: True)
+    cfg, _, cache, compiled = _engines_program(
+        one_chip, "mistral-7b-v0.3-serve", "decode", 0, ())
+    _, n, rows = cache["k"].shape[:3]
+    text = compiled.as_text()
+    heads, d = cfg.n_kv_heads, cfg.head_dim
+    assert cache["k"].shape == (cfg.n_layers, n, rows, heads, d)
+    _reads_the_cache_through_the_kernel(
+        _scheduled(text), 1, (n, rows, heads, d), (n, rows * heads, d))
+    # The stacks reach the kernel as bitcasts of the carried leaves.
+    assert len(re.findall(
+        rf"= bf16\[{cfg.n_layers},{n},{rows * heads},{d}\]\S* bitcast\(",
+        text)) == 2
+    memory = compiled.memory_analysis()
+    assert memory.temp_size_in_bytes < 0.1e9
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes \
+        < 16.0e9
+
+
 @pytest.mark.parametrize("program,bucket", [("decode", 0),
                                             ("prefill", 2048)])
 def test_olmo_hybrids_step_compiles_for_the_v5e(one_chip, program, bucket,
@@ -188,54 +247,29 @@ def test_olmo_hybrids_step_compiles_for_the_v5e(one_chip, program, bucket,
     and back (5.9 GB of temporaries, over the chip); with heads merged
     and attention on a [max_seq, 30, 128] view it copied a layer's keys
     and values once a step (`models/olmo_hybrid.py` says what a decode
-    step does instead). A decode step updates a delta layer's states
+    step does instead: `ops.attention.decode_attention` is handed the
+    stacks whole). A decode step updates a delta layer's states
     where they lie in the run's stack (`ops/delta_update.py`'s kernel,
-    which this process's CPU backend would not choose: the test says it
-    is on a TPU): nothing else makes an array of a layer's states or of
-    the stack."""
-    from benchmark.harness.manifest import ROOT, load_json, model_adapter
-    from ray_tpu.models.serving import served_model
-    from ray_tpu.ops import delta_update
-    from ray_tpu.serve.llm import LLMEngine
+    which this process's CPU backend would not choose, as it would not
+    the attention's: the test says it is on a TPU): nothing else makes
+    an array of a layer's states or of the stack."""
+    from ray_tpu.ops import attention, delta_update
 
     monkeypatch.setattr(delta_update, "on_tpu", lambda: True)
-    config = load_json(ROOT, "benchmark", "configs",
-                       "olmo-hybrid-7b-serve.json")
-    model = model_adapter(config)
-    cfg = model.program_config(config)
-    plan = config["serve"]
-    n, rows = plan["max_batch_size"], plan["max_seq_len"]
-
-    def on_chip(tree):
-        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
-            x.shape, x.dtype, sharding=one_chip), tree)
-
-    def ints(*dims, dtype=jnp.int32):
-        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
-
-    params = on_chip(jax.eval_shape(
-        lambda: model.init(cfg, jax.random.PRNGKey(0))))
-    engine = LLMEngine.__new__(LLMEngine)  # its programs, no device
-    engine.cfg, engine._served = cfg, served_model(cfg)
-    cache = on_chip(jax.eval_shape(
-        lambda: engine._served.init_cache(cfg, n, rows)))
-    engine.max_seq, engine.decode_steps, engine.n_slots = rows, 1, n
-    engine._count_names = ("delta_scan_tokens", "delta_state_resets")
-    if program == "decode":
-        compiled = jax.jit(engine._decode_impl, donate_argnums=(1,)).lower(
-            params, cache, ints(n), ints(n), ints(n, dtype=jnp.float32),
-            ints(n), ints(2, dtype=jnp.uint32)).compile()
-    else:
-        compiled = jax.jit(engine._prefill_impl, donate_argnums=(1,),
-                           static_argnums=(6,)).lower(
-            params, cache, ints(1, bucket), ints(), ints(), ints(),
-            bucket).compile()
+    monkeypatch.setattr(attention, "on_tpu", lambda: True)
+    cfg, params, cache, compiled = _engines_program(
+        one_chip, "olmo-hybrid-7b-serve", program, bucket,
+        ("delta_scan_tokens", "delta_state_resets"))
+    _, n, rows = cache["runs"][1]["k"].shape[:3]
     scheduled = _scheduled(compiled.as_text())
     width = cfg.n_kv_heads * cfg.head_dim
     assert not re.findall(
         rf"= \w+\[1,{n},{rows},{width}\]\S* (?:copy|convert|transpose)\(",
         scheduled)
     if program == "decode":
+        _reads_the_cache_through_the_kernel(
+            scheduled, sum("conv_q" not in run for run in params["runs"]),
+            (n, rows, width))
         # One kernel a linear run, in its scan's body, and the state
         # leaf aliased through it: a second reader of the carried stack
         # there would show as a `copy` of it (212 MB a layer), the

@@ -20,6 +20,7 @@ from ray_tpu._private import flight_recorder
 from ray_tpu.models import decoder, gated_delta, llama, olmo_hybrid
 from ray_tpu.models.serving import served_model
 from ray_tpu.serve.llm import LLMEngine, SamplingParams
+from tests.models.test_cached_attention import through_the_kernel
 from tools import glm_logit_check
 
 FILE = load_json(ROOT, "benchmark", "configs", "olmo-hybrid-7b-serve.json")
@@ -274,21 +275,39 @@ def test_each_mixer_is_scoped_by_its_kind(params):
         assert "attn/delta" not in text and "delta/attn" not in text
 
 
-def test_a_decode_step_attends_as_the_prefills_attention_does(params):
-    """`_attend_one_token` on the merged axis against
-    `llama._cached_attention` on the [rows, heads, head size] view."""
-    rng = np.random.default_rng(0)
-    b, s, h, d = 3, 24, CFG.n_heads, CFG.head_dim
-    q = jnp.asarray(rng.normal(size=(b, 1, h, d)), jnp.float32)
-    keys, values = (jnp.asarray(rng.normal(size=(b, s, h * d)), jnp.float32)
-                    for _ in range(2))
-    positions = jnp.asarray([[23], [7], [0]], jnp.int32)
-    want = llama._cached_attention(
-        CFG, q, keys.reshape(b, s, h, d), values.reshape(b, s, h, d),
-        positions)
-    np.testing.assert_allclose(
-        olmo_hybrid._attend_one_token(q, keys, values, positions), want,
-        atol=1e-5)
+@pytest.mark.parametrize("rows", [16, 256], ids=["blocks-of-16", "one-block"])
+@pytest.mark.parametrize("lens", [(17, 9), (16, 1), (29, 15)],
+                         ids=lambda lens: "-".join(map(str, lens)))
+def test_a_decode_step_through_the_kernel_equals_the_plain_path(
+        params, monkeypatch, lens, rows):
+    """The full layers' decode step through
+    `ops.attention.decode_attention` on the merged axis, the kernel a
+    TPU runs, interpreted here, against `llama._cached_attention` on the
+    [rows, heads, head size] view, which the CPU takes: rows prefilled
+    to their own lengths decode three steps together, the same logits
+    and the same cache either way."""
+    lens, steps = np.asarray(lens), 3
+    tokens = _tokens((2, lens.max() + steps), seed=int(lens.sum()))
+    _, filled = olmo_hybrid.forward_with_cache(
+        params, tokens[:, :lens.max()], CFG, _cache(),
+        jnp.zeros(2, jnp.int32), at=jnp.asarray(lens - 1, jnp.int32))
+
+    def decoded():
+        out, cache, at = [], filled, np.arange(2)
+        for i in range(steps):
+            fed = jnp.asarray(np.asarray(tokens)[at, lens + i][:, None])
+            logits, cache = olmo_hybrid.forward_with_cache(
+                params, fed, CFG, cache, jnp.asarray(lens + i, jnp.int32))
+            out.append(np.asarray(logits))
+        return np.stack(out), cache
+
+    want, plain_cache = decoded()
+    through_the_kernel(monkeypatch, olmo_hybrid, rows)
+    got, cache = decoded()
+    np.testing.assert_allclose(got, want, atol=3e-6 * np.abs(want).max())
+    assert not np.array_equal(got, want)  # it did go another way
+    for x, y in zip(jax.tree.leaves(cache), jax.tree.leaves(plain_cache)):
+        np.testing.assert_allclose(x, y, atol=3e-6 * np.abs(y).max())
 
 
 # -- the engine over a cache with state leaves -------------------------------

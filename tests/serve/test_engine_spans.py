@@ -230,6 +230,12 @@ def test_spans_tile_the_loop_and_totals_add_up(params, ring):
     assert sum(s["attrs"]["slot_steps"] for s in blocks) == 2 * len(blocks)
     assert all(0 <= s["attrs"]["keys_cached"] <= s["attrs"]["keys_reserved"]
                == 2 * 64 for s in steps)
+    # And the rows a layer's attention fetches (PR 43): an active slot's
+    # keys with the fed token's, rounded up to the kernel's block, here
+    # the slot's whole region of 64.
+    assert all(s["attrs"]["keys_cached"] < s["attrs"]["keys_read"]
+               == 64 * s["attrs"]["active"] for s in steps)
+    assert totals["keys_read"] == sum(s["attrs"]["keys_read"] for s in steps)
     # Every decode step's block was consumed, but the last one in
     # flight when the engine stopped.
     assert kept + discarded in (2 * len(steps), 2 * (len(steps) - 1))
