@@ -77,9 +77,10 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from ray_tpu.models import decoder, mamba2, moe
-from ray_tpu.models.glm_dsa import (_KEY_BLOCK, _by_query_blocks,
-                                    _rotate_pairs)
+from ray_tpu.models import decoder, moe
+from ray_tpu.models.serving import (
+    KEY_BLOCK as _KEY_BLOCK, Family, attention_init, by_query_blocks, normal,
+    rotate_pairs)
 from ray_tpu.ops import attention
 
 PUBLISHED_LAYER_TYPES = ("sliding", "sliding", "sliding", "full") * 8
@@ -134,19 +135,12 @@ class Cohere2MoeConfig(moe.MoEConfig):
 # Parameters and cache
 # ---------------------------------------------------------------------------
 
-# Every matrix is drawn in float32 and cast: `mamba2.normal` says why.
-_init = mamba2.normal(0.02)
-
 
 def _init_layer(cfg: Cohere2MoeConfig, key) -> Dict[str, Any]:
-    d, hd = cfg.dim, cfg.head_dim
-    kq, kk, kv, ko, k_ffn = jax.random.split(key, 5)
-    lp = {"attn_norm": jnp.ones(d, cfg.dtype),
-          "wq": _init(kq, (d, cfg.n_heads, hd), cfg.dtype),
-          "wk": _init(kk, (d, cfg.n_kv_heads, hd), cfg.dtype),
-          "wv": _init(kv, (d, cfg.n_kv_heads, hd), cfg.dtype),
-          "wo": _init(ko, (cfg.n_heads, hd, d), cfg.dtype) * d ** -0.5,
-          **moe.expert_init(cfg, jax.random.split(k_ffn, 4), _init)}
+    *k_attention, k_ffn = jax.random.split(key, 5)
+    lp = {"attn_norm": jnp.ones(cfg.dim, cfg.dtype),
+          **attention_init(cfg, normal, k_attention),
+          **moe.expert_init(cfg, jax.random.split(k_ffn, 4), normal)}
     # `expert_init` scales the fused down-projection by the root of its
     # whole width; each of the shared experts in it is an expert of a
     # `n_shared_experts`-th of that.
@@ -154,46 +148,13 @@ def _init_layer(cfg: Cohere2MoeConfig, key) -> Dict[str, Any]:
     return lp
 
 
-def init_params(cfg: Cohere2MoeConfig, rng) -> Dict[str, Any]:
-    """embed (the head too: the embeddings are tied), `runs` (a list,
-    one dict of stacked leaves a run of like layers), final norm."""
-    k_embed, k_layers = jax.random.split(rng)
-    keys = jax.random.split(k_layers, cfg.n_layers)
-    runs, at = [], 0
-    for _, n in cfg.runs():
-        runs.append(jax.vmap(functools.partial(_init_layer, cfg))(
-            keys[at:at + n]))
-        at += n
-    return {"embed": _init(k_embed, (cfg.vocab_size, cfg.dim), cfg.dtype),
-            "runs": runs, "final_norm": jnp.ones(cfg.dim, cfg.dtype)}
-
-
-# The cache leaves of a run, by its kind: a `full` run's are rows, a
-# `sliding` run's rings.
-_LEAVES = {"full": ("k", "v"), "sliding": ("ring_k", "ring_v")}
-
-
-def init_cache(cfg: Cohere2MoeConfig, n_slots: int,
-               max_seq: int) -> Dict[str, Any]:
-    """The slot cache, a run at a time: keys and values, [layers, slots,
-    rows, kv heads, head size], `max_seq` rows a slot of a `full` run
-    (`k`, `v`) and `sliding_window` of a `sliding` one (the rings
-    `ring_k`, `ring_v`)."""
-    runs = []
-    for kind, n in cfg.runs():
-        rows = cfg.sliding_window if kind == "sliding" else max_seq
-        shape = (n, n_slots, rows, cfg.n_kv_heads, cfg.head_dim)
-        runs.append({name: jnp.zeros(shape, cfg.dtype)
-                     for name in _LEAVES[kind]})
-    return {"runs": runs}
-
-
-def state_leaves(cache):
-    """`cache`'s structure with True at a ring (no row a position: the
-    engine may slice its slots and nothing else) and False at a leaf of
-    rows."""
-    return jax.tree_util.tree_map_with_path(
-        lambda path, _: path[-1].key in _LEAVES["sliding"], cache)
+def _leaves(cfg: Cohere2MoeConfig, kind):
+    """A run's cache leaves, as the module's docstring lists them."""
+    keys = (cfg.n_kv_heads, cfg.head_dim)
+    if kind == "sliding":
+        ring = ((cfg.sliding_window,) + keys, cfg.dtype)
+        return {"ring_k": ring, "ring_v": ring}
+    return {"k": (keys, cfg.dtype), "v": (keys, cfg.dtype)}
 
 
 def keys_attended(cfg: Cohere2MoeConfig, lengths):
@@ -340,7 +301,7 @@ def _mixer(cfg: Cohere2MoeConfig, kind, start_pos, positions, at):
                 # The seam the module's docstring describes: the turn
                 # is q's and k's to pay for, not the weights'.
                 q, k, v = lax.optimization_barrier((q, k, v))
-            q, k = _rotate_pairs(q, *rope), _rotate_pairs(k, *rope)
+            q, k = rotate_pairs(q, *rope), rotate_pairs(k, *rope)
         q, k = q.astype(cached), k.astype(cached)
         rows = k_stack.shape[2]
         tr = math.gcd(rows, _KEY_BLOCK)
@@ -362,7 +323,7 @@ def _mixer(cfg: Cohere2MoeConfig, kind, start_pos, positions, at):
             out = _own_keys(
                 t, start_pos,
                 lambda: attention.flash_attention_forward(q, k, v),
-                lambda: _by_query_blocks(attend, t, q, positions)[0])
+                lambda: by_query_blocks(attend, t, q, positions)[0])
             return out, (k_stack, v_stack), handed
 
         with jax.named_scope("window"):
@@ -393,7 +354,7 @@ def _mixer(cfg: Cohere2MoeConfig, kind, start_pos, positions, at):
                 t, start_pos,
                 lambda: attention.flash_attention_forward(
                     q, k, v, window=window),
-                lambda: _by_query_blocks(attend, t, q, positions)[0])
+                lambda: by_query_blocks(attend, t, q, positions)[0])
             k_stack = _write_ring(k_stack, layer, k, start_pos, at)
             v_stack = _write_ring(v_stack, layer, v, start_pos, at)
         return out, (k_stack, v_stack), handed
@@ -402,56 +363,19 @@ def _mixer(cfg: Cohere2MoeConfig, kind, start_pos, positions, at):
 
 
 # ---------------------------------------------------------------------------
-# Forward through the slot cache
+# Through the slot cache (`models.serving`)
 # ---------------------------------------------------------------------------
 
 
-def _hidden(params, tokens, cfg: Cohere2MoeConfig, cache, start_pos, at):
-    """The stack through the slot cache: (final-norm hidden states
-    [B, T, D], new cache, the expert layers' counts)."""
-    positions = start_pos[:, None] + jnp.arange(tokens.shape[1])[None, :]
-    ffn = moe.served_ffn(cfg)
-    runs = [(_mixer(cfg, kind, start_pos, positions, at), ffn, stacked,
-             tuple(run[name] for name in _LEAVES[kind]))
-            for (kind, _), stacked, run in zip(cfg.runs(), params["runs"],
-                                               cache["runs"])]
-    x, states, extras = decoder.hidden_runs(params, tokens, cfg, runs,
-                                            positions=positions)
-    new_cache = {"runs": [dict(zip(_LEAVES[kind], state))
-                          for (kind, _), state in zip(cfg.runs(), states)]}
-    counts = jax.tree.map(lambda *xs: sum(x.sum() for x in xs), *extras)
-    return x, new_cache, counts
+def _halves(cfg: Cohere2MoeConfig, start_pos, positions, at):
+    return {kind: (_mixer(cfg, kind, start_pos, positions, at),
+                   moe.served_ffn(cfg)) for kind in ("sliding", "full")}
 
 
-def _logits(params, x, cfg):
-    """The tied head in float32 (the logits feed an argmax), times
-    `logit_scale`."""
-    out = jnp.einsum("...d,vd->...v", x, params["embed"].astype(cfg.dtype),
-                     preferred_element_type=jnp.float32)
-    return out if cfg.logit_scale == 1.0 else out * cfg.logit_scale
-
-
-def forward(params, tokens, cfg: Cohere2MoeConfig, cache, start_pos, at):
-    """What the engine serves through (`models.serving`): `tokens`
-    [B, T] from per-row absolute offsets `start_pos` [B], prefill (T =
-    the prompt's bucket) and decode (T = 1) alike. Returns (the logits
-    of position `at` of `tokens`, [B, vocab] float32; the new cache,
-    whose rings hold the rows up to `at` and none after; what the expert
-    layers counted over the call, int32 scalars summed over them:
-    `pairs_held`, `pairs_routed`, `pair_overflows`, `experts_touched`,
-    `experts_held_steps`)."""
-    x, cache, counts = _hidden(params, tokens, cfg, cache, start_pos, at)
-    x = lax.dynamic_index_in_dim(x, at, 1, keepdims=False)
-    return _logits(params, x, cfg), cache, counts
-
-
-def forward_with_cache(params, tokens, cfg: Cohere2MoeConfig, cache,
-                       start_pos, at=None, keep=None):
-    """`forward` with the logits of every position (of the first `keep`,
-    where given: a padded prefill's real ones), [B, T, vocab] float32,
-    and no counts: what a comparison with a reference steps through.
-    The rings are left as after position `at` (an int for all rows, or
-    int32 [B], one a row), the last of `tokens` unless given."""
-    at = tokens.shape[1] - 1 if at is None else at
-    x, cache, _ = _hidden(params, tokens, cfg, cache, start_pos, at)
-    return _logits(params, x[:, :keep], cfg), cache
+FAMILY = Family(
+    init_layer=lambda cfg, kind, key: _init_layer(cfg, key), draw=normal,
+    leaves=_leaves, halves=_halves, state=frozenset(("ring_k", "ring_v")),
+    tied=True, keys_attended=keys_attended)
+init_params, init_cache = FAMILY.init_params, FAMILY.init_cache
+state_leaves = FAMILY.state_leaves
+forward, forward_with_cache = FAMILY.forward, FAMILY.forward_with_cache

@@ -71,6 +71,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from ray_tpu.models import mamba2
+from ray_tpu.models.serving import normal
 # `_update`: the recurrence as written, where `delta_update` has it for
 # backends without the kernel; what the tests hold the chunked form to,
 # token by token.
@@ -93,7 +94,6 @@ def init(cfg, key) -> Dict[str, Any]:
     d, h = cfg.dim, cfg.delta_heads
     dk, dv, k = cfg.delta_key_dim, cfg.delta_value_dim, cfg.conv_kernel
     ks = jax.random.split(key, 12)
-    init = mamba2.normal(0.02)
     dt = jnp.exp(jax.random.uniform(ks[6], (h,), jnp.float32,
                                     math.log(1e-3), math.log(1e-1)))
     bound = k ** -0.5
@@ -103,12 +103,12 @@ def init(cfg, key) -> Dict[str, Any]:
                                   bound).astype(cfg.dtype)
 
     return {
-        "wq": init(ks[0], (d, h, dk), cfg.dtype),
-        "wk": init(ks[1], (d, h, dk), cfg.dtype),
-        "wv": init(ks[2], (d, h, dv), cfg.dtype),
-        "wg": init(ks[3], (d, h, dv), cfg.dtype),
-        "wa": init(ks[4], (d, h), cfg.dtype),
-        "wb": init(ks[5], (d, h), cfg.dtype),
+        "wq": normal(ks[0], (d, h, dk), cfg.dtype),
+        "wk": normal(ks[1], (d, h, dk), cfg.dtype),
+        "wv": normal(ks[2], (d, h, dv), cfg.dtype),
+        "wg": normal(ks[3], (d, h, dv), cfg.dtype),
+        "wa": normal(ks[4], (d, h), cfg.dtype),
+        "wb": normal(ks[5], (d, h), cfg.dtype),
         # softplus(dt_bias) = dt
         "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),
         "A_log": jnp.log(jax.random.uniform(ks[7], (h,), jnp.float32, 1.0,
@@ -117,20 +117,19 @@ def init(cfg, key) -> Dict[str, Any]:
         "conv_k": conv(ks[9], h * dk),
         "conv_v": conv(ks[10], h * dv),
         "o_norm": jnp.ones(dv, cfg.dtype),
-        "wo": init(ks[11], (h, dv, d), cfg.dtype) * d ** -0.5,
+        "wo": normal(ks[11], (h, dv, d), cfg.dtype) * d ** -0.5,
     }
 
 
-def init_state(cfg, n_layers: int, n_slots: int) -> Dict[str, Any]:
-    """The four state leaves of a run of `n_layers` delta layers."""
+def state_shapes(cfg) -> Dict[str, Any]:
+    """The four state leaves of a delta layer, as its mixer takes them
+    (`LEAVES`): (shape a layer and slot, dtype)."""
     h, dk, dv = cfg.delta_heads, cfg.delta_key_dim, cfg.delta_value_dim
-    rows = (n_layers, n_slots, cfg.conv_kernel - 1)
-    return {
-        "state": jnp.zeros((n_layers, n_slots, h, dk, dv), cfg.state_dtype),
-        "conv_q": jnp.zeros(rows + (h * dk,), cfg.dtype),
-        "conv_k": jnp.zeros(rows + (h * dk,), cfg.dtype),
-        "conv_v": jnp.zeros(rows + (h * dv,), cfg.dtype),
-    }
+    rows = cfg.conv_kernel - 1
+    return {"state": ((h, dk, dv), cfg.state_dtype),
+            "conv_q": ((rows, h * dk), cfg.dtype),
+            "conv_k": ((rows, h * dk), cfg.dtype),
+            "conv_v": ((rows, h * dv), cfg.dtype)}
 
 
 LEAVES = ("state",) + CONVS

@@ -45,7 +45,6 @@ loss (the model is served, not trained).
 from __future__ import annotations
 
 import dataclasses
-import functools
 import itertools
 import math
 from typing import Any, Dict, Tuple
@@ -57,11 +56,11 @@ from jax import lax
 
 from ray_tpu.models import decoder, moe
 from ray_tpu.models.llama import swiglu
+from ray_tpu.models.serving import (
+    KEY_BLOCK as _KEY_BLOCK, Family, by_query_blocks as _by_query_blocks,
+    rotate_pairs as _rotate_pairs)
 from ray_tpu.ops.norms import layer_norm, rms_norm_reference
 
-# Queries and keys go through attention in blocks of at most this many.
-_QUERY_BLOCK = 256
-_KEY_BLOCK = 1024
 _INDEX_KEY_EPS = 1e-6
 
 def published_kinds(n_layers: int, first_dense: int = 3, freq: int = 4,
@@ -178,59 +177,19 @@ def _init_layer(cfg: GlmDsaConfig, kind, key) -> Dict[str, Any]:
     return lp
 
 
-def init_params(cfg: GlmDsaConfig, rng) -> Dict[str, Any]:
-    """embed, `runs` (a list, one dict of stacked leaves a run of like
-    layers: which leaves a run has says what its layers are), final
-    norm, `out`."""
-    k_embed, k_out, k_layers = jax.random.split(rng, 3)
-    init = jax.nn.initializers.normal(0.02)
-    keys = jax.random.split(k_layers, cfg.n_layers)
-    runs, at = [], 0
-    for kind, n in cfg.runs():
-        runs.append(jax.vmap(functools.partial(_init_layer, cfg, kind))(
-            keys[at:at + n]))
-        at += n
-    return {"embed": init(k_embed, (cfg.vocab_size, cfg.dim), cfg.dtype),
-            "runs": runs,
-            "final_norm": jnp.ones(cfg.dim, cfg.dtype),
-            "out": init(k_out, (cfg.dim, cfg.vocab_size), cfg.dtype)}
-
-
-def init_cache(cfg: GlmDsaConfig, n_slots: int, max_seq: int,
-               dtype=None) -> Dict[str, Any]:
-    """The slot cache, a run at a time: the latent and the rotary key
-    of every layer, the indexer's key of the `full` ones. Every leaf is
-    [layers of the run, slots, max_seq, width]."""
-    dtype = dtype or cfg.dtype
-
-    def zeros(n, width):
-        return jnp.zeros((n, n_slots, max_seq, width), dtype)
-
-    runs = []
-    for (_, indexer), n in cfg.runs():
-        run = {"latent": zeros(n, cfg.kv_lora_rank),
-               "rope": zeros(n, cfg.qk_rope_head_dim)}
-        if indexer == "full":
-            run["index"] = zeros(n, cfg.index_head_dim)
-        runs.append(run)
-    return {"runs": runs}
+def _leaves(cfg: GlmDsaConfig, kind):
+    """A run's cache leaves, all rows: the latent and the rotary key of
+    every layer, the indexer's key of the `full` ones."""
+    leaves = {"latent": ((cfg.kv_lora_rank,), cfg.dtype),
+              "rope": ((cfg.qk_rope_head_dim,), cfg.dtype)}
+    if kind[1] == "full":
+        leaves["index"] = ((cfg.index_head_dim,), cfg.dtype)
+    return leaves
 
 
 # ---------------------------------------------------------------------------
 # The mixer
 # ---------------------------------------------------------------------------
-
-
-def _rotate_pairs(x, cos, sin):
-    """Rotary positions on interleaved pairs (2i, 2i + 1) of the last
-    axis. x: [B, T, ..., D]; cos, sin: [B, T, D / 2]."""
-    extra = x.ndim - 3
-    cos = cos.reshape(cos.shape[:2] + (1,) * extra + cos.shape[2:])
-    sin = sin.reshape(cos.shape)
-    pairs = x.astype(jnp.float32).reshape(x.shape[:-1] + (-1, 2))
-    a, b = pairs[..., 0], pairs[..., 1]
-    out = jnp.stack([a * cos - b * sin, a * sin + b * cos], -1)
-    return out.reshape(x.shape).astype(x.dtype)
 
 
 def _rotate_head(x, cos, sin, n):
@@ -337,23 +296,6 @@ def _attend(q_lat, q_rope, latent, rope_keys, mask, positions, scale):
     return (acc / total[..., None]).transpose(0, 2, 1, 3)
 
 
-def _by_query_blocks(fn, t, *arrays):
-    """`fn` over blocks of the query axis (axis 1 of every array), its
-    results (a tuple of arrays) joined along it again."""
-    tq = math.gcd(t, _QUERY_BLOCK)
-    if tq == t:
-        return fn(*arrays)
-    n = t // tq
-
-    def split(x):
-        x = x.reshape((x.shape[0], n, tq) + x.shape[2:])
-        return jnp.moveaxis(x, 1, 0)
-
-    outs = lax.map(lambda xs: fn(*xs), tuple(split(x) for x in arrays))
-    return tuple(jnp.moveaxis(o, 0, 1).reshape(
-        (o.shape[1], t) + o.shape[3:]) for o in outs)
-
-
 def _mixer(cfg: GlmDsaConfig, indexer, start_pos, positions):
     """The mixer of a run of `full` or of `shared` layers. Its state is
     the run's stacks of the slot cache, (latent, rotary key) and for
@@ -435,70 +377,33 @@ def _mixer(cfg: GlmDsaConfig, indexer, start_pos, positions):
 
 
 # ---------------------------------------------------------------------------
-# Forward through the slot cache
+# Through the slot cache (`models.serving`)
 # ---------------------------------------------------------------------------
 
-def _ffn(cfg: GlmDsaConfig, ffn_kind):
-    return swiglu() if ffn_kind == "dense" else moe.served_ffn(cfg)
+
+def _halves(cfg: GlmDsaConfig, start_pos, positions, at):
+    return {kind: (_mixer(cfg, kind[1], start_pos, positions),
+                   swiglu() if kind[0] == "dense" else moe.served_ffn(cfg))
+            for kind in set(cfg.kinds)}
 
 
-def _logits(params, x, cfg):
-    """The head in float32: the served logits feed an argmax, and two
-    near-equal logits rounded to bfloat16 are a tie."""
-    return jnp.einsum("...d,dv->...v", x, params["out"].astype(cfg.dtype),
-                      preferred_element_type=jnp.float32)
-
-
-def _hidden(params, tokens, cfg: GlmDsaConfig, cache, start_pos):
-    """The stack through the slot cache: (final-norm hidden states
-    [B, T, D], new cache, the expert layers' counts)."""
-    b, t = tokens.shape
-    positions = start_pos[:, None] + jnp.arange(t)[None, :]
-    max_seq = cache["runs"][0]["latent"].shape[2]
-    runs = []
-    for (kind, _), stacked, run in zip(cfg.runs(), params["runs"],
-                                       cache["runs"]):
-        state = (run["latent"], run["rope"]) + (
-            (run["index"],) if kind[1] == "full" else ())
-        runs.append((_mixer(cfg, kind[1], start_pos, positions),
-                     _ffn(cfg, kind[0]), stacked, state))
-    x, states, extras = decoder.hidden_runs(
-        params, tokens, cfg, runs, positions=positions,
-        handed=jnp.zeros((b, t, max_seq), bool))
-    new_cache = {"runs": [dict(zip(("latent", "rope", "index"), state))
-                          for state in states]}
-    # What the expert layers counted, summed over them; the dense runs
-    # report nothing.
-    counted = [e for e in extras if e is not None]
-    counts = jax.tree.map(lambda *xs: sum(x.sum() for x in xs),
-                          *counted) if counted else {}
-    return x, new_cache, counts
-
-
-def forward(params, tokens, cfg: GlmDsaConfig, cache, start_pos, at):
-    """What the engine serves through (`models.serving`): `tokens`
-    [B, T] from per-row absolute offsets `start_pos` [B], reading and
-    writing the slot cache of `init_cache`, prefill (T = the prompt's
-    bucket) and decode (T = 1) alike. Returns (the logits of position
-    `at` of `tokens`, [B, vocab] float32, without the [T, vocab] product
-    of the rest; the new cache; what the expert layers counted over the
-    call, int32 scalars: the (token, expert) pairs computed here,
-    `pairs_held`, the pairs routed, `pairs_routed`, and the buffers
-    beyond the first that a crowded share took, `pair_overflows`)."""
-    x, cache, counts = _hidden(params, tokens, cfg, cache, start_pos)
-    x = lax.dynamic_index_in_dim(x, at, 1, keepdims=False)
-    return _logits(params, x, cfg), cache, counts
-
-
-def forward_with_cache(params, tokens, cfg: GlmDsaConfig, cache, start_pos):
-    """`forward` with the logits of every position, [B, T, vocab]
-    float32, and no counts: what a comparison with a reference steps
-    through."""
-    x, cache, _ = _hidden(params, tokens, cfg, cache, start_pos)
-    return _logits(params, x, cfg), cache
+def _no_selection(tokens, cache):
+    """What the first layer, a `full` one, is handed: a mask [B, T, S]
+    as every layer hands up."""
+    return jnp.zeros(tokens.shape + (cache["runs"][0]["latent"].shape[2],),
+                     bool)
 
 
 def keys_attended(cfg: GlmDsaConfig, lengths):
     """Of `lengths` cached keys a row (host integers), how many the
     row's next token attends."""
     return np.minimum(lengths, cfg.index_topk)
+
+
+# Every matrix is drawn in the config's dtype (ROADMAP D12).
+FAMILY = Family(
+    init_layer=_init_layer, draw=jax.nn.initializers.normal(0.02),
+    leaves=_leaves, halves=_halves, handed=_no_selection,
+    keys_attended=keys_attended)
+init_params, init_cache = FAMILY.init_params, FAMILY.init_cache
+forward, forward_with_cache = FAMILY.forward, FAMILY.forward_with_cache

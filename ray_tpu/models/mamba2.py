@@ -49,19 +49,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-
-def normal(stddev: float):
-    """`jax.nn.initializers.normal` drawn in float32 and cast. Drawn in
-    bfloat16 itself the samples' mean is -0.012 sigma (jax 0.9.0:
-    -2.4e-4 for sigma 0.02 over 22 M samples, 56 standard errors), so
-    every matrix carries a rank-one part along the all-ones direction
-    that is the same in every layer; a relu^2 amplifies what it adds to
-    the stream, and a few blocks up every token's hidden state points
-    the same way (PERF.md section 6, PR 34)."""
-    def init(key, shape, dtype):
-        return (jax.random.normal(key, shape, jnp.float32)
-                * stddev).astype(dtype)
-    return init
+from ray_tpu.models.serving import normal
 
 
 def conv_width(cfg) -> int:
@@ -80,14 +68,13 @@ def init(cfg, key) -> Dict[str, Any]:
     d, h, p = cfg.dim, cfg.ssm_heads, cfg.ssm_head_dim
     c, k = conv_width(cfg), cfg.conv_kernel
     ks = jax.random.split(key, 8)
-    init = normal(0.02)
     dt = jnp.exp(jax.random.uniform(ks[3], (h,), jnp.float32,
                                     math.log(1e-3), math.log(1e-1)))
     bound = k ** -0.5
     return {
-        "w_z": init(ks[0], (d, h, p), cfg.dtype),
-        "w_xbc": init(ks[1], (d, c), cfg.dtype),
-        "w_dt": init(ks[2], (d, h), cfg.dtype),
+        "w_z": normal(ks[0], (d, h, p), cfg.dtype),
+        "w_xbc": normal(ks[1], (d, c), cfg.dtype),
+        "w_dt": normal(ks[2], (d, h), cfg.dtype),
         # softplus(dt_bias) = dt
         "dt_bias": dt + jnp.log(-jnp.expm1(-dt)),
         "A_log": jnp.log(jax.random.uniform(ks[4], (h,), jnp.float32, 1.0,
@@ -98,19 +85,16 @@ def init(cfg, key) -> Dict[str, Any]:
         "conv_b": jax.random.uniform(ks[6], (c,), jnp.float32, -bound,
                                      bound).astype(cfg.dtype),
         "ssm_norm": jnp.ones(h * p, cfg.dtype),
-        "wo": init(ks[7], (h, p, d), cfg.dtype) * d ** -0.5,
+        "wo": normal(ks[7], (h, p, d), cfg.dtype) * d ** -0.5,
     }
 
 
-def init_state(cfg, n_layers: int, n_slots: int) -> Dict[str, Any]:
-    """The two state leaves of a run of `n_layers` Mamba-2 layers."""
-    return {
-        "ssm": jnp.zeros((n_layers, n_slots, cfg.ssm_heads,
-                          cfg.ssm_head_dim, cfg.ssm_state),
-                         cfg.state_dtype),
-        "conv": jnp.zeros((n_layers, n_slots, cfg.conv_kernel - 1,
-                           conv_width(cfg)), cfg.dtype),
-    }
+def state_shapes(cfg) -> Dict[str, Any]:
+    """The two state leaves of a Mamba-2 layer, as its mixer takes
+    them: (shape a layer and slot, dtype)."""
+    return {"ssm": ((cfg.ssm_heads, cfg.ssm_head_dim, cfg.ssm_state),
+                    cfg.state_dtype),
+            "conv": ((cfg.conv_kernel - 1, conv_width(cfg)), cfg.dtype)}
 
 
 def _conv(cfg, lp, carry, xbc, at, bias=True):

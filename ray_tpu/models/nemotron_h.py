@@ -33,16 +33,15 @@ model is served, not trained).
 from __future__ import annotations
 
 import dataclasses
-import functools
 import itertools
 from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax import lax
 
 from ray_tpu.models import decoder, llama, mamba2, moe
-from ray_tpu.models.glm_dsa import _by_query_blocks
+from ray_tpu.models.serving import (Family, attention_init, by_query_blocks,
+                                    normal)
 
 PUBLISHED_PATTERN = (
     "MEMEMEM*EMEMEMEM*EMEMEMEM*EMEMEMEMEM*EMEMEMEMEM*EMEMEMEMEM*"
@@ -121,10 +120,6 @@ class NemotronHConfig(moe.MoEConfig):
 # ---------------------------------------------------------------------------
 
 
-# Every matrix is drawn in float32 and cast: `mamba2.normal` says why.
-_init = mamba2.normal(0.02)
-
-
 def _init_block(cfg: NemotronHConfig, kind, key) -> Dict[str, Any]:
     mixer, ffn = kind
     k_mixer, k_ffn = jax.random.split(key)
@@ -134,65 +129,19 @@ def _init_block(cfg: NemotronHConfig, kind, key) -> Dict[str, Any]:
     if mixer == "ssm":
         lp.update(mamba2.init(cfg, k_mixer))
     elif mixer == "attn":
-        d, hd = cfg.dim, cfg.head_dim
-        kq, kk, kv, ko = jax.random.split(k_mixer, 4)
-        lp.update(wq=_init(kq, (d, cfg.n_heads, hd), cfg.dtype),
-                  wk=_init(kk, (d, cfg.n_kv_heads, hd), cfg.dtype),
-                  wv=_init(kv, (d, cfg.n_kv_heads, hd), cfg.dtype),
-                  wo=_init(ko, (cfg.n_heads, hd, d), cfg.dtype) * d ** -0.5)
+        lp.update(attention_init(cfg, normal, jax.random.split(k_mixer, 4)))
     if ffn is not None:
         lp["mlp_norm"] = jnp.ones(cfg.dim, cfg.dtype)
-        lp.update(moe.expert_init(cfg, jax.random.split(k_ffn, 4), _init))
+        lp.update(moe.expert_init(cfg, jax.random.split(k_ffn, 4), normal))
     return lp
 
 
-def init_params(cfg: NemotronHConfig, rng) -> Dict[str, Any]:
-    """embed, `runs` (a list, one dict of stacked leaves a run of like
-    blocks: which leaves a run has says what its blocks are), final
-    norm, `out`."""
-    k_embed, k_out, k_blocks = jax.random.split(rng, 3)
-    keys = jax.random.split(k_blocks, len(cfg.blocks))
-    runs, at = [], 0
-    for kind, n in cfg.runs():
-        runs.append(jax.vmap(functools.partial(_init_block, cfg, kind))(
-            keys[at:at + n]))
-        at += n
-    return {"embed": _init(k_embed, (cfg.vocab_size, cfg.dim), cfg.dtype),
-            "runs": runs,
-            "final_norm": jnp.ones(cfg.dim, cfg.dtype),
-            "out": _init(k_out, (cfg.dim, cfg.vocab_size), cfg.dtype)}
-
-
-
-# The cache leaves of a run, by its mixer: a Mamba-2 run's are state,
-# an attention run's rows.
-_LEAVES = {"ssm": ("ssm", "conv"), "attn": ("k", "v")}
-
-
-def init_cache(cfg: NemotronHConfig, n_slots: int,
-               max_seq: int) -> Dict[str, Any]:
-    """The slot cache, a run at a time: the recurrent state and the
-    convolution's rows of a Mamba-2 run ([layers, slots, ...], no
-    sequence axis), keys and values of an attention run ([layers,
-    slots, max_seq, kv heads, head size]), {} of a run with no mixer."""
-    runs = []
-    for (mixer, _), n in cfg.runs():
-        if mixer == "ssm":
-            runs.append(mamba2.init_state(cfg, n, n_slots))
-        elif mixer == "attn":
-            shape = (n, n_slots, max_seq, cfg.n_kv_heads, cfg.head_dim)
-            runs.append({"k": jnp.zeros(shape, cfg.dtype),
-                         "v": jnp.zeros(shape, cfg.dtype)})
-        else:
-            runs.append({})
-    return {"runs": runs}
-
-
-def state_leaves(cache):
-    """`cache`'s structure with True at a leaf that is state (no
-    sequence axis, rewritten whole) and False at one of rows."""
-    return jax.tree_util.tree_map_with_path(
-        lambda path, _: path[-1].key in _LEAVES["ssm"], cache)
+def _leaves(cfg: NemotronHConfig, kind):
+    """A run's cache leaves, as the module's docstring lists them."""
+    if kind[0] == "ssm":
+        return mamba2.state_shapes(cfg)
+    keys = ((cfg.n_kv_heads, cfg.head_dim), cfg.dtype)
+    return {"k": keys, "v": keys} if kind[0] == "attn" else {}
 
 
 # ---------------------------------------------------------------------------
@@ -216,7 +165,7 @@ def _attention(cfg: NemotronHConfig, start_pos, positions):
         max_seq = k_stack.shape[2]
         keys = decoder.layer_rows(k_stack, layer, 0, max_seq)
         values = decoder.layer_rows(v_stack, layer, 0, max_seq)
-        out, = _by_query_blocks(
+        out, = by_query_blocks(
             lambda q, pos: (llama._cached_attention(cfg, q, keys, values,
                                                     pos),),
             h.shape[1], q.astype(k_stack.dtype), positions)
@@ -226,59 +175,20 @@ def _attention(cfg: NemotronHConfig, start_pos, positions):
 
 
 # ---------------------------------------------------------------------------
-# Forward through the slot cache
+# Through the slot cache (`models.serving`)
 # ---------------------------------------------------------------------------
 
 
-
-def _hidden(params, tokens, cfg: NemotronHConfig, cache, start_pos, at):
-    """The stack through the slot cache: (final-norm hidden states
-    [B, T, D], new cache, the expert layers' counts)."""
-    positions = start_pos[:, None] + jnp.arange(tokens.shape[1])[None, :]
+def _halves(cfg: NemotronHConfig, start_pos, positions, at):
     mixers = {"ssm": mamba2.mixer(cfg, start_pos, at),
               "attn": _attention(cfg, start_pos, positions), None: None}
-    runs = [(mixers[mixer], moe.served_ffn(cfg) if ffn else None, stacked,
-             tuple(run[k] for k in _LEAVES[mixer]) if mixer else None)
-            for ((mixer, ffn), _), stacked, run in zip(
-                cfg.runs(), params["runs"], cache["runs"])]
-    x, states, extras = decoder.hidden_runs(params, tokens, cfg, runs,
-                                            positions=positions)
-    new_cache = {"runs": [
-        dict(zip(_LEAVES[mixer], state)) if mixer else {}
-        for ((mixer, _), _), state in zip(cfg.runs(), states)]}
-    counted = [e for e in extras if e is not None]
-    counts = jax.tree.map(lambda *xs: sum(x.sum() for x in xs),
-                          *counted) if counted else {}
-    return x, new_cache, counts
+    return {kind: (mixers[kind[0]], moe.served_ffn(cfg) if kind[1] else None)
+            for kind in set(cfg.blocks)}
 
 
-def _logits(params, x, cfg):
-    """The head in float32, as `glm_dsa`'s: the logits feed an argmax."""
-    return jnp.einsum("...d,dv->...v", x, params["out"].astype(cfg.dtype),
-                      preferred_element_type=jnp.float32)
-
-
-def forward(params, tokens, cfg: NemotronHConfig, cache, start_pos, at):
-    """What the engine serves through (`models.serving`): `tokens`
-    [B, T] from per-row absolute offsets `start_pos` [B], prefill (T =
-    the prompt's bucket) and decode (T = 1) alike. Returns (the logits
-    of position `at` of `tokens`, [B, vocab] float32; the new cache,
-    whose state leaves are those after position `at` and no later; what
-    the expert layers counted over the call, int32 scalars summed over
-    them: `pairs_held`, `pairs_routed`, `pair_overflows`,
-    `experts_touched`, `experts_held_steps`)."""
-    x, cache, counts = _hidden(params, tokens, cfg, cache, start_pos, at)
-    x = lax.dynamic_index_in_dim(x, at, 1, keepdims=False)
-    return _logits(params, x, cfg), cache, counts
-
-
-def forward_with_cache(params, tokens, cfg: NemotronHConfig, cache,
-                       start_pos, at=None):
-    """`forward` with the logits of every position, [B, T, vocab]
-    float32, and no counts: what a comparison with a reference steps
-    through. The state left is that after position `at` (an int for
-    all rows, or int32 [B], one a row), the last of `tokens` unless
-    given."""
-    at = tokens.shape[1] - 1 if at is None else at
-    x, cache, _ = _hidden(params, tokens, cfg, cache, start_pos, at)
-    return _logits(params, x, cfg), cache
+FAMILY = Family(
+    init_layer=_init_block, draw=normal, leaves=_leaves, halves=_halves,
+    state=frozenset(("ssm", "conv")))
+init_params, init_cache = FAMILY.init_params, FAMILY.init_cache
+state_leaves = FAMILY.state_leaves
+forward, forward_with_cache = FAMILY.forward, FAMILY.forward_with_cache

@@ -47,16 +47,15 @@ has no backward pass: the model is served, not trained).
 from __future__ import annotations
 
 import dataclasses
-import functools
 import itertools
 from typing import Any, Dict, Tuple
 
 import jax
 import jax.numpy as jnp
-from jax import lax
 
-from ray_tpu.models import decoder, gated_delta, llama, mamba2
-from ray_tpu.models.glm_dsa import _by_query_blocks
+from ray_tpu.models import decoder, gated_delta, llama
+from ray_tpu.models.serving import (Family, attention_init, by_query_blocks,
+                                    keys_read_by_blocks, normal)
 from ray_tpu.ops import attention
 
 PUBLISHED_LAYER_TYPES = ("linear", "linear", "linear", "full") * 8
@@ -111,74 +110,30 @@ class OlmoHybridConfig(llama.LlamaConfig):
 # Parameters and cache
 # ---------------------------------------------------------------------------
 
-# Every matrix is drawn in float32 and cast: `mamba2.normal` says why.
-_init = mamba2.normal(0.02)
-
 
 def _init_layer(cfg: OlmoHybridConfig, kind, key) -> Dict[str, Any]:
     d, hd, f = cfg.dim, cfg.head_dim, cfg.hidden_dim
     k_mixer, k1, k2, k3 = jax.random.split(key, 4)
     lp = {"attn_norm": jnp.ones(d, cfg.dtype),
           "mlp_norm": jnp.ones(d, cfg.dtype),
-          "w1": _init(k1, (d, f), cfg.dtype),
-          "w3": _init(k2, (d, f), cfg.dtype),
-          "w2": _init(k3, (f, d), cfg.dtype) * f ** -0.5}
+          "w1": normal(k1, (d, f), cfg.dtype),
+          "w3": normal(k2, (d, f), cfg.dtype),
+          "w2": normal(k3, (f, d), cfg.dtype) * f ** -0.5}
     if kind == "linear":
         lp.update(gated_delta.init(cfg, k_mixer))
     else:
-        kq, kk, kv, ko = jax.random.split(k_mixer, 4)
-        lp.update(wq=_init(kq, (d, cfg.n_heads, hd), cfg.dtype),
-                  wk=_init(kk, (d, cfg.n_kv_heads, hd), cfg.dtype),
-                  wv=_init(kv, (d, cfg.n_kv_heads, hd), cfg.dtype),
-                  wo=_init(ko, (cfg.n_heads, hd, d), cfg.dtype) * d ** -0.5,
+        lp.update(attention_init(cfg, normal, jax.random.split(k_mixer, 4)),
                   q_norm=jnp.ones(cfg.n_heads * hd, cfg.dtype),
                   k_norm=jnp.ones(cfg.n_kv_heads * hd, cfg.dtype))
     return lp
 
 
-def init_params(cfg: OlmoHybridConfig, rng) -> Dict[str, Any]:
-    """embed, `runs` (a list, one dict of stacked leaves a run of like
-    layers: a run with `A_log` is `linear`), final norm, `out`."""
-    k_embed, k_out, k_layers = jax.random.split(rng, 3)
-    keys = jax.random.split(k_layers, cfg.n_layers)
-    runs, at = [], 0
-    for kind, n in cfg.runs():
-        runs.append(jax.vmap(functools.partial(_init_layer, cfg, kind))(
-            keys[at:at + n]))
-        at += n
-    return {"embed": _init(k_embed, (cfg.vocab_size, cfg.dim), cfg.dtype),
-            "runs": runs,
-            "final_norm": jnp.ones(cfg.dim, cfg.dtype),
-            "out": _init(k_out, (cfg.dim, cfg.vocab_size), cfg.dtype)}
-
-
-# The cache leaves of a run, by its kind: a `linear` run's are state, a
-# `full` run's rows.
-_LEAVES = {"linear": gated_delta.LEAVES, "full": ("k", "v")}
-
-
-def init_cache(cfg: OlmoHybridConfig, n_slots: int,
-               max_seq: int) -> Dict[str, Any]:
-    """The slot cache, a run at a time: the delta state and the three
-    convolutions' rows of a `linear` run ([layers, slots, ...], no
-    sequence axis), keys and values of a `full` run ([layers, slots,
-    max_seq, heads x head size])."""
-    runs = []
-    for kind, n in cfg.runs():
-        if kind == "linear":
-            runs.append(gated_delta.init_state(cfg, n, n_slots))
-        else:
-            shape = (n, n_slots, max_seq, cfg.n_kv_heads * cfg.head_dim)
-            runs.append({"k": jnp.zeros(shape, cfg.dtype),
-                         "v": jnp.zeros(shape, cfg.dtype)})
-    return {"runs": runs}
-
-
-def state_leaves(cache):
-    """`cache`'s structure with True at a leaf that is state (no
-    sequence axis, rewritten whole) and False at one of rows."""
-    return jax.tree_util.tree_map_with_path(
-        lambda path, _: path[-1].key in _LEAVES["linear"], cache)
+def _leaves(cfg: OlmoHybridConfig, kind):
+    """A run's cache leaves, as the module's docstring lists them."""
+    if kind == "linear":
+        return gated_delta.state_shapes(cfg)
+    keys = ((cfg.n_kv_heads * cfg.head_dim,), cfg.dtype)
+    return {"k": keys, "v": keys}
 
 
 # ---------------------------------------------------------------------------
@@ -216,7 +171,7 @@ def _attention(cfg: OlmoHybridConfig, start_pos, positions):
             keys = decoder.layer_rows(k_stack, layer, 0, max_seq)
             values = decoder.layer_rows(v_stack, layer, 0, max_seq)
             by_head = (b, max_seq, cfg.n_kv_heads, cfg.head_dim)
-            out, = _by_query_blocks(
+            out, = by_query_blocks(
                 lambda q, pos: (llama._cached_attention(
                     cfg, q, keys.reshape(by_head), values.reshape(by_head),
                     pos),), t, q, positions)
@@ -224,26 +179,16 @@ def _attention(cfg: OlmoHybridConfig, start_pos, positions):
 
     return mixer
 
+
 # ---------------------------------------------------------------------------
-# Forward through the slot cache
+# Through the slot cache (`models.serving`)
 # ---------------------------------------------------------------------------
 
 
-def _hidden(params, tokens, cfg: OlmoHybridConfig, cache, start_pos, at):
-    """The stack through the slot cache: (final-norm hidden states
-    [B, T, D], new cache)."""
-    positions = start_pos[:, None] + jnp.arange(tokens.shape[1])[None, :]
-    mixers = {"linear": gated_delta.mixer(cfg, start_pos, at),
-              "full": _attention(cfg, start_pos, positions)}
+def _halves(cfg: OlmoHybridConfig, start_pos, positions, at):
     ffn = llama.swiglu()
-    runs = [(mixers[kind], ffn, stacked,
-             tuple(run[name] for name in _LEAVES[kind]))
-            for (kind, _), stacked, run in zip(cfg.runs(), params["runs"],
-                                               cache["runs"])]
-    x, states, _ = decoder.hidden_runs(params, tokens, cfg, runs,
-                                       positions=positions)
-    return x, {"runs": [dict(zip(_LEAVES[kind], state))
-                        for (kind, _), state in zip(cfg.runs(), states)]}
+    return {"linear": (gated_delta.mixer(cfg, start_pos, at), ffn),
+            "full": (_attention(cfg, start_pos, positions), ffn)}
 
 
 def _counts(tokens, start_pos, at):
@@ -257,31 +202,10 @@ def _counts(tokens, start_pos, at):
             if tokens.shape[1] > 1 else jnp.zeros((), jnp.int32)}
 
 
-def _logits(params, x, cfg):
-    """The head in float32, as `glm_dsa`'s: the logits feed an argmax."""
-    return jnp.einsum("...d,dv->...v", x, params["out"].astype(cfg.dtype),
-                      preferred_element_type=jnp.float32)
-
-
-def forward(params, tokens, cfg: OlmoHybridConfig, cache, start_pos, at):
-    """What the engine serves through (`models.serving`): `tokens`
-    [B, T] from per-row absolute offsets `start_pos` [B], prefill (T =
-    the prompt's bucket) and decode (T = 1) alike. Returns (the logits
-    of position `at` of `tokens`, [B, vocab] float32; the new cache,
-    whose state leaves are those after position `at` and no later; the
-    call's `delta_state_resets` and `delta_scan_tokens`)."""
-    x, cache = _hidden(params, tokens, cfg, cache, start_pos, at)
-    x = lax.dynamic_index_in_dim(x, at, 1, keepdims=False)
-    return _logits(params, x, cfg), cache, _counts(tokens, start_pos, at)
-
-
-def forward_with_cache(params, tokens, cfg: OlmoHybridConfig, cache,
-                       start_pos, at=None):
-    """`forward` with the logits of every position, [B, T, vocab]
-    float32, and no counts: what a comparison with a reference steps
-    through. The state left is that after position `at` (an int for
-    all rows, or int32 [B], one a row), the last of `tokens` unless
-    given."""
-    at = tokens.shape[1] - 1 if at is None else at
-    x, cache = _hidden(params, tokens, cfg, cache, start_pos, at)
-    return _logits(params, x, cfg), cache
+FAMILY = Family(
+    init_layer=_init_layer, draw=normal, leaves=_leaves, halves=_halves,
+    state=frozenset(gated_delta.LEAVES), counts=_counts,
+    keys_read=keys_read_by_blocks)
+init_params, init_cache = FAMILY.init_params, FAMILY.init_cache
+state_leaves = FAMILY.state_leaves
+forward, forward_with_cache = FAMILY.forward, FAMILY.forward_with_cache

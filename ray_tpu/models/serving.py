@@ -51,28 +51,64 @@ through the slot cache. The engine knows no model; it takes from here
   length rounded up to the kernel's block of rows; the engine holds it
   to the slot's region). None where a step reads the region whole or
   reads something that is no region (a ring, selected keys).
+
+A family whose stack is `decoder.block`s over `cfg.runs()`, runs of
+like layers, declares a `Family` and takes all of the above from it,
+one body for all (`_llama` apart: the dense decoder's cached forward
+pass is `llama`'s own). Its cache is {"runs": [a dict a run]}, its
+parameters {"embed", "runs": [a dict of stacked leaves a run],
+"final_norm", "out" unless tied}. The declaration's fields:
+
+- ``init_layer(cfg, kind, key) -> lp``: one layer's parameters, which
+  `init_params` stacks a run at a time, and ``draw(key, shape, dtype)``
+  for `embed` and `out`;
+- ``leaves(cfg, kind) -> {name: (shape, dtype)}``: a run's cache
+  leaves in the order its mixer takes and returns them, {} of a run
+  with no mixer; `shape` is what follows [layers, slots, max_seq] of a
+  row leaf and [layers, slots] of a state leaf, and ``state`` names the
+  leaves that are state;
+- ``halves(cfg, start_pos, positions, at) -> {kind: (mixer, ffn)}``:
+  what `decoder.hidden_runs` is handed for a run of each kind (None:
+  the block has no such half), made once a call: a recurrent mixer
+  broadcasts `at` when it is made;
+- what only some have: ``tied`` (the head is the embedding, times
+  `cfg.logit_scale`), ``handed(tokens, cache)`` (what enters the first
+  layer as `handed`), ``counts(tokens, start_pos, at)`` (counts of the
+  family's own, beside what its FFNs count), ``keys_attended`` and
+  ``keys_read``.
+
+Below the stack stands what the families' layers share and no one of
+them owns.
 """
 
 from __future__ import annotations
 
 import dataclasses
+import functools
+import importlib
+import math
 from typing import Callable, Optional
+
+import jax
+import jax.numpy as jnp
+from jax import lax
+
+from ray_tpu.models import decoder
+from ray_tpu.ops.attention import decode_block_rows
 
 
 def _every_key(cfg, lengths):
     return lengths
 
 
-def _keys_read(cfg, lengths):
+def keys_read_by_blocks(cfg, lengths):
     """`lengths` rounded up to `decode_attention`'s block of rows for
     `cfg`'s key heads."""
-    from ray_tpu.ops.attention import decode_block_rows
     rows = decode_block_rows(cfg.n_kv_heads, cfg.head_dim, cfg.dtype)
     return -(-lengths // rows) * rows
 
 
 def _all_rows(cache):
-    import jax
     return jax.tree.map(lambda _: False, cache)
 
 
@@ -85,6 +121,186 @@ class ServedModel:
     keys_read: Optional[Callable] = None
 
 
+@dataclasses.dataclass(frozen=True, eq=False)
+class Family:
+    """A served family's declaration (the module's docstring has the
+    fields) and, as its methods, the stack through the slot cache."""
+    init_layer: Callable
+    draw: Callable
+    leaves: Callable
+    halves: Callable
+    state: frozenset = frozenset()
+    tied: bool = False
+    handed: Optional[Callable] = None
+    counts: Optional[Callable] = None
+    keys_attended: Callable = _every_key
+    keys_read: Optional[Callable] = None
+
+    def __post_init__(self):
+        # One function object a name: what a family's module binds is
+        # what `served_model` hands the engine, and a jit of either is
+        # a jit of both.
+        for name in ("init_params", "init_cache", "state_leaves", "forward",
+                     "forward_with_cache"):
+            object.__setattr__(self, name, getattr(self, name))
+
+    def init_params(self, cfg, rng):
+        """Which leaves a run has says what its layers are."""
+        k_embed, *k_out, k_layers = jax.random.split(rng, 3 - self.tied)
+        keys = jax.random.split(k_layers, sum(n for _, n in cfg.runs()))
+        runs, at = [], 0
+        for kind, n in cfg.runs():
+            runs.append(jax.vmap(functools.partial(
+                self.init_layer, cfg, kind))(keys[at:at + n]))
+            at += n
+        return {"embed": self.draw(k_embed, (cfg.vocab_size, cfg.dim),
+                                   cfg.dtype),
+                "runs": runs, "final_norm": jnp.ones(cfg.dim, cfg.dtype),
+                **{"out": self.draw(key, (cfg.dim, cfg.vocab_size),
+                                    cfg.dtype) for key in k_out}}
+
+    def init_cache(self, cfg, n_slots: int, max_seq: int):
+        rows = {True: (n_slots,), False: (n_slots, max_seq)}
+        return {"runs": [
+            {name: jnp.zeros((n,) + rows[name in self.state] + shape, dtype)
+             for name, (shape, dtype) in self.leaves(cfg, kind).items()}
+            for kind, n in cfg.runs()]}
+
+    def state_leaves(self, cache):
+        """`cache`'s structure with True at a leaf that is state and
+        False at one of rows."""
+        return jax.tree_util.tree_map_with_path(
+            lambda path, _: path[-1].key in self.state, cache)
+
+    def _stack(self, params, tokens, cfg, cache, start_pos, at):
+        """(final-norm hidden states [B, T, D], new cache, what the
+        FFNs counted, summed over the layers; a run whose FFN reports
+        nothing adds nothing)."""
+        positions = start_pos[:, None] + jnp.arange(tokens.shape[1])[None, :]
+        halves = self.halves(cfg, start_pos, positions, at)
+        names = [tuple(self.leaves(cfg, kind)) for kind, _ in cfg.runs()]
+        runs = [(*halves[kind], stacked,
+                 tuple(run[name] for name in leaves) if leaves else None)
+                for (kind, _), leaves, stacked, run in zip(
+                    cfg.runs(), names, params["runs"], cache["runs"])]
+        x, states, extras = decoder.hidden_runs(
+            params, tokens, cfg, runs, positions=positions,
+            handed=self.handed and self.handed(tokens, cache))
+        new_cache = {"runs": [dict(zip(leaves, state or ()))
+                              for leaves, state in zip(names, states)]}
+        counted = [e for e in extras if e is not None]
+        counts = jax.tree.map(lambda *xs: sum(x.sum() for x in xs),
+                              *counted) if counted else {}
+        return x, new_cache, counts
+
+    def _logits(self, params, x, cfg):
+        """The head in float32: the served logits feed an argmax, and
+        two near-equal logits rounded to bfloat16 are a tie."""
+        product, weight = ("...d,vd->...v", "embed") if self.tied \
+            else ("...d,dv->...v", "out")
+        out = jnp.einsum(product, x, params[weight].astype(cfg.dtype),
+                         preferred_element_type=jnp.float32)
+        scale = cfg.logit_scale if self.tied else 1.0
+        return out if scale == 1.0 else out * scale
+
+    def forward(self, params, tokens, cfg, cache, start_pos, at):
+        """The module's `forward`, prefill (T = the prompt's bucket)
+        and decode (T = 1) alike: the logits of position `at` without
+        the [T, vocab] product of the rest, and as counts the FFNs'
+        and the family's own."""
+        x, cache, counts = self._stack(params, tokens, cfg, cache, start_pos,
+                                       at)
+        x = lax.dynamic_index_in_dim(x, at, 1, keepdims=False)
+        logits = self._logits(params, x, cfg)
+        if self.counts is not None:
+            counts = {**counts, **self.counts(tokens, start_pos, at)}
+        return logits, cache, counts
+
+    def forward_with_cache(self, params, tokens, cfg, cache, start_pos,
+                           at=None, keep=None):
+        """`forward` with the logits of every position (of the first
+        `keep`, where given: a padded prefill's real ones), [B, T,
+        vocab] float32, and no counts: what a comparison with a
+        reference steps through. The state left is that after position
+        `at` (an int for all rows, or int32 [B], one a row), the last
+        of `tokens` unless given."""
+        at = tokens.shape[1] - 1 if at is None else at
+        x, cache, _ = self._stack(params, tokens, cfg, cache, start_pos, at)
+        return self._logits(params, x if keep is None else x[:, :keep],
+                            cfg), cache
+
+    def served(self) -> ServedModel:
+        return ServedModel(
+            self.forward, self.init_cache, self.keys_attended,
+            self.state_leaves if self.state else _all_rows, self.keys_read)
+
+
+# ---------------------------------------------------------------------------
+# What the families' layers share
+# ---------------------------------------------------------------------------
+
+# Queries and keys go through attention in blocks of at most this many.
+QUERY_BLOCK = 256
+KEY_BLOCK = 1024
+
+
+def normal(key, shape, dtype):
+    """`jax.nn.initializers.normal(0.02)` drawn in float32 and cast.
+    Drawn in bfloat16 itself the samples' mean is -0.012 sigma (jax
+    0.9.0: -2.4e-4 for sigma 0.02 over 22 M samples, 56 standard
+    errors), so every matrix carries a rank-one part along the all-ones
+    direction that is the same in every layer; a relu^2 amplifies what
+    it adds to the stream, and a few blocks up every token's hidden
+    state points the same way (PERF.md section 6, PR 34)."""
+    return (jax.random.normal(key, shape, jnp.float32) * 0.02).astype(dtype)
+
+
+def attention_init(cfg, draw, keys):
+    """The attention projections from four keys: `wq`, `wk`, `wv`
+    [D, heads, head size] and `wo` [heads, head size, D], the last
+    scaled by D^-1/2."""
+    d, hd = cfg.dim, cfg.head_dim
+    kq, kk, kv, ko = keys
+    return {"wq": draw(kq, (d, cfg.n_heads, hd), cfg.dtype),
+            "wk": draw(kk, (d, cfg.n_kv_heads, hd), cfg.dtype),
+            "wv": draw(kv, (d, cfg.n_kv_heads, hd), cfg.dtype),
+            "wo": draw(ko, (cfg.n_heads, hd, d), cfg.dtype) * d ** -0.5}
+
+
+def rotate_pairs(x, cos, sin):
+    """Rotary positions on interleaved pairs (2i, 2i + 1) of the last
+    axis. x: [B, T, ..., D]; cos, sin: [B, T, D / 2]."""
+    extra = x.ndim - 3
+    cos = cos.reshape(cos.shape[:2] + (1,) * extra + cos.shape[2:])
+    sin = sin.reshape(cos.shape)
+    pairs = x.astype(jnp.float32).reshape(x.shape[:-1] + (-1, 2))
+    a, b = pairs[..., 0], pairs[..., 1]
+    out = jnp.stack([a * cos - b * sin, a * sin + b * cos], -1)
+    return out.reshape(x.shape).astype(x.dtype)
+
+
+def by_query_blocks(fn, t, *arrays):
+    """`fn` over blocks of the query axis (axis 1 of every array), its
+    results (a tuple of arrays) joined along it again."""
+    tq = math.gcd(t, QUERY_BLOCK)
+    if tq == t:
+        return fn(*arrays)
+    n = t // tq
+
+    def split(x):
+        x = x.reshape((x.shape[0], n, tq) + x.shape[2:])
+        return jnp.moveaxis(x, 1, 0)
+
+    outs = lax.map(lambda xs: fn(*xs), tuple(split(x) for x in arrays))
+    return tuple(jnp.moveaxis(o, 0, 1).reshape(
+        (o.shape[1], t) + o.shape[3:]) for o in outs)
+
+
+# ---------------------------------------------------------------------------
+# The registry
+# ---------------------------------------------------------------------------
+
+
 def _llama():
     from ray_tpu.models import llama
 
@@ -93,40 +309,23 @@ def _llama():
                                                  start_pos)
         return logits[:, at], cache, {}
 
-    return ServedModel(forward, llama.init_kv_cache, keys_read=_keys_read)
+    return ServedModel(forward, llama.init_kv_cache,
+                       keys_read=keys_read_by_blocks)
 
 
-def _glm_dsa():
-    from ray_tpu.models import glm_dsa
-    return ServedModel(glm_dsa.forward, glm_dsa.init_cache,
-                       glm_dsa.keys_attended)
-
-
-def _nemotron_h():
-    from ray_tpu.models import nemotron_h
-    return ServedModel(nemotron_h.forward, nemotron_h.init_cache,
-                       state_leaves=nemotron_h.state_leaves)
-
-
-def _olmo_hybrid():
-    from ray_tpu.models import olmo_hybrid
-    return ServedModel(olmo_hybrid.forward, olmo_hybrid.init_cache,
-                       state_leaves=olmo_hybrid.state_leaves,
-                       keys_read=_keys_read)
-
-
-def _cohere2_moe():
-    from ray_tpu.models import cohere2_moe
-    return ServedModel(cohere2_moe.forward, cohere2_moe.init_cache,
-                       cohere2_moe.keys_attended, cohere2_moe.state_leaves)
+def _family(module):
+    """The `FAMILY` that `ray_tpu.models.<module>` declares, imported
+    when a config of its type is first served."""
+    return lambda: importlib.import_module(
+        f"ray_tpu.models.{module}").FAMILY.served()
 
 
 # By the config's own type, not its bases: `MoEConfig` is a
 # `LlamaConfig` and has no cached forward pass.
-_SERVED = {"LlamaConfig": _llama, "GlmDsaConfig": _glm_dsa,
-           "NemotronHConfig": _nemotron_h,
-           "Cohere2MoeConfig": _cohere2_moe,
-           "OlmoHybridConfig": _olmo_hybrid}
+_SERVED = {"LlamaConfig": _llama, "GlmDsaConfig": _family("glm_dsa"),
+           "NemotronHConfig": _family("nemotron_h"),
+           "Cohere2MoeConfig": _family("cohere2_moe"),
+           "OlmoHybridConfig": _family("olmo_hybrid")}
 
 
 def served_model(cfg) -> ServedModel:

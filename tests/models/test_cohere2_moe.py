@@ -18,7 +18,7 @@ from jax import lax
 from benchmark.harness.manifest import ROOT, load_json, model_adapter
 from benchmark.references import cohere2_moe as reference
 from ray_tpu._private import flight_recorder
-from ray_tpu.models import cohere2_moe, glm_dsa, moe
+from ray_tpu.models import cohere2_moe, moe, serving
 from ray_tpu.models.serving import served_model
 from ray_tpu.serve.llm import LLMEngine, SamplingParams
 from tools import glm_logit_check
@@ -131,7 +131,7 @@ def test_prefill_then_ring_decode_against_the_full_forward(
     decode steps side by side, the rings wrapping once more. With
     `flash` the prefill's rows attend through the flash kernel, tiles
     of 16 and a window of 8, and the decode steps by blocks as ever."""
-    monkeypatch.setattr(glm_dsa, "_QUERY_BLOCK", query_block)
+    monkeypatch.setattr(serving, "QUERY_BLOCK", query_block)
     monkeypatch.setattr(cohere2_moe, "_KEY_BLOCK", key_block)
     used = _flash_as_on_a_tpu(monkeypatch, 16, 16) if flash else []
     lens = np.array([45, 39, 26, 5])
