@@ -60,8 +60,10 @@ barrier: there q is the large array and the compiler's trade costs
 nothing that shows.
 
 Not here: the vision tower (not in the language model's config), an
-uncached forward pass and a loss (the model is served, not trained; a
-window in the trained path's backward kernels is ROADMAP M7).
+uncached forward pass and a loss (the model is served, not trained:
+the trained path has had a window in its backward kernels and a loss
+over runs of unlike layers since `smallthinker.py`, and this family
+would stand on both).
 """
 
 from __future__ import annotations

@@ -29,12 +29,15 @@ handed in as two functions:
   layer reports (an expert layer's aux loss and counts), or None. An
   FFN that reads some of its parameters in place names them in its
   attribute `whole`: `layers` keeps those leaves of the run's stack out
-  of the scan and calls ``ffn(h, lp, stacks=(leaves, layer))``.
+  of the scan and calls ``ffn(h, lp, stacks=(leaves, layer))``. An FFN
+  that reads the stream as the layer received it, before the mixer and
+  before any norm (SmallThinker's router), carries the attribute
+  `stream` and is called ``ffn(h, lp, stream=x)``.
 
 Around it: the parameter skeleton, the stack (`hidden`: one run of
 like layers; `hidden_runs`: several, each with its own mixer, FFN,
 parameters and state), the output head (`logits`) and the loss tail
-(`loss`). No architecture is known here:
+(`loss`, over one run or several). No architecture is known here:
 `llama.py` and `moe.py` compose this with their mixers and FFNs. `cfg`
 is any config with `LlamaConfig`'s fields.
 """
@@ -120,8 +123,10 @@ def block(mixer, ffn, cfg, rope, x, lp, state=None, handed=None, *,
     the mixer says otherwise (its attribute `scope`: a state-space mixer
     is no attention). `state` goes to the mixer as it is and comes back
     as the mixer returns it; `handed` is what the layer below handed up
-    beside x, None in most stacks."""
+    beside x, None in most stacks. An FFN with the attribute `stream`
+    is also handed x as the block received it (`stream=`)."""
     extras = None
+    received = {"stream": x} if getattr(ffn, "stream", False) else {}
     parallel = cfg.parallel_block
     assert cfg.norm_placement in ("input", "output"), cfg.norm_placement
     after = cfg.norm_placement == "output"
@@ -143,7 +148,7 @@ def block(mixer, ffn, cfg, rope, x, lp, state=None, handed=None, *,
                 h = x
             elif not parallel:
                 h = norm(cfg, x, lp["mlp_norm"])
-            out, extras = ffn(h, lp)
+            out, extras = ffn(h, lp, **received)
             if after:
                 out = norm(cfg, out, lp["mlp_norm"])
             x = x + mixed + out if parallel else x + out
@@ -193,10 +198,12 @@ def layers(mixer, ffn, cfg, rope, x, stacked, state=None, handed=None, *,
     def body(carry, scanned):
         x, handed, state = carry
         lp, layer = scanned
+        half = ffn
+        if whole:
+            half = functools.partial(ffn, stacks=(whole, layer))
+            half.stream = getattr(ffn, "stream", False)
         x, state, extras, handed = block(
-            mixer,
-            functools.partial(ffn, stacks=(whole, layer)) if whole else ffn,
-            cfg, rope, x, lp,
+            mixer, half, cfg, rope, x, lp,
             None if state is None else (state, layer), handed, mesh=mesh,
             rules=rules)
         return (x, handed, state), extras
@@ -337,14 +344,21 @@ def _vocab_sharded(mesh, rules) -> bool:
     return size > 1
 
 
-def loss(params, batch, cfg, mixer, ffn, *, mesh=None, rules=DEFAULT_RULES,
-         save=None):
+def loss(params, batch, cfg, mixer=None, ffn=None, *, runs=None, mesh=None,
+         rules=DEFAULT_RULES, save=None):
     """batch: {"tokens": [B,S], "targets": [B,S], optional "mask": [B,S],
     optional "positions": [B,S]}. Returns (mean cross-entropy over the
-    unmasked tokens f32, how many those are, the FFN's extras)."""
-    x, _, extras = hidden(params, batch["tokens"], cfg, mixer, ffn,
-                          mesh=mesh, rules=rules,
-                          positions=batch.get("positions"), save=save)
+    unmasked tokens f32, how many those are, the FFN's extras). The
+    stack is one run of like layers (`mixer`, `ffn`, over
+    `params["layers"]`) or, with `runs`, `hidden_runs`' sequence of
+    (mixer, ffn, stacked parameters, state): the extras are then a list,
+    a run each, and `save` rematerialises every run's layers alike."""
+    x, _, extras = hidden_runs(
+        params, batch["tokens"], cfg,
+        runs or [(mixer, ffn, params["layers"], None)], mesh=mesh,
+        rules=rules, positions=batch.get("positions"), save=save)
+    if runs is None:
+        extras, = extras
     b, s, d = x.shape
     targets = batch["targets"].reshape(b * s)
     if cfg.fused_ce and not _vocab_sharded(mesh, rules):

@@ -17,6 +17,7 @@ SwiGLU with the 8/3 expansion).
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
 from typing import Any, Dict
@@ -218,21 +219,26 @@ def norm_all_heads(x, weight, eps):
                               eps).reshape(b, s, h, k)
 
 
-def _qkv(cfg: LlamaConfig, h, lp, rope, positions, qk_norm):
+def _qkv(cfg: LlamaConfig, h, lp, rope, positions, qk_norm, turned=True):
     """The projections of normed activations h [B, S, D], with the q/k
-    norm if configured and rope: q [B,S,H,D], k and v [B,S,Hkv,D]."""
+    norm if configured and rope (none where not `turned`: a layer with
+    no positional encoding): q [B,S,H,D], k and v [B,S,Hkv,D]."""
     q = jnp.einsum("bsd,dhk->bshk", h, lp["wq"])
     k = jnp.einsum("bsd,dhk->bshk", h, lp["wk"])
     v = jnp.einsum("bsd,dhk->bshk", h, lp["wv"])
     if cfg.qk_norm:
         q = qk_norm(q, lp["q_norm"], cfg.norm_eps)
         k = qk_norm(k, lp["k_norm"], cfg.norm_eps)
+    if not turned:
+        return q, k, v
     return (apply_rope(q, *rope, positions), apply_rope(k, *rope, positions),
             v)
 
 
-def _attention(cfg: LlamaConfig, q, k, v, mesh, rules):
-    """q: [B,S,H,D]; k/v: [B,S,Hkv,D] → [B,S,H,D]."""
+def _attention(cfg: LlamaConfig, q, k, v, mesh, rules, window=None):
+    """q: [B,S,H,D]; k/v: [B,S,Hkv,D] → [B,S,H,D]. With `window` a row
+    sees that many keys, itself the last (the flash kernels and the
+    reference; the context-parallel paths have none)."""
     impl = cfg.attention
     if impl == "auto":
         seq_parallel = mesh is not None and mesh.shape.get("seq", 1) > 1
@@ -241,7 +247,7 @@ def _attention(cfg: LlamaConfig, q, k, v, mesh, rules):
         else:
             impl = "flash" if on_tpu() else "reference"
     if impl == "flash":
-        attn = functools.partial(flash_attention, causal=True)
+        attn = functools.partial(flash_attention, causal=True, window=window)
         if mesh is None:
             return attn(q, k, v)
         # GSPMD cannot partition the kernel's custom call: left bare in
@@ -255,6 +261,7 @@ def _attention(cfg: LlamaConfig, q, k, v, mesh, rules):
             attn, mesh=mesh, in_specs=(q_spec, kv_spec, kv_spec),
             out_specs=q_spec, check_vma=False)(q, k, v)
     if impl in ("ring", "ulysses"):
+        assert window is None, f"no window in {impl} attention"
         # Ring/Ulysses currently take equal head counts; expand GQA KV
         # heads (cheap relative to long-context attention itself).
         rep = cfg.n_heads // cfg.n_kv_heads
@@ -269,19 +276,26 @@ def _attention(cfg: LlamaConfig, q, k, v, mesh, rules):
         q.transpose(0, 2, 1, 3),
         jnp.repeat(k, rep, axis=2).transpose(0, 2, 1, 3),
         jnp.repeat(v, rep, axis=2).transpose(0, 2, 1, 3),
-        True, cfg.head_dim ** -0.5)
+        True, cfg.head_dim ** -0.5, window)
     return out.transpose(0, 2, 1, 3)
 
 
 def self_attention(cfg: LlamaConfig, mesh=None, rules=DEFAULT_RULES,
-                   qk_norm=norm_all_heads):
+                   qk_norm=norm_all_heads, *, window=None, turned=True):
     """The mixer of training and of the uncached forward: causal
-    attention of the sequence over itself."""
+    attention of the sequence over itself. With `window` a row sees
+    that many keys, itself the last, and the layer's attention runs in
+    the scope `window` (inside the block's `attn`, as the served
+    windowed mixer's does); without `turned` q and k are not turned by
+    rotary positions (a layer with no positional encoding)."""
     def mixer(h, lp, rope, state, handed):
-        q, k, v = _qkv(cfg, h, lp, rope, None, qk_norm)
+        q, k, v = _qkv(cfg, h, lp, rope, None, qk_norm, turned)
         q = with_logical_constraint(q, "batch", "seq", "heads", "head_dim",
                                     mesh=mesh, rules=rules)
-        return _attention(cfg, q, k, v, mesh, rules), state, handed
+        with contextlib.nullcontext() if window is None \
+                else jax.named_scope("window"):
+            return (_attention(cfg, q, k, v, mesh, rules, window), state,
+                    handed)
 
     return mixer
 

@@ -7,8 +7,10 @@ softmax gives them, RMSNorm on the projected q and k); `MoEConfig`
 holds what they differ in, and what the served families add: a
 sigmoid router with a selection bias, a shared expert (or several,
 summed or averaged, fused into one), a held share of the experts,
-experts that are relu^2 with two matrices, and a latent the routed
-experts work in (`expert_kind`, `latent_dim`).
+experts that are relu^2 with two matrices or gated by a relu, a latent
+the routed experts work in (`expert_kind`, `latent_dim`), and a router
+that scores another input than the experts read (SmallThinker's, the
+layer's input: `_moe_ffn`'s `routed`).
 
 The expert layer is dropless sparse dispatch (`_moe_ffn`): float32
 softmax over the router's logits, `lax.top_k` (exactly k experts a
@@ -23,11 +25,17 @@ Both permutations are row gathers in the forward and in the backward
 pass (`_spread` and `_collect` are each other's transpose), never a
 scatter.
 
-A served model that holds a share of a layer's experts
-(`cfg.experts_held`) goes another way from the router on
-(`_held_experts`, forward only): only the pairs that fell on the share
-are gathered and computed, a buffer of rows at a time, and the expert
-matrices are read where they lie in the run's stack. `served_ffn` names
+A model that holds a share of a layer's experts (`cfg.experts_held`)
+goes another way from the router on: only the pairs that fell on the
+share are gathered and computed, a buffer of rows at a time, whatever
+the load (no pair on a held expert is dropped), and the pairs on absent
+experts are left out of the layer's output. Trained
+(`_held_experts_trained`), the buffers' products are `lax.ragged_dot`'s
+like the whole layer's, both permutations are row gathers forward and
+backward, and the layer has a gradient; a share is trained as it is
+served. Served (`_held_experts`, forward only), the expert
+matrices are read where they lie in the run's stack, and a token's
+rows are added into it by a scatter. `served_ffn` names
 them as the leaves `decoder.layers` keeps whole beside the scan, and
 the grouped products pick the layer's experts out of the stack: a
 layer's matrices sliced out of the stack by the scan are a copy of
@@ -41,14 +49,15 @@ are `lax.ragged_dot`, which the TPU compiler turns into a grouped-matmul
 kernel of its own: thousands of rows a group, bound by the MXU, and it
 has the VJP training needs. (Megablox's Pallas `gmm` ran them a third
 faster on the v5e, but tracing its group metadata for every call adds
-2.4 to 4 s to a warm process's first step: PERF.md, PR 27.) A held
+2.4 to 4 s to a warm process's first step: PERF.md, PR 27.) A served
 share's are `ops.grouped_matmul`, a Pallas kernel tiled for what a
 served step gives it, one to three rows an expert in a decode step and
 tens to hundreds in a prefill, where the product is bound by reading
 the touched experts' matrices once: the compiler's kernel reads them at
-two fifths of memory speed and less (PERF.md, PR 37). It is forward
-only, and its group metadata is built once a layer for the layer's two
-or three products.
+two fifths of memory speed and less (PERF.md, PR 37). That kernel is
+forward only (a trained share's products are `lax.ragged_dot`'s), and
+its group metadata is built once a layer for the layer's two or three
+products.
 
 On a mesh tokens stay on the chip that holds them: dispatch, the
 grouped matmuls and the combine run under `shard_map` over the batch
@@ -124,8 +133,9 @@ class MoEConfig(LlamaConfig):
     # (the chip that holds them adds them). None: all.
     experts_held: Optional[Tuple[int, int]] = None
     # What an expert, routed or shared, computes: "swiglu",
-    # w2 (silu(w1 x) * w3 x), three matrices; "relu2", w2 relu(w1 x)^2,
-    # two (the leaves `we3` and `ws3` are then absent).
+    # w2 (silu(w1 x) * w3 x), three matrices; "reglu", the same with
+    # relu in silu's place; "relu2", w2 relu(w1 x)^2, two (the leaves
+    # `we3` and `ws3` are then absent).
     expert_kind: str = "swiglu"
     # Width of the latent the routed experts work in (LatentMoE): the
     # layer projects a token down once (`w_dn`, shared by the experts)
@@ -176,8 +186,7 @@ def expert_init(cfg: MoEConfig, keys, init=None) -> Dict[str, Any]:
     k_router, k1, k2, k3 = keys
     init = init or jax.nn.initializers.normal(stddev=0.02)
     e, d, h = cfg.n_experts_held, cfg.dim, cfg.hidden_dim
-    gated = cfg.expert_kind == "swiglu"
-    assert gated or cfg.expert_kind == "relu2", cfg.expert_kind
+    gated = _gate_of(cfg) is not None
     w = cfg.latent_dim or d  # what a routed expert reads and writes
     leaves = {
         "router": init(k_router, (d, cfg.n_experts), cfg.dtype),
@@ -297,26 +306,38 @@ def _relu2(x):
     return jnp.square(jax.nn.relu(x))
 
 
+_GATES = {"swiglu": jax.nn.silu, "reglu": jax.nn.relu, "relu2": None}
+
+
+def _gate_of(cfg: MoEConfig):
+    """What a gated expert puts around its first product before it
+    multiplies the second (`expert_kind`); None where experts have two
+    matrices and no gate."""
+    assert cfg.expert_kind in _GATES, cfg.expert_kind
+    return _GATES[cfg.expert_kind]
+
+
 def _expert_matrices(lp):
     return [lp[name] for name in _EXPERT_AXES if name in lp]
 
 
-def _grouped_experts(xs, product, we1, *rest):
+def _grouped_experts(xs, product, we1, *rest, gate=jax.nn.silu):
     """Rows in expert order through their experts: [R, D] -> [R, D].
-    `rest` is (we3, we2) of gated SiLU experts, (we2,) of relu^2 ones;
+    `rest` is (we3, we2) of gated experts, whose first product goes
+    through `gate` (`_gate_of`), (we2,) of relu^2 ones;
     `product(rows, w)` is the path's grouped product of rows with their
     experts' matrices among `w`."""
-    *gate, we2 = rest
+    *gated, we2 = rest
     with jax.named_scope("expert_matmul"):
         hidden = product(xs, we1)                          # [R, F]
-        if gate:
-            hidden = jax.nn.silu(hidden) * product(xs, gate[0])
+        if gated:
+            hidden = gate(hidden) * product(xs, gated[0])
         else:
             hidden = _relu2(hidden)
         return product(hidden, we2)                        # [R, D]
 
 
-def _sparse_experts(x, gates, top_i, we1, *rest):
+def _sparse_experts(x, gates, top_i, we1, *rest, gate=jax.nn.silu):
     """The chosen experts of the tokens at hand. x [T, D], gates and
     top_i [T, k], weights [E, ...] -> [T, D]."""
     with jax.named_scope("moe_dispatch"):
@@ -326,7 +347,7 @@ def _sparse_experts(x, gates, top_i, we1, *rest):
         xs = _spread(x, order, inv)                        # [T*k, D]
     ys = _grouped_experts(
         xs, functools.partial(lax.ragged_dot, group_sizes=group_sizes),
-        we1, *rest)
+        we1, *rest, gate=gate)
     with jax.named_scope("moe_combine"):
         return _collect(ys, gates, order, inv)
 
@@ -392,7 +413,8 @@ def _held_experts(cfg: MoEConfig, x, gates, top_i, layer, *stacks):
             xs = _rows(x, token)                           # [rows, D]
         ys = _grouped_experts(
             xs, functools.partial(grouped_matmul.grouped_matmul,
-                                  layer=layer, groups=groups), *stacks)
+                                  layer=layer, groups=groups),
+            *stacks, gate=_gate_of(cfg))
         with jax.named_scope("moe_combine"):
             # Rows past the held pairs belong to no group: whatever the
             # grouped product left there counts nothing.
@@ -405,6 +427,178 @@ def _held_experts(cfg: MoEConfig, x, gates, top_i, layer, *stacks):
                         jnp.zeros(x.shape, jnp.float32))
     return (out.astype(x.dtype), n_held.astype(jnp.int32),
             jnp.maximum(n_buffers - 1, 0).astype(jnp.int32),
+            (held_counts > 0).sum(dtype=jnp.int32))
+
+
+# A trained share's buffers hold this many times the pairs it would be
+# dealt were the router uniform (and never fewer rows than
+# `_HELD_ROWS_MIN`): what a buffer's rows take in memory is paid
+# whatever the load, so less room than a served share's; the rare
+# buffer beyond the first is recomputed in the backward pass.
+_TRAINED_ROWS_SLACK = 1.5
+
+
+@jax.custom_vjp
+def _spread_some(x, pair, row, live):
+    """x [T, D] -> [R, D]: the tokens of the R pairs `pair` (a pair is
+    token * k + choice). `row` [T, k] says in which of the R rows each
+    pair lies and `live` [T, k] whether it lies in any."""
+    return _rows(x, pair // live.shape[1])
+
+
+def _sum_by_token(y, row, live, weights=None):
+    """[T, D] float32: every token's rows among y [R, D], as `row` and
+    `live` [T, k] name them, times `weights` [T, k] if given, summed.
+    A choice at a time: one gather of all T x k rows would stand whole
+    in memory, 1.9 GB of a 16k step's, where this holds [T, D]. A pair
+    that is not live counts nothing, whatever the row it names holds."""
+    out = jnp.zeros((row.shape[0], y.shape[1]), jnp.float32)
+    for j in range(row.shape[1]):
+        picked = jnp.where(live[:, j, None], _rows(y, row[:, j]), 0)
+        picked = picked.astype(jnp.float32)
+        out += picked if weights is None else picked * weights[:, j, None]
+    return out
+
+
+@jax.custom_vjp
+def _collect_some(y, gates, pair, row, live):
+    """y [R, D], the rows of the pairs `pair`, gates [T, k] float32 ->
+    [T, D]: every token's rows among them, weighted by their gates and
+    summed in float32."""
+    return _sum_by_token(y, row, live, gates).astype(y.dtype)
+
+
+# Each other's transpose again, so the backward pass of a share is row
+# gathers too: `row` is where a gradient's rows are found by token.
+def _spread_some_bwd(res, g):
+    row, live = res
+    return _sum_by_token(g, row, live).astype(g.dtype), None, None, None
+
+
+def _collect_some_bwd(res, g):
+    y, gates, pair, row, live = res
+    k = gates.shape[1]
+    g_rows = _rows(g, pair // k)                           # [R, D]
+    # A row that no live pair names (one past the pairs held, or the
+    # padding behind the last pair) has no gate of its own.
+    live_row = _rows(live.reshape(-1), pair) \
+        & (_rows(row.reshape(-1), pair) == jnp.arange(pair.size))
+    d_gates = _rows(jnp.einsum("rd,rd->r", g_rows, y,
+                               preferred_element_type=jnp.float32),
+                    row.reshape(-1)).reshape(gates.shape)
+    d_y = g_rows * jnp.where(live_row, _rows(gates.reshape(-1), pair),
+                             0.0)[:, None]
+    return (d_y.astype(y.dtype), jnp.where(live, d_gates, 0.0), None, None,
+            None)
+
+
+_spread_some.defvjp(lambda x, pair, row, live: (
+    _spread_some(x, pair, row, live), (row, live)), _spread_some_bwd)
+_collect_some.defvjp(lambda y, gates, pair, row, live: (
+    _collect_some(y, gates, pair, row, live), (y, gates, pair, row, live)),
+    _collect_some_bwd)
+
+
+def _held_buffer(cfg: MoEConfig, rows: int, lo, x, gates, order, at, ends,
+                 *ws):
+    """The held pairs in rows [lo, lo + rows) of the order through
+    their experts and back to their tokens: [T, D]. `order` lists the
+    pairs held experts first (padded to whole buffers), `at` [T, k]
+    says where in it each pair lies, `ends` [count] where each held
+    expert's pairs end; the last of them is the number held."""
+    n_held = ends[-1]
+    with jax.named_scope("moe_dispatch"):
+        pair = lax.dynamic_slice_in_dim(order, lo, rows)
+        live = (at >= lo) & (at < jnp.minimum(n_held, lo + rows))
+        row = jnp.clip(at - lo, 0, rows - 1)
+        sizes = jnp.diff(jnp.clip(ends, lo, lo + rows), prepend=lo)
+        xs = _spread_some(x, pair, row, live)              # [rows, D]
+    ys = _grouped_experts(
+        xs, functools.partial(lax.ragged_dot, group_sizes=sizes), *ws,
+        gate=_gate_of(cfg))
+    with jax.named_scope("moe_combine"):
+        return _collect_some(ys, gates, pair, row, live)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _further_buffers(cfg, rows, out, x, gates, order, at, ends, *ws):
+    """`out` plus the buffers beyond the first, as many as the pairs
+    held reach: a loop whose count only the device knows, none where
+    the first buffer held them all. Its backward pass is the same loop
+    again, each buffer recomputed and pulled back (autodiff has no
+    transpose for such a loop, and under `lax.cond` in a `lax.scan` a
+    buffer that never runs still keeps zeros the size of x and of the
+    weights an iteration, from the forward pass on)."""
+    return lax.fori_loop(
+        1, (ends[-1] + rows - 1) // rows,
+        lambda i, out: out + _held_buffer(cfg, rows, i * rows, x, gates,
+                                          order, at, ends, *ws), out)
+
+
+def _further_buffers_bwd(cfg, rows, res, g):
+    x, gates, order, at, ends, ws = res
+
+    def pulled(i, acc):
+        _, pull = jax.vjp(
+            lambda x, gates, *ws: _held_buffer(
+                cfg, rows, i * rows, x, gates, order, at, ends, *ws),
+            x, gates, *ws)
+        return jax.tree.map(jnp.add, acc, pull(g))
+
+    d_x, d_gates, *d_ws = lax.fori_loop(
+        1, (ends[-1] + rows - 1) // rows, pulled,
+        jax.tree.map(jnp.zeros_like, (x, gates, *ws)))
+    return (g, d_x, d_gates, None, None, None, *d_ws)
+
+
+_further_buffers.defvjp(
+    lambda cfg, rows, out, x, gates, order, at, ends, *ws: (
+        _further_buffers(cfg, rows, out, x, gates, order, at, ends, *ws),
+        (x, gates, order, at, ends, ws)), _further_buffers_bwd)
+
+
+def _held_experts_trained(cfg: MoEConfig, x, gates, top_i, *ws):
+    """`_sparse_experts` of a share of the experts (`cfg.experts_held`),
+    with a backward pass, at the cost of the pairs that landed on the
+    share: x [T, D], gates and top_i [T, k], this layer's matrices of
+    the experts held, [count, ...] -> (out [T, D], pairs held int32,
+    buffers beyond the first int32, held experts with a pair int32).
+
+    The pairs are sorted held experts first (stable, so an expert's
+    tokens stay in token order) and the held ones go through the
+    grouped products, `lax.ragged_dot`'s as the whole layer's are, a
+    buffer of `rows` rows at a time (`_held_buffer`): static,
+    `_TRAINED_ROWS_SLACK` times the uniform share of the T * k pairs.
+    The first buffer is the layer's as autodiff sees it; further ones
+    (`_further_buffers`) run as far as the pairs held reach, so none is
+    ever dropped, and a buffer no pair reaches costs nothing. A pair on
+    an absent expert is neither gathered nor computed, and adds nothing
+    to its token. Gathers and the weighted sum back are `_spread_some`
+    and `_collect_some`."""
+    first, count = cfg.experts_held
+    t, k = top_i.shape
+    pairs = t * k
+    rows = min(pairs, max(_HELD_ROWS_MIN, -(-int(
+        _TRAINED_ROWS_SLACK * pairs * count) // cfg.n_experts)))
+    n_buffers = -(-pairs // rows)
+    with jax.named_scope("moe_dispatch"):
+        local = top_i.reshape(-1) - first
+        local = jnp.where((local >= 0) & (local < count), local, count)
+        # The held pairs in expert order, then the absent; padded so
+        # that the last buffer's slice does not run off the end.
+        order = jnp.argsort(local, stable=True)
+        at = jnp.argsort(order).reshape(t, k)   # where each pair lies
+        order = jnp.pad(order, (0, n_buffers * rows - pairs))
+        held_counts = _expert_counts(local, count)
+        ends = jnp.cumsum(held_counts)
+    out = _held_buffer(cfg, rows, jnp.int32(0), x, gates, order, at, ends,
+                       *ws)
+    if n_buffers > 1:
+        out = _further_buffers(cfg, rows, out, x, gates, order, at, ends,
+                               *ws)
+    n_held = ends[-1]
+    return (out, n_held.astype(jnp.int32),
+            jnp.maximum((n_held + rows - 1) // rows - 1, 0).astype(jnp.int32),
             (held_counts > 0).sum(dtype=jnp.int32))
 
 
@@ -438,7 +632,8 @@ def _route(cfg: MoEConfig, lp, x):
     return probs, gates, top_i
 
 
-def _moe_ffn(cfg: MoEConfig, lp, x, mesh, rules, stacks=None):
+def _moe_ffn(cfg: MoEConfig, lp, x, mesh, rules, stacks=None, *,
+             routed=None, trained=False):
     """x: [B, S, D] -> ([B, S, D], aux loss scalar, pairs routed to each
     expert [E] int32, and what the layer computed of them as int32
     scalars: `pairs_held`, the pairs that fell on experts held here
@@ -452,11 +647,16 @@ def _moe_ffn(cfg: MoEConfig, lp, x, mesh, rules, stacks=None):
     (`latent_up`); the router and the shared expert read x itself.
     `stacks` is None, the expert matrices being `lp`'s, or, for a held
     share, (the matrices of the whole run stacked by layer, this
-    layer's index in them), `lp` holding the layer's other leaves."""
+    layer's index in them), `lp` holding the layer's other leaves.
+    `routed` [B, S, D] is what the router scores where that is not x
+    (SmallThinker scores the layer's input, its experts read the
+    normed stream after attention). `trained` says that the layer is
+    to have a gradient: a held share then goes through
+    `_held_experts_trained` and its own matrices."""
     b, s, _ = x.shape
     k = cfg.n_experts_per_token
     with jax.named_scope("router"):
-        probs, gates, top_i = _route(cfg, lp, x)
+        probs, gates, top_i = _route(cfg, lp, x if routed is None else routed)
         counts = _expert_counts(top_i, cfg.n_experts)
         # Load-balance aux loss: E * sum_e (share of the tokens that
         # chose e) * (mean router probability of e).
@@ -473,7 +673,7 @@ def _moe_ffn(cfg: MoEConfig, lp, x, mesh, rules, stacks=None):
         """On the tokens at hand: the whole batch, or one shard's."""
         t = x.shape[0] * x.shape[1]
         out = _sparse_experts(x.reshape(t, d), gates.reshape(t, k),
-                              top_i.reshape(t, k), *ws)
+                              top_i.reshape(t, k), *ws, gate=_gate_of(cfg))
         return out.reshape(x.shape)
 
     run, layer = stacks or (lp, None)
@@ -481,12 +681,19 @@ def _moe_ffn(cfg: MoEConfig, lp, x, mesh, rules, stacks=None):
     n_held, over = jnp.int32(b * s * k), jnp.int32(0)
     touched = (counts > 0).sum(dtype=jnp.int32)
     if cfg.experts_held is not None:
-        assert mesh is None, "a held share of the experts runs on one chip"
-        if stacks is None:  # a layer's own matrices: a run of one layer
-            weights, layer = [w[None] for w in weights], 0
-        out, n_held, over, touched = _held_experts(
-            cfg, x.reshape(b * s, d), gates.reshape(b * s, k),
-            top_i.reshape(b * s, k), layer, *weights)
+        assert mesh is None or mesh.size == 1, \
+            "a held share of the experts runs on one chip"
+        flat = (x.reshape(b * s, d), gates.reshape(b * s, k),
+                top_i.reshape(b * s, k))
+        if trained:
+            assert stacks is None, "a trained layer keeps its scanned slice"
+            out, n_held, over, touched = _held_experts_trained(
+                cfg, *flat, *weights)
+        else:
+            if stacks is None:  # a layer's own matrices: a run of one layer
+                weights, layer = [w[None] for w in weights], 0
+            out, n_held, over, touched = _held_experts(
+                cfg, *flat, layer, *weights)
         out = out.reshape(x.shape)
     elif mesh is None:
         out = experts(x, gates, top_i, *weights)
@@ -524,9 +731,9 @@ def _add_shared_expert(cfg: MoEConfig, lp, x, out):
     assert cfg.shared_combination in ("sum", "average"), cfg
     with jax.named_scope("shared_expert"):
         hidden = jnp.einsum("bsd,df->bsf", x, lp["ws1"])
-        if cfg.expert_kind == "swiglu":
-            hidden = jax.nn.silu(hidden) \
-                * jnp.einsum("bsd,df->bsf", x, lp["ws3"])
+        gate = _gate_of(cfg)
+        if gate is not None:
+            hidden = gate(hidden) * jnp.einsum("bsd,df->bsf", x, lp["ws3"])
         else:
             hidden = _relu2(hidden)
         shared = jnp.einsum("bsf,fd->bsd", hidden, lp["ws2"])
@@ -564,7 +771,7 @@ def _parts(cfg: MoEConfig, mesh, rules):
     """What `decoder` is handed for this architecture: the mixer, the
     FFN, and what a rematerialised layer saves (nothing)."""
     def ffn(h, lp):
-        out, aux, counts, _ = _moe_ffn(cfg, lp, h, mesh, rules)
+        out, aux, counts, _ = _moe_ffn(cfg, lp, h, mesh, rules, trained=True)
         return out, {"aux": aux, "counts": counts}
 
     # `_norm_all_heads` is read here, when a forward pass is traced, so

@@ -8,8 +8,11 @@ kernel (grid over q-blocks, inner k sweep) — the [Sq, Sk] score matrix
 never materialises in HBM in either direction. Long-context training
 memory is additionally handled one level up by ring attention
 (`ray_tpu.parallel.ring_attention`), which only ever sees per-chunk blocks.
-The forward kernel alone also takes a sliding window and can leave the
-logsumexp unwritten (`flash_attention_forward`, a served prefill's).
+All three kernels take a sliding window (`window`: a row sees that many
+keys, itself the last): tiles wholly outside a block's windows are
+neither fetched nor computed, forward and backward. The forward kernel
+alone can leave the logsumexp unwritten (`flash_attention_forward`, a
+served prefill's).
 
 Layout: public API takes [batch, seq, heads, head_dim] (matching the rest
 of the framework); the kernel runs in [batch, heads, seq, head_dim]. GQA is
@@ -35,6 +38,13 @@ _NEG_INF = -1e30
 # are independent; the inner sweep accumulates.
 _COMPILER_PARAMS = pltpu.CompilerParams(
     dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"))
+# The backward kernels of a windowed call: the second edge's mask is one
+# more [block_q, block_k] array among the tile's intermediates, and at
+# blocks of 1024 the dk/dv kernel then asks for 18.7 MB of fast memory
+# against the compiler's default limit of 16 (of the core's 128).
+_WINDOWED_PARAMS = pltpu.CompilerParams(
+    dimension_semantics=("parallel", "parallel", "parallel", "arbitrary"),
+    vmem_limit_bytes=32 << 20)
 
 
 def on_tpu() -> bool:
@@ -253,7 +263,10 @@ def _flash_fwd(q, k, v, causal: bool, sm_scale: float,
 def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                           dk_ref, dv_ref, dk_acc, dv_acc, *,
                           sm_scale: float, causal: bool,
-                          block_q: int, block_k: int, sq: int, sk: int):
+                          block_q: int, block_k: int, sq: int, sk: int,
+                          window: Optional[int] = None):
+    """`window` as the forward kernel's: a block of keys is seen by the
+    blocks of queries from its own up to the one a window ahead."""
     ik = pl.program_id(2)
     iq = pl.program_id(3)
     nq = pl.num_programs(3)
@@ -266,6 +279,10 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     should_compute = True
     if causal:
         should_compute = (iq + 1) * block_q > ik * block_k
+    if window is not None:
+        # Nor does a block of queries whose first row lies a window or
+        # more ahead of the block's newest key.
+        should_compute &= iq * block_q - ((ik + 1) * block_k - 1) < window
 
     pad_rows = sq % block_q != 0
 
@@ -302,6 +319,8 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                 cols = ik * block_k + jax.lax.broadcasted_iota(
                     jnp.int32, (block_q, block_k), 1)
                 mask &= rows >= cols
+                if window is not None:
+                    mask &= rows - cols < window
             if pad_rows:
                 mask &= rows < sq
             p = jnp.where(mask, p, 0.0)
@@ -331,6 +350,9 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         needs_mask = False
         if causal:
             needs_mask = iq * block_q < (ik + 1) * block_k - 1
+        if window is not None:
+            # ... and the tiles the window's far edge crosses.
+            needs_mask |= (iq + 1) * block_q - 1 - ik * block_k >= window
         if pad_rows:
             needs_mask = needs_mask | (iq == nq - 1)
         pl.when(should_compute & needs_mask)(lambda: compute(True))
@@ -346,7 +368,10 @@ def _flash_bwd_dkv_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                          dq_ref, dq_acc, *,
                          sm_scale: float, causal: bool,
-                         block_q: int, block_k: int, sk: int):
+                         block_q: int, block_k: int, sk: int,
+                         window: Optional[int] = None):
+    """`window` as the forward kernel's, and the same blocks of keys
+    skipped."""
     iq = pl.program_id(2)
     ik = pl.program_id(3)
     nk = pl.num_programs(3)
@@ -358,6 +383,8 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
     should_compute = True
     if causal:
         should_compute = (iq + 1) * block_q > ik * block_k
+    if window is not None:
+        should_compute &= iq * block_q - ((ik + 1) * block_k - 1) < window
 
     pad_cols = sk % block_k != 0
 
@@ -397,6 +424,8 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
                 mask = rows >= cols
             else:
                 mask = cols < sk
+            if window is not None:
+                mask &= rows - cols < window
             p = jnp.where(mask, p, 0.0)
 
         dp = jax.lax.dot_general(
@@ -416,6 +445,8 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
         needs_mask = False
         if causal:
             needs_mask = iq * block_q < (ik + 1) * block_k - 1
+        if window is not None:
+            needs_mask |= (iq + 1) * block_q - 1 - ik * block_k >= window
         if pad_cols:
             needs_mask = needs_mask | (ik == nk - 1)
         pl.when(should_compute & needs_mask)(lambda: compute(True))
@@ -428,8 +459,13 @@ def _flash_bwd_dq_kernel(q_ref, k_ref, v_ref, do_ref, lse_ref, delta_ref,
 
 
 def _flash_bwd(q, k, v, o, lse, do, causal: bool, sm_scale: float,
-               block_q: int, block_k: int, interpret: bool):
-    """All tensors [B, H(kv), S, D]; lse [B, H, Sq] float32."""
+               block_q: int, block_k: int, interpret: bool,
+               window: Optional[int] = None):
+    """All tensors [B, H(kv), S, D]; lse [B, H, Sq] float32. With
+    `window` the index maps, like the kernels, leave out the tiles that
+    lie wholly outside the windows: the blocks of keys behind a block of
+    queries' (dq), the blocks of queries ahead of a block of keys'
+    (dk/dv)."""
     b, h, sq, d = q.shape
     _, h_kv, sk, _ = k.shape
     block_q = min(block_q, sq)
@@ -437,6 +473,11 @@ def _flash_bwd(q, k, v, o, lse, do, causal: bool, sm_scale: float,
     nq = pl.cdiv(sq, block_q)
     nk = pl.cdiv(sk, block_k)
 
+    # A kernel without a window is handed none: the call, and so the
+    # program lowered from it, is the one it was before there was one.
+    windowed = {} if window is None else {"window": window}
+    compiler_params = _COMPILER_PARAMS if window is None \
+        else _WINDOWED_PARAMS
     delta = jnp.sum(do.astype(jnp.float32) * o.astype(jnp.float32), axis=-1)
     lse4 = jnp.broadcast_to(lse[..., None], (b, h, sq, 128))
     delta4 = jnp.broadcast_to(delta[..., None], (b, h, sq, 128))
@@ -444,6 +485,8 @@ def _flash_bwd(q, k, v, o, lse, do, causal: bool, sm_scale: float,
     def kv_index(ib, ih, iq, ik):
         if causal:
             ik = jnp.minimum(ik, ((iq + 1) * block_q - 1) // block_k)
+        if window is not None:
+            ik = jnp.maximum(ik, (iq * block_q - window + 1) // block_k)
         return (ib, ih * h_kv // h, ik, 0)
 
     def q_index(ib, ih, iq, ik):
@@ -456,7 +499,7 @@ def _flash_bwd(q, k, v, o, lse, do, causal: bool, sm_scale: float,
     dq = pl.pallas_call(
         functools.partial(
             _flash_bwd_dq_kernel, sm_scale=sm_scale, causal=causal,
-            block_q=block_q, block_k=block_k, sk=sk),
+            block_q=block_q, block_k=block_k, sk=sk, **windowed),
         grid=(b, h, nq, nk),
         in_specs=[
             pl.BlockSpec((1, 1, block_q, d), q_index),
@@ -469,7 +512,7 @@ def _flash_bwd(q, k, v, o, lse, do, causal: bool, sm_scale: float,
         out_specs=pl.BlockSpec((1, 1, block_q, d), q_index),
         out_shape=jax.ShapeDtypeStruct((b, h, sq, d), q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, d), jnp.float32)],
-        compiler_params=_COMPILER_PARAMS,
+        compiler_params=compiler_params,
         interpret=interpret,
         name="flash_bwd_dq",
     )(q, k, v, do, lse4, delta4)
@@ -477,15 +520,16 @@ def _flash_bwd(q, k, v, o, lse, do, causal: bool, sm_scale: float,
     # --- dk/dv: grid over k-blocks, inner sweep over q-blocks --------------
     # For causal masks the head of the q sweep is skipped; clamp the fetch
     # index up to the first contributing q-block.
+    # Under a window its tail is skipped too: clamp down to the last.
     def q_index_dkv(ib, ih, ik, iq):
         if causal:
             iq = jnp.maximum(iq, (ik * block_k) // block_q)
+        if window is not None:
+            iq = jnp.minimum(
+                iq, ((ik + 1) * block_k + window - 2) // block_q)
         return (ib, ih, iq, 0)
 
-    def lane_index_dkv(ib, ih, ik, iq):
-        if causal:
-            iq = jnp.maximum(iq, (ik * block_k) // block_q)
-        return (ib, ih, iq, 0)
+    lane_index_dkv = q_index_dkv
 
     def kv_index_dkv(ib, ih, ik, iq):
         return (ib, ih * h_kv // h, ik, 0)
@@ -498,7 +542,7 @@ def _flash_bwd(q, k, v, o, lse, do, causal: bool, sm_scale: float,
     dk, dv = pl.pallas_call(
         functools.partial(
             _flash_bwd_dkv_kernel, sm_scale=sm_scale, causal=causal,
-            block_q=block_q, block_k=block_k, sq=sq, sk=sk),
+            block_q=block_q, block_k=block_k, sq=sq, sk=sk, **windowed),
         grid=(b, h, nk, nq),
         in_specs=[
             pl.BlockSpec((1, 1, block_q, d), q_index_dkv),
@@ -520,7 +564,7 @@ def _flash_bwd(q, k, v, o, lse, do, causal: bool, sm_scale: float,
             pltpu.VMEM((block_k, d), jnp.float32),
             pltpu.VMEM((block_k, d), jnp.float32),
         ],
-        compiler_params=_COMPILER_PARAMS,
+        compiler_params=compiler_params,
         interpret=interpret,
         name="flash_bwd_dkv",
     )(q, k, v, do, lse4, delta4)
@@ -558,15 +602,18 @@ def attention_reference(q, k, v, causal: bool, sm_scale: float,
                       preferred_element_type=jnp.float32).astype(q.dtype)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7))
-def _flash(q, k, v, causal, sm_scale, block_q, block_k, interpret):
-    o, _ = _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret)
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5, 6, 7, 8))
+def _flash(q, k, v, causal, sm_scale, block_q, block_k, interpret,
+           window=None):
+    o, _ = _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret,
+                      window=window)
     return o
 
 
-def _flash_vjp_fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret):
+def _flash_vjp_fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret,
+                   window=None):
     o, lse = _flash_fwd(q, k, v, causal, sm_scale, block_q, block_k,
-                        interpret)
+                        interpret, window=window)
     # Under layer-level rematerialization, saving these two residuals (and
     # recomputing only the cheap projections for q/k/v) lets the remat
     # policy elide the forward kernel from the backward pass entirely:
@@ -581,21 +628,26 @@ def _flash_vjp_fwd(q, k, v, causal, sm_scale, block_q, block_k, interpret):
     return o, (q, k, v, o, lse)
 
 
-def _flash_vjp_bwd(causal, sm_scale, block_q, block_k, interpret,
+def _flash_vjp_bwd(causal, sm_scale, block_q, block_k, interpret, window,
                    residuals, do):
     q, k, v, o, lse = residuals
     return _flash_bwd(q, k, v, o, lse, do, causal, sm_scale,
-                      block_q, block_k, interpret)
+                      block_q, block_k, interpret, window)
 
 
 _flash.defvjp(_flash_vjp_fwd, _flash_vjp_bwd)
 
 
 def flash_attention(q, k, v, *, causal: bool = True,
+                    window: Optional[int] = None,
                     sm_scale: Optional[float] = None,
                     block_q: int = 1024, block_k: int = 1024,
                     interpret: bool = False):
-    """Flash attention over [batch, seq, heads, head_dim] tensors.
+    """Flash attention over [batch, seq, heads, head_dim] tensors, with
+    a backward pass. With `window` (causal only) a row sees that many
+    keys and no more, itself the last of them, and the tiles wholly
+    outside the windows are neither read nor computed, forward or
+    backward.
 
     KV tensors may have fewer heads (GQA). On a TPU backend this is
     always the compiled kernel: `interpret` never reaches a TPU call,
@@ -609,12 +661,12 @@ def flash_attention(q, k, v, *, causal: bool = True,
     qt = q.transpose(0, 2, 1, 3)
     kt = k.transpose(0, 2, 1, 3)
     vt = v.transpose(0, 2, 1, 3)
-    if on_tpu():
-        out = _flash(qt, kt, vt, causal, sm_scale, block_q, block_k, False)
-    elif interpret:
-        out = _flash(qt, kt, vt, causal, sm_scale, block_q, block_k, True)
+    assert window is None or (causal and window > 0), (causal, window)
+    if on_tpu() or interpret:
+        out = _flash(qt, kt, vt, causal, sm_scale, block_q, block_k,
+                     not on_tpu(), window)
     else:
-        out = attention_reference(qt, kt, vt, causal, sm_scale)
+        out = attention_reference(qt, kt, vt, causal, sm_scale, window)
     return out.transpose(0, 2, 1, 3)
 
 

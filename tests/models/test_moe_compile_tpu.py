@@ -294,3 +294,62 @@ def test_olmo_hybrids_step_compiles_for_the_v5e(one_chip, program, bucket,
     assert memory.temp_size_in_bytes < 0.5e9
     assert memory.argument_size_in_bytes + memory.temp_size_in_bytes \
         < 16.0e9
+
+
+@pytest.mark.parametrize("window", [None, 4096], ids=["full", "window"])
+def test_trained_flash_kernels_compile_at_smallthinkers_shapes(one_chip,
+                                                               window):
+    """A sequence of 16,384 tokens, 28 query heads on 4 key heads of
+    128, forward and backward at blocks of 1024: with a window the
+    dk/dv kernel's tile asks more fast memory than the compiler's
+    default allows, which `ops.attention._WINDOWED_PARAMS` grants."""
+    from ray_tpu.ops import attention
+
+    def shape(heads):
+        return jax.ShapeDtypeStruct((1, heads, 16384, 128), jnp.bfloat16,
+                                    sharding=one_chip)
+
+    def loss(q, k, v):
+        return attention._flash(q, k, v, True, 128 ** -0.5, 1024, 1024,
+                                False, window).astype(jnp.float32).sum()
+
+    text = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+        shape(28), shape(4), shape(4)).compile().as_text()
+    kernels = re.findall(r"%\w*?(flash_(?:fwd|bwd_dq|bwd_dkv))_*\.\d+ = "
+                         r".* custom-call\(", text)
+    assert sorted(kernels) == ["flash_bwd_dkv", "flash_bwd_dq", "flash_fwd"]
+
+
+def test_a_trained_share_compiles_for_the_v5e(one_chip):
+    """SmallThinker's expert layer at the cell's shapes: 65,536 tokens,
+    6 of 64 experts a token, 16 ReGLU experts of width 768 held. The
+    first buffer's 3 grouped products forward and 6 backward and, in the
+    loops of the buffers beyond it, the same again (the backward loop
+    recomputes a buffer, so 3 + 9); no scatter of rows."""
+    import dataclasses
+
+    cfg = dataclasses.replace(
+        moe.MoEConfig.debug_moe(), dim=2560, hidden_dim=768, n_experts=64,
+        n_experts_per_token=6, experts_held=(0, 16), expert_kind="reglu",
+        dtype=jnp.bfloat16)
+
+    def shape(*dims, dtype=jnp.bfloat16):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    def loss(x, gates, top_i, we1, we3, we2):
+        out, held, over, touched = moe._held_experts_trained(
+            cfg, x, gates, top_i, we1, we3, we2)
+        return out.astype(jnp.float32).sum()
+
+    compiled = jax.jit(
+        jax.value_and_grad(loss, argnums=(0, 1, 3, 4, 5))).lower(
+        shape(65536, 2560), shape(65536, 6, dtype=jnp.float32),
+        shape(65536, 6, dtype=jnp.int32), shape(16, 2560, 768),
+        shape(16, 2560, 768), shape(16, 768, 2560)).compile()
+    text = compiled.as_text()
+    kernels = re.findall(r"%([\w-]+?)(?:\.\d+)? = \S+ custom-call\(", text)
+    assert sum(k in PRODUCTS for k in kernels) == 9 + 3 + 9
+    assert not re.search(r"\[\d+,\d+\]\S* scatter\(", text)
+    # A buffer is 1.5 times the even share's 98,304 rows, and no array
+    # of all 393,216 pairs' rows stands in memory.
+    assert "[147456,2560]" in text and "[393216,2560]" not in text
