@@ -81,6 +81,7 @@ from typing import Any, Dict, Optional, Tuple
 import jax
 import jax.numpy as jnp
 from jax import lax
+from jax.ad_checkpoint import checkpoint_name
 
 from ray_tpu.models import decoder
 from ray_tpu.models.llama import (
@@ -326,15 +327,20 @@ def _grouped_experts(xs, product, we1, *rest, gate=jax.nn.silu):
     `rest` is (we3, we2) of gated experts, whose first product goes
     through `gate` (`_gate_of`), (we2,) of relu^2 ones;
     `product(rows, w)` is the path's grouped product of rows with their
-    experts' matrices among `w`."""
+    experts' matrices among `w`. The products carry the names of
+    `_SAVED` for a rematerialised layer's policy to keep; under no
+    `jax.checkpoint`, or one whose list lacks them, a name is an
+    identity. What lies between them (`gate(h) * up`, `_relu2`) has no
+    name: from saved products it is one elementwise pass."""
     *gated, we2 = rest
     with jax.named_scope("expert_matmul"):
-        hidden = product(xs, we1)                          # [R, F]
+        hidden = checkpoint_name(product(xs, we1), "expert_gate")  # [R, F]
         if gated:
-            hidden = gate(hidden) * product(xs, gated[0])
+            hidden = gate(hidden) * checkpoint_name(
+                product(xs, gated[0]), "expert_up")
         else:
             hidden = _relu2(hidden)
-        return product(hidden, we2)                        # [R, D]
+        return checkpoint_name(product(hidden, we2), "expert_out")  # [R, D]
 
 
 def _sparse_experts(x, gates, top_i, we1, *rest, gate=jax.nn.silu):
@@ -767,9 +773,42 @@ def served_ffn(cfg: MoEConfig):
     return ffn
 
 
+# What a rematerialised layer of this family keeps for its backward
+# pass (`decoder.layers`' `save`): the attention kernel's output and
+# logsumexp, as the dense family keeps them, with its q, k and v
+# (`ops.attention` names all five), and the three grouped products
+# (`_grouped_experts`; `expert_up` only where experts are gated,
+# `expert_out` being what `_collect_bwd` needs for the gates'
+# gradient). The backward pass then runs 6 grouped products a layer and
+# no forward attention kernel, where keeping nothing made it 9 and
+# one; the output projection, the norms, the router, the row gathers
+# and the experts' activation are still recomputed. A kept array is
+# written into the scan's stack and sliced out of it again, two copies
+# that a kernel's output and operand cannot be fused into: a third of
+# what the products' recomputation cost comes back as those (PERF.md,
+# PR 48). The price is memory, per layer, per chip and per token, in
+# bfloat16 (R pairs, F an expert's width, T tokens, D the hidden size):
+#
+#                           OLMoE-1B-7B, T 16,384,   Mixtral-8x7B, T 4,096,
+#                           R 131,072, F 1,024,      R 8,192, F 14,336,
+#                           D 2,048                  D 4,096
+#   expert_gate, expert_up  268 MB each (R x F)      235 MB each
+#   expert_out              537 MB (R x D)           67 MB
+#   flash_out + flash_lse   67 + 1 MB (T x D)        34 + 0.5 MB
+#   flash_q, _k, _v         67 MB each               34, 8 and 8 MB
+#
+# 1.34 and 0.62 GB a layer: it fits the benchmark's two cells because
+# they are cut to 2 layers. At a published depth the hidden products
+# stop fitting a v5e long before the others do; a list chosen by bytes
+# is PERF.md section 7's open question, and no knob chooses one now.
+_SAVED = ("flash_out", "flash_lse", "flash_q", "flash_k", "flash_v",
+          "expert_gate", "expert_up", "expert_out")
+
+
 def _parts(cfg: MoEConfig, mesh, rules):
     """What `decoder` is handed for this architecture: the mixer, the
-    FFN, and what a rematerialised layer saves (nothing)."""
+    FFN, and what a rematerialised layer saves (`_SAVED`, with its
+    bytes; `cfg.remat` false saves every activation)."""
     def ffn(h, lp):
         out, aux, counts, _ = _moe_ffn(cfg, lp, h, mesh, rules, trained=True)
         return out, {"aux": aux, "counts": counts}
@@ -777,7 +816,7 @@ def _parts(cfg: MoEConfig, mesh, rules):
     # `_norm_all_heads` is read here, when a forward pass is traced, so
     # that a test's patch of it reaches the program.
     return dict(mixer=self_attention(cfg, mesh, rules, _norm_all_heads),
-                ffn=ffn, save=[] if cfg.remat else None, mesh=mesh,
+                ffn=ffn, save=_SAVED if cfg.remat else None, mesh=mesh,
                 rules=rules)
 
 

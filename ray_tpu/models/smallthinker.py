@@ -200,6 +200,12 @@ def loss_fn(params, batch, cfg: SmallThinkerConfig, *, mesh=None,
     pair fell on, the buffers beyond a layer's first (all summed over
     layers), and the busiest held expert's count beside the held
     experts' mean."""
+    # A rematerialised layer here keeps nothing but its input, where
+    # `moe._SAVED` keeps the attention kernel's output and the grouped
+    # products: at this family's 16k sequences `flash_out` alone is
+    # 1.9 GB over four layers and a held share's hidden products 0.45 GB
+    # a layer, and its cell stands at 90.3 % of a v5e's memory. The
+    # dk/dv kernel of PERF.md section 7 would free what they need.
     ce, _, extras = decoder.loss(
         params, batch, cfg, runs=_runs(params, cfg, mesh, rules), mesh=mesh,
         rules=rules, save=[] if cfg.remat else None)

@@ -470,3 +470,98 @@ def test_a_trained_expert_layer_still_takes_its_layers_slice():
             if eqn.primitive.name == "ragged_dot_general"
             and eqn.invars[1].aval.ndim == 3}
     assert read and read <= shapes | {(e, n, k) for e, k, n in shapes}, read
+
+
+def _on(place):
+    """(mesh, parameters at debug widths) on one device or on the
+    file's fsdp mesh."""
+    cfg = MoEConfig.debug_moe()
+    if place == "one_device":
+        return None, init_moe_params(cfg, jax.random.PRNGKey(0))
+    mesh = create_mesh(MeshConfig(data=2, fsdp=2, tensor=2))
+    return mesh, init_moe_params_sharded(cfg, mesh, jax.random.PRNGKey(0))
+
+
+@pytest.mark.parametrize("place", ["one_device", "fsdp"])
+def test_remat_changes_no_number(place):
+    """What a rematerialised layer keeps (`moe._SAVED`) are the values
+    it would compute again: the loss and every gradient leaf are those
+    of a layer that keeps everything."""
+    import dataclasses
+
+    cfg = MoEConfig.debug_moe()
+    mesh, params = _on(place)
+    batch = _batch(cfg, b=4)
+
+    def run(cfg):
+        return jax.jit(jax.value_and_grad(lambda p: moe_loss_fn(
+            p, batch, cfg, mesh=mesh)[0]))(params)
+
+    plain, remat = run(cfg), run(dataclasses.replace(cfg, remat=True))
+    assert float(plain[0]) == float(remat[0])
+    leaves = jax.tree_util.tree_leaves_with_path(plain[1])
+    assert len(leaves) == 3 + 10  # ten leaves a layer
+    for (path, a), b in zip(leaves, jax.tree.leaves(remat[1])):
+        assert float(jnp.abs(a).max()) > 0, path
+        np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-7,
+                                   err_msg=jax.tree_util.keystr(path))
+
+
+def grouped_products(loss, params):
+    """How many `ragged_dot_general` equations the program of `loss`'s
+    value and gradient holds, wherever they lie."""
+    program = jax.make_jaxpr(jax.value_and_grad(loss))(params)
+    return sum(eqn.primitive.name == "ragged_dot_general"
+               for sub in _subjaxprs(program.jaxpr) for eqn in sub.eqns)
+
+
+@pytest.mark.parametrize("place", ["one_device", "fsdp"])
+@pytest.mark.parametrize("kind,kept,nothing_kept", [
+    ("swiglu", 9, 12), ("relu2", 6, 8)])
+def test_a_rematerialised_layer_computes_each_grouped_product_once(
+        place, kind, kept, nothing_kept, monkeypatch):
+    """The layer body's grouped products with `remat` on: 3 forward and
+    6 backward for gated experts, 2 and 4 for relu2 ones (`expert_up`
+    is absent there), on one device and inside the mesh's `shard_map`
+    alike, as where every activation is kept. One more a product means
+    that its name fell out of `moe._SAVED`, or off the product, and the
+    backward pass computes it again: with no name kept it is all of
+    the forward ones."""
+    import dataclasses
+
+    from ray_tpu.models import moe
+
+    cfg = dataclasses.replace(MoEConfig.debug_moe(), expert_kind=kind)
+    mesh, params = _on(place)
+    if kind == "relu2":
+        del params["layers"]["we3"]
+    batch = _batch(cfg, b=4)
+
+    def products(cfg):
+        return grouped_products(
+            lambda p: moe_loss_fn(p, batch, cfg, mesh=mesh)[0], params)
+
+    assert products(cfg) == kept
+    remat = dataclasses.replace(cfg, remat=True)
+    assert products(remat) == kept
+    monkeypatch.setattr(moe, "_SAVED", ())
+    assert products(remat) == nothing_kept
+
+
+def test_every_kept_name_is_one_the_layer_sets(monkeypatch):
+    """`moe._SAVED` against the `checkpoint_name`s in the traced loss,
+    with the flash kernels in (as on a TPU: nothing is lowered): a
+    name nobody sets is kept in silence, and its array recomputed."""
+    from ray_tpu.models import llama, moe
+    from ray_tpu.ops import attention
+
+    monkeypatch.setattr(attention, "on_tpu", lambda: True)
+    monkeypatch.setattr(llama, "on_tpu", lambda: True)
+    cfg = MoEConfig.debug_moe()
+    params = init_moe_params(cfg, jax.random.PRNGKey(0))
+    batch = _batch(cfg, s=128)
+    program = jax.make_jaxpr(jax.grad(
+        lambda p: moe_loss_fn(p, batch, cfg)[0]))(params)
+    set_names = {eqn.params["name"] for sub in _subjaxprs(program.jaxpr)
+                 for eqn in sub.eqns if eqn.primitive.name == "name"}
+    assert set(moe._SAVED) <= set_names, set(moe._SAVED) - set_names

@@ -61,6 +61,77 @@ def test_expert_layer_compiles_for_the_v5e(one_chip, tokens, k, d, f, e):
     assert not re.search(r"\[\d+,\d+\]\S* scatter\(", text)
 
 
+def test_olmoes_train_step_compiles_for_the_v5e(one_chip, monkeypatch):
+    """The whole train step of `olmoe-1b-7b-0125-train.json` (2 layers,
+    4 x 4096 tokens, bf16 moments, its file's `remat`) as the train
+    runner builds it, on a mesh of the one described chip. A
+    rematerialised layer keeps `moe._SAVED`: the program holds the
+    layer's 3 grouped products forward and 6 backward, where keeping
+    nothing made the backward scan's body compute the 3 again, and one
+    forward attention kernel, in the forward scan alone. What that
+    keeps is memory the cell has: the compiler's peak stays under nine
+    tenths of the 15.75 GiB a v5e offers (the flash kernels would not
+    be this process's CPU choice: the test says it is on a TPU)."""
+    import optax
+    from jax.sharding import NamedSharding, PartitionSpec
+
+    from benchmark.harness.manifest import ROOT, load_json, model_adapter
+    from ray_tpu.models import llama, make_optimizer, make_train_step
+    from ray_tpu.models.training import TrainState
+    from ray_tpu.ops import attention
+    from ray_tpu.parallel import MeshConfig, create_mesh, named_sharding
+    from ray_tpu.parallel.sharding import tree_shardings
+
+    monkeypatch.setattr(attention, "on_tpu", lambda: True)
+    monkeypatch.setattr(llama, "on_tpu", lambda: True)
+    config = load_json(ROOT, "benchmark", "configs",
+                       "olmoe-1b-7b-0125-train.json")
+    plan = config["train"]
+    model = model_adapter(config)
+    cfg = model.with_remat(model.program_config(config), plan["remat"])
+    assert cfg.remat is True and cfg.n_layers == 2
+    mesh = create_mesh(MeshConfig(**plan["mesh"]),
+                       devices=list(one_chip.device_set))
+    whole = NamedSharding(mesh, PartitionSpec())
+
+    def on_mesh(tree, shardings):
+        return jax.tree.map(
+            lambda x, s: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                              sharding=s or whole),
+            tree, shardings, is_leaf=lambda x: x is None)
+
+    params = on_mesh(
+        jax.eval_shape(lambda: moe.init_moe_params(cfg,
+                                                   jax.random.PRNGKey(0))),
+        tree_shardings(mesh, moe.moe_param_logical_axes(cfg),
+                       moe.DEFAULT_RULES))
+    tx = make_optimizer(plan["learning_rate"], warmup_steps=0,
+                        moment_dtype=jnp.bfloat16)
+    moments = jax.eval_shape(tx.init, params)
+    moments = on_mesh(moments, optax.tree_map_params(
+        tx, lambda _, p: p.sharding, moments, params,
+        transform_non_params=lambda _: None))
+    state = TrainState(jax.ShapeDtypeStruct((), jnp.int32, sharding=whole),
+                       params, moments)
+    batch = {name: jax.ShapeDtypeStruct(
+        (plan["sequences_per_chip"], 4096), jnp.int32,
+        sharding=named_sharding(mesh, "batch", "seq"))
+        for name in ("tokens", "targets")}
+    step = make_train_step(
+        lambda p, b: model.loss(p, b, cfg, mesh=mesh), tx, mesh=mesh,
+        batch_logical={name: ("batch", "seq") for name in batch})
+    compiled = step.lower(state, batch).compile()
+    text = compiled.as_text()
+    kernels = re.findall(r"%([\w-]+?)(?:\.\d+)? = \S+ custom-call\(", text)
+    assert sum(k in PRODUCTS for k in kernels) == 9
+    assert sorted(re.findall(
+        r"%\w*?(flash_(?:fwd|bwd_dq|bwd_dkv))_*\.\d+ = .* custom-call\(",
+        text)) == ["flash_bwd_dkv", "flash_bwd_dq", "flash_fwd"]
+    memory = compiled.memory_analysis()
+    assert memory.argument_size_in_bytes > 6.2e9  # parameters and moments
+    assert memory.peak_memory_in_bytes < 0.9 * 15.75 * 2 ** 30
+
+
 def _scheduled(text):
     """A compiled program's text without the bodies of its fusions:
     the ops it schedules, each of which leaves an array in memory (a

@@ -18,6 +18,7 @@ from ray_tpu.models import (init_train_state, llama, make_optimizer,
                             make_train_step, moe)
 from ray_tpu.models import smallthinker as st
 from ray_tpu.parallel import MeshConfig, create_mesh
+from tests.models.test_moe import grouped_products
 
 # Largest error over largest |value|: float32 on both sides leaves 2e-7
 # to 6e-7 at these widths; the mildest fault below moves the logits by
@@ -114,6 +115,25 @@ def test_remat_changes_no_number():
         p, batch, dataclasses.replace(cfg, remat=True))[0])(params)
     for a, b in zip(jax.tree.leaves(plain), jax.tree.leaves(remat)):
         np.testing.assert_allclose(a, b, rtol=1e-5, atol=1e-7)
+
+
+@pytest.mark.parametrize("held", [None, (0, 4)])
+def test_a_rematerialised_layer_keeps_nothing(held):
+    """This family's rematerialised layer saves its input alone (its
+    cell has no memory for more, `loss_fn`): with `remat` on, each of
+    the two runs' layer bodies computes its three forward products again
+    before the six backward ones, 12 a run where a layer that keeps
+    everything has 9. The products carry `moe._SAVED`'s names, which a
+    list here would honour: this count moves if one ever reaches it."""
+    cfg = config(held)
+    params, batch = seeded(cfg)
+
+    def products(cfg):
+        return grouped_products(lambda p: st.loss_fn(p, batch, cfg)[0],
+                                params)
+
+    assert products(cfg) == 2 * 9
+    assert products(dataclasses.replace(cfg, remat=True)) == 2 * 12
 
 
 # -- the faults ---------------------------------------------------------------
