@@ -59,13 +59,32 @@ forward only (a trained share's products are `lax.ragged_dot`'s), and
 its group metadata is built once a layer for the layer's two or three
 products.
 
-On a mesh tokens stay on the chip that holds them: dispatch, the
-grouped matmuls and the combine run under `shard_map` over the batch
-and sequence axes, each shard on its own tokens with every expert's
-weights gathered at the edge (the bytes FSDP moves anyway). A mesh with
-an `expert` axis gives the right result the same way, every chip
-computing all experts for its tokens; making that fast needs an
-all-to-all of tokens and is not here.
+On a mesh the layer moves tokens, not expert matrices. The matrices
+are split over `fsdp` along their hidden width F alone (`_EXPERT_AXES`),
+so a chip owns F/n of every expert, and dispatch, the grouped products
+and the combine run under `shard_map`: every chip of the n takes all
+their tokens (an all-gather), sorts all the pairs once, runs the same
+three grouped products over n times the rows at an n-th of the width
+(the same FLOPs a chip, and the chips stay level however the router
+collapses: every chip computes every expert), and a reduce-scatter adds
+the chips' partial outputs in float32 and hands each its own tokens
+(`_sparse_experts`' `over`). The backward pass is the transpose, and a
+weight's gradient is born whole on the chip that holds the slice. A
+layer, forward and backward, moves about 14 bytes a token and unit of
+D over the chips (tokens, output and the two cotangents, the sums in
+float32: 0.94 GB for Mixtral's 16,384 tokens of 4,096), where gathering
+the matrices twice and scattering their gradients moved 18 bytes a
+weight, E x D x F x 18 B (8.5 GB). Moving tokens wins while the `fsdp`
+group's tokens a step stay under about 1.3 x E x F: 147 k tokens at
+Mixtral's widths, 84 k at OLMoE's, and no configuration here is past
+it. One that is should make the layer choose by those two byte counts,
+which it can read off its operands' shapes; there is one path until
+then, and no knob. Over an `expert` or `tensor` axis the matrices are
+gathered whole inside and every chip computes all experts for its own
+tokens: the right result, and no cell has such an axis above 1. The
+exchange by expert (an all-to-all of tokens to the chips that hold
+whole experts) is not here: its busiest chip waits on the busiest
+experts' load, three times the mean where the router has collapsed.
 
 No reference equivalent (the reference has no model code); this exists
 so sparse experts are a first-class, exercised layer (SURVEY.md §2
@@ -76,6 +95,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import math
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -221,13 +241,19 @@ def init_moe_params(cfg: MoEConfig, rng) -> Dict[str, Any]:
 
 
 # Logical axes of the expert matrices without the layer axis: how they
-# are sharded where they enter the expert layer's shard_map. A layer
-# has `we3` only if its experts are gated, and hands the grouped
-# products the matrices it has, in this order.
+# are sharded where they enter the expert layer's shard_map. Split
+# along the hidden width alone (`expert_mlp`: over `fsdp` and `tensor`,
+# `parallel/sharding.py`) and whole along the model width, which the
+# other matrices split over `fsdp`: a chip then owns its slice of every
+# expert, contracts over all of D at home, and the layer moves tokens
+# to the matrices (`_sparse_experts`' `over`), 0.94 GB a Mixtral layer
+# and step where gathering the matrices moved 8.5. A layer has `we3`
+# only if its experts are gated, and hands the grouped products the
+# matrices it has, in this order.
 _EXPERT_AXES = {
-    "we1": ("expert", "embed", "mlp"),
-    "we3": ("expert", "embed", "mlp"),
-    "we2": ("expert", "mlp", "embed"),
+    "we1": ("expert", None, "expert_mlp"),
+    "we3": ("expert", None, "expert_mlp"),
+    "we2": ("expert", "expert_mlp", None),
 }
 
 
@@ -261,10 +287,14 @@ def _spread(x, order, inv):
     return _rows(x, order // (order.size // x.shape[0]))
 
 
-@jax.custom_vjp
-def _collect(y, gates, order, inv):
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _collect(y, gates, order, inv, keep=None):
     """y [T*k, D] in expert order, gates [T, k] float32 -> [T, D]: every
-    token's k rows, weighted by its gates, summed in float32."""
+    token's k rows, weighted by its gates, summed in float32. `keep`
+    says that y is a chip's partial sums in float32 (`_partial_product`)
+    and so is the result, to be added to the other chips'; what the
+    backward pass reads of y, for the gates' gradient, is then y
+    rounded to `keep`, under the name `expert_out`."""
     t, k = gates.shape
     return jnp.einsum("tkd,tk->td", _rows(y, inv).reshape(t, k, -1), gates,
                       preferred_element_type=jnp.float32).astype(y.dtype)
@@ -279,21 +309,93 @@ def _spread_bwd(res, g):
     return summed.astype(g.dtype), None, None
 
 
-def _collect_bwd(res, g):
+def _collect_fwd(y, gates, order, inv, keep):
+    kept = y if keep is None else checkpoint_name(y.astype(keep),
+                                                  "expert_out")
+    return _collect(y, gates, order, inv, keep), (kept, gates, order, inv)
+
+
+def _collect_bwd(keep, res, g):
     y, gates, order, inv = res
-    g_rows = _rows(g, order // gates.shape[1])  # each pair's token's
+    # Each pair's token's. (A partial sum's cotangent crossed the chips
+    # in `keep`, `_own_rows_summed`: its rows are gathered as narrow.)
+    g_rows = _rows(g.astype(y.dtype), order // gates.shape[1])
     d_gates = _rows(jnp.einsum("pd,pd->p", g_rows, y,
                                preferred_element_type=jnp.float32), inv)
-    d_y = g_rows * _rows(gates.reshape(-1), order)[:, None]
-    return (d_y.astype(y.dtype), d_gates.reshape(gates.shape), None, None)
+    d_y = (g_rows * _rows(gates.reshape(-1), order)[:, None]).astype(y.dtype)
+    if keep is not None:
+        # Rounded where it is made, as the plain product's cotangent is;
+        # float32 is the type a partial sum's must have, and the
+        # compiler drops the way there and back (`_partial_product`).
+        d_y = d_y.astype(jnp.float32)
+    return d_y, d_gates.reshape(gates.shape), None, None
 
 
 _spread.defvjp(lambda x, order, inv: (_spread(x, order, inv),
                                       (order, inv, x.shape[0])),
                _spread_bwd)
-_collect.defvjp(lambda y, gates, order, inv: (_collect(y, gates, order, inv),
-                                              (y, gates, order, inv)),
-                _collect_bwd)
+_collect.defvjp(_collect_fwd, _collect_bwd)
+
+
+# -- a chip's slice of every expert, and everyone's tokens --------------------
+#
+# Inside `shard_map`, over mesh axes whose n chips each hold a slice of
+# the experts' hidden width and tokens of their own. A chip's last
+# product is a partial sum over its slice; what crosses the chips is
+# rows of tokens, and every sum of partial sums is made in float32.
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1,))
+def _all_rows(x, axes):
+    """x [T, ...], a chip's rows -> [n*T, ...], every chip's. Backward,
+    each chip has a cotangent for all of them: their sum, in float32."""
+    return lax.all_gather(x, axes, axis=0, tiled=True)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1, 2))
+def _own_rows_summed(y, axes, dtype):
+    """y [n*T, D] float32, a chip's partial sums for every chip's rows
+    -> [T, D] in `dtype`: the chips' sum, added in float32, of this
+    chip's rows. Backward the cotangent crosses the chips in `dtype`."""
+    return lax.psum_scatter(y, axes, scatter_dimension=0,
+                            tiled=True).astype(dtype)
+
+
+# Each is the other's transpose.
+_all_rows.defvjp(
+    lambda x, axes: (_all_rows(x, axes), None),
+    lambda axes, _, g: (_own_rows_summed(g.astype(jnp.float32), axes,
+                                         g.dtype),))
+_own_rows_summed.defvjp(
+    lambda y, axes, dtype: (_own_rows_summed(y, axes, dtype), None),
+    lambda axes, dtype, _, g: (_all_rows(g, axes).astype(jnp.float32),))
+
+
+@jax.custom_vjp
+def _partial_product(rows, w, group_sizes):
+    """`lax.ragged_dot` over a slice of the contracted width: the result
+    stays in float32, one term of a sum across chips that is rounded
+    once, after it. The backward products are the plain product's own,
+    on the cotangent rounded to the rows' dtype as the plain product's
+    arrives."""
+    return lax.ragged_dot(rows, w, group_sizes,
+                          preferred_element_type=jnp.float32)
+
+
+def _partial_product_bwd(res, g):
+    rows, w, group_sizes = res
+    g = g.astype(rows.dtype)
+    d_rows, = jax.linear_transpose(
+        lambda r: lax.ragged_dot(r, w, group_sizes), rows)(g)
+    d_w, = jax.linear_transpose(
+        lambda m: lax.ragged_dot(rows, m, group_sizes), w)(g)
+    return d_rows, d_w, None
+
+
+_partial_product.defvjp(
+    lambda rows, w, group_sizes: (_partial_product(rows, w, group_sizes),
+                                  (rows, w, group_sizes)),
+    _partial_product_bwd)
 
 
 def _expert_counts(top_i, n_experts):
@@ -322,7 +424,7 @@ def _expert_matrices(lp):
     return [lp[name] for name in _EXPERT_AXES if name in lp]
 
 
-def _grouped_experts(xs, product, we1, *rest, gate=jax.nn.silu):
+def _grouped_experts(xs, product, we1, *rest, gate=jax.nn.silu, last=None):
     """Rows in expert order through their experts: [R, D] -> [R, D].
     `rest` is (we3, we2) of gated experts, whose first product goes
     through `gate` (`_gate_of`), (we2,) of relu^2 ones;
@@ -331,7 +433,9 @@ def _grouped_experts(xs, product, we1, *rest, gate=jax.nn.silu):
     `_SAVED` for a rematerialised layer's policy to keep; under no
     `jax.checkpoint`, or one whose list lacks them, a name is an
     identity. What lies between them (`gate(h) * up`, `_relu2`) has no
-    name: from saved products it is one elementwise pass."""
+    name: from saved products it is one elementwise pass. `last`, where
+    given, is the last product in `product`'s place, a chip's partial
+    sums in float32: it gets its name where it is rounded (`_collect`)."""
     *gated, we2 = rest
     with jax.named_scope("expert_matmul"):
         hidden = checkpoint_name(product(xs, we1), "expert_gate")  # [R, F]
@@ -340,12 +444,32 @@ def _grouped_experts(xs, product, we1, *rest, gate=jax.nn.silu):
                 product(xs, gated[0]), "expert_up")
         else:
             hidden = _relu2(hidden)
+        if last is not None:
+            return last(hidden, we2)
         return checkpoint_name(product(hidden, we2), "expert_out")  # [R, D]
 
 
-def _sparse_experts(x, gates, top_i, we1, *rest, gate=jax.nn.silu):
+def _sparse_experts(x, gates, top_i, we1, *rest, gate=jax.nn.silu, over=()):
     """The chosen experts of the tokens at hand. x [T, D], gates and
-    top_i [T, k], weights [E, ...] -> [T, D]."""
+    top_i [T, k], weights [E, ...] -> [T, D].
+
+    `over`, inside `shard_map`: the mesh axes whose n chips each hold a
+    slice of every expert's hidden width, [E, D, F/n] and [E, F/n, D],
+    and T tokens of their own. Every chip then takes all n*T tokens
+    (`token_exchange`, an all-gather), sorts all their pairs once and
+    runs the same three grouped products over n times the rows at a
+    n-th of the width: the first two are whole (they contract over D),
+    the last is a partial sum over the chip's slice, kept in float32
+    through the weighted sum, and a reduce-scatter adds the chips' and
+    hands each its own tokens. The backward pass is the transpose: the
+    output's cotangent gathered, the tokens' and the gates' summed in
+    float32, and a weight's gradient complete on the chip that holds
+    the slice. No expert matrix crosses the chips."""
+    dtype = x.dtype
+    if over:
+        with jax.named_scope("token_exchange"):
+            x, gates = _all_rows(x, over), _all_rows(gates, over)
+            top_i = lax.all_gather(top_i, over, axis=0, tiled=True)
     with jax.named_scope("moe_dispatch"):
         order = jnp.argsort(top_i.reshape(-1), stable=True)
         inv = jnp.argsort(order)
@@ -353,9 +477,14 @@ def _sparse_experts(x, gates, top_i, we1, *rest, gate=jax.nn.silu):
         xs = _spread(x, order, inv)                        # [T*k, D]
     ys = _grouped_experts(
         xs, functools.partial(lax.ragged_dot, group_sizes=group_sizes),
-        we1, *rest, gate=gate)
+        we1, *rest, gate=gate, last=functools.partial(
+            _partial_product, group_sizes=group_sizes) if over else None)
     with jax.named_scope("moe_combine"):
-        return _collect(ys, gates, order, inv)
+        out = _collect(ys, gates, order, inv, dtype if over else None)
+    if over:
+        with jax.named_scope("token_exchange"):
+            out = _own_rows_summed(out, over, dtype)
+    return out
 
 
 # A share's grouped products run over buffers of this many times the
@@ -675,11 +804,12 @@ def _moe_ffn(cfg: MoEConfig, lp, x, mesh, rules, stacks=None, *,
             x = jnp.einsum("bsd,dl->bsl", x, lp["w_dn"])
     d = x.shape[-1]
 
-    def experts(x, gates, top_i, *ws):
+    def experts(x, gates, top_i, *ws, over=()):
         """On the tokens at hand: the whole batch, or one shard's."""
         t = x.shape[0] * x.shape[1]
         out = _sparse_experts(x.reshape(t, d), gates.reshape(t, k),
-                              top_i.reshape(t, k), *ws, gate=_gate_of(cfg))
+                              top_i.reshape(t, k), *ws, gate=_gate_of(cfg),
+                              over=over)
         return out.reshape(x.shape)
 
     run, layer = stacks or (lp, None)
@@ -704,17 +834,22 @@ def _moe_ffn(cfg: MoEConfig, lp, x, mesh, rules, stacks=None, *,
     elif mesh is None:
         out = experts(x, gates, top_i, *weights)
     else:
-        # Each shard of the batch (and of the sequence) dispatches its
-        # own tokens; the expert matrices come in as they are sharded
-        # and are gathered whole inside, so that their gradients leave
-        # through the matching reduce-scatter.
+        # The expert matrices come in as they are sharded. Over the
+        # axes that split the tokens too (`_token_exchange`) they stay
+        # as they came, a slice of every expert's hidden width, and the
+        # shards' tokens go to them; over any other (`expert`,
+        # `tensor`) they are gathered whole inside, their gradients
+        # leaving through the matching reduce-scatter, and each shard
+        # dispatches its own tokens.
+        exchange = _token_exchange(mesh, rules)
         w_specs = [logical_to_mesh_axes(axes, rules)
                    for name, axes in _EXPERT_AXES.items() if name in lp]
         tok = logical_to_mesh_axes(("batch", "seq", None), rules)
         out = jax.shard_map(
             lambda x, gates, top_i, *ws: experts(
                 x, gates, top_i,
-                *[_gather_whole(w, spec) for w, spec in zip(ws, w_specs)]),
+                *[_gather_but(w, spec, mesh, exchange)
+                  for w, spec in zip(ws, w_specs)], over=exchange),
             mesh=mesh, in_specs=(tok, tok, tok, *w_specs), out_specs=tok,
             check_vma=False)(x, gates, top_i, *weights)
     if cfg.latent_dim:
@@ -748,11 +883,51 @@ def _add_shared_expert(cfg: MoEConfig, lp, x, out):
         return out + shared
 
 
-def _gather_whole(w, spec):
-    """Inside shard_map: the whole of an array that came in split as
-    `spec` says."""
-    for dim, axes in enumerate(spec):
-        if axes is not None:
+def _mesh_axes(entry):
+    """An entry of a PartitionSpec as a tuple of mesh axes."""
+    if entry is None:
+        return ()
+    return (entry,) if isinstance(entry, str) else tuple(entry)
+
+
+def _token_axes(rules):
+    """The mesh axes that split the tokens, batch or sequence."""
+    return [axis
+            for entry in logical_to_mesh_axes(("batch", "seq"), rules)
+            for axis in _mesh_axes(entry)]
+
+
+def _token_exchange(mesh, rules):
+    """The mesh axes over which the expert layer moves tokens and not
+    expert matrices: those of more than one chip that split both the
+    experts' hidden width and the tokens. Empty without a mesh or on
+    one chip: the layer then issues no collective."""
+    if mesh is None:
+        return ()
+    tokens = _token_axes(rules)
+    return tuple(axis for axis in _mesh_axes(dict(rules).get("expert_mlp"))
+                 if axis in tokens and mesh.shape[axis] > 1)
+
+
+def _rows_received(mesh, rules, tokens):
+    """The token rows a chip's expert layer takes from other chips, a
+    layer, of a batch of `tokens` in all: n - 1 times its own, n the
+    chips of `_token_exchange`."""
+    if mesh is None:
+        return 0
+    holders = math.prod(mesh.shape[axis] for axis in _token_axes(rules))
+    chips = math.prod(mesh.shape[axis]
+                      for axis in _token_exchange(mesh, rules))
+    return (chips - 1) * (tokens // holders)
+
+
+def _gather_but(w, spec, mesh, kept):
+    """Inside shard_map: an array that came in split as `spec` says,
+    whole but for the mesh axes `kept`, over which it stays split."""
+    for dim, entry in enumerate(spec):
+        axes = tuple(axis for axis in _mesh_axes(entry)
+                     if axis not in kept and mesh.shape[axis] > 1)
+        if axes:
             w = lax.all_gather(w, axes, axis=dim, tiled=True)
     return w
 
@@ -790,14 +965,18 @@ def served_ffn(cfg: MoEConfig):
 # bfloat16 (R pairs, F an expert's width, T tokens, D the hidden size):
 #
 #                           OLMoE-1B-7B, T 16,384,   Mixtral-8x7B, T 4,096,
-#                           R 131,072, F 1,024,      R 8,192, F 14,336,
-#                           D 2,048                  D 4,096
+#                           R 131,072, F 1,024,      4 chips: R 32,768,
+#                           D 2,048                  F 3,584, D 4,096
 #   expert_gate, expert_up  268 MB each (R x F)      235 MB each
-#   expert_out              537 MB (R x D)           67 MB
+#   expert_out              537 MB (R x D)           268 MB
 #   flash_out + flash_lse   67 + 1 MB (T x D)        34 + 0.5 MB
 #   flash_q, _k, _v         67 MB each               34, 8 and 8 MB
 #
-# 1.34 and 0.62 GB a layer: it fits the benchmark's two cells because
+# (Mixtral's four chips each run the four's pairs at a quarter of the
+# width, `_sparse_experts`: `expert_out` is a chip's partial sums for
+# all of them, kept rounded to bfloat16, four times what its own
+# tokens' were.)
+# 1.34 and 0.82 GB a layer: it fits the benchmark's two cells because
 # they are cut to 2 layers. At a published depth the hidden products
 # stop fitting a v5e long before the others do; a list chosen by bytes
 # is PERF.md section 7's open question, and no knob chooses one now.
@@ -843,7 +1022,9 @@ def moe_loss_fn(params, batch, cfg: MoEConfig, *, mesh=None,
     loss. The metrics carry the step's routing: `expert_tokens` [L, E],
     the pairs sent to each expert of each layer, and under `span_attrs`
     (what `make_train_step` puts on its dispatch span) the busiest
-    expert's count and the mean."""
+    expert's count, the mean, and `expert_rows_received`: the token
+    rows a chip's expert layer took from other chips, a layer
+    (`_token_exchange`; from the shapes, 0 on one chip)."""
     ce, _, extras = decoder.loss(params, batch, cfg,
                                  **_parts(cfg, mesh, rules))
     aux = extras["aux"].mean()
@@ -854,4 +1035,6 @@ def moe_loss_fn(params, batch, cfg: MoEConfig, *, mesh=None,
                   "span_attrs": {
                       "expert_tokens_max": expert_tokens.max(),
                       "expert_tokens_mean": expert_tokens.sum()
-                      // expert_tokens.size}}
+                      // expert_tokens.size,
+                      "expert_rows_received": jnp.int32(_rows_received(
+                          mesh, rules, batch["tokens"].size))}}

@@ -35,6 +35,12 @@ DEFAULT_RULES: LogicalRules = (
     ("head_dim", None),
     ("vocab", "tensor"),
     ("expert", "expert"),
+    ("expert_mlp", ("fsdp", "tensor")),  # an expert matrix's hidden
+                             # width, the one axis of it that is split:
+                             # over `fsdp` a chip owns its slice of every
+                             # expert and is sent the tokens
+                             # (`models/moe.py`), so no expert matrix is
+                             # gathered and no gradient of one scattered
     ("stage", "pipe"),
     ("norm", None),
 )

@@ -1,5 +1,8 @@
 """MoE model tests: routing, expert-parallel equivalence, training."""
 
+import math
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -214,7 +217,10 @@ def test_step_dispatch_span_carries_the_last_finished_steps_routing():
         assert span["attrs"] == {
             "expert_tokens_max": int(
                 before["span_attrs"]["expert_tokens_max"]),
-            "expert_tokens_mean": 2 * 4 * 16 // 4}
+            "expert_tokens_mean": 2 * 4 * 16 // 4,
+            # A chip holds 16 of the 64 tokens and takes in the 16 of
+            # the other chip of its `fsdp` pair.
+            "expert_rows_received": 16}
 
 
 # -- a held share reads its layer's experts in the run's stack ----------------
@@ -472,14 +478,117 @@ def test_a_trained_expert_layer_still_takes_its_layers_slice():
     assert read and read <= shapes | {(e, n, k) for e, k, n in shapes}, read
 
 
+_MESHES = {"fsdp": dict(data=2, fsdp=2, tensor=2),
+           "expert": dict(data=2, expert=2, tensor=2),
+           "fsdp4": dict(data=2, fsdp=4),
+           "one_device_mesh": dict(data=1)}
+
+
 def _on(place):
-    """(mesh, parameters at debug widths) on one device or on the
-    file's fsdp mesh."""
+    """(mesh, parameters at debug widths) on one device, on one of the
+    file's meshes (`fsdp`, `expert`), on four chips that share the
+    experts' hidden width, or on a mesh of one device."""
     cfg = MoEConfig.debug_moe()
     if place == "one_device":
         return None, init_moe_params(cfg, jax.random.PRNGKey(0))
-    mesh = create_mesh(MeshConfig(data=2, fsdp=2, tensor=2))
+    axes = _MESHES[place]
+    mesh = create_mesh(MeshConfig(**axes),
+                       devices=jax.devices()[:math.prod(axes.values())])
     return mesh, init_moe_params_sharded(cfg, mesh, jax.random.PRNGKey(0))
+
+
+def _collapsed(params):
+    """`params` with a router collapsed as the four-chip cell's is: the
+    first feature of every embedding is large, so it leads the stream
+    every layer reads, and every router scores it (3, 1, 1, -3): expert
+    0 is every token's first choice, the second is between 1 and 2, and
+    expert 3 gets no pair."""
+    router = params["layers"]["router"]
+    score = jnp.array([3.0, 1.0, 1.0, -3.0], router.dtype)
+    return {**params,
+            "embed": params["embed"].at[:, 0].set(1.0),
+            "layers": {**params["layers"],
+                       "router": router.at[:, 0].set(score)}}
+
+
+@pytest.mark.parametrize("place", ["fsdp", "expert", "fsdp4"])
+def test_a_mesh_changes_no_number(place):
+    """Chips that each hold a slice of every expert's hidden width and
+    are sent one another's tokens (`fsdp`, 2 and 4), and chips that
+    gather the matrices whole (`expert`, `tensor`), give the single
+    device's loss and every gradient leaf, also where one expert gets
+    no pair and one gets half of them."""
+    cfg = MoEConfig.debug_moe()
+    batch = _batch(cfg, b=8)
+
+    def run(place):
+        mesh, params = _on(place)
+        params = jax.jit(_collapsed, donate_argnums=0)(params)
+        return jax.jit(jax.value_and_grad(lambda p: moe_loss_fn(
+            p, batch, cfg, mesh=mesh), has_aux=True))(params)
+
+    (want, metrics), want_grads = run("one_device")
+    counts = np.asarray(metrics["expert_tokens"])
+    assert (counts[:, 0] == 8 * 16).all() and (counts[:, 3] == 0).all()
+    assert (counts[:, 1:3] > 0).all()
+    (got, on_mesh), got_grads = run(place)
+    np.testing.assert_array_equal(on_mesh["expert_tokens"], counts)
+    np.testing.assert_allclose(float(got), float(want), rtol=1e-5)
+    leaves = jax.tree_util.tree_leaves_with_path(want_grads)
+    assert len(leaves) == 3 + 10  # ten leaves a layer
+    for (path, a), b in zip(leaves, jax.tree.leaves(got_grads)):
+        assert float(jnp.abs(a).max()) > 0, path
+        np.testing.assert_allclose(np.asarray(b), np.asarray(a), rtol=2e-4,
+                                   atol=2e-4 * float(jnp.abs(a).max()),
+                                   err_msg=jax.tree_util.keystr(path))
+
+
+@pytest.mark.parametrize("place,received", [
+    ("one_device", 0), ("one_device_mesh", 0), ("expert", 0),
+    ("fsdp", 32), ("fsdp4", 3 * 16)])
+def test_the_step_says_what_rows_its_expert_layer_took_in(place, received):
+    """`span_attrs["expert_rows_received"]`: n - 1 times a chip's own
+    tokens where n chips share the experts' hidden width (128 tokens on
+    2 x 2 and on 2 x 4 holders of tokens), 0 where none do. Traced, not
+    run: the count is a constant of the program
+    (`test_step_dispatch_span_carries_the_last_finished_steps_routing`
+    reads it off a step's span)."""
+    cfg = MoEConfig.debug_moe()
+    mesh, params = _on(place)
+    program = jax.make_jaxpr(lambda p, b: moe_loss_fn(
+        p, b, cfg, mesh=mesh)[1]["span_attrs"]["expert_rows_received"])(
+        params, _batch(cfg, b=8))
+    assert program.jaxpr.outvars[0].val == received
+
+
+def test_on_a_mesh_of_one_device_the_step_holds_no_collective():
+    """The train step as it is lowered for a mesh of one device (the
+    one-chip training cells build one): the expert layer gathers
+    nothing and exchanges nothing. What the lowered text still holds
+    are the sums `shard_map`'s own transpose makes over the mesh's
+    axes, each over one group of one device, which the TPU's compiler
+    drops (`test_moe_compile_tpu.py` reads OLMoE's compiled step: no
+    collective of any kind); on the file's `fsdp` mesh the same reading
+    finds the expert layer's."""
+    cfg = MoEConfig.debug_moe()
+    tx = make_optimizer(5e-3, warmup_steps=0)
+    ops = (r"(all_gather|all_reduce|reduce_scatter|all_to_all|"
+           r"collective_permute|collective_broadcast)")
+
+    def lowered(place):
+        mesh, params = _on(place)
+        step = make_train_step(
+            lambda p, b: moe_loss_fn(p, b, cfg, mesh=mesh), tx, mesh=mesh,
+            batch_logical={"tokens": ("batch", "seq"),
+                           "targets": ("batch", "seq")})
+        return step.lower(init_train_state(params, tx), _batch(cfg, b=4))
+
+    one = lowered("one_device_mesh")
+    found = re.findall(ops + r"[^\n]*?replica_groups = (dense<[^>]*> : "
+                       r"tensor<\w+>)", one.as_text())
+    assert set(found) <= {("all_reduce", "dense<0> : tensor<1x1xi64>")}
+    assert set(re.findall(ops, lowered("fsdp").as_text())) >= {
+        "all_gather", "reduce_scatter"}
 
 
 @pytest.mark.parametrize("place", ["one_device", "fsdp"])

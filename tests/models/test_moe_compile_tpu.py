@@ -7,6 +7,7 @@ is read. The topology is described inside a fixture, never at import
 (one process at a time may load the TPU's library)."""
 
 import json
+import math
 import pathlib
 import re
 
@@ -25,14 +26,18 @@ PRODUCTS = json.loads((pathlib.Path(__file__).parents[2] / "benchmark"
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topology():
     from jax.experimental import topologies
     try:
-        topo = topologies.get_topology_desc(platform="tpu",
+        return topologies.get_topology_desc(platform="tpu",
                                             topology_name="v5e:2x2")
     except Exception as e:
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
-    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def one_chip(topology):
+    return SingleDeviceSharding(topology.devices[0])
 
 
 @pytest.mark.parametrize("tokens,k,d,f,e", [
@@ -61,17 +66,13 @@ def test_expert_layer_compiles_for_the_v5e(one_chip, tokens, k, d, f, e):
     assert not re.search(r"\[\d+,\d+\]\S* scatter\(", text)
 
 
-def test_olmoes_train_step_compiles_for_the_v5e(one_chip, monkeypatch):
-    """The whole train step of `olmoe-1b-7b-0125-train.json` (2 layers,
-    4 x 4096 tokens, bf16 moments, its file's `remat`) as the train
-    runner builds it, on a mesh of the one described chip. A
-    rematerialised layer keeps `moe._SAVED`: the program holds the
-    layer's 3 grouped products forward and 6 backward, where keeping
-    nothing made the backward scan's body compute the 3 again, and one
-    forward attention kernel, in the forward scan alone. What that
-    keeps is memory the cell has: the compiler's peak stays under nine
-    tenths of the 15.75 GiB a v5e offers (the flash kernels would not
-    be this process's CPU choice: the test says it is on a TPU)."""
+def _train_step(name, devices, monkeypatch):
+    """The whole train step of `benchmark/configs/<name>.json` as the
+    train runner builds it (its mesh with `fsdp` over `devices`, its
+    file's `remat`, bf16 moments, one batch of its sequences of 4096),
+    compiled from shapes for the described chips: (cfg, compiled). The
+    flash kernels would not be this process's CPU choice: the test says
+    it is on a TPU."""
     import optax
     from jax.sharding import NamedSharding, PartitionSpec
 
@@ -84,14 +85,13 @@ def test_olmoes_train_step_compiles_for_the_v5e(one_chip, monkeypatch):
 
     monkeypatch.setattr(attention, "on_tpu", lambda: True)
     monkeypatch.setattr(llama, "on_tpu", lambda: True)
-    config = load_json(ROOT, "benchmark", "configs",
-                       "olmoe-1b-7b-0125-train.json")
+    config = load_json(ROOT, "benchmark", "configs", name + ".json")
     plan = config["train"]
     model = model_adapter(config)
     cfg = model.with_remat(model.program_config(config), plan["remat"])
     assert cfg.remat is True and cfg.n_layers == 2
-    mesh = create_mesh(MeshConfig(**plan["mesh"]),
-                       devices=list(one_chip.device_set))
+    mesh = create_mesh(MeshConfig(**{**plan["mesh"], "fsdp": len(devices)}),
+                       devices=devices)
     whole = NamedSharding(mesh, PartitionSpec())
 
     def on_mesh(tree, shardings):
@@ -113,22 +113,71 @@ def test_olmoes_train_step_compiles_for_the_v5e(one_chip, monkeypatch):
         transform_non_params=lambda _: None))
     state = TrainState(jax.ShapeDtypeStruct((), jnp.int32, sharding=whole),
                        params, moments)
-    batch = {name: jax.ShapeDtypeStruct(
-        (plan["sequences_per_chip"], 4096), jnp.int32,
+    batch = {key: jax.ShapeDtypeStruct(
+        (plan["sequences_per_chip"] * len(devices), 4096), jnp.int32,
         sharding=named_sharding(mesh, "batch", "seq"))
-        for name in ("tokens", "targets")}
+        for key in ("tokens", "targets")}
     step = make_train_step(
         lambda p, b: model.loss(p, b, cfg, mesh=mesh), tx, mesh=mesh,
-        batch_logical={name: ("batch", "seq") for name in batch})
-    compiled = step.lower(state, batch).compile()
+        batch_logical={key: ("batch", "seq") for key in batch})
+    return cfg, step.lower(state, batch).compile()
+
+
+def test_olmoes_train_step_compiles_for_the_v5e(one_chip, monkeypatch):
+    """The whole train step of `olmoe-1b-7b-0125-train.json` (2 layers,
+    4 x 4096 tokens, bf16 moments, its file's `remat`) as the train
+    runner builds it, on a mesh of the one described chip. A
+    rematerialised layer keeps `moe._SAVED`: the program holds the
+    layer's 3 grouped products forward and 6 backward, where keeping
+    nothing made the backward scan's body compute the 3 again, and one
+    forward attention kernel, in the forward scan alone. What that
+    keeps is memory the cell has: the compiler's peak stays under nine
+    tenths of the 15.75 GiB a v5e offers. On its one chip the expert
+    layer moves nothing: the program holds no collective."""
+    _, compiled = _train_step("olmoe-1b-7b-0125-train",
+                              list(one_chip.device_set), monkeypatch)
     text = compiled.as_text()
     kernels = re.findall(r"%([\w-]+?)(?:\.\d+)? = \S+ custom-call\(", text)
     assert sum(k in PRODUCTS for k in kernels) == 9
     assert sorted(re.findall(
         r"%\w*?(flash_(?:fwd|bwd_dq|bwd_dkv))_*\.\d+ = .* custom-call\(",
         text)) == ["flash_bwd_dkv", "flash_bwd_dq", "flash_fwd"]
+    assert not re.findall(r" (?:all-gather|all-reduce|reduce-scatter|"
+                          r"all-to-all|collective-permute)\S*\(", text)
     memory = compiled.memory_analysis()
     assert memory.argument_size_in_bytes > 6.2e9  # parameters and moments
+    assert memory.peak_memory_in_bytes < 0.9 * 15.75 * 2 ** 30
+
+
+def test_mixtrals_train_step_on_four_chips_moves_no_expert_matrix(
+        topology, monkeypatch):
+    """The whole train step of `mixtral-8x7b-v0.1-train.json` (2 layers,
+    one sequence of 4096 a chip, `fsdp=4`) compiled for all four
+    devices of the described v5e:2x2. A chip owns its quarter of every
+    expert's hidden width and is sent the tokens: the scan's body holds
+    the layer's 3 grouped products forward and 6 backward, each one
+    call over the four chips' 32,768 rows, and no collective's operand
+    or result is the size of an expert matrix, whole (what the step
+    gathered three times a layer and pass, 2.8 GB, and scattered the
+    gradients of) or a chip's quarter."""
+    cfg, compiled = _train_step("mixtral-8x7b-v0.1-train",
+                                list(topology.devices), monkeypatch)
+    text = compiled.as_text()
+    kernels = re.findall(r"%([\w-]+?)(?:\.\d+)? = \S+ custom-call\(", text)
+    assert sum(k in PRODUCTS for k in kernels) == 9
+    matrix = cfg.n_experts * cfg.dim * cfg.hidden_dim
+    moved = [math.prod(map(int, dims.split(",")))
+             for line in text.splitlines()
+             if re.search(r" (?:all-gather|reduce-scatter|all-reduce|"
+                          r"all-to-all)(?:-start)?\(", line)
+             for dims in re.findall(r"\w+\[([\d,]+)\]", line)]
+    assert moved and not {matrix, matrix // 4} & set(moved)
+    # The tokens are what crosses: every chip's rows in, and the sum of
+    # the chips' partial outputs back in float32.
+    rows = 4 * 4096
+    assert re.search(rf"= bf16\[1,{rows},{cfg.dim}\]\S* all-gather\(", text)
+    assert re.search(rf"= f32\[4096,{cfg.dim}\]\S* reduce-scatter\(", text)
+    memory = compiled.memory_analysis()
     assert memory.peak_memory_in_bytes < 0.9 * 15.75 * 2 ** 30
 
 

@@ -7,6 +7,7 @@ shares of a layer adding up to the whole; a crowded share's further
 buffers; and what the step hands the tracing."""
 
 import dataclasses
+import math
 
 import jax
 import jax.numpy as jnp
@@ -90,6 +91,31 @@ def test_program_matches_the_reference(held):
     assert attrs["expert_tokens_max"] == share.max()
     assert attrs["expert_tokens_mean"] == share.sum() // share.size
     assert attrs["pair_overflows"] == 0
+
+
+@pytest.mark.parametrize("axes", [dict(data=1),
+                                  dict(data=2, fsdp=2, tensor=2)],
+                         ids=["one_device_mesh", "fsdp"])
+def test_all_the_experts_on_a_mesh_count_as_on_one_device(axes):
+    """With no held share (`experts_held=None`) the layer goes through
+    `moe._moe_ffn`'s `shard_map`, on a mesh of one device as the runner
+    builds it and on chips that share the experts' hidden width: the
+    loss and what the step hands the tracing are the one device's, the
+    overflow count an int32 0 a layer."""
+    cfg = config(None)
+    params, batch = seeded(cfg)
+    batch = {name: jnp.concatenate([rows, rows[::-1]])
+             for name, rows in batch.items()}
+    want, metrics = st.loss_fn(params, batch, cfg)
+    mesh = create_mesh(MeshConfig(**axes),
+                       devices=jax.devices()[:math.prod(axes.values())])
+    got, on_mesh = jax.jit(
+        lambda p, b: st.loss_fn(p, b, cfg, mesh=mesh))(params, batch)
+    assert float(got) == pytest.approx(float(want), rel=1e-6)
+    attrs = {k: int(v) for k, v in on_mesh["span_attrs"].items()}
+    assert attrs == {k: int(v) for k, v in metrics["span_attrs"].items()}
+    assert attrs["pair_overflows"] == 0
+    assert attrs["pairs_held"] == attrs["pairs_routed"] == 4 * 4 * 40 * 3
 
 
 @pytest.mark.parametrize("held", [None, (2, 4)])
