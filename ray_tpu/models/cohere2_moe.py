@@ -40,7 +40,8 @@ keys the ring will not keep. On a TPU a prefill from position 0 of
 1,024 rows or a multiple, whose rows see its own keys and no others,
 takes `ops.attention.flash_attention_forward` instead, on both kinds of
 layer (the trained path's forward kernel, with a window on a `sliding`
-layer: no score leaves the core); `_own_keys` chooses on the device.
+layer: no score leaves the core); `serving.own_keys` chooses on the
+device.
 Only then is the ring written, with the call's last `sliding_window`
 real rows: a decode step's one row in place, a prefill's rows up to
 `at` and none of its bucket's padding (row p + `sliding_window` lands
@@ -82,7 +83,7 @@ from jax import lax
 from ray_tpu.models import decoder, moe
 from ray_tpu.models.serving import (
     KEY_BLOCK as _KEY_BLOCK, Family, attention_init, by_query_blocks, normal,
-    rotate_pairs)
+    own_keys, rotate_pairs)
 from ray_tpu.ops import attention
 
 PUBLISHED_LAYER_TYPES = ("sliding", "sliding", "sliding", "full") * 8
@@ -270,20 +271,9 @@ def _write_ring(stack, layer, new, start_pos, at):
 _ROTATED = ("sliding",)
 
 # A call of so many rows or a multiple of it (every prefill bucket from
-# here up) goes through the flash kernel where it can: `_own_keys`.
+# here up) goes through the flash kernel where it can:
+# `serving.own_keys`.
 _FLASH_ROWS = 1024
-
-
-def _own_keys(t, start_pos, flash, by_blocks):
-    """A call's attention: `flash()` where the call's own keys are all
-    its rows can see (every row starts at position 0: a prompt's one
-    prefill, the engine's case) and the kernel has its tiles, else
-    `by_blocks()`, which also reads what the cache held. `start_pos` is
-    the device's to know, so a program that may use the kernel holds
-    both."""
-    if t % _FLASH_ROWS or not attention.on_tpu():
-        return by_blocks()
-    return lax.cond(start_pos.max() == 0, flash, by_blocks)
 
 
 def _mixer(cfg: Cohere2MoeConfig, kind, start_pos, positions, at):
@@ -322,8 +312,8 @@ def _mixer(cfg: Cohere2MoeConfig, kind, start_pos, positions, at):
                     (fetch, *_blocks_seen(pos, origin, tr, rows // tr))],
                     cfg.n_kv_heads),
 
-            out = _own_keys(
-                t, start_pos,
+            out = own_keys(
+                not t % _FLASH_ROWS, start_pos,
                 lambda: attention.flash_attention_forward(q, k, v),
                 lambda: by_query_blocks(attend, t, q, positions)[0])
             return out, (k_stack, v_stack), handed
@@ -352,8 +342,8 @@ def _mixer(cfg: Cohere2MoeConfig, kind, start_pos, positions, at):
                                         window))],
                     cfg.n_kv_heads, window),
 
-            out = _own_keys(
-                t, start_pos,
+            out = own_keys(
+                not t % _FLASH_ROWS, start_pos,
                 lambda: attention.flash_attention_forward(
                     q, k, v, window=window),
                 lambda: by_query_blocks(attend, t, q, positions)[0])

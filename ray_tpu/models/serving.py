@@ -77,6 +77,32 @@ parameters {"embed", "runs": [a dict of stacked leaves a run],
   family's own, beside what its FFNs count), ``keys_attended`` and
   ``keys_read``.
 
+A model that generates by blocks (``ServedModel.block_length`` B, None
+of the others; diffusion over blocks, `sdar_moe`) keeps all of the
+above and differs in what a step is:
+
+- ``forward`` sees `tokens` [slots, T] with T and `start_pos` multiples
+  of B. A row's query at position i sees the cache's rows up to the
+  end of i's block, this call's rows written first: causal between
+  blocks, both ways inside one. There is no shift: the logits at
+  position i score the token at i. With `at` None it returns the
+  logits of every position, [slots, T, vocab]; a prefill's logits are
+  never read;
+- its config names `mask_token_id`, what an open position is fed as,
+  and `denoising_steps`, in how many steps a block is fixed unless a
+  request says (`SamplingParams.denoising_steps`);
+- a step of the engine feeds every slot its block (T = B) at the
+  slot's length: a slot with a position open *denoises* (the most
+  confident open positions take their tokens), a slot with none
+  *commits* (the pass's rows, computed from the final tokens, stay;
+  the length grows by B; the next block opens). It hands the host a
+  row of B tokens a slot, the step of the block at which each was
+  fixed, and whether the block is whole now: 0 or B tokens a step,
+  not one. The cache holds rows only, so the prefix cache works as
+  before: a block's keys depend on nothing after the block, as long
+  as its blocks of rows are whole blocks of the model
+  (`llm_kv_block_tokens` a multiple of B).
+
 Below the stack stands what the families' layers share and no one of
 them owns.
 """
@@ -94,6 +120,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from ray_tpu.models import decoder
+from ray_tpu.ops import attention
 from ray_tpu.ops.attention import decode_block_rows
 
 
@@ -119,6 +146,9 @@ class ServedModel:
     keys_attended: Callable = _every_key
     state_leaves: Callable = _all_rows
     keys_read: Optional[Callable] = None
+    # Positions in a block, of a model that generates by blocks; None
+    # of one that yields a token a step.
+    block_length: Optional[int] = None
 
 
 @dataclasses.dataclass(frozen=True, eq=False)
@@ -135,6 +165,7 @@ class Family:
     counts: Optional[Callable] = None
     keys_attended: Callable = _every_key
     keys_read: Optional[Callable] = None
+    block_length: Optional[Callable] = None
 
     def __post_init__(self):
         # One function object a name: what a family's module binds is
@@ -206,11 +237,13 @@ class Family:
     def forward(self, params, tokens, cfg, cache, start_pos, at):
         """The module's `forward`, prefill (T = the prompt's bucket)
         and decode (T = 1) alike: the logits of position `at` without
-        the [T, vocab] product of the rest, and as counts the FFNs'
-        and the family's own."""
+        the [T, vocab] product of the rest (of every position, [B, T,
+        vocab], where `at` is None: a block step's), and as counts the
+        FFNs' and the family's own."""
         x, cache, counts = self._stack(params, tokens, cfg, cache, start_pos,
                                        at)
-        x = lax.dynamic_index_in_dim(x, at, 1, keepdims=False)
+        if at is not None:
+            x = lax.dynamic_index_in_dim(x, at, 1, keepdims=False)
         logits = self._logits(params, x, cfg)
         if self.counts is not None:
             counts = {**counts, **self.counts(tokens, start_pos, at)}
@@ -229,10 +262,11 @@ class Family:
         return self._logits(params, x if keep is None else x[:, :keep],
                             cfg), cache
 
-    def served(self) -> ServedModel:
+    def served(self, cfg) -> ServedModel:
         return ServedModel(
             self.forward, self.init_cache, self.keys_attended,
-            self.state_leaves if self.state else _all_rows, self.keys_read)
+            self.state_leaves if self.state else _all_rows, self.keys_read,
+            self.block_length and self.block_length(cfg))
 
 
 # ---------------------------------------------------------------------------
@@ -279,6 +313,19 @@ def rotate_pairs(x, cos, sin):
     return out.reshape(x.shape).astype(x.dtype)
 
 
+def own_keys(tiled, start_pos, flash, plain):
+    """A prefill's attention: `flash()` where the call's own keys are
+    all its rows can see (every row starts at position 0: a prompt's one
+    prefill, the engine's case) and the flash kernel has its tiles
+    (`tiled`, the family's to say of the call's rows; on a TPU alone),
+    else `plain()`, which also reads what the cache held. `start_pos` is
+    the device's to know, so a program that may use the kernel holds
+    both."""
+    if not tiled or not attention.on_tpu():
+        return plain()
+    return lax.cond(start_pos.max() == 0, flash, plain)
+
+
 def by_query_blocks(fn, t, *arrays):
     """`fn` over blocks of the query axis (axis 1 of every array), its
     results (a tuple of arrays) joined along it again."""
@@ -301,7 +348,7 @@ def by_query_blocks(fn, t, *arrays):
 # ---------------------------------------------------------------------------
 
 
-def _llama():
+def _llama(cfg):
     from ray_tpu.models import llama
 
     def forward(params, tokens, cfg, cache, start_pos, at):
@@ -316,8 +363,8 @@ def _llama():
 def _family(module):
     """The `FAMILY` that `ray_tpu.models.<module>` declares, imported
     when a config of its type is first served."""
-    return lambda: importlib.import_module(
-        f"ray_tpu.models.{module}").FAMILY.served()
+    return lambda cfg: importlib.import_module(
+        f"ray_tpu.models.{module}").FAMILY.served(cfg)
 
 
 # By the config's own type, not its bases: `MoEConfig` is a
@@ -325,7 +372,8 @@ def _family(module):
 _SERVED = {"LlamaConfig": _llama, "GlmDsaConfig": _family("glm_dsa"),
            "NemotronHConfig": _family("nemotron_h"),
            "Cohere2MoeConfig": _family("cohere2_moe"),
-           "OlmoHybridConfig": _family("olmo_hybrid")}
+           "OlmoHybridConfig": _family("olmo_hybrid"),
+           "SdarMoeConfig": _family("sdar_moe")}
 
 
 def served_model(cfg) -> ServedModel:
@@ -335,4 +383,4 @@ def served_model(cfg) -> ServedModel:
             f"no served path for {type(cfg).__name__}: ray_tpu/models/"
             f"serving.py names a cached forward pass and a cache "
             f"initialiser for {sorted(_SERVED)}")
-    return find()
+    return find(cfg)

@@ -63,9 +63,14 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
                       m_ref, l_ref, acc_ref, *,
                       sm_scale: float, causal: bool,
                       block_q: int, block_k: int, sk: int,
-                      window: Optional[int] = None):
+                      window: Optional[int] = None,
+                      block: Optional[int] = None):
     """`window` (with `causal`): a row sees that many keys, itself the
-    last of them. `lse_ref` is None where no backward pass will ask for
+    last of them. `block` (with `causal`, a power of two that divides
+    `block_q`): a row sees the keys up to the end of its own block of
+    that many positions, both ways inside one and causal between them;
+    a tile's edge is a block's edge, so the tiles skipped are
+    causality's. `lse_ref` is None where no backward pass will ask for
     the logsumexp (`flash_attention_forward`)."""
     iq = pl.program_id(2)
     ik = pl.program_id(3)
@@ -116,6 +121,9 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
                 jnp.int32, (block_q, block_k), 0)
             cols = ik * block_k + jax.lax.broadcasted_iota(
                 jnp.int32, (block_q, block_k), 1)
+            if block is not None:
+                # The last position of the row's block.
+                rows = rows | (block - 1)
             if causal and pad_cols:
                 mask = (rows >= cols) & (cols < sk)
             elif causal:
@@ -175,7 +183,8 @@ def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
 
 def _flash_fwd(q, k, v, causal: bool, sm_scale: float,
                block_q: int, block_k: int, interpret: bool,
-               window: Optional[int] = None, with_lse: bool = True):
+               window: Optional[int] = None, with_lse: bool = True,
+               block: Optional[int] = None):
     """q: [B, H, S, D]; k/v: [B, Hkv, Sk, D] (already transposed).
 
     Returns ``(o, lse)`` where ``lse`` is the per-row logsumexp with shape
@@ -187,6 +196,9 @@ def _flash_fwd(q, k, v, causal: bool, sm_scale: float,
     block_q = min(block_q, sq)
     block_k = min(block_k, sk)
     grid = (b, h, pl.cdiv(sq, block_q), pl.cdiv(sk, block_k))
+    assert block is None or (causal and window is None and block > 0
+                             and not block & (block - 1)
+                             and not block_q % block), (block, block_q)
 
     def kv_index(ib, ih, iq, ik):
         if causal:
@@ -201,7 +213,7 @@ def _flash_fwd(q, k, v, causal: bool, sm_scale: float,
 
     kernel = functools.partial(
         _flash_fwd_kernel, sm_scale=sm_scale, causal=causal,
-        block_q=block_q, block_k=block_k, sk=sk, window=window,
+        block_q=block_q, block_k=block_k, sk=sk, window=window, block=block,
     )
     if not with_lse:
         with_out = kernel
@@ -582,8 +594,10 @@ def _flash_bwd(q, k, v, o, lse, do, causal: bool, sm_scale: float,
 
 
 def attention_reference(q, k, v, causal: bool, sm_scale: float,
-                        window: Optional[int] = None):
-    """[B, H, S, D] layout. GQA-aware. `window` as the kernel's."""
+                        window: Optional[int] = None,
+                        block: Optional[int] = None):
+    """[B, H, S, D] layout. GQA-aware. `window` and `block` as the
+    forward kernel's."""
     b, h, sq, d = q.shape
     h_kv = k.shape[1]
     if h_kv != h:
@@ -594,7 +608,9 @@ def attention_reference(q, k, v, causal: bool, sm_scale: float,
                    preferred_element_type=jnp.float32) * sm_scale
     if causal:
         sk = k.shape[2]
-        back = jnp.arange(sq)[:, None] - jnp.arange(sk)[None, :]
+        rows = jnp.arange(sq) if block is None \
+            else jnp.arange(sq) // block * block + block - 1
+        back = rows[:, None] - jnp.arange(sk)[None, :]
         mask = back >= 0 if window is None else (back >= 0) & (back < window)
         s = jnp.where(mask[None, None], s, _NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
@@ -671,6 +687,7 @@ def flash_attention(q, k, v, *, causal: bool = True,
 
 
 def flash_attention_forward(q, k, v, *, window: Optional[int] = None,
+                            block: Optional[int] = None,
                             sm_scale: Optional[float] = None,
                             block_q: int = 1024, block_k: int = 1024,
                             interpret: bool = False):
@@ -678,7 +695,11 @@ def flash_attention_forward(q, k, v, *, window: Optional[int] = None,
     its own call's keys: `flash_attention`'s forward kernel, which here
     writes no logsumexp, and with `window` a row sees that many keys and
     no more, itself the last of them (the blocks of keys wholly behind a
-    block of queries' windows are neither read nor computed). Layout,
+    block of queries' windows are neither read nor computed); with
+    `block` (a power of two, and no `window`) attention is causal
+    between blocks of that many positions and goes both ways inside
+    one: row i sees key j iff j // block <= i // block, what a model
+    that generates by diffusion over blocks prefills with. Layout,
     GQA and the choice between kernel, interpreter and reference as
     `flash_attention`'s."""
     if sm_scale is None:
@@ -686,9 +707,10 @@ def flash_attention_forward(q, k, v, *, window: Optional[int] = None,
     qt, kt, vt = (x.transpose(0, 2, 1, 3) for x in (q, k, v))
     if on_tpu() or interpret:
         out, _ = _flash_fwd(qt, kt, vt, True, sm_scale, block_q, block_k,
-                            not on_tpu(), window=window, with_lse=False)
+                            not on_tpu(), window=window, with_lse=False,
+                            block=block)
     else:
-        out = attention_reference(qt, kt, vt, True, sm_scale, window)
+        out = attention_reference(qt, kt, vt, True, sm_scale, window, block)
     return out.transpose(0, 2, 1, 3)
 
 

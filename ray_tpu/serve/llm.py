@@ -29,6 +29,21 @@ shapes — so:
   as it has.
 - Sampling (greedy / temperature / top-k) runs on device; one token per
   slot per step streams back to waiting callers.
+- That is the one-token contract: a prefill yields a request's first
+  token, a decode step one more a slot, the host's lengths grow by one
+  a token. A model that generates by blocks (`ServedModel.block_length`
+  B; diffusion over blocks) is served by the same loop, cache,
+  admission and spans through `_BlockEngine`, whose step is another:
+  `forward` sees a slot's block of B positions, which see each other
+  and the slot's rows before them; a step's forward either *denoises* a
+  block (some of its open positions take their tokens) or *commits* it
+  (its rows stay, the length grows by B, the next block opens); a step
+  returns, a slot, a row of B tokens, the steps they were fixed at and
+  whether the block is whole now, so 0 or B tokens and not one; a
+  prefill covers the prompt's whole blocks and yields no token; the
+  host counts lengths by blocks, tokens where they are handed to a
+  request, and the forwards of each phase (`slot_forwards_denoise`,
+  `slot_forwards_commit`, `tokens_fixed`, `blocks_emitted`).
 
 The engine is thread-safe: callers enqueue requests and block on their
 completion; a background loop interleaves admission and decode — the
@@ -167,6 +182,10 @@ class SamplingParams:
     temperature: float = 0.0  # 0 → greedy
     top_k: int = 0            # 0 = full softmax; clamped to _TOP_K_MAX
     stop_token_ids: tuple = ()
+    # Of a model that generates by blocks: in how many denoising steps
+    # a block's positions are fixed (it divides the block's length);
+    # None: the model config's own.
+    denoising_steps: Optional[int] = None
 
 
 @dataclasses.dataclass
@@ -192,6 +211,12 @@ class _Request:
     # `LAG_SAMPLE_EVERY` into `out_queue`: its reader records
     # `stream.wake` from it.
     t_put: float = 0.0
+    # Of a model that generates by blocks: the prompt tokens that open
+    # the request's first block (known positions, not part of the
+    # answer), the blocks handed over so far and when the last was.
+    known: int = 0
+    blocks: int = 0
+    t_block: float = 0.0
 
 
 @dataclasses.dataclass
@@ -209,6 +234,14 @@ class _Readback:
 
 
 class LLMEngine:
+    def __new__(cls, cfg=None, *args, **kwargs):
+        # A model that generates by blocks has a step, an admission and
+        # a hand-over of its own (`_BlockEngine`); the loop is this one.
+        if cls is LLMEngine and cfg is not None \
+                and served_model(cfg).block_length:
+            cls = _BlockEngine
+        return super().__new__(cls)
+
     def __init__(self, cfg, params, *,
                  max_batch_size: int = 8, max_seq_len: Optional[int] = None,
                  decode_steps: int = 1, seed: int = 0,
@@ -226,6 +259,8 @@ class LLMEngine:
         self.decode_steps = max(1, int(decode_steps))
         self.max_seq = max_seq_len or cfg.max_seq_len
         self._served = served_model(cfg)
+        # Positions a slot is fed a step: a token, or a block.
+        self._step_len = self._served.block_length or 1
         self.cache = self._served.init_cache(cfg, self.n_slots,
                                              self.max_seq)
         # The cache's row leaves as shapes, [layers_i, slots, max_seq,
@@ -289,7 +324,7 @@ class LLMEngine:
         # evaluation of a decode step gives. The same evaluation says
         # in which dtype the model hands over its logits (the sample
         # program is compiled for it).
-        step = jnp.zeros((self.n_slots, 1), jnp.int32)
+        step = jnp.zeros((self.n_slots, self._step_len), jnp.int32)
         logits, _, counts = jax.eval_shape(
             lambda p, c: self._served.forward(p, step, cfg, c, step[:, 0],
                                               0),
@@ -322,22 +357,12 @@ class LLMEngine:
             jnp.zeros(self.n_slots, jnp.int32), s1)
         self._dev_lengths = jax.device_put(
             jnp.zeros(self.n_slots, jnp.int32), s1)
-        self._decode = jax.jit(
-            self._decode_impl, donate_argnums=(1,),
-            in_shardings=(None, s1, s1, s1, s1, s1, s1),
-            out_shardings=(s1, s1, s1, s1, s1, s1))
         self._prefill = jax.jit(
             self._prefill_impl, donate_argnums=(1,),
             static_argnums=(6,),  # t — positional: pjit rejects kwargs
             in_shardings=(None, s1, s1, s1, s1, s1),  # with in_shardings
             out_shardings=(s1, s1))
-        # First-token sampling for an admission wave — FIXED shape
-        # [n_slots, vocab] (padded) so it is ONE program compiled at
-        # warmup, not a variant per distinct admitted-count. It also
-        # writes the admitted slots into the decode carries.
-        self._sample_admitted = jax.jit(
-            self._sample_admitted_impl,
-            in_shardings=(s1,) * 7, out_shardings=(s1,) * 4)
+        self._make_step_programs(s1)
         # AOT-compiled executables, filled by warmup(): the bucket
         # ladder compiles CONCURRENTLY (XLA releases the GIL; compiles
         # parallelize across cores) and the serving path then calls the
@@ -394,6 +419,19 @@ class LLMEngine:
             self._write_block_impl, donate_argnums=(0,),
             in_shardings=(s1, s1, s1, s1), out_shardings=s1)
 
+    def _make_step_programs(self, s1):
+        self._decode = jax.jit(
+            self._decode_impl, donate_argnums=(1,),
+            in_shardings=(None, s1, s1, s1, s1, s1, s1),
+            out_shardings=(s1, s1, s1, s1, s1, s1))
+        # First-token sampling for an admission wave — FIXED shape
+        # [n_slots, vocab] (padded) so it is ONE program compiled at
+        # warmup, not a variant per distinct admitted-count. It also
+        # writes the admitted slots into the decode carries.
+        self._sample_admitted = jax.jit(
+            self._sample_admitted_impl,
+            in_shardings=(s1,) * 7, out_shardings=(s1,) * 4)
+
     def _seed_for(self, model: str) -> str:
         """Chain-key seed: model identity + the KV-shape fingerprint.
         Two chains share keys only when the cached bytes are
@@ -426,7 +464,8 @@ class LLMEngine:
             "warmup() must run before the engine loop starts"
         t0 = time.perf_counter()
         limit = min(max_prompt_len or self.max_seq, self.max_seq)
-        buckets = bucket_ladder(limit, self.max_seq)
+        buckets = [b for b in bucket_ladder(limit, self.max_seq)
+                   if b % self._step_len == 0]
         self._compile_ladder_concurrent(buckets)
         last = None
         for bucket in buckets:
@@ -442,8 +481,16 @@ class LLMEngine:
                           x.dtype) for x in self._leaves)
             self.cache = self._write_block_j(
                 self.cache, block, np.int32(0), np.int32(0))
-        # Admission-wave sampling program; every row is padding, so
-        # the carries would come back as they went.
+        np.asarray(self._warm_step_programs(last))  # wait for the device
+        # Warmup wrote garbage KV into slot 0; lengths stay 0 so every
+        # slot still reads as empty when serving starts.
+        return time.perf_counter() - t0
+
+    def _warm_step_programs(self, last):
+        """Run once the two programs of a step, on padding, and return
+        an array of the last: the wave's program (every row is padding,
+        so the carries would come back as they went) and the decode
+        block."""
         _firsts, self._rng, _last, _lens = self._run_sample(
             (last,) * self.n_slots, np.zeros(self.n_slots, np.float32),
             np.full(self.n_slots, self.n_slots, np.int32),
@@ -454,10 +501,7 @@ class LLMEngine:
             jnp.zeros(self.n_slots, jnp.int32),
             jnp.zeros(self.n_slots, jnp.float32),
             jnp.zeros(self.n_slots, jnp.int32))
-        np.asarray(toks)  # wait for the device before stopping the clock
-        # Warmup wrote garbage KV into slot 0; lengths stay 0 so every
-        # slot still reads as empty when serving starts.
-        return time.perf_counter() - t0
+        return toks
 
     def _compile_ladder_concurrent(self, buckets) -> None:
         """AOT-compile every serving program on a thread pool."""
@@ -474,7 +518,6 @@ class LLMEngine:
         cache_avals = jax.tree_util.tree_map(
             lambda x: aval(x.shape, x.dtype), self.cache)
         rng_aval = aval(self._rng.shape, self._rng.dtype)
-        n = self.n_slots
 
         def compile_prefill(bucket):
             lowered = self._prefill.lower(
@@ -484,17 +527,13 @@ class LLMEngine:
 
         def compile_decode():
             lowered = self._decode.lower(
-                params_avals, cache_avals, aval((n,)), aval((n,)),
-                aval((n,), _jnp.float32), aval((n,)), rng_aval)
+                params_avals, cache_avals, *self._decode_avals(aval),
+                rng_aval)
             return "decode", lowered.compile()
 
         def compile_sample():
-            # Prefill hands over its last-position logits as the model
-            # gives them.
             lowered = self._sample_admitted.lower(
-                (aval((self.cfg.vocab_size,), self._logits_dtype),) * n,
-                aval((n,), _jnp.float32), rng_aval, aval((n,)),
-                aval((n,)), aval((n,)), aval((n,)))
+                *self._sample_avals(aval, rng_aval))
             return "sample", lowered.compile()
 
         def compile_read_rows(rows):
@@ -520,6 +559,20 @@ class LLMEngine:
                     self._read_rows_exec[key[1]] = compiled
                 else:
                     self._prefill_exec[key] = compiled
+
+    def _decode_avals(self, aval):
+        """The decode program's arguments between the cache and the
+        rng, as shapes."""
+        n = self.n_slots
+        return aval((n,)), aval((n,)), aval((n,), jnp.float32), aval((n,))
+
+    def _sample_avals(self, aval, rng_aval):
+        # Prefill hands over its last-position logits as the model
+        # gives them.
+        n = self.n_slots
+        return ((aval((self.cfg.vocab_size,), self._logits_dtype),) * n,
+                aval((n,), jnp.float32), rng_aval, aval((n,)), aval((n,)),
+                aval((n,)), aval((n,)))
 
     # -- compiled-or-jit call shims --------------------------------------
     #
@@ -711,8 +764,14 @@ class LLMEngine:
                  model: Optional[str] = None,
                  priority: int = 1,
                  job: str = "default",
-                 beat_s: Optional[float] = None):
+                 beat_s: Optional[float] = None,
+                 with_steps: bool = False):
         """Blocking generate (or an iterator of tokens with stream=True).
+
+        With `with_steps` an item is (token, step): of a model that
+        generates by blocks, the denoising step of its block at which
+        the token was fixed; None of a model that yields a token a
+        step. A block's tokens come together.
 
         With `beat_s` the iterator yields None where the request has
         waited that long for its first token and the loop has run
@@ -722,9 +781,11 @@ class LLMEngine:
         cap = self.max_seq - 1
         if len(prompt) > cap:
             raise PromptTooLongError(len(prompt), cap)
+        params = params or SamplingParams()
         req = _Request(
             request_id=next(self._req_counter), prompt=prompt,
-            params=params or SamplingParams(), out_queue=queue.Queue(),
+            params=params, out_queue=queue.Queue(),
+            known=self._known_tokens(prompt, params),
             t_arrival=critical_path.clock(),
             model=model, priority=max(0, min(2, int(priority))), job=job,
             # Stamped on the CALLING thread (the replica's task context
@@ -754,11 +815,20 @@ class LLMEngine:
                         None, "stream.wake",
                         critical_path.clock() - req.t_put)
                 wait = None  # admitted: every step brings a token
-                yield item
+                # (A model that generates by blocks queues pairs.)
+                token, step = item if isinstance(item, tuple) \
+                    else (item, None)
+                yield (token, step) if with_steps else token
 
         if stream:
             return token_iter()
         return list(token_iter())
+
+    def _known_tokens(self, prompt, params) -> int:
+        """How many of the prompt's last tokens are not prefilled but
+        open the request's first block: none, of a model that yields a
+        token a step. Where the request's parameters are judged."""
+        return 0
 
     def metrics(self) -> Dict[str, Any]:
         with self._lock:
@@ -890,7 +960,9 @@ class LLMEngine:
             if not self._free_slots:
                 leftover.append(req)
                 continue
-            prompt = req.prompt
+            # What is prefilled: the prompt; its whole blocks, of a
+            # model that generates by blocks.
+            prompt = req.prompt[:len(req.prompt) - req.known]
             slot = self._free_slots.pop()
             # Stage: admit = time spent queued for a slot.
             t_admit = critical_path.clock()
@@ -908,14 +980,17 @@ class LLMEngine:
             tail = prompt[m_tok:]
             t_tail = len(tail)
             bucket = self._serve_bucket(t_tail)
-            with critical_path.span("engine.prefill_dispatch",
-                                    real=t_tail, bucket=bucket):
-                tokens = np.zeros((1, bucket), np.int32)
-                tokens[0, :t_tail] = tail
-                self.cache, last_logits = self._run_prefill(
-                    tokens, slot, t_tail, m_tok, bucket)
-            totals["prefill_tokens_real"] += t_tail
-            totals["prefill_tokens_bucketed"] += bucket
+            last_logits = None
+            # (A prompt shorter than a block has nothing to prefill.)
+            if t_tail or self._step_len == 1:
+                with critical_path.span("engine.prefill_dispatch",
+                                        real=t_tail, bucket=bucket):
+                    tokens = np.zeros((1, bucket), np.int32)
+                    tokens[0, :t_tail] = tail
+                    self.cache, last_logits = self._run_prefill(
+                        tokens, slot, t_tail, m_tok, bucket)
+                totals["prefill_tokens_real"] += t_tail
+                totals["prefill_tokens_bucketed"] += bucket
             with self._lock:
                 req.slot = slot
                 self._slot_req[slot] = req
@@ -935,12 +1010,25 @@ class LLMEngine:
         totals["admit_waves_behind_block"] += behind
         self._wave_dispatched = True  # the next block runs behind them
         totals["admissions"] += len(staged)
-        # ONE device-side sampling for the whole wave, padded to
-        # n_slots rows so the program has one fixed shape, compiled
-        # once at warmup. The same program puts each first token and
-        # prompt length into the decode carries, and the tokens' copy
-        # to the host is started here and waited for behind the next
-        # decode dispatch.
+        self._dispatch_first_tokens(staged)
+        # The prefix cache admits the prompts' blocks after the prefill
+        # is dispatched, and here only starts their read-back: one
+        # gather and an asynchronous copy a request, queued behind that
+        # prefill; `_decode_once` finishes it on the host while a decode
+        # block runs. Safe ordering: the slot cannot be admitted again
+        # before a LATER wave, so the KV bytes being gathered are this
+        # request's prefill output.
+        for req, slot, _logits, chain in staged:
+            self._prefix_admit(req, slot, chain)
+        return True
+
+    def _dispatch_first_tokens(self, staged):
+        """ONE device-side sampling for the whole wave, padded to
+        n_slots rows so the program has one fixed shape, compiled
+        once at warmup. The same program puts each first token and
+        prompt length into the decode carries, and the tokens' copy
+        to the host is started here and waited for behind the next
+        decode dispatch."""
         with critical_path.span("engine.sample_dispatch"):
             pad = self.n_slots - len(staged)
             rows = tuple(s[2] for s in staged) + (staged[0][2],) * pad
@@ -957,16 +1045,6 @@ class LLMEngine:
             firsts.copy_to_host_async()
             self._first_tokens = (
                 firsts, [(req, slot) for req, slot, _, _ in staged])
-        # The prefix cache admits the prompts' blocks after the prefill
-        # is dispatched, and here only starts their read-back: one
-        # gather and an asynchronous copy a request, queued behind that
-        # prefill; `_decode_once` finishes it on the host while a decode
-        # block runs. Safe ordering: the slot cannot be admitted again
-        # before a LATER wave, so the KV bytes being gathered are this
-        # request's prefill output.
-        for req, slot, _logits, chain in staged:
-            self._prefix_admit(req, slot, chain)
-        return True
 
     def _decode_once(self):
         # The fed token occupies absolute position `lengths` (prompt is
@@ -981,9 +1059,11 @@ class LLMEngine:
                      "keys_attended": int(self._served.keys_attended(
                          self.cfg, lengths).sum())}
             if self._served.keys_read is not None:
-                # The fed token's row is written before the step reads.
+                # The fed token's row (a block's rows, read once for
+                # all of them) is written before the step reads.
                 attrs["keys_read"] = int(np.minimum(
-                    self._served.keys_read(self.cfg, lengths + 1),
+                    self._served.keys_read(self.cfg,
+                                           lengths + self._step_len),
                     self.max_seq).sum())
             for name, n in attrs.items():
                 self._totals[name] += n
@@ -992,11 +1072,7 @@ class LLMEngine:
                                 keys_reserved=self.n_slots * self.max_seq,
                                 **attrs):
             prev = self._pending_block
-            (self.cache, next_tokens, self._dev_last, self._dev_lengths,
-             self._rng, counts) = self._run_decode(
-                self._dev_last, self._dev_lengths,
-                jnp.asarray(self._temps_arr),
-                jnp.asarray(self._topks_arr))
+            next_tokens, counts = self._dispatch_decode()
             self._pending_block = (next_tokens, [
                 self._slot_req.get(slot) for slot in range(self.n_slots)],
                 counts, self._wave_dispatched)
@@ -1015,6 +1091,15 @@ class LLMEngine:
         # Dispatched before the block just dispatched, so they reach
         # their clients before that block is waited for.
         self._deliver_first_tokens()
+
+    def _dispatch_decode(self):
+        """Dispatch a decode block from the device's carries: (what the
+        host will fetch of it, what the model counted)."""
+        (self.cache, next_tokens, self._dev_last, self._dev_lengths,
+         self._rng, counts) = self._run_decode(
+            self._dev_last, self._dev_lengths,
+            jnp.asarray(self._temps_arr), jnp.asarray(self._topks_arr))
+        return next_tokens, counts
 
     def _flush_pending(self):
         prev, self._pending_block = self._pending_block, None
@@ -1074,6 +1159,9 @@ class LLMEngine:
             sp.set(kept=kept, discarded=discarded, stale=stale,
                    slot_steps=next_host.size, behind_wave=int(behind_wave),
                    **counted)
+        self._record_hand_over(behind_wave)
+
+    def _record_hand_over(self, behind_wave):
         now = critical_path.clock()
         if self._handed_over is not None:
             total, name = ("blocks_behind_wave", "engine.block_gap.wave") \
@@ -1425,6 +1513,316 @@ class LLMEngine:
         }
 
 
+class _BlockEngine(LLMEngine):
+    """The engine of a model that generates by blocks of B positions
+    (`ServedModel.block_length`; `models/serving.py` has the model's
+    side of the contract). The loop, admission's order, the prefix
+    cache, the pipelined fetch and every span's name are `LLMEngine`'s;
+    what differs is what a step is.
+
+    A slot carries, on the device, its block ([B] tokens and [B] flags,
+    which positions are still open: never found by comparing ids with
+    the mask id, a prompt may hold any id), the step of the block it is
+    at, the step at which each position was fixed, and its length, the
+    committed rows, a multiple of B. One fixed-shape program
+    (`_decode_impl`, `decode_steps` forwards a dispatch) feeds every
+    slot its block at its length, the mask token at the open positions,
+    whatever the slot's phase. A slot that came in with a position open
+    *denoises*: at every open position the token x0 (greedy at
+    temperature 0, else drawn from softmax(logits / T)) and its
+    confidence (x0's probability, float32), and the `n` most confident
+    open positions, or those that are left, take their tokens (ties to
+    the lower position; n = B / `SamplingParams.denoising_steps`, the
+    config's unless the request says); if none is open now the block is
+    *emitted*. A slot that came in with none open *commits*: the pass's
+    rows, computed from the final tokens, stay in the cache, the length
+    grows by B and the next block opens, all mask. So a step hands the
+    host, a slot, a row of B tokens, the steps they were fixed at and a
+    count that is 0 or B; `top_k` is not applied.
+
+    Admission prefills the prompt's whole blocks, `(len // B) * B`
+    tokens, at the engine's buckets (a bucket's padding lies in later
+    blocks, which no real row sees), yields no first token, and seeds
+    the slot's block with the `len % B` tokens left over as known
+    positions (`_seed_blocks_impl`, the wave's one program in the
+    sample program's place). The first tokens a client sees are its
+    first block's, `denoising_steps` forwards after the prefill; a
+    request ends inside a block (`max_tokens`, a stop id) and the rest
+    of the block is dropped.
+
+    The host counts by blocks: `_lengths` grows by B at a commit;
+    `tokens_kept` are the tokens handed to requests and
+    `tokens_discarded` the other token places of the emitted rows (a
+    retired slot's, a first block's known positions, what follows a
+    request's end), so that kept over `slot_steps` stays the share of
+    what the device made that a request got."""
+
+    def _make_step_programs(self, s1):
+        b, n, cfg = self._step_len, self.n_slots, self.cfg
+        assert self.max_seq % b == 0 and not b % cfg.denoising_steps, \
+            (self.max_seq, b, cfg.denoising_steps)
+        # A cached block of rows has to be whole blocks of the model:
+        # a block's keys depend on nothing after the block.
+        assert int(ray_config.llm_kv_block_tokens) % b == 0, \
+            (ray_config.llm_kv_block_tokens, b)
+        self._dev_block = jax.device_put(jnp.zeros((n, b), jnp.int32), s1)
+        self._dev_open = jax.device_put(jnp.ones((n, b), bool), s1)
+        self._dev_fixed_at = jax.device_put(jnp.zeros((n, b), jnp.int32), s1)
+        self._dev_step = jax.device_put(jnp.zeros(n, jnp.int32), s1)
+        # Positions a denoising step fixes, a slot.
+        self._nfix_arr = np.full(n, b // cfg.denoising_steps, np.int32)
+        self._decode = jax.jit(
+            self._decode_impl, donate_argnums=(1,),
+            in_shardings=(None,) + (s1,) * 9, out_shardings=(s1,) * 9)
+        # The wave's program: the admitted slots' blocks and lengths
+        # into the carries, at one fixed shape.
+        self._sample_admitted = jax.jit(
+            self._seed_blocks_impl, in_shardings=(s1,) * 9,
+            out_shardings=(s1,) * 5)
+        self._totals.update(dict.fromkeys((
+            "slot_forwards_denoise", "slot_forwards_commit", "tokens_fixed",
+            "blocks_emitted"), 0))
+
+    def _decode_avals(self, aval):
+        n, b = self.n_slots, self._step_len
+        return (aval((n, b)), aval((n, b), jnp.bool_), aval((n, b)),
+                aval((n,)), aval((n,)), aval((n,), jnp.float32), aval((n,)))
+
+    def _sample_avals(self, aval, rng_aval):
+        n, b = self.n_slots, self._step_len
+        return (aval((n, b)), aval((n, b), jnp.bool_), aval((n, b)),
+                aval((n,)), aval((n,)), aval((n,)), aval((n, b)),
+                aval((n, b), jnp.bool_), aval((n,)))
+
+    def _carries(self):
+        return (self._dev_block, self._dev_open, self._dev_fixed_at,
+                self._dev_step, self._dev_lengths)
+
+    def _run_seed(self, slots, blocks, opens, lengths):
+        fn = self._sample_exec or self._sample_admitted
+        return fn(*self._carries(), slots, blocks, opens, lengths)
+
+    def _run_decode(self):
+        fn = self._decode_exec or self._decode
+        return fn(self.params, self.cache, *self._carries(),
+                  jnp.asarray(self._temps_arr), jnp.asarray(self._nfix_arr),
+                  self._rng)
+
+    def _warm_step_programs(self, last):
+        n, b = self.n_slots, self._step_len
+        self._temps_arr = np.zeros(n, np.float32)
+        self._run_seed(np.full(n, n, np.int32), np.zeros((n, b), np.int32),
+                       np.ones((n, b), bool), np.zeros(n, np.int32))
+        self.cache, out, *_carries, self._rng, _counts = self._run_decode()
+        return out
+
+    # -- compiled bodies -------------------------------------------------
+
+    def _prefill_impl(self, params, cache, tokens, slot, length, start, t):
+        """`LLMEngine._prefill_impl` of whole blocks: the slot's rows
+        are written and no logits are handed on, so the program
+        computes no head: a prefill yields no token."""
+        cache, _ = super()._prefill_impl(params, cache, tokens, slot, length,
+                                         start, t)
+        return cache, jnp.zeros((), jnp.float32)
+
+    def _seed_blocks_impl(self, block, open_, fixed_at, step, lengths,
+                          slots, new_block, new_open, new_lengths):
+        """A wave's rows, one an admitted request, into the carries at
+        the row's slot: the first block (the prompt's leftover tokens,
+        known, then open positions) and the prefilled length. Rows
+        beyond the admitted count are padding: their slot is `n_slots`,
+        which the update drops."""
+        return (block.at[slots].set(new_block, mode="drop"),
+                open_.at[slots].set(new_open, mode="drop"),
+                fixed_at.at[slots].set(-1, mode="drop"),
+                step.at[slots].set(0, mode="drop"),
+                lengths.at[slots].set(new_lengths, mode="drop"))
+
+    def _decode_impl(self, params, cache, block, open_, fixed_at, step,
+                     lengths, temps, n_fix, rng):
+        """`decode_steps` forwards of every slot's block a dispatch, by
+        an in-program `lax.scan`; a slot denoises or commits by whether
+        a position of its block is open (the class's docstring).
+        Returns, after the cache, int32 [slots, K, 2 B + 3]: a forward's
+        B tokens, the B steps they were fixed at, whether the block was
+        emitted by this forward, whether it denoised, and how many
+        positions it fixed; then the carries, the rng and what the
+        model counted over the K forwards."""
+        b, mask_id = self._step_len, self.cfg.mask_token_id
+        before = jnp.arange(b)[None, :] < jnp.arange(b)[:, None]  # [i, j]
+
+        def forward(carry, _):
+            cache, block, open_, fixed_at, step, lengths, rng = carry
+            # Clamp for retired slots that keep computing until their
+            # slot is re-admitted: their writes stay in the region.
+            lengths = jnp.minimum(lengths, self.max_seq - b)
+            logits, cache, counts = self._served.forward(
+                params, jnp.where(open_, mask_id, block), self.cfg, cache,
+                lengths, None)
+            counts = tuple(counts[name] for name in self._count_names)
+            # [slots, B, vocab], at the slot's temperature (as they are
+            # at temperature 0).
+            logits = logits.astype(jnp.float32) / jnp.where(
+                temps > 0, temps, 1.0)[:, None, None]
+            rng, sub = jax.random.split(rng)
+            greedy = logits.argmax(-1)
+            x0 = jax.lax.cond(
+                (temps > 0).any(),
+                lambda: jnp.where(temps[:, None] > 0,
+                                  jax.random.categorical(sub, logits),
+                                  greedy),
+                lambda: greedy).astype(jnp.int32)
+            confidence = jnp.exp(
+                jnp.take_along_axis(logits, x0[..., None], -1)[..., 0]
+                - jax.nn.logsumexp(logits, -1))
+            # How many positions stand ahead of position i: the open
+            # ones of higher confidence, or of the same and before it.
+            score = jnp.where(open_, confidence, -1.0)
+            ahead = ((score[:, None, :] > score[:, :, None])
+                     | ((score[:, None, :] == score[:, :, None]) & before)
+                     ).sum(-1)
+            fix = open_ & (ahead < n_fix[:, None])
+            denoise = open_.any(-1)
+            block = jnp.where(fix, x0, block)
+            open_ = open_ & ~fix
+            fixed_at = jnp.where(fix, step[:, None], fixed_at)
+            emitted = denoise & ~open_.any(-1)
+            out = jnp.concatenate(
+                [block, fixed_at, emitted[:, None], denoise[:, None],
+                 fix.sum(-1, keepdims=True)], -1, dtype=jnp.int32)
+            commit = ~denoise
+            return (cache, block, open_ | commit[:, None], fixed_at,
+                    jnp.where(commit, 0, step + 1),
+                    lengths + jnp.where(commit, b, 0), rng), (out, counts)
+
+        (cache, block, open_, fixed_at, step, lengths, rng), (out, counts) \
+            = jax.lax.scan(
+                forward, (cache, block, open_, fixed_at, step, lengths, rng),
+                None, length=self.decode_steps)
+        if counts:
+            counts = jnp.stack(counts, -1).sum(0, dtype=jnp.int32)
+        return (cache, out.transpose(1, 0, 2), block, open_, fixed_at, step,
+                lengths, rng, counts)
+
+    # -- the loop's seams ------------------------------------------------
+
+    def _known_tokens(self, prompt, params) -> int:
+        steps = params.denoising_steps
+        if steps is not None and (steps < 1 or self._step_len % steps):
+            raise ValueError(
+                f"denoising_steps {steps} does not divide the block's "
+                f"length {self._step_len}")
+        return len(prompt) % self._step_len
+
+    def _dispatch_first_tokens(self, staged):
+        """The wave's one program: each admitted slot's first block and
+        prefilled length into the decode carries, queued behind the
+        prefills. No token comes of it."""
+        with critical_path.span("engine.sample_dispatch"):
+            n, b = self.n_slots, self._step_len
+            slots = np.full(n, n, np.int32)
+            blocks = np.zeros((n, b), np.int32)
+            opens = np.ones((n, b), bool)
+            lengths = np.zeros(n, np.int32)
+            for i, (req, slot, _logits, _chain) in enumerate(staged):
+                slots[i] = slot
+                known = req.known
+                blocks[i, :known] = req.prompt[len(req.prompt) - known:]
+                opens[i, :known] = False
+                lengths[i] = len(req.prompt) - known
+                self._nfix_arr[slot] = b // (req.params.denoising_steps
+                                             or self.cfg.denoising_steps)
+            (self._dev_block, self._dev_open, self._dev_fixed_at,
+             self._dev_step, self._dev_lengths) = self._run_seed(
+                slots, blocks, opens, lengths)
+
+    def _dispatch_decode(self):
+        (self.cache, out, self._dev_block, self._dev_open,
+         self._dev_fixed_at, self._dev_step, self._dev_lengths, self._rng,
+         counts) = self._run_decode()
+        return out, counts
+
+    def _consume_block(self, next_host, owners, counts=(),
+                       behind_wave=False):
+        """`LLMEngine._consume_block` of rows of 0 or B tokens: every
+        forward of an owner's slot is counted by its phase, an emitted
+        block's tokens go to the request together, each with the step
+        it was fixed at, and a commit grows the slot's length. The
+        first block's known positions are the prompt's, and a request
+        that ends inside a block leaves the rest. A request's first
+        block closes its `llm.prefill` stage; from one block of a
+        request to its next, one in four is recorded as
+        `engine.emitted_block_gap`, the gap its client is dealt."""
+        b = self._step_len
+        tokens, fixed_at = next_host[..., :b], next_host[..., b:2 * b]
+        emitted, denoised, n_fixed = (next_host[..., 2 * b + i]
+                                      for i in range(3))
+        kept = stale = denoise = commit = fixed = blocks = 0
+        counted = dict(zip(self._count_names, map(int, np.asarray(counts))))
+        with critical_path.span("engine.consume_block") as sp, self._lock:
+            for slot in np.nonzero(self._active)[0]:
+                req = self._slot_req[slot]
+                if owners[slot] is not req:
+                    stale += b * int(emitted[slot].sum())
+                    continue
+                for k in range(next_host.shape[1]):
+                    if denoised[slot, k]:
+                        denoise += 1
+                        fixed += int(n_fixed[slot, k])
+                    else:
+                        commit += 1
+                        self._lengths[slot] += b
+                    if not emitted[slot, k]:
+                        continue
+                    blocks += 1
+                    now = critical_path.clock()
+                    if req.t_first_token is None:
+                        critical_path.record_stage(
+                            req.trace_id, "llm.prefill",
+                            now - req.t_kv_done)
+                        req.t_first_token = now
+                    elif not req.blocks % 4:
+                        critical_path.record_stage(
+                            None, "engine.emitted_block_gap", now - req.t_block)
+                    req.blocks += 1
+                    req.t_block = now
+                    ended = False
+                    for at in range(req.known, b):
+                        tok = int(tokens[slot, k, at])
+                        req.tokens.append(tok)
+                        if not len(req.tokens) % LAG_SAMPLE_EVERY:
+                            req.t_put = critical_path.clock()
+                        req.out_queue.put((tok, int(fixed_at[slot, k, at])))
+                        kept += 1
+                        if self._finished(req, tok):
+                            ended = True
+                            break
+                    req.known = 0
+                    # The next block needs its B rows behind this one's.
+                    if ended or self._lengths[slot] + 2 * b > self.max_seq:
+                        self._retire(slot)
+                        break
+            slot_steps = b * int(emitted.sum())
+            totals = self._totals
+            totals["tokens_kept"] += kept
+            totals["tokens_discarded"] += slot_steps - kept
+            totals["slot_steps_stale"] += stale
+            totals["slot_forwards_denoise"] += denoise
+            totals["slot_forwards_commit"] += commit
+            totals["tokens_fixed"] += fixed
+            totals["blocks_emitted"] += blocks
+            for name, n in counted.items():
+                totals[name] += n
+            sp.set(kept=kept, discarded=slot_steps - kept, stale=stale,
+                   slot_steps=slot_steps, behind_wave=int(behind_wave),
+                   slot_forwards_denoise=denoise,
+                   slot_forwards_commit=commit,
+                   slot_forwards=denoise + commit, tokens_fixed=fixed,
+                   blocks_emitted=blocks, **counted)
+        self._record_hand_over(behind_wave)
+
+
 # -- Serve integration ------------------------------------------------------
 
 
@@ -1448,9 +1846,13 @@ class LLMDeployment:
 
     `cfg` is the config of any architecture `models.serving` names a
     cached forward pass for (`LlamaConfig`, `GlmDsaConfig`,
-    `NemotronHConfig`): nothing else of the deployment depends on
+    `NemotronHConfig`, ...): nothing else of the deployment depends on
     which, but that a model with a state leaf in its cache has no
-    prefix cache, so the router gets no digests to route by.
+    prefix cache, so the router gets no digests to route by, and that
+    a model that generates by blocks (`SdarMoeConfig`) hands a stream
+    its tokens a block at a time, each event with the key `step` (the
+    denoising step of its block at which the token was fixed), and
+    takes `denoising_steps` from a request.
     Each replica owns one engine (one KV cache in its chip's HBM) and
     may multiplex N weight variants (``models={name: params_fn}``): the
     compiled programs take params as arguments, so switching models is
@@ -1578,7 +1980,8 @@ class LLMDeployment:
         params = SamplingParams(
             max_tokens=int(request.get("max_tokens", 64)),
             temperature=float(request.get("temperature", 0.0)),
-            stop_token_ids=tuple(request.get("stop_token_ids", ())))
+            stop_token_ids=tuple(request.get("stop_token_ids", ())),
+            denoising_steps=request.get("denoising_steps"))
         model = str(request.get("model") or self.default_model)
         priority = _parse_priority(request.get("priority", 1))
         job = str(request.get("job") or request.get("job_id") or "default")
@@ -1591,7 +1994,7 @@ class LLMDeployment:
             self._ensure_model(model, job)  # raylint: disable=R2 -- the blocking drain IS the design: the swap lock must span drain+swap+enqueue or a concurrent request could swap weights between our model check and our admission; the engine drains independently of this lock, so the wait always terminates
             it = self.engine.generate(
                 request["prompt_ids"], params, stream=True,
-                model=model, priority=priority, job=job,
+                model=model, priority=priority, job=job, with_steps=True,
                 beat_s=WAITING_BEAT_S if request.get("stream") else None)
         if request.get("stream"):
             # Generator return → the replica streams it chunk-by-chunk
@@ -1601,20 +2004,28 @@ class LLMDeployment:
             # its 60 s without a chunk (`serve/streaming.py`).
             def token_stream():
                 i = 0
-                for token in it:
-                    if token is None:
+                for item in it:
+                    if item is None:
                         yield {STREAM_WAITING_KEY: True}
                         continue
-                    yield {"token": int(token), "index": i}
+                    token, step = item
+                    # A model that generates by blocks: its tokens come
+                    # a block at a time, each with the denoising step
+                    # of its block at which it was fixed.
+                    yield {"token": int(token), "index": i,
+                           **({} if step is None else {"step": step})}
                     i += 1
             return token_stream()
-        tokens = []
+        tokens, steps = [], []
         ttft_s = None
-        for token in it:
+        for token, step in it:
             if ttft_s is None:
                 ttft_s = time.perf_counter() - t0
             tokens.append(int(token))
+            steps.append(step)
         return {"tokens": tokens,
+                **({"steps": steps} if tokens and steps[0] is not None
+                   else {}),
                 "model": model,
                 "ttft_s": ttft_s,
                 "latency_s": time.perf_counter() - t0}

@@ -416,6 +416,88 @@ def test_olmo_hybrids_step_compiles_for_the_v5e(one_chip, program, bucket,
         < 16.0e9
 
 
+@pytest.mark.parametrize("program,bucket", [("decode", 0),
+                                            ("prefill", 1024),
+                                            ("prefill", 2048)])
+def test_sdars_step_compiles_for_the_v5e(one_chip, program, bucket,
+                                         monkeypatch):
+    """SDAR-30B-A3B's stage as `sdar-30b-a3b-serve.json` cuts it,
+    through the block engine's own programs at the cell's 32 slots of
+    2,048: the block step compiles and fits, reads the slot cache
+    through `decode_attention` (the block's 4 x 32 queries as query
+    heads of their key heads, the stacks handed whole: no op leaves an
+    array of a layer's keys), and runs every expert through the held
+    path's grouped kernel where the matrices lie (no layer's 1.2 GB of
+    experts is sliced out of the scan's stack); a prefill holds the
+    flash kernel with the block mask beside the plain path, and
+    computes no head."""
+    from benchmark.harness.manifest import ROOT, load_json, model_adapter
+    from ray_tpu.models.serving import served_model
+    from ray_tpu.ops import attention, grouped_matmul
+    from ray_tpu.serve.llm import _BlockEngine
+
+    monkeypatch.setattr(grouped_matmul, "on_tpu", lambda: True)
+    monkeypatch.setattr(attention, "on_tpu", lambda: True)
+    config = load_json(ROOT, "benchmark", "configs",
+                       "sdar-30b-a3b-serve.json")
+    model = model_adapter(config)
+    cfg = model.program_config(config)
+    n, rows = config["serve"]["max_batch_size"], config["serve"]["max_seq_len"]
+    b = cfg.block_length
+
+    def on_chip(tree):
+        return jax.tree.map(lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=one_chip), tree)
+
+    def ints(*dims, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    params = on_chip(jax.eval_shape(
+        lambda: model.init(cfg, jax.random.PRNGKey(0))))
+    engine = _BlockEngine.__new__(_BlockEngine)  # its programs, no device
+    engine.cfg, engine._served = cfg, served_model(cfg)
+    engine._step_len = b
+    cache = on_chip(jax.eval_shape(
+        lambda: engine._served.init_cache(cfg, n, rows)))
+    engine.max_seq, engine.decode_steps, engine.n_slots = rows, 1, n
+    engine._count_names = ("experts_touched", "pairs_routed")
+    if program == "decode":
+        compiled = jax.jit(engine._decode_impl, donate_argnums=(1,)).lower(
+            params, cache, ints(n, b), ints(n, b, dtype=jnp.bool_),
+            ints(n, b), ints(n), ints(n), ints(n, dtype=jnp.float32),
+            ints(n), ints(2, dtype=jnp.uint32)).compile()
+    else:
+        compiled = jax.jit(engine._prefill_impl, donate_argnums=(1,),
+                           static_argnums=(6,)).lower(
+            params, cache, ints(1, bucket), ints(), ints(), ints(),
+            bucket).compile()
+    text = compiled.as_text()
+    kernels = re.findall(r"%([\w-]+?)(?:\.\d+)? = \S+ custom-call\(", text)
+    assert [k for k in kernels if k in PRODUCTS] == ["gmm"] * 3
+    assert "ragged-dot" not in text
+    e, d, f = cfg.n_experts, cfg.dim, cfg.hidden_dim
+    assert not re.findall(
+        rf"= bf16\[{e},(?:{d},{f}|{f},{d})\]\S* "
+        r"(?:fusion|copy|copy-start|dynamic-slice)\(", text)
+    scheduled = _scheduled(text)
+    width = cfg.n_kv_heads * cfg.head_dim
+    if program == "decode":
+        _reads_the_cache_through_the_kernel(scheduled, 1, (n, rows, width))
+        assert kernels.count("flash_fwd") == 0
+    else:
+        assert kernels.count("flash_fwd") == 1
+        # No logits are read, so the head is not computed.
+        assert f"{cfg.vocab_size}]" not in text
+    memory = compiled.memory_analysis()
+    # Weights and cache; a prefill is handed neither head nor embedding
+    # it does not read.
+    assert memory.argument_size_in_bytes > (
+        9.5e9 if program == "decode" else 8.8e9)
+    assert memory.temp_size_in_bytes < 1.0e9
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes \
+        < 16.0e9
+
+
 @pytest.mark.parametrize("window", [None, 4096], ids=["full", "window"])
 def test_trained_flash_kernels_compile_at_smallthinkers_shapes(one_chip,
                                                                window):

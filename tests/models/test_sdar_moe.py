@@ -1,0 +1,252 @@
+"""SDAR's decoder at debug widths on the CPU, in float32, seeded random
+weights at the program's own initialiser (the plain weights, where the
+benchmark's hide the routed experts): the served path (a block-causal
+prefill padded to its bucket, then block steps through the cache, each
+block as a denoising pass and as its commit, rows at different
+positions and phases in one call) against the plain reference, each
+named fault failing where the program passes; a slot used before; the
+flash kernel's block mask; and what the registry says of a model that
+generates by blocks."""
+
+import dataclasses
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from benchmark.harness.manifest import ROOT, load_json, model_adapter
+from benchmark.references import sdar_moe as reference
+from benchmark.runners import serve_blocks
+from ray_tpu.models import llama, sdar_moe, serving
+from ray_tpu.ops import attention
+
+FILE = load_json(ROOT, "benchmark", "configs", "sdar-30b-a3b-serve.json")
+ADAPTER = model_adapter(FILE)
+
+
+def debug_config():
+    config = ADAPTER.debug(FILE)
+    # 44 is whole blocks and no bucket: the check pads it to 64; the
+    # shorter rows step from their own lengths.
+    config["serve"] = {**config["serve"], "max_seq_len": 128,
+                       "reference_prompt_lens": [44, 36, 24, 12],
+                       "reference_block_steps": 2}
+    return config
+
+
+CONFIG = debug_config()
+CFG = ADAPTER.program_config(CONFIG)
+HP = reference.hyper(CONFIG)
+
+
+def _patched(**names):
+    """`ADAPTER.cached_forward` with some names of `sdar_moe` replaced
+    while it is traced."""
+    def served(params, tokens, cfg, cache, start_pos):
+        before = {name: getattr(sdar_moe, name) for name in names}
+        for name, value in names.items():
+            setattr(sdar_moe, name, value)
+        try:
+            return ADAPTER.cached_forward(params, tokens, cfg, cache,
+                                          start_pos)
+        finally:
+            for name, value in before.items():
+                setattr(sdar_moe, name, value)
+    return served
+
+
+def _plain(tiled, start_pos, flash, plain):
+    """`own_keys` that never takes the kernel: a fault of the mask is
+    written into the plain path, and has to bite on a TPU too."""
+    return plain()
+
+
+def _gates_as_they_are(params, tokens, cfg, cache, start_pos):
+    return ADAPTER.cached_forward(
+        params, tokens, dataclasses.replace(cfg, norm_topk_prob=False),
+        cache, start_pos)
+
+
+def _shifted(params, tokens, cfg, cache, start_pos):
+    logits, cache = ADAPTER.cached_forward(params, tokens, cfg, cache,
+                                           start_pos)
+    return jnp.roll(logits, 1, axis=1), cache
+
+
+def _commit_keeps_the_denoised_keys(params, tokens, cfg, cache, start_pos):
+    """A block step that holds no mask token (a commit) leaves the
+    cache as the denoising pass before it left it."""
+    logits, new = ADAPTER.cached_forward(params, tokens, cfg, cache,
+                                         start_pos)
+    if tokens.shape[1] > cfg.block_length:
+        return logits, new
+    commit = ~(tokens == cfg.mask_token_id).any()
+    return logits, jax.tree.map(
+        lambda old, new: jnp.where(commit, old, new), cache, new)
+
+
+def _padding_seen(params, tokens, cfg, cache, start_pos):
+    """A prefill whose rows see every row of the call, its bucket's
+    padding with them."""
+    if tokens.shape[1] <= cfg.block_length:
+        return ADAPTER.cached_forward(params, tokens, cfg, cache, start_pos)
+    return _patched(
+        own_keys=_plain, block_ends=lambda positions, block: jnp.full_like(
+            positions, 10 ** 6))(params, tokens, cfg, cache, start_pos)
+
+
+def _faults():
+    return {
+        "a causal mask where block causal is due":
+            _patched(own_keys=_plain,
+                     block_ends=lambda positions, block: positions),
+        "q/k norm over all heads": _patched(
+            norm_each_head=lambda x, w, eps: llama.norm_all_heads(
+                x, jnp.tile(w, x.shape[2]), eps)),
+        "gates not renormalised": _gates_as_they_are,
+        "interleaved in place of split-half rotary": _patched(
+            apply_rope=lambda x, cos, sin: serving.rotate_pairs(
+                x, cos, sin)),
+        "logits shifted by one": _shifted,
+        "a commit that keeps the denoising pass's keys":
+            _commit_keeps_the_denoised_keys,
+        "a padded prefill whose padding is seen": _padding_seen,
+    }
+
+
+FAULTS = sorted(_faults())
+
+
+@pytest.fixture(scope="module")
+def distances():
+    """Of the program and of each fault, the largest logit error over
+    the largest |reference| logit, by the runner's check (a), at the
+    plain weights. (A stand-in is called while the check traces it, not
+    under a jit of its own: a patch has to hold while it is traced.)"""
+    init, ADAPTER.init = ADAPTER.init, sdar_moe.init_params
+    try:
+        return {name: serve_blocks.check_against_reference(
+            CONFIG, 2 ** 31 + 5, served=served)[0]
+            for name, served in {"program": None, **_faults()}.items()}
+    finally:
+        ADAPTER.init = init
+
+
+def test_the_file_builds_the_published_model():
+    cfg = ADAPTER.program_config(FILE)
+    assert cfg == dataclasses.replace(
+        sdar_moe.SdarMoeConfig(), n_layers=6, denoising_steps=2)
+    assert (cfg.block_length, cfg.mask_token_id) == (4, 151669)
+    assert cfg.runs() == [("full", 6)]
+    served = serving.served_model(cfg)
+    assert served.block_length == 4
+    # The five that yield a token a step say nothing.
+    assert serving.served_model(llama.LlamaConfig.debug()).block_length \
+        is None
+
+
+def test_the_served_path_agrees_with_the_reference(distances):
+    assert distances["program"] < 1e-5, distances
+
+
+@pytest.mark.parametrize("fault", FAULTS)
+def test_a_fault_fails(distances, fault):
+    assert distances[fault] > 1e-3 > 100 * distances["program"], distances
+
+
+@pytest.fixture(scope="module")
+def params():
+    return sdar_moe.init_params(CFG, jax.random.PRNGKey(2))
+
+
+def _tokens(shape, seed=1):
+    return jnp.asarray(np.random.default_rng(seed).integers(
+        1, CFG.vocab_size - 1, shape, dtype=np.int32))
+
+
+def _want(params, tokens):
+    with jax.default_matmul_precision("highest"):
+        return np.asarray(reference.forward(params, tokens, HP))
+
+
+def test_rows_at_different_positions_and_phases_in_one_call(params):
+    """Row 0 commits its second block while row 1 denoises its fourth:
+    one call, two positions, two phases, each against the reference's
+    pass of that very input."""
+    tokens = _tokens((2, 16))
+    cache = sdar_moe.init_cache(CFG, 2, 32)
+    _, cache = sdar_moe.forward_with_cache(
+        params, tokens[:, :12], CFG, cache, jnp.zeros(2, jnp.int32))
+    mask = CFG.mask_token_id
+    noised = np.asarray(tokens[1, 12:16]).copy()
+    noised[[0, 3]] = mask
+    fed = jnp.stack([tokens[0, 4:8], jnp.asarray(noised)])
+    logits, _ = sdar_moe.forward_with_cache(
+        params, fed, CFG, cache, jnp.asarray([4, 12], jnp.int32))
+    want = _want(params, tokens)
+    with jax.default_matmul_precision("highest"):
+        denoised = reference.denoise_logits(
+            params, np.asarray(tokens[1, :12]), noised, HP)
+    np.testing.assert_allclose(logits[0], want[0, 4:8], atol=1e-5)
+    np.testing.assert_allclose(logits[1], denoised, atol=1e-5)
+
+
+def test_a_slot_used_by_a_longer_request_before_serves_a_shorter_one(params):
+    long, short = _tokens((1, 32), 3), _tokens((1, 12), 4)
+    cache = sdar_moe.init_cache(CFG, 1, 32)
+    _, used = sdar_moe.forward_with_cache(
+        params, long, CFG, cache, jnp.zeros(1, jnp.int32))
+    padded = jnp.pad(short, ((0, 0), (0, 4)))
+    logits, used = sdar_moe.forward_with_cache(
+        params, padded, CFG, used, jnp.zeros(1, jnp.int32), keep=12)
+    np.testing.assert_allclose(logits, _want(params, short), atol=1e-5)
+    # ... and its next block, which stands on rows the long one wrote.
+    block = _tokens((1, 4), 5)
+    logits, _ = sdar_moe.forward_with_cache(
+        params, block, CFG, used, jnp.full(1, 12, jnp.int32))
+    np.testing.assert_allclose(
+        logits, _want(params, jnp.concatenate([short, block], 1))[:, 12:],
+        atol=1e-5)
+
+
+def test_a_block_step_returns_every_positions_logits(params):
+    """`forward` with `at` None: the engine's block step."""
+    served = serving.served_model(CFG)
+    tokens = _tokens((2, 4))
+    cache = served.init_cache(CFG, 2, 16)
+    start = jnp.zeros(2, jnp.int32)
+    logits, _, counts = served.forward(params, tokens, CFG, cache, start,
+                                       None)
+    assert logits.shape == (2, 4, CFG.vocab_size)
+    np.testing.assert_allclose(logits, _want(params, tokens), atol=1e-5)
+    # Every layer holds all its experts and reads them where they lie.
+    assert int(counts["pairs_held"]) == int(counts["pairs_routed"]) \
+        == CFG.n_layers * 2 * 4 * CFG.n_experts_per_token
+    assert int(counts["experts_held_steps"]) == CFG.n_layers * CFG.n_experts
+
+
+@pytest.mark.parametrize("rows,block_q", [(64, 1024), (256, 128)],
+                         ids=["one-tile", "two-tiles"])
+def test_the_flash_kernels_block_mask(rows, block_q):
+    """The forward kernel through the Pallas interpreter against a
+    dense softmax under the mask written out: key j is seen from row i
+    iff j // 4 <= i // 4. Tiles above the diagonal are skipped as
+    causality's are."""
+    rng = np.random.default_rng(0)
+    q = jnp.asarray(rng.normal(size=(1, rows, 4, 16)), jnp.float32)
+    k, v = (jnp.asarray(rng.normal(size=(1, rows, 2, 16)), jnp.float32)
+            for _ in range(2))
+    got = attention.flash_attention_forward(
+        q, k, v, block=4, block_q=block_q, block_k=block_q, interpret=True)
+    at = np.arange(rows)
+    seen = at[None, :] // 4 <= at[:, None] // 4
+    scores = np.einsum("bqhd,bkhd->bhqk", q, np.repeat(k, 2, 2)) / 4.0
+    probs = jax.nn.softmax(jnp.where(seen, scores, -np.inf), -1)
+    want = np.einsum("bhqk,bkhd->bqhd", probs, np.repeat(v, 2, 2))
+    np.testing.assert_allclose(got, want, atol=2e-5)
+    # Off the TPU the same call is the reference, with the same mask.
+    np.testing.assert_allclose(
+        attention.flash_attention_forward(q, k, v, block=4), want, atol=2e-5)
+    causal = attention.flash_attention_forward(q, k, v, interpret=True)
+    assert np.abs(np.asarray(causal) - want).max() > 1e-2

@@ -3,7 +3,7 @@ its registry at the family's smallest test config: what the engine
 leans on (a cache that keeps its structure through `forward`, state
 leaves where the family says, the logits of position `at`), the seeded
 weights a cell's numbers are read on, and which of the optional
-functions each family hands the engine. A sixth family adds a row to
+functions each family hands the engine. A further family adds a row to
 `ROWS` and no file."""
 
 import ast
@@ -70,6 +70,11 @@ ROWS = {
         given=frozenset({"state_leaves", "keys_read"}),
         weights="db71416ce808872b3f6340bae503fb5e"
                 "bdf2d8821b44ae01cc49259e97929de0"),
+    "SdarMoeConfig": Row(
+        "sdar_moe", lambda: _debug("sdar-30b-a3b-serve.json"),
+        given=frozenset({"keys_read"}),
+        weights="1b22ab037784016de8ad761330f20cd7"
+                "3625010e91da2878e71e44e3763e96a7"),
 }
 SERVED = sorted(serving._SERVED)
 FAMILIES = [name for name in SERVED if name != "LlamaConfig"]
@@ -144,7 +149,7 @@ def test_the_optional_functions_a_family_gives(name):
 
 def test_no_served_module_imports_a_siblings_private_name():
     for module in ("glm_dsa", "nemotron_h", "cohere2_moe", "olmo_hybrid",
-                   "gated_delta", "mamba2"):
+                   "sdar_moe", "gated_delta", "mamba2"):
         tree = ast.parse(inspect.getsource(
             importlib.import_module(f"ray_tpu.models.{module}")))
         reached = [(node.module, alias.name) for node in ast.walk(tree)

@@ -273,6 +273,51 @@ def test_spans_tile_the_loop_and_totals_add_up(params, ring):
                for kb, vb in engine._kv_store.values())
 
 
+def test_a_block_engines_spans_carry_its_sums(ring):
+    """A model that generates by blocks, under the loop's span names as
+    they are: `engine.consume_block` carries the forwards of owners'
+    slots by phase, the positions fixed and the blocks handed over,
+    which add up to the totals; `slot_steps` counts the token places of
+    emitted rows alone, so kept over it stays a share; a request's
+    first block closes its `llm.prefill`, and from one block to the
+    next one gap in four is recorded."""
+    from benchmark.harness.manifest import ROOT, load_json, model_adapter
+
+    file = load_json(ROOT, "benchmark", "configs", "sdar-30b-a3b-serve.json")
+    adapter = model_adapter(file)
+    cfg = adapter.program_config(adapter.debug(file))
+    engine = LLMEngine(cfg, adapter.init(cfg, jax.random.PRNGKey(0)),
+                       max_batch_size=2, max_seq_len=64, decode_steps=2)
+    prompts = [[(5 * i + j) % 400 + 1 for j in range(6 + 3 * i)]
+               for i in range(4)]
+    answers = _generate_all(engine, prompts, 22)
+    engine.stop()
+    engine._flush_pending()
+    assert [len(a) for a in answers] == [22] * 4
+    totals = engine.metrics()["totals"]
+    blocks = _ring("engine.consume_block")
+    summed = {name: sum(s["attrs"].get(name, 0) for s in blocks)
+              for name in ("slot_forwards_denoise", "slot_forwards_commit",
+                           "slot_forwards", "tokens_fixed", "blocks_emitted",
+                           "kept", "discarded", "slot_steps")}
+    for name in ("slot_forwards_denoise", "slot_forwards_commit",
+                 "tokens_fixed", "blocks_emitted"):
+        assert summed[name] == totals[name] > 0, name
+    assert summed["slot_forwards"] == summed["slot_forwards_denoise"] \
+        + summed["slot_forwards_commit"]
+    assert summed["kept"] == totals["tokens_kept"] == 4 * 22
+    assert summed["kept"] + summed["discarded"] == summed["slot_steps"]
+    assert summed["slot_steps"] % cfg.block_length == 0
+    # 2 of a block's 4 positions a denoising forward, a commit behind
+    # two of them: 4/3 tokens a forward but for the requests' ends.
+    assert 1.2 < summed["tokens_fixed"] / summed["slot_forwards"] <= 4 / 3 + .1
+    assert len(_ring("llm.prefill")) == 4
+    assert _ring("engine.emitted_block_gap")
+    steps = _ring("engine.decode_dispatch")
+    assert all(s["attrs"]["keys_read"] >= s["attrs"]["keys_cached"]
+               for s in steps)
+
+
 def test_block_gaps_tile_a_busy_stretch_by_kind(params, ring):
     """Between two blocks handed over one behind the other lies one
     thin record (PR 36): the gaps of a busy stretch run from its first
