@@ -768,7 +768,7 @@ def _route(cfg: MoEConfig, lp, x):
 
 
 def _moe_ffn(cfg: MoEConfig, lp, x, mesh, rules, stacks=None, *,
-             routed=None, trained=False):
+             routed=None, trained=False, live=None):
     """x: [B, S, D] -> ([B, S, D], aux loss scalar, pairs routed to each
     expert [E] int32, and what the layer computed of them as int32
     scalars: `pairs_held`, the pairs that fell on experts held here
@@ -787,11 +787,18 @@ def _moe_ffn(cfg: MoEConfig, lp, x, mesh, rules, stacks=None, *,
     (SmallThinker scores the layer's input, its experts read the
     normed stream after attention). `trained` says that the layer is
     to have a gradient: a held share then goes through
-    `_held_experts_trained` and its own matrices."""
+    `_held_experts_trained` and its own matrices. `live` [B, S] bool
+    says which rows are anyone's: a row that is not chooses no expert,
+    so a served share computes and counts nothing for it (its pairs are
+    absent, as those on an expert not held are) and the routed
+    experts add nothing to it."""
     b, s, _ = x.shape
     k = cfg.n_experts_per_token
     with jax.named_scope("router"):
         probs, gates, top_i = _route(cfg, lp, x if routed is None else routed)
+        if live is not None:
+            assert cfg.experts_held is not None and not trained
+            top_i = jnp.where(live[..., None], top_i, cfg.n_experts)
         counts = _expert_counts(top_i, cfg.n_experts)
         # Load-balance aux loss: E * sum_e (share of the tokens that
         # chose e) * (mean router probability of e).
@@ -932,15 +939,18 @@ def _gather_but(w, spec, mesh, kept):
     return w
 
 
-def served_ffn(cfg: MoEConfig):
+def served_ffn(cfg: MoEConfig, live=None):
     """The FFN `decoder` is handed for a served expert layer: the
     layer's output and, as its extras, what it counted (`_moe_ffn`'s
     int32 scalars). Where the config holds a share of the experts, it
     names the expert matrices as the leaves `decoder.layers` leaves
     whole (`whole`) and reads its layer's in their stacks
-    (`_held_experts`); a layer of all the experts takes its slice."""
+    (`_held_experts`); a layer of all the experts takes its slice.
+    `live` [B, S] is `_moe_ffn`'s: the call's rows that are anyone's,
+    all of them unless given."""
     def ffn(h, lp, stacks=None):
-        out, _, _, share = _moe_ffn(cfg, lp, h, None, DEFAULT_RULES, stacks)
+        out, _, _, share = _moe_ffn(cfg, lp, h, None, DEFAULT_RULES, stacks,
+                                    live=live)
         return out, share
 
     if cfg.experts_held is not None:

@@ -23,7 +23,9 @@ all alike:
 `forward` and `forward_with_cache` take `tokens` [B, T] at `start_pos`
 [B] as every family's do, with T and `start_pos` multiples of
 `block_length`: the call's rows are written first, and a row then sees
-its slot's rows up to the end of its own block. The cache is two row
+its slot's rows up to the end of its own block. `start_pos` [B, T /
+`block_length`] gives every block of the call a start of its own
+(`models/serving.py`). The cache is two row
 leaves a run, `k` and `v`, [layers, slots, max_seq, kv heads x head
 size]: a row of keys is one axis of 512 channels and not [4, 128] (an
 array whose last two axes are [4, 128] the TPU pads to whole tiles of
@@ -33,10 +35,15 @@ scratch: a denoising pass writes its block's rows from mask tokens,
 and the pass that commits the block writes them again from the final
 ones.
 
-Three shapes of call: a block step (T = `block_length`: every query of
-a slot sees the same keys, so the T x heads queries ride
-`ops.attention.decode_attention` as further query heads of their key
-head and the slot's keys are read once for the block); a prefill from
+Three shapes of call: block steps (T = `block_length`, or a start a
+block: every query of a block sees the same keys, so the block's
+queries x heads ride `ops.attention.decode_attention` as further query
+heads of their key head, the blocks of a call as groups of them with a
+length each, and the slot's keys are read once; the engine's step is
+two blocks a slot, the one it commits and the one it denoises, their
+rows written by `ops.block_rows.write_blocks`, and a block that the
+next is written over goes through no expert and reads one key); a
+prefill from
 position 0 at a bucket the flash kernel tiles, on a TPU
 (`ops.attention.flash_attention_forward` with the block mask, over the
 call's own keys; `serving.own_keys` chooses on the device, since a tail
@@ -62,7 +69,7 @@ import jax.numpy as jnp
 from ray_tpu.models import decoder, llama, moe
 from ray_tpu.models.serving import (Family, attention_init, by_query_blocks,
                                     keys_read_by_blocks, normal, own_keys)
-from ray_tpu.ops import attention
+from ray_tpu.ops import attention, block_rows
 from ray_tpu.ops.norms import rms_norm_reference
 from ray_tpu.ops.rope import apply_rope
 
@@ -155,12 +162,25 @@ def block_ends(positions, block):
 
 
 def _mixer(cfg: SdarMoeConfig, start_pos, positions):
-    """The mixer of every layer; its state is the run's (K, V) stacks."""
+    """The mixer of every layer; its state is the run's (K, V) stacks.
+    `start_pos` [B] is where the call's rows begin, or [B, T / block],
+    a start a block of the call (a step of the engine)."""
     block = cfg.block_length
     b, t = positions.shape
     assert t % block == 0, (t, block)
     g, d = cfg.n_kv_heads, cfg.head_dim
     rep = cfg.n_heads // g
+
+    def block_step(q, k_stack, v_stack, layer, ends):
+        # A block's queries see the same keys, so they stand beside
+        # each other as query heads of their key head, [key head,
+        # position, query head of it]; the blocks of a call with a
+        # start each as groups of them, `ends` [B, blocks] an end each.
+        heads = q.reshape(b, t, g, rep, d).transpose(0, 2, 1, 3, 4)
+        out = attention.decode_attention(
+            heads.reshape(b, g * t * rep, d), k_stack, v_stack, layer, ends)
+        out = out.reshape(b, g, t, rep, d).transpose(0, 2, 1, 3, 4)
+        return out.reshape(b, t, g * rep, d)
 
     def mixer(h, lp, rope, state, handed):
         (k_stack, v_stack), layer = state
@@ -172,20 +192,23 @@ def _mixer(cfg: SdarMoeConfig, start_pos, positions):
         k = norm_each_head(k, lp["k_norm"], cfg.norm_eps)
         q = apply_rope(q, *rope).astype(cached)
         k = apply_rope(k, *rope).astype(cached)
-        k_stack = decoder.write_rows(k_stack, layer, k.reshape(b, t, g * d),
-                                     start_pos)
-        v_stack = decoder.write_rows(v_stack, layer, v.reshape(b, t, g * d),
-                                     start_pos)
+        k_rows, v_rows = k.reshape(b, t, g * d), v.reshape(b, t, g * d)
+        if start_pos.ndim == 2:
+            # Blocks in the call's order, each written where it starts
+            # (of two at one start the later one's rows stay) and then
+            # reading up to its own end, the slot's keys once for all.
+            k_stack, v_stack = block_rows.write_blocks(
+                (k_stack, v_stack), layer, (k_rows, v_rows), start_pos)
+            # (A block that is written over reads one key, not its
+            # slot's: what comes of it is nobody's.)
+            ends = jnp.where(_written_over(start_pos), 1, start_pos + block)
+            out = block_step(q, k_stack, v_stack, layer, ends)
+            return out, (k_stack, v_stack), handed
+        k_stack = decoder.write_rows(k_stack, layer, k_rows, start_pos)
+        v_stack = decoder.write_rows(v_stack, layer, v_rows, start_pos)
         if t == block:
-            # A block step: the block's queries see the same keys, so
-            # they stand beside each other as query heads of their key
-            # head, [key head, position, query head of it].
-            heads = q.reshape(b, t, g, rep, d).transpose(0, 2, 1, 3, 4)
-            out = attention.decode_attention(
-                heads.reshape(b, g * t * rep, d), k_stack, v_stack, layer,
-                start_pos + t)
-            out = out.reshape(b, g, t, rep, d).transpose(0, 2, 1, 3, 4)
-            return out.reshape(b, t, g * rep, d), (k_stack, v_stack), handed
+            out = block_step(q, k_stack, v_stack, layer, start_pos + t)
+            return out, (k_stack, v_stack), handed
 
         def plain():
             rows = k_stack.shape[2]
@@ -211,12 +234,23 @@ def _mixer(cfg: SdarMoeConfig, start_pos, positions):
 # ---------------------------------------------------------------------------
 
 
+def _written_over(start_pos):
+    """[B, blocks] bool, of a call with a start a block: the block
+    stands where the one behind it does, so its rows are written over
+    and nobody reads what comes of them."""
+    return jnp.pad(start_pos[:, :-1] == start_pos[:, 1:], ((0, 0), (0, 1)))
+
+
 def _halves(cfg: SdarMoeConfig, start_pos, positions, at):
     # The share that is the whole: the grouped products pick (layer,
     # expert) out of the run's stack as they fetch a matrix.
     whole = dataclasses.replace(cfg, experts_held=(0, cfg.n_experts))
+    live = None
+    if start_pos.ndim == 2:
+        # A block that is written over goes through no expert.
+        live = ~jnp.repeat(_written_over(start_pos), cfg.block_length, -1)
     return {"full": (_mixer(cfg, start_pos, positions),
-                     moe.served_ffn(whole))}
+                     moe.served_ffn(whole, live))}
 
 
 FAMILY = Family(

@@ -87,17 +87,31 @@ above and differs in what a step is:
   blocks, both ways inside one. There is no shift: the logits at
   position i score the token at i. With `at` None it returns the
   logits of every position, [slots, T, vocab]; a prefill's logits are
-  never read;
+  never read. `start_pos` may be [slots, T / B], a start a block of
+  the call in place of one for its rows: the blocks are written in
+  the call's order, each where it starts, and then each sees the rows
+  up to its own end as before; two blocks of a row may stand at one
+  start: the later one's rows are those that stay, and what comes of
+  the earlier one is nobody's (the model may spare its work). Of such
+  a call `at` None means the logits of the last block, [slots, B,
+  vocab];
 - its config names `mask_token_id`, what an open position is fed as,
   and `denoising_steps`, in how many steps a block is fixed unless a
   request says (`SamplingParams.denoising_steps`);
-- a step of the engine feeds every slot its block (T = B) at the
-  slot's length: a slot with a position open *denoises* (the most
-  confident open positions take their tokens), a slot with none
-  *commits* (the pass's rows, computed from the final tokens, stay;
-  the length grows by B; the next block opens). It hands the host a
-  row of B tokens a slot, the step of the block at which each was
-  fixed, and whether the block is whole now: 0 or B tokens a step,
+- a step of the engine feeds every slot two blocks (T = 2 B, a start
+  each) and *denoises* the second: the most confident of its open
+  positions take their tokens. Where the slot's block before is
+  whole and *awaits its commit*, it is the first, at the slot's
+  length, with the block being denoised behind it: its rows,
+  computed from the final tokens, are written before anything of
+  the next block attends them, they stay, and the length grows by B
+  in the forward that fixes the next block's first positions. Where
+  nothing awaits a commit (a request's first block; a block's
+  second step) both are the block being denoised, at the slot's
+  length: no row below the length is written again, and none past
+  the block. It hands the host a row of B tokens a slot, the step of
+  the block at which each was fixed, whether the block is whole now
+  and whether the one before was committed: 0 or B tokens a step,
   not one. The cache holds rows only, so the prefix cache works as
   before: a block's keys depend on nothing after the block, as long
   as its blocks of rows are whole blocks of the model
@@ -207,7 +221,13 @@ class Family:
         """(final-norm hidden states [B, T, D], new cache, what the
         FFNs counted, summed over the layers; a run whose FFN reports
         nothing adds nothing)."""
-        positions = start_pos[:, None] + jnp.arange(tokens.shape[1])[None, :]
+        if start_pos.ndim == 2:  # a start a block of the call
+            blocks = start_pos.shape[1]
+            positions = (start_pos[:, :, None] + jnp.arange(
+                tokens.shape[1] // blocks)).reshape(tokens.shape)
+        else:
+            positions = start_pos[:, None] \
+                + jnp.arange(tokens.shape[1])[None, :]
         halves = self.halves(cfg, start_pos, positions, at)
         names = [tuple(self.leaves(cfg, kind)) for kind, _ in cfg.runs()]
         runs = [(*halves[kind], stacked,
@@ -237,13 +257,16 @@ class Family:
     def forward(self, params, tokens, cfg, cache, start_pos, at):
         """The module's `forward`, prefill (T = the prompt's bucket)
         and decode (T = 1) alike: the logits of position `at` without
-        the [T, vocab] product of the rest (of every position, [B, T,
-        vocab], where `at` is None: a block step's), and as counts the
-        FFNs' and the family's own."""
+        the [T, vocab] product of the rest (where `at` is None, a block
+        model's step: of every position, [B, T, vocab], or, of a call
+        with a start a block, of its last block, [B, T / blocks,
+        vocab]), and as counts the FFNs' and the family's own."""
         x, cache, counts = self._stack(params, tokens, cfg, cache, start_pos,
                                        at)
         if at is not None:
             x = lax.dynamic_index_in_dim(x, at, 1, keepdims=False)
+        elif start_pos.ndim == 2:
+            x = x[:, -(tokens.shape[1] // start_pos.shape[1]):]
         logits = self._logits(params, x, cfg)
         if self.counts is not None:
             counts = {**counts, **self.counts(tokens, start_pos, at)}

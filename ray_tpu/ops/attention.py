@@ -741,7 +741,8 @@ def decode_block_rows(kv_heads: int, head_dim: int, dtype) -> int:
 
 def _decode_kernel(layer_ref, len_ref, q_ref, own_ref, k_ref, v_ref, o_ref,
                    qs_ref, m_ref, l_ref, acc_ref, *, rows: int, group: int,
-                   lane_heads: int, rep: int, sm_scale: float):
+                   lane_heads: int, rep: int, sm_scale: float,
+                   limits: int = 1):
     """One slot's block of `rows` positions. A block of K or V is
     [rows x group, lane_heads x D] as the leaf stores it: `group` key
     heads a position lie in consecutive rows (the dense leaf's
@@ -752,11 +753,25 @@ def _decode_kernel(layer_ref, len_ref, q_ref, own_ref, k_ref, v_ref, o_ref,
     lanes and zeros elsewhere, `own_ref` [heads, rows x group] takes
     the columns of other key heads' rows out (-1e30; zeros where a row
     holds every head), and of the weighted sum [heads,
-    lane_heads x D] a head keeps the D lanes of its key head."""
+    lane_heads x D] a head keeps the D lanes of its key head. With
+    `limits` over 1 a slot has so many lengths, and the query heads of
+    a key head are as many equal groups in order, each seeing the keys
+    under its own."""
     del layer_ref  # the K and V blocks' index maps read it
     i = pl.program_id(1)
-    n = len_ref[pl.program_id(0)]
     d = q_ref.shape[-1]
+    if limits == 1:
+        n = len_ref[pl.program_id(0)]
+    else:
+        own = [len_ref[pl.program_id(0) * limits + j] for j in range(limits)]
+        n, least = functools.reduce(jnp.maximum, own), \
+            functools.reduce(jnp.minimum, own)
+        # A row of the scores is a query head: its group's length.
+        part = jax.lax.broadcasted_iota(
+            jnp.int32, (q_ref.shape[0], 1), 0) % rep // (rep // limits)
+        n_of_head = functools.reduce(
+            lambda at, j: jnp.where(part == j, own[j], at),
+            range(1, limits), jnp.full(part.shape, own[0], jnp.int32))
 
     @pl.when(i == 0)
     def _init():
@@ -784,7 +799,8 @@ def _decode_kernel(layer_ref, len_ref, q_ref, own_ref, k_ref, v_ref, o_ref,
             # zeroed in V (0 x NaN is NaN).
             left = (n - i * rows) * group
             s = jnp.where(jax.lax.broadcasted_iota(
-                jnp.int32, s.shape, 1) < left, s, _NEG_INF)
+                jnp.int32, s.shape, 1) < (left if limits == 1 else (
+                    n_of_head - i * rows) * group), s, _NEG_INF)
             v = jnp.where(jax.lax.broadcasted_iota(
                 jnp.int32, v.shape, 0) < left, v.astype(jnp.float32),
                 0.0).astype(v.dtype)
@@ -803,9 +819,12 @@ def _decode_kernel(layer_ref, len_ref, q_ref, own_ref, k_ref, v_ref, o_ref,
 
     # A block past the slot's length is neither fetched (its index map
     # names the next slot's first block all along) nor computed.
+    # (Every head has seen a key by then: a length is 1 at least, so a
+    # later block that lies past a head's own length adds nothing to it.)
     start = i * rows
-    pl.when(start + rows <= n)(lambda: attend(False))
-    pl.when((start < n) & (start + rows > n))(lambda: attend(True))
+    whole = n if limits == 1 else least
+    pl.when(start + rows <= whole)(lambda: attend(False))
+    pl.when((start < n) & (start + rows > whole))(lambda: attend(True))
 
     @pl.when(i == pl.num_programs(1) - 1)
     def _finalize():
@@ -819,13 +838,13 @@ def _decode_kernel(layer_ref, len_ref, q_ref, own_ref, k_ref, v_ref, o_ref,
                 o_ref[at, :] = acc_ref[at, kv * d:(kv + 1) * d] * inv[at]
 
 
-@functools.partial(jax.jit,
-                   static_argnames=("rows", "group", "rep", "interpret"))
+@functools.partial(jax.jit, static_argnames=("rows", "group", "rep",
+                                             "interpret", "limits"))
 def _decode_call(q, own, k, v, layer, lengths, *, rows: int, group: int,
-                 rep: int, interpret: bool):
+                 rep: int, interpret: bool, limits: int = 1):
     """The kernel's call: q [B, heads, D], k and v [layers, B,
-    S x group, width]. Jitted, so that a program's layers trace and
-    lower it once."""
+    S x group, width], lengths [B x limits]. Jitted, so that a
+    program's layers trace and lower it once."""
     _, slots, span, width = k.shape
     heads, d = q.shape[1:]
     lane_heads = width // d
@@ -835,7 +854,9 @@ def _decode_call(q, own, k, v, layer, lengths, *, rows: int, group: int,
         # Past the slot's last needed block: the next slot's first, so
         # that it is on its way while this slot's last is worked on and
         # is not fetched again when its turn comes.
-        needed = i * rows < lengths[b]
+        longest = lengths[b] if limits == 1 else functools.reduce(
+            jnp.maximum, [lengths[b * limits + j] for j in range(limits)])
+        needed = i * rows < longest
         ahead = jnp.minimum(b + 1, slots - 1)
         return (layer[0], jnp.where(needed, b, ahead),
                 jnp.where(needed, i, 0), 0)
@@ -846,7 +867,7 @@ def _decode_call(q, own, k, v, layer, lengths, *, rows: int, group: int,
     return pl.pallas_call(
         functools.partial(
             _decode_kernel, rows=rows, group=group, lane_heads=lane_heads,
-            rep=rep, sm_scale=d ** -0.5),
+            rep=rep, sm_scale=d ** -0.5, limits=limits),
         out_shape=jax.ShapeDtypeStruct(q.shape, jnp.float32),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
@@ -876,7 +897,11 @@ def decode_attention(q, k_stack, v_stack, layer, lengths, *,
     `k_stack` and `v_stack` a run's leaves whole, [layers, B, S, Hkv, D]
     or [layers, B, S, Hkv x D]; `layer` an int32 scalar; `lengths`
     int32 [B], the keys a row sees (its position + 1, this step's row
-    written) -> [B, H, D].
+    written) -> [B, H, D]. `lengths` [B, n] gives a slot n of them: the
+    `H // Hkv` query heads of a key head are n equal groups in order,
+    and group j sees `lengths[:, j]` keys, the slot's keys read once
+    for all of them (a step of n blocks of a model that generates by
+    blocks, each block's queries standing as query heads).
 
     The grid is (slot, block of `decode_block_rows` positions). `layer`
     and `lengths` are scalar-prefetch arguments: the K and V blocks'
@@ -897,6 +922,7 @@ def decode_attention(q, k_stack, v_stack, layer, lengths, *,
     span = k_stack.shape[2]
     kv_heads = math.prod(k_stack.shape[3:]) // d
     lengths = jnp.clip(lengths, 1, span).astype(jnp.int32)
+    limits = 1 if lengths.ndim == 1 else lengths.shape[1]
     interpret = interpret and not on_tpu()
     if not (on_tpu() or interpret):
         from ray_tpu.models import decoder, llama
@@ -904,8 +930,17 @@ def decode_attention(q, k_stack, v_stack, layer, lengths, *,
             decoder.layer_rows(x, layer, 0, span).reshape(
                 b, span, kv_heads, d) for x in (k_stack, v_stack))
         plain = llama._cached_attention  # raylint: disable=R3 -- the plain path is the served model's own, found by the name the benchmark's tests patch; no second copy of its arithmetic lives here
-        return plain(None, q[:, None], keys, values,
-                     lengths[:, None] - 1)[:, 0]
+        if limits == 1:
+            return plain(None, q[:, None], keys, values,
+                         lengths[:, None] - 1)[:, 0]
+        # A group of heads at a time, as a call of its own would run
+        # it: [B, key heads, n, a group's heads, D].
+        groups = q.reshape(b, kv_heads, limits, -1, d)
+        return jnp.stack([
+            plain(None, groups[:, :, j].reshape(b, 1, -1, d), keys, values,
+                  lengths[:, j, None] - 1)[:, 0].reshape(
+                      groups[:, :, j].shape)
+            for j in range(limits)], 2).reshape(q.shape)
     rows = min(decode_block_rows(kv_heads, d, k_stack.dtype), span)
     group = kv_heads if k_stack.ndim == 5 else 1
     if group > 1:
@@ -923,7 +958,10 @@ def decode_attention(q, k_stack, v_stack, layer, lengths, *,
     own = jnp.where(
         jnp.arange(rows * group)[None, :] % group
         == jnp.arange(padded)[:, None] // rep % group, 0.0, _NEG_INF)
+    assert limits == 1 or (padded == h and rep % limits == 0), \
+        (h, rep, limits)
     out = _decode_call(q, own, k_stack, v_stack,
-                       jnp.asarray(layer, jnp.int32), lengths, rows=rows,
-                       group=group, rep=rep, interpret=interpret)
+                       jnp.asarray(layer, jnp.int32), lengths.reshape(-1),
+                       rows=rows, group=group, rep=rep, interpret=interpret,
+                       limits=limits)
     return out[:, :h].astype(q.dtype)

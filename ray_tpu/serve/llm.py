@@ -1523,22 +1523,30 @@ class _BlockEngine(LLMEngine):
     A slot carries, on the device, its block ([B] tokens and [B] flags,
     which positions are still open: never found by comparing ids with
     the mask id, a prompt may hold any id), the step of the block it is
-    at, the step at which each position was fixed, and its length, the
-    committed rows, a multiple of B. One fixed-shape program
-    (`_decode_impl`, `decode_steps` forwards a dispatch) feeds every
-    slot its block at its length, the mask token at the open positions,
-    whatever the slot's phase. A slot that came in with a position open
-    *denoises*: at every open position the token x0 (greedy at
-    temperature 0, else drawn from softmax(logits / T)) and its
-    confidence (x0's probability, float32), and the `n` most confident
-    open positions, or those that are left, take their tokens (ties to
-    the lower position; n = B / `SamplingParams.denoising_steps`, the
-    config's unless the request says); if none is open now the block is
-    *emitted*. A slot that came in with none open *commits*: the pass's
-    rows, computed from the final tokens, stay in the cache, the length
-    grows by B and the next block opens, all mask. So a step hands the
-    host, a slot, a row of B tokens, the steps they were fixed at and a
-    count that is 0 or B; `top_k` is not applied.
+    at, the step at which each position was fixed, its length, the
+    committed rows, a multiple of B, and the block before, whole, with
+    a flag that says whether it still awaits its commit. One
+    fixed-shape program (`_decode_impl`, `decode_steps` forwards a
+    dispatch) feeds every slot two blocks a forward, a start each
+    (`models/serving.py`): the block that awaits its commit at the
+    slot's length and the slot's own block behind it, the mask token
+    at the open positions; or, where nothing awaits a commit, the
+    slot's own block twice at the slot's length. The head runs on the
+    second alone. Every forward *denoises* the slot's block: at every
+    open position the token x0 (greedy at temperature 0, else drawn
+    from softmax(logits / T)) and its confidence (x0's probability,
+    float32), and the `n` most confident open positions, or those that
+    are left, take their tokens (ties to the lower position; n = B /
+    `SamplingParams.denoising_steps`, the config's unless the request
+    says); if none is open now the block is *emitted*: it awaits its
+    commit, and the next block opens behind it, all mask. The forward
+    after that *commits* it while it fixes the next block's first
+    positions: the emitted block's rows are written from its final
+    tokens before the next block's queries read them, they stay, and
+    the length grows by B. So a block of B positions costs
+    `denoising_steps` forwards and no more, and a step hands the host,
+    a slot, a row of B tokens, the steps they were fixed at, a count
+    that is 0 or B, and whether it committed; `top_k` is not applied.
 
     Admission prefills the prompt's whole blocks, `(len // B) * B`
     tokens, at the engine's buckets (a bucket's padding lies in later
@@ -1550,7 +1558,11 @@ class _BlockEngine(LLMEngine):
     request ends inside a block (`max_tokens`, a stop id) and the rest
     of the block is dropped.
 
-    The host counts by blocks: `_lengths` grows by B at a commit;
+    The host counts by blocks: `_lengths` grows by B at a commit, and
+    a forward of a request's slot is counted by what it did
+    (`slot_forwards_denoise`: it fixed a position; `slot_forwards_fused`:
+    it also committed the block before; `slot_forwards_commit`: it
+    committed and fixed nothing, which the schedule above never does);
     `tokens_kept` are the tokens handed to requests and
     `tokens_discarded` the other token places of the emitted rows (a
     retired slot's, a first block's known positions, what follows a
@@ -1569,34 +1581,45 @@ class _BlockEngine(LLMEngine):
         self._dev_open = jax.device_put(jnp.ones((n, b), bool), s1)
         self._dev_fixed_at = jax.device_put(jnp.zeros((n, b), jnp.int32), s1)
         self._dev_step = jax.device_put(jnp.zeros(n, jnp.int32), s1)
+        self._dev_whole = jax.device_put(jnp.zeros((n, b), jnp.int32), s1)
+        self._dev_awaits = jax.device_put(jnp.zeros(n, bool), s1)
         # Positions a denoising step fixes, a slot.
         self._nfix_arr = np.full(n, b // cfg.denoising_steps, np.int32)
         self._decode = jax.jit(
             self._decode_impl, donate_argnums=(1,),
-            in_shardings=(None,) + (s1,) * 9, out_shardings=(s1,) * 9)
+            in_shardings=(None,) + (s1,) * 11, out_shardings=(s1,) * 11)
         # The wave's program: the admitted slots' blocks and lengths
         # into the carries, at one fixed shape.
         self._sample_admitted = jax.jit(
-            self._seed_blocks_impl, in_shardings=(s1,) * 9,
-            out_shardings=(s1,) * 5)
+            self._seed_blocks_impl, in_shardings=(s1,) * 11,
+            out_shardings=(s1,) * 7)
         self._totals.update(dict.fromkeys((
-            "slot_forwards_denoise", "slot_forwards_commit", "tokens_fixed",
-            "blocks_emitted"), 0))
+            "slot_forwards_denoise", "slot_forwards_commit",
+            "slot_forwards_fused", "tokens_fixed", "blocks_emitted"), 0))
 
-    def _decode_avals(self, aval):
+    def _carry_avals(self, aval):
         n, b = self.n_slots, self._step_len
         return (aval((n, b)), aval((n, b), jnp.bool_), aval((n, b)),
-                aval((n,)), aval((n,)), aval((n,), jnp.float32), aval((n,)))
+                aval((n,)), aval((n,)), aval((n, b)), aval((n,), jnp.bool_))
+
+    def _decode_avals(self, aval):
+        n = self.n_slots
+        return (*self._carry_avals(aval), aval((n,), jnp.float32),
+                aval((n,)))
 
     def _sample_avals(self, aval, rng_aval):
         n, b = self.n_slots, self._step_len
-        return (aval((n, b)), aval((n, b), jnp.bool_), aval((n, b)),
-                aval((n,)), aval((n,)), aval((n,)), aval((n, b)),
+        return (*self._carry_avals(aval), aval((n,)), aval((n, b)),
                 aval((n, b), jnp.bool_), aval((n,)))
 
     def _carries(self):
         return (self._dev_block, self._dev_open, self._dev_fixed_at,
-                self._dev_step, self._dev_lengths)
+                self._dev_step, self._dev_lengths, self._dev_whole,
+                self._dev_awaits)
+
+    def _set_carries(self, carries):
+        (self._dev_block, self._dev_open, self._dev_fixed_at, self._dev_step,
+         self._dev_lengths, self._dev_whole, self._dev_awaits) = carries
 
     def _run_seed(self, slots, blocks, opens, lengths):
         fn = self._sample_exec or self._sample_admitted
@@ -1627,42 +1650,52 @@ class _BlockEngine(LLMEngine):
         return cache, jnp.zeros((), jnp.float32)
 
     def _seed_blocks_impl(self, block, open_, fixed_at, step, lengths,
-                          slots, new_block, new_open, new_lengths):
+                          whole, awaits, slots, new_block, new_open,
+                          new_lengths):
         """A wave's rows, one an admitted request, into the carries at
         the row's slot: the first block (the prompt's leftover tokens,
-        known, then open positions) and the prefilled length. Rows
-        beyond the admitted count are padding: their slot is `n_slots`,
-        which the update drops."""
+        known, then open positions), the prefilled length, and no block
+        that awaits its commit. Rows beyond the admitted count are
+        padding: their slot is `n_slots`, which the update drops."""
         return (block.at[slots].set(new_block, mode="drop"),
                 open_.at[slots].set(new_open, mode="drop"),
                 fixed_at.at[slots].set(-1, mode="drop"),
                 step.at[slots].set(0, mode="drop"),
-                lengths.at[slots].set(new_lengths, mode="drop"))
+                lengths.at[slots].set(new_lengths, mode="drop"),
+                whole, awaits.at[slots].set(False, mode="drop"))
 
     def _decode_impl(self, params, cache, block, open_, fixed_at, step,
-                     lengths, temps, n_fix, rng):
-        """`decode_steps` forwards of every slot's block a dispatch, by
-        an in-program `lax.scan`; a slot denoises or commits by whether
-        a position of its block is open (the class's docstring).
-        Returns, after the cache, int32 [slots, K, 2 B + 3]: a forward's
-        B tokens, the B steps they were fixed at, whether the block was
-        emitted by this forward, whether it denoised, and how many
-        positions it fixed; then the carries, the rng and what the
-        model counted over the K forwards."""
+                     lengths, whole, awaits, temps, n_fix, rng):
+        """`decode_steps` forwards a dispatch, by an in-program
+        `lax.scan`, each of two blocks a slot (the class's docstring):
+        the whole block that awaits its commit, or the slot's own block
+        once more, and the block being denoised. Returns, after the
+        cache, int32 [slots, K, 2 B + 3]: a forward's B tokens, the B
+        steps they were fixed at, whether the block was emitted by this
+        forward, whether the forward committed the block before it, and
+        how many positions it fixed; then the carries, the rng and what
+        the model counted over the K forwards."""
         b, mask_id = self._step_len, self.cfg.mask_token_id
         before = jnp.arange(b)[None, :] < jnp.arange(b)[:, None]  # [i, j]
 
         def forward(carry, _):
-            cache, block, open_, fixed_at, step, lengths, rng = carry
+            cache, block, open_, fixed_at, step, lengths, whole, awaits, \
+                rng = carry
             # Clamp for retired slots that keep computing until their
-            # slot is re-admitted: their writes stay in the region.
+            # slot is re-admitted: their writes stay in the region. (A
+            # request's slot has room for both blocks: `_consume_block`
+            # retires it where the next block's rows would not fit.)
             lengths = jnp.minimum(lengths, self.max_seq - b)
+            fed = jnp.where(open_, mask_id, block)
+            starts = jnp.stack([lengths, jnp.minimum(
+                lengths + jnp.where(awaits, b, 0), self.max_seq - b)], -1)
             logits, cache, counts = self._served.forward(
-                params, jnp.where(open_, mask_id, block), self.cfg, cache,
-                lengths, None)
+                params, jnp.concatenate(
+                    [jnp.where(awaits[:, None], whole, fed), fed], -1),
+                self.cfg, cache, starts, None)
             counts = tuple(counts[name] for name in self._count_names)
-            # [slots, B, vocab], at the slot's temperature (as they are
-            # at temperature 0).
+            # [slots, B, vocab], the block being denoised, at the
+            # slot's temperature (as they are at temperature 0).
             logits = logits.astype(jnp.float32) / jnp.where(
                 temps > 0, temps, 1.0)[:, None, None]
             rng, sub = jax.random.split(rng)
@@ -1683,27 +1716,27 @@ class _BlockEngine(LLMEngine):
                      | ((score[:, None, :] == score[:, :, None]) & before)
                      ).sum(-1)
             fix = open_ & (ahead < n_fix[:, None])
-            denoise = open_.any(-1)
             block = jnp.where(fix, x0, block)
             open_ = open_ & ~fix
             fixed_at = jnp.where(fix, step[:, None], fixed_at)
-            emitted = denoise & ~open_.any(-1)
+            emitted = ~open_.any(-1)
             out = jnp.concatenate(
-                [block, fixed_at, emitted[:, None], denoise[:, None],
+                [block, fixed_at, emitted[:, None], awaits[:, None],
                  fix.sum(-1, keepdims=True)], -1, dtype=jnp.int32)
-            commit = ~denoise
-            return (cache, block, open_ | commit[:, None], fixed_at,
-                    jnp.where(commit, 0, step + 1),
-                    lengths + jnp.where(commit, b, 0), rng), (out, counts)
+            # An emitted block awaits its commit and the next one opens
+            # behind it, all mask.
+            return (cache, block, open_ | emitted[:, None], fixed_at,
+                    jnp.where(emitted, 0, step + 1),
+                    lengths + jnp.where(awaits, b, 0),
+                    jnp.where(emitted[:, None], block, whole), emitted,
+                    rng), (out, counts)
 
-        (cache, block, open_, fixed_at, step, lengths, rng), (out, counts) \
-            = jax.lax.scan(
-                forward, (cache, block, open_, fixed_at, step, lengths, rng),
-                None, length=self.decode_steps)
+        (cache, *carries, rng), (out, counts) = jax.lax.scan(
+            forward, (cache, block, open_, fixed_at, step, lengths, whole,
+                      awaits, rng), None, length=self.decode_steps)
         if counts:
             counts = jnp.stack(counts, -1).sum(0, dtype=jnp.int32)
-        return (cache, out.transpose(1, 0, 2), block, open_, fixed_at, step,
-                lengths, rng, counts)
+        return (cache, out.transpose(1, 0, 2), *carries, rng, counts)
 
     # -- the loop's seams ------------------------------------------------
 
@@ -1733,22 +1766,19 @@ class _BlockEngine(LLMEngine):
                 lengths[i] = len(req.prompt) - known
                 self._nfix_arr[slot] = b // (req.params.denoising_steps
                                              or self.cfg.denoising_steps)
-            (self._dev_block, self._dev_open, self._dev_fixed_at,
-             self._dev_step, self._dev_lengths) = self._run_seed(
-                slots, blocks, opens, lengths)
+            self._set_carries(self._run_seed(slots, blocks, opens, lengths))
 
     def _dispatch_decode(self):
-        (self.cache, out, self._dev_block, self._dev_open,
-         self._dev_fixed_at, self._dev_step, self._dev_lengths, self._rng,
-         counts) = self._run_decode()
+        self.cache, out, *carries, self._rng, counts = self._run_decode()
+        self._set_carries(carries)
         return out, counts
 
     def _consume_block(self, next_host, owners, counts=(),
                        behind_wave=False):
         """`LLMEngine._consume_block` of rows of 0 or B tokens: every
-        forward of an owner's slot is counted by its phase, an emitted
-        block's tokens go to the request together, each with the step
-        it was fixed at, and a commit grows the slot's length. The
+        forward of an owner's slot is counted by what it did, an
+        emitted block's tokens go to the request together, each with
+        the step it was fixed at, and a commit grows the slot's length. The
         first block's known positions are the prompt's, and a request
         that ends inside a block leaves the rest. A request's first
         block closes its `llm.prefill` stage; from one block of a
@@ -1756,9 +1786,9 @@ class _BlockEngine(LLMEngine):
         `engine.emitted_block_gap`, the gap its client is dealt."""
         b = self._step_len
         tokens, fixed_at = next_host[..., :b], next_host[..., b:2 * b]
-        emitted, denoised, n_fixed = (next_host[..., 2 * b + i]
-                                      for i in range(3))
-        kept = stale = denoise = commit = fixed = blocks = 0
+        emitted, committed, n_fixed = (next_host[..., 2 * b + i]
+                                       for i in range(3))
+        kept = stale = forwards = denoise = commit = fused = fixed = blocks = 0
         counted = dict(zip(self._count_names, map(int, np.asarray(counts))))
         with critical_path.span("engine.consume_block") as sp, self._lock:
             for slot in np.nonzero(self._active)[0]:
@@ -1767,12 +1797,14 @@ class _BlockEngine(LLMEngine):
                     stale += b * int(emitted[slot].sum())
                     continue
                 for k in range(next_host.shape[1]):
-                    if denoised[slot, k]:
-                        denoise += 1
-                        fixed += int(n_fixed[slot, k])
-                    else:
-                        commit += 1
+                    forwards += 1
+                    fixes = int(n_fixed[slot, k])
+                    fixed += fixes
+                    denoise += fixes > 0
+                    if committed[slot, k]:
                         self._lengths[slot] += b
+                        fused += fixes > 0
+                        commit += not fixes
                     if not emitted[slot, k]:
                         continue
                     blocks += 1
@@ -1810,6 +1842,7 @@ class _BlockEngine(LLMEngine):
             totals["slot_steps_stale"] += stale
             totals["slot_forwards_denoise"] += denoise
             totals["slot_forwards_commit"] += commit
+            totals["slot_forwards_fused"] += fused
             totals["tokens_fixed"] += fixed
             totals["blocks_emitted"] += blocks
             for name, n in counted.items():
@@ -1818,7 +1851,8 @@ class _BlockEngine(LLMEngine):
                    slot_steps=slot_steps, behind_wave=int(behind_wave),
                    slot_forwards_denoise=denoise,
                    slot_forwards_commit=commit,
-                   slot_forwards=denoise + commit, tokens_fixed=fixed,
+                   slot_forwards_fused=fused, slot_forwards=forwards,
+                   tokens_fixed=fixed,
                    blocks_emitted=blocks, **counted)
         self._record_hand_over(behind_wave)
 

@@ -272,6 +272,32 @@ def _share_by_hand(cfg, lp, h):
     return moe._add_shared_expert(cfg, lp, h, out)
 
 
+@pytest.mark.parametrize("kind", ["swiglu", "relu2"])
+def test_a_row_that_is_nobodys_goes_through_no_expert(kind):
+    """`served_ffn` with `live`: a row that is not live chooses no
+    expert, so the held share computes and counts nothing for it (it
+    keeps what every token gets, the shared expert); the live rows'
+    output is what it is without the mask."""
+    from ray_tpu.models import moe
+
+    cfg, stacked, h = _held_run(kind, n_layers=1)
+    lp = jax.tree.map(lambda x: x[0], stacked)
+    live = jnp.arange(h.shape[1])[None, :] % 3 != jnp.arange(2)[:, None]
+    masked, counted = moe.served_ffn(cfg, live)(h, lp)
+    plain, all_counted = moe.served_ffn(cfg)(h, lp)
+    np.testing.assert_allclose(masked[live], plain[live], atol=1e-6)
+    np.testing.assert_allclose(
+        masked[~live], moe._add_shared_expert(
+            cfg, lp, h, jnp.zeros_like(h))[~live], atol=1e-6)
+    _, _, top_i = moe._route(cfg, lp, h)
+    first, count = cfg.experts_held
+    held = (top_i >= first) & (top_i < first + count)
+    assert int(counted["pairs_held"]) == int(held[live].sum()) \
+        < int(all_counted["pairs_held"]) == int(held.sum())
+    assert int(counted["experts_touched"]) == len(
+        np.unique(np.asarray(top_i)[np.asarray(held & live[..., None])]))
+
+
 @pytest.mark.parametrize("products", ["ragged_dot", "kernel"])
 @pytest.mark.parametrize("crowded", [False, True], ids=["roomy", "crowded"])
 @pytest.mark.parametrize("kind", ["swiglu", "relu2"])

@@ -423,21 +423,24 @@ def test_sdars_step_compiles_for_the_v5e(one_chip, program, bucket,
                                          monkeypatch):
     """SDAR-30B-A3B's stage as `sdar-30b-a3b-serve.json` cuts it,
     through the block engine's own programs at the cell's 32 slots of
-    2,048: the block step compiles and fits, reads the slot cache
-    through `decode_attention` (the block's 4 x 32 queries as query
-    heads of their key heads, the stacks handed whole: no op leaves an
-    array of a layer's keys), and runs every expert through the held
+    2,048: the step of two blocks a slot compiles and fits, writes
+    their rows through `write_blocks` and reads the slot cache through
+    `decode_attention` once for both (a block's 4 x 32 queries as query
+    heads of their key heads, a length a block, the stacks handed
+    whole: no op leaves an array of a layer's keys), runs the head on
+    one block a slot, and runs every expert through the held
     path's grouped kernel where the matrices lie (no layer's 1.2 GB of
     experts is sliced out of the scan's stack); a prefill holds the
     flash kernel with the block mask beside the plain path, and
     computes no head."""
     from benchmark.harness.manifest import ROOT, load_json, model_adapter
     from ray_tpu.models.serving import served_model
-    from ray_tpu.ops import attention, grouped_matmul
+    from ray_tpu.ops import attention, block_rows, grouped_matmul
     from ray_tpu.serve.llm import _BlockEngine
 
     monkeypatch.setattr(grouped_matmul, "on_tpu", lambda: True)
     monkeypatch.setattr(attention, "on_tpu", lambda: True)
+    monkeypatch.setattr(block_rows, "on_tpu", lambda: True)
     config = load_json(ROOT, "benchmark", "configs",
                        "sdar-30b-a3b-serve.json")
     model = model_adapter(config)
@@ -464,8 +467,9 @@ def test_sdars_step_compiles_for_the_v5e(one_chip, program, bucket,
     if program == "decode":
         compiled = jax.jit(engine._decode_impl, donate_argnums=(1,)).lower(
             params, cache, ints(n, b), ints(n, b, dtype=jnp.bool_),
-            ints(n, b), ints(n), ints(n), ints(n, dtype=jnp.float32),
-            ints(n), ints(2, dtype=jnp.uint32)).compile()
+            ints(n, b), ints(n), ints(n), ints(n, b),
+            ints(n, dtype=jnp.bool_), ints(n, dtype=jnp.float32), ints(n),
+            ints(2, dtype=jnp.uint32)).compile()
     else:
         compiled = jax.jit(engine._prefill_impl, donate_argnums=(1,),
                            static_argnums=(6,)).lower(
@@ -484,6 +488,15 @@ def test_sdars_step_compiles_for_the_v5e(one_chip, program, bucket,
     if program == "decode":
         _reads_the_cache_through_the_kernel(scheduled, 1, (n, rows, width))
         assert kernels.count("flash_fwd") == 0
+        # Both blocks' rows, K's and V's, are written by one kernel a
+        # layer where the stacks lie: no scatter a block and leaf.
+        assert len(re.findall(r"%write_blocks(?:\.\d+)? = .* custom-call\(",
+                              text)) == 1
+        assert not re.findall(r' scatter\(.*op_name="[^"]*/attn/', text)
+        # The head's product and the float32 pass over the logits are
+        # of the block being denoised alone.
+        assert f"f32[{n},{b},{cfg.vocab_size}]" in text
+        assert f"[{n},{2 * b},{cfg.vocab_size}]" not in text
     else:
         assert kernels.count("flash_fwd") == 1
         # No logits are read, so the head is not computed.

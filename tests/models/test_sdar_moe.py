@@ -3,8 +3,10 @@ weights at the program's own initialiser (the plain weights, where the
 benchmark's hide the routed experts): the served path (a block-causal
 prefill padded to its bucket, then block steps through the cache, each
 block as a denoising pass and as its commit, rows at different
-positions and phases in one call) against the plain reference, each
-named fault failing where the program passes; a slot used before; the
+positions and phases in one call; the engine's step of two blocks a
+row, a start each, with and without a block that awaits its commit)
+against the plain reference, each named fault failing where the program
+passes; what such a step writes and what it leaves; a slot used before; the
 flash kernel's block mask; and what the registry says of a model that
 generates by blocks."""
 
@@ -190,6 +192,122 @@ def test_rows_at_different_positions_and_phases_in_one_call(params):
             params, np.asarray(tokens[1, :12]), noised, HP)
     np.testing.assert_allclose(logits[0], want[0, 4:8], atol=1e-5)
     np.testing.assert_allclose(logits[1], denoised, atol=1e-5)
+
+
+def _prefilled(params, tokens, upto, max_seq=32):
+    cache = sdar_moe.init_cache(CFG, tokens.shape[0], max_seq)
+    return sdar_moe.forward_with_cache(
+        params, tokens[:, :upto], CFG, cache,
+        jnp.zeros(tokens.shape[0], jnp.int32))[1]
+
+
+def _noised(block, open_):
+    block = np.asarray(block).copy()
+    block[list(open_)] = CFG.mask_token_id
+    return block
+
+
+# Of the call's two rows, which carry a whole block that awaits its
+# commit in front of the block being denoised; the other carries its
+# own block twice, at one start.
+@pytest.mark.parametrize("awaiting", [(True, True), (False, False),
+                                      (True, False), (False, True)],
+                         ids=["both", "neither", "first", "second"])
+def test_a_step_of_two_blocks_a_row(params, awaiting):
+    """The engine's step: two blocks a row, a start each. Row 0 stands
+    at length 4 and row 1 at 12; a row that awaits a commit feeds the
+    whole block at its length and the noised one behind it, the other
+    its noised block twice at its length. Every block's logits are the
+    reference's pass of that very input, but for the first of two at
+    one start, which is written over and is nobody's; the head's
+    (`forward` with `at` None) are the last block's."""
+    tokens = _tokens((2, 24))
+    lengths = [4, 12]
+    cache = _prefilled(params, tokens, 12)
+    fed, starts, want = [], [], []
+    for row, (length, waits, open_) in enumerate(zip(
+            lengths, awaiting, [(1, 2), (0, 3)])):
+        first = length + 4 * waits  # of the block being denoised
+        noised = _noised(tokens[row, first:first + 4], open_)
+        with jax.default_matmul_precision("highest"):
+            denoised = reference.denoise_logits(
+                params, np.asarray(tokens[row, :first]), noised, HP)
+        if waits:
+            fed.append(np.concatenate([tokens[row, length:first], noised]))
+            want.append(np.concatenate(
+                [_want(params, tokens[row:row + 1, :first])[0, length:],
+                 denoised]))
+        else:
+            fed.append(np.concatenate([noised, noised]))
+            want.append(np.concatenate([denoised, denoised]))
+        starts.append([length, first])
+    fed, starts = jnp.asarray(np.stack(fed)), jnp.asarray(starts, jnp.int32)
+    logits, _ = sdar_moe.forward_with_cache(params, fed, CFG, cache, starts)
+    np.testing.assert_allclose(logits[:, 4:], np.stack(want)[:, 4:],
+                               atol=1e-5)
+    waits = np.asarray(awaiting)
+    np.testing.assert_allclose(logits[waits, :4], np.stack(want)[waits, :4],
+                               atol=1e-5)
+    head, _, _ = serving.served_model(CFG).forward(
+        params, fed, CFG, cache, starts, None)
+    np.testing.assert_array_equal(head, logits[:, 4:])
+
+
+def test_a_commit_in_a_step_of_two_blocks_writes_what_a_commit_writes(params):
+    """The rows a two-block step leaves for the block it commits, and
+    for the block behind it, are to the bit those of a block step with
+    the whole block's tokens and one with the noised block's behind it;
+    so are the noised block's logits, which see the committed rows as
+    the cache stores them."""
+    tokens = _tokens((2, 24), seed=6)
+    cache = _prefilled(params, tokens, 12)
+    lengths = jnp.asarray([4, 12], jnp.int32)
+    whole = jnp.stack([tokens[0, 4:8], tokens[1, 12:16]])
+    noised = jnp.asarray(np.stack([_noised(tokens[0, 8:12], (0, 1, 2, 3)),
+                                   _noised(tokens[1, 16:20], (2,))]))
+    _, stepped = sdar_moe.forward_with_cache(params, whole, CFG, cache,
+                                             lengths)
+    want, stepped = sdar_moe.forward_with_cache(params, noised, CFG, stepped,
+                                                lengths + 4)
+    got, fused = sdar_moe.forward_with_cache(
+        params, jnp.concatenate([whole, noised], 1), CFG, cache,
+        jnp.stack([lengths, lengths + 4], -1))
+    np.testing.assert_array_equal(got[:, 4:], want)
+    for a, b in zip(jax.tree.leaves(fused), jax.tree.leaves(stepped)):
+        np.testing.assert_array_equal(a, b)
+    # ... and not the rows a denoising pass of the block had left there.
+    _, denoised = sdar_moe.forward_with_cache(
+        params, jnp.asarray(np.stack([_noised(whole[0], (1, 3)),
+                                      _noised(whole[1], (0,))])),
+        CFG, cache, lengths)
+    assert np.abs(np.asarray(denoised["runs"][0]["k"])
+                  - np.asarray(fused["runs"][0]["k"])).max() > 1e-3
+
+
+def test_a_committed_row_is_not_written_again(params):
+    """Steps of a row with nothing awaiting commit (its block twice, at
+    its length) write that block's rows and no other: what the cache
+    holds before the length, a committed block's rows among them (the
+    prefix cache may have hashed them), stays to the bit, and so does
+    what lies behind the block."""
+    tokens = _tokens((2, 24), seed=8)
+    cache = _prefilled(params, tokens, 12)
+    lengths = np.asarray([4, 12])
+    starts = jnp.asarray(np.stack([lengths, lengths], -1), jnp.int32)
+    after = cache
+    for open_ in [(0, 1, 2, 3), (1, 2)]:
+        noised = jnp.asarray(np.stack([
+            _noised(tokens[row, at:at + 4], open_)
+            for row, at in enumerate(lengths)]))
+        _, after = sdar_moe.forward_with_cache(
+            params, jnp.concatenate([noised, noised], 1), CFG, after, starts)
+    for old, new in zip(jax.tree.leaves(cache), jax.tree.leaves(after)):
+        old, new = np.asarray(old), np.asarray(new)
+        for row, at in enumerate(lengths):
+            np.testing.assert_array_equal(new[:, row, :at], old[:, row, :at])
+            np.testing.assert_array_equal(new[:, row, at + 4:],
+                                          old[:, row, at + 4:])
+            assert (new[:, row, at:at + 4] != old[:, row, at:at + 4]).any()
 
 
 def test_a_slot_used_by_a_longer_request_before_serves_a_shorter_one(params):

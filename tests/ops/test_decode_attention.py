@@ -70,6 +70,61 @@ def test_the_kernel_reads_what_a_slot_holds_and_no_more(layout, dtype,
     assert np.abs(got - want).max() <= LIMIT[dtype] * np.abs(want).max()
 
 
+# A slot's two lengths: the first half of a key head's query heads sees
+# the keys under the first, the second half those under the second.
+TWO_LENGTHS = {
+    "one and a few": [[1, 9]],
+    "either side of a block's edge": [[BLOCK - 3, BLOCK + 1]],
+    "a block apart": [[BLOCK, 2 * BLOCK]],
+    "more than a block apart": [[5, 3 * BLOCK]],
+    "the same": [[300, 300]],
+    "mixed": [[1, 2 * BLOCK + 7], [BLOCK + 4, BLOCK + 8], [3 * BLOCK - 4,
+                                                          3 * BLOCK],
+              [1, 1], [2 * BLOCK - 4, 2 * BLOCK], [700, 9]],
+}
+
+
+@pytest.mark.parametrize("lengths", TWO_LENGTHS.values(),
+                         ids=TWO_LENGTHS.keys())
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("merged", [False, True], ids=["dense", "merged"])
+def test_two_lengths_a_slot_are_two_groups_of_its_query_heads(merged, dtype,
+                                                              lengths):
+    """32 query heads on 4 key heads, 8 a key head: the first 4 of each
+    see the slot's first length and the last 4 its second, as two calls
+    of 16 heads with a length each would; nothing past the longer one
+    is read."""
+    heads, kv_heads, layers, layer = 32, 4, 2, 1
+    b, s = len(lengths), 3 * BLOCK
+    rng = np.random.default_rng(b)
+    q = jnp.asarray(rng.normal(size=(b, heads, D)), dtype)
+    k, v = (jnp.asarray(rng.normal(size=(layers, b, s, kv_heads, D)), dtype)
+            for _ in range(2))
+    lengths = jnp.asarray(lengths, jnp.int32)
+    by_group = q.reshape(b, kv_heads, 2, 4, D)
+    want = jnp.stack([llama._cached_attention(
+        None, by_group[:, :, j].reshape(b, 1, 16, D), k[layer], v[layer],
+        lengths[:, j, None] - 1)[:, 0].reshape(b, kv_heads, 4, D)
+        for j in range(2)], 2).reshape(q.shape)
+    unread = (jnp.arange(layers)[:, None, None] != layer) | (
+        jnp.arange(s)[None, None, :] >= lengths.max(-1)[None, :, None])
+    k, v = (jnp.where(unread[..., None, None], jnp.nan, x) for x in (k, v))
+    if merged:
+        k, v = (x.reshape(layers, b, s, kv_heads * D) for x in (k, v))
+    got = jax.jit(attention.decode_attention, static_argnames="interpret")(
+        q, k, v, jnp.int32(layer), lengths, interpret=True)
+    assert got.shape == want.shape and got.dtype == dtype
+    got, want = (np.asarray(x, np.float32) for x in (got, want))
+    assert np.isfinite(got).all()
+    assert np.abs(got - want).max() <= LIMIT[dtype] * np.abs(want).max()
+    # Off the TPU the plain path, a group of heads a query position.
+    k, v = (jnp.nan_to_num(x) for x in (k, v))
+    plain = attention.decode_attention(q, k, v, jnp.int32(layer), lengths)
+    assert np.abs(np.asarray(plain, np.float32) - want).max() \
+        <= LIMIT[dtype] * np.abs(want).max()
+
+
 def test_off_the_tpu_it_is_the_plain_path_on_the_sliced_layer():
     """No interpreter asked for: `llama._cached_attention` itself, to
     the bit, on both layouts (finite stacks: the plain path reads the

@@ -298,19 +298,28 @@ def test_a_block_engines_spans_carry_its_sums(ring):
     blocks = _ring("engine.consume_block")
     summed = {name: sum(s["attrs"].get(name, 0) for s in blocks)
               for name in ("slot_forwards_denoise", "slot_forwards_commit",
-                           "slot_forwards", "tokens_fixed", "blocks_emitted",
-                           "kept", "discarded", "slot_steps")}
-    for name in ("slot_forwards_denoise", "slot_forwards_commit",
+                           "slot_forwards_fused", "slot_forwards",
+                           "tokens_fixed", "blocks_emitted", "kept",
+                           "discarded", "slot_steps")}
+    for name in ("slot_forwards_denoise", "slot_forwards_fused",
                  "tokens_fixed", "blocks_emitted"):
         assert summed[name] == totals[name] > 0, name
+    # A block's commit rides in the next block's first forward: no
+    # forward of a request's slot fixes nothing.
+    assert summed["slot_forwards_commit"] \
+        == totals["slot_forwards_commit"] == 0
     assert summed["slot_forwards"] == summed["slot_forwards_denoise"] \
         + summed["slot_forwards_commit"]
+    # Every block but a request's first is committed in such a forward,
+    # but for a last block, whose commit is nobody's.
+    assert summed["blocks_emitted"] - 2 * 4 \
+        <= summed["slot_forwards_fused"] <= summed["blocks_emitted"] - 4
     assert summed["kept"] == totals["tokens_kept"] == 4 * 22
     assert summed["kept"] + summed["discarded"] == summed["slot_steps"]
     assert summed["slot_steps"] % cfg.block_length == 0
-    # 2 of a block's 4 positions a denoising forward, a commit behind
-    # two of them: 4/3 tokens a forward but for the requests' ends.
-    assert 1.2 < summed["tokens_fixed"] / summed["slot_forwards"] <= 4 / 3 + .1
+    # 2 of a block's 4 positions a forward, but for a first block that
+    # opens with known positions.
+    assert 1.8 < summed["tokens_fixed"] / summed["slot_forwards"] <= 2
     assert len(_ring("llm.prefill")) == 4
     assert _ring("engine.emitted_block_gap")
     steps = _ring("engine.decode_dispatch")

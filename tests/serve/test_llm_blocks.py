@@ -4,8 +4,10 @@ at equal the plain reference's `generate` for prompts of every length
 modulo the block and one shorter than a block, answers that end inside
 a block, a stop id inside a block, 1, 2 and 4 denoising steps, several
 requests at different phases in their slots, one forward a dispatch and
-several; what the host counts; the prefix cache; and the stream's
-events."""
+several; a slot used before and a region's last rows; the step's
+program by hand (a commit rides in the next block's first forward, and
+a committed row is never written again); what the host counts; the
+prefix cache; and the stream's events."""
 
 import dataclasses
 import threading
@@ -142,14 +144,78 @@ def test_what_the_host_counts(params):
     finally:
         engine.stop()
     # Three blocks handed over: 2 denoising forwards each, 2 positions
-    # fixed a forward, and a commit behind each but the last, whose
-    # request ended with it (the block in flight then is nobody's).
+    # fixed a forward; the first forward of the second and of the third
+    # block commits the block before it, and no forward only commits
+    # (the last block's request ended with it: the block in flight then
+    # is nobody's).
     assert totals["blocks_emitted"] == 3 and totals["tokens_kept"] == 12
     assert totals["slot_forwards_denoise"] == 6
     assert totals["tokens_fixed"] == 12
-    assert totals["slot_forwards_commit"] == 2
+    assert totals["slot_forwards_commit"] == 0
+    assert totals["slot_forwards_fused"] == 2
     assert totals["tokens_kept"] + totals["tokens_discarded"] \
         >= 4 * totals["blocks_emitted"]
+
+
+def test_the_program_never_writes_a_committed_row_again(params):
+    """The step's program driven by hand, a forward a dispatch: a slot
+    prefilled with 8 tokens, its first block seeded with 2 known. What
+    the cache holds below the slot's length after a forward is there to
+    the bit after every later one (the prefix cache may have hashed and
+    copied it), the length grows by a block in the very forward that
+    fixes the next block's first positions, and no forward of the slot
+    fixes nothing."""
+    engine = _engine(params, slots=2)
+    b, prompt = CFG.block_length, _prompt(10)
+    tokens = np.zeros((1, 16), np.int32)
+    tokens[0, :8] = prompt[:8]
+    engine.cache, _ = engine._run_prefill(tokens, 1, 8, 0, 16)
+    engine._temps_arr = np.zeros(2, np.float32)
+    blocks, opens = np.zeros((2, b), np.int32), np.ones((2, b), bool)
+    blocks[0, :2], opens[0, :2] = prompt[8:], False
+    engine._set_carries(engine._run_seed(
+        np.asarray([1, 2], np.int32), blocks, opens,
+        np.asarray([8, 0], np.int32)))
+    seen, answer = [], []
+    for _ in range(7):
+        out, _ = engine._dispatch_decode()
+        row = np.asarray(out)[1, 0]
+        emitted, committed, fixed = row[2 * b:]
+        if emitted:
+            answer += zip(row[:b].tolist(), row[b:2 * b].tolist())
+        seen.append((int(engine._dev_lengths[1]), int(committed), int(fixed),
+                     [np.asarray(x)[:, 1] for x in
+                      jax.tree.leaves(engine.cache)]))
+    assert [(length, committed, fixed) for length, committed, fixed, _
+            in seen] == [(8, 0, 2), (12, 1, 2), (12, 0, 2), (16, 1, 2),
+                         (16, 0, 2), (20, 1, 2), (20, 0, 2)]
+    for i, (length, _, _, rows) in enumerate(seen):
+        for _, _, _, later in seen[i + 1:]:
+            for old, new in zip(rows, later):
+                np.testing.assert_array_equal(new[:, :length],
+                                              old[:, :length])
+    assert answer[2:] == _want(params, prompt, 14)
+
+
+def test_a_slot_a_longer_request_used_serves_a_shorter_one(params):
+    """One slot: a request of 21 prompt tokens and 20 of answer leaves
+    its rows and its last block, which awaited a commit, behind; the
+    request admitted into the slot next has a prompt shorter than a
+    block (nothing to prefill) and answers as the reference does; so
+    does the one after it, whose rows end where the region does."""
+    engine = _engine(params, slots=1, decode_steps=2)
+    try:
+        for n_prompt, max_tokens, kept in [(21, 20, 20), (2, 11, 11),
+                                           (50, 40, 14)]:
+            prompt = _prompt(n_prompt, seed=5)
+            got = engine.generate(prompt, SamplingParams(
+                max_tokens=max_tokens), with_steps=True)
+            # (The third runs out of rows: blocks at 48, 52, 56 and 60
+            # of 64 are the last that fit.)
+            assert got == _want(params, prompt, max_tokens)[:kept]
+            assert len(got) == kept
+    finally:
+        engine.stop()
 
 
 def test_a_prompt_seen_before_is_served_from_the_prefix_cache(params):
