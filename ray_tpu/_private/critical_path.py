@@ -42,7 +42,9 @@ request path every instruction between "replica produced the result"
 and "client read the response" is paid at GIL-scheduling granularity,
 so 20 µs of inline folding measured as ~70 µs of added latency — while
 an append costs ~0.15 µs and the fold runs when the loop would
-otherwise be idle.
+otherwise be idle. The folder thread records its own beat's lateness
+(``process.wake_late``, :func:`_folder_loop`): whether the process
+let a thread run that wanted to.
 
 Every record reaches the flight recorder's ring. Only a *request*
 opens an accumulator: the proxy's envelope calls :func:`open_request`
@@ -315,7 +317,7 @@ class Span:
     """One timed stretch of work on one thread; see :func:`span`."""
 
     __slots__ = ("name", "trace_id", "route", "attrs", "id", "parent",
-                 "t0", "_p0", "_ann")
+                 "t0", "dur_s", "_p0", "_ann")
 
     def __init__(self, name: str, trace_id: str, route: str,
                  attrs: Optional[dict]):
@@ -350,7 +352,9 @@ class Span:
         return self
 
     def __exit__(self, *exc) -> None:
-        dur_s = clock() - self._p0
+        # Kept on the span: whoever opened it reads what it took
+        # without a pair of clock reads of its own.
+        self.dur_s = dur_s = clock() - self._p0
         if self._ann is not None:
             self._ann.__exit__(*exc)
         _tls.stack.pop()
@@ -365,6 +369,7 @@ class _NullSpan:
     """What :func:`span` hands out while the recorder is off."""
 
     __slots__ = ()
+    dur_s = 0.0
 
     def set(self, **attrs) -> None:
         pass
@@ -449,7 +454,19 @@ def _ensure_folder() -> None:
 
 
 def _folder_loop() -> None:
+    """Fold every `_FOLD_PERIOD_S`, and record each beat's lateness as
+    the thin record ``process.wake_late``: the beat's wall time less
+    the sleeps it asked for, clamped at 0. That is how long a Python
+    thread of this process that wanted to run, or to be done, was kept
+    from it (its own folding included), ten readings a second with no
+    floor. Beside a long stretch of another thread it tells the two
+    cases apart: a lateness near 0 says that thread blocked with the
+    interpreter released (a sleep, a lock, a full arena), one of the
+    stretch's own length that the process stood still as a whole (a
+    thread that held the interpreter, the machine)."""
+    top = clock()
     while True:
+        asked = _FOLD_PERIOD_S
         time.sleep(_FOLD_PERIOD_S)
         try:
             # Fold in small slices with a real sleep between them: one
@@ -459,9 +476,14 @@ def _folder_loop() -> None:
             # amplification the deferral exists to remove. Sliced, the
             # folder's cost converges to its true CPU share.
             while flush(_FOLD_SLICE) == _FOLD_SLICE:
+                asked += 0.002
                 time.sleep(0.002)
         except Exception:
             pass  # diagnostics must never take the process down
+        now = clock()
+        record_stage(None, "process.wake_late",
+                     max(0.0, now - top - asked))
+        top = now
 
 
 # Records folded per GIL slice in the folder thread. ~200 folds cost
@@ -759,21 +781,6 @@ def attribution_vectors() -> Dict[str, Dict[str, Dict[str, float]]]:
             "p50": stat.quantile(0.5), "p99": stat.quantile(0.99),
             "count": stat.total, "sum": stat.sum}
     return out
-
-
-def stage_spans_for_trace(trace_id: str) -> List[dict]:
-    """The recorded stages for one trace (open or finished) — what
-    ``export_spans`` merges into the OTLP view as synthetic child
-    spans so a trace's stage anatomy rides the same trace id."""
-    flush()
-    with _lock:
-        tr = _traces.get(trace_id)
-        if tr is not None:
-            return _stage_dicts(tr[0])
-        for entry in _finished:
-            if entry["trace_id"] == trace_id:
-                return _stage_dicts(entry["stages"])
-    return []
 
 
 def finished_waterfalls() -> List[dict]:

@@ -175,6 +175,36 @@ _TOP_K_MAX = 64
 # 35 ms and one of 28 MiB in 5 (v5e, jax 0.9.0; PERF.md section 6, PR 28).
 _D2H_ARRAY_BYTES = 16 << 20
 
+# A pass of the loop whose host time reaches this leaves a stall record
+# under the name of the tile that held most of it (`_end_pass`). Set
+# from the longest healthy pass of each serve cell, to stand at least
+# twice over the longest of them, 187 ms: a pass that a hold of the
+# interpreter fell into, the cyclic collector's gen-2 pass for one
+# (v5e; PERF.md section 6, PR 53; ISSUE 53's 0.25 s stood under twice
+# that).
+_STALL_S = 0.5
+_STALL_NAMES = {
+    "engine.prefix_admit": "engine.loop_stall.prefix_admit",
+    "engine.admit_wave": "engine.loop_stall.admit_wave",
+    "engine.decode_dispatch": "engine.loop_stall.decode_dispatch",
+    "engine.consume_block": "engine.loop_stall.consume_block",
+    "engine.first_tokens": "engine.loop_stall.first_tokens",
+    "other": "engine.loop_stall.other",
+}
+# `engine.prefix_admit`'s attributes: the rise across the span of these
+# totals (`forced` counts every read-back waited for inside it, be it
+# for an evicted block's bytes or for the cap on pending ones).
+_PREFIX_ADMIT = {
+    "created": "kv_blocks_read_back", "evicted": "kv_blocks_evicted",
+    "offloaded": "kv_blocks_offloaded", "forced": "kv_readbacks_forced",
+    "backpressure_waits": "kv_offload_backpressure_waits",
+    "put_us": "kv_offload_us",
+}
+# The process's count of creates that found the shm arena full
+# (`shm_plane.maybe_put`).
+_SHM_BACKPRESSURE_WAITS = perf_stats.counter(
+    "object_create_backpressure_waits")
+
 
 @dataclasses.dataclass
 class SamplingParams:
@@ -317,8 +347,16 @@ class LLMEngine:
             "admit_waves_behind_block", "slot_steps_stale", "admissions",
             "kv_blocks_read_back", "kv_bytes_read_back",
             "kv_readbacks_deferred", "kv_readbacks_forced",
+            "kv_blocks_evicted", "kv_blocks_offloaded",
+            "kv_offload_backpressure_waits", "kv_offload_us",
             "keys_cached", "keys_attended", "keys_read",
-            "blocks_behind_wave", "blocks_plain"), 0)
+            "blocks_behind_wave", "blocks_plain", "loop_passes",
+            "loop_host_us", "loop_stalls", "loop_stall_us"), 0)
+        # The pass of the loop that is under way: what each of its
+        # tiles has taken so far, by the span's name, and what it spent
+        # waiting for a result or a request (`_end_pass`).
+        self._pass: Dict[str, float] = {}
+        self._pass_waited = 0.0
         # What the model counts while it runs (nothing, for most) joins
         # the totals under the model's own names, which one abstract
         # evaluation of a decode step gives. The same evaluation says
@@ -866,7 +904,13 @@ class LLMEngine:
                 # 0 for a model that names none); the blocks handed over
                 # behind another, those with a wave's prefills in front
                 # of them and those without (one `engine.block_gap.*`
-                # record each); and what the model
+                # record each); what the prefix cache's admissions
+                # evicted, what of it the warm tier took, the waits for
+                # room there and the microseconds in its `maybe_put`;
+                # the loop's passes that did work and their host time
+                # (one `engine.loop_host` record each), and those of
+                # them that reached `_STALL_S` (one
+                # `engine.loop_stall.*` each); and what the model
                 # itself counted in its decode blocks, under its names
                 # (an expert layer's `pairs_held`, `pairs_routed`,
                 # `pair_overflows`, `experts_touched`,
@@ -889,7 +933,9 @@ class LLMEngine:
         `engine.consume_block`, `engine.idle_wait`. Between two blocks
         handed over one behind the other lies one thin record,
         `engine.block_gap.wave` or `engine.block_gap.plain`
-        (`_consume_block`).
+        (`_consume_block`), and at the end of every pass that did work
+        one more, `engine.loop_host`, what the pass cost the host
+        (`_end_pass`).
 
         A wave only dispatches, so the decode pipeline runs through it:
         wave (prefills and the sample program, queued behind block N in
@@ -898,20 +944,67 @@ class LLMEngine:
         self._temps_arr = np.zeros(self.n_slots, np.float32)
         self._topks_arr = np.zeros(self.n_slots, np.int32)
         while self._running.is_set():
+            t_pass = critical_path.clock()
             admitted = self._admit()
-            if not self._active.any():
+            if self._active.any():
+                self._decode_once()
+            else:
                 # Drop any in-flight block for fully-retired slots.
                 self._flush_pending()
                 if not admitted:
-                    with critical_path.span("engine.idle_wait"):
+                    with critical_path.span("engine.idle_wait") as idle:
                         self._finish_readbacks()
                         try:
                             req = self._queue.get(timeout=0.05)
                             self._queue.put(req)
                         except queue.Empty:
                             pass
-                continue
-            self._decode_once()
+                    self._pass_waited += idle.dur_s
+            self._end_pass(t_pass)
+
+    def _tile(self, sp) -> None:
+        """`sp`, closed, was a tile of the pass under way (nothing,
+        with the recorder off: its spans take no time)."""
+        if sp.dur_s:
+            self._pass[sp.name] = self._pass.get(sp.name, 0.0) + sp.dur_s
+
+    def _end_pass(self, t_pass: float) -> None:
+        """The end of a pass of `_loop` that began at `t_pass`. A pass
+        that did work (a wave, a decode block) leaves one thin record,
+        `engine.loop_host`: its wall time less what it spent waiting
+        for a result or a request, which is the two fetches' wait for
+        the device and `engine.idle_wait`. Everything else is the
+        host's, on purpose: dispatches, hand-overs, a forced
+        read-back's copy, `maybe_put`'s sleeps, a wait for a lock. With
+        the profiler off it is the reading of what the loop costs.
+
+        A pass whose host time reaches `_STALL_S` leaves a second
+        record of that duration, named by the tile that held most of
+        it: `engine.loop_stall.prefix_admit` where that child held most
+        of the wave, `.admit_wave`, `.decode_dispatch`,
+        `.consume_block`, `.first_tokens`, or `.other` for what lies
+        between the tiles. Beside it in the ring lie the tiles' own
+        spans and the beats of `process.wake_late`, which say whether
+        the loop blocked or the process stood still."""
+        tiles, waited = self._pass, self._pass_waited
+        self._pass_waited = 0.0
+        if not tiles:
+            return  # it only idled
+        host_s = max(0.0, critical_path.clock() - t_pass - waited)
+        critical_path.record_stage(None, "engine.loop_host", host_s)
+        totals, host_us = self._totals, round(host_s * 1e6)
+        totals["loop_passes"] += 1
+        totals["loop_host_us"] += host_us
+        if host_s >= _STALL_S:
+            inner = tiles.pop("engine.prefix_admit", 0.0)
+            tiles["other"] = host_s - sum(tiles.values())
+            top = max(tiles, key=tiles.get)
+            if top == "engine.admit_wave" and 2 * inner > tiles[top]:
+                top = "engine.prefix_admit"
+            critical_path.record_stage(None, _STALL_NAMES[top], host_s)
+            totals["loop_stalls"] += 1
+            totals["loop_stall_us"] += host_us
+        tiles.clear()
 
     def _serve_bucket(self, t_real: int) -> int:
         """Smallest compiled bucket that fits `t_real` tokens. The old
@@ -930,7 +1023,9 @@ class LLMEngine:
         if self._queue.empty() or not self._free_slots:
             return False
         with critical_path.span("engine.admit_wave") as wave:
-            return self._admit_wave(wave)
+            admitted = self._admit_wave(wave)
+        self._tile(wave)
+        return admitted
 
     def _admit_wave(self, wave) -> bool:
         """One wave of admission. It dispatches and returns: the
@@ -1017,9 +1112,18 @@ class LLMEngine:
         # prefill; `_decode_once` finishes it on the host while a decode
         # block runs. Safe ordering: the slot cannot be admitted again
         # before a LATER wave, so the KV bytes being gathered are this
-        # request's prefill output.
-        for req, slot, _logits, chain in staged:
-            self._prefix_admit(req, slot, chain)
+        # request's prefill output. The one stretch of a wave that may
+        # wait (a forced read-back, the warm tier's back-pressure) has
+        # a span of its own, with what it did as the rise of the totals
+        # across it.
+        if self.prefix_cache is not None:
+            with critical_path.span("engine.prefix_admit") as sp:
+                before = [totals[name] for name in _PREFIX_ADMIT.values()]
+                for req, slot, _logits, chain in staged:
+                    self._prefix_admit(req, slot, chain)
+                sp.set(**{attr: totals[name] - was for (attr, name), was
+                          in zip(_PREFIX_ADMIT.items(), before)})
+            self._tile(sp)
         return True
 
     def _dispatch_first_tokens(self, staged):
@@ -1070,7 +1174,7 @@ class LLMEngine:
         with critical_path.span("engine.decode_dispatch", active=active,
                                 n_slots=self.n_slots,
                                 keys_reserved=self.n_slots * self.max_seq,
-                                **attrs):
+                                **attrs) as dispatch:
             prev = self._pending_block
             next_tokens, counts = self._dispatch_decode()
             self._pending_block = (next_tokens, [
@@ -1082,6 +1186,7 @@ class LLMEngine:
             self._finish_readbacks(
                 self.max_seq // self.block_tokens,
                 prev[0] if prev is not None else None)
+        self._tile(dispatch)
         self._totals["decode_steps"] += self.decode_steps
         self._totals["active_slot_steps"] += active * self.decode_steps
         if prev is None:
@@ -1109,8 +1214,10 @@ class LLMEngine:
     def _fetch_tokens(self, block):
         """A decode block's sampled tokens to the host: the wait for
         the block to finish on the device."""
-        with critical_path.span("engine.token_fetch"):
-            return np.asarray(block)
+        with critical_path.span("engine.token_fetch") as fetch:
+            tokens = np.asarray(block)
+        self._pass_waited += fetch.dur_s
+        return tokens
 
     def _consume_block(self, next_host, owners, counts=(),
                        behind_wave=False):
@@ -1159,6 +1266,7 @@ class LLMEngine:
             sp.set(kept=kept, discarded=discarded, stale=stale,
                    slot_steps=next_host.size, behind_wave=int(behind_wave),
                    **counted)
+        self._tile(sp)
         self._record_hand_over(behind_wave)
 
     def _record_hand_over(self, behind_wave):
@@ -1183,7 +1291,8 @@ class LLMEngine:
         if pending is None:
             return
         firsts_dev, staged = pending
-        with critical_path.span("engine.token_fetch", first_tokens=1):
+        with critical_path.span("engine.token_fetch",
+                                first_tokens=1) as fetch:
             firsts = np.asarray(firsts_dev)
             t_host = critical_path.clock()
             with critical_path.span("engine.first_tokens",
@@ -1205,6 +1314,8 @@ class LLMEngine:
                         ended += 1
                 self._totals["tokens_kept"] += len(staged)
                 sp.set(ended=ended)
+        self._tile(sp)
+        self._pass_waited += fetch.dur_s - sp.dur_s  # the wait alone
 
     def _finished(self, req: _Request, token: int) -> bool:
         if token in req.params.stop_token_ids:
@@ -1460,7 +1571,11 @@ class LLMEngine:
         """Evicted blocks leave the host store but persist as shm-plane
         objects (spill-backed, charged to the admitting tenant's plane
         quota) — a later hit restores bytes instead of recomputing."""
-        plane = self._shm_plane() if evicted else None
+        if not evicted:
+            return
+        plane, totals = self._shm_plane(), self._totals
+        totals["kv_blocks_evicted"] += len(evicted)
+        waits = _SHM_BACKPRESSURE_WAITS.value
         for e in evicted:
             rb = self._readback_of.get(e.block_id)
             if rb is not None and plane is not None:
@@ -1472,12 +1587,19 @@ class LLMEngine:
             payload = self._kv_store.pop(e.block_id, None)
             if plane is None or payload is None:
                 continue
+            t_put = critical_path.clock()
             try:
                 if plane.maybe_put(self._shm_object_id(e.key), payload,
                                    timeout=0.1):
                     self._c_shm_offloads.inc()
+                    totals["kv_blocks_offloaded"] += 1
             except Exception:
                 pass  # warm tier is best-effort; the cold path recomputes
+            totals["kv_offload_us"] += round(
+                (critical_path.clock() - t_put) * 1e6)
+        # Each is a full arena that `maybe_put` slept 10 ms on.
+        totals["kv_offload_backpressure_waits"] += \
+            _SHM_BACKPRESSURE_WAITS.value - waits
 
     # -- multi-model ----------------------------------------------------
 
@@ -1854,6 +1976,7 @@ class _BlockEngine(LLMEngine):
                    slot_forwards_fused=fused, slot_forwards=forwards,
                    tokens_fixed=fixed,
                    blocks_emitted=blocks, **counted)
+        self._tile(sp)
         self._record_hand_over(behind_wave)
 
 

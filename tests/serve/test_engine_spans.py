@@ -5,7 +5,11 @@ profiler's trace carries the spans under their own names on the ring's
 clock, and the proxy's envelope gives `front.ttft_self`. PR 36: the gap
 between two blocks handed over one behind the other is a thin record
 by kind (`engine.block_gap.*`), a stream's hand-over lag is sampled
-(`stream.wake`), and nothing is recorded a token.
+(`stream.wake`), and nothing is recorded a token. PR 53: every pass of
+the loop that did work says what it cost the host (`engine.loop_host`),
+a long one names the tile it was in (`engine.loop_stall.*`), the wave's
+closing stretch has its span (`engine.prefix_admit`), and the recorder's
+own thread says whether the process let it run (`process.wake_late`).
 
 Kept tier-1-sized: one tiny model, a few dozen requests.
 """
@@ -20,14 +24,17 @@ import time
 
 import pytest
 
+import numpy as np
+
 import jax
 import jax.numpy as jnp
 
 import ray_tpu
 from ray_tpu import serve
-from ray_tpu._private import critical_path, flight_recorder
+from ray_tpu._private import critical_path, flight_recorder, perf_stats
 from ray_tpu._private.config import ray_config
 from ray_tpu.models.llama import LlamaConfig, init_params
+from ray_tpu.serve import llm
 from ray_tpu.serve.llm import LLMDeployment, LLMEngine, SamplingParams
 
 _TINY = LlamaConfig(vocab_size=64, dim=16, n_layers=1, n_heads=2,
@@ -39,7 +46,8 @@ _LOOP_SPANS = {"engine.admit_wave", "engine.decode_dispatch",
                "engine.token_fetch", "engine.consume_block",
                "engine.idle_wait"}
 _WAVE_SPANS = {"engine.flush_pending", "engine.prefix_copy_in",
-               "engine.prefill_dispatch", "engine.sample_dispatch"}
+               "engine.prefill_dispatch", "engine.sample_dispatch",
+               "engine.prefix_admit"}
 # A wave's first tokens reach their clients behind the next decode
 # dispatch (PR 31): the wait is an `engine.token_fetch` of its own and
 # the delivery its child.
@@ -50,6 +58,10 @@ _READBACK = "engine.prefix_readback"
 # The thin records between two blocks (PR 36): durations, not spans of
 # the loop's tiling (each lies over a whole turn of the loop).
 _GAP_WAVE, _GAP_PLAIN = "engine.block_gap.wave", "engine.block_gap.plain"
+# A pass's host time and a long pass's second record (PR 53): thin
+# records too, made at the end of a pass under no span.
+_LOOP_HOST, _STALL = "engine.loop_host", "engine.loop_stall."
+_WAKE_LATE = "process.wake_late"
 
 
 @pytest.fixture(scope="module")
@@ -105,7 +117,8 @@ def _ring(*names):
 def _engine_spans():
     return [s for s in flight_recorder.local_snapshot()["spans"]
             if s["stage"].startswith("engine.")
-            and s["stage"] not in (_GAP_WAVE, _GAP_PLAIN)]
+            and s["stage"] not in (_GAP_WAVE, _GAP_PLAIN, _LOOP_HOST)
+            and not s["stage"].startswith(_STALL)]
 
 
 def _union(intervals):
@@ -184,12 +197,15 @@ def test_spans_tile_the_loop_and_totals_add_up(params, ring):
     assert max(s["dur_s"] for s in by_name["engine.flush_pending"]) < 1e-3
     behind = sum(s["attrs"]["behind_block"] for s in admitting)
     assert totals["admit_waves_behind_block"] == behind >= 4
-    # A read-back is dispatched in a wave and finished under a decode
-    # dispatch (or the idle wait), so the loop's tiling covers both.
+    # A read-back is dispatched in a wave's closing stretch and finished
+    # under a decode dispatch (or the idle wait), so the loop's tiling
+    # covers both.
     shadows = {s["id"] for name in ("engine.decode_dispatch",
                                     "engine.idle_wait")
                for s in by_name.get(name, ())}
-    dispatched = [s for s in by_name[_READBACK] if s["parent"] in waves]
+    closing = {s["id"]: s for s in by_name["engine.prefix_admit"]}
+    assert len(closing) == len(admitting)
+    dispatched = [s for s in by_name[_READBACK] if s["parent"] in closing]
     finished = [s for s in by_name[_READBACK] if s["parent"] in shadows]
     assert len(dispatched) + len(finished) == len(by_name[_READBACK])
     assert len(dispatched) == 10 and finished
@@ -263,6 +279,25 @@ def test_spans_tile_the_loop_and_totals_add_up(params, ring):
         == blocks * engine._block_nbytes
     assert totals["kv_readbacks_deferred"] == 10
     assert totals["kv_readbacks_forced"] == 0
+    # The closing stretch says what it did: the blocks it created are
+    # its read-backs', and a cache of 256 MB evicts nothing.
+    for s in closing.values():
+        assert s["attrs"]["created"] == sum(
+            d["attrs"]["blocks"] for d in dispatched
+            if d["parent"] == s["id"])
+        assert not any(s["attrs"][k] for k in (
+            "evicted", "offloaded", "forced", "backpressure_waits",
+            "put_us")), s
+    assert totals["kv_blocks_evicted"] == 0
+    # One `engine.loop_host` a pass that did work, all of them far
+    # under the threshold of a stall.
+    passes = _ring(_LOOP_HOST)
+    assert totals["loop_passes"] == len(passes) >= len(steps)
+    assert abs(totals["loop_host_us"]
+               - 1e6 * sum(s["dur_s"] for s in passes)) <= len(passes)
+    assert totals["loop_stalls"] == totals["loop_stall_us"] == 0
+    assert not [s for s in flight_recorder.local_snapshot()["spans"]
+                if s["stage"].startswith(_STALL)]
     # After stop() nothing is on its way: every created block's payload
     # is in the host store, in the shape the copy-in program takes.
     assert not engine._readbacks and not engine._readback_of
@@ -589,3 +624,275 @@ def test_front_ttft_self_from_the_proxys_envelope(params, ring):
     assert {s["trace_id"] for s in derived} >= {"front-1", "front-2"}
     vec = critical_path.attribution_vectors()["/llm"]
     assert vec["front.ttft_self"]["count"] >= 2
+
+
+# -- what a pass cost the host (PR 53) ------------------------------------
+
+
+class _SlowTokens:
+    """A decode block's tokens whose copy to the host takes 20 ms: the
+    wait for the device, on a backend that has none."""
+
+    def __init__(self, tokens):
+        self.tokens = tokens
+
+    def is_ready(self):
+        return False
+
+    def __array__(self, dtype=None, copy=None):
+        time.sleep(0.02)
+        return np.asarray(self.tokens)
+
+
+class _FakePlane:
+    """A warm tier whose `maybe_put` takes `waits[i]` seconds for the
+    i-th block (0 from there on), counting a back-pressure wait for
+    every 10 ms as `shm_plane.maybe_put` does, and takes the block
+    unless it waited."""
+
+    def __init__(self, waits):
+        self.waits = list(waits)
+        self.put = 0
+        self.counter = perf_stats.counter("object_create_backpressure_waits")
+
+    def maybe_put(self, object_id, value, timeout):
+        wait = self.waits.pop(0) if self.waits else 0.0
+        self.put += 1
+        if wait:
+            self.counter.inc(round(wait / 0.01))
+            time.sleep(wait)
+        return not wait
+
+    def get(self, object_id):
+        return False, None
+
+
+def _evicting(monkeypatch, engine_cls, params, waits, **overrides):
+    """An engine of two slots whose prefix cache holds two 4-token
+    blocks, so that every prompt of 9 tokens evicts the two before it
+    into a `_FakePlane`; `overrides` replace methods of the engine."""
+    monkeypatch.setattr(ray_config, "llm_prefix_cache_bytes", 2 * 512)
+    monkeypatch.setattr(llm, "_STALL_S", 0.25)  # whatever the chip's is
+    engine = type("_Engine", (engine_cls,), overrides)(
+        _TINY, params, max_batch_size=2, max_seq_len=64)
+    assert engine._block_nbytes == 512
+    plane = _FakePlane(waits)
+    engine._shm_plane = lambda: plane
+    engine.warmup(16)
+    return engine, plane
+
+
+def _serve(engine, n, max_tokens=12):
+    """`n` requests of 9 distinct tokens, one after the other, so that
+    each is a wave of its own."""
+    for i in range(n):
+        prompt = [(11 * i + j) % 60 + 1 for j in range(9)]
+        assert len(engine.generate(
+            prompt, SamplingParams(max_tokens=max_tokens))) == max_tokens
+    _wait_idle(engine, n)
+    engine.stop()
+    return engine.metrics()["totals"]
+
+
+def _stalls():
+    return [s for s in flight_recorder.local_snapshot()["spans"]
+            if s["stage"].startswith(_STALL)]
+
+
+def test_a_pass_leaves_out_the_wait_for_the_device_and_keeps_the_hosts(
+        params, ring, monkeypatch):
+    """`engine.loop_host` is the pass less its waits: a fetch that waits
+    20 ms for its block is not in it, a `maybe_put` that sleeps 40 ms
+    is. One record a pass that did work, and the totals count them."""
+
+    def slow_fetch(self):
+        tokens, counts = LLMEngine._dispatch_decode(self)
+        return _SlowTokens(tokens), counts
+
+    engine, plane = _evicting(monkeypatch, LLMEngine, params,
+                              [0.0, 0.0, 0.04],
+                              _dispatch_decode=slow_fetch)
+    totals = _serve(engine, 4)
+    passes = _ring(_LOOP_HOST)
+    fetches = [s for s in _ring("engine.token_fetch") if not s.get("attrs")]
+    assert len(fetches) >= 30
+    assert min(s["dur_s"] for s in fetches) >= 0.02
+    # The waits are left out: a pass whose fetch took 20 ms cost the
+    # host a fraction of that.
+    durs = sorted(s["dur_s"] for s in passes)
+    assert durs[len(durs) // 2] < 0.01, durs
+    # The sleep is kept: the pass of the wave that offloaded the third
+    # block, and no other, is 40 ms long.
+    assert 0.04 <= durs[-1] < 0.25 and durs[-2] < 0.04, durs[-3:]
+    slow = max(passes, key=lambda s: s["dur_s"])
+    closing = [s for s in _ring("engine.prefix_admit")
+               if s["attrs"]["put_us"] >= 40_000]
+    assert len(closing) == 1
+    assert slow["t0"] <= closing[0]["t0"] and closing[0]["t1"] <= slow["t1"]
+    assert closing[0]["attrs"]["backpressure_waits"] == 4
+    # Three waves evicted two blocks each; the one waited for was not
+    # taken.
+    assert plane.put == totals["kv_blocks_evicted"] == 6
+    assert totals["kv_blocks_offloaded"] == 5
+    assert totals["kv_offload_backpressure_waits"] == 4
+    assert totals["kv_offload_us"] >= 40_000
+    assert totals["loop_passes"] == len(passes)
+    assert abs(totals["loop_host_us"] - 1e6 * sum(durs)) <= len(durs)
+    assert totals["loop_stalls"] == 0 and not _stalls()
+
+
+def test_a_wave_that_waits_for_the_warm_tier_is_a_stall_of_prefix_admit(
+        params, ring, monkeypatch):
+    """One `maybe_put` of 0.3 s: exactly one `engine.loop_stall.*`
+    record, of that length and under `prefix_admit`'s name, beside the
+    `engine.loop_host` of the same pass, and the `engine.prefix_admit`
+    of its wave says what it waited for."""
+    engine, _plane = _evicting(monkeypatch, LLMEngine, params, [0.3])
+    totals = _serve(engine, 3)
+    (stall,) = _stalls()
+    assert stall["stage"] == "engine.loop_stall.prefix_admit"
+    assert 0.3 <= stall["dur_s"] < 0.6
+    assert not stall["trace_id"] and not stall.get("attrs")
+    twin = max(_ring(_LOOP_HOST), key=lambda s: s["dur_s"])
+    assert twin["dur_s"] == stall["dur_s"]
+    closing = _ring("engine.prefix_admit")
+    assert len(closing) == 3
+    (waited,) = [s for s in closing if s["attrs"]["backpressure_waits"]]
+    assert waited["attrs"]["backpressure_waits"] == 30
+    assert 300_000 <= waited["attrs"]["put_us"] < 600_000
+    assert waited["attrs"]["evicted"] == 2 and waited["attrs"]["offloaded"] == 1
+    assert waited["attrs"]["created"] == 2
+    assert stall["t0"] <= waited["t0"] and waited["t1"] <= stall["t1"]
+    for attr, total in llm._PREFIX_ADMIT.items():
+        assert sum(s["attrs"][attr] for s in closing) == totals[total], attr
+    assert totals["loop_stalls"] == 1
+    assert abs(totals["loop_stall_us"] - 1e6 * stall["dur_s"]) <= 1
+
+
+class _Once:
+    """Called, it sleeps 0.3 s: the first time after `arm()`."""
+
+    def __init__(self):
+        self.left = 0
+
+    def arm(self):
+        self.left = 1
+
+    def __call__(self):
+        if self.left:
+            self.left = 0
+            time.sleep(0.3)
+
+
+def _slow(name, sleep, n_tokens=None):
+    """`LLMEngine`'s method `name` behind `sleep()`; `_finished` only
+    for a request of `n_tokens` tokens (its first is judged in
+    `engine.first_tokens`, the others in `engine.consume_block`)."""
+
+    def call(self, *args):
+        if n_tokens is None or len(args[0].tokens) == n_tokens:
+            sleep()
+        return getattr(LLMEngine, name)(self, *args)
+    return {name: call}
+
+
+@pytest.mark.parametrize("tile, method, n_tokens", [
+    ("consume_block", "_finished", 5),
+    ("first_tokens", "_finished", 1),
+    ("decode_dispatch", "_run_decode", None),
+    ("admit_wave", "_run_prefill", None),
+    ("other", "_record_hand_over", None),
+])
+def test_a_long_pass_names_the_tile_it_was_in(params, ring, monkeypatch,
+                                              tile, method, n_tokens):
+    sleep = _Once()
+    engine, _plane = _evicting(monkeypatch, LLMEngine, params, [],
+                               **_slow(method, sleep, n_tokens))
+    sleep.arm()  # the warm-up is over
+    totals = _serve(engine, 2)
+    (stall,) = _stalls()
+    assert stall["stage"] == _STALL + tile
+    assert 0.3 <= stall["dur_s"] < 0.6
+    assert totals["loop_stalls"] == 1
+
+
+def test_with_the_recorder_off_a_pass_leaves_nothing(params, ring,
+                                                    monkeypatch):
+    monkeypatch.setattr(ray_config, "stage_spans_enabled", False)
+    engine, _plane = _evicting(monkeypatch, LLMEngine, params, [0.3])
+    totals = _serve(engine, 3)
+    time.sleep(0.25)  # two beats of the folder, if it runs
+    assert not flight_recorder.local_snapshot()["spans"]
+    assert not any(totals[k] for k in (
+        "loop_passes", "loop_host_us", "loop_stalls", "loop_stall_us"))
+    assert totals["kv_blocks_evicted"] == 4  # counted, not recorded
+
+
+def test_a_block_engine_records_the_same_names(ring, monkeypatch):
+    """`_BlockEngine` has a `_consume_block` of its own and the loop,
+    the wave and the pass's end are `LLMEngine`'s: the same records
+    under the same names, and its own consume as a stall's tile."""
+    from benchmark.harness.manifest import ROOT, load_json, model_adapter
+
+    file = load_json(ROOT, "benchmark", "configs", "sdar-30b-a3b-serve.json")
+    adapter = model_adapter(file)
+    cfg = adapter.program_config(adapter.debug(file))
+    sleep = _Once()
+    sleep.arm()
+    monkeypatch.setattr(llm, "_STALL_S", 0.25)
+    monkeypatch.setattr(llm._BlockEngine, "_finished",
+                        _slow("_finished", sleep, 6)["_finished"])
+    engine = LLMEngine(cfg, adapter.init(cfg, jax.random.PRNGKey(0)),
+                       max_batch_size=2, max_seq_len=64, decode_steps=2)
+    assert isinstance(engine, llm._BlockEngine)
+    prompts = [[(5 * i + j) % 400 + 1 for j in range(6 + 3 * i)]
+               for i in range(3)]
+    answers = _generate_all(engine, prompts, 14)
+    engine.stop()
+    assert [len(a) for a in answers] == [14] * 3
+    totals = engine.metrics()["totals"]
+    passes = _ring(_LOOP_HOST)
+    assert totals["loop_passes"] == len(passes) \
+        >= len(_ring("engine.decode_dispatch")) > 0
+    closing = _ring("engine.prefix_admit")
+    assert closing and all(
+        set(s["attrs"]) == set(llm._PREFIX_ADMIT) for s in closing)
+    assert sum(s["attrs"]["created"] for s in closing) \
+        == totals["kv_blocks_read_back"] > 0
+    # No warm-up here, so the passes that compiled are stalls too, of
+    # the tiles that dispatch.
+    stalls = [s["stage"] for s in _stalls()]
+    assert stalls.count("engine.loop_stall.consume_block") == 1
+    assert set(stalls) <= {"engine.loop_stall.consume_block",
+                           "engine.loop_stall.admit_wave",
+                           "engine.loop_stall.decode_dispatch"}, stalls
+    assert totals["loop_stalls"] == len(stalls)
+
+
+def _folder_beats(seconds):
+    critical_path.record_stage(None, "test.tick", 0.0)  # the folder is up
+    time.sleep(seconds)
+    return [s["dur_s"] for s in _ring(_WAKE_LATE)]
+
+
+def test_the_folders_beat_says_whether_the_process_stood_still(
+        ring, monkeypatch):
+    """`process.wake_late`: every beat of the recorder's thread, what
+    it took beyond the sleeps it asked for. Near 0 on a quiet process
+    (the median: the box is shared); the length of the hold where
+    something kept the thread from being done, here its own fold."""
+    quiet = _folder_beats(0.65)
+    assert len(quiet) >= 4 and min(quiet) >= 0.0
+    assert sorted(quiet)[len(quiet) // 2] < 0.05, quiet
+    flush, sleep = critical_path.flush, _Once()
+    sleep.arm()
+
+    def held(max_n=None):
+        if threading.current_thread().name == "critical-path-folder":
+            sleep()
+        return flush(max_n)
+
+    monkeypatch.setattr(critical_path, "flush", held)
+    beats = _folder_beats(0.65)
+    assert max(beats) >= 0.2, beats
+    assert sum(b >= 0.2 for b in beats) == 1

@@ -558,11 +558,14 @@ def test_wave_readback_time_does_not_grow_with_blocks(model, monkeypatch):
     critical_path.reset()
     flight_recorder.reset()
     waves = {s["id"] for s in spans if s["stage"] == "engine.admit_wave"}
+    # The wave's half lies in its closing stretch (PR 53).
+    closing = {s["id"] for s in spans if s["stage"] == "engine.prefix_admit"
+               and s["parent"] in waves}
     readbacks = [s for s in spans if s["stage"] == "engine.prefix_readback"]
     in_wave = {s["attrs"]["blocks"]: s["dur_s"] for s in readbacks
-               if s["parent"] in waves}
+               if s["parent"] in closing}
     outside = {s["attrs"]["blocks"]: s["dur_s"] for s in readbacks
-               if s["parent"] not in waves}
+               if s["parent"] not in closing}
     assert set(in_wave) == set(outside) == {1, 25}
     assert outside[25] >= 0.25 and outside[1] >= 0.01
     # The wave's half is a dispatch: far under the copy's 250 ms, and
