@@ -16,8 +16,9 @@ over two kinds of layer, three `sliding` to one `full`
 
 The cache is {"runs": [a dict a run of like layers]}. A `full` run's
 leaves `k` and `v` are rows, [layers, slots, max_seq, kv heads, head
-size], written through `decoder.write_rows` and read through
-`layer_rows`. A `sliding` run's `ring_k` and `ring_v` are *rings*,
+size], written through `block_rows.write_tokens` (`decoder.write_rows`,
+or for a decode step on a TPU the kernel that does the same) and read
+through `layer_rows`. A `sliding` run's `ring_k` and `ring_v` are *rings*,
 [layers, slots, `sliding_window`, kv heads, head size] whatever
 `max_seq` is: the key of position p lies in row p mod
 `sliding_window`, turned before it was written, and which position a
@@ -84,7 +85,7 @@ from ray_tpu.models import decoder, moe
 from ray_tpu.models.serving import (
     KEY_BLOCK as _KEY_BLOCK, Family, attention_init, by_query_blocks, normal,
     own_keys, rotate_pairs)
-from ray_tpu.ops import attention
+from ray_tpu.ops import attention, block_rows
 
 PUBLISHED_LAYER_TYPES = ("sliding", "sliding", "sliding", "full") * 8
 
@@ -243,27 +244,33 @@ def _ring_positions(start_pos, window):
     return newest - (newest - jnp.arange(window)[None, :]) % window
 
 
-def _write_ring(stack, layer, new, start_pos, at):
-    """The call's rows `new` [B, T, ...], at positions `start_pos` [B]
-    on and real up to index `at` ([B] or a scalar), into the ring
-    `stack` [layers, B, window, ...]: row r takes the newest real
-    position congruent to r, if the call has one, and keeps what it
-    held otherwise. One token a row is written where it lies; more
-    rewrite the layer's ring, [B, window, ...]."""
-    window = stack.shape[2]
-    if new.shape[1] == 1:
-        return decoder.write_rows(stack, layer, new, start_pos % window)
+def _write_rings(stacks, layer, news, start_pos, at):
+    """The call's rows (`news`, one [B, T, ...] a ring), at positions
+    `start_pos` [B] on and real up to index `at` ([B] or a scalar), into
+    the rings `stacks` (each [layers, B, window, ...]): row r takes the
+    newest real position congruent to r, if the call has one, and keeps
+    what it held otherwise. One token a row is written where it lies,
+    as any layer's new row is; more rewrite the layer's rings, [B,
+    window, ...]."""
+    window = stacks[0].shape[2]
+    if news[0].shape[1] == 1:
+        return block_rows.write_tokens(stacks, layer, news,
+                                       start_pos % window)
     at = jnp.broadcast_to(at, start_pos.shape)[:, None]
     rows = jnp.arange(window)[None, :]
     index = at - (start_pos[:, None] + at - rows) % window    # [B, window]
-    tail = (1,) * (new.ndim - 2)
-    picked = jnp.take_along_axis(
-        new, jnp.maximum(index, 0).reshape(index.shape + tail), 1)
-    old = decoder.layer_rows(stack, layer, 0, window)
-    ring = jnp.where((index >= 0).reshape(index.shape + tail),
-                     picked.astype(stack.dtype), old)
-    return lax.dynamic_update_slice(
-        stack, ring[None], (layer,) + (0,) * (stack.ndim - 1))
+
+    def rewritten(stack, new):
+        tail = (1,) * (new.ndim - 2)
+        picked = jnp.take_along_axis(
+            new, jnp.maximum(index, 0).reshape(index.shape + tail), 1)
+        old = decoder.layer_rows(stack, layer, 0, window)
+        ring = jnp.where((index >= 0).reshape(index.shape + tail),
+                         picked.astype(stack.dtype), old)
+        return lax.dynamic_update_slice(
+            stack, ring[None], (layer,) + (0,) * (stack.ndim - 1))
+
+    return tuple(rewritten(stack, new) for stack, new in zip(stacks, news))
 
 
 # The kinds of layer whose queries and keys are turned by rotary
@@ -298,8 +305,8 @@ def _mixer(cfg: Cohere2MoeConfig, kind, start_pos, positions, at):
         rows = k_stack.shape[2]
         tr = math.gcd(rows, _KEY_BLOCK)
         if kind == "full":
-            k_stack = decoder.write_rows(k_stack, layer, k, start_pos)
-            v_stack = decoder.write_rows(v_stack, layer, v, start_pos)
+            k_stack, v_stack = block_rows.write_tokens(
+                (k_stack, v_stack), layer, (k, v), start_pos)
             origin = jnp.zeros_like(start_pos)
 
             def fetch(j):
@@ -347,8 +354,8 @@ def _mixer(cfg: Cohere2MoeConfig, kind, start_pos, positions, at):
                 lambda: attention.flash_attention_forward(
                     q, k, v, window=window),
                 lambda: by_query_blocks(attend, t, q, positions)[0])
-            k_stack = _write_ring(k_stack, layer, k, start_pos, at)
-            v_stack = _write_ring(v_stack, layer, v, start_pos, at)
+            k_stack, v_stack = _write_rings(
+                (k_stack, v_stack), layer, (k, v), start_pos, at)
         return out, (k_stack, v_stack), handed
 
     return mixer

@@ -19,12 +19,15 @@ handed in as two functions:
   call as `(stacks, layer)`: the run's stacked leaves (a slot cache's,
   each [layers, slots, max_seq, ...]) and the index of this layer in
   them. Only the mixer writes them, its new rows at its own layer
-  (`write_rows`), and it reads its layer back out of them
-  (`layer_rows`); it returns the stacks. `handed` is what a layer hands
-  up to the layer above beside `x` (a sparse-attention layer's
-  selection), None in a stack that hands nothing on. A mixer that is
-  no attention carries its kind as the attribute `scope` (`"ssm"`),
-  the name of the scope the block opens around it.
+  (`ops.block_rows.write_tokens`: all of the layer's leaves in one
+  call, by `write_rows`' scatter or, for a decode step on a TPU, by a
+  kernel that does what `write_rows` defines), and it reads its layer
+  back out of them (`layer_rows`); it returns the stacks. `handed` is
+  what a layer hands up to the layer above beside `x` (a
+  sparse-attention layer's selection), None in a stack that hands
+  nothing on. A mixer that is no attention carries its kind as the
+  attribute `scope` (`"ssm"`), the name of the scope the block opens
+  around it.
 - ``ffn(h, lp) -> (out [B, S, D], extras)``: `extras` is a pytree the
   layer reports (an expert layer's aux loss and counts), or None. An
   FFN that reads some of its parameters in place names them in its
@@ -170,8 +173,9 @@ def layers(mixer, ffn, cfg, rope, x, stacked, state=None, handed=None, *,
     output: a scanned leaf that the body changes is copied out of its
     stack and into a new one, layer by layer, whole. The mixer is handed
     `(state, layer)`, the stacks and its layer's index in them, writes
-    its new rows there (`write_rows`), reads its layer through
-    `layer_rows`, and returns the stacks. With no state it is handed
+    its new rows there (`block_rows.write_tokens`, which is
+    `write_rows` or its kernel), reads its layer through `layer_rows`,
+    and returns the stacks. With no state it is handed
     None and the scan carries x and `handed` alone.
 
     Parameters ride whole beside the state where the FFN asks for them:
@@ -224,7 +228,16 @@ def write_rows(stack, layer, new, start_pos):
     row, start_pos[row]), cast to the stack's dtype: B x T rows are
     written and no other byte of the stack is read or written. A row
     that would pass S is moved back to end there, as
-    `lax.dynamic_update_slice` does."""
+    `lax.dynamic_update_slice` does.
+
+    A scatter of one window a slot. A prefill's one window is a plain
+    `dynamic-update-slice`; a decode step's B windows the TPU runs one
+    at a time, 2.5 to 4 us each with the select and the bounds check
+    that ride beside them, whatever they hold, which is why a mixer
+    writes through `ops.block_rows.write_tokens`: that is this function
+    a leaf at a time, but for a step of one token a slot on a TPU,
+    where one kernel call rewrites the tiles the rows lie in. This
+    stays the definition the kernel is tested against."""
     rows = jnp.arange(new.shape[0], dtype=start_pos.dtype)
     at = jnp.stack([jnp.full_like(rows, layer), rows, start_pos], -1)
     return lax.scatter(

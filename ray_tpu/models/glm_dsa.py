@@ -59,6 +59,7 @@ from ray_tpu.models.llama import swiglu
 from ray_tpu.models.serving import (
     KEY_BLOCK as _KEY_BLOCK, Family, by_query_blocks as _by_query_blocks,
     rotate_pairs as _rotate_pairs)
+from ray_tpu.ops import block_rows
 from ray_tpu.ops.norms import layer_norm, rms_norm_reference
 
 _INDEX_KEY_EPS = 1e-6
@@ -301,7 +302,8 @@ def _mixer(cfg: GlmDsaConfig, indexer, start_pos, positions):
     the run's stacks of the slot cache, (latent, rotary key) and for
     `full` layers the indexer's key, each [layers, B, S, width], which
     `decoder.layers` carries through the scan: the layer's B x T new
-    rows go into them at (layer, row, `start_pos[row]`), and the
+    rows go into them at (layer, row, `start_pos[row]`), all of the
+    layer's leaves in one `block_rows.write_tokens` call, and the
     indexer and attention read the layer's keys out of them by blocks.
     It is handed and hands on the selection [B, T, S]."""
     nope, rot = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim
@@ -319,18 +321,10 @@ def _mixer(cfg: GlmDsaConfig, indexer, start_pos, positions):
             kva = jnp.einsum("btd,dc->btc", h, lp["wkva"])
             c_kv = rms_norm_reference(kva[..., :cfg.kv_lora_rank],
                                       lp["kv_norm"], cfg.norm_eps)
-            # Each a stack with this layer's new rows in it, and the
-            # layer: what the indexer and attention read blocks from.
-            latent = (decoder.write_rows(stacks[0], layer, c_kv, start_pos),
-                      layer)
-            rope_keys = (decoder.write_rows(
-                stacks[1], layer, _rotate_pairs(
-                    kva[..., cfg.kv_lora_rank:], cos, sin), start_pos),
-                layer)
+            new = (c_kv, _rotate_pairs(kva[..., cfg.kv_lora_rank:], cos, sin))
             cached = stacks[0].dtype
             q_nope = q[..., :nope].astype(cached)
             q_rope = _rotate_pairs(q[..., nope:], cos, sin).astype(cached)
-        new_state = (latent[0], rope_keys[0])
         if indexer == "full":
             with jax.named_scope("indexer"):
                 qi = _rotate_head(
@@ -339,12 +333,15 @@ def _mixer(cfg: GlmDsaConfig, indexer, start_pos, positions):
                 ki = _rotate_head(layer_norm(
                     jnp.einsum("btd,de->bte", h, lp["wik"]), lp["ik_norm"],
                     lp["ik_bias"], _INDEX_KEY_EPS), cos, sin, rot)
-                index_keys = (decoder.write_rows(stacks[2], layer, ki,
-                                                 start_pos), layer)
                 qi = qi.astype(stacks[2].dtype)
                 w = jnp.einsum("btd,dj->btj", h, lp["wiw"]).astype(
                     jnp.float32) * index_scale
-            new_state += (index_keys[0],)
+            new += (ki,)
+        # Each a stack with this layer's new rows in it, and the layer:
+        # what the indexer and attention read blocks from.
+        new_state = block_rows.write_tokens(stacks, layer, new, start_pos)
+        caches = [(stack, layer) for stack in new_state]
+        latent, rope_keys = caches[:2]
 
         def attend(q_nope, q_rope, pos, *chosen):
             """One block of queries. `chosen`: the indexer's queries
@@ -352,7 +349,7 @@ def _mixer(cfg: GlmDsaConfig, indexer, start_pos, positions):
             `shared` one."""
             if indexer == "full":
                 with jax.named_scope("indexer"):
-                    scores = _index_scores(*chosen, index_keys, pos)
+                    scores = _index_scores(*chosen, caches[2], pos)
                 with jax.named_scope("index_select"):
                     mask = _select(cfg, scores, pos)
             else:

@@ -27,7 +27,7 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
 from ray_tpu.models import decoder
-from ray_tpu.ops import attention
+from ray_tpu.ops import attention, block_rows
 from ray_tpu.ops.attention import (attention_reference, flash_attention,
                                    on_tpu)
 from ray_tpu.ops.norms import rms_norm_reference
@@ -442,18 +442,20 @@ def _cached_self_attention(cfg: LlamaConfig, start_pos, positions):
     """The mixer of serving. Its state is the slot cache's two stacks,
     (K, V), each [layers, B, S, Hkv, D], which `decoder.layers` carries
     through the scan: the layer's new K and V go into them at (layer,
-    row, `start_pos[row]`), B x T rows a stack. On a TPU a call of one
-    token a slot (a decode step) then hands the stacks whole to
-    `attention.decode_attention`, which reads out of them the blocks of
-    rows each slot holds; a call of more tokens (a prefill), and any
-    call off the TPU, has `_cached_attention` (looked up in this module
-    when the mixer is traced) read the layer's [B, S, Hkv, D] out of
-    them."""
+    row, `start_pos[row]`), B x T rows a stack
+    (`block_rows.write_tokens`: a scatter, or on a TPU, for one token a
+    slot, one kernel call for both stacks where they lie). On a TPU a
+    call of one token a slot (a decode step) then hands the stacks
+    whole to `attention.decode_attention`, which reads out of them the
+    blocks of rows each slot holds; a call of more tokens (a prefill),
+    and any call off the TPU, has `_cached_attention` (looked up in
+    this module when the mixer is traced) read the layer's [B, S, Hkv,
+    D] out of them."""
     def mixer(h, lp, rope, state, handed):
         (k_stack, v_stack), layer = state
         q, k, v = _qkv(cfg, h, lp, rope, positions, norm_all_heads)
-        k_stack = decoder.write_rows(k_stack, layer, k, start_pos)
-        v_stack = decoder.write_rows(v_stack, layer, v, start_pos)
+        k_stack, v_stack = block_rows.write_tokens(
+            (k_stack, v_stack), layer, (k, v), start_pos)
         if q.shape[1] == 1 and attention.on_tpu():
             out = attention.decode_attention(
                 q[:, 0], k_stack, v_stack, layer, positions[:, 0] + 1)[:, None]

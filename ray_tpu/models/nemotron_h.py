@@ -42,6 +42,7 @@ import jax.numpy as jnp
 from ray_tpu.models import decoder, llama, mamba2, moe
 from ray_tpu.models.serving import (Family, attention_init, by_query_blocks,
                                     normal)
+from ray_tpu.ops import block_rows
 
 PUBLISHED_PATTERN = (
     "MEMEMEM*EMEMEMEM*EMEMEMEM*EMEMEMEMEM*EMEMEMEMEM*EMEMEMEMEM*"
@@ -156,12 +157,10 @@ def _attention(cfg: NemotronHConfig, start_pos, positions):
     def mixer(h, lp, rope, state, handed):
         (k_stack, v_stack), layer = state
         q = jnp.einsum("bsd,dhk->bshk", h, lp["wq"])
-        k_stack = decoder.write_rows(
-            k_stack, layer, jnp.einsum("bsd,dhk->bshk", h, lp["wk"]),
-            start_pos)
-        v_stack = decoder.write_rows(
-            v_stack, layer, jnp.einsum("bsd,dhk->bshk", h, lp["wv"]),
-            start_pos)
+        k_stack, v_stack = block_rows.write_tokens(
+            (k_stack, v_stack), layer,
+            (jnp.einsum("bsd,dhk->bshk", h, lp["wk"]),
+             jnp.einsum("bsd,dhk->bshk", h, lp["wv"])), start_pos)
         max_seq = k_stack.shape[2]
         keys = decoder.layer_rows(k_stack, layer, 0, max_seq)
         values = decoder.layer_rows(v_stack, layer, 0, max_seq)

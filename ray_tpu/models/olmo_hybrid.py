@@ -56,7 +56,7 @@ import jax.numpy as jnp
 from ray_tpu.models import decoder, gated_delta, llama
 from ray_tpu.models.serving import (Family, attention_init, by_query_blocks,
                                     keys_read_by_blocks, normal)
-from ray_tpu.ops import attention
+from ray_tpu.ops import attention, block_rows
 
 PUBLISHED_LAYER_TYPES = ("linear", "linear", "linear", "full") * 8
 
@@ -158,10 +158,9 @@ def _attention(cfg: OlmoHybridConfig, start_pos, positions):
             q = llama.norm_all_heads(q, lp["q_norm"], cfg.norm_eps)
             k = llama.norm_all_heads(k, lp["k_norm"], cfg.norm_eps)
         v = jnp.einsum("bsd,dhk->bshk", h, lp["wv"])
-        k_stack = decoder.write_rows(k_stack, layer, k.reshape(b, t, -1),
-                                     start_pos)
-        v_stack = decoder.write_rows(v_stack, layer, v.reshape(b, t, -1),
-                                     start_pos)
+        k_stack, v_stack = block_rows.write_tokens(
+            (k_stack, v_stack), layer,
+            (k.reshape(b, t, -1), v.reshape(b, t, -1)), start_pos)
         q = q.astype(k_stack.dtype)
         if t == 1 and attention.on_tpu():
             out = attention.decode_attention(

@@ -255,19 +255,31 @@ def test_served_step_compiles_for_the_v5e(one_chip, name, program, bucket,
     run through their own grouped kernel (`ops/grouped_matmul.py`'s
     `gmm`, which this process's CPU backend would not choose: the test
     says it is on a TPU) where their matrices lie, and the program fits
-    beside nothing else. GLM-5.2 at 16 slots x 16,384; Nemotron 3 Super at 64 x 4,096,
+    beside nothing else. A decode step writes its new rows through
+    `write_blocks` (`_writes_its_rows_through_the_kernel`). GLM-5.2 at
+    16 slots x 16,384; Nemotron 3 Super at 64 x 4,096,
     whose Mamba-2 state rides the same carry as leaves with no sequence
     axis; Command A+ at 16 x 16,384, whose sliding layers' rings of
     4,096 rows ride it beside the full layer's rows. `products`: the grouped products an expert layer has, three of
     a gated SwiGLU, two of relu^2."""
-    from ray_tpu.ops import attention, grouped_matmul
+    from ray_tpu.ops import attention, block_rows, grouped_matmul
 
     monkeypatch.setattr(grouped_matmul, "on_tpu", lambda: True)
     monkeypatch.setattr(attention, "on_tpu", lambda: True)
-    cfg, params, _, compiled = _engines_program(
+    monkeypatch.setattr(block_rows, "on_tpu", lambda: True)
+    cfg, params, cache, compiled = _engines_program(
         one_chip, name, program, bucket,
         ("pair_overflows", "pairs_held", "pairs_routed"))
     text = compiled.as_text()
+    # A decode step's rows go in by one kernel a run of layers that
+    # keep keys (GLM-5.2's three leaves of unlike widths in one call,
+    # Command A+'s rings as its full rows, Nemotron's one attention
+    # layer; a Mamba-2 run keeps none), a prefill's by one window.
+    keyed = [[leaf.shape for key, leaf in run.items()
+              if key not in ("ssm", "conv")] for run in cache["runs"]]
+    _writes_its_rows_through_the_kernel(
+        text, sum(map(bool, keyed)) if program == "decode" else 0,
+        *(leaf for run in keyed for leaf in run))
     kernels = re.findall(r"%([\w-]+?)(?:\.\d+)? = \S+ custom-call\(", text)
     # Each run of expert layers is one scan, whose body holds the
     # layer's products once: the held path's kernel and no other.
@@ -307,6 +319,37 @@ def test_served_step_compiles_for_the_v5e(one_chip, name, program, bucket,
         < 16.0e9
 
 
+def _writes_its_rows_through_the_kernel(text, calls, *leaves):
+    """A decode program's new rows into the slot cache: `calls`
+    `write_blocks` kernels in all, one a scan of attention layers in
+    the body under `attn` for all of the layer's leaves, where the
+    carried stacks lie; no scatter a slot, leaf and layer under `attn`
+    (PERF.md, PR 54: 1,024 windows were an eighth of the dense step),
+    and no op of the mixer's that leaves an array of a stack's or a
+    layer's shape (`leaves`: the stacks' shapes; their views reach the
+    kernel as bitcasts. Of the mixer's, by its scope: GLM-5.2's rotary
+    keys of a run of one layer, 33 MB, the compiler itself moves to and
+    from faster memory around the step, with the scatter as with the
+    kernel). A prefill (`calls` 0) writes one window a leaf, no
+    kernel."""
+    paths = re.findall(r'%write_blocks(?:\.\d+)? = .* custom-call\('
+                       r'.*op_name="([^"]*)"', text)
+    assert len(paths) == calls
+    assert all(re.search(r"while/body/(?:closed_call/)?attn/", path)
+               for path in paths)
+    if not calls:
+        return
+    assert not re.findall(r' scatter\(.*op_name="[^"]*/attn/', text)
+    scheduled = _scheduled(text)
+    for leaf in leaves:
+        for dims in (leaf, (1,) + tuple(leaf[1:]), tuple(leaf[1:])):
+            dims = ",".join(map(str, dims))
+            assert not re.findall(
+                rf"= \w+\[{dims}\]\S* (?:fusion|copy|copy-start|"
+                r"dynamic-slice|convert|transpose)\(.*"
+                r'op_name="[^"]*/attn/', scheduled), dims
+
+
 def _reads_the_cache_through_the_kernel(scheduled, scans, *regions):
     """A decode program's attention over the slot cache: one
     `decode_attention` call a scan of attention layers, in the body
@@ -333,10 +376,13 @@ def test_the_dense_decode_step_compiles_for_the_v5e(one_chip, monkeypatch):
     layer scan's body holds one `decode_attention` call, which takes the
     carried K and V stacks [16, 32, 1024, 8, 128] as [16, 32, 8192, 128]
     (a bitcast: the TPU tiles 8 rows of 128 lanes either way), and the
-    layer's keys and values are never sliced out of them."""
-    from ray_tpu.ops import attention
+    layer's keys and values are never sliced out of them; in front of
+    it one `write_blocks` call puts the layer's 32 new rows, K's and
+    V's, into the same view where the stacks lie."""
+    from ray_tpu.ops import attention, block_rows
 
     monkeypatch.setattr(attention, "on_tpu", lambda: True)
+    monkeypatch.setattr(block_rows, "on_tpu", lambda: True)
     cfg, _, cache, compiled = _engines_program(
         one_chip, "mistral-7b-v0.3-serve", "decode", 0, ())
     _, n, rows = cache["k"].shape[:3]
@@ -345,10 +391,13 @@ def test_the_dense_decode_step_compiles_for_the_v5e(one_chip, monkeypatch):
     assert cache["k"].shape == (cfg.n_layers, n, rows, heads, d)
     _reads_the_cache_through_the_kernel(
         _scheduled(text), 1, (n, rows, heads, d), (n, rows * heads, d))
-    # The stacks reach the kernel as bitcasts of the carried leaves.
+    # The stacks reach the kernels as bitcasts of the carried leaves:
+    # the layer's new rows are written into that view, K and V by one
+    # `write_blocks` call, and attention reads what comes back.
     assert len(re.findall(
         rf"= bf16\[{cfg.n_layers},{n},{rows * heads},{d}\]\S* bitcast\(",
         text)) == 2
+    _writes_its_rows_through_the_kernel(text, 1, cache["k"].shape)
     memory = compiled.memory_analysis()
     assert memory.temp_size_in_bytes < 0.1e9
     assert memory.argument_size_in_bytes + memory.temp_size_in_bytes \
@@ -369,19 +418,26 @@ def test_olmo_hybrids_step_compiles_for_the_v5e(one_chip, program, bucket,
     and values once a step (`models/olmo_hybrid.py` says what a decode
     step does instead: `ops.attention.decode_attention` is handed the
     stacks whole). A decode step updates a delta layer's states
-    where they lie in the run's stack (`ops/delta_update.py`'s kernel,
-    which this process's CPU backend would not choose, as it would not
-    the attention's: the test says it is on a TPU): nothing else makes
+    where they lie in the run's stack and a full layer's new rows
+    where they lie in its (`ops/delta_update.py`'s kernel and
+    `ops/block_rows.py`'s, which this process's CPU backend would not
+    choose, as it would not the attention's: the test says it is on a
+    TPU): nothing else makes
     an array of a layer's states or of the stack."""
-    from ray_tpu.ops import attention, delta_update
+    from ray_tpu.ops import attention, block_rows, delta_update
 
     monkeypatch.setattr(delta_update, "on_tpu", lambda: True)
     monkeypatch.setattr(attention, "on_tpu", lambda: True)
+    monkeypatch.setattr(block_rows, "on_tpu", lambda: True)
     cfg, params, cache, compiled = _engines_program(
         one_chip, "olmo-hybrid-7b-serve", program, bucket,
         ("delta_scan_tokens", "delta_state_resets"))
     _, n, rows = cache["runs"][1]["k"].shape[:3]
-    scheduled = _scheduled(compiled.as_text())
+    text = compiled.as_text()
+    scheduled = _scheduled(text)
+    full = [run["k"].shape for run in cache["runs"] if "k" in run]
+    _writes_its_rows_through_the_kernel(
+        text, len(full) if program == "decode" else 0, *full)
     width = cfg.n_kv_heads * cfg.head_dim
     assert not re.findall(
         rf"= \w+\[1,{n},{rows},{width}\]\S* (?:copy|convert|transpose)\(",

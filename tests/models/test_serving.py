@@ -157,3 +157,50 @@ def test_no_served_module_imports_a_siblings_private_name():
                    and (node.module or "").startswith("ray_tpu.models")
                    for alias in node.names if alias.name.startswith("_")]
         assert not reached, (module, reached)
+
+
+@pytest.mark.parametrize("name", [n for n in SERVED if n != "SdarMoeConfig"])
+def test_a_decode_step_by_the_kernel_is_the_step_by_the_scatter(
+        name, monkeypatch):
+    """A step of one token a slot writes its rows through
+    `block_rows.write_tokens`, on a TPU the tile-rewrite kernel: run
+    through the Pallas interpreter it leaves the cache and the logits
+    the scatter leaves, to the bit, every leaf of every run, a fresh
+    slot (start 0), a slot at a tile's last row and a retired one (the
+    engine's clamp, max_seq - 2) among the rows."""
+    from ray_tpu.ops import block_rows
+
+    row, module = ROWS[name], _module(name)
+    cfg = row.config()
+    served = serving.served_model(cfg)
+    params = module.init_params(cfg, jax.random.PRNGKey(1))
+    slots, rows = 4, 32
+    rng = np.random.default_rng(4)
+    prompt = jnp.asarray(rng.integers(1, cfg.vocab_size, (slots, 16)),
+                         jnp.int32)
+    _, cache, _ = jax.jit(lambda p, c: served.forward(
+        p, prompt, cfg, c, jnp.zeros(slots, jnp.int32), 15))(
+            params, served.init_cache(cfg, slots, rows))
+    tokens = jnp.asarray(rng.integers(1, cfg.vocab_size, (slots, 1)),
+                         jnp.int32)
+    start = jnp.asarray([0, 9, 15, rows - 2], jnp.int32)
+
+    def step():
+        return jax.jit(lambda p, c: served.forward(
+            p, tokens, cfg, c, start, 0)[:2])(params, cache)
+
+    want = step()
+    plain, seen = block_rows.write_tokens, []
+
+    def through_the_interpreter(stacks, layer, new, start_pos):
+        seen.append(block_rows._fits(tuple(stacks), new))
+        return plain(stacks, layer, new, start_pos, interpret=True)
+
+    monkeypatch.setattr(block_rows, "write_tokens", through_the_interpreter)
+    got = step()
+    # Every run of layers that keeps keys went through the kernel.
+    assert seen and all(seen)
+    for x, y in zip(jax.tree.leaves(got), jax.tree.leaves(want)):
+        assert x.dtype == y.dtype
+        np.testing.assert_array_equal(np.asarray(x, np.float32),
+                                      np.asarray(y, np.float32))
