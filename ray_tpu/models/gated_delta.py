@@ -22,7 +22,8 @@ scalar a head, `mamba2._scan`), and with beta up to 2 it may have a
 negative eigenvalue. The norm comes before the gate here and after it
 in `mamba2._gated_norm`: two functions, not one with a switch.
 `mamba2._conv` serves the three convolutions (they have no bias, which
-it is told).
+it is told; `lfm2_moe` tells it to leave out the activation too, and
+keeps a convolution's carry as its mixer's only state).
 
 What a sequence leaves behind is S [H, dk, dv] (`cfg.state_dtype`) and
 the last K - 1 un-convolved rows of q~, k~ and v~: four leaves of the

@@ -97,18 +97,23 @@ def state_shapes(cfg) -> Dict[str, Any]:
             "conv": ((cfg.conv_kernel - 1, conv_width(cfg)), cfg.dtype)}
 
 
-def _conv(cfg, lp, carry, xbc, at, bias=True):
+def _conv(cfg, lp, carry, xbc, at, bias=True, activation=jax.nn.silu):
     """The causal depthwise convolution of xbc [B, T, C] behind the
-    carried rows [B, K - 1, C] -> (silu of it [B, T, C], the rows to
-    carry on: the K - 1 un-convolved rows that end at position `at[row]`).
-    `bias` False: the convolution has none (`gated_delta`'s three)."""
+    carried rows [B, K - 1, C] -> (`activation` of it [B, T, C], the
+    rows to carry on: the K - 1 un-convolved rows that end at position
+    `at[row]`). `bias` False: the convolution has none (`gated_delta`'s
+    three). `activation` None: the sum as it is (`lfm2_moe`'s gated
+    short convolution, which gates before and after instead, and whose
+    only state this carry is: a mixer need keep nothing else)."""
     k, t = cfg.conv_kernel, xbc.shape[1]
     window = jnp.concatenate([carry.astype(xbc.dtype), xbc], 1)
     out = sum(window[:, j:j + t].astype(jnp.float32)
               * lp["conv_w"][:, j].astype(jnp.float32) for j in range(k))
     if bias:
         out = out + lp["conv_b"].astype(jnp.float32)
-    return (jax.nn.silu(out).astype(xbc.dtype), jax.vmap(
+    if activation is not None:
+        out = activation(out)
+    return (out.astype(xbc.dtype), jax.vmap(
         lambda rows, last: lax.dynamic_slice_in_dim(rows, last + 1, k - 1)
     )(window, at))
 

@@ -19,7 +19,9 @@ through the slot cache. The engine knows no model; it takes from here
   leaves. A *state* leaf has no sequence axis (a recurrent layer's
   state, a vector a channel as Mamba-2's or a matrix a head as the
   delta rule's, and a convolution's last rows, of which a layer may
-  carry several: `gated_delta` has three): `forward` rewrites it whole, a row
+  carry several, `gated_delta` has three, and which may be all a mixer
+  keeps: `lfm2_moe`'s gated short convolution has no other state):
+  `forward` rewrites it whole, a row
   that starts at position 0 starts from zeros whatever the leaf held,
   and what is left is the state after position `at` and no later (a
   prefill's bucket padding must not enter it); the engine only slices
@@ -396,7 +398,8 @@ _SERVED = {"LlamaConfig": _llama, "GlmDsaConfig": _family("glm_dsa"),
            "NemotronHConfig": _family("nemotron_h"),
            "Cohere2MoeConfig": _family("cohere2_moe"),
            "OlmoHybridConfig": _family("olmo_hybrid"),
-           "SdarMoeConfig": _family("sdar_moe")}
+           "SdarMoeConfig": _family("sdar_moe"),
+           "Lfm2MoeConfig": _family("lfm2_moe")}
 
 
 def served_model(cfg) -> ServedModel:

@@ -701,7 +701,10 @@ def flash_attention_forward(q, k, v, *, window: Optional[int] = None,
     one: row i sees key j iff j // block <= i // block, what a model
     that generates by diffusion over blocks prefills with. Layout,
     GQA and the choice between kernel, interpreter and reference as
-    `flash_attention`'s."""
+    `flash_attention`'s. Head sizes served: 128 (a row of lanes a
+    head) and 64 (LFM2's: blocks [block_q, 64], half a row, which
+    Mosaic takes as they are; compiled for the v5e and run there,
+    PERF.md, PR 55); no other has been compiled."""
     if sm_scale is None:
         sm_scale = q.shape[-1] ** -0.5
     qt, kt, vt = (x.transpose(0, 2, 1, 3) for x in (q, k, v))
@@ -912,6 +915,15 @@ def decode_attention(q, k_stack, v_stack, layer, lengths, *,
     accumulations float32, the weights cast to the stacks' dtype for
     the product with V, and the `H // Hkv` query heads of a key head
     contracted together: what `llama._cached_attention` guarantees.
+
+    Head sizes served: 128 (the dense leaf's rows x heads, and merged
+    leaves of 4 and 30 heads) and 64 on a merged leaf (LFM2's 8 x 64 =
+    512 channels, four query heads a key head: q is spread over slices
+    of 64 lanes that start at multiples of 64, and a head keeps such a
+    slice of the weighted sum; compiled for the v5e and run there,
+    PERF.md, PR 55). A dense leaf [.., Hkv, 64] has not been compiled:
+    its view [S x Hkv, 64] is half-filled rows of lanes, and a family
+    with such heads merges them.
 
     On a TPU backend this is always the compiled kernel: `interpret`
     never reaches a TPU call, and a kernel Mosaic refuses is an error.

@@ -567,6 +567,80 @@ def test_sdars_step_compiles_for_the_v5e(one_chip, program, bucket,
         < 16.0e9
 
 
+@pytest.mark.parametrize("program,bucket", [("decode", 0),
+                                            ("prefill", 512),
+                                            ("prefill", 1024),
+                                            ("prefill", 2048)])
+def test_lfm2s_step_compiles_for_the_v5e(one_chip, program, bucket,
+                                         monkeypatch):
+    """LFM2-8B-A1B's first stage as `lfm2-8b-a1b-serve.json` cuts it,
+    through the engine's own programs at the cell's 64 slots of 2,048:
+    it compiles and fits, heads of 64 channels and all. A decode step
+    reads the three full layers' keys through `decode_attention` on the
+    merged leaf of 8 x 64 = 512 channels (a head's slice of 64 lanes
+    starts at a multiple of 64) and writes their rows through
+    `write_blocks`; a prefill holds the flash kernel over blocks
+    [rows, 64], once a run of full layers; every expert layer's three
+    products are the held path's kernel on the run's stack; and no step
+    copies a leaf of the cache: a conv layer's two rows a slot are read
+    and written where the stack lies (5.8 MB in all), and a prefill
+    whose bucket is under the region (every one the cell's prompts
+    take but 2,048, which overwrites the region and reads none of it)
+    keeps the key leaves in the order they lie in: with a view [rows,
+    8, 64] of a layer left to the compiler, every leaf was copied whole
+    with its rows in the lanes, 6 x 134 MB a call (`lfm2_moe._attention`
+    pins the layer's rows)."""
+    from ray_tpu.ops import attention, block_rows, grouped_matmul
+
+    monkeypatch.setattr(grouped_matmul, "on_tpu", lambda: True)
+    monkeypatch.setattr(attention, "on_tpu", lambda: True)
+    monkeypatch.setattr(block_rows, "on_tpu", lambda: True)
+    cfg, params, cache, compiled = _engines_program(
+        one_chip, "lfm2-8b-a1b-serve", program, bucket,
+        ("conv_prefill_tokens", "conv_state_resets", "experts_held_steps",
+         "experts_touched", "pair_overflows", "pairs_held", "pairs_routed"))
+    n, rows = cache["runs"][1]["k"].shape[1:3]
+    assert (n, rows, cfg.head_dim) == (64, 2048, 64)
+    text = compiled.as_text()
+    scheduled = _scheduled(text)
+    full = [run["k"].shape for run in cache["runs"] if "k" in run]
+    assert full == [(1, n, rows, 512)] * 3
+    _writes_its_rows_through_the_kernel(
+        text, len(full) if program == "decode" else 0, *full)
+    assert not re.findall(
+        rf"= \w+\[1,{n},{rows},512\]\S* (?:copy|convert|transpose)\(",
+        scheduled)
+    kernels = re.findall(r"%([\w-]+?)(?:\.\d+)? = \S+ custom-call\(", text)
+    sparse = sum("we1" in run for run in params["runs"])
+    assert sparse == 6
+    assert [k for k in kernels if k in PRODUCTS] == ["gmm"] * (3 * sparse)
+    assert "ragged-dot" not in text
+    if program == "decode":
+        _reads_the_cache_through_the_kernel(scheduled, len(full),
+                                            (n, rows, 512))
+        assert "flash_fwd" not in kernels
+    else:
+        assert kernels.count("flash_fwd") == len(full)
+        assert "decode_attention" not in kernels
+    # No op makes an array of a run's carried rows or of one layer's
+    # expert matrices.
+    for run in cache["runs"]:
+        if "conv" in run:
+            dims = ",".join(map(str, run["conv"].shape))
+            assert not re.findall(
+                rf"= bf16\[{dims}\]\S* (?:copy|copy-start|transpose)\(",
+                scheduled), dims
+    e, d, f = cfg.n_experts, cfg.dim, cfg.hidden_dim
+    assert not re.findall(
+        rf"= bf16\[{e},(?:{d},{f}|{f},{d})\]\S* "
+        r"(?:fusion|copy|copy-start|dynamic-slice)\(", text)
+    memory = compiled.memory_analysis()
+    assert memory.argument_size_in_bytes > 10.1e9  # weights and cache
+    assert memory.temp_size_in_bytes < 0.7e9
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes \
+        < 16.0e9
+
+
 @pytest.mark.parametrize("window", [None, 4096], ids=["full", "window"])
 def test_trained_flash_kernels_compile_at_smallthinkers_shapes(one_chip,
                                                                window):

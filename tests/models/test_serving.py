@@ -75,6 +75,13 @@ ROWS = {
         given=frozenset({"keys_read"}),
         weights="1b22ab037784016de8ad761330f20cd7"
                 "3625010e91da2878e71e44e3763e96a7"),
+    # (Recorded at the PR that brought the family, PR 55.)
+    "Lfm2MoeConfig": Row(
+        "lfm2_moe", lambda: _debug("lfm2-8b-a1b-serve.json"),
+        state=frozenset({"conv"}),
+        given=frozenset({"state_leaves", "keys_read"}),
+        weights="095f16a464f413890cf1264bf270d315"
+                "73db4533b44ad90f5d1e0c492de252ad"),
 }
 SERVED = sorted(serving._SERVED)
 FAMILIES = [name for name in SERVED if name != "LlamaConfig"]
@@ -149,7 +156,7 @@ def test_the_optional_functions_a_family_gives(name):
 
 def test_no_served_module_imports_a_siblings_private_name():
     for module in ("glm_dsa", "nemotron_h", "cohere2_moe", "olmo_hybrid",
-                   "sdar_moe", "gated_delta", "mamba2"):
+                   "sdar_moe", "lfm2_moe", "gated_delta", "mamba2"):
         tree = ast.parse(inspect.getsource(
             importlib.import_module(f"ray_tpu.models.{module}")))
         reached = [(node.module, alias.name) for node in ast.walk(tree)

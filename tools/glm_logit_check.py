@@ -8,7 +8,8 @@ name it had when GLM-5.2 was the one model it knew, which
 family, its faults, the faults a set of weights cannot show and the
 program's own initialiser; below, GLM-5.2's at length, then
 `nemotron_faults` for `nemotron-3-super-serve`, `cohere_faults` for
-`command-a-plus-serve` and `olmo_faults` for `olmo-hybrid-7b-serve`.
+`command-a-plus-serve`, `olmo_faults` for `olmo-hybrid-7b-serve` and
+`lfm2_faults` for `lfm2-8b-a1b-serve`.
 
 Outside the benchmark and its timed window (PERF.md, PR 32, has the
 readings). For each seed, what `benchmark/runners/serve.py`'s
@@ -123,8 +124,9 @@ def _state_in_bfloat16(forward, state_leaves):
 def _to_the_end(real):
     """A `forward_with_cache` whose state is left as after the call's
     last token, padding and all: `at` is dropped."""
-    def forward_with_cache(params, tokens, cfg, cache, start_pos, at=None):
-        return real(params, tokens, cfg, cache, start_pos)
+    def forward_with_cache(params, tokens, cfg, cache, start_pos, at=None,
+                           **rest):
+        return real(params, tokens, cfg, cache, start_pos, **rest)
     return forward_with_cache
 
 
@@ -377,6 +379,72 @@ def olmo_faults(forward, init_cache):
     }
 
 
+def lfm2_faults(forward, init_cache):
+    """{name: served} for the family `lfm2_moe`, as `faults` for
+    GLM-5.2: the weights cut to float8 e4m3's mantissa, `silu` left in
+    the short convolution, the B gate or the C gate left out, a prefill
+    whose bucket padding enters the carried rows, a carry not zeroed
+    for a row that starts at position 0 (the check's second prefill
+    runs over what the first one left), the q and k norm a head left
+    out, the selection bias weighing in the gates, the chosen gates
+    left as the sigmoid gave them, and the leading dense layers given
+    experts (those of the topmost layer)."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.models import lfm2_moe, mamba2
+
+    glm = faults(forward, init_cache)
+    with_cfg = functools.partial(_with_cfg, forward)
+    patched = functools.partial(_patched, forward)
+
+    def silu_kept(real):
+        def conv(cfg, lp, carry, x, at, bias=True, activation=None):
+            return real(cfg, lp, carry, x, at, bias, jax.nn.silu)
+        return conv
+
+    def without(gate):
+        def short_conv(real):
+            def faulty(cfg, lp, carry, bcu, fresh, at):
+                ones = jnp.ones_like(bcu[..., :cfg.dim])
+                b_gate, c_gate, u = jnp.split(bcu, 3, -1)
+                parts = {"B": (ones, c_gate, u), "C": (b_gate, ones, u)}
+                return real(cfg, lp, carry, jnp.concatenate(parts[gate], -1),
+                            fresh, at)
+            return faulty
+        return short_conv
+
+    def never_fresh(real):
+        def faulty(cfg, lp, carry, bcu, fresh, at):
+            return real(cfg, lp, carry, bcu, jnp.zeros_like(fresh), at)
+        return faulty
+
+    def experts_in_the_dense_layers(params, tokens, cfg, cache, start_pos):
+        ours = ("router", "router_bias", "we1", "we2", "we3")
+        top = params["runs"][-1]
+        runs = [{**{k: v for k, v in run.items()
+                    if k not in ("w1", "w2", "w3")},
+                 **{k: jnp.repeat(top[k][-1:], run["w1"].shape[0], 0)
+                    for k in ours}} if "w1" in run else run
+                for run in params["runs"]]
+        return forward({**params, "runs": runs}, tokens,
+                       dataclasses.replace(cfg, n_dense_layers=0), cache,
+                       start_pos)
+
+    return {
+        "lower precision": glm["lower precision"],
+        "silu in the conv": patched(mamba2, "_conv", silu_kept),
+        "no B gate": patched(lfm2_moe, "_short_conv", without("B")),
+        "no C gate": patched(lfm2_moe, "_short_conv", without("C")),
+        "pad absorbed": patched(lfm2_moe, "forward_with_cache", _to_the_end),
+        "carry not zeroed": patched(lfm2_moe, "_short_conv", never_fresh),
+        "no q and k norm": with_cfg(qk_norm=False),
+        "bias in the gates": glm["bias in the gates"],
+        "gates not renormalised": with_cfg(norm_topk_prob=False),
+        "experts in the dense layers": experts_in_the_dense_layers,
+    }
+
+
 # The faults a set of weights cannot show on the chip (each is seen at
 # the other; PERF.md section 6, PR 32, has the readings). The
 # benchmark's weights make the routed experts 32 times quieter, so what
@@ -410,6 +478,21 @@ OLMO_UNSEEN = {"benchmark": ("state in bfloat16",),
                "plain": ("state in bfloat16",)}
 
 
+# The same for `lfm2_moe` (PERF.md section 6, PR 55). The benchmark's
+# weights make the routed experts 32 times quieter, as GLM-5.2's do:
+# the bias in the gates reads the program's digits there, and the gates
+# not renormalised 2.3 to 2.6 % against the runner's 2 %, over it but
+# too near to count on; the plain weights show both. A carry not zeroed
+# for a row that starts at 0 moves two positions of a row's second
+# prefill, 1e-5 of the largest logit in float32 and nothing in
+# bfloat16; the float32 test on the CPU holds it
+# (`tests/models/test_lfm2_moe.py`).
+LFM2_UNSEEN = {
+    "benchmark": ("carry not zeroed", "bias in the gates",
+                  "gates not renormalised"),
+    "plain": ("carry not zeroed",)}
+
+
 def _glm_init():
     from ray_tpu.models.glm_dsa import init_params
     return init_params
@@ -430,6 +513,11 @@ def _olmo_init():
     return init_params
 
 
+def _lfm2_init():
+    from ray_tpu.models.lfm2_moe import init_params
+    return init_params
+
+
 # By a configuration's family: its faults, the faults a set of weights
 # cannot show, the program's own initialiser (the plain weights), and
 # the prompt lengths of a rehearsal at debug widths.
@@ -441,6 +529,7 @@ FAMILIES = {
                     [45, 39, 26, 19]),
     "olmo_hybrid": (olmo_faults, OLMO_UNSEEN, _olmo_init,
                     [45, 39, 26, 19]),
+    "lfm2_moe": (lfm2_faults, LFM2_UNSEEN, _lfm2_init, [45, 33, 12, 5]),
 }
 
 
