@@ -144,9 +144,11 @@ def _leaves(cfg: SdarMoeConfig, kind):
 # Attention
 # ---------------------------------------------------------------------------
 
-# A prefill of so many rows or more goes through the flash kernel where
-# it can (`serving.own_keys`): every bucket from here up is one tile of the
-# kernel or a whole number of its tiles of 1,024.
+# A prefill of a multiple of so many rows goes through the flash kernel
+# where it can (`serving.own_keys`): every bucket from here up is one
+# (`serve.llm.prefill_bucket`: 384, 768 and 1,536 among them), and the
+# kernel's tile is the largest such multiple, up to 1,024, that divides
+# the call's rows (`attention.forward_tile`).
 _FLASH_ROWS = 128
 
 
@@ -221,7 +223,7 @@ def _mixer(cfg: SdarMoeConfig, start_pos, positions):
                 t, q, block_ends(positions, block))[0]
 
         out = own_keys(
-            t >= _FLASH_ROWS and not (t & (t - 1) and t % 1024), start_pos,
+            not t % _FLASH_ROWS, start_pos,
             lambda: attention.flash_attention_forward(q, k, v, block=block),
             plain)
         return out, (k_stack, v_stack), handed

@@ -686,6 +686,20 @@ def flash_attention(q, k, v, *, causal: bool = True,
     return out.transpose(0, 2, 1, 3)
 
 
+def forward_tile(rows: int, most: int = 1024) -> int:
+    """The tile `flash_attention_forward` takes along an axis of `rows`
+    positions: for a multiple of 128 the largest multiple of 128 that
+    divides it and is at most `most`, so that no tile hangs over the
+    end (1,024 wherever that divides the rows, the rows themselves up
+    to 1,024, 640 for 1,280, 896 for 3,584); for any other count, or a
+    `most` under 128, `most` or the rows, whichever is less, and the
+    kernel masks a ragged last tile."""
+    if rows % 128 or most < 128:
+        return min(most, rows)
+    return max(t for t in range(128, min(most, rows) + 1, 128)
+               if rows % t == 0)
+
+
 def flash_attention_forward(q, k, v, *, window: Optional[int] = None,
                             block: Optional[int] = None,
                             sm_scale: Optional[float] = None,
@@ -701,15 +715,19 @@ def flash_attention_forward(q, k, v, *, window: Optional[int] = None,
     one: row i sees key j iff j // block <= i // block, what a model
     that generates by diffusion over blocks prefills with. Layout,
     GQA and the choice between kernel, interpreter and reference as
-    `flash_attention`'s. Head sizes served: 128 (a row of lanes a
-    head) and 64 (LFM2's: blocks [block_q, 64], half a row, which
-    Mosaic takes as they are; compiled for the v5e and run there,
-    PERF.md, PR 55); no other has been compiled."""
+    `flash_attention`'s; `block_q` and `block_k` are the most a tile
+    may hold, and `forward_tile` says what it does hold. Head sizes
+    served: 128 (a row of lanes a head) and 64 (LFM2's: blocks
+    [block_q, 64], half a row, which Mosaic takes as they are; compiled
+    for the v5e and run there, PERF.md, PR 55); no other has been
+    compiled."""
     if sm_scale is None:
         sm_scale = q.shape[-1] ** -0.5
     qt, kt, vt = (x.transpose(0, 2, 1, 3) for x in (q, k, v))
     if on_tpu() or interpret:
-        out, _ = _flash_fwd(qt, kt, vt, True, sm_scale, block_q, block_k,
+        out, _ = _flash_fwd(qt, kt, vt, True, sm_scale,
+                            forward_tile(qt.shape[2], block_q),
+                            forward_tile(kt.shape[2], block_k),
                             not on_tpu(), window=window, with_lse=False,
                             block=block)
     else:

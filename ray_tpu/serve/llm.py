@@ -22,11 +22,13 @@ shapes — so:
   prefix cache's reads, writes and payloads on the row leaves alone. A
   model with a state leaf is served with no prefix cache
   (`models/serving.py` says why).
-- Prefill lengths are bucketed: to powers of two up to 4,096 tokens and
-  to multiples of 1,024 past that (`prefill_bucket`), so at most
-  log2(4096) + max_seq / 1024 prefill programs ever compile and a long
-  prompt pays for at most 1,023 tokens of padding, not for as many again
-  as it has.
+- Prefill lengths are bucketed (`prefill_bucket`): to powers of two up
+  to 256 tokens, to the next quarter of the prompt's power of two past
+  that (384, 512, 768, 1,024, 1,536, 2,048) and to multiples of 1,024
+  from 2,049 on, so some 8 + 2 log2(max_seq / 256) prefill programs
+  compile up to 2,048 tokens and max_seq / 1024 past it, and a prompt
+  pays for padding of under a third of its bucket and at most 1,023
+  tokens, not for as many again as it has.
 - Sampling (greedy / temperature / top-k) runs on device; one token per
   slot per step streams back to waiting callers.
 - That is the one-token contract: a prefill yields a request's first
@@ -98,22 +100,44 @@ from ray_tpu.serve.streaming import (LAG_SAMPLE_EVERY, STREAM_WAITING_KEY,
                                      WAITING_BEAT_S)
 
 
-# A prefill program's cost grows faster than its length (attention), so
-# from here on a power of two's padding would cost seconds: the buckets
-# come every `_BUCKET_STEP` tokens instead.
-_BUCKET_LINEAR_FROM = 4096
-_BUCKET_STEP = 1024
+# A prompt's prefill bucket. Padding rows cost what real rows cost (a
+# prefill is compute bound and a line through its rows), and a compiled
+# program a bucket costs set-up time and memory, so a bucket stands a
+# fixed share of its octave above the last:
+# - `_BUCKET_STEPS` steps to a power of two (two buckets an octave): a
+#   prompt is padded by under a third of its bucket and a sixth at the
+#   mean, where a power of two's padding is under a half and three
+#   tenths, at one more program an octave. Eight steps take two thirds
+#   of the padding away and not half of it, but a program more costs a
+#   family whose prefill holds many kernel call sites 2 to 3 s of every
+#   set-up, cached or not: at eight, four more programs put LFM2's warm
+#   set-up 11 % up (PERF.md section 6, PR 56).
+# - no step under `_BUCKET_STEP_MIN`: every tile on the prefill path is
+#   a multiple of it (a row of lanes, the flash kernels' least tile,
+#   Nemotron's 128-token chunk, twice Olmo-Hybrid's), so up to it the
+#   buckets are the powers of two.
+# - none over `_BUCKET_STEP_MAX`: a prefill's cost grows faster than
+#   its length (attention), so past 4,096 tokens a quarter of a power
+#   of two would cost seconds of padding.
+_BUCKET_STEPS = 4
+_BUCKET_STEP_MIN = 128
+_BUCKET_STEP_MAX = 1024
 
 
 def prefill_bucket(n_tokens: int) -> int:
-    """The smallest prefill bucket that holds `n_tokens`: a power of two
-    up to `_BUCKET_LINEAR_FROM`, a multiple of `_BUCKET_STEP` past it."""
-    if n_tokens > _BUCKET_LINEAR_FROM:
-        return -(-n_tokens // _BUCKET_STEP) * _BUCKET_STEP
-    b = 1
-    while b < n_tokens:
-        b *= 2
-    return b
+    """The smallest prefill bucket that holds `n_tokens`: the next power
+    of two up to `_BUCKET_STEP_MIN`; past it `n_tokens` rounded up to
+    the step of its octave, which is the `_BUCKET_STEPS`-th part of the
+    next power of two, held between `_BUCKET_STEP_MIN` and
+    `_BUCKET_STEP_MAX` (so 129-256 take 256, 257-512 take 384 or 512,
+    513-1,024 take 768 or 1,024, and from 2,049 on every bucket is a
+    multiple of 1,024)."""
+    octave = 1 << max(n_tokens - 1, 0).bit_length()
+    if octave <= _BUCKET_STEP_MIN:
+        return octave
+    step = min(max(octave // _BUCKET_STEPS, _BUCKET_STEP_MIN),
+               _BUCKET_STEP_MAX)
+    return -(-n_tokens // step) * step
 
 
 def bucket_ladder(limit: int, max_seq: int) -> List[int]:

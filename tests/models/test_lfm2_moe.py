@@ -327,28 +327,33 @@ def test_a_decode_step_through_the_kernel_equals_the_plain_path(
         np.testing.assert_allclose(x, y, atol=3e-6 * np.abs(y).max())
 
 
+# 128 rows in two tiles of 64; 640, a multiple of 128 between two powers
+# of two as `serve.llm.prefill_bucket`'s 384, 768 and 1,536 are, in the
+# tile the kernel chooses.
+@pytest.mark.parametrize("rows, tiles", [
+    (128, {"block_q": 64, "block_k": 64}), (640, {})], ids=["128", "640"])
 def test_a_prefill_through_the_flash_kernel_equals_the_plain_path(
-        params, monkeypatch):
-    """A prefill from position 0 at a bucket the kernel tiles takes
-    `flash_attention_forward` over the call's own keys on a TPU
-    (interpreted here); one from a later position takes the plain path
-    on the same program."""
+        params, monkeypatch, rows, tiles):
+    """A prefill from position 0 at a bucket the kernel tiles (any
+    multiple of 128 rows) takes `flash_attention_forward` over the
+    call's own keys on a TPU (interpreted here); one from a later
+    position takes the plain path on the same program."""
     import types
 
     from jax import lax
 
     from ray_tpu.ops import attention
 
-    tokens = _tokens((1, 128), seed=8)
+    tokens = _tokens((1, rows), seed=8)
     start = jnp.zeros(1, jnp.int32)
     want, plain_cache = lfm2_moe.forward_with_cache(
-        params, tokens, CFG, _cache(1, 256), start, at=100)
+        params, tokens, CFG, _cache(1, 2 * rows), start, at=rows - 28)
     calls = []
 
     def flash(q, k, v):
         calls.append(q.shape)
         return attention.flash_attention_forward(
-            q, k, v, block_q=64, block_k=64, interpret=True)
+            q, k, v, interpret=True, **tiles)
 
     monkeypatch.setattr(lfm2_moe, "attention", types.SimpleNamespace(
         on_tpu=lambda: False, flash_attention_forward=flash))
@@ -357,14 +362,14 @@ def test_a_prefill_through_the_flash_kernel_equals_the_plain_path(
         lfm2_moe, "own_keys", lambda tiled, start_pos, flash, plain:
         lax.cond(start_pos.max() == 0, flash, plain) if tiled else plain())
     got, cache = lfm2_moe.forward_with_cache(
-        params, tokens, CFG, _cache(1, 256), start, at=100)
-    assert calls == [(1, 128, 8, 8)] * 2  # a trace a run of full layers
+        params, tokens, CFG, _cache(1, 2 * rows), start, at=rows - 28)
+    assert calls == [(1, rows, 8, 8)] * 2  # a trace a run of full layers
     np.testing.assert_allclose(got, want, atol=3e-6 * np.abs(want).max())
     assert not np.array_equal(got, want)
     for x, y in zip(jax.tree.leaves(cache), jax.tree.leaves(plain_cache)):
         np.testing.assert_allclose(x, y, atol=3e-6 * np.abs(y).max())
     later, _ = lfm2_moe.forward_with_cache(
-        params, tokens, CFG, cache, start + 128, at=100)
+        params, tokens, CFG, cache, start + rows, at=rows - 28)
     assert later.shape == want.shape and bool(jnp.isfinite(later).all())
 
 

@@ -344,6 +344,43 @@ def test_a_block_step_returns_every_positions_logits(params):
     assert int(counts["experts_held_steps"]) == CFG.n_layers * CFG.n_experts
 
 
+def test_a_prefill_of_640_rows_goes_through_the_flash_kernel(
+        params, monkeypatch):
+    """A bucket between two powers of two (`serve.llm.prefill_bucket`:
+    384, 768, 1,536) is a multiple of 128 rows, as 640 is, which is all
+    `own_keys` asks of a prefill from position 0 on a TPU (interpreted
+    here, in the tile the kernel chooses); the plain path gives the
+    same logits and rows."""
+    import types
+
+    from jax import lax
+
+    tokens = _tokens((1, 640), seed=8)
+    start = jnp.zeros(1, jnp.int32)
+    want, plain_cache = sdar_moe.forward_with_cache(
+        params, tokens, CFG, sdar_moe.init_cache(CFG, 1, 1024), start)
+    calls = []
+
+    def flash(q, k, v, block):
+        calls.append((q.shape[1], block))
+        return attention.flash_attention_forward(q, k, v, block=block,
+                                                 interpret=True)
+
+    monkeypatch.setattr(sdar_moe, "attention", types.SimpleNamespace(
+        on_tpu=lambda: False, flash_attention_forward=flash))
+    # `serving.own_keys` as it is on a TPU.
+    monkeypatch.setattr(
+        sdar_moe, "own_keys", lambda tiled, start_pos, flash, plain:
+        lax.cond(start_pos.max() == 0, flash, plain) if tiled else plain())
+    got, cache = sdar_moe.forward_with_cache(
+        params, tokens, CFG, sdar_moe.init_cache(CFG, 1, 1024), start)
+    assert calls == [(640, CFG.block_length)]  # one run of full layers
+    np.testing.assert_allclose(got, want, atol=3e-6 * np.abs(want).max())
+    assert not np.array_equal(got, want)
+    for x, y in zip(jax.tree.leaves(cache), jax.tree.leaves(plain_cache)):
+        np.testing.assert_allclose(x, y, atol=3e-6 * np.abs(y).max())
+
+
 @pytest.mark.parametrize("rows,block_q", [(64, 1024), (256, 128)],
                          ids=["one-tile", "two-tiles"])
 def test_the_flash_kernels_block_mask(rows, block_q):

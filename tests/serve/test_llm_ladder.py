@@ -10,28 +10,54 @@ from ray_tpu.serve.llm import LLMDeployment
 
 
 @pytest.mark.parametrize("n_tokens, bucket", [
-    (1, 1), (2, 2), (3, 4), (129, 256), (856, 1024), (2049, 4096),
-    (4096, 4096), (4097, 5120), (5120, 5120), (6144, 6144), (8193, 9216),
-    (11202, 11264), (16383, 16384)])
+    (1, 1), (2, 2), (3, 4), (129, 256), (256, 256), (257, 384), (380, 384),
+    (385, 512), (513, 768), (769, 1024), (856, 1024), (1025, 1536),
+    (1537, 2048), (2049, 3072), (3073, 4096), (3452, 4096), (4096, 4096),
+    (4097, 5120), (5120, 5120), (6144, 6144), (8193, 9216), (11202, 11264),
+    (16383, 16384)])
 def test_prefill_bucket(n_tokens, bucket):
-    """Powers of two to 4,096, multiples of 1,024 past it: a long
-    prompt pads by less than 1,024 tokens."""
+    """Powers of two to 256, the next quarter of the prompt's power of
+    two to 2,048 (by 128, 256 and 512), multiples of 1,024 past it."""
     assert llm.prefill_bucket(n_tokens) == bucket
     assert bucket >= n_tokens
     assert llm.prefill_bucket(bucket) == bucket
+
+
+def test_the_rule_of_the_buckets_for_every_length():
+    """Every prompt up to 16,384 tokens: its bucket holds it, is its own
+    bucket, is a multiple of 128 past 128 (every tile on the prefill
+    path divides it), pads by under a step of 128 or a third of itself,
+    and is a power of two up to 256 and a multiple of 1,024 past 4,096,
+    as before the quarters came."""
+    for n in range(1, 16385):
+        bucket = llm.prefill_bucket(n)
+        assert n <= bucket == llm.prefill_bucket(bucket), n
+        assert bucket <= 128 or bucket % 128 == 0, n
+        assert bucket - n < max(128, bucket / 3), n
+        if n <= 256:
+            assert bucket == 1 << (n - 1).bit_length(), n
+        if n > 4096:
+            assert bucket == -(-n // 1024) * 1024, n
+
+
+_TO_256 = [2 ** i for i in range(9)]
+_TO_1024 = _TO_256 + [384, 512, 768, 1024]
+_TO_4096 = _TO_1024 + [1536, 2048, 3072, 4096]
 
 
 @pytest.mark.parametrize("limit, max_seq, ladder", [
     (1, 128, [1]),
     (16, 128, [1, 2, 4, 8, 16]),
     (100, 128, [1, 2, 4, 8, 16, 32, 64, 128]),
-    (856, 1024, [2 ** i for i in range(11)]),
-    (900, 1000, [2 ** i for i in range(10)] + [1000]),
-    (11202, 16384, [2 ** i for i in range(13)]
+    (380, 1024, _TO_256 + [384]),
+    (700, 1024, _TO_1024[:-1]),
+    (856, 1024, _TO_1024),
+    (900, 1000, _TO_1024[:-1] + [1000]),
+    (1234, 2048, _TO_1024 + [1536]),
+    (11202, 16384, _TO_4096
      + [5120, 6144, 7168, 8192, 9216, 10240, 11264]),
-    (16384, 16384, [2 ** i for i in range(13)]
-     + list(range(5120, 16385, 1024))),
-    (6000, 5000, [2 ** i for i in range(13)] + [5000])])
+    (16384, 16384, _TO_4096 + list(range(5120, 16385, 1024))),
+    (6000, 5000, _TO_4096 + [5000])])
 def test_bucket_ladder(limit, max_seq, ladder):
     """What `warmup` compiles: every bucket a prompt of up to `limit`
     tokens can take, so that `_serve_bucket` finds each compiled."""
