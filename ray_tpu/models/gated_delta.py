@@ -60,6 +60,23 @@ Every ratio of decays is the exponential of a difference that is not
 positive, so a gamma near 0 underflows to the right limit. Products
 take their inputs in `cfg.dtype` and accumulate in float32. Forward
 only: no backward pass is written for the chunked scan.
+
+A decay a key channel (Kimi Delta Attention, `kda`: gamma_t[h] in
+(0, 1)^dk, S_t = Diag(gamma_t) S_{t-1} + ...) goes through the same
+kernel, which takes the decay as a column a head either way, and the
+same chunked form, by the shape of what the mixer hands down
+(`log_gamma` [B, T, H, dk] where a scalar decay is [B, T, H]). What
+the scan across chunks carries takes a vector where it took a scalar
+and stays as exact: W = T (beta K * G), Q * G, K * G_C / G_j and
+S <- Diag(G_C) S + ..., every G now [C, dk]. The two [C, C] matrices
+inside a chunk do not factor any more: A_ij = beta_i sum_c k_ic k_jc
+exp(g_ic - g_jc), and Q K^T likewise. Written as (k_i * exp(g_i)) .
+(k_j * exp(-g_j)) the second factor overflows where a channel forgets
+fast; [C, C, dk] whole is 2 MB a head and chunk. `_decayed_products`
+builds them by sub-blocks of 16 rows: inside a sub-block on the
+diagonal the [16, 16, dk] differences themselves, below it each side
+decayed to the first row of the upper sub-block, so the rule above
+holds of every exponent.
 """
 
 from __future__ import annotations
@@ -81,6 +98,8 @@ from ray_tpu.ops.delta_update import (  # noqa: F401
 
 # Rows below which `_unit_lower_inverse` substitutes row by row.
 _INVERSE_BASE = 16
+# Rows of a sub-block of `_decayed_products`.
+_SUB_BLOCK = 16
 _L2_EPS = 1e-6
 
 CONVS = ("conv_q", "conv_k", "conv_v")
@@ -184,13 +203,62 @@ def _unit_lower_inverse(a):
         jnp.concatenate([below, t2], -1)], -2)
 
 
+def _decayed_products(xs, k, g):
+    """sum_c x_ic k_jc exp(g_ic - g_jc) for j <= i, of each x of `xs`:
+    xs and k [..., C, dk], g [..., C, dk] the log decays summed through
+    each row (so no row's is above the row's before) -> [..., C, C]
+    float32 each, whatever stands above the diagonal to be masked by
+    the caller. By sub-blocks of `_SUB_BLOCK` rows, so that every
+    exponent is a difference that is not positive: inside a sub-block
+    on the diagonal the differences themselves, [rows, rows, dk]; below
+    it, with r the first row of i's sub-block, (x_i exp(g_i - g_r)) .
+    (k_j exp(g_r - g_j)), one product a sub-block of rows against all
+    the keys before it."""
+    c, dk = k.shape[-2:]
+    n = math.gcd(c, _SUB_BLOCK)
+    f32 = jnp.float32
+
+    def rows(x):  # [..., C, dk] -> [..., C / n, n, dk]
+        return x.reshape(x.shape[:-2] + (c // n, n, dk))
+
+    g_rows = rows(g)
+    first = g_rows[..., :1, :]                       # [..., C / n, 1, dk]
+    # Keys before each sub-block, decayed to its first row; a key at or
+    # past that row is no key of this product and takes exp(0).
+    before = jnp.exp(jnp.minimum(first - g[..., None, :, :], 0.0))
+    k_to_first = (k[..., None, :, :].astype(f32) * before).astype(k.dtype)
+    own = jnp.tril(jnp.ones((n, n), bool))[..., None]
+    within = jnp.exp(jnp.where(
+        own, g_rows[..., :, None, :] - g_rows[..., None, :, :], -jnp.inf))
+    k_rows = rows(k).astype(f32)
+    below = (jnp.arange(c) // n)[:, None] > (jnp.arange(c) // n)[None, :]
+    outs = []
+    for x in xs:
+        x_rows = rows(x).astype(f32)
+        from_first = (x_rows * jnp.exp(g_rows - first)).astype(x.dtype)
+        off = jnp.einsum("...sik,...sjk->...sij", from_first, k_to_first,
+                         preferred_element_type=f32)  # [..., C / n, n, C]
+        on = (x_rows[..., :, None, :] * k_rows[..., None, :, :]
+              * within).sum(-1)                       # [..., C / n, n, n]
+        on = jnp.einsum("...sij,st->...sitj", on,
+                        jnp.eye(c // n, dtype=f32))   # onto the diagonal
+        outs.append(jnp.where(
+            below, off.reshape(off.shape[:-3] + (c, c)),
+            on.reshape(on.shape[:-4] + (c, c))))
+    return outs
+
+
 def _scan(cfg, s0, q, k, v, log_gamma, beta):
     """The chunked form over T tokens from the carried state: s0
     [B, H, dk, dv] float32, q and k [B, T, H, dk] (normalised, q
-    scaled), v [B, T, H, dv], log_gamma and beta [B, T, H] float32
+    scaled), v [B, T, H, dv], log_gamma [B, T, H] (a decay a head) or
+    [B, T, H, dk] (a decay a key channel) and beta [B, T, H] float32
     (both 0 at a position that is not to count) -> (o [B, T, H, dv]
     float32, S after the last position [B, H, dk, dv] float32)."""
     bsz, t, h, _ = q.shape
+    by_channel = log_gamma.ndim == 4
+    if not by_channel:  # one column that every key channel reads
+        log_gamma = log_gamma[..., None]
     c = min(cfg.chunk_size, t)
     pad = -t % c
     if pad:  # beta = 0, gamma = 1: the tail neither writes nor decays
@@ -205,25 +273,27 @@ def _scan(cfg, s0, q, k, v, log_gamma, beta):
         return jnp.moveaxis(x.reshape((bsz, nc, c) + x.shape[2:]), 2, 3)
 
     q, k, v, beta = chunks(q), chunks(k), chunks(v), chunks(beta)
-    g = jnp.cumsum(chunks(log_gamma), -1)                    # [B, nc, H, C]
+    g = jnp.cumsum(chunks(log_gamma), -2)         # [B, nc, H, C, dk or 1]
     seen = jnp.tril(jnp.ones((c, c), bool))
-    ratio = jnp.exp(jnp.where(seen, g[..., :, None] - g[..., None, :],
-                              -jnp.inf))                      # G_i / G_j
-    kk = jnp.einsum("bnhik,bnhjk->bnhij", k, k, preferred_element_type=f32)
-    a = jnp.where(jnp.tril(seen, -1), beta[..., None] * kk * ratio, 0.0)
-    t_inv = _unit_lower_inverse(a)
-    w = jnp.einsum("bnhij,bnhjk->bnhik",
-                   (t_inv * (beta * jnp.exp(g))[..., None, :]).astype(dtype),
-                   k, preferred_element_type=f32).astype(dtype)
-    u = jnp.einsum("bnhij,bnhjv->bnhiv",
-                   (t_inv * beta[..., None, :]).astype(dtype), v,
+    if by_channel:
+        kk, qk = _decayed_products((k, q), k, g)
+    else:
+        ratio = jnp.exp(jnp.where(seen, g[..., :, None, 0] - g[..., None, :, 0],
+                                  -jnp.inf))                  # G_i / G_j
+        kk, qk = (jnp.einsum("bnhik,bnhjk->bnhij", x, k,
+                             preferred_element_type=f32) * ratio
+                  for x in (k, q))
+    a = jnp.where(jnp.tril(seen, -1), beta[..., None] * kk, 0.0)
+    written = (_unit_lower_inverse(a) * beta[..., None, :]).astype(dtype)
+    w = jnp.einsum("bnhij,bnhjk->bnhik", written,
+                   (k.astype(f32) * jnp.exp(g)).astype(dtype),
+                   preferred_element_type=f32).astype(dtype)
+    u = jnp.einsum("bnhij,bnhjv->bnhiv", written, v,
                    preferred_element_type=f32)
-    qk = (jnp.einsum("bnhik,bnhjk->bnhij", q, k,
-                     preferred_element_type=f32) * ratio).astype(dtype)
-    q_in = (q.astype(f32) * jnp.exp(g)[..., None]).astype(dtype)
-    k_out = (k.astype(f32) * jnp.exp(g[..., -1:] - g)[..., None]).astype(
-        dtype)
-    whole = jnp.exp(g[..., -1])                               # [B, nc, H]
+    qk = jnp.where(seen, qk, 0.0).astype(dtype)
+    q_in = (q.astype(f32) * jnp.exp(g)).astype(dtype)
+    k_out = (k.astype(f32) * jnp.exp(g[..., -1:, :] - g)).astype(dtype)
+    whole = jnp.exp(g[..., -1, :])                    # [B, nc, H, dk or 1]
 
     def chunk(s, xs):
         w, u, qk, q_in, k_out, whole = xs
@@ -235,7 +305,7 @@ def _scan(cfg, s0, q, k, v, log_gamma, beta):
                        preferred_element_type=f32) \
             + jnp.einsum("bhij,bhjv->bhiv", qk, written,
                          preferred_element_type=f32)
-        s = s * whole[..., None, None] + jnp.einsum(
+        s = s * whole[..., None] + jnp.einsum(
             "bhik,bhiv->bhkv", k_out, written, preferred_element_type=f32)
         return s, o
 
@@ -245,16 +315,43 @@ def _scan(cfg, s0, q, k, v, log_gamma, beta):
     return (jnp.moveaxis(o, 2, 3).reshape(bsz, nc * c, h, -1)[:, :t], last)
 
 
+def _normed(cfg, o, weight):
+    """RMSNorm over each head's dv channels: o [B, T, H, dv] -> the
+    same, float32."""
+    o = o.astype(jnp.float32)
+    o = o * lax.rsqrt(jnp.mean(o * o, -1, keepdims=True) + cfg.norm_eps)
+    return o * weight.astype(jnp.float32)
+
+
 def _gated_norm(cfg, o, z, weight):
     """RMSNorm over each head's dv channels, then the gate: o, z
     [B, T, H, dv] -> [B, T, H, dv] float32."""
-    o = o.astype(jnp.float32)
-    o = o * lax.rsqrt(jnp.mean(o * o, -1, keepdims=True) + cfg.norm_eps)
-    return o * weight.astype(jnp.float32) \
-        * jax.nn.silu(z.astype(jnp.float32))
+    return _normed(cfg, o, weight) * jax.nn.silu(z.astype(jnp.float32))
 
 
-def mixer(cfg, start_pos, at):
+def _gates(cfg, a, lp):
+    """What Gated DeltaNet makes of a layer's input beside q, k, v and
+    beta: (the log decay, one number a head, [B, T, H] float32; z
+    [B, T, H, dv], of which the output gate is the silu)."""
+    z = jnp.einsum("btd,dhv->bthv", a, lp["wg"])
+    log_gamma = -jnp.exp(lp["A_log"].astype(jnp.float32)) \
+        * jax.nn.softplus(jnp.einsum("btd,dh->bth", a, lp["wa"])
+                          .astype(jnp.float32) + lp["dt_bias"])
+    return log_gamma, z
+
+
+def counts(tokens, start_pos, at):
+    """What a call of a stack with delta layers counts, int32 scalars:
+    the rows that started from zeros, and the real tokens a prefill
+    carried through the chunked scan (its padding past `at` left out;
+    none of a call of one token)."""
+    at = jnp.broadcast_to(jnp.asarray(at, jnp.int32), start_pos.shape)
+    return {"delta_state_resets": (start_pos == 0).sum(dtype=jnp.int32),
+            "delta_scan_tokens": (at + 1).sum(dtype=jnp.int32)
+            if tokens.shape[1] > 1 else jnp.zeros((), jnp.int32)}
+
+
+def mixer(cfg, start_pos, at, gates=None, gated_norm=None):
     """The mixer of a run of delta layers. Its state is the run's four
     stacks (`LEAVES`: S [layers, B, H, dk, dv] and the three carries
     [layers, B, K - 1, channels]), which `decoder.layers` carries
@@ -263,7 +360,11 @@ def mixer(cfg, start_pos, at):
     place in the stack and never slices it out. `start_pos` [B]: a row
     at 0 starts from zeros; `at`: the position of the call's tokens
     after which the state is left, an int or an int32 scalar for all
-    rows or int32 [B], one a row."""
+    rows or int32 [B], one a row. `gates(cfg, a, lp)` and
+    `gated_norm(cfg, o, z, weight)` are this module's `_gates` and
+    `_gated_norm` unless given (`kda` gives its own): the log decay is
+    [B, T, H], one number a head, or [B, T, H, dk], one a key channel,
+    and the recurrence and the chunked form follow its shape."""
     h, dk, dv = cfg.delta_heads, cfg.delta_key_dim, cfg.delta_value_dim
     at = jnp.broadcast_to(jnp.asarray(at, jnp.int32), start_pos.shape)
     fresh = start_pos == 0
@@ -284,12 +385,11 @@ def mixer(cfg, start_pos, at):
         q = _queries(q.reshape(bsz, t, h, dk))
         k = _keys(k.reshape(bsz, t, h, dk))
         v = v.reshape(bsz, t, h, dv)
-        z = jnp.einsum("btd,dhv->bthv", a, lp["wg"])
+        log_gamma, z = (gates or _gates)(cfg, a, lp)
         real = (jnp.arange(t) <= at[:, None])[..., None]
-        log_gamma = -jnp.exp(lp["A_log"].astype(jnp.float32)) \
-            * jax.nn.softplus(jnp.einsum("btd,dh->bth", a, lp["wa"])
-                              .astype(jnp.float32) + lp["dt_bias"])
-        log_gamma = jnp.where(real, log_gamma, 0.0)
+        log_gamma = jnp.where(
+            real.reshape(real.shape + (1,) * (log_gamma.ndim - 3)),
+            log_gamma, 0.0)
         beta = jax.nn.sigmoid(jnp.einsum("btd,dh->bth", a, lp["wb"])
                               .astype(jnp.float32))
         beta = jnp.where(real, 2.0 * beta if cfg.allow_neg_eigval else beta,
@@ -314,7 +414,7 @@ def mixer(cfg, start_pos, at):
             s_stack = lax.dynamic_update_index_in_dim(
                 s_stack, s.astype(s_stack.dtype), layer, 0)
         with jax.named_scope("delta_norm"):
-            out = _gated_norm(cfg, o, z, lp["o_norm"])
+            out = (gated_norm or _gated_norm)(cfg, o, z, lp["o_norm"])
         state = (s_stack,) + tuple(
             lax.dynamic_update_index_in_dim(
                 stack, carry.astype(stack.dtype), layer, 0)

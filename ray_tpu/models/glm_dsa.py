@@ -27,11 +27,12 @@ has the equations in full):
   scores' bit patterns (32 compare-and-count passes, exact): a mask of
   the keys above it and the first of those tied with it, which are the
   keys `lax.top_k` returns; never a sort and never a gather of keys.
-- *Attention* over the selected keys is masked dense: by blocks of
-  queries and, inside, blocks of keys up to the last one the block's
-  positions can see, with a running softmax in float32, so that no
-  array of [T, S] a head exists and a row pays for the keys before it,
-  not for the slot's whole region. Rows stand at their own positions
+- *Attention* over the selected keys is masked dense
+  (`serving.latent_attention`, which `kimi_linear` calls too): by
+  blocks of queries and, inside, blocks of keys up to the last one the
+  block's positions can see, with a running softmax in float32, so that
+  no array of [T, S] a head exists and a row pays for the keys before
+  it, not for the slot's whole region. Rows stand at their own positions
   (`positions`), as `llama._cached_attention` guarantees.
 
 Layers differ (dense or sparse FFN, `full` or `shared` indexer), so the
@@ -58,6 +59,7 @@ from ray_tpu.models import decoder, moe
 from ray_tpu.models.llama import swiglu
 from ray_tpu.models.serving import (
     KEY_BLOCK as _KEY_BLOCK, Family, by_query_blocks as _by_query_blocks,
+    key_blocks as _key_blocks, latent_attention as _attend,
     rotate_pairs as _rotate_pairs)
 from ray_tpu.ops import block_rows
 from ray_tpu.ops.norms import layer_norm, rms_norm_reference
@@ -199,12 +201,6 @@ def _rotate_head(x, cos, sin, n):
         [_rotate_pairs(x[..., :n], cos, sin), x[..., n:]], -1)
 
 
-def _key_blocks(positions, max_seq, block):
-    """How many blocks of `block` keys hold every key the rows at
-    `positions` can see."""
-    return jnp.minimum(positions.max() // block + 1, max_seq // block)
-
-
 def _index_scores(qi, w, index_cache, positions):
     """I[b, t, s] = sum_j w[b, t, j] relu(qi[b, t, j] . k[b, s]) in
     float32 for s <= positions[b, t], -inf past it. qi [B, T, J, D], w
@@ -257,44 +253,6 @@ def _select(cfg, scores, positions):
     seen = jnp.arange(scores.shape[-1])[None, None, :] \
         <= positions[:, :, None]
     return _top_k_mask(scores, cfg.index_topk) & seen
-
-
-def _attend(q_lat, q_rope, latent, rope_keys, mask, positions, scale):
-    """Attention of q over the cached keys `mask` allows, against the
-    latent: q_lat [B, T, H, C], q_rope [B, T, H, R], mask [B, T, S] ->
-    [B, T, H, C] float32. `latent` and `rope_keys` are each (the stack
-    [layers, B, S, width], the layer), read a block of keys at a time.
-    Scores, softmax and both accumulations are float32; the caches
-    enter both products in the dtype they are stored in."""
-    b, t, h, c = q_lat.shape
-    s = latent[0].shape[2]
-    tk = math.gcd(s, _KEY_BLOCK)
-
-    def body(j, carry):
-        top, total, acc = carry
-        lat = decoder.layer_rows(*latent, j * tk, tk)
-        rot = decoder.layer_rows(*rope_keys, j * tk, tk)
-        allowed = lax.dynamic_slice_in_dim(mask, j * tk, tk, 2)[:, None]
-        scores = (jnp.einsum("bthc,bsc->bhts", q_lat, lat,
-                             preferred_element_type=jnp.float32)
-                  + jnp.einsum("bthr,bsr->bhts", q_rope, rot,
-                               preferred_element_type=jnp.float32)) * scale
-        new_top = jnp.maximum(
-            top, jnp.where(allowed, scores, -1e30).max(-1))
-        probs = jnp.where(allowed, jnp.exp(scores - new_top[..., None]), 0.0)
-        shrink = jnp.exp(top - new_top)
-        total = total * shrink + probs.sum(-1)
-        acc = acc * shrink[..., None] + jnp.einsum(
-            "bhts,bsc->bhtc", probs.astype(lat.dtype), lat,
-            preferred_element_type=jnp.float32)
-        return new_top, total, acc
-
-    _, total, acc = lax.fori_loop(
-        0, _key_blocks(positions, s, tk), body,
-        (jnp.full((b, h, t), -1e30, jnp.float32),
-         jnp.zeros((b, h, t), jnp.float32),
-         jnp.zeros((b, h, t, c), jnp.float32)))
-    return (acc / total[..., None]).transpose(0, 2, 1, 3)
 
 
 def _mixer(cfg: GlmDsaConfig, indexer, start_pos, positions):

@@ -282,7 +282,7 @@ def _halves(cfg: Lfm2MoeConfig, start_pos, positions, at):
             for kind in set(cfg.kinds)}
 
 
-def _counts(tokens, start_pos, at):
+def _counts(cfg, tokens, cache, start_pos, at):
     """What a call counts, int32 scalars: the rows whose convolutions
     started from zeros, and the real tokens a prefill carried through
     them (its padding past `at` left out; none of a call of one

@@ -492,6 +492,14 @@ def _sparse_experts(x, gates, top_i, we1, *rest, gate=jax.nn.silu, over=()):
 # rows than this (a decode step's few pairs then always fit in one).
 _HELD_ROWS_SLACK = 2
 _HELD_ROWS_MIN = 256
+# The TPU compiler keeps a buffer's gathered rows in its 16 MiB of fast
+# memory when they fit there alone, and then has no room for the
+# gather's own 2.9 MiB beside 13.5 MiB of them: a prefill of 1,536
+# tokens at D = 2,304 (3,072 rows) was refused, "ran out of memory in
+# memory space vmem", where 1,024 tokens compiled and 12,288 rows of
+# 6,144 never go there (PERF.md, PR 57). A buffer of so many bytes
+# takes the rows that put it past the fast memory instead.
+_HELD_BYTES_REFUSED = (13 << 20, 16 << 20)
 
 
 def _held_experts(cfg: MoEConfig, x, gates, top_i, layer, *stacks):
@@ -525,6 +533,10 @@ def _held_experts(cfg: MoEConfig, x, gates, top_i, layer, *stacks):
     rows = min(pairs, max(
         _HELD_ROWS_MIN,
         -(-_HELD_ROWS_SLACK * pairs * count // cfg.n_experts)))
+    row_bytes = x.shape[-1] * x.dtype.itemsize
+    low, high = _HELD_BYTES_REFUSED
+    if low < rows * row_bytes < high:
+        rows = min(pairs, -(-high // (8 * row_bytes)) * 8)
     with jax.named_scope("moe_dispatch"):
         local = top_i.reshape(-1) - first
         local = jnp.where((local >= 0) & (local < count), local, count)

@@ -190,15 +190,8 @@ def _halves(cfg: OlmoHybridConfig, start_pos, positions, at):
             "full": (_attention(cfg, start_pos, positions), ffn)}
 
 
-def _counts(tokens, start_pos, at):
-    """What a call counts, int32 scalars: the rows that started from
-    zeros, and the real tokens a prefill carried through the chunked
-    scan (its padding past `at` left out; none of a call of one
-    token)."""
-    at = jnp.broadcast_to(jnp.asarray(at, jnp.int32), start_pos.shape)
-    return {"delta_state_resets": (start_pos == 0).sum(dtype=jnp.int32),
-            "delta_scan_tokens": (at + 1).sum(dtype=jnp.int32)
-            if tokens.shape[1] > 1 else jnp.zeros((), jnp.int32)}
+def _counts(cfg, tokens, cache, start_pos, at):
+    return gated_delta.counts(tokens, start_pos, at)
 
 
 FAMILY = Family(

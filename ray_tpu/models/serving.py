@@ -75,8 +75,9 @@ parameters {"embed", "runs": [a dict of stacked leaves a run],
   broadcasts `at` when it is made;
 - what only some have: ``tied`` (the head is the embedding, times
   `cfg.logit_scale`), ``handed(tokens, cache)`` (what enters the first
-  layer as `handed`), ``counts(tokens, start_pos, at)`` (counts of the
-  family's own, beside what its FFNs count), ``keys_attended`` and
+  layer as `handed`), ``counts(cfg, tokens, cache, start_pos, at)``
+  (counts of the family's own, beside what its FFNs count; `cache` the
+  one the call was handed), ``keys_attended`` and
   ``keys_read``.
 
 A model that generates by blocks (``ServedModel.block_length`` B, None
@@ -120,7 +121,12 @@ above and differs in what a step is:
   (`llm_kv_block_tokens` a multiple of B).
 
 Below the stack stands what the families' layers share and no one of
-them owns.
+them owns: the initialiser, the attention projections, the rotary turn
+of interleaved pairs, the choice between a prefill's flash kernel and
+the plain path, the loop over blocks of queries, and absorbed latent
+attention against the cached latent by blocks of keys
+(`latent_attention`: GLM-5.2 hands it the mask its indexer chose, Kimi
+Linear none, every key up to the row's position).
 """
 
 from __future__ import annotations
@@ -263,16 +269,17 @@ class Family:
         model's step: of every position, [B, T, vocab], or, of a call
         with a start a block, of its last block, [B, T / blocks,
         vocab]), and as counts the FFNs' and the family's own."""
-        x, cache, counts = self._stack(params, tokens, cfg, cache, start_pos,
-                                       at)
+        x, new_cache, counts = self._stack(params, tokens, cfg, cache,
+                                           start_pos, at)
         if at is not None:
             x = lax.dynamic_index_in_dim(x, at, 1, keepdims=False)
         elif start_pos.ndim == 2:
             x = x[:, -(tokens.shape[1] // start_pos.shape[1]):]
         logits = self._logits(params, x, cfg)
         if self.counts is not None:
-            counts = {**counts, **self.counts(tokens, start_pos, at)}
-        return logits, cache, counts
+            counts = {**counts, **self.counts(cfg, tokens, cache, start_pos,
+                                              at)}
+        return logits, new_cache, counts
 
     def forward_with_cache(self, params, tokens, cfg, cache, start_pos,
                            at=None, keep=None):
@@ -368,6 +375,60 @@ def by_query_blocks(fn, t, *arrays):
         (o.shape[1], t) + o.shape[3:]) for o in outs)
 
 
+def key_blocks(positions, max_seq, block):
+    """How many blocks of `block` keys hold every key the rows at
+    `positions` can see."""
+    return jnp.minimum(positions.max() // block + 1, max_seq // block)
+
+
+def latent_attention(q_lat, q_rope, latent, rope_keys, mask, positions, scale,
+                     block=KEY_BLOCK):
+    """Absorbed latent attention (`glm_dsa`, `kimi_linear`: the key
+    half of `wkvb` already in the query, its value half still to come
+    onto the output) of q over the cached keys `mask` allows, against
+    the latent: q_lat [B, T, H, C], q_rope [B, T, H, R], mask [B, T, S], or
+    None for every key up to the row's position (`kimi_linear`, whose
+    latent layers select nothing and so need no array of [T, S]) ->
+    [B, T, H, C] float32. `latent` and `rope_keys` are each (the stack
+    [layers, B, S, width], the layer), read a block of keys at a time.
+    Scores, softmax and both accumulations are float32; the caches
+    enter both products in the dtype they are stored in. `block`: the
+    keys of a block, at most (every row reads whole blocks up to the
+    one that holds the furthest position any row can see)."""
+    b, t, h, c = q_lat.shape
+    s = latent[0].shape[2]
+    tk = math.gcd(s, block)
+
+    def body(j, carry):
+        top, total, acc = carry
+        lat = decoder.layer_rows(*latent, j * tk, tk)
+        rot = decoder.layer_rows(*rope_keys, j * tk, tk)
+        if mask is None:
+            allowed = (j * tk + jnp.arange(tk) <= positions[..., None])[:, None]
+        else:
+            allowed = lax.dynamic_slice_in_dim(mask, j * tk, tk, 2)[:, None]
+        scores = (jnp.einsum("bthc,bsc->bhts", q_lat, lat,
+                             preferred_element_type=jnp.float32)
+                  + jnp.einsum("bthr,bsr->bhts", q_rope, rot,
+                               preferred_element_type=jnp.float32)) * scale
+        new_top = jnp.maximum(
+            top, jnp.where(allowed, scores, -1e30).max(-1))
+        probs = jnp.where(allowed, jnp.exp(scores - new_top[..., None]), 0.0)
+        shrink = jnp.exp(top - new_top)
+        total = total * shrink + probs.sum(-1)
+        acc = acc * shrink[..., None] + jnp.einsum(
+            "bhts,bsc->bhtc", probs.astype(lat.dtype), lat,
+            preferred_element_type=jnp.float32)
+        return new_top, total, acc
+
+    _, total, acc = lax.fori_loop(
+        0, key_blocks(positions, s, tk), body,
+        (jnp.full((b, h, t), -1e30, jnp.float32),
+         jnp.zeros((b, h, t), jnp.float32),
+         jnp.zeros((b, h, t, c), jnp.float32)))
+    return (acc / total[..., None]).transpose(0, 2, 1, 3)
+
+
 # ---------------------------------------------------------------------------
 # The registry
 # ---------------------------------------------------------------------------
@@ -399,7 +460,8 @@ _SERVED = {"LlamaConfig": _llama, "GlmDsaConfig": _family("glm_dsa"),
            "Cohere2MoeConfig": _family("cohere2_moe"),
            "OlmoHybridConfig": _family("olmo_hybrid"),
            "SdarMoeConfig": _family("sdar_moe"),
-           "Lfm2MoeConfig": _family("lfm2_moe")}
+           "Lfm2MoeConfig": _family("lfm2_moe"),
+           "KimiLinearConfig": _family("kimi_linear")}
 
 
 def served_model(cfg) -> ServedModel:

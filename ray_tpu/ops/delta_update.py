@@ -3,8 +3,13 @@ lies: a head's state `[dk, dv]` is read once out of a run's stack
 `[layers, slots, heads, dk, dv]`, decayed, corrected and written back to
 the place it came from.
 
-    s = (fresh ? 0 : S) * gamma;   u = beta * (v - sum_i s[i, :] k[i])
-    s = s + k (outer) u;           o = sum_i s[i, :] q[i]
+    s = (fresh ? 0 : S) * gamma[:, None];   u = beta * (v - sum_i s[i, :] k[i])
+    s = s + k (outer) u;                    o = sum_i s[i, :] q[i]
+
+The decay is a number a key channel, `gamma [dk]` a head (Kimi Delta
+Attention), or one number a head (Gated DeltaNet), which is the column
+that repeats it: one kernel serves both, and a scalar decay costs
+`dk` floats a head beside the `dk x dv` state they decay.
 
 Written as `jnp` ops on a layer sliced out of the stack (`reference`,
 and `dynamic_update_index_in_dim` to put the layer back) the TPU
@@ -19,10 +24,10 @@ flight while this one is worked on. Nothing slices the stack and no
 other layer of it is touched.
 
 Float32 wherever the state is touched, whatever the stack stores, and no
-matmul unit: the two sums over `dk` are sublane reductions. `k` and `q`
-arrive a column a head (`dk` on sublanes, so that their broadcast over
-`dv` is a lane broadcast), `v` a row a head, `gamma` and `beta` as
-scalars.
+matmul unit: the two sums over `dk` are sublane reductions. `k`, `q` and
+`gamma` arrive a column a head (`dk` on sublanes, so that their
+broadcast over `dv` is a lane broadcast), `v` a row a head, `beta` as a
+scalar.
 
 On a TPU backend this is always the compiled kernel; on other backends
 it is `reference` unless `interpret=True` runs the kernel through the
@@ -45,9 +50,10 @@ from ray_tpu.ops.attention import on_tpu
 def reference(s0, q, k, v, gamma, beta):
     """The recurrence for one token, float32 throughout and elementwise
     (no matmul unit rounds the state): s0 [B, H, dk, dv], q and k
-    [B, H, dk], v [B, H, dv], gamma and beta [B, H] -> (o [B, H, dv],
+    [B, H, dk], v [B, H, dv], gamma [B, H] (a decay a head) or
+    [B, H, dk] (a decay a key channel), beta [B, H] -> (o [B, H, dv],
     S [B, H, dk, dv])."""
-    s = s0 * gamma[..., None, None]
+    s = s0 * gamma.reshape(gamma.shape[:2] + (-1, 1))
     u = beta[..., None] * (v - (s * k[..., None]).sum(-2))
     s = s + k[..., None] * u[..., None, :]
     return (s * q[..., None]).sum(-2), s
@@ -75,20 +81,21 @@ def _head_block(heads: int, dk: int, dv: int, dtype) -> int:
     return -(-heads // -(-heads // most))
 
 
-def _kernel(layer_ref, fresh_ref, gamma_ref, beta_ref, cols_ref, v_ref, s_ref,
-            o_ref, new_ref, *, heads: int):
+def _kernel(layer_ref, fresh_ref, beta_ref, cols_ref, v_ref, s_ref, o_ref,
+            new_ref, *, heads: int):
     del layer_ref  # the state block's index map reads it
     slot, hb = pl.program_id(0), s_ref.shape[0]
     kept = fresh_ref[slot] == 0
     first = pl.program_id(1) * hb
     for h in range(hb):
         # A head past the last (the last block's, where the heads are
-        # not a multiple of a block) reads the last one's scalars and
+        # not a multiple of a block) reads the last one's scalar and
         # writes nowhere.
         at = slot * heads + jnp.minimum(first + h, heads - 1)
         k = cols_ref[:, h:h + 1]                                  # [dk, 1]
         q = cols_ref[:, hb + h:hb + h + 1]
-        s = jnp.where(kept, s_ref[h].astype(jnp.float32), 0.0) * gamma_ref[at]
+        gamma = cols_ref[:, 2 * hb + h:2 * hb + h + 1]
+        s = jnp.where(kept, s_ref[h].astype(jnp.float32), 0.0) * gamma
         u = beta_ref[at] * (v_ref[h:h + 1, :] - (s * k).sum(0, keepdims=True))
         s = s + k * u
         o_ref[h:h + 1, :] = (s * q).sum(0, keepdims=True)
@@ -96,7 +103,7 @@ def _kernel(layer_ref, fresh_ref, gamma_ref, beta_ref, cols_ref, v_ref, s_ref,
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
-def _call(stack, layer, fresh, gamma, beta, cols, v, *, interpret: bool):
+def _call(stack, layer, fresh, beta, cols, v, *, interpret: bool):
     """The kernel's call. Jitted, so that a program's runs of delta
     layers trace and lower it once."""
     _, slots, heads, dk, dv = stack.shape
@@ -114,11 +121,11 @@ def _call(stack, layer, fresh, gamma, beta, cols, v, *, interpret: bool):
         out_shape=(jax.ShapeDtypeStruct(v.shape, jnp.float32),
                    jax.ShapeDtypeStruct(stack.shape, stack.dtype)),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=4,
+            num_scalar_prefetch=3,
             grid=(slots, blocks),
-            in_specs=[small(dk, 2 * hb), small(hb, dv), state],
+            in_specs=[small(dk, 3 * hb), small(hb, dv), state],
             out_specs=(small(hb, dv), state)),
-        input_output_aliases={6: 1},
+        input_output_aliases={5: 1},
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel"),
             vmem_limit_bytes=4 * block + (8 << 20)),
@@ -128,7 +135,7 @@ def _call(stack, layer, fresh, gamma, beta, cols, v, *, interpret: bool):
             * stack.dtype.itemsize),
         interpret=interpret,
         name="delta_update",
-    )(layer[None], fresh, gamma, beta, cols, v, stack)
+    )(layer[None], fresh, beta, cols, v, stack)
 
 
 def delta_update(stack, layer, fresh, q, k, v, gamma, beta, *,
@@ -136,8 +143,11 @@ def delta_update(stack, layer, fresh, q, k, v, gamma, beta, *,
     """stack [layers, B, H, dk, dv], a run's state leaf whole; `layer`
     an int32 scalar; `fresh` [B], the rows that start from zeros
     whatever their slot holds; q and k [B, H, dk], v [B, H, dv], gamma
-    and beta [B, H], float32 -> (o [B, H, dv] float32, the stack with
+    [B, H] (one decay a head) or [B, H, dk] (one a key channel), beta
+    [B, H], float32 -> (o [B, H, dv] float32, the stack with
     `stack[layer]` the new states and every other layer as it was).
+    Which decay it is follows from gamma's shape and chooses nothing:
+    the kernel takes a column a head either way.
 
     On a TPU backend that is always the compiled kernel: `interpret`
     never reaches a TPU call, and a kernel Mosaic refuses is an error,
@@ -158,10 +168,12 @@ def delta_update(stack, layer, fresh, q, k, v, gamma, beta, *,
         x = jnp.pad(x, ((0, 0), (0, blocks * hb - h), (0, 0)))
         return x.reshape(bsz, blocks, hb, -1)
 
-    # A block's keys, then its queries, a column a head.
-    cols = jnp.concatenate([by_block(k), by_block(q)], 2).swapaxes(2, 3)
+    # A block's keys, then its queries, then its decays, a column a
+    # head.
+    gamma = jnp.broadcast_to(gamma.reshape(bsz, h, -1), k.shape)
+    cols = jnp.concatenate([by_block(k), by_block(q), by_block(gamma)],
+                           2).swapaxes(2, 3)
     o, stack = _call(
         stack, jnp.asarray(layer, jnp.int32), fresh.astype(jnp.int32),
-        gamma.reshape(-1), beta.reshape(-1), cols, by_block(v),
-        interpret=interpret)
+        beta.reshape(-1), cols, by_block(v), interpret=interpret)
     return o.reshape(bsz, blocks * hb, dv)[:, :h], stack

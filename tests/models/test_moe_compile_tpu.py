@@ -641,6 +641,85 @@ def test_lfm2s_step_compiles_for_the_v5e(one_chip, program, bucket,
         < 16.0e9
 
 
+@pytest.mark.parametrize("program,bucket", [("decode", 0),
+                                            ("prefill", 1536)])
+def test_kimi_linears_step_compiles_for_the_v5e(one_chip, program, bucket,
+                                                monkeypatch):
+    """Kimi-Linear-48B-A3B's share as `kimi-linear-48b-a3b-serve.json`
+    cuts it, through the engine's own programs at the cell's 64 slots
+    of 4,096: it compiles and fits. A decode step updates a KDA layer's
+    states, 32 heads of [128, 128] float32 a slot, where they lie in
+    the run's stack, through `ops/delta_update.py`'s one kernel with
+    the decay a column a head, the stack aliased through it: one call
+    a run of KDA layers, in its scan's body, and nothing else makes an
+    array of the stack or of a layer of it (537 MB). A latent layer's
+    new rows go into the `latent` and `rope` leaves through
+    `write_blocks`, and neither leaf is copied, converted or laid out
+    anew by either program (with the shared key channels in rows of 64
+    the TPU kept that leaf with its positions in the lanes and every
+    step copied it there and back: `kimi_linear._shared_row`); every expert layer's three products are
+    the held path's kernel on the run's stack."""
+    from ray_tpu.ops import attention, block_rows, delta_update
+    from ray_tpu.ops import grouped_matmul
+
+    monkeypatch.setattr(grouped_matmul, "on_tpu", lambda: True)
+    monkeypatch.setattr(delta_update, "on_tpu", lambda: True)
+    monkeypatch.setattr(attention, "on_tpu", lambda: True)
+    monkeypatch.setattr(block_rows, "on_tpu", lambda: True)
+    cfg, params, cache, compiled = _engines_program(
+        one_chip, "kimi-linear-48b-a3b-serve", program, bucket,
+        ("delta_scan_tokens", "delta_state_resets", "experts_held_steps",
+         "experts_touched", "latent_keys_read", "pair_overflows",
+         "pairs_held", "pairs_routed"))
+    text = compiled.as_text()
+    scheduled = _scheduled(text)
+    latent = [run["latent"].shape for run in cache["runs"]
+              if "latent" in run]
+    n, rows = latent[0][1:3]
+    assert (n, rows) == (64, 4096)
+    assert latent == [(1, n, rows, cfg.kv_lora_rank)] * 3
+    shared = (1, n, rows, 128)  # the 64 shared channels in rows of lanes
+    assert [run["rope"].shape for run in cache["runs"] if "rope" in run] \
+        == [shared] * 3
+    _writes_its_rows_through_the_kernel(
+        text, len(latent) if program == "decode" else 0, latent[0], shared)
+    for width in (cfg.kv_lora_rank, 128):
+        assert not re.findall(
+            rf"= \w+\[1,{n},{rows},{width}\]\S* (?:copy|convert|transpose)\(",
+            scheduled), width
+    kernels = re.findall(r"%([\w-]+?)(?:\.\d+)? = \S+ custom-call\(", text)
+    sparse = sum("we1" in run for run in params["runs"])
+    assert sparse == 6
+    assert [k for k in kernels if k in PRODUCTS] == ["gmm"] * (3 * sparse)
+    assert "ragged-dot" not in text
+    states = [run["state"].shape for run in cache["runs"] if "state" in run]
+    assert states == [(layers, n, 32, 128, 128) for layers in (1, 2, 3, 3)]
+    made = re.findall(r"%[\w.-]+ = (.*?) (?:copy|copy-start|fusion|"
+                      r"dynamic-update-slice)\(", scheduled)
+    for state in states if program == "decode" else ():
+        # One kernel a KDA run, in its scan's body, the state leaf
+        # aliased through it: a second reader of the carried stack
+        # there would show as a `copy` of it, the plain recurrence as
+        # fusions over a layer's states.
+        stack, layer = (f"f32[{','.join(map(str, dims))}]"
+                        for dims in (state, state[1:]))
+        assert made and not [shapes for shapes in made
+                             if stack in shapes or layer in shapes], state
+    if program == "decode":
+        calls = re.findall(r'%delta_update(?:\.\d+)? = .* custom-call\('
+                           r'.*op_name="([^"]*)"', scheduled)
+        assert len(calls) == len(states)
+        assert all(re.search(r"(?:while/body/(?:closed_call/)?)?delta/"
+                             r"delta_update/", path) for path in calls)
+    else:
+        assert "delta_update" not in kernels
+    memory = compiled.memory_analysis()
+    assert memory.argument_size_in_bytes > 8.4e9  # weights and cache
+    assert memory.temp_size_in_bytes < 1.0e9
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes \
+        < 16.0e9
+
+
 @pytest.mark.parametrize("window", [None, 4096], ids=["full", "window"])
 def test_trained_flash_kernels_compile_at_smallthinkers_shapes(one_chip,
                                                                window):

@@ -82,6 +82,13 @@ ROWS = {
         given=frozenset({"state_leaves", "keys_read"}),
         weights="095f16a464f413890cf1264bf270d315"
                 "73db4533b44ad90f5d1e0c492de252ad"),
+    # (Recorded at the PR that brought the family, PR 57.)
+    "KimiLinearConfig": Row(
+        "kimi_linear", lambda: _debug("kimi-linear-48b-a3b-serve.json"),
+        state=frozenset({"state", "conv_q", "conv_k", "conv_v"}),
+        given=frozenset({"state_leaves"}),
+        weights="1bf01fb107f049aadc1a280ab34db915"
+                "c388bad0375f0f738064ebc8b3d35eaf"),
 }
 SERVED = sorted(serving._SERVED)
 FAMILIES = [name for name in SERVED if name != "LlamaConfig"]
@@ -156,7 +163,8 @@ def test_the_optional_functions_a_family_gives(name):
 
 def test_no_served_module_imports_a_siblings_private_name():
     for module in ("glm_dsa", "nemotron_h", "cohere2_moe", "olmo_hybrid",
-                   "sdar_moe", "lfm2_moe", "gated_delta", "mamba2"):
+                   "sdar_moe", "lfm2_moe", "kimi_linear", "gated_delta", "kda",
+                   "mamba2"):
         tree = ast.parse(inspect.getsource(
             importlib.import_module(f"ray_tpu.models.{module}")))
         reached = [(node.module, alias.name) for node in ast.walk(tree)
