@@ -18,9 +18,11 @@ with N states, g(h) = h // (H / G):
 What a sequence leaves behind is S [H, P, N] and the last K - 1 rows of
 the un-convolved xBC: two leaves of the slot cache with *no sequence
 axis*, [layers, slots, H, P, N] (`cfg.state_dtype`) and [layers, slots,
-K - 1, C], the same size at any length. The mixer rewrites its layer of
-them whole on every call. Three things follow from a state that cannot
-be masked afterwards, as attention masks keys by length:
+K - 1, C], the same size at any length. A prefill rewrites its layer of
+both whole; a decode step rewrites its layer of the convolution's rows
+and updates its layer of S where it lies in the stack. Three things
+follow from a state that cannot be masked afterwards, as attention
+masks keys by length:
 
 - a row that starts at position 0 starts from zeros, whatever its slot
   held (a retired slot keeps stepping until it is admitted again);
@@ -31,8 +33,12 @@ be masked afterwards, as attention masks keys by length:
 - a row that starts past 0 continues from its leaf.
 
 A call of one token (a decode step) is the recurrence as written, in
-float32 on the state (scope `ssm_update`). A longer one (a prefill) is
-the chunked form (scope `ssm_scan`): inside a chunk of `cfg.chunk_size`
+float32 on the state (scope `ssm_update`; `ops/ssm_update.py`: on a TPU
+one kernel that reads a head's state once out of the run's stack and
+writes it back there, which is the stack's one reader and writer in the
+scan's body; elsewhere `_update` on the sliced layer). A longer one (a
+prefill) is the chunked form (scope `ssm_scan`) from the sliced layer,
+which it puts back: inside a chunk of `cfg.chunk_size`
 the outputs are a masked [Q, Q] product of C, B and the decays, across
 chunks a scan carries S in float32 from the row's carried state; the
 products take their inputs in `cfg.dtype` and accumulate in float32.
@@ -50,6 +56,10 @@ import jax.numpy as jnp
 from jax import lax
 
 from ray_tpu.models.serving import normal
+# `_update`: the recurrence as written, where `ssm_update` has it for
+# the backends with no kernel.
+from ray_tpu.ops.ssm_update import (  # noqa: F401
+    ssm_update, reference as _update)
 
 
 def conv_width(cfg) -> int:
@@ -116,16 +126,6 @@ def _conv(cfg, lp, carry, xbc, at, bias=True, activation=jax.nn.silu):
     return (out.astype(xbc.dtype), jax.vmap(
         lambda rows, last: lax.dynamic_slice_in_dim(rows, last + 1, k - 1)
     )(window, at))
-
-
-def _update(s0, xs, b_mat, c_mat, dt, a):
-    """The recurrence for one token, float32 throughout and elementwise
-    (no matmul unit rounds the state): s0 [B, H, P, N], xs [B, H, P], b
-    and c [B, H, N] (a group's, repeated for its heads), dt [B, H], a
-    [H] -> (y [B, H, P], S [B, H, P, N])."""
-    s = s0 * jnp.exp(dt * a)[..., None, None] \
-        + (dt[..., None] * xs)[..., None] * b_mat[:, :, None, :]
-    return (s * c_mat[:, :, None, :]).sum(-1), s
 
 
 def _scan(cfg, s0, xs, b_mat, c_mat, dt, a):
@@ -201,10 +201,11 @@ def mixer(cfg, start_pos, at):
     """The mixer of a run of Mamba-2 layers. Its state is the run's two
     stacks, (S [layers, B, H, P, N], conv rows [layers, B, K - 1, C]),
     which `decoder.layers` carries through the scan; it reads its layer
-    of each and writes it back whole. `start_pos` [B]: a row at 0
-    starts from zeros; `at`: the position of the call's tokens after
-    which the state is left, an int or an int32 scalar for all rows or
-    int32 [B], one a row."""
+    of each and writes it back whole, but in a call of one token, which
+    updates its layer of S in place in the stack and never slices it
+    out. `start_pos` [B]: a row at 0 starts from zeros; `at`: the
+    position of the call's tokens after which the state is left, an int
+    or an int32 scalar for all rows or int32 [B], one a row."""
     h, p = cfg.ssm_heads, cfg.ssm_head_dim
     g, n = cfg.ssm_groups, cfg.ssm_state
     at = jnp.broadcast_to(jnp.asarray(at, jnp.int32), start_pos.shape)
@@ -213,9 +214,6 @@ def mixer(cfg, start_pos, at):
         (ssm_stack, conv_stack), layer = state
         t = a.shape[1]
         fresh = start_pos == 0
-        s0 = jnp.where(fresh[:, None, None, None], 0.0,
-                       lax.dynamic_index_in_dim(ssm_stack, layer, 0, False)
-                       .astype(jnp.float32))
         carry = lax.dynamic_index_in_dim(conv_stack, layer, 0, False)
         carry = jnp.where(fresh[:, None, None], 0, carry)
         z = jnp.einsum("btd,dhp->bthp", a, lp["w_z"])
@@ -230,24 +228,28 @@ def mixer(cfg, start_pos, at):
         dt = jnp.where((jnp.arange(t) <= at[:, None])[..., None], dt, 0.0)
         a_neg = -jnp.exp(lp["A_log"].astype(jnp.float32))
         if t == 1:
+            # The stack's one reader and writer in the scan's body: a
+            # second one would have the compiler copy the stack to keep
+            # the kernel's alias honest.
             with jax.named_scope("ssm_update"):
-                y, s = _update(
-                    s0, xs[:, 0].astype(jnp.float32),
-                    jnp.repeat(b_mat[:, 0], h // g, 1).astype(jnp.float32),
-                    jnp.repeat(c_mat[:, 0], h // g, 1).astype(jnp.float32),
-                    dt[:, 0], a_neg)
+                y, ssm_stack = ssm_update(
+                    ssm_stack, layer, fresh, xs[:, 0].astype(jnp.float32),
+                    b_mat[:, 0].astype(jnp.float32),
+                    c_mat[:, 0].astype(jnp.float32), dt[:, 0], a_neg)
                 y = y[:, None]
         else:
+            s0 = jnp.where(fresh[:, None, None, None], 0.0,
+                           lax.dynamic_index_in_dim(ssm_stack, layer, 0, False)
+                           .astype(jnp.float32))
             with jax.named_scope("ssm_scan"):
                 y, s = _scan(cfg, s0, xs, b_mat, c_mat, dt, a_neg)
+            ssm_stack = lax.dynamic_update_index_in_dim(
+                ssm_stack, s.astype(ssm_stack.dtype), layer, 0)
         y = y + lp["D"].astype(jnp.float32)[:, None] \
             * xs.astype(jnp.float32)
         out = _gated_norm(cfg, y, z, lp["ssm_norm"])
-        state = (
-            lax.dynamic_update_index_in_dim(
-                ssm_stack, s.astype(ssm_stack.dtype), layer, 0),
-            lax.dynamic_update_index_in_dim(
-                conv_stack, carry.astype(conv_stack.dtype), layer, 0))
+        state = (ssm_stack, lax.dynamic_update_index_in_dim(
+            conv_stack, carry.astype(conv_stack.dtype), layer, 0))
         return out.reshape(y.shape).astype(a.dtype), state, handed
 
     mix.scope = "ssm"

@@ -4,7 +4,9 @@ three kinds of part, one a published layer
 (`benchmark/references/nemotron_h.py` has the equations in full):
 
 - `M`, a Mamba-2 mixer (`mamba2`): its state is two cache leaves with
-  no sequence axis, rewritten whole at every call;
+  no sequence axis, a layer's rewritten whole by a prefill; a decode
+  step updates a layer's S where it lies in the run's stack
+  (`ops/ssm_update.py`) and rewrites its convolution's rows;
 - `*`, grouped-query attention through cached keys and values, with no
   positional encoding (the state-space layers carry the order);
 - `E`, a LatentMoE (`moe`'s expert layer): a sigmoid router with a

@@ -17,6 +17,11 @@ on CPU and in interpret-mode tests):
                       head's state read once and written back where it
                       lies in a run's stack (the plain recurrence on the
                       sliced layer off the TPU)
+- ``ssm_update``    — the Mamba-2 one-token recurrence the same way: a
+                      head's state read once and written back where it
+                      lies, y's sum over the state's last axis on the
+                      matmul unit at float32's rounding (the plain
+                      recurrence on the sliced layer off the TPU)
 """
 
 from ray_tpu.ops.attention import flash_attention  # noqa: F401

@@ -255,22 +255,30 @@ def test_served_step_compiles_for_the_v5e(one_chip, name, program, bucket,
     run through their own grouped kernel (`ops/grouped_matmul.py`'s
     `gmm`, which this process's CPU backend would not choose: the test
     says it is on a TPU) where their matrices lie, and the program fits
-    beside nothing else. A decode step writes its new rows through
-    `write_blocks` (`_writes_its_rows_through_the_kernel`). GLM-5.2 at
+    beside nothing else. Nemotron's decode step updates a Mamba-2
+    layer's states where they lie in the run's stack
+    (`_updates_its_states_where_they_lie`). A decode step writes its
+    new rows through `write_blocks`
+    (`_writes_its_rows_through_the_kernel`). GLM-5.2 at
     16 slots x 16,384; Nemotron 3 Super at 64 x 4,096,
     whose Mamba-2 state rides the same carry as leaves with no sequence
     axis; Command A+ at 16 x 16,384, whose sliding layers' rings of
     4,096 rows ride it beside the full layer's rows. `products`: the grouped products an expert layer has, three of
     a gated SwiGLU, two of relu^2."""
-    from ray_tpu.ops import attention, block_rows, grouped_matmul
+    from ray_tpu.ops import attention, block_rows, grouped_matmul, ssm_update
 
     monkeypatch.setattr(grouped_matmul, "on_tpu", lambda: True)
     monkeypatch.setattr(attention, "on_tpu", lambda: True)
     monkeypatch.setattr(block_rows, "on_tpu", lambda: True)
+    monkeypatch.setattr(ssm_update, "on_tpu", lambda: True)
     cfg, params, cache, compiled = _engines_program(
         one_chip, name, program, bucket,
         ("pair_overflows", "pairs_held", "pairs_routed"))
     text = compiled.as_text()
+    if name == "nemotron-3-super-serve":
+        _updates_its_states_where_they_lie(
+            _scheduled(text), program,
+            [run["ssm"].shape for run in cache["runs"] if "ssm" in run])
     # A decode step's rows go in by one kernel a run of layers that
     # keep keys (GLM-5.2's three leaves of unlike widths in one call,
     # Command A+'s rings as its full rows, Nemotron's one attention
@@ -317,6 +325,34 @@ def test_served_step_compiles_for_the_v5e(one_chip, name, program, bucket,
     assert memory.argument_size_in_bytes > resident  # weights and cache
     assert memory.argument_size_in_bytes + memory.temp_size_in_bytes \
         < 16.0e9
+
+
+def _updates_its_states_where_they_lie(scheduled, program, stacks):
+    """A decode step of a stack with Mamba-2 layers: one `ssm_update`
+    kernel a Mamba-2 run (`stacks`: the runs' state leaves), in the
+    body under `ssm/ssm_update`, and the state leaf aliased through it.
+    A second reader of the carried stack there would show as a `copy`
+    of it, the plain recurrence as a fusion that makes a layer's states
+    and an `add_dynamic-update-slice_fusion` that writes them into the
+    stack (268 MB a layer moved three times, three tenths of the step:
+    PERF.md, PR 58). A prefill is the chunked scan and holds none of
+    the kernel."""
+    calls = re.findall(r'%ssm_update(?:\.\d+)? = .* custom-call\('
+                       r'.*op_name="([^"]*)"', scheduled)
+    if program != "decode":
+        assert not calls
+        return
+    assert len(calls) == len(stacks) == 2
+    assert all(re.search(r"while/body/(?:closed_call/)?ssm/ssm_update/",
+                         path) for path in calls)
+    made = re.findall(r"%[\w.-]+ = (.*?) (?:copy|copy-start|fusion|convert|"
+                      r"dynamic-slice|dynamic-update-slice)\(", scheduled)
+    assert made
+    for stack in stacks:
+        assert stack[1:] == (64, 128, 64, 128)
+        for dims in {stack, (1,) + stack[1:], stack[1:]}:
+            array = f"f32[{','.join(map(str, dims))}]"
+            assert not [shapes for shapes in made if array in shapes], array
 
 
 def _writes_its_rows_through_the_kernel(text, calls, *leaves):
