@@ -148,8 +148,11 @@ def test_span_records_start_end_parent_and_attributes():
         critical_path.end(sp, admitted=1)
         wave.set(admitted=1, left_over=0)
     t_after = time.time()
+    # (`process.wake_late` is the recorder's own beat of a process that
+    # stood still: a loaded host's, not this test's.)
     spans = {s["stage"]: s
-             for s in flight_recorder.local_snapshot()["spans"]}
+             for s in flight_recorder.local_snapshot()["spans"]
+             if s["stage"] != "process.wake_late"}
     assert set(spans) == {"engine.admit_wave", "engine.prefill_dispatch",
                           "engine.sample_dispatch", "data.block_fetch"}
     for s in spans.values():

@@ -15,11 +15,17 @@ What the leg pins (the ISSUE's acceptance criteria):
   mode: every quiescent state also cross-checks the live core against
   its executable sequential spec's reachable states — the
   ``conformance_checks`` counters prove the refinement pass really ran;
-- the leg stays under its wall budget so it can live in tier-1
-  forever (raised from 60s to 75s when conformance mode added ~25%
-  for ~450k refinement checks per run, then to 90s when the
-  seam-coverage audit added a per-crossing recording cost — the leg
-  runs ~68s solo but shares the budget with full-suite load);
+- the leg is bounded by the executions it explores, which every
+  scenario's exhaustive sweep fixes, and not by seconds of a host it
+  shares with five other workers: every scenario drains (no sweep is
+  cut by its time budget, so the report's counts are the same in
+  every run) and the twelve together stay under `_LEG_EXECUTIONS`.
+  `_LEG_BUDGET_S` is what the leg takes alone with room to spare
+  (~75s at 19,228 executions; raised 60 -> 75 -> 90s up to PR 20 and
+  not since); beside five other workers it takes twice that, so the
+  subprocess counts as hung only at `_HANG_S`. A scenario whose sweep
+  outgrows this shrinks its scope
+  (`actor_restart` did, PR 59: `tools/raymc/scenarios.py` says how);
 - raymc holds itself to the repo's own gates: its sources pass raylint
   (asserted in test_raylint.py's tier-1 sweep alongside ray_tpu and
   raysan), and its harness machinery runs clean under the raysan
@@ -31,12 +37,16 @@ import json
 import os
 import subprocess
 import sys
-import time
 
 REPO_ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
     os.path.abspath(__file__))))
 
 _LEG_BUDGET_S = 90.0
+_LEG_EXECUTIONS = 25_000
+# When the subprocess counts as hung. Beside five other workers the leg
+# has taken twice its own time (PR 59's whole run: past 150 s where it
+# takes 75 alone), so this is no budget: the executions are.
+_HANG_S = 4 * _LEG_BUDGET_S
 _ARTIFACT = os.path.join(REPO_ROOT, "RAYMC_REPORT.json")
 
 
@@ -48,24 +58,28 @@ def _env():
 
 
 def test_raymc_leg_clean_exhaustive_and_bounded():
-    t0 = time.monotonic()
     out = subprocess.run(
         [sys.executable, "-m", "tools.raymc",
-         "--report", "json", "--report-file", _ARTIFACT],
+         "--report", "json", "--report-file", _ARTIFACT,
+         # A sweep ends when it has drained, not when a loaded host's
+         # clock says so: no scenario's own budget bites before the
+         # leg's.
+         "--time-budget-s", str(_HANG_S)],
         cwd=REPO_ROOT, env=_env(), capture_output=True, text=True,
-        timeout=_LEG_BUDGET_S + 60)
-    wall = time.monotonic() - t0
+        timeout=_HANG_S)
     assert out.returncode == 0, (
         f"raymc leg failed (rc={out.returncode}):\n"
         f"{out.stdout[-4000:]}\n{out.stderr[-2000:]}")
-    assert wall < _LEG_BUDGET_S, (
-        f"raymc leg took {wall:.1f}s — over the {_LEG_BUDGET_S:.0f}s "
-        f"budget; shrink scenario scopes before shrinking coverage")
 
     with open(_ARTIFACT, "r", encoding="utf-8") as f:
         report = json.load(f)
     assert report["pass"] is True
     by_name = {s["scenario"]: s for s in report["scenarios"]}
+    explored = sum(s["executions"] for s in by_name.values())
+    assert explored <= _LEG_EXECUTIONS, (
+        f"raymc leg explored {explored} executions — over the "
+        f"{_LEG_EXECUTIONS} the leg is bounded by; shrink scenario "
+        f"scopes before shrinking coverage")
     assert set(by_name) == {"router_cap", "gcs_durability",
                             "pipelined_close", "spill_race",
                             "lineage_reconstruction", "actor_restart",
@@ -87,9 +101,10 @@ def test_raymc_leg_clean_exhaustive_and_bounded():
     # interleavings alone (26 at this scope without crash branching).
     assert by_name["gcs_durability"]["executions"] >= 50, by_name
     assert by_name["head_crash_recovery"]["executions"] >= 50, by_name
-    # The actor replay-or-reject space is the largest in the leg: a
-    # shrunk count means the scenario lost its death placements.
-    assert by_name["actor_restart"]["executions"] >= 5000, by_name
+    # The actor replay-or-reject space (2,252 at the scope PR 59 cut
+    # it to): a shrunk count means the scenario lost its death
+    # placements.
+    assert by_name["actor_restart"]["executions"] >= 2000, by_name
     # Tenancy admission: the grant/release race + WFQ put/pop space
     # drained — a shrunk count means the racing submitters (or the
     # queue race) fell out of the scenario.

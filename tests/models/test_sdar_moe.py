@@ -1,14 +1,14 @@
-"""SDAR's decoder at debug widths on the CPU, in float32, seeded random
-weights at the program's own initialiser (the plain weights, where the
-benchmark's hide the routed experts): the served path (a block-causal
-prefill padded to its bucket, then block steps through the cache, each
-block as a denoising pass and as its commit, rows at different
-positions and phases in one call; the engine's step of two blocks a
-row, a start each, with and without a block that awaits its commit)
-against the plain reference, each named fault failing where the program
-passes; what such a step writes and what it leaves; a slot used before; the
-flash kernel's block mask; and what the registry says of a model that
-generates by blocks."""
+"""What is SDAR's alone, at debug widths on the CPU, in float32, seeded
+random weights at the program's own initialiser: block steps through
+the cache, each block as a denoising pass and as its commit, rows at
+different positions and phases in one call; the engine's step of two
+blocks a row, a start each, with and without a block that awaits its
+commit, against the plain reference; what such a step writes and what
+it leaves; a slot used before; the flash kernel's block mask; and what
+the registry says of a model that generates by blocks. The served path
+against the reference and each named fault failing are cases of
+`test_served_contract.py`, over this family's row in `families.py`,
+which has the faults."""
 
 import dataclasses
 
@@ -17,122 +17,18 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from benchmark.harness.manifest import ROOT, load_json, model_adapter
-from benchmark.references import sdar_moe as reference
-from benchmark.runners import serve_blocks
 from ray_tpu.models import llama, sdar_moe, serving
 from ray_tpu.ops import attention
+from tests.models import families
+# (`tests/benchmark/test_sdar_moe.py` takes the faults from this module
+# by this name.)
+from tests.models.families import sdar_faults as _faults  # noqa: F401
 
-FILE = load_json(ROOT, "benchmark", "configs", "sdar-30b-a3b-serve.json")
-ADAPTER = model_adapter(FILE)
-
-
-def debug_config():
-    config = ADAPTER.debug(FILE)
-    # 44 is whole blocks and no bucket: the check pads it to 64; the
-    # shorter rows step from their own lengths.
-    config["serve"] = {**config["serve"], "max_seq_len": 128,
-                       "reference_prompt_lens": [44, 36, 24, 12],
-                       "reference_block_steps": 2}
-    return config
-
-
-CONFIG = debug_config()
-CFG = ADAPTER.program_config(CONFIG)
-HP = reference.hyper(CONFIG)
-
-
-def _patched(**names):
-    """`ADAPTER.cached_forward` with some names of `sdar_moe` replaced
-    while it is traced."""
-    def served(params, tokens, cfg, cache, start_pos):
-        before = {name: getattr(sdar_moe, name) for name in names}
-        for name, value in names.items():
-            setattr(sdar_moe, name, value)
-        try:
-            return ADAPTER.cached_forward(params, tokens, cfg, cache,
-                                          start_pos)
-        finally:
-            for name, value in before.items():
-                setattr(sdar_moe, name, value)
-    return served
-
-
-def _plain(tiled, start_pos, flash, plain):
-    """`own_keys` that never takes the kernel: a fault of the mask is
-    written into the plain path, and has to bite on a TPU too."""
-    return plain()
-
-
-def _gates_as_they_are(params, tokens, cfg, cache, start_pos):
-    return ADAPTER.cached_forward(
-        params, tokens, dataclasses.replace(cfg, norm_topk_prob=False),
-        cache, start_pos)
-
-
-def _shifted(params, tokens, cfg, cache, start_pos):
-    logits, cache = ADAPTER.cached_forward(params, tokens, cfg, cache,
-                                           start_pos)
-    return jnp.roll(logits, 1, axis=1), cache
-
-
-def _commit_keeps_the_denoised_keys(params, tokens, cfg, cache, start_pos):
-    """A block step that holds no mask token (a commit) leaves the
-    cache as the denoising pass before it left it."""
-    logits, new = ADAPTER.cached_forward(params, tokens, cfg, cache,
-                                         start_pos)
-    if tokens.shape[1] > cfg.block_length:
-        return logits, new
-    commit = ~(tokens == cfg.mask_token_id).any()
-    return logits, jax.tree.map(
-        lambda old, new: jnp.where(commit, old, new), cache, new)
-
-
-def _padding_seen(params, tokens, cfg, cache, start_pos):
-    """A prefill whose rows see every row of the call, its bucket's
-    padding with them."""
-    if tokens.shape[1] <= cfg.block_length:
-        return ADAPTER.cached_forward(params, tokens, cfg, cache, start_pos)
-    return _patched(
-        own_keys=_plain, block_ends=lambda positions, block: jnp.full_like(
-            positions, 10 ** 6))(params, tokens, cfg, cache, start_pos)
-
-
-def _faults():
-    return {
-        "a causal mask where block causal is due":
-            _patched(own_keys=_plain,
-                     block_ends=lambda positions, block: positions),
-        "q/k norm over all heads": _patched(
-            norm_each_head=lambda x, w, eps: llama.norm_all_heads(
-                x, jnp.tile(w, x.shape[2]), eps)),
-        "gates not renormalised": _gates_as_they_are,
-        "interleaved in place of split-half rotary": _patched(
-            apply_rope=lambda x, cos, sin: serving.rotate_pairs(
-                x, cos, sin)),
-        "logits shifted by one": _shifted,
-        "a commit that keeps the denoising pass's keys":
-            _commit_keeps_the_denoised_keys,
-        "a padded prefill whose padding is seen": _padding_seen,
-    }
-
-
-FAULTS = sorted(_faults())
-
-
-@pytest.fixture(scope="module")
-def distances():
-    """Of the program and of each fault, the largest logit error over
-    the largest |reference| logit, by the runner's check (a), at the
-    plain weights. (A stand-in is called while the check traces it, not
-    under a jit of its own: a patch has to hold while it is traced.)"""
-    init, ADAPTER.init = ADAPTER.init, sdar_moe.init_params
-    try:
-        return {name: serve_blocks.check_against_reference(
-            CONFIG, 2 ** 31 + 5, served=served)[0]
-            for name, served in {"program": None, **_faults()}.items()}
-    finally:
-        ADAPTER.init = init
+NAME = "SdarMoeConfig"
+FILE, ADAPTER = families.file(NAME), families.adapter(NAME)
+CFG = families.cfg(NAME)
+reference = families.reference(NAME)
+HP = reference.hyper(families.config(NAME))
 
 
 def test_the_file_builds_the_published_model():
@@ -148,18 +44,9 @@ def test_the_file_builds_the_published_model():
         is None
 
 
-def test_the_served_path_agrees_with_the_reference(distances):
-    assert distances["program"] < 1e-5, distances
-
-
-@pytest.mark.parametrize("fault", FAULTS)
-def test_a_fault_fails(distances, fault):
-    assert distances[fault] > 1e-3 > 100 * distances["program"], distances
-
-
-@pytest.fixture(scope="module")
+@pytest.fixture
 def params():
-    return sdar_moe.init_params(CFG, jax.random.PRNGKey(2))
+    return families.params(NAME)
 
 
 def _tokens(shape, seed=1):

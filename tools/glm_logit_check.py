@@ -691,50 +691,29 @@ KIMI_UNSEEN = {
               "score scaled by nope alone", "no latent norm")}
 
 
-def _glm_init():
-    from ray_tpu.models.glm_dsa import init_params
-    return init_params
+def _plain_init(family):
+    """A call that hands back the initialiser of the program's module
+    of that name (the plain weights), imported when it is made."""
+    def init():
+        import importlib
+        return importlib.import_module(f"ray_tpu.models.{family}").init_params
+    return init
 
 
-def _nemotron_init():
-    from ray_tpu.models.nemotron_h import init_params
-    return init_params
-
-
-def _cohere_init():
-    from ray_tpu.models.cohere2_moe import init_params
-    return init_params
-
-
-def _olmo_init():
-    from ray_tpu.models.olmo_hybrid import init_params
-    return init_params
-
-
-def _lfm2_init():
-    from ray_tpu.models.lfm2_moe import init_params
-    return init_params
-
-
-def _kimi_init():
-    from ray_tpu.models.kimi_linear import init_params
-    return init_params
-
-
-# By a configuration's family: its faults, the faults a set of weights
-# cannot show, the program's own initialiser (the plain weights), and
-# the prompt lengths of a rehearsal at debug widths.
+# By a configuration's family, which is the name of the program's
+# module: its faults, the faults a set of weights cannot show, the
+# program's own initialiser (the plain weights), and the prompt lengths
+# of a rehearsal at debug widths.
 FAMILIES = {
-    "glm_dsa": (faults, UNSEEN, _glm_init, [40, 33, 26, 19]),
-    "nemotron_h": (nemotron_faults, NEMOTRON_UNSEEN, _nemotron_init,
-                   [45, 39, 26, 19]),
-    "cohere2_moe": (cohere_faults, COHERE_UNSEEN, _cohere_init,
-                    [45, 39, 26, 19]),
-    "olmo_hybrid": (olmo_faults, OLMO_UNSEEN, _olmo_init,
-                    [45, 39, 26, 19]),
-    "lfm2_moe": (lfm2_faults, LFM2_UNSEEN, _lfm2_init, [45, 33, 12, 5]),
-    "kimi_linear": (kimi_faults, KIMI_UNSEEN, _kimi_init, [45, 39, 26, 19]),
-}
+    family: (family_faults, unseen, _plain_init(family), rehearsal_lens)
+    for family, (family_faults, unseen, rehearsal_lens) in {
+        "glm_dsa": (faults, UNSEEN, [40, 33, 26, 19]),
+        "nemotron_h": (nemotron_faults, NEMOTRON_UNSEEN, [45, 39, 26, 19]),
+        "cohere2_moe": (cohere_faults, COHERE_UNSEEN, [45, 39, 26, 19]),
+        "olmo_hybrid": (olmo_faults, OLMO_UNSEEN, [45, 39, 26, 19]),
+        "lfm2_moe": (lfm2_faults, LFM2_UNSEEN, [45, 33, 12, 5]),
+        "kimi_linear": (kimi_faults, KIMI_UNSEEN, [45, 39, 26, 19]),
+    }.items()}
 
 
 def within(row, limits):
@@ -763,12 +742,24 @@ def weights_and_tokens(config, seed, model, init):
     return small, params, lens, tokens
 
 
+def reference_logits(config, params, tokens, reference):
+    """The plain reference's logits of every position of `tokens`."""
+    import jax
+    import jax.numpy as jnp
+
+    with jax.default_matmul_precision("highest"):
+        return jax.jit(functools.partial(
+            reference.forward, hp=reference.hyper(config)))(
+                params, jnp.asarray(tokens))
+
+
 def distances(config, small, params, lens, tokens, model, reference,
-              served_by_name):
+              served_by_name, want=None):
     """`check_against_reference` of the serving runner for each of
-    `served_by_name`, the reference computed once: {name: the errors of
-    its positions, each the largest logit error over the largest
-    |reference| logit, as their largest, median, 99th and 99.9th
+    `served_by_name`, the reference computed once (`want`, where a
+    caller that comes again has kept `reference_logits`): {name: the
+    errors of its positions, each the largest logit error over the
+    largest |reference| logit, as their largest, median, 99th and 99.9th
     percentile and the share over `logit_tolerance`}."""
     import jax
     import jax.numpy as jnp
@@ -777,10 +768,8 @@ def distances(config, small, params, lens, tokens, model, reference,
     plan = config["serve"]
     rows, n_dec = len(lens), plan["reference_decode_steps"]
     n_pre = tokens.shape[1] - n_dec
-    with jax.default_matmul_precision("highest"):
-        want = jax.jit(functools.partial(
-            reference.forward, hp=reference.hyper(config)))(
-                params, jnp.asarray(tokens))
+    if want is None:
+        want = reference_logits(config, params, tokens, reference)
     top = float(jnp.abs(want).max())
     error = jax.jit(lambda got, want: jnp.abs(
         got.astype(jnp.float32) - want).max(-1))

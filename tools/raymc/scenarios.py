@@ -1130,13 +1130,23 @@ class ActorRestartScenario(Scenario):
     description = ("node death across mailbox-submit/dispatch/restart: "
                    "<=1 execution per call always, exactly-1 for calls "
                    "with retry budget, rejects name the budget")
-    points = ("actor.route", "actor.replay", "actor.restart.begin",
-              "actor.restart.ready", "mc.sync.exec1")
+    # The scope tier-1 can drain (PR 59). With the gate's two other
+    # seams scheduled too (`actor.restart.begin`, `actor.replay`) and
+    # a second crossing a beat of node1 the sweep is 17,284 schedules,
+    # 78 s alone: the tier-1 leg cut it at 45 s and 12,760, never
+    # exhausted. Left unscheduled here: the windows between the kill
+    # and the restart decision and between the decision and a call's
+    # replay (env_kill runs from the kill to its first route in one
+    # step; `test_fault_semantics` pins the sweep deterministically and
+    # the rayspec refinement still checks every quiescent state). A
+    # caller's route still lands before the kill, mid-restart with the
+    # budgeted call parked or not yet, and after the actor is ready.
+    points = ("actor.route", "actor.restart.ready", "mc.sync.exec1")
     max_steps = 36
-    # Measured exhaustive sweep: ~17.3k schedules (~17s on a 1-core
-    # box); the floor leaves headroom so the tier-1 `exhausted` claim
-    # stays honest.
-    max_schedules = 25000
+    # Measured exhaustive sweep: 2,252 schedules (~9s alone); the
+    # floor leaves headroom so the tier-1 `exhausted` claim stays
+    # honest.
+    max_schedules = 5000
     block_grace_s = 0.04
 
     # The model around the REAL ActorRestartGate mirrors the head's
@@ -1249,16 +1259,18 @@ class ActorRestartScenario(Scenario):
         def node1():
             # Two service beats: c_r is pre-queued, c_n may land during
             # the loop — both can execute pre-death; a third beat only
-            # re-observes an empty mailbox (space, no coverage).
+            # re-observes an empty mailbox (space, no coverage). One
+            # crossing a beat: a death mid-call leaves the call popped
+            # and in flight, which the sweep treats as it treats a call
+            # still in the dead node's mailbox (it reads the in-flight
+            # table alone), so a second crossing between the pop and
+            # the execution reaches no state the first does not.
             for _ in range(2):
                 sanitize_hooks.sched_point("mc.sync.exec1")
                 if not self.node1["alive"]:
                     return
                 if self.node1["mailbox"]:
                     spec = self.node1["mailbox"].pop(0)
-                    sanitize_hooks.sched_point("mc.sync.exec1")
-                    if not self.node1["alive"]:
-                        return  # died mid-call: spec stays in flight
                     with self._lock:
                         self.executions[spec.name] += 1
                     if spec in self.inflight:

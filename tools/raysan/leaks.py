@@ -61,6 +61,17 @@ def _classify(target: str) -> str:
     return "file"
 
 
+def _target_name(thread) -> str:
+    """A thread's target by module and qualified name: a function's
+    `repr` carries its address, which differs by run, and the report
+    this lands in is committed."""
+    target = getattr(thread, "_target", None)
+    name = getattr(target, "__qualname__", None)
+    if name is None:
+        return type(target).__name__
+    return f"{getattr(target, '__module__', '?')}.{name}"
+
+
 def _pooled_rpc_filenos() -> Dict[int, str]:
     """fileno -> label for sockets owned by the process-lifetime
     RpcClient pool (kept across tests by design)."""
@@ -146,7 +157,7 @@ class LeakSanitizer(Sanitizer):
                 message=f"thread leaked: {t.name!r} "
                         f"(daemon={t.daemon}) still alive "
                         f"{self.grace_s:.1f}s after teardown",
-                detail=f"target={getattr(t, '_target', None)!r}"))
+                detail=f"target={_target_name(t)}"))
 
         # -- fds (after the thread grace, so closes-in-progress land) ----
         pooled = _pooled_rpc_filenos()
