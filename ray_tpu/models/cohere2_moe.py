@@ -85,7 +85,8 @@ from ray_tpu.models import decoder, moe
 from ray_tpu.models.serving import (
     KEY_BLOCK as _KEY_BLOCK, Family, attention_init, by_query_blocks, normal,
     own_keys, rotate_pairs)
-from ray_tpu.ops import attention, block_rows
+from ray_tpu.ops import attention, block_rows, stacked_product
+from ray_tpu.ops.stacked_product import leaf_product
 
 PUBLISHED_LAYER_TYPES = ("sliding", "sliding", "sliding", "full") * 8
 
@@ -288,13 +289,13 @@ def _mixer(cfg: Cohere2MoeConfig, kind, start_pos, positions, at):
     the run's (K, V) stacks, rows or rings. A ring's length is its
     leaf's (`init_cache` made it `sliding_window` long); what a row sees
     is `cfg.sliding_window`'s to say."""
-    def mixer(h, lp, rope, state, handed):
+    def mixer(h, lp, rope, state, handed, stacks=None):
         (k_stack, v_stack), layer = state
         b, t = positions.shape
         cached = k_stack.dtype
-        q = jnp.einsum("bsd,dhk->bshk", h, lp["wq"])
-        k = jnp.einsum("bsd,dhk->bshk", h, lp["wk"])
-        v = jnp.einsum("bsd,dhk->bshk", h, lp["wv"]).astype(cached)
+        q = leaf_product("bsd,dhk->bshk", h, "wq", lp, stacks)
+        k = leaf_product("bsd,dhk->bshk", h, "wk", lp, stacks)
+        v = leaf_product("bsd,dhk->bshk", h, "wv", lp, stacks).astype(cached)
         if kind in _ROTATED:
             if t == 1:
                 # The seam the module's docstring describes: the turn
@@ -358,6 +359,13 @@ def _mixer(cfg: Cohere2MoeConfig, kind, start_pos, positions, at):
                 (k_stack, v_stack), layer, (k, v), start_pos, at)
         return out, (k_stack, v_stack), handed
 
+    # A decode step reads `wq` where it lies in the run's stack
+    # (`ops.stacked_product`; `decoder.layers` keeps the leaves a half
+    # names out of its scan). Not `wk` and `wv`: a stack of theirs is
+    # 25 MB, which the compiler then fetches whole into fast memory
+    # ahead of the kernel, once a layer, three times what a layer reads.
+    if stacked_product.engages(positions.shape[1]):
+        mixer.whole = ("wq",)
     return mixer
 
 

@@ -30,12 +30,16 @@ handed in as two functions:
   around it.
 - ``ffn(h, lp) -> (out [B, S, D], extras)``: `extras` is a pytree the
   layer reports (an expert layer's aux loss and counts), or None. An
-  FFN that reads some of its parameters in place names them in its
-  attribute `whole`: `layers` keeps those leaves of the run's stack out
-  of the scan and calls ``ffn(h, lp, stacks=(leaves, layer))``. An FFN
-  that reads the stream as the layer received it, before the mixer and
-  before any norm (SmallThinker's router), carries the attribute
+  FFN that reads the stream as the layer received it, before the mixer
+  and before any norm (SmallThinker's router), carries the attribute
   `stream` and is called ``ffn(h, lp, stream=x)``.
+
+A half, mixer or FFN, that reads some of its parameters in place names
+them in its attribute `whole`: `layers` keeps those leaves of the run's
+stack out of the scan and the block calls the half with
+``stacks=(leaves, layer)`` beside what it is called with above, `lp`
+holding the rest. A mixer may name `wo`, which the block then reads in
+place for it.
 
 Around it: the parameter skeleton, the stack (`hidden`: one run of
 like layers; `hidden_runs`: several, each with its own mixer, FFN,
@@ -47,7 +51,6 @@ is any config with `LlamaConfig`'s fields.
 
 from __future__ import annotations
 
-import functools
 from typing import Any, Dict, Optional, Sequence
 
 import jax
@@ -57,6 +60,7 @@ from jax import lax
 from ray_tpu.ops.cross_entropy import (fused_linear_cross_entropy,
                                        softmax_cross_entropy)
 from ray_tpu.ops.norms import layer_norm, rms_norm_reference
+from ray_tpu.ops import stacked_product
 from ray_tpu.ops.rope import rope_frequencies, rope_from_positions
 from ray_tpu.parallel.sharding import (
     DEFAULT_RULES,
@@ -112,7 +116,7 @@ def norm(cfg, x, weight):
 
 
 def block(mixer, ffn, cfg, rope, x, lp, state=None, handed=None, *,
-          mesh=None, rules=DEFAULT_RULES):
+          stacks=None, mesh=None, rules=DEFAULT_RULES):
     """One block. x: [B, S, D] -> (x, state, extras, handed). Either
     half may be absent (`mixer` or `ffn` None: a stack whose layers are
     a mixer or an FFN alone); the block is then the other half, norm,
@@ -127,9 +131,16 @@ def block(mixer, ffn, cfg, rope, x, lp, state=None, handed=None, *,
     is no attention). `state` goes to the mixer as it is and comes back
     as the mixer returns it; `handed` is what the layer below handed up
     beside x, None in most stacks. An FFN with the attribute `stream`
-    is also handed x as the block received it (`stream=`)."""
+    is also handed x as the block received it (`stream=`). `stacks` is
+    `layers`': (the leaves the halves named in `whole`, the layer), or
+    None; a half that named any is handed it (`stacks=`), and the
+    output projection reads `wo` there if the mixer named it."""
     extras = None
     received = {"stream": x} if getattr(ffn, "stream", False) else {}
+
+    def named(half):
+        return {"stacks": stacks} if stacks is not None \
+            and getattr(half, "whole", ()) else {}
     parallel = cfg.parallel_block
     assert cfg.norm_placement in ("input", "output"), cfg.norm_placement
     after = cfg.norm_placement == "output"
@@ -138,9 +149,10 @@ def block(mixer, ffn, cfg, rope, x, lp, state=None, handed=None, *,
     if mixer is not None:
         with jax.named_scope(getattr(mixer, "scope", "attn")):
             h = x if after else norm(cfg, x, lp["attn_norm"])
-            attn, state, handed = mixer(h, lp, rope, state, handed)
-            mixed = jnp.einsum("bshk,hkd->bsd", attn.astype(cfg.dtype),
-                               lp["wo"])
+            attn, state, handed = mixer(h, lp, rope, state, handed,
+                                        **named(mixer))
+            mixed = stacked_product.leaf_product(
+                "bshk,hkd->bsd", attn.astype(cfg.dtype), "wo", lp, stacks)
             if after:
                 mixed = norm(cfg, mixed, lp["attn_norm"])
             if not parallel:
@@ -151,7 +163,7 @@ def block(mixer, ffn, cfg, rope, x, lp, state=None, handed=None, *,
                 h = x
             elif not parallel:
                 h = norm(cfg, x, lp["mlp_norm"])
-            out, extras = ffn(h, lp, **received)
+            out, extras = ffn(h, lp, **received, **named(ffn))
             if after:
                 out = norm(cfg, out, lp["mlp_norm"])
             x = x + mixed + out if parallel else x + out
@@ -178,38 +190,39 @@ def layers(mixer, ffn, cfg, rope, x, stacked, state=None, handed=None, *,
     and returns the stacks. With no state it is handed
     None and the scan carries x and `handed` alone.
 
-    Parameters ride whole beside the state where the FFN asks for them:
-    the leaves of `stacked` it names in its attribute `whole` are no
-    scanned input either, and the FFN is called as `ffn(h, lp,
-    stacks=(those leaves, layer))` with `lp` holding the rest. A scanned
-    leaf reaches the body as a slice of its stack, which fuses into a
-    matmul that reads it and is a copy of the slice, whole, ahead of a
-    kernel that takes no fused operand (the grouped products of a held
-    share of the experts, `moe.served_ffn`: the copy was a third of a
-    decode step). Forward only: the gradient of a stack handed whole
-    would be the size of the stack at every layer, so a trained FFN
-    names nothing and keeps its slices.
+    Parameters ride whole beside the state where a half asks for them:
+    the leaves of `stacked` that the mixer or the FFN names in its
+    attribute `whole` are no scanned input either, and that half is
+    called with `stacks=(those leaves, layer)` (`block`), `lp` holding
+    the rest.
+    A scanned leaf reaches the body as a slice of its stack, which
+    fuses into a matmul that reads it and is a copy of the slice,
+    whole, ahead of a kernel that takes no fused operand (the grouped
+    products of a held share of the experts, `moe.served_ffn`: the copy
+    was a third of a decode step) and ahead of a product the compiler
+    wants laid out otherwise (a decode step's projections,
+    `ops.stacked_product`). Forward only: the gradient of a stack
+    handed whole would be the size of the stack at every layer, so a
+    trained half names nothing and keeps its slices, and a run whose
+    halves name nothing is scanned as it always was.
 
     `save` is the remat policy: None keeps every activation; a list
     rematerialises each layer in the backward pass but for the
     `checkpoint_name`s in it (an empty list saves nothing)."""
-    whole = {name: stacked[name] for name in getattr(ffn, "whole", ())
-             if name in stacked}
+    whole = {name: stacked[name] for half in (mixer, ffn)
+             for name in getattr(half, "whole", ()) if name in stacked}
     if whole:
         stacked = {name: leaf for name, leaf in stacked.items()
                    if name not in whole}
+    stacked_product.note("sliced", *jax.tree.leaves(stacked))
 
     def body(carry, scanned):
         x, handed, state = carry
         lp, layer = scanned
-        half = ffn
-        if whole:
-            half = functools.partial(ffn, stacks=(whole, layer))
-            half.stream = getattr(ffn, "stream", False)
         x, state, extras, handed = block(
-            mixer, half, cfg, rope, x, lp,
-            None if state is None else (state, layer), handed, mesh=mesh,
-            rules=rules)
+            mixer, ffn, cfg, rope, x, lp,
+            None if state is None else (state, layer), handed,
+            stacks=(whole, layer) if whole else None, mesh=mesh, rules=rules)
         return (x, handed, state), extras
 
     if save is not None:

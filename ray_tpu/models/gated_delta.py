@@ -95,6 +95,7 @@ from ray_tpu.models.serving import normal
 # token by token.
 from ray_tpu.ops.delta_update import (  # noqa: F401
     delta_update, reference as _update)
+from ray_tpu.ops.stacked_product import leaf_product
 
 # Rows below which `_unit_lower_inverse` substitutes row by row.
 _INVERSE_BASE = 16
@@ -329,11 +330,12 @@ def _gated_norm(cfg, o, z, weight):
     return _normed(cfg, o, weight) * jax.nn.silu(z.astype(jnp.float32))
 
 
-def _gates(cfg, a, lp):
+def _gates(cfg, a, lp, stacks=None):
     """What Gated DeltaNet makes of a layer's input beside q, k, v and
     beta: (the log decay, one number a head, [B, T, H] float32; z
-    [B, T, H, dv], of which the output gate is the silu)."""
-    z = jnp.einsum("btd,dhv->bthv", a, lp["wg"])
+    [B, T, H, dv], of which the output gate is the silu). `stacks`: the
+    mixer's, where it was handed leaves whole."""
+    z = leaf_product("btd,dhv->bthv", a, "wg", lp, stacks)
     log_gamma = -jnp.exp(lp["A_log"].astype(jnp.float32)) \
         * jax.nn.softplus(jnp.einsum("btd,dh->bth", a, lp["wa"])
                           .astype(jnp.float32) + lp["dt_bias"])
@@ -351,7 +353,15 @@ def counts(tokens, start_pos, at):
             if tokens.shape[1] > 1 else jnp.zeros((), jnp.int32)}
 
 
-def mixer(cfg, start_pos, at, gates=None, gated_norm=None):
+# The projections a decode step reads where they lie in the run's
+# stack (`mixer`'s `in_place`): the three the convolutions follow, whose
+# slices the scan copied (the compiler wants them laid out for a
+# product it then reshapes), and the gate's.
+WHOLE = ("wq", "wk", "wv", "wg")
+
+
+def mixer(cfg, start_pos, at, gates=None, gated_norm=None, *,
+          in_place=False):
     """The mixer of a run of delta layers. Its state is the run's four
     stacks (`LEAVES`: S [layers, B, H, dk, dv] and the three carries
     [layers, B, K - 1, channels]), which `decoder.layers` carries
@@ -364,18 +374,23 @@ def mixer(cfg, start_pos, at, gates=None, gated_norm=None):
     `gated_norm(cfg, o, z, weight)` are this module's `_gates` and
     `_gated_norm` unless given (`kda` gives its own): the log decay is
     [B, T, H], one number a head, or [B, T, H, dk], one a key channel,
-    and the recurrence and the chunked form follow its shape."""
+    and the recurrence and the chunked form follow its shape.
+    `in_place`: a decode step's (`stacked_product.engages`), which
+    names `WHOLE` as the leaves `decoder.layers` leaves whole and reads
+    its layer's in their stacks (`gates` is then handed `stacks=` as
+    the mixer was)."""
     h, dk, dv = cfg.delta_heads, cfg.delta_key_dim, cfg.delta_value_dim
     at = jnp.broadcast_to(jnp.asarray(at, jnp.int32), start_pos.shape)
     fresh = start_pos == 0
 
-    def mix(a, lp, rope, state, handed):
+    def mix(a, lp, rope, state, handed, stacks=None):
         (s_stack, *carries), layer = state
         bsz, t = a.shape[:2]
         convolved = []
         with jax.named_scope("delta_conv"):
             for name, stack, w in zip(CONVS, carries, ("wq", "wk", "wv")):
-                x = jnp.einsum("btd,dhk->bthk", a, lp[w]).reshape(bsz, t, -1)
+                x = leaf_product("btd,dhk->bthk", a, w, lp,
+                                 stacks).reshape(bsz, t, -1)
                 carry = jnp.where(
                     fresh[:, None, None], 0,
                     lax.dynamic_index_in_dim(stack, layer, 0, False))
@@ -385,7 +400,8 @@ def mixer(cfg, start_pos, at, gates=None, gated_norm=None):
         q = _queries(q.reshape(bsz, t, h, dk))
         k = _keys(k.reshape(bsz, t, h, dk))
         v = v.reshape(bsz, t, h, dv)
-        log_gamma, z = (gates or _gates)(cfg, a, lp)
+        log_gamma, z = (gates or _gates)(
+            cfg, a, lp, **({} if stacks is None else {"stacks": stacks}))
         real = (jnp.arange(t) <= at[:, None])[..., None]
         log_gamma = jnp.where(
             real.reshape(real.shape + (1,) * (log_gamma.ndim - 3)),
@@ -422,4 +438,6 @@ def mixer(cfg, start_pos, at, gates=None, gated_norm=None):
         return out.astype(a.dtype), state, handed
 
     mix.scope = "delta"
+    if in_place:
+        mix.whole = WHOLE
     return mix

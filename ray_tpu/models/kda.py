@@ -58,10 +58,12 @@ def init(cfg, key) -> Dict[str, Any]:
             "dt_bias": dt + jnp.log(-jnp.expm1(-dt))}
 
 
-def _gates(cfg, a, lp):
+def _gates(cfg, a, lp, stacks=None):
     """`gated_delta._gates` for this mixer: (the log decay a key
     channel [B, T, H, dk] float32; z [B, T, H, dv], of which the output
-    gate is the sigmoid)."""
+    gate is the sigmoid). Its four projections are small and read from
+    `lp` whatever the mixer was handed whole."""
+    del stacks
     with jax.named_scope("delta_gate"):
         f = jnp.einsum("btr,rhk->bthk",
                        jnp.einsum("btd,dr->btr", a, lp["w_fa"]), lp["w_fb"])
@@ -79,8 +81,8 @@ def _gated_norm(cfg, o, z, weight):
         * jax.nn.sigmoid(z.astype(jnp.float32))
 
 
-def mixer(cfg, start_pos, at):
+def mixer(cfg, start_pos, at, *, in_place=False):
     """The mixer of a run of KDA layers: `gated_delta.mixer` with this
-    module's decay and gate."""
+    module's decay and gate (`in_place` as its)."""
     return gated_delta.mixer(cfg, start_pos, at, gates=_gates,
-                             gated_norm=_gated_norm)
+                             gated_norm=_gated_norm, in_place=in_place)

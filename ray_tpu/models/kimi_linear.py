@@ -52,7 +52,7 @@ import jax.numpy as jnp
 from ray_tpu.models import gated_delta, kda, llama, moe
 from ray_tpu.models.serving import (KEY_BLOCK, Family, by_query_blocks,
                                     key_blocks, latent_attention, normal)
-from ray_tpu.ops import block_rows
+from ray_tpu.ops import block_rows, stacked_product
 from ray_tpu.ops.norms import rms_norm_reference
 
 
@@ -259,7 +259,9 @@ def _latent_mixer(cfg: KimiLinearConfig, start_pos, positions):
 
 def _halves(cfg: KimiLinearConfig, start_pos, positions, at):
     ffns = {"dense": llama.swiglu(), "sparse": moe.served_ffn(cfg)}
-    mixers = {"kda": kda.mixer(cfg, start_pos, at),
+    mixers = {"kda": kda.mixer(cfg, start_pos, at,
+                               in_place=stacked_product.engages(
+                                   positions.shape[1])),
               "mla": _latent_mixer(cfg, start_pos, positions)}
     return {kind: (mixers[kind[1]], ffns[kind[0]])
             for kind in set(cfg.kinds)}

@@ -27,11 +27,12 @@ import jax.numpy as jnp
 from jax.ad_checkpoint import checkpoint_name
 
 from ray_tpu.models import decoder
-from ray_tpu.ops import attention, block_rows
+from ray_tpu.ops import attention, block_rows, stacked_product
 from ray_tpu.ops.attention import (attention_reference, flash_attention,
                                    on_tpu)
 from ray_tpu.ops.norms import rms_norm_reference
 from ray_tpu.ops.rope import apply_rope
+from ray_tpu.ops.stacked_product import leaf_product
 from ray_tpu.parallel.ring_attention import ring_attention
 from ray_tpu.parallel.sharding import (
     DEFAULT_RULES,
@@ -219,13 +220,16 @@ def norm_all_heads(x, weight, eps):
                               eps).reshape(b, s, h, k)
 
 
-def _qkv(cfg: LlamaConfig, h, lp, rope, positions, qk_norm, turned=True):
+def _qkv(cfg: LlamaConfig, h, lp, rope, positions, qk_norm, turned=True,
+         stacks=None):
     """The projections of normed activations h [B, S, D], with the q/k
     norm if configured and rope (none where not `turned`: a layer with
-    no positional encoding): q [B,S,H,D], k and v [B,S,Hkv,D]."""
-    q = jnp.einsum("bsd,dhk->bshk", h, lp["wq"])
-    k = jnp.einsum("bsd,dhk->bshk", h, lp["wk"])
-    v = jnp.einsum("bsd,dhk->bshk", h, lp["wv"])
+    no positional encoding): q [B,S,H,D], k and v [B,S,Hkv,D].
+    `stacks`: the mixer's, where `decoder.layers` handed it the three
+    leaves whole (a served decode step)."""
+    q = leaf_product("bsd,dhk->bshk", h, "wq", lp, stacks)
+    k = leaf_product("bsd,dhk->bshk", h, "wk", lp, stacks)
+    v = leaf_product("bsd,dhk->bshk", h, "wv", lp, stacks)
     if cfg.qk_norm:
         q = qk_norm(q, lp["q_norm"], cfg.norm_eps)
         k = qk_norm(k, lp["k_norm"], cfg.norm_eps)
@@ -451,9 +455,10 @@ def _cached_self_attention(cfg: LlamaConfig, start_pos, positions):
     and any call off the TPU, has `_cached_attention` (looked up in
     this module when the mixer is traced) read the layer's [B, S, Hkv,
     D] out of them."""
-    def mixer(h, lp, rope, state, handed):
+    def mixer(h, lp, rope, state, handed, stacks=None):
         (k_stack, v_stack), layer = state
-        q, k, v = _qkv(cfg, h, lp, rope, positions, norm_all_heads)
+        q, k, v = _qkv(cfg, h, lp, rope, positions, norm_all_heads,
+                       stacks=stacks)
         k_stack, v_stack = block_rows.write_tokens(
             (k_stack, v_stack), layer, (k, v), start_pos)
         if q.shape[1] == 1 and attention.on_tpu():
@@ -466,6 +471,11 @@ def _cached_self_attention(cfg: LlamaConfig, start_pos, positions):
                 decoder.layer_rows(v_stack, layer, 0, max_seq), positions)
         return out, (k_stack, v_stack), handed
 
+    # A decode step reads the three projections where they lie in the
+    # stack of layers (`ops.stacked_product`; `decoder.layers` keeps
+    # the leaves a half names out of its scan).
+    if stacked_product.engages(positions.shape[1]):
+        mixer.whole = ("wq", "wk", "wv")
     return mixer
 
 

@@ -197,7 +197,7 @@ def _gated_norm(cfg, y, z, weight):
     return groups.reshape(b, t, h * p) * weight.astype(jnp.float32)
 
 
-def mixer(cfg, start_pos, at):
+def mixer(cfg, start_pos, at, *, in_place=False):
     """The mixer of a run of Mamba-2 layers. Its state is the run's two
     stacks, (S [layers, B, H, P, N], conv rows [layers, B, K - 1, C]),
     which `decoder.layers` carries through the scan; it reads its layer
@@ -205,12 +205,20 @@ def mixer(cfg, start_pos, at):
     updates its layer of S in place in the stack and never slices it
     out. `start_pos` [B]: a row at 0 starts from zeros; `at`: the
     position of the call's tokens after which the state is left, an int
-    or an int32 scalar for all rows or int32 [B], one a row."""
+    or an int32 scalar for all rows or int32 [B], one a row.
+    `in_place`: a decode step's (`stacked_product.engages`), which
+    names the output projection as the leaf `decoder.layers` leaves
+    whole, for the block to read its layer's in the stack (the scan
+    copied the slice; `w_xbc` the compiler reads where it lies itself,
+    and `w_z`, which the TPU keeps [D, P, heads] with the contracted
+    axis outermost, no kernel of `ops.stacked_product` can: its slice
+    stays)."""
     h, p = cfg.ssm_heads, cfg.ssm_head_dim
     g, n = cfg.ssm_groups, cfg.ssm_state
     at = jnp.broadcast_to(jnp.asarray(at, jnp.int32), start_pos.shape)
 
-    def mix(a, lp, rope, state, handed):
+    def mix(a, lp, rope, state, handed, stacks=None):
+        del stacks  # `wo` is the block's to read
         (ssm_stack, conv_stack), layer = state
         t = a.shape[1]
         fresh = start_pos == 0
@@ -253,4 +261,6 @@ def mixer(cfg, start_pos, at):
         return out.reshape(y.shape).astype(a.dtype), state, handed
 
     mix.scope = "ssm"
+    if in_place:
+        mix.whole = ("wo",)
     return mix

@@ -44,7 +44,7 @@ import jax.numpy as jnp
 from ray_tpu.models import decoder, llama, mamba2, moe
 from ray_tpu.models.serving import (Family, attention_init, by_query_blocks,
                                     normal)
-from ray_tpu.ops import block_rows
+from ray_tpu.ops import block_rows, stacked_product
 
 PUBLISHED_PATTERN = (
     "MEMEMEM*EMEMEMEM*EMEMEMEM*EMEMEMEMEM*EMEMEMEMEM*EMEMEMEMEM*"
@@ -181,7 +181,9 @@ def _attention(cfg: NemotronHConfig, start_pos, positions):
 
 
 def _halves(cfg: NemotronHConfig, start_pos, positions, at):
-    mixers = {"ssm": mamba2.mixer(cfg, start_pos, at),
+    mixers = {"ssm": mamba2.mixer(cfg, start_pos, at,
+                                  in_place=stacked_product.engages(
+                                      positions.shape[1])),
               "attn": _attention(cfg, start_pos, positions), None: None}
     return {kind: (mixers[kind[0]], moe.served_ffn(cfg) if kind[1] else None)
             for kind in set(cfg.blocks)}

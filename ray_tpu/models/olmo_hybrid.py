@@ -56,7 +56,8 @@ import jax.numpy as jnp
 from ray_tpu.models import decoder, gated_delta, llama
 from ray_tpu.models.serving import (Family, attention_init, by_query_blocks,
                                     keys_read_by_blocks, normal)
-from ray_tpu.ops import attention, block_rows
+from ray_tpu.ops import attention, block_rows, stacked_product
+from ray_tpu.ops.stacked_product import leaf_product
 
 PUBLISHED_LAYER_TYPES = ("linear", "linear", "linear", "full") * 8
 
@@ -149,15 +150,15 @@ def _attention(cfg: OlmoHybridConfig, start_pos, positions):
     max_seq] a head, and so does any call off the TPU; on a TPU a
     decode step hands the stacks whole to
     `attention.decode_attention`."""
-    def mixer(h, lp, rope, state, handed):
+    def mixer(h, lp, rope, state, handed, stacks=None):
         (k_stack, v_stack), layer = state
         b, t = h.shape[:2]
-        q = jnp.einsum("bsd,dhk->bshk", h, lp["wq"])
-        k = jnp.einsum("bsd,dhk->bshk", h, lp["wk"])
+        q = leaf_product("bsd,dhk->bshk", h, "wq", lp, stacks)
+        k = leaf_product("bsd,dhk->bshk", h, "wk", lp, stacks)
         if cfg.qk_norm:
             q = llama.norm_all_heads(q, lp["q_norm"], cfg.norm_eps)
             k = llama.norm_all_heads(k, lp["k_norm"], cfg.norm_eps)
-        v = jnp.einsum("bsd,dhk->bshk", h, lp["wv"])
+        v = leaf_product("bsd,dhk->bshk", h, "wv", lp, stacks)
         k_stack, v_stack = block_rows.write_tokens(
             (k_stack, v_stack), layer,
             (k.reshape(b, t, -1), v.reshape(b, t, -1)), start_pos)
@@ -176,6 +177,10 @@ def _attention(cfg: OlmoHybridConfig, start_pos, positions):
                     pos),), t, q, positions)
         return out, (k_stack, v_stack), handed
 
+    # A decode step reads the three projections where they lie in the
+    # run's stack (`ops.stacked_product`), a matrix a head.
+    if stacked_product.engages(positions.shape[1]):
+        mixer.whole = ("wq", "wk", "wv")
     return mixer
 
 
@@ -186,7 +191,10 @@ def _attention(cfg: OlmoHybridConfig, start_pos, positions):
 
 def _halves(cfg: OlmoHybridConfig, start_pos, positions, at):
     ffn = llama.swiglu()
-    return {"linear": (gated_delta.mixer(cfg, start_pos, at), ffn),
+    linear = gated_delta.mixer(
+        cfg, start_pos, at,
+        in_place=stacked_product.engages(positions.shape[1]))
+    return {"linear": (linear, ffn),
             "full": (_attention(cfg, start_pos, positions), ffn)}
 
 

@@ -72,7 +72,10 @@ parameters {"embed", "runs": [a dict of stacked leaves a run],
 - ``halves(cfg, start_pos, positions, at) -> {kind: (mixer, ffn)}``:
   what `decoder.hidden_runs` is handed for a run of each kind (None:
   the block has no such half), made once a call: a recurrent mixer
-  broadcasts `at` when it is made;
+  broadcasts `at` when it is made, and a decode step's halves
+  (`ops.stacked_product.engages(positions.shape[1])`: one token a
+  slot, on a TPU) may name in `whole` the big leaves they read where
+  they lie in the run's stack, which a prefill's never do;
 - what only some have: ``tied`` (the head is the embedding, times
   `cfg.logit_scale`), ``handed(tokens, cache)`` (what enters the first
   layer as `handed`), ``counts(cfg, tokens, cache, start_pos, at)``

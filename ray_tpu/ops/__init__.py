@@ -13,6 +13,10 @@ on CPU and in interpret-mode tests):
 - ``grouped_matmul`` — a served share of experts' grouped products, the
                       matrices read where they lie in a run's stack
                       (``lax.ragged_dot`` off the TPU)
+- ``stacked_product`` — a decode step's product of a few rows with one
+                      layer's matrix, the matrix read where, and in the
+                      order, it lies in a run's stack (``jnp.einsum`` on
+                      the layer's slice off the TPU)
 - ``delta_update``  — the gated delta rule's one-token recurrence, a
                       head's state read once and written back where it
                       lies in a run's stack (the plain recurrence on the

@@ -136,12 +136,14 @@ def _k_chunk(k: int) -> int:
     return k
 
 
-def _columns(k: int, n: int, itemsize: int) -> int:
+def _columns(k: int, n: int, itemsize: int, block_bytes: int = 0) -> int:
     """Columns of the block of a [K, N] matrix a grid step multiplies:
-    the whole matrix where it fits, else one of the fewest even bands of
-    its columns that do, at all of K, so that no product is summed
-    across steps."""
-    most = max(128, _WEIGHT_BLOCK_BYTES // (k * itemsize) // 128 * 128)
+    the whole matrix where it fits `block_bytes` (this module's
+    `_WEIGHT_BLOCK_BYTES` unless given), else one of the fewest even
+    bands of its columns that do, at all of K, so that no product is
+    summed across steps."""
+    most = max(128, (block_bytes or _WEIGHT_BLOCK_BYTES)
+               // (k * itemsize) // 128 * 128)
     bands = -(-n // most)
     return n if bands == 1 else -(-n // (bands * 128)) * 128
 

@@ -96,6 +96,7 @@ from ray_tpu._private import perf_stats
 from ray_tpu._private.config import ray_config
 from ray_tpu._private.kv_cache import PrefixCache, chain_keys
 from ray_tpu.models.serving import served_model
+from ray_tpu.ops import stacked_product
 from ray_tpu.serve.streaming import (LAG_SAMPLE_EVERY, STREAM_WAITING_KEY,
                                      WAITING_BEAT_S)
 
@@ -588,10 +589,17 @@ class LLMEngine:
             return bucket, lowered.compile()
 
         def compile_decode():
-            lowered = self._decode.lower(
-                params_avals, cache_avals, *self._decode_avals(aval),
-                rng_aval)
-            return "decode", lowered.compile()
+            # What the step's layers read of their parameters, and how:
+            # where they lie in their stacks (`ops.stacked_product`) or
+            # as the layer scan's slices, as the trace counted them.
+            with critical_path.span("setup.compile_decode") as sp, \
+                    stacked_product.weights_read() as read:
+                lowered = self._decode.lower(
+                    params_avals, cache_avals, *self._decode_avals(aval),
+                    rng_aval)
+                sp.set(weights_in_place_bytes=read["in_place"],
+                       weights_sliced_bytes=read["sliced"])
+                return "decode", lowered.compile()
 
         def compile_sample():
             lowered = self._sample_admitted.lower(

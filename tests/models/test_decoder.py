@@ -188,3 +188,131 @@ def test_the_skeleton_draws_the_leaves_the_two_initialisers_drew(name):
     ones = {"final_norm": params["final_norm"],
             **{k: params["layers"][k] for k in ("attn_norm", "mlp_norm")}}
     assert all(bool(jnp.all(v == 1)) for v in ones.values())
+
+
+# ---------------------------------------------------------------------------
+# Leaves handed whole (`layers`' contract for both halves)
+# ---------------------------------------------------------------------------
+
+
+def _halves_that_name(mixer_names, ffn_names, seen):
+    """A mixer and a SwiGLU over `llama`'s leaves that read `wv`, and
+    `w1` and `w2`, through `leaf_product`, naming in `whole` what they
+    are told to; `seen` takes what each call was handed."""
+    from ray_tpu.ops.stacked_product import leaf_product
+
+    def mixer(h, lp, rope, state, handed, stacks=None):
+        seen.append(("mixer", stacks, sorted(lp)))
+        v = leaf_product("bsd,dhk->bshk", h, "wv", lp, stacks)
+        return jnp.repeat(v, DENSE.n_heads // DENSE.n_kv_heads, 2), state, \
+            handed
+
+    def ffn(h, lp, stacks=None):
+        seen.append(("ffn", stacks, sorted(lp)))
+        up = jax.nn.silu(leaf_product("bsd,df->bsf", h, "w1", lp, stacks))
+        return leaf_product("bsf,fd->bsd", up, "w2", lp, stacks), None
+
+    mixer.scope = "ssm"
+    if mixer_names:
+        mixer.whole = mixer_names
+    if ffn_names:
+        ffn.whole = ffn_names
+    return mixer, ffn
+
+
+@pytest.mark.parametrize("mixer_names,ffn_names", [
+    (("wv",), ()), ((), ("w1", "w2")), (("wv", "not_a_leaf"), ("w1", "w2")),
+], ids=["mixer", "ffn", "both"])
+def test_a_half_that_names_leaves_is_handed_them_whole(mixer_names,
+                                                       ffn_names):
+    """One rule for both halves: the leaves a half names in `whole` are
+    no scanned input, the half is called with `stacks=(those leaves,
+    layer)` and `lp` holds the rest, its other attributes (`scope`)
+    kept; the hidden states are those of the same halves fed slices."""
+    params, batch = _init(DENSE), _batch(DENSE)
+    plain, handed = [], []
+    want, _, _ = decoder.hidden(params, batch["tokens"], DENSE,
+                                *_halves_that_name((), (), plain))
+    text = jax.jit(lambda p: decoder.hidden(
+        p, batch["tokens"], DENSE,
+        *_halves_that_name(mixer_names, ffn_names, []))).lower(
+            params).as_text(debug_info=True)
+    got, _, _ = decoder.hidden(
+        params, batch["tokens"], DENSE,
+        *_halves_that_name(mixer_names, ffn_names, handed))
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), rtol=1e-6,
+                               atol=1e-6)
+    assert '"ssm' in text and '"attn:' not in text and '"mlp' in text
+    assert all(stacks is None for _, stacks, _ in plain)
+    named = {name for name in mixer_names + ffn_names
+             if name in params["layers"]}
+    for half, stacks, lp in handed:
+        names = {"mixer": mixer_names, "ffn": ffn_names}[half]
+        assert not named & set(lp)
+        if not names:
+            assert stacks is None
+            continue
+        whole, layer = stacks
+        assert set(whole) == named and layer.shape == ()
+        assert all(whole[name].shape == params["layers"][name].shape
+                   for name in named)
+
+
+def _layers_as_they_were(mixer, ffn, cfg, rope, x, stacked, state=None,
+                         handed=None, *, save=None, mesh=None,
+                         rules=decoder.DEFAULT_RULES):
+    """`decoder.layers` before a mixer could name leaves (PR 59's)."""
+    import functools
+    from jax import lax
+
+    whole = {name: stacked[name] for name in getattr(ffn, "whole", ())
+             if name in stacked}
+    if whole:
+        stacked = {name: leaf for name, leaf in stacked.items()
+                   if name not in whole}
+
+    def body(carry, scanned):
+        x, handed, state = carry
+        lp, layer = scanned
+        half = ffn
+        if whole:
+            half = functools.partial(ffn, stacks=(whole, layer))
+            half.stream = getattr(ffn, "stream", False)
+        x, state, extras, handed = decoder.block(
+            mixer, half, cfg, rope, x, lp,
+            None if state is None else (state, layer), handed, mesh=mesh,
+            rules=rules)
+        return (x, handed, state), extras
+
+    if save is not None:
+        body = jax.checkpoint(
+            body,
+            policy=jax.checkpoint_policies.save_only_these_names(*save))
+    index = None if state is None and not whole else jnp.arange(
+        jax.tree.leaves(stacked)[0].shape[0])
+    (x, handed, state), extras = lax.scan(body, (x, handed, state),
+                                          (stacked, index))
+    return x, state, extras, handed
+
+
+@pytest.mark.parametrize("entry", ["loss_fn", "moe_loss_fn", "forward",
+                                   "forward_with_cache"])
+def test_a_run_whose_halves_name_nothing_is_traced_as_it_was(monkeypatch,
+                                                             entry):
+    """The trained path names nothing: its jaxpr (and its gradient's)
+    is, to the letter, what `layers` made of it before the contract
+    reached the mixer, with every leaf a scanned input and no kernel;
+    so is the served dense step's off the TPU."""
+    cfg, fn = ENTRY_POINTS[entry]
+    params, batch = _init(cfg), _batch(cfg)
+
+    def traced():
+        run = fn
+        if entry.endswith("loss_fn"):
+            run = jax.value_and_grad(lambda p, b: fn(p, b)[0])
+        return str(jax.make_jaxpr(run)(params, batch))
+
+    now = traced()
+    monkeypatch.setattr(decoder, "layers", _layers_as_they_were)
+    assert now == traced()
+    assert "pallas_call" not in now

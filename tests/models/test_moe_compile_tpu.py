@@ -197,11 +197,13 @@ def _scheduled(text):
     return "\n".join(kept)
 
 
-def _engines_program(one_chip, name, program, bucket, count_names):
+def _engines_program(one_chip, name, program, bucket, count_names,
+                     compile=True):
     """The engine's own decode or prefill program for the configuration
     `benchmark/configs/<name>.json` at its cell's slots, compiled for
     the described chip from shapes alone: (cfg, params, cache,
-    compiled), the first three as shapes."""
+    compiled), the first three as shapes; lowered and no more where
+    not `compile`."""
     from benchmark.harness.manifest import ROOT, load_json, model_adapter
     from ray_tpu.models.serving import served_model
     from ray_tpu.serve.llm import LLMEngine
@@ -228,15 +230,33 @@ def _engines_program(one_chip, name, program, bucket, count_names):
     engine.max_seq, engine.decode_steps, engine.n_slots = rows, 1, n
     engine._count_names = count_names
     if program == "decode":
-        compiled = jax.jit(engine._decode_impl, donate_argnums=(1,)).lower(
+        lowered = jax.jit(engine._decode_impl, donate_argnums=(1,)).lower(
             params, cache, ints(n), ints(n), ints(n, dtype=jnp.float32),
-            ints(n), ints(2, dtype=jnp.uint32)).compile()
+            ints(n), ints(2, dtype=jnp.uint32))
     else:
-        compiled = jax.jit(engine._prefill_impl, donate_argnums=(1,),
-                           static_argnums=(6,)).lower(
-            params, cache, ints(1, bucket), ints(), ints(), ints(),
-            bucket).compile()
-    return cfg, params, cache, compiled
+        lowered = jax.jit(engine._prefill_impl, donate_argnums=(1,),
+                          static_argnums=(6,)).lower(
+            params, cache, ints(1, bucket), ints(), ints(), ints(), bucket)
+    return cfg, params, cache, lowered.compile() if compile else lowered
+
+
+def _reads_its_weights_where_they_lie(scheduled, text, calls, *leaves):
+    """A decode step whose halves name leaves (`ops/stacked_product.py`;
+    PERF.md, PR 60): `calls` `stacked_product` kernels in all, and no
+    op that leaves in memory an array of one layer of a named leaf
+    (`leaves`: a layer's shape each), which is what the layer scan's
+    slice of it was (`constant_dynamic-slice_fusion`) and what laid a
+    run of one layer's out anew (`copy`). The compiler may still fetch
+    a leaf ahead into fast memory as it lies (`copy-start`,
+    `slice-start`): that reads it once and writes no byte back."""
+    kernels = re.findall(r"%stacked_product(?:\.\d+)? = \S+ custom-call\(",
+                         text)
+    assert len(kernels) == calls
+    for leaf in leaves:
+        dims = ",".join(map(str, leaf))
+        assert not re.findall(
+            rf"= bf16\[(?:1,)?{dims}\]\S* (?:fusion|copy|dynamic-slice|"
+            r"transpose)\(", scheduled), leaf
 
 
 @pytest.mark.parametrize("name,program,bucket,resident,products", [
@@ -265,12 +285,14 @@ def test_served_step_compiles_for_the_v5e(one_chip, name, program, bucket,
     axis; Command A+ at 16 x 16,384, whose sliding layers' rings of
     4,096 rows ride it beside the full layer's rows. `products`: the grouped products an expert layer has, three of
     a gated SwiGLU, two of relu^2."""
-    from ray_tpu.ops import attention, block_rows, grouped_matmul, ssm_update
+    from ray_tpu.ops import (attention, block_rows, grouped_matmul,
+                             ssm_update, stacked_product)
 
     monkeypatch.setattr(grouped_matmul, "on_tpu", lambda: True)
     monkeypatch.setattr(attention, "on_tpu", lambda: True)
     monkeypatch.setattr(block_rows, "on_tpu", lambda: True)
     monkeypatch.setattr(ssm_update, "on_tpu", lambda: True)
+    monkeypatch.setattr(stacked_product, "on_tpu", lambda: True)
     cfg, params, cache, compiled = _engines_program(
         one_chip, name, program, bucket,
         ("pair_overflows", "pairs_held", "pairs_routed"))
@@ -279,6 +301,21 @@ def test_served_step_compiles_for_the_v5e(one_chip, name, program, bucket,
         _updates_its_states_where_they_lie(
             _scheduled(text), program,
             [run["ssm"].shape for run in cache["runs"] if "ssm" in run])
+        # A decode step reads a Mamba-2 layer's output projection where
+        # it lies, one kernel a Mamba-2 run; a prefill names nothing.
+        ssm = [run["wo"].shape[1:] for run in params["runs"]
+               if "w_xbc" in run]
+        _reads_its_weights_where_they_lie(
+            _scheduled(text), text, len(ssm) if program == "decode" else 0,
+            *(ssm if program == "decode" else ()))
+    elif (name, program) == ("command-a-plus-serve", "decode"):
+        # `wq` where it lies, [D, 128 heads, 128] as named, one kernel a
+        # run: the scan's slice of 134 MB a `sliding` layer is gone.
+        _reads_its_weights_where_they_lie(
+            _scheduled(text), text, len(params["runs"]),
+            (cfg.dim, cfg.n_heads, cfg.head_dim))
+    else:  # (no half of GLM-5.2's names a leaf, nor any prefill's)
+        _reads_its_weights_where_they_lie(_scheduled(text), text, 0)
     # A decode step's rows go in by one kernel a run of layers that
     # keep keys (GLM-5.2's three leaves of unlike widths in one call,
     # Command A+'s rings as its full rows, Nemotron's one attention
@@ -313,10 +350,8 @@ def test_served_step_compiles_for_the_v5e(one_chip, name, program, bucket,
         # `wv` out anew (the rotary turn had the compiler do that, to
         # find q in pairs: 134 MB read and written a `sliding` layer,
         # 1.4 ms of a 16.1 ms step: PERF.md, PR 40). The layer scan's
-        # slice of `wq` out of the run's stack stays
-        # (`constant_dynamic-slice_fusion`, 1.2 ms a step). Whoever
-        # takes that: a run unrolled yields its slices from one fusion
-        # as a tuple, which an expression like this one does not see.
+        # slices of `wk` and `wv` out of the run's stack stay (8.4 MB
+        # each; `wq`'s went with PR 60, above).
         d, k = cfg.dim, cfg.head_dim
         assert not re.findall(
             rf"= bf16\[1,{d},(?:{cfg.n_heads}|{cfg.n_kv_heads}),{k}\]\S* "
@@ -415,15 +450,23 @@ def test_the_dense_decode_step_compiles_for_the_v5e(one_chip, monkeypatch):
     layer's keys and values are never sliced out of them; in front of
     it one `write_blocks` call puts the layer's 32 new rows, K's and
     V's, into the same view where the stacks lie."""
-    from ray_tpu.ops import attention, block_rows
+    from ray_tpu.ops import attention, block_rows, stacked_product
 
     monkeypatch.setattr(attention, "on_tpu", lambda: True)
     monkeypatch.setattr(block_rows, "on_tpu", lambda: True)
+    monkeypatch.setattr(stacked_product, "on_tpu", lambda: True)
     cfg, _, cache, compiled = _engines_program(
         one_chip, "mistral-7b-v0.3-serve", "decode", 0, ())
     _, n, rows = cache["k"].shape[:3]
     text = compiled.as_text()
     heads, d = cfg.n_kv_heads, cfg.head_dim
+    # The three projections are read where they lie in the stack of
+    # layers, [D, heads, 128] as named, a head's matrix gathered by
+    # strided loads: the scan's slices of `wq` (33.6 MB) and of `wk`
+    # and `wv` (8.4 MB each) a layer are gone (PERF.md, PR 60).
+    _reads_its_weights_where_they_lie(
+        _scheduled(text), text, 3, (cfg.dim, cfg.n_heads, d),
+        (cfg.dim, heads, d))
     assert cache["k"].shape == (cfg.n_layers, n, rows, heads, d)
     _reads_the_cache_through_the_kernel(
         _scheduled(text), 1, (n, rows, heads, d), (n, rows * heads, d))
@@ -460,17 +503,44 @@ def test_olmo_hybrids_step_compiles_for_the_v5e(one_chip, program, bucket,
     choose, as it would not the attention's: the test says it is on a
     TPU): nothing else makes
     an array of a layer's states or of the stack."""
-    from ray_tpu.ops import attention, block_rows, delta_update
+    from ray_tpu.ops import (attention, block_rows, delta_update,
+                             stacked_product)
 
     monkeypatch.setattr(delta_update, "on_tpu", lambda: True)
     monkeypatch.setattr(attention, "on_tpu", lambda: True)
     monkeypatch.setattr(block_rows, "on_tpu", lambda: True)
+    counts = ("delta_scan_tokens", "delta_state_resets")
+    if program == "prefill":
+        # A prefill's halves name nothing: its program is, to the
+        # letter, the one lowered where no decode step would either.
+        without = _engines_program(one_chip, "olmo-hybrid-7b-serve",
+                                   program, bucket, counts, compile=False)[3]
+    monkeypatch.setattr(stacked_product, "on_tpu", lambda: True)
     cfg, params, cache, compiled = _engines_program(
-        one_chip, "olmo-hybrid-7b-serve", program, bucket,
-        ("delta_scan_tokens", "delta_state_resets"))
+        one_chip, "olmo-hybrid-7b-serve", program, bucket, counts)
     _, n, rows = cache["runs"][1]["k"].shape[:3]
     text = compiled.as_text()
     scheduled = _scheduled(text)
+    if program == "prefill":
+        _reads_its_weights_where_they_lie(scheduled, text, 0)
+        assert without.as_text() == _engines_program(
+            one_chip, "olmo-hybrid-7b-serve", program, bucket, counts,
+            compile=False)[3].as_text()
+    else:
+        # A decode step reads a delta layer's four big projections and
+        # a full layer's three where they lie in the run's stack, as
+        # the TPU stores them ([heads, k, D] and [heads, D, k]): the
+        # scan's slices of the first (`constant_dynamic-slice_fusion`,
+        # 1.4 ms of a 15.1 ms step) and the `copy` that laid the
+        # second out anew (0.5 ms) are gone (PERF.md, PR 60).
+        linear = [run for run in params["runs"] if "conv_q" in run]
+        attended = [run for run in params["runs"] if "conv_q" not in run]
+        _reads_its_weights_where_they_lie(
+            scheduled, text, 4 * len(linear) + 3 * len(attended),
+            *(run[name].shape[1:] for run in linear
+              for name in ("wq", "wk", "wv", "wg")),
+            *(run[name].shape[1:] for run in attended
+              for name in ("wq", "wk", "wv")))
     full = [run["k"].shape for run in cache["runs"] if "k" in run]
     _writes_its_rows_through_the_kernel(
         text, len(full) if program == "decode" else 0, *full)
@@ -696,12 +766,13 @@ def test_kimi_linears_step_compiles_for_the_v5e(one_chip, program, bucket,
     step copied it there and back: `kimi_linear._shared_row`); every expert layer's three products are
     the held path's kernel on the run's stack."""
     from ray_tpu.ops import attention, block_rows, delta_update
-    from ray_tpu.ops import grouped_matmul
+    from ray_tpu.ops import grouped_matmul, stacked_product
 
     monkeypatch.setattr(grouped_matmul, "on_tpu", lambda: True)
     monkeypatch.setattr(delta_update, "on_tpu", lambda: True)
     monkeypatch.setattr(attention, "on_tpu", lambda: True)
     monkeypatch.setattr(block_rows, "on_tpu", lambda: True)
+    monkeypatch.setattr(stacked_product, "on_tpu", lambda: True)
     cfg, params, cache, compiled = _engines_program(
         one_chip, "kimi-linear-48b-a3b-serve", program, bucket,
         ("delta_scan_tokens", "delta_state_resets", "experts_held_steps",
@@ -730,6 +801,13 @@ def test_kimi_linears_step_compiles_for_the_v5e(one_chip, program, bucket,
     assert "ragged-dot" not in text
     states = [run["state"].shape for run in cache["runs"] if "state" in run]
     assert states == [(layers, n, 32, 128, 128) for layers in (1, 2, 3, 3)]
+    # A decode step reads a KDA layer's `wq`, `wk` and `wv` where they
+    # lie in the run's stack, [D, 32 heads, 128] as named, a head's
+    # matrix gathered by strided loads: the scan's three slices of
+    # 18.9 MB a layer are gone (PERF.md, PR 60).
+    _reads_its_weights_where_they_lie(
+        scheduled, text, 3 * len(states) if program == "decode" else 0,
+        *([(cfg.dim, 32, 128)] if program == "decode" else ()))
     made = re.findall(r"%[\w.-]+ = (.*?) (?:copy|copy-start|fusion|"
                       r"dynamic-update-slice)\(", scheduled)
     for state in states if program == "decode" else ():

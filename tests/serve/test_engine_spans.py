@@ -902,3 +902,21 @@ def test_the_folders_beat_says_whether_the_process_stood_still(
     beats = _folder_beats(0.65)
     assert max(beats) >= 0.2, beats
     assert sum(b >= 0.2 for b in beats) == 1
+
+
+def test_the_decode_compile_says_how_the_step_reads_its_weights(params, ring):
+    """PR 60: the engine's compile of its decode program is a span,
+    `setup.compile_decode`, that carries what the trace counted: the
+    bytes of the layers' parameters a step reads where they lie in
+    their stacks (`ops.stacked_product`: none off the TPU) and as the
+    layer scan's slices (here every leaf of the one run)."""
+    engine = LLMEngine(_TINY, params, max_batch_size=2, max_seq_len=64)
+    engine.warmup(16)
+    engine.stop()
+    compiles = _ring("setup.compile_decode")
+    assert len(compiles) == 1 and compiles[0]["parent"] == 0
+    layers = sum(x.size * x.dtype.itemsize
+                 for x in jax.tree.leaves(params["layers"]))
+    assert compiles[0]["attrs"] == {"weights_in_place_bytes": 0,
+                                    "weights_sliced_bytes": layers}
+    assert compiles[0]["dur_s"] > 0
